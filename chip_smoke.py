@@ -1,0 +1,615 @@
+#!/usr/bin/env python
+"""Proof that the two main paths — a trainer that takes steps and a
+server that answers requests — run on one TPU v5e through the entry
+points a user calls, at the full width of models the repo supports.
+
+    python chip_smoke.py              one chip: device, lm_train, lm_serve,
+                                      resnet_train, resnet_serve, kernels
+    python chip_smoke.py --chips 4    the cross-chip paths only: the
+                                      DistributedLMTrainer on a 2x2 mesh and
+                                      tensor-parallel serving on 1x4, each
+                                      against its single-device reference
+    python chip_smoke.py --rehearse [--chips 4]
+                                      the same phases and control flow at a
+                                      tiny size on the CPU (no chip proof:
+                                      its last line says so)
+
+One process owns the chip: everything runs here, nothing is spawned.
+Every phase prints one JSON line; a phase that fails raises and the
+exit code is non-zero. The last line of a successful chip run is
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+"""
+
+import argparse
+import gc
+import http.client
+import json
+import os
+import sys
+import threading
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, REPO)
+# jax and the package are imported inside the functions: --rehearse has to
+# pin the platform before the first import of jax.
+
+# -- sizes -------------------------------------------------------------------
+FULL = {
+    # the LM of bench.py's train cell and ROADMAP S1: GPT-2-small widths
+    "lm": dict(vocab_size=32000, d_model=768, n_heads=12, n_layers=12,
+               max_length=512, compute_dtype="bfloat16"),
+    "lm_batch": 16, "lm_steps": 5,
+    "slots": 4, "requests": 6, "prompt_len": (64, 128), "max_new": 32,
+    "resnet": dict(num_classes=1000, compute_dtype="bfloat16"),
+    "image": 224, "resnet_batch": 128, "resnet_steps": 3,
+    "serve_model": "resnet50",
+    # --chips 4: float32 so that a tolerance means something (the
+    # package pins matmul precision "highest")
+    "lm4": dict(vocab_size=32000, d_model=768, n_heads=12, n_layers=4,
+                max_length=512),
+    "lm4_batch": 8, "lm4_steps": 3,
+    "mlp4": (768, 3072, 768, 1000), "mlp4_rows": 32,
+}
+TINY = {
+    "lm": dict(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
+               max_length=128, compute_dtype="bfloat16"),
+    "lm_batch": 4, "lm_steps": 5,
+    "slots": 4, "requests": 6, "prompt_len": (8, 16), "max_new": 8,
+    "resnet": dict(num_classes=10, compute_dtype="bfloat16"),
+    "image": 32, "resnet_batch": 4, "resnet_steps": 3,
+    "serve_model": "lenet",
+    "lm4": dict(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
+                max_length=128),
+    "lm4_batch": 4, "lm4_steps": 3,
+    "mlp4": (32, 64, 32, 8), "mlp4_rows": 8,
+}
+
+#: relative tolerance of one logit row against another: bf16 keeps 8
+#: bits of mantissa and a 12-block stack rounds the residual stream
+#: after every matmul; float32 under "highest" differs only by
+#: reassociation of partial sums
+LOGIT_RTOL = {"bfloat16": 2e-2, "float32": 1e-5}
+
+
+# -- bookkeeping ---------------------------------------------------------------
+class CompileMeter:
+    """Seconds spent in backend compiles and persistent-cache hits and
+    misses, read from JAX's own monitoring events."""
+
+    def __init__(self):
+        import jax.monitoring as mon
+
+        self.seconds = 0.0
+        self.hits = 0
+        self.misses = 0
+        mon.register_event_duration_secs_listener(self._on_duration)
+        mon.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.seconds += seconds
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def read(self):
+        return self.seconds, self.hits, self.misses
+
+
+def memory_stats():
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    return {k: stats.get(k) for k in ("peak_bytes_in_use", "bytes_in_use")}
+
+
+def run_phase(name, fn, meter, *args):
+    """Run one phase, print its line, and drop what it left on the
+    device. Whatever the phase raises ends the run."""
+    import jax
+
+    s0, h0, m0 = meter.read()
+    t0 = time.perf_counter()
+    out = fn(*args)
+    seconds = time.perf_counter() - t0
+    s1, h1, m1 = meter.read()
+    keep = out.pop("_keep", None)
+    gc.collect()
+    jax.clear_caches()
+    print(json.dumps({
+        "phase": name, "seconds": round(seconds, 3),
+        "compile_seconds": round(s1 - s0, 3),
+        "cache_hits": h1 - h0, "cache_misses": m1 - m0,
+        **memory_stats(), "checked": out}), flush=True)
+    return keep
+
+
+def check(cond, msg):
+    if not cond:
+        raise AssertionError(msg)
+
+
+def lm_batch(vocab, batch, seq, seed=0):
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, vocab, (batch, seq)).astype(np.int32)
+    tgt = np.roll(ids, -1, axis=1).astype(np.int32)
+    tgt[:, -1] = -1
+    return ids, tgt
+
+
+# -- greedy-token comparison -------------------------------------------------
+def next_token_logits(model, prefix, rows):
+    """The logit row that predicts the token after ``prefix``, computed
+    by a cached decode over ``rows`` identical rows (row 0 returned):
+    prefill all but the last token, then one ``decode_step``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deeplearning4j_tpu.models.transformer_lm import (
+        decode_step,
+        init_decode_cache,
+        prefill_cache,
+    )
+
+    cfg = model.cfg
+    n = len(prefix) - 1
+    bucket = next(t for t in model.prefill_buckets() if t >= n)
+    ids = np.zeros((rows, bucket), np.int32)
+    ids[:, :n] = np.asarray(prefix[:-1], np.int32)
+    last = jnp.full((rows,), int(prefix[-1]), jnp.int32)
+
+    @jax.jit
+    def row(params, ids, last):
+        cache = init_decode_cache(cfg, rows)
+        _, cache = prefill_cache(cfg, params, cache, ids,
+                                 length=jnp.asarray(n, jnp.int32))
+        logits, _ = decode_step(cfg, params, cache, last)
+        return logits[0]
+
+    return np.asarray(row(model.params_, jnp.asarray(ids), last), np.float32)
+
+
+def compare_greedy(got, want, prompt_len, row_got, row_want, rtol):
+    """Greedy tokens of two programs over the same weights. Equal is
+    the contract on the CPU. On the chip two programs may tile or
+    partition their matmuls differently; where the tokens part, compare
+    the LOGITS at that position — ``row_got(prefix)`` and
+    ``row_want(prefix)`` recompute the row each side decoded from — and
+    fail unless the rows agree within ``rtol`` and the two candidates
+    were that close to a tie."""
+    import numpy as np
+
+    got, want = np.asarray(got), np.asarray(want)
+    check(got.shape == want.shape, f"lengths differ: {got.shape} {want.shape}")
+    diff = np.nonzero(got != want)[0]
+    if diff.size == 0:
+        return {"tokens_equal": True}
+    pos = int(diff[0])
+    check(pos >= prompt_len, f"prompts differ at {pos}")
+    a, b = row_got(want[:pos]), row_want(want[:pos])
+    scale = float(np.max(np.abs(b)))
+    max_diff = float(np.max(np.abs(a - b)))
+    top2 = np.sort(b)[-2:]
+    cand_gap = float(abs(b[got[pos]] - b[want[pos]]))
+    report = {"tokens_equal": False, "first_difference_at": pos,
+              "tokens": [int(got[pos]), int(want[pos])],
+              "logit_rows_max_diff": max_diff, "logit_scale": scale,
+              "top2_gap": float(top2[1] - top2[0]),
+              "candidates_gap": cand_gap, "rtol": rtol}
+    print(json.dumps({"greedy_tokens_part": report}), flush=True)
+    check(max_diff <= rtol * scale,
+          f"logit rows differ by {max_diff} > {rtol} * {scale}: {report}")
+    check(cand_gap <= 2 * rtol * scale,
+          f"tokens part where the logits do not tie: {report}")
+    return report
+
+
+# -- one-chip phases -----------------------------------------------------------
+def phase_device(want_count, rehearse):
+    import jax
+
+    devs = jax.devices()
+    dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    check(rehearse or dev["platform"] == "tpu",
+          f"no TPU: JAX reports {dev}")
+    check(len(devs) == want_count,
+          f"need {want_count} device(s), JAX reports {dev}")
+    import jaxlib
+
+    return {"device": dev, "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__, "_keep": dev}
+
+
+def phase_lm_train(size):
+    import numpy as np
+
+    from deeplearning4j_tpu.models.transformer_lm import TransformerLM
+
+    model = TransformerLM(**size["lm"]).init()
+    ids, tgt = lm_batch(model.cfg.vocab_size, size["lm_batch"],
+                        model.cfg.max_length)
+    losses = [model.fit_batch(ids, tgt) for _ in range(size["lm_steps"])]
+    check(np.all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    check(losses[-1] < losses[0],
+          f"loss did not fall on the repeated batch: {losses}")
+    compiles = model._jit_cache["step"]._cache_size()
+    check(compiles == 1, f"train step compiled {compiles} times")
+    model.opt_state_ = None  # lm_serve needs the weights only
+    return {"params": model.num_params(), "losses": losses,
+            "step_compiles": compiles, "_keep": model}
+
+
+def _http(port, method, path, body=None, timeout=600):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        conn.request(method, path,
+                     None if body is None else json.dumps(body))
+        resp = conn.getresponse()
+        return resp.status, resp.read()
+    finally:
+        conn.close()
+
+
+def phase_lm_serve(size, model):
+    import numpy as np
+
+    from deeplearning4j_tpu.serving import (
+        BucketPolicy,
+        InferenceEngine,
+        InferenceServer,
+    )
+    from deeplearning4j_tpu.serving.generate import GenerationEngine
+
+    rng = np.random.default_rng(1)
+    lo, hi = size["prompt_len"]
+    prompts = [rng.integers(0, model.cfg.vocab_size,
+                            int(rng.integers(lo, hi + 1))).tolist()
+               for _ in range(size["requests"])]
+    max_new = size["max_new"]
+
+    gen = GenerationEngine(model, n_slots=size["slots"])
+    eng = InferenceEngine(model, buckets=BucketPolicy(batch_buckets=[1]))
+    srv = InferenceServer(eng, port=0, generation=gen).start()
+    try:
+        warm = gen.warmup()
+        traced = dict(gen.trace_counts)
+        answers = [None] * len(prompts)
+
+        def ask(i):
+            answers[i] = _http(srv.port, "POST", "/generate",
+                               {"prompt": prompts[i], "max_new": max_new,
+                                "temperature": 0.0, "stream": False})
+
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=900)
+        check(not any(t.is_alive() for t in threads), "a request hung")
+        bodies = []
+        for i, (status, raw) in enumerate(answers):
+            check(status == 200, f"request {i}: HTTP {status} {raw[:300]}")
+            body = json.loads(raw)
+            check(len(body["tokens"]) == max_new
+                  and body["sequence"] == prompts[i] + body["tokens"],
+                  f"request {i}: wrong token count or sequence")
+            bodies.append(body)
+        retraces = {k: v - traced.get(k, 0)
+                    for k, v in gen.trace_counts.items()
+                    if v != traced.get(k, 0)}
+        check(not retraces, f"retraced after warm-up: {retraces}")
+        status, raw = _http(srv.port, "GET", "/healthz")
+        check(status == 200, f"/healthz: HTTP {status} {raw[:300]}")
+    finally:
+        srv.generation = None
+        srv.shutdown()
+        gen.shutdown()
+
+    solo = model.generate_cached(np.asarray(prompts[0], np.int32),
+                                 max_new=max_new)[0]
+    parity = compare_greedy(
+        bodies[0]["sequence"], solo, len(prompts[0]),
+        lambda prefix: next_token_logits(model, prefix, size["slots"]),
+        lambda prefix: next_token_logits(model, prefix, 1),
+        LOGIT_RTOL["bfloat16"])
+    return {"requests": len(prompts), "slots": size["slots"],
+            "prompt_lens": [len(p) for p in prompts], "max_new": max_new,
+            "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
+            "retraces_after_warmup": 0, "healthz": 200,
+            "vs_generate_cached": parity}
+
+
+def phase_resnet_train(size):
+    import numpy as np
+
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.models.resnet50 import ResNet50
+
+    hw, batch = size["image"], size["resnet_batch"]
+    net = ResNet50(height=hw, width=hw, **size["resnet"]).init()
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((batch, hw, hw, 3)).astype(np.float32)
+    n_cls = size["resnet"]["num_classes"]
+    y = np.eye(n_cls, dtype=np.float32)[rng.integers(0, n_cls, batch)]
+    data = DataSet(x, y)
+    losses = []
+    for _ in range(size["resnet_steps"]):
+        net.fit(data, epochs=1, batch_size=batch)
+        losses.append(float(net.score_))
+    check(np.all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    return {"batch": batch, "image": hw, "losses": losses}
+
+
+def phase_resnet_serve(size):
+    from deeplearning4j_tpu import cli
+
+    # in-process: this process holds the chip, a child could not
+    rc = cli.serve_main(["--model", size["serve_model"], "--port", "0",
+                         "--smoke"])
+    check(rc == 0, f"cli serve --smoke returned {rc}")
+    return {"model": size["serve_model"], "rc": rc}
+
+
+def phase_kernels(platform):
+    """Which Pallas kernels the phases asked for and what each resolved
+    to. On the TPU backend a kernel that fell back to its reference is
+    a failure: the run would otherwise pass on dense XLA."""
+    from deeplearning4j_tpu.nn.conf.layers import attention
+    from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
+
+    snap = default_kernel_registry().snapshot()
+    flash = {repr(k): (None if impl is None
+                       else getattr(impl.args[0], "__module__", "?"))
+             for k, impl in attention._FLASH_PROBE_CACHE.items()}
+    refused = []
+    for key, winner in flash.items():
+        if winner is None:  # every candidate lost: say why each did
+            why = [f"{k}: {v['reason']}"
+                   for k, v in snap.get("flash_attention", {}).items()
+                   if k.startswith(key[:-1] + ",")]
+            refused.append(f"flash_attention {key} fell back to dense "
+                           f"attention ({'; '.join(why)})")
+    for kernel, entries in snap.items():
+        for key, verdict in entries.items():
+            if verdict["enabled"] or kernel == "flash_attention":
+                continue  # a flash candidate may lose while another wins
+            refused.append(f"{kernel} {key}: {verdict['reason']}")
+    out = {"registry": snap, "flash_attention_winner": flash,
+           "refused": refused}
+    if platform == "tpu" and refused:
+        print(json.dumps({"phase": "kernels", "checked": out}), flush=True)
+        raise AssertionError(
+            "kernels fell back to their reference on the TPU backend: "
+            + "; ".join(refused))
+    return out
+
+
+# -- --chips 4 -----------------------------------------------------------------
+def device_bytes(tree):
+    """{device id: bytes of ``tree`` resident there}, from the arrays'
+    own shards."""
+    import jax
+
+    held = {}
+    for leaf in jax.tree_util.tree_leaves(tree):
+        for shard in leaf.addressable_shards:
+            held[shard.device.id] = (held.get(shard.device.id, 0)
+                                     + shard.data.nbytes)
+    return held
+
+
+def check_shares(what, held, expect_each, devices):
+    ids = sorted(d.id for d in devices)
+    check(sorted(held) == ids and all(held[i] == expect_each for i in ids),
+          f"{what}: bytes per device {held}, ledger says "
+          f"{expect_each} on each of {ids}")
+
+
+def phase_dist_train(size):
+    import jax
+    import numpy as np
+
+    from deeplearning4j_tpu.models.transformer_lm import TransformerLM
+    from deeplearning4j_tpu.parallel.mesh import TrainingMesh
+    from deeplearning4j_tpu.parallel.transformer import (
+        DistributedLMTrainer,
+        param_pspecs,
+    )
+
+    devices = jax.devices()
+    cfg = size["lm4"]
+    ids, tgt = lm_batch(cfg["vocab_size"], size["lm4_batch"],
+                        cfg["max_length"])
+    steps = size["lm4_steps"]
+
+    ref = TransformerLM(**cfg).init()
+    ref_losses = [ref.fit_batch(ids, tgt) for _ in range(steps)]
+    del ref
+    gc.collect()
+
+    model = TransformerLM(**cfg).init()
+    mesh = TrainingMesh(data=2, model=2, devices=devices)
+    trainer = DistributedLMTrainer(model, mesh).place()
+
+    # the ledger: every leaf's shard under the spec param_pspecs gives it
+    flat_s, treedef = jax.tree_util.tree_flatten(
+        param_pspecs(model.cfg),
+        is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec))
+    expect = sum(
+        int(np.prod(jax.sharding.NamedSharding(mesh.mesh, spec)
+                    .shard_shape(leaf.shape))) * leaf.dtype.itemsize
+        for leaf, spec in zip(treedef.flatten_up_to(model.params_), flat_s))
+    held = device_bytes(model.params_)
+    check_shares("trainer params", held, expect, devices)
+
+    losses = [trainer.fit_batch(ids, tgt) for _ in range(steps)]
+    check(np.all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-4)
+    check_shares("trainer params after the steps",
+                 device_bytes(model.params_), expect, devices)
+    return {"mesh": {k: v for k, v in mesh.shape.items() if v > 1},
+            "losses": losses, "single_device_losses": ref_losses,
+            "rtol": 1e-4, "param_bytes_total": sum(
+                leaf.nbytes for leaf in
+                jax.tree_util.tree_leaves(model.params_)),
+            "param_bytes_per_device": held}
+
+
+def phase_sharded_serve(size):
+    import jax
+    import numpy as np
+
+    from deeplearning4j_tpu.models.transformer_lm import TransformerLM
+    from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.obs import flight
+    from deeplearning4j_tpu.parallel.serving_mesh import ServingMesh
+    from deeplearning4j_tpu.serving import InferenceEngine
+    from deeplearning4j_tpu.serving.generate import GenerationEngine
+    from deeplearning4j_tpu.serving.sharded import (
+        ShardedInferenceEngine,
+        sharded_generation_engine,
+    )
+
+    devices = jax.devices()
+    mesh = ServingMesh.from_spec("1x4")
+    rng = np.random.default_rng(2)
+
+    # -- /predict: a feed-forward stack at the LM's FFN widths, sharded
+    #    against solo, logits (identity output) not probabilities
+    d_in, d_hid, d_mid, d_out = size["mlp4"]
+
+    def mlp():
+        conf = (NeuralNetConfiguration.builder().seed(11).list()
+                .layer(DenseLayer(n_out=d_hid, activation="relu"))
+                .layer(DenseLayer(n_out=d_mid, activation="relu"))
+                .layer(OutputLayer(n_out=d_out, activation="identity",
+                                   loss="mse"))
+                .set_input_type(InputType.feed_forward(d_in)).build())
+        return MultiLayerNetwork(conf).init()
+
+    x = rng.standard_normal((size["mlp4_rows"], d_in)).astype(np.float32)
+    y_solo = InferenceEngine(mlp()).infer(x)
+    sharded = ShardedInferenceEngine(mlp(), mesh=mesh)
+    y_sh = sharded.infer(x)
+    rtol = LOGIT_RTOL["float32"]
+    scale = float(np.max(np.abs(y_solo)))
+    np.testing.assert_allclose(y_sh, y_solo, rtol=rtol, atol=rtol * scale)
+    check(not sharded.fallback_active, "sharded engine demoted to solo")
+    rep = sharded.shard_report
+    held_mlp = device_bytes(sharded._snap.params)
+    check_shares("sharded /predict params", held_mlp,
+                 rep["per_device_bytes"], devices)
+
+    # -- /generate: the LM, params and KV slab on the mesh, against the
+    #    solo engine
+    cfg = size["lm4"]
+    lo, hi = size["prompt_len"]
+    prompt = rng.integers(0, cfg["vocab_size"], (lo + hi) // 2)
+    max_new, slots = size["max_new"], size["slots"]
+
+    lm_solo = TransformerLM(**cfg).init()
+    solo = GenerationEngine(lm_solo, n_slots=slots)
+    try:
+        toks_solo = solo.submit(prompt, max_new=max_new,
+                                temperature=0.0).result(timeout=900)
+    finally:
+        solo.shutdown()
+    del solo
+    gc.collect()
+
+    lm = TransformerLM(**cfg).init()
+    gsh = sharded_generation_engine(lm, mesh, n_slots=slots)
+    try:
+        gsh.warmup()
+        traced = dict(gsh.trace_counts)
+        toks_sh = gsh.submit(prompt, max_new=max_new,
+                             temperature=0.0).result(timeout=900)
+        check(gsh.trace_counts == traced,
+              f"retraced after warm-up: {traced} -> {gsh.trace_counts}")
+        held_lm = device_bytes(lm.params_)
+        check_shares("sharded LM params", held_lm,
+                     gsh.shard_report["per_device_bytes"], devices)
+        slab = (gsh.backend._kc, gsh.backend._vc)
+        held_kv = device_bytes(slab)
+        check_shares("KV slab", held_kv,
+                     sum(a.nbytes for a in slab) // len(devices), devices)
+    finally:
+        gsh.shutdown()
+    parity = compare_greedy(
+        toks_sh, toks_solo, len(prompt),
+        lambda prefix: next_token_logits(lm, prefix, slots),
+        lambda prefix: next_token_logits(lm_solo, prefix, slots), rtol)
+
+    fell_back = [e for e in flight.default_flight_recorder().events()
+                 if e.get("kind") == "sharded_fallback"]
+    check(not fell_back, f"sharded_fallback recorded: {fell_back}")
+    return {"mesh": "1x4", "predict_max_abs_diff": float(
+                np.max(np.abs(y_sh - y_solo))), "predict_scale": scale,
+            "rtol": rtol, "predict_param_bytes_per_device": held_mlp,
+            "predict_ledger": {k: rep[k] for k in (
+                "total_bytes", "per_device_bytes", "replicated_bytes")},
+            "lm_param_bytes_per_device": held_lm,
+            "lm_ledger": {k: gsh.shard_report[k] for k in (
+                "total_bytes", "per_device_bytes", "replicated_bytes")},
+            "kv_bytes_per_device": held_kv,
+            "generate_vs_solo": parity, "sharded_fallback_events": 0}
+
+
+# -- entry -------------------------------------------------------------------
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4 runs the cross-chip phases only")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny sizes on the CPU: control flow, not proof")
+    args = ap.parse_args(argv)
+
+    if args.rehearse:
+        # must precede the first import of jax
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.chips}")
+    size = TINY if args.rehearse else FULL
+
+    from deeplearning4j_tpu.runtime import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    meter = CompileMeter()
+    t0 = time.perf_counter()
+    dev = run_phase("device", phase_device, meter, args.chips, args.rehearse)
+    if args.chips == 4:
+        run_phase("dist_train", phase_dist_train, meter, size)
+        run_phase("sharded_serve", phase_sharded_serve, meter, size)
+    else:
+        model = run_phase("lm_train", phase_lm_train, meter, size)
+        run_phase("lm_serve", phase_lm_serve, meter, size, model)
+        del model
+        run_phase("resnet_train", phase_resnet_train, meter, size)
+        run_phase("resnet_serve", phase_resnet_serve, meter, size)
+    run_phase("kernels", phase_kernels, meter, dev["platform"])
+    seconds, hits, misses = meter.read()
+    print(json.dumps({
+        "total_seconds": round(time.perf_counter() - t0, 3),
+        "compile_seconds": round(seconds, 3), "cache_hits": hits,
+        "cache_misses": misses, "compile_cache": cache_dir}), flush=True)
+    if args.rehearse:
+        print(json.dumps({"rehearsal_only": True, "device": dev}))
+    else:
+        print(json.dumps({"ok": True, "device": dev}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -153,7 +153,7 @@ register_hook_seam(
 register_hook_seam(
     "kernel.probe", "kernels",
     "kernel availability probes (mode 'transient_compile' carries the "
-    "tunnel-crash signature probe_with_retry retries on)")
+    "compile-service-crash signature probe_with_retry retries on)")
 register_hook_seam(
     "cluster.decision", "cluster",
     "a canary-controller decision about to be epoch-fence checked "

@@ -435,6 +435,7 @@ def serve_main(argv) -> int:
         conn.request("POST", "/predict", _json.dumps({"inputs": x}))
         resp = conn.getresponse()
         body = _json.loads(resp.read())
+        conn.close()
         ok = resp.status == 200 and "outputs" in body
         print(f"smoke: HTTP {resp.status} "
               f"{'ok' if ok else body}", flush=True)
@@ -1229,6 +1230,9 @@ def data_main(argv) -> int:
 
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
+    from deeplearning4j_tpu.runtime import enable_compile_cache
+
+    enable_compile_cache()
     if argv[:1] == ["serve"]:
         return serve_main(argv[1:])
     if argv[:1] == ["tune"]:

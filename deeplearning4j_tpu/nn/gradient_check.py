@@ -24,15 +24,6 @@ DEFAULT_MAX_REL_ERROR = 1e-3
 DEFAULT_MIN_ABS_ERROR = 1e-8
 
 
-def enable_x64(enabled=True):
-    """``jax.enable_x64`` across jax versions: the top-level alias landed
-    after 0.4.x, where only ``jax.experimental.enable_x64`` exists."""
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx
-    return ctx(enabled)
-
-
 def _to64(tree):
     return jax.tree_util.tree_map(
         lambda a: jnp.asarray(np.asarray(a), jnp.float64), tree
@@ -116,7 +107,7 @@ def check_gradients(
     see identical masks (the reference requires deterministic=true layers).
     Returns True if all parameters pass.
     """
-    with enable_x64(True):
+    with jax.enable_x64(True):
         params64 = _to64(net.params_)
         state64 = _to64(net.state_)
         f = _opt64(ds.features)
@@ -151,7 +142,7 @@ def check_gradients_graph(
     from deeplearning4j_tpu.nn.graph import _as_multi
 
     mds = _as_multi(mds)
-    with enable_x64(True):
+    with jax.enable_x64(True):
         params64 = _to64(net.params_)
         state64 = _to64(net.state_)
         feats = tuple(_opt64(f) for f in mds.features)

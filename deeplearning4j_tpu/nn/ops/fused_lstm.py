@@ -30,8 +30,12 @@ backward recomputes the gates from the saved ``(x, h, c)`` residuals and
 applies the standard LSTM cell gradient as an XLA composition (the
 flash-attention recompute discipline — recompute in the backward instead
 of materializing gate activations in the forward). Parity contract
-(tests/test_fused_kernels.py): forward bit-exact vs the reference step
-at fp32 under the interpreter; gradients allclose at ≤1e-5; bf16 carries
+(tests/test_fused_kernels.py, compiled leg against compiled leg, fp32
+under the interpreter): forward bit-exact vs the reference step at
+lane-aligned shapes; at lane-padded shapes the zero-padded gemm may sum
+in another order, so (h', c') agree within one float32 ulp of the
+largest gate pre-activation (bit-exact under the XLA:CPU of jax 0.4,
+not of 0.9); gradients allclose at ≤1e-5; bf16 carries
 the documented ~1e-2 tolerance of one MXU pass vs the "highest"
 -precision XLA path.
 
@@ -273,8 +277,8 @@ def _probe_cell(n_in: int, n: int, dtype, peephole: bool,
                 interpret: bool, B: int = 8) -> None:
     """Compile (AOT — safe under an ambient trace) and EXECUTE the fused
     cell forward + grad at a (B, n_in/n) instance; compare against the
-    reference cell. Raises on any mismatch — a lagging server-side
-    Mosaic can MIScompile, not just reject. ``B`` is the CALLER's padded
+    reference cell. Raises on any mismatch — a compiler can MIScompile
+    a kernel, not just reject it. ``B`` is the CALLER's padded
     batch, not a toy size: a VMEM overflow at the real batch must fail
     the probe, not the training step's compile."""
     rng = np.random.default_rng(0)

@@ -17,10 +17,15 @@ HBM over the full shard. This kernel computes the whole update —
 — in ONE pass over the flat shard: p/g/m/v stream HBM→VMEM once, three
 results stream back, nothing else is materialized. α is computed
 OUTSIDE the kernel with exactly the scalar expression ``Adam.apply``
-uses, so the fused step is **bit-exact** vs the unfused reference — the
-probe (and tests/test_fused_kernels.py) assert ``array_equal`` on
-params AND both Adam slots, including the zero-padding lanes of
-odd-count groups, which provably stay zero through the update.
+uses, so the fused step computes the same expressions as the unfused
+reference. Parity contract (the probe and tests/test_fused_kernels.py,
+compiled leg against compiled leg): params AND both Adam slots agree
+within :func:`parity_atol` — two float32 ulps of the largest operand.
+They are not bit-equal in general: a compiler may contract
+``β·m + (1-β)·g`` into a fused multiply-add (one rounding) in one
+program and not in the other, as the XLA:CPU of jax 0.9 does. The
+zero-padding lanes of odd-count groups stay exactly zero through the
+update either way.
 
 The collectives stay where GSPMD puts them: the kernel's operands carry
 the ``(N, chunk)`` flat-shard layout and its sharding constraints, so
@@ -109,8 +114,16 @@ def fused_adam_apply(p, g, m, v, alpha, *, b1: float, b2: float, eps: float,
 # --------------------------------------------------------------------------
 # group-level impl + probe (wired from parallel/zero.py)
 # --------------------------------------------------------------------------
+def parity_atol(*operands) -> float:
+    """Largest absolute difference the parity contract allows between
+    the fused update and its reference on these operands: two float32
+    ulps of the largest operand magnitude (see the module docstring)."""
+    top = max(float(np.max(np.abs(np.asarray(o)))) for o in operands)
+    return 2.0 * float(np.finfo(np.float32).eps) * max(top, 1.0)
+
+
 def _adam_alpha(upd, t, iteration, epoch):
-    """EXACTLY ``Adam.apply``'s scalar pipeline — bit-parity depends on
+    """EXACTLY ``Adam.apply``'s scalar pipeline — parity depends on
     reusing the same expressions in the same order."""
     tf = t.astype(jnp.float32) if hasattr(t, "astype") else float(t)
     return upd.lr(iteration, epoch) * jnp.sqrt(1 - upd.beta2 ** tf) \
@@ -130,9 +143,10 @@ def _make_impl(interpret: bool) -> Callable:
 
 def _probe_group(upd, n_shards: int, mesh, interpret: bool) -> None:
     """Compile (AOT) and execute the fused update UNDER the training
-    mesh's flat-shard shardings; assert bit-exactness vs the unfused
-    reference program. A GSPMD partitioner that cannot place the Pallas
-    call inside the sharded region fails HERE, not in the train step."""
+    mesh's flat-shard shardings; assert parity (:func:`parity_atol`) vs
+    the unfused reference program. A GSPMD partitioner that cannot
+    place the Pallas call inside the sharded region fails HERE, not in
+    the train step."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     chunk = 2 * _LANE
@@ -176,14 +190,13 @@ def _probe_group(upd, n_shards: int, mesh, interpret: bool) -> None:
               for a in (p, g, m, v, t, it, ep)]
     got = k.lower(*shapes).compile()(*args)
     want = r.lower(*shapes).compile()(*args)
+    atol = parity_atol(p, g, m, v)
     for name, a, b in zip(("p", "m", "v"), got, want):
-        a = np.asarray(a)
-        b = np.asarray(b)
-        if not np.array_equal(a, b):
-            err = float(np.max(np.abs(a - b)))
+        err = float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
+        if not err <= atol:  # also catches NaN
             raise RuntimeError(
                 f"fused ZeRO-1 update parity check failed ({name}): "
-                f"max abs err {err:.3e} (bit-exactness required)")
+                f"max abs err {err:.3e} > {atol:.3e}")
 
 
 def resolve_group_impls(layout, mesh=None,

@@ -85,9 +85,10 @@ def hardware_peak_flops(devices=None) -> Dict[str, object]:
             if sub in kind:
                 return {"peak_flops": per * n, "per_device": per,
                         "n_devices": n, "source": f"table:{sub} (bf16)"}
-        per = TPU_PEAK_FLOPS[-1][1]
-        return {"peak_flops": per * n, "per_device": per, "n_devices": n,
-                "source": f"table:unknown-tpu ({kind!r} → v2 floor)"}
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device_kind {kind!r}: add it "
+            "to TPU_PEAK_FLOPS with its source (a utilization against an "
+            "assumed peak is not a measurement)")
     per = DEFAULT_CPU_PEAK_FLOPS
     return {"peak_flops": per * n, "per_device": per, "n_devices": n,
             "source": f"nominal:{platform} (placeholder — relative MFU "
@@ -113,9 +114,6 @@ def _shape_structs(tree):
 
 
 def _normalize_cost(raw) -> Dict[str, float]:
-    # jax 0.4.x returns [dict]; newer versions a plain dict
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else {}
     if not isinstance(raw, dict):
         return {}
     out = {}

@@ -28,24 +28,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma=False):
-    """``jax.shard_map`` across jax versions (portable-collectives
-    mandate, arXiv 2112.01075): the top-level API with its
-    check_vma/axis_names spelling landed after 0.4.x, where only
-    ``jax.experimental.shard_map`` (check_rep/auto spelling) exists.
-    ``axis_names`` is the set of MANUAL axes; the rest of the mesh stays
-    automatic."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma, **kw)
+    """``jax.shard_map`` with this package's defaults: ``check_vma``
+    off, and ``axis_names`` the set of MANUAL axes (None = all; the
+    rest of the mesh stays automatic)."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def zero1_donation(*argnums) -> tuple:

@@ -76,8 +76,7 @@ def initialize(
     driver/executor bootstrap + Aeron shard/controller address selection
     (``SharedTrainingMaster.java:425-431``).
     """
-    if not _distributed_initialized():
-        _enable_cpu_collectives()
+    if not jax.distributed.is_initialized():
         if coordinator_address is None:
             jax.distributed.initialize()
         else:
@@ -87,35 +86,6 @@ def initialize(
                 process_id=process_id,
             )
     return MultiHostContext()
-
-
-def _enable_cpu_collectives() -> None:
-    """jax 0.4.x ships Gloo CPU collectives in jaxlib but defaults the
-    implementation to 'none', so any cross-process computation on the CPU
-    backend dies with "Multiprocess computations aren't implemented on
-    the CPU backend". Newer jax defaults to 'gloo'; opt in here (before
-    backend init) so the CPU-mesh multi-host path works on both. The
-    flag only registers once xla_bridge is imported, so attempt the
-    update directly and tolerate its absence (renamed/removed → the
-    default is already gloo there)."""
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
-
-
-def _distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized`` across jax versions — 0.4.x has
-    no public predicate, so probe the distributed client's global state."""
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except Exception:  # pragma: no cover — unexpected jax layout
-        return False
 
 
 class MultiHostContext:
@@ -169,13 +139,12 @@ def reinitialize_for_survivors(coordinator_address: str,
     This is the multihost half of elastic recovery; the state half —
     reload ``latest_valid_checkpoint`` and reshard onto the new mesh —
     is topology-independent (parallel/reshard.py), which is exactly why
-    the checkpoint format stays canonical. jax 0.4.x cannot shrink a
-    LIVE world (no barrier re-negotiation), so re-forming is
-    shutdown + initialize, not an in-place membership change."""
-    shutdown = getattr(jax.distributed, "shutdown", None)
-    if _distributed_initialized() and shutdown is not None:
+    the checkpoint format stays canonical. A LIVE world cannot shrink
+    (no barrier re-negotiation), so re-forming is shutdown +
+    initialize, not an in-place membership change."""
+    if jax.distributed.is_initialized():
         try:
-            shutdown()
+            jax.distributed.shutdown()
         except Exception:  # noqa: BLE001 — the old world is already torn
             pass
     return initialize(coordinator_address=coordinator_address,

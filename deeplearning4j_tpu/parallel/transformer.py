@@ -43,7 +43,10 @@ from deeplearning4j_tpu.models.transformer_lm import (
     TransformerLMConfig,
     block_apply,
 )
-from deeplearning4j_tpu.nn.conf.layers.attention import _layer_norm
+from deeplearning4j_tpu.nn.conf.layers.attention import (
+    _layer_norm,
+    dense_attention,
+)
 from deeplearning4j_tpu.parallel.mesh import TrainingMesh, shard_map
 from deeplearning4j_tpu.parallel.ring_attention import ring_attention_sharded
 
@@ -185,8 +188,9 @@ class DistributedLMTrainer:
           routing/capacity/aux stay the GLOBAL single-device math
           (bit-parity with the unsharded step, test-asserted);
         - "seq" stays the ring-attention axis, "pipe" the GPipe ring.
-        The pure stack_scan path (pp==sp==1) is untouched: data/model/
-        expert partition via jit in_shardings alone (GSPMD auto)."""
+        The pure stack_scan path (pp==sp==1) partitions data/model/
+        expert via jit in_shardings alone (GSPMD auto), except
+        attention, which runs in a shard_map over (data, model)."""
         cfg = self.cfg
         mesh = self.mesh
         pp = mesh.shape["pipe"]
@@ -203,6 +207,19 @@ class DistributedLMTrainer:
                 return ring_attention_sharded(
                     q, k, v, axis_name="seq", causal=causal, mask=mask
                 )
+        elif not manual_region and mesh.shape["expert"] == 1 and (
+                dp > 1 or mesh.shape["model"] > 1):
+            # attention is independent per example and per head, so it
+            # runs manual over the axes that shard them: every device
+            # sees whole local (b/dp, h/tp, T, hd) blocks — what a
+            # Pallas flash kernel needs, since GSPMD cannot partition
+            # one (the route declines it under automatic axes)
+            bh = P("data", "model")
+
+            def attn_fn(q, k, v, *, causal, mask=None):
+                local = partial(dense_attention, causal=causal, mask=mask)
+                return shard_map(local, mesh.mesh, in_specs=(bh,) * 3,
+                                 out_specs=bh)(q, k, v)
 
         def _blk(bp, x):
             return block_apply(cfg, bp, x, attn_fn=attn_fn,

@@ -61,7 +61,11 @@ def conf_example_shape(conf) -> Optional[Tuple[int, ...]]:
     warmup, reload re-warming, and ``ZooModel.serving_input_shape``."""
     itype = getattr(conf, "input_type", None)
     if itype is None:
-        return None
+        # a ComputationGraph conf declares one type per network input
+        itypes = getattr(conf, "input_types", None)
+        if not itypes or len(itypes) != 1:
+            return None
+        itype = itypes[0]
     return tuple(itype.shape(1)[1:])
 
 
@@ -272,15 +276,22 @@ class InferenceEngine:
 
     def _build_fn(self, model):
         """Pure jitted forward for models exposing the functional
-        ``_forward`` (MultiLayerNetwork family). Returns None for other
-        models — they serve through ``model.output`` (no compile-count
-        hook, still batched/bucketed/hot-swapped)."""
+        ``_forward`` (MultiLayerNetwork, and a ComputationGraph with one
+        input and one output). Returns None for other models — they
+        serve through ``model.output`` (no compile-count hook, still
+        batched/bucketed/hot-swapped)."""
         if not hasattr(model, "_forward"):
             if not hasattr(model, "output"):
                 raise TypeError(
                     f"{type(model).__name__} has neither _forward nor "
                     "output; cannot serve it")
             return None
+        graph_out = None
+        if hasattr(model, "output_single"):  # ComputationGraph surface
+            if (len(model.conf.network_inputs) != 1
+                    or len(model.conf.network_outputs) != 1):
+                return None
+            graph_out = model.conf.network_outputs[0]
 
         retraces = self.metrics.registry.counter(
             "jit_retraces_total",
@@ -301,6 +312,11 @@ class InferenceEngine:
 
             _flight.record("retrace", fn="serving_forward",
                            shape=str(tuple(x.shape)))
+            if graph_out is not None:
+                acts, _, _, _ = model._forward(
+                    params, state, (x,), train=False, rng=None,
+                    fmasks=(fmask,))
+                return acts[graph_out]
             y, _, _, _, _ = model._forward(params, state, x, train=False,
                                            rng=None, fmask=fmask)
             return y

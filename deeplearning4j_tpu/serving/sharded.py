@@ -295,8 +295,11 @@ def sharded_generation_engine(model, mesh: ServingMesh,
     eng.shard_report = report
     eng.shard_stats = stats
 
-    slab_sharding = NamedSharding(mesh.mesh,
-                                  P(None, "batch", "model", None, None))
+    # placed exactly as the programs hand the slabs back (this spelling
+    # of the spec; an empty draft slab replicated on the mesh), so the
+    # first dispatch and every later one see the same input shardings
+    # and each program is traced once
+    slab_sharding = NamedSharding(mesh.mesh, P(None, "batch", "model"))
 
     be = eng.backend
 
@@ -306,8 +309,11 @@ def sharded_generation_engine(model, mesh: ServingMesh,
         ld = getattr(be, "draft_layers", 0)
         # draft slabs are L-axis slices of the sharded slab: re-derive
         # so they inherit the placement (zero-size when drafting is off)
-        be._dkc = be._kc[:ld] if ld else be._kc[:0]
-        be._dvc = be._vc[:ld] if ld else be._vc[:0]
+        if ld:
+            be._dkc, be._dvc = be._kc[:ld], be._vc[:ld]
+        else:
+            be._dkc = jax.device_put(be._kc[:0], mesh.replicated())
+            be._dvc = jax.device_put(be._vc[:0], mesh.replicated())
 
     orig_reset = be.reset
 
@@ -317,4 +323,16 @@ def sharded_generation_engine(model, mesh: ServingMesh,
 
     be.reset = reset_sharded
     _place_slab()
+
+    # prefill is the one program that reaches dense_attention: trace it
+    # with the mesh visible, so the flash route sees axes GSPMD would
+    # partition over and keeps to XLA attention (a Pallas kernel cannot
+    # be partitioned automatically)
+    solo_prefill = be.prefill
+
+    def prefill_on_mesh(*args, **kw):
+        with jax.set_mesh(mesh.mesh):
+            return solo_prefill(*args, **kw)
+
+    be.prefill = prefill_on_mesh
     return eng

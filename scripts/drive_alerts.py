@@ -16,7 +16,8 @@ import textwrap
 import time
 import urllib.request
 
-sys.path.insert(0, "/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 checks = []
 
@@ -26,13 +27,13 @@ def check(name, ok, detail=""):
     print(f"[{'OK' if ok else 'FAIL'}] {name} {detail}", flush=True)
 
 
-ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
+ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
 
 
 def cli(*args, timeout=300):
     p = subprocess.run(
         [sys.executable, "-m", "deeplearning4j_tpu.cli", *args],
-        capture_output=True, text=True, cwd="/root/repo", env=ENV,
+        capture_output=True, text=True, cwd=REPO, env=ENV,
         timeout=timeout)
     return p.returncode, p.stdout, p.stderr
 
@@ -64,7 +65,7 @@ SERVER = textwrap.dedent("""\
 
 proc = subprocess.Popen([sys.executable, "-c", SERVER],
                         stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                        text=True, env=ENV, cwd="/root/repo")
+                        text=True, env=ENV, cwd=REPO)
 try:
     port = int(proc.stdout.readline())
     base = f"http://127.0.0.1:{port}"
@@ -133,9 +134,9 @@ with tempfile.TemporaryDirectory() as td:
         r.dump(path="{td}/flight_recorder_" + sys.argv[1] + ".json")
     """)
     subprocess.run([sys.executable, "-c", mk, "1111", "step", "fit_end"],
-                   env=ENV, cwd="/root/repo", check=True)
+                   env=ENV, cwd=REPO, check=True)
     subprocess.run([sys.executable, "-c", mk, "2222", "publish",
-                    "canary_start"], env=ENV, cwd="/root/repo",
+                    "canary_start"], env=ENV, cwd=REPO,
                    check=True)
     rc, out, _ = cli("flight-dump", td)
     check("cli flight-dump merges a directory of rings into one "
@@ -182,7 +183,7 @@ with tempfile.TemporaryDirectory() as td:
           out.strip().splitlines()[0] if out.strip() else "")
 
 rc, out, _ = cli("lint", "--alerts-table")
-arch = open("/root/repo/ARCHITECTURE.md").read()
+arch = open(os.path.join(REPO, "ARCHITECTURE.md")).read()
 check("--alerts-table output is byte-identical to the ARCHITECTURE "
       "embed", rc == 0 and out.strip() in arch, f"{len(out)} bytes")
 

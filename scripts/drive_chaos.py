@@ -14,7 +14,8 @@ import jax
 jax.config.update("jax_platforms", "cpu")
 import numpy as np  # noqa: F401
 
-sys.path.insert(0, "/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 checks = []
 

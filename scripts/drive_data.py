@@ -17,7 +17,8 @@ import sys
 import tempfile
 import time
 
-sys.path.insert(0, "/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 checks = []
 
@@ -27,13 +28,13 @@ def check(name, ok, detail=""):
     print(f"[{'OK' if ok else 'FAIL'}] {name} {detail}", flush=True)
 
 
-ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
+ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
 
 
 def cli(*args, timeout=300):
     p = subprocess.run(
         [sys.executable, "-m", "deeplearning4j_tpu.cli", *args],
-        capture_output=True, text=True, cwd="/root/repo", env=ENV,
+        capture_output=True, text=True, cwd=REPO, env=ENV,
         timeout=timeout)
     return p.returncode, p.stdout, p.stderr
 
@@ -94,7 +95,7 @@ proc = subprocess.Popen(
      "--dataset", "mnist", "--data-dir", shards, "--epochs", str(EPOCHS),
      "--checkpoint-dir", ck_kill],
     stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-    cwd="/root/repo", env=ENV)
+    cwd=REPO, env=ENV)
 deadline = time.time() + 240
 ckpt = None
 while time.time() < deadline and proc.poll() is None:

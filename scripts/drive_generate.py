@@ -12,7 +12,8 @@ import time
 import os
 import urllib.request
 
-sys.path.insert(0, "/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 checks = []
 
@@ -22,7 +23,7 @@ def check(name, ok, detail=""):
     print(f"[{'OK' if ok else 'FAIL'}] {name} {detail}", flush=True)
 
 
-ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
+ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
 
 
 def post(url, payload):
@@ -69,7 +70,7 @@ SERVER = textwrap.dedent("""\
 
 proc = subprocess.Popen([sys.executable, "-c", SERVER],
                         stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                        text=True, env=ENV, cwd="/root/repo")
+                        text=True, env=ENV, cwd=REPO)
 try:
     port = int(proc.stdout.readline())
     base = f"http://127.0.0.1:{port}"
@@ -128,7 +129,7 @@ p = subprocess.Popen(
      "--gen-slots", "2", "--gen-max-length", "32",
      "--spec-decode-k", "4", "--prefix-cache-mb", "2"],
     stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
-    env=ENV, cwd="/root/repo")
+    env=ENV, cwd=REPO)
 try:
     port = None
     deadline = time.monotonic() + 240

@@ -11,7 +11,8 @@ import sys
 import tempfile
 import textwrap
 
-sys.path.insert(0, "/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 checks = []
 
@@ -24,10 +25,10 @@ def check(name, ok, detail=""):
 def cli_lint(*args, cwd=None):
     """Run `python -m deeplearning4j_tpu.cli lint ...` as an operator
     would (package boundary: separate process, no test harness)."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     p = subprocess.run(
         [sys.executable, "-m", "deeplearning4j_tpu.cli", "lint", *args],
-        capture_output=True, text=True, cwd=cwd or "/root/repo", env=env)
+        capture_output=True, text=True, cwd=cwd or REPO, env=env)
     return p.returncode, p.stdout, p.stderr
 
 
@@ -111,7 +112,7 @@ with tempfile.TemporaryDirectory(prefix="drive_lint_bl_") as tmp:
 
 # 10: the events table renders and matches ARCHITECTURE ------------------
 rc, out, _ = cli_lint("--events-table")
-arch = open("/root/repo/ARCHITECTURE.md").read()
+arch = open(os.path.join(REPO, "ARCHITECTURE.md")).read()
 check("--events-table renders and ARCHITECTURE embeds it",
       rc == 0 and out.strip() in arch,
       f"{len(out.splitlines())} lines")

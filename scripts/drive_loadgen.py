@@ -15,7 +15,8 @@ import sys
 import tempfile
 import time
 
-sys.path.insert(0, "/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 checks = []
 
@@ -25,13 +26,13 @@ def check(name, ok, detail=""):
     print(f"[{'OK' if ok else 'FAIL'}] {name} {detail}", flush=True)
 
 
-ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo")
+ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
 
 
 def cli(*args, timeout=300):
     p = subprocess.run(
         [sys.executable, "-m", "deeplearning4j_tpu.cli", *args],
-        capture_output=True, text=True, cwd="/root/repo", env=ENV,
+        capture_output=True, text=True, cwd=REPO, env=ENV,
         timeout=timeout)
     return p.returncode, p.stdout, p.stderr
 

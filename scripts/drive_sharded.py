@@ -13,7 +13,8 @@ import textwrap
 import time
 import urllib.request
 
-sys.path.insert(0, "/root/repo")
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 checks = []
 
@@ -23,7 +24,7 @@ def check(name, ok, detail=""):
     print(f"[{'OK' if ok else 'FAIL'}] {name} {detail}", flush=True)
 
 
-ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="/root/repo",
+ENV = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
            XLA_FLAGS="--xla_force_host_platform_device_count=8")
 
 
@@ -106,12 +107,12 @@ SOLO = textwrap.dedent("""\
 
 solo_out = subprocess.run([sys.executable, "-c", SOLO], check=True,
                           capture_output=True, text=True, env=ENV,
-                          cwd="/root/repo")
+                          cwd=REPO)
 solo = json.loads(solo_out.stdout.splitlines()[-1])
 
 proc = subprocess.Popen([sys.executable, "-c", SERVER],
                         stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                        text=True, env=ENV, cwd="/root/repo")
+                        text=True, env=ENV, cwd=REPO)
 try:
     port = int(proc.stdout.readline())
     base = f"http://127.0.0.1:{port}"
@@ -162,7 +163,7 @@ r = subprocess.run(
     [sys.executable, "-m", "deeplearning4j_tpu.cli", "serve",
      "--model", "lenet", "--num-classes", "8", "--mesh", "2x4",
      "--cpu-mesh", "8", "--port", "0", "--smoke"],
-    capture_output=True, text=True, env=dict(os.environ), cwd="/root/repo",
+    capture_output=True, text=True, env=dict(os.environ), cwd=REPO,
     timeout=600)
 out = r.stdout
 check("cli serve --mesh 2x4 boots, shards, and answers the smoke request",

@@ -62,10 +62,25 @@ def _batches(n=4, per=8, seed=0):
     return out
 
 
+def _assert_same_math(a, b, rel=None):
+    """Two DIFFERENT compiled programs computing the same math (guarded
+    vs unguarded, poisoned-and-skipped vs batch-removed): a compiler may
+    contract a multiply-add into one rounding in one program and two in
+    the other (the XLA:CPU of jax 0.9 does), so the contract is equality
+    to one float32 ulp of the tensor's largest element, not bits.
+    ``rel``: where the programs also SUM gradients over a mesh in
+    another order, float32 reassociation — ``rel`` of the tensor's scale."""
+    a, b = np.asarray(a), np.asarray(b)
+    top = np.float32(np.max(np.abs(b)))
+    np.testing.assert_allclose(
+        a, b, rtol=rel or 0,
+        atol=float(np.spacing(top) if rel is None else rel * top))
+
+
 class TestNonFiniteGuard:
-    def test_nan_step_skipped_bit_identical(self):
+    def test_nan_step_skipped_same_as_batch_removed(self):
         """Inject NaN grads at step 1 of 4: the run must equal the same
-        fit with batch 1 removed — params AND updater state, exactly."""
+        fit with batch 1 removed — params AND updater state."""
         batches = _batches()
         with fault_injection(nan_grad_steps=[1]):
             a = _net(FaultPolicy())
@@ -73,8 +88,8 @@ class TestNonFiniteGuard:
         b = _net()
         b.fit(ExistingDataSetIterator(
             [batches[0], batches[2], batches[3]]))
-        np.testing.assert_array_equal(a.params_flat(), b.params_flat())
-        np.testing.assert_array_equal(a.opt_state_flat(), b.opt_state_flat())
+        _assert_same_math(a.params_flat(), b.params_flat())
+        _assert_same_math(a.opt_state_flat(), b.opt_state_flat())
         assert a.bad_step_count == 1
         assert int(a.fault_state_["good_count"]) == 3
         assert int(a.fault_state_["consec"]) == 0  # reset by good steps
@@ -87,7 +102,7 @@ class TestNonFiniteGuard:
         a.fit(ExistingDataSetIterator(batches), epochs=2)
         b = _net()
         b.fit(ExistingDataSetIterator(batches), epochs=2)
-        np.testing.assert_array_equal(a.params_flat(), b.params_flat())
+        _assert_same_math(a.params_flat(), b.params_flat())
         assert a.bad_step_count == 0
 
     def test_max_consecutive_bad_steps_raises(self):
@@ -115,9 +130,7 @@ class TestNonFiniteGuard:
         b.fit(ExistingDataSetIterator([batches[0], batches[2], batches[3]]))
         for name in a.layer_names:
             for k in a.params_[name]:
-                np.testing.assert_array_equal(
-                    np.asarray(a.params_[name][k]),
-                    np.asarray(b.params_[name][k]))
+                _assert_same_math(a.params_[name][k], b.params_[name][k])
         assert a.bad_step_count == 1
 
     def test_tbptt_chunk_guard_skips_batch(self):
@@ -229,8 +242,7 @@ class TestParallelPathsGuard:
         removed = _net()
         ParallelWrapper.builder(removed).workers(4).build().fit(
             ExistingDataSetIterator([ds]), epochs=2)
-        np.testing.assert_array_equal(repl.params_flat(),
-                                      removed.params_flat())
+        _assert_same_math(repl.params_flat(), removed.params_flat())
         assert repl.bad_step_count == 1 and zero.bad_step_count == 1
         np.testing.assert_allclose(zero.params_flat(), repl.params_flat(),
                                    atol=1e-6)
@@ -292,7 +304,7 @@ class TestParallelPathsGuard:
             ref.fit_batch(ids, tgt)
         for a, b in zip(jax.tree_util.tree_leaves(tr.model.params_),
                         jax.tree_util.tree_leaves(ref.model.params_)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            _assert_same_math(a, b, rel=1e-5)  # sums over an 8-way mesh
         assert tr.bad_step_count == 1
 
         with fault_injection(nan_grad_steps=[2]):

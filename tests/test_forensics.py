@@ -468,6 +468,18 @@ class TestHardwareEfficiency:
         pk = obs_cost.hardware_peak_flops()
         assert pk["peak_flops"] > 0 and "source" in pk
 
+    def test_peak_flops_unknown_tpu_kind_is_an_error(self):
+        """A TPU the peak table does not list has no utilization: the
+        lookup raises instead of assuming the slowest generation."""
+        from types import SimpleNamespace
+
+        v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+        pk = obs_cost.hardware_peak_flops([v5e] * 4)
+        assert pk["per_device"] == 197e12 and pk["n_devices"] == 4
+        unknown = SimpleNamespace(platform="tpu", device_kind="TPU v99x")
+        with pytest.raises(ValueError, match="v99x"):
+            obs_cost.hardware_peak_flops([unknown])
+
     def test_train_cost_does_not_perturb_training(self):
         """The analysis lowers with ShapeDtypeStructs — params and the
         rng stream must be untouched, so the fit after a cost report is

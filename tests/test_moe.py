@@ -464,7 +464,7 @@ class TestLMMixedPrecision:
         with pytest.raises(ValueError, match="compute_dtype"):
             TransformerLM(vocab_size=8, compute_dtype="bf16")
 
-    def test_bf16_sp_ring_attention(self):
+    def test_bf16_sp_ring_attention(self, no_persistent_cache):
         """bf16 + sequence parallelism: the ring-attention kernel gets
         bf16 q/k/v but accumulates fp32 internally."""
         from deeplearning4j_tpu.models.transformer_lm import TransformerLM

@@ -555,8 +555,17 @@ class TestDataParallelBundling:
         scores = tb.fit_bundle(ids, tgt)
         assert scores.shape == (2,)
         assert ma.iteration == mb.iteration == 2
-        _assert_trees_equal(ma.params_, mb.params_)
-        _assert_trees_equal(ma.opt_state_, mb.opt_state_)
+        # the single step and the scanned bundle are two programs over
+        # an 8-way mesh: the compiler may contract multiply-adds and
+        # order the gradient sums differently in each (the XLA:CPU of
+        # jax 0.9 does), so the contract is float32 reassociation —
+        # 1e-5 of each tensor's scale — not bits
+        for x, y in zip(
+                jax.tree_util.tree_leaves((ma.params_, ma.opt_state_)),
+                jax.tree_util.tree_leaves((mb.params_, mb.opt_state_))):
+            x, y = np.asarray(x), np.asarray(y)
+            np.testing.assert_allclose(
+                x, y, rtol=1e-5, atol=1e-5 * float(np.max(np.abs(y))))
 
 
 @pytest.mark.slow

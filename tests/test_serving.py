@@ -330,6 +330,23 @@ class TestInferenceEngine:
         x = _rows(3)
         np.testing.assert_allclose(eng.infer(x), net.output(x), atol=1e-6)
 
+    def test_computation_graph_serves_through_the_jitted_path(self):
+        """A one-input one-output ComputationGraph (every zoo CNN — what
+        ``cli serve --model resnet50`` stands up) gets the same jitted,
+        compile-counted forward as a MultiLayerNetwork: its conf's input
+        type drives warmup, and steady state compiles nothing."""
+        graph = _net().to_computation_graph()
+        eng = InferenceEngine(graph,
+                              buckets=BucketPolicy(batch_buckets=[2, 4]))
+        assert eng.compile_count_supported
+        assert eng.example_shape() == (4,)
+        rep = eng.warmup()
+        assert rep["shapes"] == 2 and rep["compiles"] == 2
+        x = _rows(3)
+        np.testing.assert_allclose(eng.infer(x), graph.output_single(x),
+                                   atol=1e-6)
+        assert eng.compile_count == 2
+
     def test_warmup_then_steady_state_zero_compiles(self):
         """The acceptance property: after warmup(), mixed request sizes
         cause ZERO new XLA compilations (compile-count hook)."""

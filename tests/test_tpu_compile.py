@@ -1,0 +1,151 @@
+"""The in-tree Pallas kernels, compiled by the installed TPU compiler for
+a DESCRIBED v5e (no chip attached) at the main path's real widths.
+
+Interpret-mode tests prove the kernel math; they cannot see what Mosaic
+refuses (tiling, fast-memory budget). These compiles can, in about two
+seconds each, so every later PR is guarded at no chip time. Nothing
+runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a ``skipif`` or a ``parametrize`` argument, and never in a
+child process: one process at a time may load the TPU library, and
+under xdist every worker imports this file but only one runs it.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.nn.ops.flash_attention import flash_attention
+from deeplearning4j_tpu.nn.ops.fused_conv import conv3x3, pw_conv
+from deeplearning4j_tpu.nn.ops.fused_lstm import fused_lstm_cell
+from deeplearning4j_tpu.nn.ops.fused_update import fused_adam_apply
+from deeplearning4j_tpu.nn.ops.int8_matmul import int8_matmul
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # the TPU compiler would otherwise write its logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip; keep
+    # the cache out of these compiles so reruns stay silent
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """AOT-compile ``fn`` for the described chip; returns the HLO text."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _custom_calls(text: str) -> int:
+    return text.count("tpu_custom_call")
+
+
+# lm_train's attention instantiation: batch 16, 12 heads, T 512, head 64
+_QKV = [((16, 12, 512, 64), BF16)] * 3
+
+
+@pytest.mark.parametrize("with_seg", [False, True],
+                         ids=["causal", "causal+segment_ids"])
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_lm_width(one_chip, grad, with_seg):
+    def attn(q, k, v, *seg):
+        return flash_attention(q, k, v, causal=True,
+                               segment_ids=seg[0] if seg else None)
+
+    def loss(q, k, v, *seg):
+        return jnp.sum(attn(q, k, v, *seg).astype(F32) ** 2)
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else attn
+    shapes = _QKV + ([((16, 512), jnp.int32)] if with_seg else [])
+    text = _compile(fn, one_chip, *shapes)
+    # forward is one kernel; backward adds the dq and dkv kernels
+    assert _custom_calls(text) >= (3 if grad else 1)
+
+
+def test_fused_lstm_cell_textgenlstm_width(one_chip):
+    # zoo textgenlstm: GravesLSTM (peephole) n_in = n_out = 256
+    B, n = 32, 256
+    mat, vec = ((n, 4 * n), F32), ((n,), F32)
+    text = _compile(
+        fused_lstm_cell, one_chip,
+        ((B, n), F32), ((B, n), F32), ((B, n), F32), mat, mat,
+        ((4 * n,), F32), vec, vec, vec)
+    assert _custom_calls(text) >= 1
+
+
+@pytest.mark.parametrize("shape", [(768, 768), (768, 3072), (3072, 768),
+                                   (32000, 768)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_adam_apply_d768_block_shapes(one_chip, shape):
+    fn = functools.partial(fused_adam_apply,
+                           b1=0.9, b2=0.999, eps=1e-8)
+    text = _compile(fn, one_chip, *([(shape, F32)] * 4), ((), F32))
+    assert _custom_calls(text) >= 1
+
+
+@pytest.mark.parametrize("kn", [(768, 3072), (3072, 768)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_int8_matmul_ffn_width(one_chip, kn):
+    K, N = kn
+    text = _compile(int8_matmul, one_chip,
+                    ((128, K), F32), ((K, N), jnp.int8), ((N,), F32))
+    assert _custom_calls(text) >= 1
+
+
+def _conv_loss(conv):
+    def loss(x, s, t, w):
+        y, st = conv(x, s, t, w, True)
+        return jnp.sum(y.astype(F32)) + jnp.sum(st)
+
+    return loss
+
+
+# ResNet-50 stage 2 at batch 128: 28x28 maps, bottleneck width 128,
+# block width 512 — the 1x1 reduce and the 3x3
+_PW = [((128 * 28 * 28, 512), BF16), ((512,), F32), ((512,), F32),
+       ((512, 128), BF16)]
+_C3 = [((128, 28, 28, 128), BF16), ((128,), F32), ((128,), F32),
+       ((3, 3, 128, 128), BF16)]
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("kernel,shapes", [
+    (pw_conv, _PW), (conv3x3, _C3),
+], ids=["pointwise", "conv3x3"])
+def test_fused_conv_resnet50_stage(one_chip, kernel, shapes, grad):
+    loss = _conv_loss(kernel)
+    fn = jax.grad(loss, argnums=(0, 3)) if grad else loss
+    text = _compile(fn, one_chip, *shapes)
+    # backward adds the dx and dw kernels to the forward one
+    assert _custom_calls(text) >= (3 if grad else 1)
+
+
+def test_compiled_for_the_described_chip(one_chip):
+    """The guard on the guard: the sharding these tests compile for is a
+    TPU v5e, not the CPU the suite runs on."""
+    (dev,) = one_chip.device_set
+    assert dev.platform == "tpu"
+    assert "v5" in dev.device_kind.lower()

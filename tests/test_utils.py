@@ -71,3 +71,28 @@ class TestStringGrid:
         g.write_file(str(p))
         back = utils.StringGrid.from_file(str(p))
         assert back.to_lines() == g.to_lines()
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout():
+    """``runtime.enable_compile_cache``: a cache already placed (by
+    ``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself, or by this
+    harness) is left alone; with none, it is ``<checkout>/.jax_cache`` —
+    a fixed path, never a temp name."""
+    import os
+
+    import jax
+
+    from deeplearning4j_tpu import runtime
+
+    placed = jax.config.jax_compilation_cache_dir
+    assert placed  # conftest.py or the environment did
+    assert runtime.enable_compile_cache() == placed
+    assert jax.config.jax_compilation_cache_dir == placed
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        path = runtime.enable_compile_cache()
+        assert path == os.path.join(runtime.CHECKOUT, ".jax_cache")
+        assert os.path.isfile(os.path.join(runtime.CHECKOUT, "chip_smoke.py"))
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", placed)
