@@ -1,0 +1,15 @@
+"""Device milliseconds a decode step spends under the ``attn`` scope
+(projections, scores over the slab, softmax, output projection): self
+time inside the decode program's executions of the traced window over
+their number.
+
+Left out where the trace shows no scope of the program's at all: it has
+none, or its executable was compiled before they were added and came out
+of the persistent cache, whose key leaves names out (said on stderr)."""
+
+from lib import phases
+
+
+def read(run):
+    program = run["work"].get("decode_program")
+    return phases.run_scope_ms("attn", program) if program else None

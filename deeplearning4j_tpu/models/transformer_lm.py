@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.models.zoo import ZooModel
+from deeplearning4j_tpu.obs import trace as _trace
 from deeplearning4j_tpu.nn.conf.layers.attention import (
     TransformerBlock,
     _layer_norm,
@@ -32,6 +33,23 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
 )
 
 Array = jax.Array
+
+#: the model phases that device time is read by (``jax.named_scope``:
+#: nothing at run time). The benchmark's ``lib/phases.py`` groups a
+#: trace's operations by the innermost of them. For XLA's own operations
+#: a scope is metadata only, and the persistent compile cache leaves it
+#: out of its key. A Pallas custom call is NAMED after its innermost
+#: scope (the flash kernel reads ``attn.27`` in a trace where it read
+#: ``closed_call.82``), so a program that holds one (the LM step, the
+#: prefill buckets) gets a new cache key when a scope around the kernel
+#: is added, moved or renamed.
+SCOPES = ("embed", "attn", "kv_write", "mlp", "head", "loss", "sample",
+          "update")
+_scope = jax.named_scope
+
+_PUT_BATCH = _trace.phase("train.put_batch")
+_DISPATCH = _trace.phase("train.dispatch")
+_FETCH_LOSS = _trace.phase("train.fetch_loss")
 
 
 class TransformerLMConfig:
@@ -166,45 +184,47 @@ def block_apply(cfg: TransformerLMConfig, bp: Dict[str, Array], x: Array,
         x = x.astype(cd)
         bp = {k2: (v.astype(cd) if k2[0] == "W" or k2[0] == "b" else v)
               for k2, v in bp.items()}
-    a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
     # under manual TP the head projections are column slices: this
     # shard owns d_local/head_dim of the hn heads
     d_local = bp["Wq"].shape[-1]
     hn_local = hn * d_local // d
+    with _scope("attn"):
+        a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
 
-    def heads(W):
-        return (a_in @ W).reshape(b, T, hn_local, -1).transpose(0, 2, 1, 3)
+        def heads(W):
+            return (a_in @ W).reshape(b, T, hn_local, -1).transpose(0, 2, 1, 3)
 
-    if cfg.fused_qkv:
-        qkv = a_in @ jnp.concatenate(
-            [bp["Wq"], bp["Wk"], bp["Wv"]], axis=-1)  # (b, T, 3*d_local)
-        q, k, v = (s.reshape(b, T, hn_local, -1).transpose(0, 2, 1, 3)
-                   for s in jnp.split(qkv, 3, axis=-1))
-    else:
-        q, k, v = heads(bp["Wq"]), heads(bp["Wk"]), heads(bp["Wv"])
-    fn = attn_fn if attn_fn is not None else dense_attention
-    o = fn(q, k, v, causal=True, mask=None)
-    o = o.transpose(0, 2, 1, 3).reshape(b, T, d_local).astype(x.dtype)
-    om = o @ bp["Wo"]
-    if tp_axis is not None:
-        om = jax.lax.psum(om, tp_axis)
-    x = x + om + bp["bo"]
-    m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
-    if cfg.n_experts > 0:
-        from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
+        if cfg.fused_qkv:
+            qkv = a_in @ jnp.concatenate(
+                [bp["Wq"], bp["Wk"], bp["Wv"]], axis=-1)  # (b, T, 3*d_local)
+            q, k, v = (s.reshape(b, T, hn_local, -1).transpose(0, 2, 1, 3)
+                       for s in jnp.split(qkv, 3, axis=-1))
+        else:
+            q, k, v = heads(bp["Wq"]), heads(bp["Wk"]), heads(bp["Wv"])
+        fn = attn_fn if attn_fn is not None else dense_attention
+        o = fn(q, k, v, causal=True, mask=None)
+        o = o.transpose(0, 2, 1, 3).reshape(b, T, d_local).astype(x.dtype)
+        om = o @ bp["Wo"]
+        if tp_axis is not None:
+            om = jax.lax.psum(om, tp_axis)
+        x = x + om + bp["bo"]
+    with _scope("mlp"):
+        m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
+        if cfg.n_experts > 0:
+            from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
 
-        y2, aux, _load = _moe_ffn(
-            {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
-            m_in.reshape(b * T, d), jax.nn.gelu,
-            _moe_capacity(cfg, b * T), cfg.top_k,
-            expert_axis=expert_axis, tp_axis=tp_axis,
-        )
-        return x + y2.reshape(b, T, d).astype(x.dtype), aux
-    h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-    hm = h @ bp["W2"]
-    if tp_axis is not None:
-        hm = jax.lax.psum(hm, tp_axis)
-    return x + hm + bp["b2"]
+            y2, aux, _load = _moe_ffn(
+                {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
+                m_in.reshape(b * T, d), jax.nn.gelu,
+                _moe_capacity(cfg, b * T), cfg.top_k,
+                expert_axis=expert_axis, tp_axis=tp_axis,
+            )
+            return x + y2.reshape(b, T, d).astype(x.dtype), aux
+        h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
+        hm = h @ bp["W2"]
+        if tp_axis is not None:
+            hm = jax.lax.psum(hm, tp_axis)
+        return x + hm + bp["b2"]
 
 
 class ContextWindowExceeded(ValueError):
@@ -314,11 +334,13 @@ def sample_next_device(logits, temperature, top_k, top_p, key):
     differ at ties on the boundary — tolerance documented in
     ARCHITECTURE § Continuous batching. The key is split every call
     (data-independent chain) even under greedy, which ignores it."""
-    l = _filter_logits(logits, temperature, top_k, top_p)
-    key, sub = jax.random.split(key)
-    sampled = jax.random.categorical(sub, l)
-    nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1), sampled)
-    return nxt.astype(jnp.int32), key
+    with _scope("sample"):
+        l = _filter_logits(logits, temperature, top_k, top_p)
+        key, sub = jax.random.split(key)
+        sampled = jax.random.categorical(sub, l)
+        nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1),
+                        sampled)
+        return nxt.astype(jnp.int32), key
 
 
 def sample_next_rows(logits, temperature, top_k, top_p, keys):
@@ -331,13 +353,15 @@ def sample_next_rows(logits, temperature, top_k, top_p, keys):
     identical to ``sample_next_device(logits[s:s+1], ..., keys[s])``
     (counter-based PRNG + vmap semantics), which is what makes engine
     output ≡ solo output."""
-    l = _filter_logits(logits, temperature, top_k, top_p)
-    splits = jax.vmap(jax.random.split)(keys)  # (b, 2, 2)
-    nkeys, subs = splits[:, 0], splits[:, 1]
-    sampled = jax.vmap(
-        lambda k, row: jax.random.categorical(k, row[None])[0])(subs, l)
-    nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1), sampled)
-    return nxt.astype(jnp.int32), nkeys
+    with _scope("sample"):
+        l = _filter_logits(logits, temperature, top_k, top_p)
+        splits = jax.vmap(jax.random.split)(keys)  # (b, 2, 2)
+        nkeys, subs = splits[:, 0], splits[:, 1]
+        sampled = jax.vmap(
+            lambda k, row: jax.random.categorical(k, row[None])[0])(subs, l)
+        nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1),
+                        sampled)
+        return nxt.astype(jnp.int32), nkeys
 
 
 def init_decode_cache(cfg: TransformerLMConfig, batch: int,
@@ -378,39 +402,46 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
     b, Tp = ids.shape
     hn = cfg.n_heads
     d = cfg.d_model
-    x = params["embed"][ids] + params["pos"][:Tp][None]
-    if cd is not None:
-        x = x.astype(cd)
+    with _scope("embed"):
+        x = params["embed"][ids] + params["pos"][:Tp][None]
+        if cd is not None:
+            x = x.astype(cd)
 
     def body(x, xs):
         bp, kc, vc = xs
         if cd is not None:
             bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
                   for k2, v in bp.items()}
-        a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
+        with _scope("attn"):
+            a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
 
-        def heads(W):
-            return (a_in @ W).reshape(b, Tp, hn, -1).transpose(0, 2, 1, 3)
+            def heads(W):
+                return (a_in @ W).reshape(b, Tp, hn, -1).transpose(0, 2, 1, 3)
 
-        q, k, v = heads(bp["Wq"]), heads(bp["Wk"]), heads(bp["Wv"])
-        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype), (0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype), (0, 0, 0, 0))
-        o = dense_attention(q, k, v, causal=True, mask=None)
-        o = o.transpose(0, 2, 1, 3).reshape(b, Tp, d).astype(x.dtype)
-        x = x + o @ bp["Wo"] + bp["bo"]
-        m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
-        if cfg.n_experts > 0:
-            from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
+            q, k, v = heads(bp["Wq"]), heads(bp["Wk"]), heads(bp["Wv"])
+        with _scope("kv_write"):
+            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                              (0, 0, 0, 0))
+            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                              (0, 0, 0, 0))
+        with _scope("attn"):
+            o = dense_attention(q, k, v, causal=True, mask=None)
+            o = o.transpose(0, 2, 1, 3).reshape(b, Tp, d).astype(x.dtype)
+            x = x + o @ bp["Wo"] + bp["bo"]
+        with _scope("mlp"):
+            m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
+            if cfg.n_experts > 0:
+                from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
 
-            y2, _aux, _load = _moe_ffn(
-                {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
-                m_in.reshape(b * Tp, d), jax.nn.gelu,
-                _moe_capacity(cfg, b * Tp), cfg.top_k,
-            )
-            x = x + y2.reshape(b, Tp, d).astype(x.dtype)
-        else:
-            h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-            x = x + h @ bp["W2"] + bp["b2"]
+                y2, _aux, _load = _moe_ffn(
+                    {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
+                    m_in.reshape(b * Tp, d), jax.nn.gelu,
+                    _moe_capacity(cfg, b * Tp), cfg.top_k,
+                )
+                x = x + y2.reshape(b, Tp, d).astype(x.dtype)
+            else:
+                h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
+                x = x + h @ bp["W2"] + bp["b2"]
         return x, (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -422,9 +453,10 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
         pos_out = jnp.asarray(length, jnp.int32)
         x_last = jax.lax.dynamic_index_in_dim(x, pos_out - 1, axis=1,
                                               keepdims=False)
-    x_last = _ln(x_last, params["lnf_g"], params["lnf_b"], cd)
-    head = params["head"].astype(cd) if cd is not None else params["head"]
-    logits = (x_last @ head).astype(jnp.float32)
+    with _scope("head"):
+        x_last = _ln(x_last, params["lnf_g"], params["lnf_b"], cd)
+        head = params["head"].astype(cd) if cd is not None else params["head"]
+        logits = (x_last @ head).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v, "pos": pos_out}
 
 
@@ -453,10 +485,11 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
     pos = cache["pos"]
     per_row = getattr(pos, "ndim", 0) == 1
     T = cache["k"].shape[3]
-    ptab = jnp.take(params["pos"], pos, axis=0)  # clip-mode gather
-    x = params["embed"][ids_1] + (ptab if per_row else ptab[None, :])
-    if cd is not None:
-        x = x.astype(cd)
+    with _scope("embed"):
+        ptab = jnp.take(params["pos"], pos, axis=0)  # clip-mode gather
+        x = params["embed"][ids_1] + (ptab if per_row else ptab[None, :])
+        if cd is not None:
+            x = x.astype(cd)
     b = x.shape[0]
     hn = cfg.n_heads
     d = cfg.d_model
@@ -472,46 +505,54 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
         if cd is not None:
             bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
                   for k2, v in bp.items()}
-        a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
+        with _scope("attn"):
+            a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
 
-        def head_proj(W):
-            return (a_in @ W).reshape(b, hn, -1)
+            def head_proj(W):
+                return (a_in @ W).reshape(b, hn, -1)
 
-        q, k, v = head_proj(bp["Wq"]), head_proj(bp["Wk"]), head_proj(bp["Wv"])
-        if per_row:
-            rows = jnp.arange(b)
-            kc = kc.at[rows, :, wp].set(k.astype(kc.dtype))
-            vc = vc.at[rows, :, wp].set(v.astype(vc.dtype))
-        else:
-            kc = jax.lax.dynamic_update_index_in_dim(
-                kc, k.astype(kc.dtype), pos, 2)
-            vc = jax.lax.dynamic_update_index_in_dim(
-                vc, v.astype(vc.dtype), pos, 2)
-        scores = jnp.einsum("bhd,bhtd->bht", q, kc).astype(jnp.float32) * scale
-        scores = jnp.where(valid[:, None, :] if per_row
-                           else valid[None, None, :], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1).astype(kc.dtype)
-        o = jnp.einsum("bht,bhtd->bhd", p, vc).reshape(b, d).astype(x.dtype)
-        x = x + o @ bp["Wo"] + bp["bo"]
-        m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
-        if cfg.n_experts > 0:
-            from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
+            q, k, v = (head_proj(bp["Wq"]), head_proj(bp["Wk"]),
+                       head_proj(bp["Wv"]))
+        with _scope("kv_write"):
+            if per_row:
+                rows = jnp.arange(b)
+                kc = kc.at[rows, :, wp].set(k.astype(kc.dtype))
+                vc = vc.at[rows, :, wp].set(v.astype(vc.dtype))
+            else:
+                kc = jax.lax.dynamic_update_index_in_dim(
+                    kc, k.astype(kc.dtype), pos, 2)
+                vc = jax.lax.dynamic_update_index_in_dim(
+                    vc, v.astype(vc.dtype), pos, 2)
+        with _scope("attn"):
+            scores = jnp.einsum("bhd,bhtd->bht", q,
+                                kc).astype(jnp.float32) * scale
+            scores = jnp.where(valid[:, None, :] if per_row
+                               else valid[None, None, :], scores, -1e30)
+            p = jax.nn.softmax(scores, axis=-1).astype(kc.dtype)
+            o = jnp.einsum("bht,bhtd->bhd", p,
+                           vc).reshape(b, d).astype(x.dtype)
+            x = x + o @ bp["Wo"] + bp["bo"]
+        with _scope("mlp"):
+            m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
+            if cfg.n_experts > 0:
+                from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
 
-            y2, _aux, _load = _moe_ffn(
-                {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
-                m_in, jax.nn.gelu, _moe_capacity(cfg, b), cfg.top_k,
-            )
-            x = x + y2.astype(x.dtype)
-        else:
-            h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-            x = x + h @ bp["W2"] + bp["b2"]
+                y2, _aux, _load = _moe_ffn(
+                    {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
+                    m_in, jax.nn.gelu, _moe_capacity(cfg, b), cfg.top_k,
+                )
+                x = x + y2.astype(x.dtype)
+            else:
+                h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
+                x = x + h @ bp["W2"] + bp["b2"]
         return x, (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-    head = params["head"].astype(cd) if cd is not None else params["head"]
-    logits = (x @ head).astype(jnp.float32)
+    with _scope("head"):
+        x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
+        head = params["head"].astype(cd) if cd is not None else params["head"]
+        logits = (x @ head).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v, "pos": pos + 1}
 
 
@@ -556,10 +597,11 @@ def decode_steps(cfg: TransformerLMConfig, params: Dict[str, Array],
     d = cfg.d_model
     scale = 1.0 / math.sqrt(d // hn)
     cols = pos[:, None] + jnp.arange(K)[None, :]  # (b, K) absolute pos
-    ptab = jnp.take(params["pos"], cols, axis=0)  # clip-mode gather
-    x = params["embed"][ids_k] + ptab
-    if cd is not None:
-        x = x.astype(cd)
+    with _scope("embed"):
+        ptab = jnp.take(params["pos"], cols, axis=0)  # clip-mode gather
+        x = params["embed"][ids_k] + ptab
+        if cd is not None:
+            x = x.astype(cd)
     valid = jnp.arange(T)[None, None, :] <= cols[:, :, None]  # (b, K, T)
     rows = jnp.arange(b)
 
@@ -568,36 +610,42 @@ def decode_steps(cfg: TransformerLMConfig, params: Dict[str, Array],
         if cd is not None:
             bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
                   for k2, v in bp.items()}
-        a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
+        with _scope("attn"):
+            a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
 
-        def head_proj(W):
-            return (a_in @ W).reshape(b, K, hn, -1)  # (b, K, hn, hd)
+            def head_proj(W):
+                return (a_in @ W).reshape(b, K, hn, -1)  # (b, K, hn, hd)
 
-        k, v = head_proj(bp["Wk"]), head_proj(bp["Wv"])
-        q = head_proj(bp["Wq"]).transpose(0, 2, 1, 3)  # (b, hn, K, hd)
-        # advanced indices at axes 0 and 2 around the ':' slice → result
-        # dims (b, K) lead, so the (b, K, hn, hd) values scatter directly
-        kc = kc.at[rows[:, None], :, cols].set(k.astype(kc.dtype),
-                                               mode="drop")
-        vc = vc.at[rows[:, None], :, cols].set(v.astype(vc.dtype),
-                                               mode="drop")
-        scores = jnp.einsum("bhkd,bhtd->bhkt", q,
-                            kc).astype(jnp.float32) * scale
-        scores = jnp.where(valid[:, None, :, :], scores, -1e30)
-        p = jax.nn.softmax(scores, axis=-1).astype(kc.dtype)
-        o = jnp.einsum("bhkt,bhtd->bhkd", p, vc)
-        o = o.transpose(0, 2, 1, 3).reshape(b, K, d).astype(x.dtype)
-        x = x + o @ bp["Wo"] + bp["bo"]
-        m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
-        h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-        x = x + h @ bp["W2"] + bp["b2"]
+            k, v = head_proj(bp["Wk"]), head_proj(bp["Wv"])
+            q = head_proj(bp["Wq"]).transpose(0, 2, 1, 3)  # (b, hn, K, hd)
+        with _scope("kv_write"):
+            # advanced indices at axes 0 and 2 around the ':' slice →
+            # result dims (b, K) lead, so the (b, K, hn, hd) values
+            # scatter directly
+            kc = kc.at[rows[:, None], :, cols].set(k.astype(kc.dtype),
+                                                   mode="drop")
+            vc = vc.at[rows[:, None], :, cols].set(v.astype(vc.dtype),
+                                                   mode="drop")
+        with _scope("attn"):
+            scores = jnp.einsum("bhkd,bhtd->bhkt", q,
+                                kc).astype(jnp.float32) * scale
+            scores = jnp.where(valid[:, None, :, :], scores, -1e30)
+            p = jax.nn.softmax(scores, axis=-1).astype(kc.dtype)
+            o = jnp.einsum("bhkt,bhtd->bhkd", p, vc)
+            o = o.transpose(0, 2, 1, 3).reshape(b, K, d).astype(x.dtype)
+            x = x + o @ bp["Wo"] + bp["bo"]
+        with _scope("mlp"):
+            m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
+            h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
+            x = x + h @ bp["W2"] + bp["b2"]
         return x, (kc, vc)
 
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-    head = params["head"].astype(cd) if cd is not None else params["head"]
-    logits = (x @ head).astype(jnp.float32)
+    with _scope("head"):
+        x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
+        head = params["head"].astype(cd) if cd is not None else params["head"]
+        logits = (x @ head).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v, "pos": pos + K}
 
 
@@ -631,10 +679,11 @@ def forward(cfg: TransformerLMConfig, params: Dict[str, Array], ids: Array,
     ``cast_logits=False`` keeps logits in the compute dtype — the loss
     path's choice, so no full-vocab fp32 tensor is materialized (see
     ``token_nll``)."""
-    x = params["embed"][ids] + params["pos"][pos_offset:pos_offset + ids.shape[1]][None]
     cd = _cdtype(cfg)
-    if cd is not None:
-        x = x.astype(cd)  # stable scan-carry dtype; blocks keep it bf16
+    with _scope("embed"):
+        x = params["embed"][ids] + params["pos"][pos_offset:pos_offset + ids.shape[1]][None]
+        if cd is not None:
+            x = x.astype(cd)  # stable scan-carry dtype; blocks keep it bf16
 
     if cfg.n_experts > 0:
         def body(carry, bp):
@@ -650,11 +699,12 @@ def forward(cfg: TransformerLMConfig, params: Dict[str, Array], ids: Array,
 
         x, _ = jax.lax.scan(body, x, params["blocks"])
         aux = jnp.zeros((), jnp.float32)
-    x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-    head = params["head"].astype(cd) if cd is not None else params["head"]
-    logits = x @ head
-    if cast_logits:
-        logits = logits.astype(jnp.float32)  # inference APIs: fp32 logits
+    with _scope("head"):
+        x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
+        head = params["head"].astype(cd) if cd is not None else params["head"]
+        logits = x @ head
+        if cast_logits:
+            logits = logits.astype(jnp.float32)  # inference APIs: fp32 logits
     if return_aux:
         return logits, aux
     return logits
@@ -669,14 +719,15 @@ def token_nll(logits, targets):
     passes per step (the LM step's single largest activation).
     logits (..., V) any float dtype; targets (...) int32, -1 = ignore.
     Returns (mean_nll, valid_count)."""
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    tgt = jnp.maximum(targets, 0)
-    tgt_logit = jnp.take_along_axis(lf, tgt[..., None], axis=-1)[..., 0]
-    valid = (targets >= 0).astype(jnp.float32)
-    nll = (lse - tgt_logit) * valid
-    count = jnp.maximum(jnp.sum(valid), 1.0)
-    return jnp.sum(nll) / count, count
+    with _scope("loss"):
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        tgt = jnp.maximum(targets, 0)
+        tgt_logit = jnp.take_along_axis(lf, tgt[..., None], axis=-1)[..., 0]
+        valid = (targets >= 0).astype(jnp.float32)
+        nll = (lse - tgt_logit) * valid
+        count = jnp.maximum(jnp.sum(valid), 1.0)
+        return jnp.sum(nll) / count, count
 
 
 def lm_loss(cfg: TransformerLMConfig, params, ids, targets, attn_fn=None,
@@ -704,7 +755,8 @@ def lm_loss(cfg: TransformerLMConfig, params, ids, targets, attn_fn=None,
                           cast_logits=False)
     loss, _ = token_nll(logits, targets)
     if cfg.n_experts > 0:
-        loss = loss + cfg.aux_loss_weight * aux
+        with _scope("loss"):
+            loss = loss + cfg.aux_loss_weight * aux
     return loss
 
 
@@ -775,10 +827,11 @@ class TransformerLM(ZooModel):
             flat_g = treedef.flatten_up_to(grads)
             flat_o = treedef.flatten_up_to(opt_state)
             new_p, new_o = [], []
-            for p, g, o in zip(flat_p, flat_g, flat_o):
-                delta, o2 = upd.apply(g, o, t, t, 0)
-                new_p.append(p - delta)
-                new_o.append(o2)
+            with _scope("update"):
+                for p, g, o in zip(flat_p, flat_g, flat_o):
+                    delta, o2 = upd.apply(g, o, t, t, 0)
+                    new_p.append(p - delta)
+                    new_o.append(o2)
             return (jax.tree_util.tree_unflatten(treedef, new_p),
                     jax.tree_util.tree_unflatten(treedef, new_o), loss)
 
@@ -793,14 +846,18 @@ class TransformerLM(ZooModel):
             self._jit_cache[key] = self._make_step(
                 with_seg=segment_ids is not None)
         self.iteration += 1
-        args = [self.params_, self.opt_state_, jnp.asarray(ids, jnp.int32),
-                jnp.asarray(targets, jnp.int32),
-                jnp.asarray(self.iteration, jnp.int32)]
-        if segment_ids is not None:
-            args.append(jnp.asarray(segment_ids, jnp.int32))
-        self.params_, self.opt_state_, self.score_ = \
-            self._jit_cache[key](*args)
-        return float(self.score_)
+        with _PUT_BATCH:
+            args = [self.params_, self.opt_state_,
+                    jnp.asarray(ids, jnp.int32),
+                    jnp.asarray(targets, jnp.int32),
+                    jnp.asarray(self.iteration, jnp.int32)]
+            if segment_ids is not None:
+                args.append(jnp.asarray(segment_ids, jnp.int32))
+        with _DISPATCH:
+            self.params_, self.opt_state_, self.score_ = \
+                self._jit_cache[key](*args)
+        with _FETCH_LOSS:
+            return float(self.score_)
 
     def logits(self, ids: np.ndarray) -> np.ndarray:
         if "fwd" not in self._jit_cache:

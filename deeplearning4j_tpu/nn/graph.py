@@ -52,9 +52,18 @@ from deeplearning4j_tpu.nn.multilayer import (
     _dtype_of,
     _resolve_remat_policy,
 )
+from deeplearning4j_tpu.obs import trace as _trace
 from deeplearning4j_tpu.updaters import NoOp
 
 Array = jax.Array
+
+# host phases of one training step (obs/trace.py): what the input pipeline
+# costs the loop, the host arrays going to the device, everything that
+# launches device work, and what the program itself reads back
+_ITERATE = _trace.phase("train.iterate")
+_PUT_BATCH = _trace.phase("train.put_batch")
+_DISPATCH = _trace.phase("train.dispatch")
+_FETCH_LOSS = _trace.phase("train.fetch_loss")
 
 
 def _as_multi(ds: Union[DataSet, MultiDataSet]) -> MultiDataSet:
@@ -328,7 +337,6 @@ class ComputationGraph:
             from deeplearning4j_tpu.obs import telemetry as _obs_telemetry
 
         def _jit(fn):
-            from deeplearning4j_tpu.obs import trace as _trace
             from deeplearning4j_tpu.train import faults as _faults
 
             # telemetry's extra reads are plain dataflow; the
@@ -490,7 +498,7 @@ class ComputationGraph:
             from deeplearning4j_tpu.data.iterators import iter_grouped
 
             stream = iter_grouped(stream, k, self._multi_compat_key)
-        for item in stream:
+        for item in _trace.each_next(_ITERATE, stream):
             if isinstance(item, list):
                 self._fit_bundle(bstep, item, tconf)
             elif use_tbptt and item.features[0].ndim == 3:
@@ -562,58 +570,63 @@ class ComputationGraph:
                 lst.on_gradient_calculation(self, grads_np)
 
     def _fit_batch(self, step, mds: MultiDataSet, tconf=None):
-        from deeplearning4j_tpu.obs import trace as _trace
         from deeplearning4j_tpu.train.listeners import _hook_recipients
 
-        feats = tuple(jnp.asarray(f) for f in mds.features)
-        labels = tuple(jnp.asarray(l) for l in mds.labels)
-        fmasks = tuple(None if m is None else jnp.asarray(m) for m in mds.features_masks)
-        lmasks = tuple(None if m is None else jnp.asarray(m) for m in mds.labels_masks)
-        rng = self._next_rng()
-        self._run_introspection(feats, labels, fmasks, lmasks, rng)
-        policy = self._active_fault_policy()
-        telem = None
-        with _trace.step_span("train", self.iteration):
-            if policy is not None:
-                fstate = self._ensure_fault_state(policy)
-                out = step(
-                    self.params_, self.opt_state_, self.state_, fstate,
-                    feats, labels, fmasks, lmasks, rng,
-                    jnp.asarray(self.iteration, jnp.int32),
-                    jnp.asarray(self.epoch, jnp.int32),
-                )
-                if tconf is not None:
-                    *out, telem = out
-                (self.params_, self.opt_state_, self.state_,
-                 self.fault_state_, self.score_) = out
-            else:
-                out = step(
-                    self.params_, self.opt_state_, self.state_, feats,
-                    labels, fmasks, lmasks, rng,
-                    jnp.asarray(self.iteration, jnp.int32),
-                    jnp.asarray(self.epoch, jnp.int32),
-                )
-                if tconf is not None:
-                    *out, telem = out
-                (self.params_, self.opt_state_, self.state_,
-                 self.score_) = out
+        with _PUT_BATCH:
+            feats = tuple(jnp.asarray(f) for f in mds.features)
+            labels = tuple(jnp.asarray(l) for l in mds.labels)
+            fmasks = tuple(None if m is None else jnp.asarray(m)
+                           for m in mds.features_masks)
+            lmasks = tuple(None if m is None else jnp.asarray(m)
+                           for m in mds.labels_masks)
+        with _DISPATCH:
+            rng = self._next_rng()
+            self._run_introspection(feats, labels, fmasks, lmasks, rng)
+            policy = self._active_fault_policy()
+            telem = None
+            with _trace.step_span("train", self.iteration):
+                if policy is not None:
+                    fstate = self._ensure_fault_state(policy)
+                    out = step(
+                        self.params_, self.opt_state_, self.state_, fstate,
+                        feats, labels, fmasks, lmasks, rng,
+                        jnp.asarray(self.iteration, jnp.int32),
+                        jnp.asarray(self.epoch, jnp.int32),
+                    )
+                    if tconf is not None:
+                        *out, telem = out
+                    (self.params_, self.opt_state_, self.state_,
+                     self.fault_state_, self.score_) = out
+                else:
+                    out = step(
+                        self.params_, self.opt_state_, self.state_, feats,
+                        labels, fmasks, lmasks, rng,
+                        jnp.asarray(self.iteration, jnp.int32),
+                        jnp.asarray(self.epoch, jnp.int32),
+                    )
+                    if tconf is not None:
+                        *out, telem = out
+                    (self.params_, self.opt_state_, self.state_,
+                     self.score_) = out
         it0 = self.iteration
         self.iteration += 1
         self.last_batch_size = int(feats[0].shape[0])
-        if policy is not None:
-            from deeplearning4j_tpu.train import faults as _faults
+        if policy is not None or telem is not None or self.listeners:
+            with _FETCH_LOSS:
+                if policy is not None:
+                    from deeplearning4j_tpu.train import faults as _faults
 
-            _faults.check_fault_state(policy, self.fault_state_, owner=self)
-        if telem is not None:
-            from deeplearning4j_tpu.obs import telemetry as _telemetry
+                    _faults.check_fault_state(policy, self.fault_state_, owner=self)
+                if telem is not None:
+                    from deeplearning4j_tpu.obs import telemetry as _telemetry
 
-            _telemetry.dispatch_telemetry(
-                self.listeners, self, it0, self.epoch,
-                _telemetry.BundleTelemetry(telem, 1))
-        for lst in _hook_recipients(self.listeners, "on_backward_pass"):
-            lst.on_backward_pass(self)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
+                    _telemetry.dispatch_telemetry(
+                        self.listeners, self, it0, self.epoch,
+                        _telemetry.BundleTelemetry(telem, 1))
+                for lst in _hook_recipients(self.listeners, "on_backward_pass"):
+                    lst.on_backward_pass(self)
+                for lst in self.listeners:
+                    lst.iteration_done(self, self.iteration, self.epoch)
 
     def _fit_bundle(self, bstep, group, tconf=None):
         """K optimizer steps in one dispatch (train/pipeline.py): per-slot
@@ -643,7 +656,6 @@ class ComputationGraph:
         policy = self._active_fault_policy()
         it0 = self.iteration
         telem = None
-        from deeplearning4j_tpu.obs import trace as _trace
 
         with _trace.step_span("train_bundle", it0):
             if policy is not None:

@@ -43,9 +43,18 @@ from deeplearning4j_tpu.nn.conf.layers.base import (
 from deeplearning4j_tpu.nn.conf.layers.recurrent import BaseRecurrentLayer
 from deeplearning4j_tpu.nn.conf.layers.special import CenterLossOutputLayer, FrozenLayer
 from deeplearning4j_tpu.regularization import normalize_layer_gradients
+from deeplearning4j_tpu.obs import trace as _trace
 from deeplearning4j_tpu.updaters import NoOp
 
 Array = jax.Array
+
+# host phases of one training step (obs/trace.py): what the input pipeline
+# costs the loop, the host arrays going to the device, everything that
+# launches device work, and what the program itself reads back
+_ITERATE = _trace.phase("train.iterate")
+_PUT_BATCH = _trace.phase("train.put_batch")
+_DISPATCH = _trace.phase("train.dispatch")
+_FETCH_LOSS = _trace.phase("train.fetch_loss")
 
 
 def _dtype_of(name: str):
@@ -379,7 +388,6 @@ class MultiLayerNetwork:
             from deeplearning4j_tpu.obs import telemetry as _obs_telemetry
 
         def _jit(fn):
-            from deeplearning4j_tpu.obs import trace as _trace
             from deeplearning4j_tpu.train import faults as _faults
 
             # telemetry's extra reads (update norm = new - old) are plain
@@ -548,7 +556,7 @@ class MultiLayerNetwork:
             if k > 1 else None)
         use_tbptt = self.conf.backprop_type == "tbptt"
         try:
-            for ds in stream:
+            for ds in _trace.each_next(_ITERATE, stream):
                 if isinstance(ds, BatchBundle):
                     self._fit_bundle(bstep, ds, tconf)
                 elif use_tbptt and ds.features.ndim == 3:
@@ -628,70 +636,73 @@ class MultiLayerNetwork:
                 lst.on_gradient_calculation(self, grads_np)
 
     def _fit_batch(self, step, ds: DataSet, tconf=None):
-        from deeplearning4j_tpu.obs import trace as _trace
         from deeplearning4j_tpu.train.listeners import _hook_recipients
 
-        features = jnp.asarray(ds.features)
-        labels = None if ds.labels is None else jnp.asarray(ds.labels)
-        if self._augment is not None:
-            # jitted device stage fused ahead of the train step —
-            # iteration passed as a dynamic scalar (no retrace per step).
-            # Batch-crossing stages (mixup) mix labels with the same
-            # lam/permutation, so they take the pair path.
-            if labels is not None and getattr(self._augment,
-                                              "mixes_labels", False):
-                features, labels = self._augment.apply_pair(
-                    features, labels, self.iteration)
-            else:
-                features = self._augment.apply(features, self.iteration)
-        fmask = (None if ds.features_mask is None
-                 else jnp.asarray(ds.features_mask))
-        lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
-        rng = self._next_rng()
-        self._run_introspection(features, labels, fmask, lmask, rng)
-        policy = self._active_fault_policy()
-        telem = None
-        with _trace.step_span("train", self.iteration):
-            if policy is not None:
-                fstate = self._ensure_fault_state(policy)
-                out = step(
-                    self.params_, self.opt_state_, self.state_, fstate,
-                    features, labels, fmask, lmask, rng,
-                    jnp.asarray(self.iteration, jnp.int32),
-                    jnp.asarray(self.epoch, jnp.int32),
-                )
-                if tconf is not None:
-                    *out, telem = out
-                (self.params_, self.opt_state_, self.state_,
-                 self.fault_state_, self.score_) = out
-            else:
-                out = step(
-                    self.params_, self.opt_state_, self.state_,
-                    features, labels, fmask, lmask, rng,
-                    jnp.asarray(self.iteration, jnp.int32),
-                    jnp.asarray(self.epoch, jnp.int32),
-                )
-                if tconf is not None:
-                    *out, telem = out
-                (self.params_, self.opt_state_, self.state_,
-                 self.score_) = out
+        with _PUT_BATCH:
+            features = jnp.asarray(ds.features)
+            labels = None if ds.labels is None else jnp.asarray(ds.labels)
+            fmask = (None if ds.features_mask is None
+                     else jnp.asarray(ds.features_mask))
+            lmask = None if ds.labels_mask is None else jnp.asarray(ds.labels_mask)
+        with _DISPATCH:
+            if self._augment is not None:
+                # jitted device stage fused ahead of the train step —
+                # iteration passed as a dynamic scalar (no retrace per step).
+                # Batch-crossing stages (mixup) mix labels with the same
+                # lam/permutation, so they take the pair path.
+                if labels is not None and getattr(self._augment,
+                                                  "mixes_labels", False):
+                    features, labels = self._augment.apply_pair(
+                        features, labels, self.iteration)
+                else:
+                    features = self._augment.apply(features, self.iteration)
+            rng = self._next_rng()
+            self._run_introspection(features, labels, fmask, lmask, rng)
+            policy = self._active_fault_policy()
+            telem = None
+            with _trace.step_span("train", self.iteration):
+                if policy is not None:
+                    fstate = self._ensure_fault_state(policy)
+                    out = step(
+                        self.params_, self.opt_state_, self.state_, fstate,
+                        features, labels, fmask, lmask, rng,
+                        jnp.asarray(self.iteration, jnp.int32),
+                        jnp.asarray(self.epoch, jnp.int32),
+                    )
+                    if tconf is not None:
+                        *out, telem = out
+                    (self.params_, self.opt_state_, self.state_,
+                     self.fault_state_, self.score_) = out
+                else:
+                    out = step(
+                        self.params_, self.opt_state_, self.state_,
+                        features, labels, fmask, lmask, rng,
+                        jnp.asarray(self.iteration, jnp.int32),
+                        jnp.asarray(self.epoch, jnp.int32),
+                    )
+                    if tconf is not None:
+                        *out, telem = out
+                    (self.params_, self.opt_state_, self.state_,
+                     self.score_) = out
         it0 = self.iteration
         self.iteration += 1
         self.last_batch_size = int(features.shape[0])
-        if policy is not None:
-            from deeplearning4j_tpu.train import faults as _faults
+        if policy is not None or telem is not None or self.listeners:
+            with _FETCH_LOSS:
+                if policy is not None:
+                    from deeplearning4j_tpu.train import faults as _faults
 
-            _faults.check_fault_state(policy, self.fault_state_, owner=self)
-        if telem is not None:
-            from deeplearning4j_tpu.obs import telemetry as _telemetry
+                    _faults.check_fault_state(policy, self.fault_state_, owner=self)
+                if telem is not None:
+                    from deeplearning4j_tpu.obs import telemetry as _telemetry
 
-            _telemetry.dispatch_telemetry(
-                self.listeners, self, it0, self.epoch,
-                _telemetry.BundleTelemetry(telem, 1))
-        for lst in _hook_recipients(self.listeners, "on_backward_pass"):
-            lst.on_backward_pass(self)
-        for lst in self.listeners:
-            lst.iteration_done(self, self.iteration, self.epoch)
+                    _telemetry.dispatch_telemetry(
+                        self.listeners, self, it0, self.epoch,
+                        _telemetry.BundleTelemetry(telem, 1))
+                for lst in _hook_recipients(self.listeners, "on_backward_pass"):
+                    lst.on_backward_pass(self)
+                for lst in self.listeners:
+                    lst.iteration_done(self, self.iteration, self.epoch)
 
     def _fit_bundle(self, bstep, bundle, tconf=None):
         """K optimizer steps in ONE dispatch (train/pipeline.py): the
@@ -700,7 +711,6 @@ class MultiLayerNetwork:
         tripwire is checked once per bundle on the final ``consec``.
         With telemetry the stacked per-step signals ride the same
         dispatch and reach listeners through one deferred fetch."""
-        from deeplearning4j_tpu.obs import trace as _trace
         from deeplearning4j_tpu.train import faults as _faults
         from deeplearning4j_tpu.train import pipeline as _pipeline
 

@@ -16,8 +16,10 @@ pipelined training loop (train/pipeline.py) removed.
   gradient/parameter global norms, update:param ratio and loss scale
   computed INSIDE the jitted train step, stacked by the ``lax.scan``
   bundle and delivered to listeners via ``telemetry_done``.
-- :mod:`obs.trace` — ``jax.profiler`` span annotations around the
-  dispatch sites, plus a registry-backed per-function jit cache-miss
+- :mod:`obs.trace` — host phases at the phase boundaries of the hot
+  paths (one call site: a ``jax.profiler`` annotation, a bounded ring on
+  the profiler's clock and per-name counters), the bare span
+  annotations, plus a registry-backed per-function jit cache-miss
   counter so steady-state recompiles surface as a metric instead of a
   mystery slowdown.
 - :mod:`obs.exporter` — stdlib HTTP endpoint exposing a registry
@@ -68,6 +70,9 @@ from deeplearning4j_tpu.obs.telemetry import (  # noqa: F401
 from deeplearning4j_tpu.obs.trace import (  # noqa: F401
     RetraceMonitor,
     count_retraces,
+    observe,
+    phase,
+    phases,
     retrace_counts,
     span,
     step_span,

@@ -1,14 +1,26 @@
-"""Trace spans and the jit retrace monitor.
+"""Host phases, trace spans and the jit retrace monitor.
 
-Two pieces:
+Three pieces:
 
-- **Spans**: thin wrappers over ``jax.profiler`` annotations —
-  :func:`step_span` (``StepTraceAnnotation``) brackets each training
-  dispatch so xprof/Perfetto traces show one box per optimizer
-  step/bundle, :func:`span` (``TraceAnnotation``) brackets serving
-  dispatches and checkpoint writes. Both are no-ops (nullcontext) when
-  the profiler API is unavailable, and cost ~a TraceMe when no trace is
-  active.
+- **Phases**: :func:`phase` names a stretch of host work at a phase
+  boundary of a hot path (``train.put_batch``, ``gen.decode.fetch``).
+  One call site feeds two sinks from one pair of clock reads: a
+  ``jax.profiler.TraceAnnotation`` (xprof and the benchmark's gap
+  attribution see it where the host tracer is on) and one process-wide
+  bounded ring of ``(name, start_ns, duration_ns)`` plus the per-name
+  counters ``host_phase_total`` / ``host_phase_seconds_total`` in the
+  default registry. ``start_ns`` is ``time.time_ns()``: the profiler
+  stamps its planes from the same wall clock (a plane's times count from
+  the session's ``profile_start_time``), so a ring entry can be laid over
+  the device plane of the same process with the host tracer off. Phases
+  are always counted; there is no switch. :func:`observe` enters an
+  interval measured elsewhere (queue wait spans two threads);
+  :func:`phases` hands the ring out.
+
+- **Spans**: :func:`span` and :func:`step_span` are the bare
+  ``jax.profiler`` annotations (``TraceAnnotation``,
+  ``StepTraceAnnotation``) for call sites that are not a phase of a step:
+  serving dispatches, checkpoint writes, one box per optimizer step.
 
 - **Retrace monitor**: generalizes serving/engine.py's trace-time
   compile-count hook into a registry-backed per-function jit cache-miss
@@ -23,8 +35,12 @@ Two pieces:
 
 from __future__ import annotations
 
-import contextlib
-from typing import Callable, Dict, Optional
+import threading
+import time
+from collections import deque
+from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from deeplearning4j_tpu.obs.metrics import MetricsRegistry, default_registry
 
@@ -114,21 +130,116 @@ class RetraceMonitor:
 # --------------------------------------------------------------------------
 def step_span(name: str, step: int):
     """``jax.profiler.StepTraceAnnotation`` around one training dispatch
-    (xprof groups device work per step); nullcontext when unavailable."""
-    try:
-        import jax
-
-        return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
-    except (ImportError, AttributeError):
-        return contextlib.nullcontext()
+    (xprof groups device work per step)."""
+    return StepTraceAnnotation(name, step_num=int(step))
 
 
-def span(name: str, **kwargs):
-    """``jax.profiler.TraceAnnotation`` around a host-side region
-    (serving dispatch, checkpoint write); nullcontext when unavailable."""
-    try:
-        import jax
+#: ``jax.profiler.TraceAnnotation`` around a host-side region that is no
+#: phase of a step (serving dispatch, checkpoint write)
+span = TraceAnnotation
 
-        return jax.profiler.TraceAnnotation(name, **kwargs)
-    except (ImportError, AttributeError):
-        return contextlib.nullcontext()
+
+# --------------------------------------------------------------------------
+# phases
+# --------------------------------------------------------------------------
+PHASE_COUNTER = "host_phase_total"
+PHASE_SECONDS = "host_phase_seconds_total"
+RING_SIZE = 65536
+
+#: (name, start_ns on ``time.time_ns()``, duration_ns), oldest first
+_ring: deque = deque(maxlen=RING_SIZE)
+_sites: Dict[str, "Phase"] = {}
+_sites_lock = threading.Lock()
+
+
+class _Open(threading.local):
+    """The phases a thread has entered and not left, innermost last."""
+
+    def __init__(self):
+        self.stack: List[Tuple[TraceAnnotation, int]] = []
+
+
+class Phase:
+    """One named phase; ``with`` it around the work. Re-entrant and
+    shared between threads: what is open lives on a per-thread stack.
+    Get it from :func:`phase`, once, where the name is known."""
+
+    __slots__ = ("name", "_count", "_seconds", "_open")
+
+    def __init__(self, name: str):
+        self.name = name
+        reg = default_registry()
+        self._count = reg.counter(
+            PHASE_COUNTER, "host phases entered, by name",
+            labels={"phase": name})
+        self._seconds = reg.counter(
+            PHASE_SECONDS, "host seconds spent inside a phase, by name",
+            labels={"phase": name})
+        self._open = _Open()
+
+    def __enter__(self) -> "Phase":
+        annotation = TraceAnnotation(self.name)
+        annotation.__enter__()
+        self._open.stack.append((annotation, time.time_ns()))
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.time_ns()
+        annotation, start = self._open.stack.pop()
+        annotation.__exit__(*exc)
+        self.record(start, end - start)
+
+    def cancel(self) -> None:
+        """Leave without an entry: what was entered turned out to be no
+        phase (the ``next()`` that found its iterator exhausted)."""
+        annotation, _ = self._open.stack.pop()
+        annotation.__exit__(None, None, None)
+
+    def record(self, start_ns: int, duration_ns: int) -> None:
+        _ring.append((self.name, start_ns, duration_ns))
+        self._count.inc()
+        self._seconds.inc(max(duration_ns, 0) * 1e-9)
+
+
+def phase(name: str) -> Phase:
+    """The :class:`Phase` of ``name`` (one object a name, made on first
+    use). Hot paths resolve it once, at import or in ``__init__``."""
+    site = _sites.get(name)
+    if site is None:
+        with _sites_lock:
+            site = _sites.setdefault(name, Phase(name))
+    return site
+
+
+def observe(name: str, start_ns: int, duration_ns: int) -> None:
+    """Enter an interval measured elsewhere into the ring and counters
+    of ``name``; ``start_ns`` is on ``time.time_ns()``."""
+    phase(name).record(int(start_ns), int(duration_ns))
+
+
+def phases(since_ns: Optional[int] = None) -> List[Tuple[str, int, int]]:
+    """The ring, oldest first: entries are appended when a phase ENDS,
+    so an enclosing phase follows the phases inside it. ``since_ns``
+    keeps the entries that start at or after it."""
+    entries = list(_ring)
+    if since_ns is None:
+        return entries
+    return [e for e in entries if e[1] >= since_ns]
+
+
+def each_next(site: Phase, iterable):
+    """Yield the items of ``iterable`` with every ``next()`` that returns
+    one under ``site``: what an input pipeline costs the loop that
+    consumes it, one phase an item."""
+    it = iter(iterable)
+    while True:
+        site.__enter__()
+        try:
+            item = next(it)
+        except BaseException as stop:
+            site.cancel()  # exhausted or failed: no item, no phase
+            if isinstance(stop, StopIteration):
+                return
+            raise
+        site.__exit__(None, None, None)
+        yield item

@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu.chaos import hooks as chaos_hooks
+from deeplearning4j_tpu.obs import trace as _trace
 from deeplearning4j_tpu.obs.lockwitness import witnessed_lock
 from deeplearning4j_tpu.serving import rtrace
 from deeplearning4j_tpu.serving.batcher import (
@@ -73,6 +74,18 @@ from deeplearning4j_tpu.serving.batcher import (
 )
 from deeplearning4j_tpu.serving.metrics import GenerationMetrics
 
+# host phases of the worker loop (obs/trace.py). ``gen.admit`` encloses
+# ``gen.prefill``, which encloses ``gen.prefill.put``; the others follow
+# one another, so the self times sum to a loop iteration.
+_ADMIT = _trace.phase("gen.admit")
+_PREFILL = _trace.phase("gen.prefill")
+_PREFILL_PUT = _trace.phase("gen.prefill.put")
+_DECODE_PUT = _trace.phase("gen.decode.put")
+_DECODE_DISPATCH = _trace.phase("gen.decode.dispatch")
+_DECODE_FETCH = _trace.phase("gen.decode.fetch")
+_EMIT = _trace.phase("gen.emit")
+_IDLE_WAIT = _trace.phase("gen.idle_wait")
+_QUEUE_WAIT = _trace.phase("gen.queue_wait")
 
 class GenerationMemoryError(ServingError):
     """The requested ``n_slots × max_length`` decode slab would not fit
@@ -113,8 +126,11 @@ class GenerationRequest:
         self.seed = int(seed)
         #: absolute time.monotonic() deadline, or None
         self.deadline = deadline
-        self.enqueued_at = time.monotonic()
         self.trace = rtrace.RequestTrace() if trace else None
+        #: the trace's ``enqueue`` mark where there is one, so that the
+        #: queue wait and the trace's ``queue`` stage are one interval
+        self.enqueued_at = (self.trace.marks[0][1] if trace
+                            else time.monotonic())
         #: generated token ids, in order (grows as decoding proceeds)
         self.tokens: List[int] = []
         #: speculative-decoding accounting: draft tokens proposed for /
@@ -440,10 +456,11 @@ class _TransformerBackend:
             trace_hook("generation_prefill")
             tmp = init_decode_cache(cfg, 1, max_length=T)
             logits, tmp = prefill_cache(cfg, p, tmp, ids, length=ln)
-            kc = jax.lax.dynamic_update_slice(kc, tmp["k"],
-                                              (0, slot, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, tmp["v"],
-                                              (0, slot, 0, 0, 0))
+            with jax.named_scope("kv_write"):
+                kc = jax.lax.dynamic_update_slice(kc, tmp["k"],
+                                                  (0, slot, 0, 0, 0))
+                vc = jax.lax.dynamic_update_slice(vc, tmp["v"],
+                                                  (0, slot, 0, 0, 0))
             if Ld:
                 # the truncated draft model prefills its own (shallower)
                 # slab from the same prompt
@@ -454,10 +471,11 @@ class _TransformerBackend:
                                        tmp["v"].dtype),
                         "pos": jnp.zeros((), jnp.int32)}
                 _dl, dtmp = prefill_cache(cfg, dp, dtmp, ids, length=ln)
-                dkc = jax.lax.dynamic_update_slice(dkc, dtmp["k"],
-                                                   (0, slot, 0, 0, 0))
-                dvc = jax.lax.dynamic_update_slice(dvc, dtmp["v"],
-                                                   (0, slot, 0, 0, 0))
+                with jax.named_scope("kv_write"):
+                    dkc = jax.lax.dynamic_update_slice(dkc, dtmp["k"],
+                                                       (0, slot, 0, 0, 0))
+                    dvc = jax.lax.dynamic_update_slice(dvc, dtmp["v"],
+                                                       (0, slot, 0, 0, 0))
             tok0, key = sample_next_device(logits, t, k, pp, key)
             return tok0[0], key, kc, vc, dkc, dvc, logits[0]
 
@@ -563,29 +581,40 @@ class _TransformerBackend:
         ``generate_cached``)."""
         tp = int(prompt.shape[0])
         tb = tp if self._cfg.n_experts > 0 else self.bucket_for(tp)
-        ids = np.zeros((1, tb), np.int32)
-        ids[0, :tp] = prompt
+        with _PREFILL_PUT:
+            ids = np.zeros((1, tb), np.int32)
+            ids[0, :tp] = prompt
+            args = (jnp.asarray(ids), jnp.asarray(tp, jnp.int32),
+                    jnp.asarray(int(slot), jnp.int32),
+                    jnp.asarray(temperature, jnp.float32),
+                    jnp.asarray(int(top_k), jnp.int32),
+                    jnp.asarray(top_p, jnp.float32), jnp.asarray(key))
         tok0, key, self._kc, self._vc, self._dkc, self._dvc, logits0 = \
             self._prefill_fn(
                 self.model.params_, self._kc, self._vc, self._dkc,
-                self._dvc, jnp.asarray(ids),
-                jnp.asarray(tp, jnp.int32),
-                jnp.asarray(int(slot), jnp.int32),
-                jnp.asarray(temperature, jnp.float32),
-                jnp.asarray(int(top_k), jnp.int32),
-                jnp.asarray(top_p, jnp.float32), jnp.asarray(key))
+                self._dvc, *args)
+        del args  # see decode
         return int(tok0), np.asarray(key), tb, logits0
 
     def decode(self, tokens, pos, active, temperature, top_k, top_p, keys):
         """One batched token step for all slots; returns
         (next tokens (S,), advanced keys (S, 2)) as host arrays — the
         single per-token host sync for the whole batch."""
-        nxt, nkeys, self._kc, self._vc = self._decode_fn(
-            self.model.params_, self._kc, self._vc,
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active),
-            jnp.asarray(temperature), jnp.asarray(top_k),
-            jnp.asarray(top_p), jnp.asarray(keys))
-        return np.asarray(nxt), np.asarray(nkeys)
+        with _DECODE_PUT:
+            args = (jnp.asarray(tokens), jnp.asarray(pos),
+                    jnp.asarray(active), jnp.asarray(temperature),
+                    jnp.asarray(top_k), jnp.asarray(top_p),
+                    jnp.asarray(keys))
+        with _DECODE_DISPATCH:
+            nxt, nkeys, self._kc, self._vc = self._decode_fn(
+                self.model.params_, self._kc, self._vc, *args)
+            # let the step's input buffers go while it runs, as the
+            # call temporaries they were before the put had a phase of
+            # its own: held until this returns, they are freed on this
+            # thread after the fetch
+            del args
+        with _DECODE_FETCH:
+            return np.asarray(nxt), np.asarray(nkeys)
 
     def verify(self, toks_k, dlen, pos, active, temperature, top_k, top_p,
                keys):
@@ -594,21 +623,31 @@ class _TransformerBackend:
         host arrays (emitted (S, K), accepted counts e (S,), new current
         token (S,), advanced keys (S, 2)) — still ONE host sync for up
         to K tokens per slot."""
-        s, e, last, nkeys, self._kc, self._vc = self._verify_fn(
-            self.model.params_, self._kc, self._vc,
-            jnp.asarray(toks_k), jnp.asarray(dlen), jnp.asarray(pos),
-            jnp.asarray(active), jnp.asarray(temperature),
-            jnp.asarray(top_k), jnp.asarray(top_p), jnp.asarray(keys))
-        return (np.asarray(s), np.asarray(e), np.asarray(last),
-                np.asarray(nkeys))
+        with _DECODE_PUT:
+            args = (jnp.asarray(toks_k), jnp.asarray(dlen),
+                    jnp.asarray(pos), jnp.asarray(active),
+                    jnp.asarray(temperature), jnp.asarray(top_k),
+                    jnp.asarray(top_p), jnp.asarray(keys))
+        with _DECODE_DISPATCH:
+            s, e, last, nkeys, self._kc, self._vc = self._verify_fn(
+                self.model.params_, self._kc, self._vc, *args)
+            del args  # see decode
+        with _DECODE_FETCH:
+            return (np.asarray(s), np.asarray(e), np.asarray(last),
+                    np.asarray(nkeys))
 
     def draft(self, tokens, pos, active):
         """Truncated-layer draft proposals: (S, K-1) greedy tokens from
         the first ``draft_layers`` blocks, one dispatch for all slots."""
-        drafts, self._dkc, self._dvc = self._draft_fn(
-            self.model.params_, self._dkc, self._dvc,
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(active))
-        return np.asarray(drafts)
+        with _DECODE_PUT:
+            args = (jnp.asarray(tokens), jnp.asarray(pos),
+                    jnp.asarray(active))
+        with _DECODE_DISPATCH:
+            drafts, self._dkc, self._dvc = self._draft_fn(
+                self.model.params_, self._dkc, self._dvc, *args)
+            del args  # see decode
+        with _DECODE_FETCH:
+            return np.asarray(drafts)
 
     # -- shared-prefix cache hooks ------------------------------------------
     def prefix_capture(self, slot: int, tb: int, logits0) -> dict:
@@ -859,15 +898,17 @@ class _RecurrentBackend:
     def prefill(self, slot, prompt, temperature, top_k, top_p, key):
         tp = int(prompt.shape[0])
         tb = self.bucket_for(tp)
-        ids = np.zeros((tb,), np.int32)
-        ids[:tp] = prompt
+        with _PREFILL_PUT:
+            ids = np.zeros((tb,), np.int32)
+            ids[:tp] = prompt
+            args = (jnp.asarray(ids), jnp.asarray(tp, jnp.int32),
+                    jnp.asarray(int(slot), jnp.int32),
+                    jnp.asarray(temperature, jnp.float32),
+                    jnp.asarray(int(top_k), jnp.int32),
+                    jnp.asarray(top_p, jnp.float32), jnp.asarray(key))
         tok0, key, self._carries, logits0 = self._prefill_fn(
-            self.model.params_, self.model.state_, self._carries,
-            jnp.asarray(ids), jnp.asarray(tp, jnp.int32),
-            jnp.asarray(int(slot), jnp.int32),
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(int(top_k), jnp.int32),
-            jnp.asarray(top_p, jnp.float32), jnp.asarray(key))
+            self.model.params_, self.model.state_, self._carries, *args)
+        del args  # see _TransformerBackend.decode
         return int(tok0), np.asarray(key), tb, logits0
 
     # -- shared-prefix cache hooks ------------------------------------------
@@ -895,12 +936,17 @@ class _RecurrentBackend:
         return int(tok0), np.asarray(key)
 
     def decode(self, tokens, pos, active, temperature, top_k, top_p, keys):
-        nxt, nkeys, self._carries = self._decode_fn(
-            self.model.params_, self.model.state_, self._carries,
-            jnp.asarray(tokens), jnp.asarray(active),
-            jnp.asarray(temperature), jnp.asarray(top_k),
-            jnp.asarray(top_p), jnp.asarray(keys))
-        return np.asarray(nxt), np.asarray(nkeys)
+        with _DECODE_PUT:
+            args = (jnp.asarray(tokens), jnp.asarray(active),
+                    jnp.asarray(temperature), jnp.asarray(top_k),
+                    jnp.asarray(top_p), jnp.asarray(keys))
+        with _DECODE_DISPATCH:
+            nxt, nkeys, self._carries = self._decode_fn(
+                self.model.params_, self.model.state_, self._carries,
+                *args)
+            del args  # see _TransformerBackend.decode
+        with _DECODE_FETCH:
+            return np.asarray(nxt), np.asarray(nkeys)
 
     def window_check(self, prompt_len: int, max_new: int) -> None:
         from deeplearning4j_tpu.models.transformer_lm import (
@@ -1345,8 +1391,6 @@ class GenerationEngine:
         return [i for i in range(self.n_slots) if self._slots[i] is None]
 
     def _admit(self, block_s: float) -> None:
-        from deeplearning4j_tpu.obs import flight as _flight
-
         for slot in self._free_slots():
             try:
                 req = (self._queue.get(timeout=block_s) if block_s > 0
@@ -1361,9 +1405,23 @@ class GenerationEngine:
                 req.fail(RequestDeadlineExceeded(
                     "request deadline passed while queued"))
                 continue
-            t0 = time.monotonic()
-            if req.trace is not None:
-                req.trace.mark("slot_claimed", t0)
+            with _ADMIT:
+                self._claim(slot, req)
+
+    def _claim(self, slot: int, req: GenerationRequest) -> None:
+        """Give ``slot`` to ``req``: first token (prefill, or a
+        prefix-cache restore) under ``gen.prefill``, then the slot's
+        bookkeeping."""
+        from deeplearning4j_tpu.obs import flight as _flight
+
+        t0 = time.monotonic()
+        if req.trace is not None:
+            req.trace.mark("slot_claimed", t0)
+        waited = t0 - req.enqueued_at
+        self.metrics.record_queue_wait(waited)
+        waited_ns = int(waited * 1e9)
+        _QUEUE_WAIT.record(time.time_ns() - waited_ns, waited_ns)
+        with _PREFILL:
             key0 = np.asarray(jax.random.PRNGKey(req.seed),
                               np.uint32).reshape(2)
             hit = False
@@ -1406,49 +1464,49 @@ class GenerationEngine:
                 except BaseException as e:  # keep the worker alive
                     self.metrics.record_error()
                     req.fail(e)
-                    continue
+                    return
                 if pk is not None:
                     self._prefix_cache.put(
                         pk,
                         self.backend.prefix_capture(slot, bucket,
                                                     logits0))
             dt = time.monotonic() - t0
-            if not hit:
-                self.metrics.record_prefill(dt)
-            self.metrics.record_first_token()
-            _flight.record("slot_claim", slot=slot,
-                           prompt_len=int(req.prompt.size),
-                           prompt_bucket=int(bucket),
-                           max_new=req.max_new, prefix_hit=hit)
-            self._slot_pk[slot] = pk
-            self._replay[slot] = None
-            if hit:
-                comp = entry.get("completion")
-                if comp:
-                    self._replay[slot] = list(comp)
-            self._slots[slot] = req
-            req.slot = slot
-            self._active[slot] = True
-            self._tokens[slot] = tok0
-            self._pos[slot] = req.prompt.size
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
-            self._topp[slot] = req.top_p
-            self._keys[slot] = key
-            if self._draft is not None:
-                # teach the n-gram table the prompt + first token; seed
-                # this slot's draft context with the last two tokens
-                self._draft.learn_seq(req.prompt.tolist() + [int(tok0)])
-            self._ctx[slot, 0] = int(req.prompt[-1])
-            self._ctx[slot, 1] = int(tok0)
-            if req.trace is not None:
-                req.trace.mark("prefill_done")
-                req.trace.note(slot=slot, prompt_len=int(req.prompt.size),
-                               prompt_bucket=int(bucket), prefix_hit=hit)
-            req.push_token(tok0)
-            self._replay_advance(slot, int(tok0), 1)
-            if len(req.tokens) >= req.max_new:
-                self._finish_slot(slot, reason="done")
+        if not hit:
+            self.metrics.record_prefill(dt)
+        self.metrics.record_first_token()
+        _flight.record("slot_claim", slot=slot,
+                       prompt_len=int(req.prompt.size),
+                       prompt_bucket=int(bucket),
+                       max_new=req.max_new, prefix_hit=hit)
+        self._slot_pk[slot] = pk
+        self._replay[slot] = None
+        if hit:
+            comp = entry.get("completion")
+            if comp:
+                self._replay[slot] = list(comp)
+        self._slots[slot] = req
+        req.slot = slot
+        self._active[slot] = True
+        self._tokens[slot] = tok0
+        self._pos[slot] = req.prompt.size
+        self._temp[slot] = req.temperature
+        self._topk[slot] = req.top_k
+        self._topp[slot] = req.top_p
+        self._keys[slot] = key
+        if self._draft is not None:
+            # teach the n-gram table the prompt + first token; seed
+            # this slot's draft context with the last two tokens
+            self._draft.learn_seq(req.prompt.tolist() + [int(tok0)])
+        self._ctx[slot, 0] = int(req.prompt[-1])
+        self._ctx[slot, 1] = int(tok0)
+        if req.trace is not None:
+            req.trace.mark("prefill_done")
+            req.trace.note(slot=slot, prompt_len=int(req.prompt.size),
+                           prompt_bucket=int(bucket), prefix_hit=hit)
+        req.push_token(tok0)
+        self._replay_advance(slot, int(tok0), 1)
+        if len(req.tokens) >= req.max_new:
+            self._finish_slot(slot, reason="done")
 
     def _replay_advance(self, slot: int, tok: int, n: int) -> None:
         """Invalidate the slot's completion replay at the first emitted
@@ -1680,60 +1738,61 @@ class GenerationEngine:
                                           error=err)
                 self.backend.reset()
                 return
-        self._step_ewma_s = (dt if self._step_ewma_s is None
-                             else 0.8 * self._step_ewma_s + 0.2 * dt)
-        if use_spec:
-            emitted = int(e_all.sum())
-            self.metrics.record_decode_step(dt, emitted)
-            self.metrics.record_draft(int(dlen[self._active].sum()),
-                                      emitted - n_active)
-        else:
-            self.metrics.record_decode_step(dt, n_active)
-        if dt * 1e3 > self.stall_ms:
-            _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
-                           active=n_active)
-        # copy: np.asarray on a device array is a read-only view, and
-        # the admit path writes per-slot lanes into these
-        if use_spec:
-            self._tokens = np.array(last, np.int32)
-            self._keys = np.array(keys, np.uint32)
-            # accepted counts are data: each slot advances by its own e
-            # (masked to 0 on inactive rows)
-            self._pos += e_all.astype(np.int32)
-        else:
-            self._tokens = np.array(toks, np.int32)
-            self._keys = np.array(keys, np.uint32)
-            self._pos[self._active] += 1
-        now = time.monotonic()
-        for slot in range(self.n_slots):
-            if not self._active[slot]:
-                continue
-            req = self._slots[slot]
+        with _EMIT:
+            self._step_ewma_s = (dt if self._step_ewma_s is None
+                                 else 0.8 * self._step_ewma_s + 0.2 * dt)
             if use_spec:
-                m = int(e_all[slot])
-                req.draft_proposed += int(dlen[slot])
-                req.draft_accepted += m - 1
-                for j in range(m):
-                    tok = int(s_all[slot, j])
+                emitted = int(e_all.sum())
+                self.metrics.record_decode_step(dt, emitted)
+                self.metrics.record_draft(int(dlen[self._active].sum()),
+                                          emitted - n_active)
+            else:
+                self.metrics.record_decode_step(dt, n_active)
+            if dt * 1e3 > self.stall_ms:
+                _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
+                               active=n_active)
+            # copy: np.asarray on a device array is a read-only view, and
+            # the admit path writes per-slot lanes into these
+            if use_spec:
+                self._tokens = np.array(last, np.int32)
+                self._keys = np.array(keys, np.uint32)
+                # accepted counts are data: each slot advances by its own e
+                # (masked to 0 on inactive rows)
+                self._pos += e_all.astype(np.int32)
+            else:
+                self._tokens = np.array(toks, np.int32)
+                self._keys = np.array(keys, np.uint32)
+                self._pos[self._active] += 1
+            now = time.monotonic()
+            for slot in range(self.n_slots):
+                if not self._active[slot]:
+                    continue
+                req = self._slots[slot]
+                if use_spec:
+                    m = int(e_all[slot])
+                    req.draft_proposed += int(dlen[slot])
+                    req.draft_accepted += m - 1
+                    for j in range(m):
+                        tok = int(s_all[slot, j])
+                        self._learn(slot, tok)
+                        req.push_token(tok)
+                        self._replay_advance(slot, tok, len(req.tokens))
+                else:
+                    tok = int(toks[slot])
                     self._learn(slot, tok)
                     req.push_token(tok)
                     self._replay_advance(slot, tok, len(req.tokens))
-            else:
-                tok = int(toks[slot])
-                self._learn(slot, tok)
-                req.push_token(tok)
-                self._replay_advance(slot, tok, len(req.tokens))
-            if len(req.tokens) >= req.max_new:
-                self._finish_slot(slot, reason="done")
-            elif req.expired(now) or req.done():
-                # done() → the caller gave up (result timeout); either
-                # way the slot frees at token granularity (deadline
-                # expiry mid-verify frees it just like mid-decode — the
-                # already-accepted tokens were pushed above)
-                self._finish_slot(
-                    slot, reason="deadline",
-                    error=RequestDeadlineExceeded(
-                        "request deadline passed mid-decode"))
+                if len(req.tokens) >= req.max_new:
+                    self._finish_slot(slot, reason="done")
+                elif req.expired(now) or req.done():
+                    # done() → the caller gave up (result timeout); either
+                    # way the slot frees at token granularity (deadline
+                    # expiry mid-verify frees it just like mid-decode — the
+                    # already-accepted tokens were pushed above)
+                    self._finish_slot(
+                        slot, reason="deadline",
+                        error=RequestDeadlineExceeded(
+                            "request deadline passed mid-decode"))
 
     def _learn(self, slot: int, tok: int) -> None:
         """Advance the slot's 2-token draft context and teach the n-gram
@@ -1756,7 +1815,8 @@ class GenerationEngine:
                     return
                 # idle: wait for work without holding the device lock
                 try:
-                    req = self._queue.get(timeout=0.05)
+                    with _IDLE_WAIT:
+                        req = self._queue.get(timeout=0.05)
                 except queue.Empty:
                     continue
                 # put it back and admit under the lock (single admission

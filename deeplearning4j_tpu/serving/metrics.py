@@ -266,6 +266,10 @@ class GenerationMetrics:
             "generation_request_seconds",
             "end-to-end request latency (ring-buffer window)",
             ring_size=ring_size)
+        self._queue_wait = reg.histogram(
+            "generation_queue_wait_seconds",
+            "enqueue to slot claim, per claimed request (ring-buffer "
+            "window)", ring_size=ring_size)
         self._slots = reg.gauge(
             "generation_slots", "decode slots in the engine slab")
         self._active = reg.gauge(
@@ -305,7 +309,6 @@ class GenerationMetrics:
         #: lookups before the hit-rate gauge materializes (and the
         #: prefix_hit_rate_low rule can fire)
         self.prefix_gauge_floor = 8
-        self.started_at = time.time()
 
     # -- recording ----------------------------------------------------------
     def set_slots(self, n: int) -> None:
@@ -341,6 +344,9 @@ class GenerationMetrics:
 
     def record_finish(self, latency_seconds: float) -> None:
         self._latency.observe(float(latency_seconds))
+
+    def record_queue_wait(self, seconds: float) -> None:
+        self._queue_wait.observe(float(seconds))
 
     def record_draft(self, proposed: int, accepted: int) -> None:
         if proposed:
@@ -432,6 +438,10 @@ class GenerationMetrics:
             out[f"latency_{name}_ms"] = (
                 None if n == 0
                 else round(window[min(int(q * n), n - 1)] * 1e3, 3))
+        for name, q in (("p50", 0.50), ("p95", 0.95)):
+            wait = self._queue_wait.quantile(q)
+            out[f"queue_wait_{name}_ms"] = (
+                None if wait is None else round(wait * 1e3, 3))
         return out
 
 

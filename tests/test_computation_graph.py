@@ -526,3 +526,54 @@ class TestGraphSummary:
         s = cg.summary()
         assert "NetworkInput" in s and "MergeVertex" in s
         assert f"Total parameters: {cg.num_params():,}" in s
+
+
+def test_fit_phases_cover_the_step():
+    """ComputationGraph.fit on a DataSet: train.iterate / put_batch /
+    dispatch (/ fetch_loss with a listener), at most four a step, nested or
+    disjoint, covering the wall time of fit; phases on, nothing retraces."""
+    import time
+
+    from deeplearning4j_tpu.obs import trace as obs_trace
+    from deeplearning4j_tpu.train.listeners import ScoreIterationListener
+    from tests.phase_checks import assert_nested_or_disjoint, covered_ns
+
+    # wide enough that a step outweighs the interpreter's own time between
+    # two phases (some tens of microseconds), as on the chip
+    conf = (
+        NeuralNetConfiguration.builder().seed(5).updater("sgd")
+        .graph_builder().add_inputs("in")
+        .add_layer("d0", DenseLayer(n_out=512, activation="tanh"), "in")
+        .add_layer("out", OutputLayer(n_out=3, activation="softmax",
+                                      loss="mcxent"), "d0")
+        .set_outputs("out").set_input_types(InputType.feed_forward(256))
+        .build()
+    )
+    net = ComputationGraph(conf).init()
+    rng = np.random.default_rng(3)
+    ds = DataSet(rng.standard_normal((8192, 256)).astype(np.float32),
+                 np.eye(3, dtype=np.float32)[rng.integers(0, 3, 8192)])
+    net.fit(ds, epochs=1, batch_size=2048)  # compiles
+    with obs_trace.RetraceMonitor() as mon:
+        mark = time.time_ns()
+        net.fit(ds, epochs=1, batch_size=2048)
+        done = time.time_ns()
+        got = [e for e in obs_trace.phases(mark) if e[0].startswith("train.")]
+        # no listener: the program fetches nothing, so no fetch_loss
+        assert [e[0] for e in got] == ["train.iterate", "train.put_batch",
+                                       "train.dispatch"] * 4
+        net.listeners.append(ScoreIterationListener(1))
+        mark = time.time_ns()
+        net.fit(ds, epochs=2, batch_size=2048)
+        done = time.time_ns()
+    assert mon.total() == 0, mon.delta()
+    got = [e for e in obs_trace.phases(mark) if e[0].startswith("train.")]
+    assert [e[0] for e in got] == ["train.iterate", "train.put_batch",
+                                   "train.dispatch", "train.fetch_loss"] * 8
+    assert_nested_or_disjoint(got)
+    # from the first batch's iterate to the last step's end: fit's own
+    # prologue and epilogue (epoch hooks, iterator reset, the prefetch
+    # thread's start and join) are no part of a step
+    lo, hi = got[0][1], max(a + d for _, a, d in got)
+    assert mark <= lo and hi <= done
+    assert covered_ns(got, lo, hi) >= 0.95 * (hi - lo)

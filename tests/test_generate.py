@@ -408,6 +408,122 @@ class TestGenerationEngine:
 
 
 # ---------------------------------------------------------------------------
+# host phases of the worker loop, queue wait, named scopes
+# ---------------------------------------------------------------------------
+class TestEnginePhases:
+    """obs/trace.py phases inside GenerationEngine._loop: they nest or
+    follow one another, cover a loop iteration, stay within the budget a
+    decode iteration is given, and cost no retrace."""
+
+    @pytest.fixture(scope="class")
+    def storm(self):
+        from deeplearning4j_tpu.obs import trace as obs_trace
+
+        # large enough that a decode step outweighs the loop's own
+        # bookkeeping, as on the chip
+        lm = TransformerLM(vocab_size=256, d_model=256, n_heads=4,
+                           n_layers=6, max_length=64, seed=3).init()
+        eng = GenerationEngine(lm, n_slots=2, queue_limit=16,
+                               default_timeout_s=120.0)
+        eng.warmup()
+        traced = dict(eng.trace_counts)
+        mark = time.time_ns()
+        rng = np.random.default_rng(1)
+        reqs = [eng.submit(rng.integers(0, 256, (12,)).astype(np.int32),
+                           max_new=20, timeout=120, trace=True)
+                for _ in range(4)]
+        for r in reqs:
+            r.result(timeout=120)
+        time.sleep(0.05)
+        ring = [e for e in obs_trace.phases(mark) if e[0].startswith("gen.")]
+        yield eng, reqs, ring, traced
+        eng.shutdown(drain=False)
+
+    def test_named_phases_nest_and_cover_the_loop(self, storm):
+        from tests.phase_checks import assert_nested_or_disjoint, covered_ns
+
+        _eng, _reqs, ring, _ = storm
+        loop = [e for e in ring if e[0] not in ("gen.queue_wait",
+                                                "gen.idle_wait")]
+        assert {e[0] for e in loop} == {
+            "gen.admit", "gen.prefill", "gen.prefill.put", "gen.decode.put",
+            "gen.decode.dispatch", "gen.decode.fetch", "gen.emit"}
+        assert_nested_or_disjoint(loop)
+        # the busy stretch: first claim to the last token handed out
+        lo = min(a for n, a, _ in loop if n == "gen.admit")
+        hi = max(a + d for n, a, d in loop if n == "gen.emit")
+        assert covered_ns(loop, lo, hi) >= 0.95 * (hi - lo)
+
+    def test_phase_budget_of_a_decode_iteration(self, storm):
+        _eng, _reqs, ring, _ = storm
+        loop = sorted((e for e in ring if e[0] not in ("gen.queue_wait",
+                                                       "gen.idle_wait")),
+                      key=lambda e: e[1])
+        # an iteration runs from one claim-or-put to the emit that ends it
+        groups, cur = [], []
+        for e in loop:
+            cur.append(e[0])
+            if e[0] == "gen.emit":
+                groups.append(cur)
+                cur = []
+        assert len(groups) >= 20
+        for names in groups:
+            claims = names.count("gen.admit")
+            assert len(names) <= 4 + 3 * claims, names
+            if claims <= 1:
+                assert len(names) <= 8, names
+            assert names[-4:] == ["gen.decode.put", "gen.decode.dispatch",
+                                  "gen.decode.fetch", "gen.emit"]
+
+    def test_queue_wait_is_the_rtrace_queue_stage(self, storm):
+        eng, reqs, ring, _ = storm
+        waits_ms = sorted(r.trace.timeline()["stages"][0]["ms"] for r in reqs)
+        assert [r.trace.timeline()["stages"][0]["stage"]
+                for r in reqs] == ["queue"] * 4
+        # two slots, four requests at once: two waited for a slot to free
+        assert waits_ms[-1] > 5 * waits_ms[0]
+        snap = eng.metrics.snapshot()
+        # 0.95 of four observations is the fourth
+        assert snap["queue_wait_p95_ms"] == pytest.approx(waits_ms[-1],
+                                                          abs=2e-3)
+        assert snap["queue_wait_p50_ms"] == pytest.approx(waits_ms[2],
+                                                          abs=2e-3)
+        observed = sorted(d * 1e-6 for n, _, d in ring
+                          if n == "gen.queue_wait")
+        assert observed == pytest.approx(waits_ms, abs=2e-3)
+        assert "generation_queue_wait_seconds" in \
+            eng.metrics.registry.prometheus_text()
+
+    def test_phases_cost_no_retrace(self, storm):
+        eng, _reqs, _ring, traced = storm
+        assert eng.trace_counts == traced
+
+    def test_scope_names_in_the_engine_programs(self, storm):
+        import jax.numpy as jnp
+
+        eng = storm[0]
+        b = eng.backend
+        S = eng.n_slots
+        small = (jnp.zeros((S,), jnp.float32), jnp.zeros((S,), jnp.int32),
+                 jnp.zeros((S,), jnp.float32))
+        decode = b._decode_fn.lower(
+            b.model.params_, b._kc, b._vc, jnp.zeros((S,), jnp.int32),
+            jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool), *small,
+            jnp.zeros((S, 2), jnp.uint32)).as_text(debug_info=True)
+        prefill = b._prefill_fn.lower(
+            b.model.params_, b._kc, b._vc, b._dkc, b._dvc,
+            jnp.zeros((1, 16), jnp.int32), jnp.asarray(5, jnp.int32),
+            jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
+            jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
+            jnp.zeros((2,), jnp.uint32)).as_text(debug_info=True)
+        from tests.phase_checks import scopes_in
+
+        want = {"embed", "attn", "kv_write", "mlp", "head", "sample"}
+        assert want <= scopes_in(decode)
+        assert want <= scopes_in(prefill)
+
+
+# ---------------------------------------------------------------------------
 # speculative decoding + shared-prefix KV reuse
 # ---------------------------------------------------------------------------
 _SPEC = {}

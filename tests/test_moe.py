@@ -606,3 +606,55 @@ class TestKVCacheDecoding:
         m, ids = self._trained()
         with pytest.raises(ValueError, match="max_length"):
             m.generate_cached(ids[:1, :10], max_new=10)
+
+
+class TestLMPhasesAndScopes:
+    """obs/trace.py phases inside TransformerLM.fit_batch and the
+    jax.named_scope names of the LM step (models/transformer_lm.SCOPES)."""
+
+    def _lm(self):
+        from deeplearning4j_tpu.models.transformer_lm import TransformerLM
+
+        m = TransformerLM(vocab_size=128, d_model=64, n_heads=4, n_layers=2,
+                          max_length=64, seed=2).init()
+        rng = np.random.default_rng(0)
+        ids = rng.integers(0, 128, (8, 64)).astype(np.int32)
+        tgt = np.roll(ids, -1, 1).astype(np.int32)
+        tgt[:, -1] = -1
+        return m, ids, tgt
+
+    def test_fit_batch_phases_cover_the_step(self):
+        import time
+
+        from deeplearning4j_tpu.obs import trace as obs_trace
+        from tests.phase_checks import assert_nested_or_disjoint, covered_ns
+
+        m, ids, tgt = self._lm()
+        m.fit_batch(ids, tgt)  # compiles
+        with obs_trace.RetraceMonitor() as mon:
+            traced = dict(m.trace_counts)
+            mark = time.time_ns()
+            for _ in range(5):
+                m.fit_batch(ids, tgt)
+            done = time.time_ns()
+        assert mon.total() == 0 and m.trace_counts == traced
+        got = [e for e in obs_trace.phases(mark) if e[0].startswith("train.")]
+        assert [e[0] for e in got] == ["train.put_batch", "train.dispatch",
+                                       "train.fetch_loss"] * 5
+        assert_nested_or_disjoint(got)
+        assert covered_ns(got, mark, done) >= 0.95 * (done - mark)
+
+    def test_every_training_scope_is_in_the_lowered_step(self):
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.models import transformer_lm as tlm
+        from tests.phase_checks import scopes_in
+
+        m, ids, tgt = self._lm()
+        text = m._make_step().lower(
+            m.params_, m.opt_state_, jnp.asarray(ids), jnp.asarray(tgt),
+            jnp.asarray(1, jnp.int32)).as_text(debug_info=True)
+        found = scopes_in(text)
+        assert {"embed", "attn", "mlp", "head", "loss", "update"} <= found
+        # the serving programs carry the other two (tests/test_generate.py)
+        assert set(tlm.SCOPES) - found == {"kv_write", "sample"}

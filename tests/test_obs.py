@@ -417,6 +417,122 @@ class TestRetraceMonitor:
 # ---------------------------------------------------------------------------
 # exporter + serving surfaces
 # ---------------------------------------------------------------------------
+class TestPhases:
+    """obs/trace.py's phase primitive: one call site, the profiler's
+    annotation and the ring + registry counters."""
+
+    def test_nests_and_records_in_order(self):
+        from tests.phase_checks import assert_nested_or_disjoint
+
+        outer, inner = obs_trace.phase("t.outer"), obs_trace.phase("t.inner")
+        assert obs_trace.phase("t.outer") is outer  # one object a name
+        mark = time.time_ns()
+        with outer:
+            with inner:
+                time.sleep(0.002)
+            with inner:
+                pass
+        got = [e for e in obs_trace.phases(mark) if e[0].startswith("t.")]
+        # appended when a phase ends: the two inner ones, then the outer
+        assert [e[0] for e in got] == ["t.inner", "t.inner", "t.outer"]
+        (_, a0, d0), (_, a1, d1), (_, a2, d2) = got
+        assert a2 <= a0 and a0 + d0 <= a1 and a1 + d1 <= a2 + d2
+        assert d0 >= 2_000_000 and all(isinstance(x, int) for x in (a0, d0))
+        assert_nested_or_disjoint(got)
+        # start_ns is on time.time_ns(), the profiler's wall clock
+        assert mark <= a2 <= time.time_ns()
+
+    def test_ring_is_bounded(self):
+        p = obs_trace.phase("t.flood")
+        for _ in range(obs_trace.RING_SIZE + 10):
+            p.record(1, 1)
+        ring = obs_trace.phases()
+        assert len(ring) == obs_trace.RING_SIZE
+        assert all(e[0] == "t.flood" for e in ring)
+
+    def test_observe_lands_in_the_same_ring_and_registry(self):
+        from deeplearning4j_tpu.obs.metrics import default_registry
+
+        reg = default_registry()
+        before = reg.family_values(obs_trace.PHASE_COUNTER).get(
+            "phase=t.waited", 0.0)
+        start = time.time_ns() - 5_000_000
+        obs_trace.observe("t.waited", start, 5_000_000)
+        assert obs_trace.phases()[-1] == ("t.waited", start, 5_000_000)
+        assert reg.family_values(obs_trace.PHASE_COUNTER)[
+            "phase=t.waited"] == before + 1
+        assert reg.family_values(obs_trace.PHASE_SECONDS)[
+            "phase=t.waited"] >= 0.005
+        assert 'host_phase_total{phase="t.waited"}' in reg.prometheus_text()
+
+    def test_each_next_times_items_not_exhaustion(self):
+        p = obs_trace.phase("t.iterate")
+        mark = time.time_ns()
+        assert list(obs_trace.each_next(p, iter([1, 2, 3]))) == [1, 2, 3]
+        got = [e for e in obs_trace.phases(mark) if e[0] == "t.iterate"]
+        assert len(got) == 3
+        assert not p._open.stack  # the exhausted next() was cancelled
+
+    def test_threads_do_not_share_open_phases(self):
+        import threading
+
+        p = obs_trace.phase("t.threads")
+        mark = time.time_ns()
+
+        def work():
+            for _ in range(200):
+                with p:
+                    with p:
+                        pass
+
+        threads = [threading.Thread(target=work) for _ in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        got = [e for e in obs_trace.phases(mark) if e[0] == "t.threads"]
+        assert len(got) == 4 * 400 and all(d >= 0 for _, _, d in got)
+
+    def test_fit_phases_cover_the_step_and_never_retrace(self):
+        """MultiLayerNetwork.fit: iterate / put_batch / dispatch /
+        fetch_loss, at most four a step, nested or disjoint, covering
+        the wall time of fit; phases on, the step compiles once."""
+        from tests.phase_checks import assert_nested_or_disjoint, covered_ns
+
+        from deeplearning4j_tpu.train.listeners import ScoreIterationListener
+
+        # wide enough that a step outweighs the interpreter's own time
+        # between two phases (some tens of microseconds), as on the chip
+        conf = (NeuralNetConfiguration.builder().seed(7).updater(Adam(1e-3))
+                .list()
+                .layer(DenseLayer(n_out=512, activation="relu"))
+                .layer(OutputLayer(n_out=3, activation="softmax",
+                                   loss="mcxent"))
+                .set_input_type(InputType.feed_forward(256)).build())
+        net = MultiLayerNetwork(conf).init()
+        net.listeners.append(ScoreIterationListener(1))
+        batches = _batches(6, b=2048, d=256)
+        it = ExistingDataSetIterator(batches)
+        with obs_trace.RetraceMonitor() as mon:
+            net.fit(it, epochs=1)  # compiles
+            mon.rebaseline()
+            mark = time.time_ns()
+            net.fit(it, epochs=1)
+            done = time.time_ns()
+        assert mon.total() == 0, mon.delta()
+        got = [e for e in obs_trace.phases(mark) if e[0].startswith("train.")]
+        names = [e[0] for e in got]
+        assert names == ["train.iterate", "train.put_batch", "train.dispatch",
+                         "train.fetch_loss"] * 6
+        assert_nested_or_disjoint(got)
+        # from the first batch's iterate to the last step's end: fit's own
+        # prologue and epilogue (epoch hooks, iterator reset, the prefetch
+        # thread's start and join) are no part of a step
+        lo, hi = got[0][1], max(a + d for _, a, d in got)
+        assert mark <= lo and hi <= done
+        assert covered_ns(got, lo, hi) >= 0.95 * (hi - lo)
+
+
 class TestExporter:
     def test_negotiation_rule(self):
         assert wants_prometheus("text/plain;version=0.0.4")

@@ -88,6 +88,9 @@ def test_compile_cache_is_placed_from_outside_or_in_the_checkout():
     assert placed  # conftest.py or the environment did
     assert runtime.enable_compile_cache() == placed
     assert jax.config.jax_compilation_cache_dir == placed
+    # an entry is found by the computation alone, so that two checkouts
+    # of one program share executables (runtime.py says what that costs)
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         jax.config.update("jax_compilation_cache_dir", None)
         path = runtime.enable_compile_cache()
