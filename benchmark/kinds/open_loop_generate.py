@@ -9,12 +9,18 @@ the generator ran is reported beside them.
 The arrivals start ``lead_in_s`` before the window opens, so that the
 window opens on slots as full as a steady stream keeps them; the lead-in
 is set-up and counts in ``setup_s``. Everything measured is measured
-inside the window, whichever request it belongs to.
+inside the window.
 
-End to end: ``itl_p95_ms`` and ``serve_tokens_per_s``. Time to first token
-is printed in the detail line only: with some tens of requests in a window
-its tail is two or three requests and no bound of at most 10 % holds it
-(PERF.md section 2).
+End to end: ``itl_p95_ms`` (gaps of every request) and
+``serve_due_tokens_per_s`` (tokens of the requests DUE inside the window).
+The count over every request stays in the detail line with its two edge
+terms: below the knee it is the offered load plus what the lead-in's
+requests still owe at the opening less what is still owed at the close,
+and both terms shrink as the engine gets faster, the first one faster, so
+a faster engine read 2-3 % slower on it (PERF.md section 2). Time to first
+token is printed in the detail line only: with some tens of requests in a
+window its tail is two or three requests and no bound of at most 10 % holds
+it.
 
 After the window has closed and the requests in flight have drained, the
 server is freed and the plain reference runs once over a seeded sample of
@@ -49,11 +55,16 @@ def percentile(values, q):
 
 
 def measure(results, t0, seconds):
-    """End-to-end numbers of one window from the client's records: tokens
-    delivered and gaps closed inside the window, of every request; time
-    to first token of the requests due inside it."""
+    """End-to-end numbers of one window from the client's records: gaps
+    closed inside the window, of every request; tokens delivered inside it
+    to the requests due inside it, and their time to first token. The
+    detail keeps the count over every request and its edges:
+    ``tokens_in_window`` = every token of the requests due in the window
+    + ``tokens_owed_at_open`` (delivered from the opening on to requests
+    due before it) - ``tokens_owed_at_close`` (delivered after the close)."""
     close = t0 + seconds
-    ttft, gaps, delivered, late, failed = [], [], 0, [], 0
+    ttft, gaps, delivered, delivered_due, late, failed = [], [], 0, 0, [], 0
+    owed_at_open = owed_at_close = 0
     for r in results:
         due = t0 + r["due"]
         ok = r["done"] and not r["error"]
@@ -64,9 +75,16 @@ def measure(results, t0, seconds):
         if r["sent"] is not None:
             late.append(r["sent"] - due)
         times = r["token_times"]
-        delivered += sum(1 for t in times if t0 <= t <= close)
+        inside = sum(1 for t in times if t0 <= t <= close)
+        after = sum(1 for t in times if t > close)
+        delivered += inside
+        owed_at_close += after
+        if r["due"] >= 0:
+            delivered_due += inside
+        else:
+            owed_at_open += inside + after
         gaps.extend(b - a for a, b in zip(times, times[1:]) if t0 <= b <= close)
-    out = {"serve_tokens_per_s": delivered / seconds}
+    out = {"serve_due_tokens_per_s": delivered_due / seconds}
     if gaps:
         out["itl_p95_ms"] = 1e3 * percentile(gaps, 95)
     in_flight = [sum(1 for r in results if r["token_times"] and r["token_times"][0] <= t
@@ -75,7 +93,9 @@ def measure(results, t0, seconds):
               "ttft_p95_ms": 1e3 * percentile(ttft, 95) if ttft else None,
               "requests": len(results), "requests_due_in_window": len(ttft),
               "streaming_at_open": in_flight[0], "streaming_at_close": in_flight[1],
-              "tokens_in_window": delivered, "gaps": len(gaps),
+              "tokens_in_window": delivered, "tokens_due_in_window": delivered_due,
+              "tokens_owed_at_open": owed_at_open, "tokens_owed_at_close": owed_at_close,
+              "gaps": len(gaps),
               "itl_p50_ms": 1e3 * percentile(gaps, 50) if gaps else None,
               "gen_late_p95_ms": 1e3 * percentile(late, 95) if late else None}
     return out, detail, failed

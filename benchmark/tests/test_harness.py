@@ -57,7 +57,10 @@ def test_a_window_counts_what_happens_inside_it():
         result(9.0, [], done=False),
     ]
     out, detail, failed = kind.measure(results, t0, 10.0)
-    assert detail["tokens_in_window"] == 5 and out["serve_tokens_per_s"] == 0.5
+    # every request's tokens: 5; those of the requests due inside the window: 2
+    assert detail["tokens_in_window"] == 5 and out["serve_due_tokens_per_s"] == 0.2
+    # 5 = the 3 tokens of the requests due inside + 3 owed at the opening - 1 owed at the close
+    assert detail["tokens_owed_at_open"] == 3 and detail["tokens_owed_at_close"] == 1
     # gaps closed inside the window: 99.5->100.5, 100.5->101, 101->101.5, 102.25->103
     assert detail["gaps"] == 4 and out["itl_p95_ms"] == pytest.approx(1000.0)
     assert detail["requests_due_in_window"] == 2
