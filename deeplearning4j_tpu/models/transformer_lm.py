@@ -367,15 +367,21 @@ def sample_next_rows(logits, temperature, top_k, top_p, keys):
 def init_decode_cache(cfg: TransformerLMConfig, batch: int,
                       max_length: Optional[int] = None) -> Dict:
     """Preallocated per-layer KV cache for single-token decoding: static
-    (L, b, heads, max_length, head_dim) buffers + a position counter —
+    (L, b, heads, head_dim, max_length) buffers + a position counter —
     TPU-friendly (no growing shapes; writes are dynamic_update slices).
+    Time is the MINOR axis for K and V alike: it is the layout both
+    decode einsums (``bhd,bhdt->bht``, ``bht,bhdt->bhd``) read without a
+    conversion, and head_dim 64 as the minor axis would pad every bf16
+    tile to 128 lanes. Every reader and writer of the cache (prefill,
+    decode, speculative verify, the serving engine's slab and its prefix
+    cache) uses this one layout.
     ``max_length`` overrides the slab's time extent (the continuous-
     batching engine sizes its slots independently of the model's full
     window); default is ``cfg.max_length``."""
     cd = _cdtype(cfg) or jnp.float32
     hd = cfg.d_model // cfg.n_heads
     T = cfg.max_length if max_length is None else int(max_length)
-    shape = (cfg.n_layers, batch, cfg.n_heads, T, hd)
+    shape = (cfg.n_layers, batch, cfg.n_heads, hd, T)
     return {"k": jnp.zeros(shape, cd), "v": jnp.zeros(shape, cd),
             "pos": jnp.zeros((), jnp.int32)}
 
@@ -385,8 +391,10 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
     """Batched prompt prefill: ids (b, Tp) int32 into a fresh cache →
     (last-position logits (b, V) fp32, cache with pos=Tp). One device
     launch regardless of prompt length (causal attention within the
-    prompt, K/V written as one slice per layer); MoE routing competes all
-    b*Tp prompt tokens, exactly like ``forward``.
+    prompt; each layer hands its K/V out transposed to the cache's
+    (b, hn, hd, Tp) and all layers' Tp columns are written after the
+    loop as one slice); MoE routing competes all b*Tp prompt tokens,
+    exactly like ``forward``.
 
     ``length`` (traced scalar int32, <= Tp) marks the REAL prompt length
     when ids is right-padded up to a bucketed Tp: logits are gathered at
@@ -394,7 +402,8 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
     attention makes end-padding exact for dense models — position i
     attends only to <= i, so pad positions can never influence real
     ones; their K/V is written but masked from every future decode read
-    (decode masks to <= pos) and overwritten as decoding advances. The
+    (decode reads the cache below pos) and overwritten as decoding
+    advances. The
     one exception is MoE (cfg.n_experts > 0), where pad tokens compete
     for expert capacity — callers keep MoE prefill unbucketed (see
     ``TransformerLM.generate_cached``)."""
@@ -407,8 +416,9 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
         if cd is not None:
             x = x.astype(cd)
 
-    def body(x, xs):
-        bp, kc, vc = xs
+    kvd = cache["k"].dtype
+
+    def body(x, bp):
         if cd is not None:
             bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
                   for k2, v in bp.items()}
@@ -420,10 +430,8 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
 
             q, k, v = heads(bp["Wq"]), heads(bp["Wk"]), heads(bp["Wv"])
         with _scope("kv_write"):
-            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
-                                              (0, 0, 0, 0))
-            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                              (0, 0, 0, 0))
+            kt = k.astype(kvd).transpose(0, 1, 3, 2)  # (b, hn, hd, Tp)
+            vt = v.astype(kvd).transpose(0, 1, 3, 2)
         with _scope("attn"):
             o = dense_attention(q, k, v, causal=True, mask=None)
             o = o.transpose(0, 2, 1, 3).reshape(b, Tp, d).astype(x.dtype)
@@ -442,10 +450,13 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
             else:
                 h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
                 x = x + h @ bp["W2"] + bp["b2"]
-        return x, (kc, vc)
+        return x, (kt, vt)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
+    x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
+    with _scope("kv_write"):
+        origin = (0, 0, 0, 0, 0)
+        new_k = jax.lax.dynamic_update_slice(cache["k"], ks, origin)
+        new_v = jax.lax.dynamic_update_slice(cache["v"], vs, origin)
     if length is None:
         x_last = x[:, -1]
         pos_out = jnp.asarray(Tp, jnp.int32)
@@ -460,21 +471,65 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
     return logits, {"k": new_k, "v": new_v, "pos": pos_out}
 
 
+def _joint_softmax(s_slab, s_new, dtype):
+    """One softmax over the cache's scores (..., T) and the step's own
+    (..., K), which are never concatenated: the same max and the same
+    sum for both parts, probabilities cast to ``dtype`` (the cache's).
+    The step's own scores always hold a live entry, so a row that reads
+    nothing of the cache (pos 0) is finite."""
+    m = jnp.maximum(s_slab.max(axis=-1), s_new.max(axis=-1))[..., None]
+    e_slab, e_new = jnp.exp(s_slab - m), jnp.exp(s_new - m)
+    z = (e_slab.sum(axis=-1) + e_new.sum(axis=-1))[..., None]
+    return (e_slab / z).astype(dtype), (e_new / z).astype(dtype)
+
+
+def _put_columns(slab, new, wp):
+    """The after-loop cache write: new (L, b, hn, K, hd), row s's column
+    j → slab[:, s, :, :, wp[s, j]], each as ONE ``dynamic_update_slice``
+    on the (donated) slab — in place, in the slab's own layout. A
+    scatter here picks its layout for the whole slab and brings two
+    slab-sized copies a step back; so does a column that first reads its
+    old value (the (L, 1, hn, hd, 1) read; along the slot axis it also
+    gathers a slot-sharded slab), and a K-wide update clamps its start
+    and shifts the block (PERF.md section 5).
+
+    ``wp`` is clamped to T-1 by the caller, and a row's columns go
+    last-first: every column past the end lands on T-1 BEFORE the
+    column that belongs there (there is one whenever the row's first
+    position is <= T-1), so columns past the end are dropped, never
+    left clipped over a real write."""
+    for s in range(new.shape[1]):
+        for j in reversed(range(new.shape[3])):
+            slab = jax.lax.dynamic_update_slice(
+                slab, new[:, s:s + 1, :, j, :, None], (0, s, 0, 0, wp[s, j]))
+    return slab
+
+
 def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
                 cache: Dict, ids_1: Array):
     """One autoregressive step: ids_1 (b,) int32 at position cache["pos"]
     → (logits (b, V) fp32, new cache). Attention reads the cached K/V
-    (masked to positions ≤ pos) instead of re-running the prefix — O(T)
-    decoding vs the O(T²) full-forward loop; greedy-parity tested against
-    ``forward`` in tests/test_moe.py.
+    instead of re-running the prefix — O(T) decoding vs the O(T²)
+    full-forward loop; greedy-parity tested against ``forward`` in
+    tests/test_moe.py.
+
+    The cache is READ-ONLY inside the layer loop: each layer scores the
+    cached positions < pos and the step's own key (one more logit) under
+    one softmax, adds ``p_new * v_new`` to the cached part's output, and
+    hands its new (b, hn, hd) key and value out of the scan. After the
+    loop the (L, b, hn, hd) stacks are written into the cache in place
+    (``_put_columns``), at ``min(pos, T-1)``. Writing inside the loop
+    made XLA convert each layer's slice to the write's layout and back
+    and copy the whole stacked cache once more, every step.
 
     ``cache["pos"]`` may be a scalar (every row at the same position —
-    the single-request path) or a per-row (b,) vector (the continuous-
-    batching engine: each slot carries its own position; K/V writes
-    become a per-row scatter and the attention mask is per-row). The
-    attention math is row-independent either way, so a row decoded among
-    other slots is bit-identical to the same row decoded alone
-    (parity-asserted in tests/test_generate.py).
+    the single-request path: one column written for all rows) or a
+    per-row (b,) vector (the continuous-batching engine: each slot
+    carries its own position; the mask is per-row and each row's column
+    is written at its own position). The attention math is
+    row-independent either way, so a row decoded among other slots is
+    bit-identical to the same row decoded alone (parity-asserted in
+    tests/test_generate.py).
 
     MoE note: decode routes only the b current-step tokens (per-step
     capacity), while the full forward competes all window tokens; when
@@ -484,9 +539,13 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
     cd = _cdtype(cfg)
     pos = cache["pos"]
     per_row = getattr(pos, "ndim", 0) == 1
-    T = cache["k"].shape[3]
+    T = cache["k"].shape[4]
+    kvd = cache["k"].dtype
     with _scope("embed"):
-        ptab = jnp.take(params["pos"], pos, axis=0)  # clip-mode gather
+        # clip, not jnp.take's default NaN fill: a row past the table
+        # (a draft chained over the window's end) writes the clamped
+        # column T-1, which later steps read at probability 0
+        ptab = jnp.take(params["pos"], pos, axis=0, mode="clip")
         x = params["embed"][ids_1] + (ptab if per_row else ptab[None, :])
         if cd is not None:
             x = x.astype(cd)
@@ -495,13 +554,13 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
     d = cfg.d_model
     scale = 1.0 / math.sqrt(d // hn)
     if per_row:
-        valid = jnp.arange(T)[None, :] <= pos[:, None]  # (b, T)
-        wp = jnp.minimum(pos, T - 1)  # clamped per-row write index
+        live = (jnp.arange(T)[None, :] < pos[:, None])[:, None, :]
     else:
-        valid = (jnp.arange(T) <= pos)  # (T,)
+        live = (jnp.arange(T) < pos)[None, None, :]
+    wp = jnp.minimum(pos, T - 1)  # clamped write index
 
     def body(x, xs):
-        bp, kc, vc = xs  # kc/vc: (b, hn, T, hd)
+        bp, kc, vc = xs  # kc/vc: (b, hn, hd, T), never written here
         if cd is not None:
             bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
                   for k2, v in bp.items()}
@@ -513,24 +572,17 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
 
             q, k, v = (head_proj(bp["Wq"]), head_proj(bp["Wk"]),
                        head_proj(bp["Wv"]))
-        with _scope("kv_write"):
-            if per_row:
-                rows = jnp.arange(b)
-                kc = kc.at[rows, :, wp].set(k.astype(kc.dtype))
-                vc = vc.at[rows, :, wp].set(v.astype(vc.dtype))
-            else:
-                kc = jax.lax.dynamic_update_index_in_dim(
-                    kc, k.astype(kc.dtype), pos, 2)
-                vc = jax.lax.dynamic_update_index_in_dim(
-                    vc, v.astype(vc.dtype), pos, 2)
-        with _scope("attn"):
-            scores = jnp.einsum("bhd,bhtd->bht", q,
+            kn, vn = k.astype(kvd), v.astype(kvd)
+            s_slab = jnp.einsum("bhd,bhdt->bht", q,
                                 kc).astype(jnp.float32) * scale
-            scores = jnp.where(valid[:, None, :] if per_row
-                               else valid[None, None, :], scores, -1e30)
-            p = jax.nn.softmax(scores, axis=-1).astype(kc.dtype)
-            o = jnp.einsum("bht,bhtd->bhd", p,
-                           vc).reshape(b, d).astype(x.dtype)
+            s_slab = jnp.where(live, s_slab, -1e30)
+            s_new = jnp.einsum("bhd,bhd->bh", q,
+                               kn).astype(jnp.float32)[..., None] * scale
+            p_slab, p_new = _joint_softmax(s_slab, s_new, kvd)
+            o = jnp.einsum("bht,bhdt->bhd", p_slab, vc,
+                           preferred_element_type=jnp.float32)
+            o = o + p_new.astype(jnp.float32) * vn.astype(jnp.float32)
+            o = o.reshape(b, d).astype(x.dtype)
             x = x + o @ bp["Wo"] + bp["bo"]
         with _scope("mlp"):
             m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
@@ -545,10 +597,18 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
             else:
                 h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
                 x = x + h @ bp["W2"] + bp["b2"]
-        return x, (kc, vc)
+        return x, (kn, vn)
 
-    x, (new_k, new_v) = jax.lax.scan(
+    x, (ks, vs) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
+    with _scope("kv_write"):  # ks/vs: (L, b, hn, hd)
+        if per_row:  # one in-place column a slot
+            new_k = _put_columns(cache["k"], ks[:, :, :, None], wp[:, None])
+            new_v = _put_columns(cache["v"], vs[:, :, :, None], wp[:, None])
+        else:  # one column for all rows
+            at = (0, 0, 0, 0, wp)
+            new_k = jax.lax.dynamic_update_slice(cache["k"], ks[..., None], at)
+            new_v = jax.lax.dynamic_update_slice(cache["v"], vs[..., None], at)
     with _scope("head"):
         x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
         head = params["head"].astype(cd) if cd is not None else params["head"]
@@ -567,20 +627,21 @@ def decode_steps(cfg: TransformerLMConfig, params: Dict[str, Array],
     the distribution token-by-token decode would have produced, which is
     what makes speculative acceptance exact.
 
-    K/V for all K columns is written (scatter at pos..pos+K-1) BEFORE
-    attention, so column j attends to columns 0..j of the current block
-    plus the prior context (mask t <= pos+j). Writes use ``mode="drop"``:
-    a column whose absolute position falls past the slab (pos+j >= T)
-    is dropped rather than clipped — clipping would land every
-    out-of-range column on T-1 and corrupt the real write when a row's
-    final token sits exactly at the slab edge. Callers must therefore
-    never ACCEPT a column at pos+j > T-1 (its logits are garbage); the
-    engine clamps draft lengths to the window.
+    As in ``decode_step`` the cache is read-only inside the layer loop:
+    column j attends to the cached positions < pos and, causally, to
+    columns 0..j of its own block, under one softmax. All K columns are
+    written after the loop (``_put_columns``), and a column whose
+    absolute position falls past the cache (pos+j >= T) is DROPPED,
+    never left clipped over the real write of a row whose final token
+    sits exactly at the edge. Callers must never ACCEPT a column at
+    pos+j > T-1 (its position embedding is clipped); the engine clamps
+    draft lengths to the window. A row at pos >= T is outside the
+    contract, as in ``decode_step``: its clamped write stays on T-1.
 
-    Rejected-draft "rollback" is free: stale K/V past the accepted
-    position is masked from every later read (t <= pos') and each later
-    dispatch rewrites its columns contiguously from pos' before reading
-    them, so garbage is always overwritten before it becomes visible.
+    Rejected-draft "rollback" is free: stale K/V at and past the
+    accepted position is never read (reads stop below pos') and each
+    later dispatch rewrites its columns contiguously from pos', so
+    garbage is always overwritten before it becomes visible.
 
     MoE is unsupported (routing would compete b*K tokens per step where
     sequential decode competes b — acceptance would no longer be exact);
@@ -591,57 +652,63 @@ def decode_steps(cfg: TransformerLMConfig, params: Dict[str, Array],
                          "sequential decode); use decode_step")
     cd = _cdtype(cfg)
     pos = cache["pos"]
-    T = cache["k"].shape[3]
+    T = cache["k"].shape[4]
+    kvd = cache["k"].dtype
     b, K = ids_k.shape
     hn = cfg.n_heads
     d = cfg.d_model
     scale = 1.0 / math.sqrt(d // hn)
     cols = pos[:, None] + jnp.arange(K)[None, :]  # (b, K) absolute pos
     with _scope("embed"):
-        ptab = jnp.take(params["pos"], cols, axis=0)  # clip-mode gather
+        # clip, not jnp.take's default NaN fill: a column past the table
+        # is dropped from the cache but still sits in its block's
+        # softmax at probability 0, and 0 * NaN would reach its row
+        ptab = jnp.take(params["pos"], cols, axis=0, mode="clip")
         x = params["embed"][ids_k] + ptab
         if cd is not None:
             x = x.astype(cd)
-    valid = jnp.arange(T)[None, None, :] <= cols[:, :, None]  # (b, K, T)
-    rows = jnp.arange(b)
+    live = (jnp.arange(T)[None, :] < pos[:, None])[:, None, None, :]
+    causal = jnp.arange(K)[None, :] <= jnp.arange(K)[:, None]  # (K, K)
 
     def body(x, xs):
-        bp, kc, vc = xs  # kc/vc: (b, hn, T, hd)
+        bp, kc, vc = xs  # kc/vc: (b, hn, hd, T), never written here
         if cd is not None:
             bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
                   for k2, v in bp.items()}
         with _scope("attn"):
             a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
 
-            def head_proj(W):
-                return (a_in @ W).reshape(b, K, hn, -1)  # (b, K, hn, hd)
+            def head_proj(W):  # (b, hn, K, hd)
+                return (a_in @ W).reshape(b, K, hn, -1).transpose(0, 2, 1, 3)
 
-            k, v = head_proj(bp["Wk"]), head_proj(bp["Wv"])
-            q = head_proj(bp["Wq"]).transpose(0, 2, 1, 3)  # (b, hn, K, hd)
-        with _scope("kv_write"):
-            # advanced indices at axes 0 and 2 around the ':' slice →
-            # result dims (b, K) lead, so the (b, K, hn, hd) values
-            # scatter directly
-            kc = kc.at[rows[:, None], :, cols].set(k.astype(kc.dtype),
-                                                   mode="drop")
-            vc = vc.at[rows[:, None], :, cols].set(v.astype(vc.dtype),
-                                                   mode="drop")
-        with _scope("attn"):
-            scores = jnp.einsum("bhkd,bhtd->bhkt", q,
+            q, k, v = (head_proj(bp["Wq"]), head_proj(bp["Wk"]),
+                       head_proj(bp["Wv"]))
+            kn, vn = k.astype(kvd), v.astype(kvd)
+            s_slab = jnp.einsum("bhkd,bhdt->bhkt", q,
                                 kc).astype(jnp.float32) * scale
-            scores = jnp.where(valid[:, None, :, :], scores, -1e30)
-            p = jax.nn.softmax(scores, axis=-1).astype(kc.dtype)
-            o = jnp.einsum("bhkt,bhtd->bhkd", p, vc)
+            s_slab = jnp.where(live, s_slab, -1e30)
+            s_new = jnp.einsum("bhkd,bhjd->bhkj", q,
+                               kn).astype(jnp.float32) * scale
+            s_new = jnp.where(causal, s_new, -1e30)
+            p_slab, p_new = _joint_softmax(s_slab, s_new, kvd)
+            o = jnp.einsum("bhkt,bhdt->bhkd", p_slab, vc,
+                           preferred_element_type=jnp.float32)
+            o = o + jnp.einsum("bhkj,bhjd->bhkd", p_new, vn,
+                               preferred_element_type=jnp.float32)
             o = o.transpose(0, 2, 1, 3).reshape(b, K, d).astype(x.dtype)
             x = x + o @ bp["Wo"] + bp["bo"]
         with _scope("mlp"):
             m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
             h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
             x = x + h @ bp["W2"] + bp["b2"]
-        return x, (kc, vc)
+        return x, (kn, vn)
 
-    x, (new_k, new_v) = jax.lax.scan(
+    x, (ks, vs) = jax.lax.scan(
         body, x, (params["blocks"], cache["k"], cache["v"]))
+    with _scope("kv_write"):  # ks/vs: (L, b, hn, K, hd)
+        wp = jnp.minimum(cols, T - 1)
+        new_k = _put_columns(cache["k"], ks, wp)
+        new_v = _put_columns(cache["v"], vs, wp)
     with _scope("head"):
         x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
         head = params["head"].astype(cd) if cd is not None else params["head"]

@@ -8,7 +8,7 @@ Orca/vLLM scheduling discipline — keeps ONE fixed-shape decode program
 in flight and lets requests join and leave it **between token steps**:
 
 - the engine owns a persistent **slot slab**: for TransformerLM an
-  ``(n_layers, n_slots, heads, max_length, head_dim)`` KV cache pair
+  ``(n_layers, n_slots, heads, head_dim, max_length)`` KV cache pair
   (``init_decode_cache``); for recurrent nets (TextGenerationLSTM) the
   per-layer carried (h, c) state stacked to ``(n_slots, units)``;
 - a request claims a free slot, **prefills** its prompt at a bucketed
@@ -386,8 +386,11 @@ class PrefixCache:
 # decode backends
 # --------------------------------------------------------------------------
 class _TransformerBackend:
-    """TransformerLM decode backend: fixed (L, S, hn, T, hd) KV slab,
-    per-slot positions, per-bucket prefill programs."""
+    """TransformerLM decode backend: fixed (L, S, hn, hd, T) KV slab
+    (time minor: ``init_decode_cache``), per-slot positions, per-bucket
+    prefill programs. Decode and verify read the slab and write their
+    new columns in place after the layer loop; prefill and the prefix
+    cache move whole blocks of columns with one slice a slab."""
 
     kind = "transformer"
 
@@ -445,7 +448,6 @@ class _TransformerBackend:
             nkeys = jnp.where(active[:, None], nkeys, keys)
             return nxt, nkeys, c["k"], c["v"]
 
-        T = self.max_length
         Ld = self.draft_layers
 
         def _slice_draft(p):
@@ -454,7 +456,9 @@ class _TransformerBackend:
 
         def _prefill(p, kc, vc, dkc, dvc, ids, ln, slot, t, k, pp, key):
             trace_hook("generation_prefill")
-            tmp = init_decode_cache(cfg, 1, max_length=T)
+            # only the bucket's columns are written: what a slot holds
+            # past them is never read before decode overwrites it
+            tmp = init_decode_cache(cfg, 1, max_length=ids.shape[1])
             logits, tmp = prefill_cache(cfg, p, tmp, ids, length=ln)
             with jax.named_scope("kv_write"):
                 kc = jax.lax.dynamic_update_slice(kc, tmp["k"],
@@ -652,23 +656,24 @@ class _TransformerBackend:
     # -- shared-prefix cache hooks ------------------------------------------
     def prefix_capture(self, slot: int, tb: int, logits0) -> dict:
         """Slice the slot's first ``tb`` KV columns (and the truncated
-        draft slab's, when speculating through it) out of the slab into
-        a self-contained cache entry. The slab is donated to every
-        decode dispatch, so the entry must be a COPY, not a view."""
+        draft slab's, when speculating through it) out of the
+        (L, S, hn, hd, T) slab into a self-contained cache entry of
+        (L, 1, hn, hd, tb) blocks. The slab is donated to every decode
+        dispatch, so the entry must be a COPY, not a view."""
         fn = self._cap_fns.get(tb)
         if fn is None:
-            L, _S, hn, _T, hd = self._kc.shape
+            L, _S, hn, hd, _T = self._kc.shape
             Ld = self.draft_layers
 
             def _cap(kc, vc, dkc, dvc, slot):
                 sl = (0, slot, 0, 0, 0)
-                out = (jax.lax.dynamic_slice(kc, sl, (L, 1, hn, tb, hd)),
-                       jax.lax.dynamic_slice(vc, sl, (L, 1, hn, tb, hd)))
+                out = (jax.lax.dynamic_slice(kc, sl, (L, 1, hn, hd, tb)),
+                       jax.lax.dynamic_slice(vc, sl, (L, 1, hn, hd, tb)))
                 if Ld:
                     out += (jax.lax.dynamic_slice(dkc, sl,
-                                                  (Ld, 1, hn, tb, hd)),
+                                                  (Ld, 1, hn, hd, tb)),
                             jax.lax.dynamic_slice(dvc, sl,
-                                                  (Ld, 1, hn, tb, hd)))
+                                                  (Ld, 1, hn, hd, tb)))
                 return out
 
             fn = self._cap_fns[tb] = jax.jit(_cap)

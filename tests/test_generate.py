@@ -571,6 +571,48 @@ class TestSpeculativePrefix:
                 out, m.generate_cached(p, max_new=n)[0])
             np.testing.assert_array_equal(out, m.generate(p, max_new=n)[0])
 
+    @pytest.mark.parametrize("which", ["plain", "ngram_drafts",
+                                       "truncated_drafts"])
+    def test_last_token_on_the_slab_edge_after_a_longer_occupant(self,
+                                                                 which):
+        # prompt + max_new == max_length: the last token lands on the
+        # slab's column T-1 and, speculating, the last verify blocks
+        # reach past it (those columns are dropped, the real write
+        # stays; the truncated draft model chains steps over the edge).
+        # Every slot was first filled to its end by another request, and
+        # prefill writes only the new prompt's bucket of columns: what
+        # the earlier occupant left behind it must never be read.
+        m = _lm()
+        if which == "truncated_drafts":
+            eng = GenerationEngine(m, n_slots=3, queue_limit=32,
+                                   default_timeout_s=120.0,
+                                   spec_decode_k=4, draft_mode="truncated")
+            eng.warmup()
+        else:
+            eng = _engine() if which == "plain" else _spec_engine()
+        T = eng.max_length
+        try:
+            assert eng.backend._kc.shape[-1] == T  # time is the minor axis
+            fill = _prompts(3, (30, 40), seed=31)
+            for r in [eng.submit(p, max_new=T - p.size, timeout=90)
+                      for p in fill]:
+                assert r.result(timeout=90).size == T
+            before = dict(eng.trace_counts)
+            short = _prompts(3, (3, 9), seed=32)
+            outs = [r.result(timeout=90) for r in
+                    [eng.submit(p, max_new=T - p.size, timeout=90)
+                     for p in short]]
+            assert eng.trace_counts == before
+            for p, out in zip(short, outs):
+                assert out.size == T
+                np.testing.assert_array_equal(
+                    out, m.generate_cached(p, max_new=T - p.size)[0])
+                np.testing.assert_array_equal(
+                    out, m.generate(p, max_new=T - p.size)[0])
+        finally:
+            if which == "truncated_drafts":
+                eng.shutdown()
+
     def test_sampled_key_chain_parity_with_rejection(self):
         # sampled path: rejected drafts must not desync the per-slot
         # PRNG chain — the key advances once per EMITTED token, so a

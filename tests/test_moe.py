@@ -607,6 +607,104 @@ class TestKVCacheDecoding:
         with pytest.raises(ValueError, match="max_length"):
             m.generate_cached(ids[:1, :10], max_new=10)
 
+    _BY_DTYPE = {}
+
+    def _trained_once(self, dtype):
+        if dtype not in self._BY_DTYPE:
+            kw = {} if dtype == "float32" else {"compute_dtype": dtype}
+            self._BY_DTYPE[dtype] = self._trained(**kw)
+        return self._BY_DTYPE[dtype]
+
+    @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
+                                            ("bfloat16", 0.15)])
+    @pytest.mark.parametrize("at", [0, 5, 15], ids=["first", "middle",
+                                                    "last_column"])
+    def test_decode_step_scalar_and_per_row_pos_match_forward(
+            self, at, dtype, atol):
+        # the (L, b, hn, hd, T) cache is read below pos and the step's
+        # own key joins the softmax; its column is written after the
+        # layer loop — one column for all rows (scalar pos) or one a
+        # row (per-row pos). Both must give forward's next-token logits
+        # and the same cache, also with nothing cached (pos 0) and with
+        # the token landing on the cache's last column (pos T-1).
+        from deeplearning4j_tpu.models.transformer_lm import (
+            decode_step,
+            forward,
+            init_decode_cache,
+            prefill_cache,
+        )
+
+        m, ids = self._trained_once(dtype)
+        cfg, p, b = m.cfg, m.params_, 3
+        ids = jnp.asarray(ids[:b])
+        T = cfg.max_length
+        want = forward(cfg, p, ids[:, :at + 1])[:, -1]
+        cache = init_decode_cache(cfg, b)
+        assert cache["k"].shape == (cfg.n_layers, b, cfg.n_heads,
+                                    cfg.d_model // cfg.n_heads, T)
+        if at:
+            _, cache = prefill_cache(cfg, p, cache, ids[:, :at])
+        got_s, new_s = decode_step(cfg, p, cache, ids[:, at])
+        rows = {**cache, "pos": jnp.full((b,), at, jnp.int32)}
+        got_r, new_r = decode_step(cfg, p, rows, ids[:, at])
+        np.testing.assert_allclose(got_s, want, atol=atol, rtol=0)
+        np.testing.assert_allclose(got_r, got_s, atol=1e-6, rtol=0)
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(new_s[name], np.float32),
+                np.asarray(new_r[name], np.float32))
+            # exactly one column changed: the one at pos
+            changed = np.any(np.asarray(new_s[name] != cache[name]),
+                             axis=(0, 1, 2, 3))
+            assert np.flatnonzero(changed).tolist() == [at]
+        assert int(new_s["pos"]) == at + 1
+        assert np.asarray(new_r["pos"]).tolist() == [at + 1] * b
+
+    @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
+                                            ("bfloat16", 0.15)])
+    def test_verify_block_past_the_edge_is_dropped_not_clipped(
+            self, dtype, atol):
+        # K = 4 columns from per-row positions T-6 (all inside), T-2
+        # (two inside, two past the edge) and T-1 (one inside): columns
+        # inside are written where they belong and score like forward;
+        # columns at or past T are dropped — column T-1 keeps the real
+        # write and no other column moves
+        from deeplearning4j_tpu.models.transformer_lm import (
+            decode_steps,
+            forward,
+            init_decode_cache,
+            prefill_cache,
+        )
+
+        m, ids = self._trained_once(dtype)
+        cfg, p, K = m.cfg, m.params_, 4
+        T = cfg.max_length
+        ids = jnp.asarray(ids[:3])
+        full = np.asarray(forward(cfg, p, ids))
+        _, cache = prefill_cache(cfg, p, init_decode_cache(cfg, 3), ids)
+        pos = np.array([T - 6, T - 2, T - 1], np.int32)
+        padded = np.concatenate([np.asarray(ids), np.zeros((3, K), np.int32)],
+                                axis=1)
+        block = np.stack([padded[r, q:q + K] for r, q in enumerate(pos)])
+        logits, new = decode_steps(cfg, p, {**cache, "pos": jnp.asarray(pos)},
+                                   jnp.asarray(block))
+        assert np.asarray(new["pos"]).tolist() == (pos + K).tolist()
+        for r, q in enumerate(pos):
+            inside = range(q, min(q + K, T))
+            for j, t in enumerate(inside):
+                np.testing.assert_allclose(logits[r, j], full[r, t],
+                                           atol=atol, rtol=0)
+            for name in ("k", "v"):
+                old = np.asarray(cache[name][:, r], np.float32)
+                got = np.asarray(new[name][:, r], np.float32)
+                keep = np.ones(T, bool)
+                keep[list(inside)] = False
+                np.testing.assert_array_equal(got[..., keep], old[..., keep])
+                # rewritten from the same tokens: the same values, up
+                # to the K-column program's summation order
+                np.testing.assert_allclose(got[..., ~keep], old[..., ~keep],
+                                           atol=atol, rtol=0)
+
 
 class TestLMPhasesAndScopes:
     """obs/trace.py phases inside TransformerLM.fit_batch and the
