@@ -11,6 +11,7 @@ from deeplearning4j_tpu.models.labels import (
     VOCLabels,
 )
 from deeplearning4j_tpu.models.darknet import TinyYOLO, YOLO2, Darknet19
+from deeplearning4j_tpu.models.decoder_lm import DecoderConfig, DecoderLM
 from deeplearning4j_tpu.models.facenet import FaceNetNN4Small2, InceptionResNetV1
 from deeplearning4j_tpu.models.googlenet import GoogLeNet
 from deeplearning4j_tpu.models.lenet import LeNet
@@ -27,7 +28,7 @@ __all__ = [
     "AlexNet", "Darknet19", "FaceNetNN4Small2", "GoogLeNet",
     "InceptionResNetV1", "LeNet", "ResNet50", "SimpleCNN",
     "TextGenerationLSTM", "TinyYOLO", "VGG16", "VGG19", "YOLO2",
-    "TransformerLM",
+    "TransformerLM", "DecoderLM", "DecoderConfig",
     "BaseLabels", "ClassPrediction", "ImageNetLabels", "DarknetLabels",
     "COCOLabels", "VOCLabels",
 ]

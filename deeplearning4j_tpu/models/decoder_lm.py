@@ -1,0 +1,568 @@
+"""DecoderLM: a causal decoder whose stack is DATA, served through
+``GenerationEngine``.
+
+``TransformerLM`` is one GPT-2 block scanned L times. Today's open
+decoders are not that: RMSNorm, no biases, rotary positions on part of a
+head, fewer key/value heads than query heads, query/key heads wider than
+value heads, window layers (with a learned softmax sink) among full
+layers, a leading dense layer and then expert layers of which a chip
+holds its share. Here all of that is configuration:
+
+- ``attn_kinds`` names the kinds of attention layer (key/value heads,
+  rotary base, window, sink) and ``layers`` gives each layer's attention
+  kind and FFN kind (``"dense"`` / ``"experts"``), in order;
+- consecutive layers of one (attention, FFN) pair form a SEGMENT whose
+  parameters are stacked and scanned; the stack is the list of segments;
+- ONE block function (:func:`block`) serves the full forward, prefill
+  and decode. It attends over the step's own keys and, when given one,
+  over a cache described by the absolute position each of its columns
+  holds, so a full layer's slab and a window layer's ring are the same
+  code with different position maps;
+- the cache is sized by layer kind (:meth:`DecoderConfig.cache_plan`):
+  the slot's length for a full layer, a ring of ``window`` columns for a
+  window layer, T-minor and written in place after the layer loop, as
+  ``TransformerLM``'s slab is (``_put_columns``);
+- expert layers route over every expert of the layer and compute the
+  part of the result their held experts give
+  (``nn/conf/layers/moe.moe_dropless_ffn``); the vocabulary may be the
+  chip's slice of the published one.
+
+Serving only: there is no training step for this block yet (ROADMAP M1).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, List, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu.models.transformer_lm import (
+    ContextWindowExceeded,
+    _put_columns,
+    _validate_sampling,
+    prefill_bucket_lengths,
+    sample_next_device,
+)
+from deeplearning4j_tpu.nn.conf.layers.moe import moe_dropless_ffn
+
+Array = jax.Array
+
+#: device-time scopes of this model, beside ``transformer_lm.SCOPES``
+#: (``embed``, ``kv_write``, ``head``, ``sample`` are shared): attention
+#: by layer kind, the dense FFN, and the two halves of an expert layer
+SCOPES = ("attn_full", "attn_window", "mlp", "moe_route", "moe_experts")
+_scope = jax.named_scope
+_NEG = -1e30
+
+
+class DecoderConfig:
+    """The decoder as data. ``attn_kinds``: name -> {"n_kv_heads",
+    "rope_theta", "window" (None = full), "sink" (bool)}; ``layers``:
+    one (attention kind, "dense" | "experts") pair a layer.
+    ``experts_held`` = (offset, count): which of the ``n_experts`` the
+    router scores have their weights here. ``vocab_size`` is what is
+    held here (the chip's slice, where the vocabulary is sliced)."""
+
+    def __init__(self, vocab_size: int, d_model: int, n_heads: int,
+                 head_dim: int, v_head_dim: int, rotary_dim: int,
+                 attn_kinds: Dict[str, dict],
+                 layers: Sequence[Sequence[str]], dense_width: int,
+                 expert_width: int = 0, n_experts: int = 0, top_k: int = 0,
+                 experts_held: Optional[Sequence[int]] = None,
+                 value_scale: float = 1.0, norm_eps: float = 1e-5,
+                 max_length: int = 2048, param_dtype: str = "bfloat16",
+                 seed: int = 0):
+        self.vocab_size = int(vocab_size)
+        self.d_model = int(d_model)
+        self.n_heads = int(n_heads)
+        self.head_dim = int(head_dim)
+        self.v_head_dim = int(v_head_dim)
+        self.rotary_dim = int(rotary_dim)
+        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
+            raise ValueError("rotary_dim must be even and <= head_dim")
+        self.attn_kinds = {
+            name: {"n_kv_heads": int(k["n_kv_heads"]),
+                   "rope_theta": float(k["rope_theta"]),
+                   "window": None if k.get("window") is None
+                   else int(k["window"]),
+                   "sink": bool(k.get("sink", False))}
+            for name, k in attn_kinds.items()}
+        self.layers = [(str(a), str(f)) for a, f in layers]
+        for a, f in self.layers:
+            if a not in self.attn_kinds or f not in ("dense", "experts"):
+                raise ValueError(f"unknown layer ({a!r}, {f!r})")
+            if self.n_heads % self.attn_kinds[a]["n_kv_heads"]:
+                raise ValueError("n_heads must be a multiple of n_kv_heads")
+        self.dense_width = int(dense_width)
+        self.expert_width = int(expert_width)
+        self.n_experts = int(n_experts)
+        self.top_k = int(top_k)
+        held = (0, self.n_experts) if experts_held is None else experts_held
+        self.experts_held = (int(held[0]), int(held[1]))
+        if self.experts_held[0] + self.experts_held[1] > self.n_experts:
+            raise ValueError("experts_held reaches past n_experts")
+        self.value_scale = float(value_scale)
+        self.norm_eps = float(norm_eps)
+        self.max_length = int(max_length)
+        if param_dtype not in ("float32", "bfloat16"):
+            raise ValueError("param_dtype must be 'float32' or 'bfloat16'")
+        self.param_dtype = param_dtype
+        self.seed = int(seed)
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layers)
+
+    @property
+    def dtype(self):
+        return jnp.bfloat16 if self.param_dtype == "bfloat16" else jnp.float32
+
+    def segments(self) -> List[Tuple[str, str, int]]:
+        """Runs of consecutive layers of one kind: (attention kind, FFN
+        kind, layers in the run). Each is one ``lax.scan``."""
+        out: List[List] = []
+        for a, f in self.layers:
+            if out and out[-1][0] == a and out[-1][1] == f:
+                out[-1][2] += 1
+            else:
+                out.append([a, f, 1])
+        return [tuple(s) for s in out]
+
+    def cache_columns(self, kind: str, max_length: int) -> int:
+        """Columns a layer of ``kind`` keeps a slot: a ring of ``window``
+        for a window layer, the slot's length for a full one."""
+        window = self.attn_kinds[kind]["window"]
+        return int(max_length) if window is None else min(window,
+                                                          int(max_length))
+
+    def cache_plan(self, n_slots: int, max_length: int) -> List[dict]:
+        """What the engine allocates, a segment at a time: shapes
+        (layers, slots, kv heads, head size, columns) of K and V."""
+        item = jnp.dtype(self.dtype).itemsize
+        plan = []
+        for kind, _ffn, n in self.segments():
+            hkv = self.attn_kinds[kind]["n_kv_heads"]
+            cols = self.cache_columns(kind, max_length)
+            k = (n, int(n_slots), hkv, self.head_dim, cols)
+            v = (n, int(n_slots), hkv, self.v_head_dim, cols)
+            plan.append({"kind": kind, "layers": n, "columns": cols,
+                         "ring": self.attn_kinds[kind]["window"] is not None,
+                         "k": k, "v": v,
+                         "bytes": (int(np.prod(k)) + int(np.prod(v))) * item})
+        return plan
+
+
+# -- parameters ---------------------------------------------------------------
+def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
+    """Leaf name -> (shape of ONE layer, dtype). Norm gains, sinks and
+    the router stay float32 whatever the parameter dtype. ``Wq`` is
+    stored by head, (d, heads, head size): flat, the TPU compiler
+    re-laid its 100 MB out in every layer of a decode step to split a
+    product 12,288 wide into heads of 192 (by compile, PR 27)."""
+    d, hq = cfg.d_model, cfg.n_heads
+    hkv = cfg.attn_kinds[kind]["n_kv_heads"]
+    pd, f32 = cfg.dtype, jnp.float32
+    out = {"norm1": ((d,), f32), "norm2": ((d,), f32),
+           "Wq": ((d, hq, cfg.head_dim), pd),
+           "Wk": ((d, hkv * cfg.head_dim), pd),
+           "Wv": ((d, hkv * cfg.v_head_dim), pd),
+           "Wo": ((hq * cfg.v_head_dim, d), pd)}
+    if cfg.attn_kinds[kind]["sink"]:
+        out["sink"] = ((hq,), f32)
+    if ffn == "dense":
+        out.update({"Wg": ((d, cfg.dense_width), pd),
+                    "Wu": ((d, cfg.dense_width), pd),
+                    "Wd": ((cfg.dense_width, d), pd)})
+    else:
+        held, f = cfg.experts_held[1], cfg.expert_width
+        out.update({"Wr": ((d, cfg.n_experts), f32),
+                    "br": ((cfg.n_experts,), f32),
+                    "Eg": ((held, d, f), pd), "Eu": ((held, d, f), pd),
+                    "Ed": ((held, f, d), pd)})
+    return out
+
+
+def init_params(cfg: DecoderConfig, rng: Optional[Array] = None) -> Dict:
+    """{"embed", "segments": [stacked leaves a segment], "norm_f",
+    "head"}; normal(0, 0.02) matrices, unit gains, zero sinks, a small
+    router bias."""
+    rng = rng if rng is not None else jax.random.PRNGKey(cfg.seed)
+    keys = iter(jax.random.split(rng, 16 * len(cfg.segments()) + 4))
+    pd = cfg.dtype
+
+    def normal(shape, dtype, std=0.02):
+        return (std * jax.random.normal(next(keys), shape,
+                                        jnp.float32)).astype(dtype)
+
+    segments = []
+    for kind, ffn, n in cfg.segments():
+        seg = {}
+        for name, (shape, dtype) in segment_shapes(cfg, kind, ffn).items():
+            full = (n,) + shape
+            if name.startswith("norm"):
+                seg[name] = jnp.ones(full, dtype)
+            elif name == "sink":
+                seg[name] = jnp.zeros(full, dtype)
+            else:
+                seg[name] = normal(full, dtype)
+        segments.append(seg)
+    return {"embed": normal((cfg.vocab_size, cfg.d_model), pd),
+            "segments": segments,
+            "norm_f": jnp.ones((cfg.d_model,), jnp.float32),
+            "head": normal((cfg.d_model, cfg.vocab_size), pd)}
+
+
+# -- the block ----------------------------------------------------------------
+def _rms_norm(x, g, eps):
+    """float32 statistics; returns float32 (the caller casts)."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps) * g
+
+
+def _rotate(x, pos, rotary_dim: int, theta: float):
+    """Rotary positions on the first ``rotary_dim`` of each head
+    (half-split pairing: dimension i turns with i + rotary_dim/2); the
+    rest pass through. x (b, T, h, hd), pos (b, T) absolute."""
+    half = rotary_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    ang = pos.astype(jnp.float32)[..., None] * inv          # (b, T, half)
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:rotary_dim]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, xf[..., rotary_dim:]],
+        axis=-1).astype(x.dtype)
+
+
+def _visible(q_pos, k_pos, window):
+    """(b, Tq, Tk): key position visible from query position: held
+    (>= 0), not later than the query and, in a window layer, fewer than
+    ``window`` positions back."""
+    qp, kp = q_pos[:, :, None], k_pos[:, None, :]
+    ok = (kp >= 0) & (kp <= qp)
+    if window is not None:
+        ok &= (qp - kp) < window
+    return ok
+
+
+#: the leaves of an expert layer that stay stacked through a segment's scan
+EXPERT_STACKS = ("Eg", "Eu", "Ed")
+
+
+def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
+          x: Array, q_pos: Array, cache=None, token_mask=None, layer=None):
+    """One layer on x (b, Tq, d) at absolute positions q_pos (b, Tq);
+    bp holds ONE layer's leaves. The queries attend, under one softmax,
+    to the layer's own Tq keys and, if ``cache`` = (kc (b, hkv, hd, Tc),
+    vc (b, hkv, vd, Tc), c_pos (b, Tc)) is given, to the cache columns,
+    each of which holds absolute position ``c_pos`` (< 0: nothing). The
+    cache is only READ: the layer's new (b, hkv, Tq, hd) keys and
+    (b, hkv, Tq, vd) values are returned for the caller to drop (full
+    forward), write whole (prefill) or append (decode). With ``layer``
+    the expert weights in ``bp`` are a segment's whole stacks and
+    ``layer`` the one to use (``moe_dropless_ffn``). Returns
+    (x, (k, v), (expert pairs computed here, held experts hit))."""
+    ak = cfg.attn_kinds[kind]
+    b, tq, d = x.shape
+    hq, hkv, hd, vd = cfg.n_heads, ak["n_kv_heads"], cfg.head_dim, cfg.v_head_dim
+    grp = hq // hkv
+    window = ak["window"]
+    with _scope("attn_window" if window is not None else "attn_full"):
+        a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(x.dtype)
+        q = jnp.einsum("btd,dhk->bthk", a_in, bp["Wq"])
+        k = (a_in @ bp["Wk"]).reshape(b, tq, hkv, hd)
+        v = (a_in @ bp["Wv"]).reshape(b, tq, hkv, vd)
+        q = _rotate(q, q_pos, cfg.rotary_dim, ak["rope_theta"])
+        k = _rotate(k, q_pos, cfg.rotary_dim, ak["rope_theta"])
+        # query head i reads key/value head i // grp
+        qg = q.reshape(b, tq, hkv, grp, hd).transpose(0, 2, 3, 1, 4)
+        kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        scale = 1.0 / math.sqrt(hd)
+        f32 = jnp.float32
+        s_own = jnp.einsum("bkgqd,bktd->bkgqt", qg, kh,
+                           preferred_element_type=f32) * scale
+        s_own = jnp.where(_visible(q_pos, q_pos, window)[:, None, None],
+                          s_own, _NEG)
+        m = s_own.max(-1)
+        if cache is not None:
+            kc, vc, c_pos = cache
+            s_c = jnp.einsum("bkgqd,bkdt->bkgqt", qg, kc,
+                             preferred_element_type=f32) * scale
+            s_c = jnp.where(_visible(q_pos, c_pos, window)[:, None, None],
+                            s_c, _NEG)
+            m = jnp.maximum(m, s_c.max(-1))
+        if ak["sink"]:
+            sink = bp["sink"].astype(f32).reshape(1, hkv, grp, 1)
+            m = jnp.maximum(m, sink)
+        e_own = jnp.exp(s_own - m[..., None])
+        z = e_own.sum(-1)
+        o = jnp.einsum("bkgqt,bktd->bkgqd", e_own.astype(x.dtype), vh,
+                       preferred_element_type=f32)
+        if cache is not None:
+            e_c = jnp.exp(s_c - m[..., None])
+            z = z + e_c.sum(-1)
+            o = o + jnp.einsum("bkgqt,bkdt->bkgqd", e_c.astype(x.dtype), vc,
+                               preferred_element_type=f32)
+        if ak["sink"]:
+            z = z + jnp.exp(sink - m)  # the sink takes weight, adds no value
+        o = o * (cfg.value_scale / z[..., None])
+        o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
+        x = x + o @ bp["Wo"]
+    if ffn == "dense":
+        with _scope("mlp"):
+            m_in = _rms_norm(x, bp["norm2"], cfg.norm_eps).astype(x.dtype)
+            h = jax.nn.silu(m_in @ bp["Wg"]) * (m_in @ bp["Wu"])
+            x = x + h @ bp["Wd"]
+        counts = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+    else:
+        with _scope("moe_route"):
+            r_in = _rms_norm(x, bp["norm2"], cfg.norm_eps).reshape(b * tq, d)
+        y, pairs, hit = moe_dropless_ffn(
+            r_in.astype(x.dtype), r_in, bp, cfg.top_k, cfg.experts_held,
+            None if token_mask is None else token_mask.reshape(b * tq),
+            layer)
+        x = x + y.reshape(b, tq, d).astype(x.dtype)
+        counts = (pairs.astype(jnp.int32), hit)
+    return x, (kh, vh), counts
+
+
+def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
+               caches=None, c_pos=None, token_mask=None):
+    """Every segment in order, each one ``lax.scan`` of :func:`block`
+    over its stacked layers. ``caches``: per segment (K, V) slabs
+    (layers, b, hkv, hd, Tc) to read, with ``c_pos`` the position map of
+    each attention kind. Returns (x, per segment (k, v) stacks
+    (layers, b, hkv, Tq, hd), summed expert counters)."""
+    new_kv = []
+    pairs = hit = jnp.zeros((), jnp.int32)
+    for i, (kind, ffn, n) in enumerate(cfg.segments()):
+        seg = params["segments"][i]
+        # the expert stacks are not sliced by the scan: the grouped
+        # product takes them whole and the layer's index
+        stacks = {k: seg[k] for k in EXPERT_STACKS if k in seg}
+        scanned = {k: v for k, v in seg.items() if k not in stacks}
+
+        def body(x, xs, kind=kind, ffn=ffn, stacks=stacks):
+            bp, kv, layer = xs
+            cache = None if kv is None else (kv[0], kv[1], c_pos[kind])
+            x, knew, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
+                                    q_pos, cache, token_mask,
+                                    layer if stacks else None)
+            return x, (knew, counts)
+
+        kv = None if caches is None else caches[i]
+        x, (knew, counts) = jax.lax.scan(
+            body, x, (scanned, kv, jnp.arange(n, dtype=jnp.int32)))
+        new_kv.append(knew)
+        pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
+    return x, new_kv, (pairs, hit)
+
+
+def _head(cfg: DecoderConfig, params: Dict, x: Array):
+    with _scope("head"):
+        x = _rms_norm(x, params["norm_f"], cfg.norm_eps).astype(cfg.dtype)
+        return (x @ params["head"]).astype(jnp.float32)
+
+
+def _embed(cfg: DecoderConfig, params: Dict, ids: Array):
+    with _scope("embed"):
+        return params["embed"][ids].astype(cfg.dtype)
+
+
+def forward(cfg: DecoderConfig, params: Dict, ids: Array):
+    """ids (b, T) -> float32 logits (b, T, V) over the held vocabulary."""
+    b, t = ids.shape
+    q_pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+    x, _kv, _counts = _run_stack(cfg, params, _embed(cfg, params, ids), q_pos)
+    return _head(cfg, params, x)
+
+
+# -- the cache ----------------------------------------------------------------
+def init_cache(cfg: DecoderConfig, n_slots: int, max_length: int):
+    """Zeroed (K, V) slabs a segment, by the cache plan."""
+    return [(jnp.zeros(p["k"], cfg.dtype), jnp.zeros(p["v"], cfg.dtype))
+            for p in cfg.cache_plan(n_slots, max_length)]
+
+
+def cache_positions(cfg: DecoderConfig, pos: Array, max_length: int):
+    """Per attention kind, (b, Tc): the absolute position each cache
+    column holds for a row that has ``pos`` positions behind it; -1
+    where it holds none. A full layer keeps position p in column p; a
+    ring keeps p in column p mod window, so column c holds the latest
+    position below ``pos`` that is congruent to c."""
+    out = {}
+    for kind in cfg.attn_kinds:
+        cols = cfg.cache_columns(kind, max_length)
+        c = jnp.arange(cols, dtype=jnp.int32)[None, :]
+        last = pos.astype(jnp.int32)[:, None] - 1
+        if cfg.attn_kinds[kind]["window"] is None:
+            out[kind] = jnp.where(c <= last, c, -1)
+        else:
+            out[kind] = last - jnp.mod(last - c, cols)  # < 0: not yet written
+    return out
+
+
+def _put_ring(ring, new, pos):
+    """The after-loop write of a ring: new (L, b, hkv, 1, hd), row s's
+    column -> ring[:, s, :, :, pos[s] mod window], as ONE select over the
+    whole (donated) ring. A ring is ``window`` columns whatever the
+    slot's length, so rewriting it costs a fixed pass over a few hundred
+    megabytes, where a column update a slot (``_put_columns``, what a
+    full layer's slab takes) costs an operation a slot and slab."""
+    cols = ring.shape[4]
+    at = jnp.mod(pos[:, 0], cols)[None, :, None, None, None]
+    here = jnp.arange(cols, dtype=at.dtype)[None, None, None, None, :] == at
+    return jnp.where(here, new.transpose(0, 1, 2, 4, 3), ring)
+
+
+def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
+                pos: Array, active: Optional[Array] = None):
+    """One token a row: ids_1 (b,) at per-row positions pos (b,) ->
+    (logits (b, V), caches, (expert pairs, experts hit)). The caches are
+    read inside the layer loop and written after it: a full layer's slab
+    by one in-place column a row at ``pos`` (``_put_columns``), a ring by
+    one select at ``pos mod window`` (``_put_ring``). ``active`` (b,) bool keeps idle rows
+    out of the expert layers (and of their counters)."""
+    t_max = max(p[0].shape[4] for p in caches)
+    q_pos = pos.astype(jnp.int32)[:, None]
+    x, new_kv, counts = _run_stack(
+        cfg, params, _embed(cfg, params, ids_1[:, None]), q_pos, caches,
+        cache_positions(cfg, pos, t_max),
+        None if active is None else active[:, None])
+    out = []
+    with _scope("kv_write"):
+        for (kind, _ffn, _n), (kc, vc), (k, v) in zip(cfg.segments(), caches,
+                                                      new_kv):
+            cols = kc.shape[4]
+            if cfg.attn_kinds[kind]["window"] is None:
+                wp = jnp.minimum(q_pos, cols - 1)
+                out.append((_put_columns(kc, k, wp), _put_columns(vc, v, wp)))
+            else:
+                out.append((_put_ring(kc, k, q_pos), _put_ring(vc, v, q_pos)))
+    return _head(cfg, params, x[:, 0]), out, counts
+
+
+def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
+                 length: Array, slot: Array):
+    """One prompt, right-padded to a bucket: ids (1, Tb), ``length`` real
+    tokens, into row ``slot`` of every slab, from ONE pass. A full layer
+    gets the bucket's columns at 0..Tb-1; a ring gets, in column c, the
+    latest real position congruent to c, i.e. the prompt's last
+    ``window`` columns when it is longer than the window. Padding follows
+    the real tokens, so causal attention keeps it from them, and the
+    expert layers leave it out. Returns (logits (1, V) at length-1,
+    caches)."""
+    _b, tb = ids.shape
+    q_pos = jnp.arange(tb, dtype=jnp.int32)[None]
+    real = q_pos < length
+    x, new_kv, _counts = _run_stack(cfg, params, _embed(cfg, params, ids),
+                                    q_pos, token_mask=real)
+    out = []
+    with _scope("kv_write"):
+        for (kind, _ffn, _n), (kc, vc), (k, v) in zip(cfg.segments(), caches,
+                                                      new_kv):
+            cols = kc.shape[4]
+            kt = k.transpose(0, 1, 2, 4, 3)           # (L, 1, hkv, hd, Tb)
+            vt = v.transpose(0, 1, 2, 4, 3)
+            if cfg.attn_kinds[kind]["window"] is not None and tb > cols:
+                c = jnp.arange(cols, dtype=jnp.int32)
+                src = jnp.maximum(length - 1 - jnp.mod(length - 1 - c, cols), 0)
+                kt, vt = jnp.take(kt, src, axis=4), jnp.take(vt, src, axis=4)
+            elif tb > cols:
+                raise ValueError("prefill bucket longer than the slot")
+            at = (0, slot, 0, 0, 0)
+            out.append((jax.lax.dynamic_update_slice(kc, kt, at),
+                        jax.lax.dynamic_update_slice(vc, vt, at)))
+    x_last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                          keepdims=False)
+    return _head(cfg, params, x_last), out
+
+
+# -- the model ----------------------------------------------------------------
+class DecoderLM:
+    """The serving surface ``GenerationEngine`` and ``InferenceEngine``
+    read: ``cfg``, ``params_``, ``state_``, ``output``, and a solo cached
+    generation that the engine's output is tested against."""
+
+    name = "decoderlm"
+    serving_seq_buckets = (16, 32, 64, 128, 256, 512)
+
+    def __init__(self, cfg: DecoderConfig):
+        self.cfg = cfg
+        self.params_: Optional[Dict] = None
+        self.state_ = None
+        self._jit_cache: Dict = {}
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "DecoderLM":
+        return cls(DecoderConfig(**d))
+
+    def init(self):
+        self.params_ = init_params(self.cfg)
+        return self
+
+    def num_params(self) -> int:
+        return sum(int(np.prod(p.shape))
+                   for p in jax.tree_util.tree_leaves(self.params_))
+
+    def logits(self, ids) -> np.ndarray:
+        if "fwd" not in self._jit_cache:
+            self._jit_cache["fwd"] = jax.jit(
+                lambda p, i: forward(self.cfg, p, i))
+        return np.asarray(self._jit_cache["fwd"](
+            self.params_, jnp.asarray(ids, jnp.int32)))
+
+    def output(self, x, mask=None) -> np.ndarray:
+        """Token ids (b, T) -> float32 logits (b, T, V): the generic
+        ``/predict`` surface."""
+        return self.logits(np.asarray(x).astype(np.int32))
+
+    def prefill_buckets(self, max_length: Optional[int] = None):
+        return prefill_bucket_lengths(max_length or self.cfg.max_length,
+                                      self.serving_seq_buckets)
+
+    def generate_cached(self, prompt_ids, max_new: int = 20,
+                        temperature: float = 0.0, rng=None, top_k: int = 0,
+                        top_p: float = 0.0, return_logits: bool = False):
+        """One prompt through bucketed prefill and then the cache, a
+        token a step, on a one-slot cache of ``max_length``: what a slot
+        of the engine computes, alone. ``return_logits`` also returns the
+        (max_new, V) logits each token was sampled from."""
+        ids = np.asarray(prompt_ids, np.int32).reshape(-1)
+        cfg = self.cfg
+        if ids.size + max_new > cfg.max_length:
+            raise ContextWindowExceeded(ids.size, max_new, cfg.max_length)
+        _validate_sampling(temperature, top_k, top_p)
+        if "prefill" not in self._jit_cache:
+            self._jit_cache["prefill"] = jax.jit(
+                lambda p, c, i, n: prefill_slot(cfg, p, c, i, n,
+                                                jnp.zeros((), jnp.int32)),
+                donate_argnums=(1,))
+            self._jit_cache["decode"] = jax.jit(
+                lambda p, c, tok, pos: decode_step(cfg, p, c, tok, pos)[:2],
+                donate_argnums=(1,))
+            self._jit_cache["sample"] = jax.jit(sample_next_device)
+        tb = next(t for t in self.prefill_buckets() if t >= ids.size)
+        padded = np.zeros((1, tb), np.int32)
+        padded[0, :ids.size] = ids
+        pol = (jnp.asarray(float(temperature), jnp.float32),
+               jnp.asarray(int(top_k), jnp.int32),
+               jnp.asarray(float(top_p), jnp.float32))
+        key = rng if rng is not None else jax.random.PRNGKey(0)
+        logits, cache = self._jit_cache["prefill"](
+            self.params_, init_cache(cfg, 1, cfg.max_length),
+            jnp.asarray(padded), jnp.asarray(ids.size, jnp.int32))
+        toks, all_logits = [], []
+        for step in range(max_new):
+            tok, key = self._jit_cache["sample"](logits, *pol, key)
+            toks.append(int(tok[0]))
+            all_logits.append(np.asarray(logits[0]))
+            if step + 1 < max_new:
+                logits, cache = self._jit_cache["decode"](
+                    self.params_, cache, tok,
+                    jnp.asarray([ids.size + step], jnp.int32))
+        out = np.concatenate([ids, np.asarray(toks, np.int32)])
+        return (out, np.stack(all_logits)) if return_logits else out

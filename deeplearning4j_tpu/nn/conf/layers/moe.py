@@ -10,6 +10,14 @@ parallelism = shard the leading expert dim of the FFN params over the
 mesh "expert" axis (`parallel/moe.py`); GSPMD inserts the token
 all-to-all from the shardings alone.
 
+Beside it, for serving (PR 27): ``moe_dropless_ffn``, the expert layer
+as today's open models deploy it: a sigmoid router over every expert,
+the choice by score + correction bias, no capacity and no dropped token,
+the (token, expert) pairs sorted by expert and run as grouped products,
+and the layer TOLD WHICH EXPERTS IT HOLDS, computing their share of the
+result (``models/decoder_lm.py``; manual expert parallelism in
+``parallel/moe.py``). The GShard layers below are unchanged.
+
 Load-balancing: the Switch-Transformer auxiliary loss
 ``E * Σ_e f_e · P_e`` (f_e = fraction of tokens routed to expert e,
 P_e = mean router probability) is returned through the layer-state
@@ -29,6 +37,7 @@ from deeplearning4j_tpu.nn.conf import serde
 from deeplearning4j_tpu.nn.conf.input_type import InputType
 from deeplearning4j_tpu.nn.conf.layers.attention import TransformerBlock, _layer_norm
 from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
+from deeplearning4j_tpu.nn.ops.kernel_compat import PRECISION
 
 
 def moe_capacity(n_tokens: int, capacity_factor: float, top_k: int,
@@ -139,6 +148,126 @@ def _moe_ffn(params, x2, act_fn, capacity: int, top_k: int, valid=None,
     if expert_axis is not None:
         y = jax.lax.psum(y, expert_axis)
     return y, aux, load
+
+
+def sigmoid_topk_route(z, bias, top_k: int):
+    """Sigmoid-scored routing over every expert of the layer: z (N, E)
+    float32 router outputs -> (chosen (N, k) expert ids, weights (N, k)
+    float32). The k experts are the largest of ``sigmoid(z) + bias``
+    (the correction bias enters the CHOICE only); the weights are the
+    chosen experts' plain scores, renormalised to sum to one."""
+    s = jax.nn.sigmoid(z.astype(jnp.float32))
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def _grouped_swiglu(rows, experts, sizes, chunk: int):
+    """rows (M, d), sorted by group, through each row's expert:
+    ``(silu(r Eg_e) * (r Eu_e)) Ed_e`` as three grouped products ->
+    (M, d) float32. Rows past ``sum(sizes)`` belong to no group and come
+    back unspecified.
+
+    The rows go ``chunk`` at a time, for as many chunks as the groups
+    fill: the grouped kernel multiplies a whole tile of rows for every
+    tile of weights it reads, so at M = slots x k rows of which a
+    sixteenth are held it was bound by multiplying empty rows, not by
+    reading weights (512-row tiles: 39 % of the HBM roofline on the
+    chip, PR 27)."""
+    # bfloat16 operands take one exact MXU pass; the package-wide
+    # "highest" would ask the grouped kernel for a multi-pass
+    # algorithm, which Mosaic refuses (nn/ops/kernel_compat.py)
+    kw = {"preferred_element_type": jnp.float32,
+          "precision": PRECISION if rows.dtype == jnp.bfloat16 else None}
+
+    def products(r, part):
+        g = jax.lax.ragged_dot(r, experts["Eg"], part, **kw)
+        u = jax.lax.ragged_dot(r, experts["Eu"], part, **kw)
+        h = (jax.nn.silu(g) * u).astype(r.dtype)
+        return jax.lax.ragged_dot(h, experts["Ed"], part, **kw)
+
+    m, d = rows.shape
+    if m <= chunk:
+        return products(rows, sizes)
+    pad = -m % chunk
+    if pad:
+        rows = jnp.concatenate([rows, jnp.zeros((pad, d), rows.dtype)])
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+
+    def body(c, out):
+        lo = c * chunk
+        part = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
+        r = jax.lax.dynamic_slice(rows, (lo, 0), (chunk, d))
+        return jax.lax.dynamic_update_slice(out, products(r, part), (lo, 0))
+
+    out = jax.lax.fori_loop(0, (ends[-1] + chunk - 1) // chunk, body,
+                            jnp.zeros(rows.shape, jnp.float32))
+    return out[:m]
+
+
+def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
+                     token_mask=None, layer=None, chunk: int = 128):
+    """Dropless expert FFN, this holder's part of it: x (N, d) ->
+    (y (N, d) float32, pairs computed here, held experts with a pair).
+
+    The router (``Wr`` (d, E), ``br`` (E,), float32, over ALL E experts
+    of the layer) reads ``router_in`` (N, d) float32; ``experts_held`` =
+    (offset, count) names the experts whose weights ``Eg``/``Eu``
+    (count, d, f) and ``Ed`` (count, f, d) are in ``params`` (offset may
+    be traced: ``axis_index * count`` under manual expert parallelism).
+    Of the N * k (token, expert) pairs those whose expert is held are
+    sorted by expert, their rows gathered, and the three products run as
+    grouped products over the ragged groups (``jax.lax.ragged_dot``: on
+    the TPU a group without rows reads no weights), ``chunk`` rows at a
+    time (``_grouped_swiglu``); each result is
+    scaled by its routing weight and summed into its token. No capacity,
+    no dropped token, no [S, E, C] tensor; what absent experts would add
+    is left out, so the shares of all holders sum to the whole layer.
+    ``token_mask`` (N,) bool leaves rows (padding, idle slots) out.
+
+    ``layer``: where the expert weights of SEVERAL layers are stacked
+    (``Eg`` (L, count, d, f), ...), the layer to use, which may be
+    traced (a scan's counter). The stack goes to the grouped product
+    whole, as L * count groups of which only this layer's have rows: a
+    layer sliced out of the stack inside a loop would be copied for the
+    kernel every time (3 x 268 MB a layer at MiMo's widths, a third of
+    a decode step on the chip: PERF.md, PR 27)."""
+    n, d = x.shape
+    offset, count = experts_held
+    experts = {k: params[k] for k in ("Eg", "Eu", "Ed")}
+    if layer is not None:
+        experts = {k: v.reshape((-1,) + v.shape[2:])
+                   for k, v in experts.items()}
+    with jax.named_scope("moe_route"):
+        z = jnp.matmul(router_in.astype(jnp.float32), params["Wr"],
+                       precision=jax.lax.Precision.HIGHEST)
+        chosen, w = sigmoid_topk_route(z, params["br"], top_k)
+        local = chosen - offset                      # (N, k)
+        held = (local >= 0) & (local < count)
+        if token_mask is not None:
+            held &= token_mask[:, None]
+        key = jnp.where(held, local, count).reshape(-1)     # (N k,)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :],
+                        axis=0).astype(jnp.int32)
+        n_local = jnp.sum(sizes)
+        hit = jnp.sum(sizes > 0).astype(jnp.int32)
+        if layer is not None:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((experts["Eg"].shape[0],), jnp.int32), sizes,
+                (layer * count,))
+        rows = jnp.take(x, order // top_k, axis=0)          # (N k, d)
+    with jax.named_scope("moe_experts"):
+        out = _grouped_swiglu(rows, experts, sizes, chunk)
+        # rows past the held pairs belong to no group: whatever the
+        # grouped product left there is not a number to keep
+        out = jnp.where((jnp.arange(n * top_k) < n_local)[:, None], out, 0.0)
+    with jax.named_scope("moe_route"):
+        back = jnp.argsort(order)                    # pair -> sorted row
+        out = jnp.take(out, back, axis=0).reshape(n, top_k, d)
+        y = jnp.sum(out * jnp.where(held, w, 0.0)[:, :, None], axis=1)
+    return y, n_local, hit
 
 
 class _MoEParamsMixin:

@@ -23,10 +23,27 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.nn.conf.layers.moe import (
     MixtureOfExpertsLayer,
     MoETransformerBlock,
+    moe_dropless_ffn,
 )
 from deeplearning4j_tpu.parallel.mesh import TrainingMesh
 
 _EXPERT_PARAMS = ("W1", "b1", "W2", "b2")
+
+
+def expert_parallel_dropless_ffn(x, router_in, params, top_k: int,
+                                 expert_axis: str, token_mask=None):
+    """The dropless expert layer (``moe_dropless_ffn``) under MANUAL
+    expert parallelism, for use inside a ``shard_map`` region: ``Eg`` /
+    ``Eu`` / ``Ed`` arrive with their expert dimension sliced over
+    ``expert_axis`` and the router (``Wr``, ``br``) replicated. Every
+    shard routes its tokens over ALL experts, computes the share of the
+    experts it holds (``experts_held`` = its slice), and the shares,
+    which are disjoint, are summed over the axis. Returns (y, pairs
+    computed, held experts hit), each over the whole axis."""
+    count = params["Eg"].shape[0]
+    held = (jax.lax.axis_index(expert_axis) * count, count)
+    share = moe_dropless_ffn(x, router_in, params, top_k, held, token_mask)
+    return tuple(jax.lax.psum(part, expert_axis) for part in share)
 
 
 class ExpertParallelWrapper:

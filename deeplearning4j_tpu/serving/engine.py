@@ -323,6 +323,13 @@ class InferenceEngine:
 
         return jax.jit(run)
 
+    def release(self) -> None:
+        """Let go of the served snapshot's parameters and state (after
+        shutdown): the snapshot holds them beside the model, so a caller
+        that frees the model's own keeps the device memory until the last
+        of the server's threads has dropped the engine."""
+        self._snap.params = self._snap.state = None
+
     # -- properties ---------------------------------------------------------
     @property
     def compile_count(self) -> int:

@@ -10,7 +10,9 @@ in flight and lets requests join and leave it **between token steps**:
 - the engine owns a persistent **slot slab**: for TransformerLM an
   ``(n_layers, n_slots, heads, head_dim, max_length)`` KV cache pair
   (``init_decode_cache``); for recurrent nets (TextGenerationLSTM) the
-  per-layer carried (h, c) state stacked to ``(n_slots, units)``;
+  per-layer carried (h, c) state stacked to ``(n_slots, units)``; for
+  DecoderLM the cache its plan asks for, sized by layer kind (a full
+  layer's slab of the slot's length, a window layer's ring);
 - a request claims a free slot, **prefills** its prompt at a bucketed
   length (``prefill_bucket_lengths`` — the ``serving_seq_buckets``
   discipline, so prefill compiles a bounded program set), and joins the
@@ -569,6 +571,10 @@ class _TransformerBackend:
             self._dkc = self._kc[:0]
             self._dvc = self._vc[:0]
 
+    def release(self) -> None:
+        """Let the slabs go (engine shutdown)."""
+        self._kc = self._vc = self._dkc = self._dvc = None
+
     def bucket_for(self, prompt_len: int) -> int:
         return next(t for t in self.buckets if t >= prompt_len)
 
@@ -724,6 +730,182 @@ class _TransformerBackend:
         if prompt_len + max_new > self.max_length:
             raise ContextWindowExceeded(prompt_len, max_new,
                                         self.max_length)
+
+
+class _DecoderBackend:
+    """``DecoderLM`` decode backend: the cache the model's plan asks
+    for, a segment at a time: a full layer's (L, S, hkv, hd, T) slab and
+    a window layer's (L, S, hkv, hd, window) ring, K and V of their own
+    head sizes. Decode reads them and writes one column a slot in place
+    after the layer loop; prefill writes a slot's columns of every slab
+    from one pass. Speculation stays at K = 1 (an expert model routes
+    per step), and there is no prefix cache: a ring holds a prompt's
+    last ``window`` columns only, so a captured prefix could not be
+    spliced under a longer prompt's own columns."""
+
+    kind = "decoder"
+    spec_k = 1
+    draft_layers = 0
+    supports_prefix_cache = False
+
+    def __init__(self, model, n_slots: int, max_length: Optional[int],
+                 prefill_buckets: Optional[Sequence[int]], trace_hook):
+        from deeplearning4j_tpu.models.decoder_lm import (
+            decode_step,
+            prefill_slot,
+        )
+        from deeplearning4j_tpu.models.transformer_lm import (
+            prefill_bucket_lengths,
+            sample_next_device,
+            sample_next_rows,
+        )
+
+        self.model = model
+        cfg = self._cfg = model.cfg
+        self.n_slots = int(n_slots)
+        self.max_length = (cfg.max_length if max_length is None
+                           else min(int(max_length), cfg.max_length))
+        self.buckets = prefill_bucket_lengths(
+            self.max_length,
+            prefill_buckets or getattr(model, "serving_seq_buckets", None))
+        self.cache_bytes = sum(
+            p["bytes"] for p in cfg.cache_plan(self.n_slots, self.max_length))
+        #: (expert pairs computed here, held experts hit) of the last
+        #: decode step, all layers; the engine adds them to its metrics
+        self.step_counters = (0, 0)
+        self.reset()
+
+        def _f32(bits):
+            return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+        def _decode(p, caches, state):
+            # the slots' inputs arrive as one array and leave as one, the
+            # next step's (see ``_state``)
+            trace_hook("generation_decode")
+            rows = state[:-1]
+            toks, pos, k = rows[:, 0], rows[:, 1], rows[:, 3]
+            active = rows[:, 2] != 0
+            keys = jax.lax.bitcast_convert_type(rows[:, 4:6], jnp.uint32)
+            logits, caches, counts = decode_step(cfg, p, caches, toks, pos,
+                                                 active)
+            nxt, nkeys = sample_next_rows(logits, _f32(rows[:, 6]), k,
+                                          _f32(rows[:, 7]), keys)
+            nxt = jnp.where(active, nxt, toks)
+            nkeys = jnp.where(active[:, None], nkeys, keys)
+            after = jnp.concatenate(
+                [nxt[:, None], (pos + active)[:, None], rows[:, 2:4],
+                 jax.lax.bitcast_convert_type(nkeys, jnp.int32),
+                 rows[:, 6:8]], axis=1)
+            last = jnp.zeros((1, 8), jnp.int32).at[0, :2].set(
+                jnp.stack(counts).astype(jnp.int32))
+            return caches, jnp.concatenate([after, last])
+
+        def _prefill(p, caches, state, req):
+            # req: the request's row as ``_state`` lays it (its token is
+            # still to come), then the prompt padded to its bucket
+            trace_hook("generation_prefill")
+            ln, slot, k = req[1], req[2], req[3]
+            key = jax.lax.bitcast_convert_type(req[4:6], jnp.uint32)
+            logits, caches = prefill_slot(cfg, p, caches, req[None, 8:], ln,
+                                          slot)
+            tok0, key = sample_next_device(logits, _f32(req[6]), k,
+                                           _f32(req[7]), key)
+            row = jnp.concatenate(
+                [tok0, ln[None], jnp.ones((1,), jnp.int32), k[None],
+                 jax.lax.bitcast_convert_type(key, jnp.int32), req[6:8]])
+            return caches, state.at[slot].set(row), row, logits[0]
+
+        self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
+        self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))
+
+    def reset(self) -> None:
+        from deeplearning4j_tpu.models.decoder_lm import init_cache
+
+        self._caches = init_cache(self._cfg, self.n_slots, self.max_length)
+        #: what the next step takes if the host changes nothing, on the
+        #: device and as the host's copy of it (see ``_state``)
+        self._kept = (np.zeros((self.n_slots + 1, 8), np.int32),
+                      jnp.zeros((self.n_slots + 1, 8), jnp.int32))
+        #: where ``decode`` lays the host's view of the slots each step
+        self._mine = np.zeros((self.n_slots + 1, 8), np.int32)
+
+    def release(self) -> None:
+        """Let the cache go (engine shutdown)."""
+        self._caches = self._kept = None
+
+    bucket_for = _TransformerBackend.bucket_for
+    window_check = _TransformerBackend.window_check
+
+    @staticmethod
+    def _state(rows, tokens, pos, active, temperature, top_k, top_p, keys):
+        """The slots' inputs as ONE int32 array, a row a slot: token,
+        position, active, top_k, the key's two words, and temperature and
+        top_p as their bits. The decode program returns the next step's
+        (tokens, positions advanced, keys) with the step's two expert
+        counters in a last row, and a prefill writes its slot's row, so
+        the array stays on the device and the host puts it only after it
+        changed a slot itself (a finish). A step costs one fetch and, as
+        a rule, no put; a claim one put and one fetch. Seven puts a step
+        took 5.7 ms of 54 on the chip among 42 streaming threads, and the
+        host's share of a step, most of all of the step after a prefill,
+        is where the cell's run-to-run spread comes from (PERF.md,
+        PR 27)."""
+        rows[:, 0], rows[:, 1], rows[:, 2], rows[:, 3] = (tokens, pos,
+                                                          active, top_k)
+        rows[:, 4:6] = np.asarray(keys, np.uint32).reshape(-1, 2).view(
+            np.int32)
+        rows[:, 6] = np.asarray(temperature, np.float32).reshape(-1).view(
+            np.int32)
+        rows[:, 7] = np.asarray(top_p, np.float32).reshape(-1).view(np.int32)
+        return rows
+
+    def prefill(self, slot: int, prompt: np.ndarray, temperature: float,
+                top_k: int, top_p: float, key: np.ndarray):
+        """As ``_TransformerBackend.prefill``; every prompt is bucketed:
+        the dropless expert layer has no capacity for padding to take."""
+        tp = int(prompt.shape[0])
+        tb = self.bucket_for(tp)
+        with _PREFILL_PUT:
+            req = np.zeros((8 + tb,), np.int32)
+            self._state(req[None, :8], 0, tp, 1, temperature, top_k, top_p,
+                        key)
+            req[2] = slot
+            req[8:8 + tp] = prompt
+            req = jnp.asarray(req)
+        mirror, state = self._kept
+        self._caches, state, row, logits0 = self._prefill_fn(
+            self.model.params_, self._caches, state, req)
+        del req
+        row = np.asarray(row)
+        mirror[slot] = row
+        self._kept = (mirror, state)
+        return int(row[0]), row[4:6].view(np.uint32), tb, logits0
+
+    def decode(self, tokens, pos, active, temperature, top_k, top_p, keys):
+        """As ``_TransformerBackend.decode``, through ``_state``."""
+        with _DECODE_PUT:
+            mirror, state = self._kept
+            mine = self._mine
+            self._state(mine[:-1], tokens, pos, active, temperature, top_k,
+                        top_p, keys)
+            # no new array and no loop over the whole of one before the
+            # dispatch: NumPy lets the interpreter go inside a loop over
+            # more than 500 elements (64 slots x 8 are 512), and the
+            # streaming threads that the last emit woke then all run
+            # while the device waits for this step: with ``array_equal``
+            # here the phase took 8.9 ms a step on the chip (PERF.md,
+            # PR 27). A put may read its host array after it returns:
+            # it gets a copy
+            if mine[:-1].tobytes() != mirror[:-1].tobytes():
+                state = jnp.asarray(mine.copy())
+        with _DECODE_DISPATCH:
+            self._caches, state = self._decode_fn(
+                self.model.params_, self._caches, state)
+        with _DECODE_FETCH:
+            mirror = np.array(state)  # a copy: prefill writes a row into it
+            self.step_counters = (int(mirror[-1, 0]), int(mirror[-1, 1]))
+            self._kept = (mirror, state)
+            return mirror[:-1, 0], mirror[:-1, 4:6].view(np.uint32)
 
 
 def _cell_decode_supported(model) -> bool:
@@ -897,6 +1079,10 @@ class _RecurrentBackend:
         the donated carries)."""
         self._carries = self.model._init_carries(self.n_slots)
 
+    def release(self) -> None:
+        """Let the carried state go (engine shutdown)."""
+        self._carries = None
+
     def bucket_for(self, prompt_len: int) -> int:
         return next(t for t in self.buckets if t >= prompt_len)
 
@@ -966,6 +1152,7 @@ class _RecurrentBackend:
 def _pick_backend(model, n_slots, max_length, prefill_buckets, trace_hook,
                   cell_path: Optional[bool] = None, spec_k: int = 1,
                   draft_layers: int = 0):
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
     from deeplearning4j_tpu.models.transformer_lm import TransformerLM
 
     if isinstance(model, TransformerLM):
@@ -973,6 +1160,9 @@ def _pick_backend(model, n_slots, max_length, prefill_buckets, trace_hook,
                                    prefill_buckets, trace_hook,
                                    spec_k=spec_k,
                                    draft_layers=draft_layers)
+    if isinstance(model, DecoderLM):
+        return _DecoderBackend(model, n_slots, max_length, prefill_buckets,
+                               trace_hook)
     layers = getattr(model, "layers", None)
     if layers is not None:
         from deeplearning4j_tpu.nn.conf.layers.recurrent import (
@@ -985,8 +1175,9 @@ def _pick_backend(model, n_slots, max_length, prefill_buckets, trace_hook,
                                      cell_path=cell_path)
     raise TypeError(
         f"{type(model).__name__} has no incremental-decode path: expected "
-        "a TransformerLM (KV-cache slab) or a MultiLayerNetwork with "
-        "recurrent layers (carried h/c state)")
+        "a TransformerLM (KV-cache slab), a DecoderLM (a cache sized by "
+        "layer kind) or a MultiLayerNetwork with recurrent layers "
+        "(carried h/c state)")
 
 
 # --------------------------------------------------------------------------
@@ -1001,18 +1192,26 @@ def generation_memory_report(model, n_slots: int,
     ``draft_layers`` > 0 adds the truncated-layer speculation slab (the
     draft model keeps its own KV over the first ``draft_layers``
     blocks)."""
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
     from deeplearning4j_tpu.models.transformer_lm import TransformerLM
 
-    if isinstance(model, TransformerLM):
+    plan = None
+    if isinstance(model, (TransformerLM, DecoderLM)):
         cfg = model.cfg
         T = cfg.max_length if max_length is None else min(int(max_length),
                                                           cfg.max_length)
-        hd = cfg.d_model // cfg.n_heads
-        itemsize = 2 if cfg.compute_dtype == "bfloat16" else 4
-        cache = 2 * (cfg.n_layers + int(draft_layers)) * int(n_slots) \
-            * cfg.n_heads * T * hd * itemsize
         params = sum(int(np.prod(p.shape)) * p.dtype.itemsize
                      for p in jax.tree_util.tree_leaves(model.params_))
+        if isinstance(model, DecoderLM):
+            # sized by layer kind: the slot's length for a full layer,
+            # a ring of ``window`` columns for a window layer
+            plan = cfg.cache_plan(n_slots, T)
+            cache = sum(p["bytes"] for p in plan)
+        else:
+            hd = cfg.d_model // cfg.n_heads
+            itemsize = 2 if cfg.compute_dtype == "bfloat16" else 4
+            cache = 2 * (cfg.n_layers + int(draft_layers)) * int(n_slots) \
+                * cfg.n_heads * T * hd * itemsize
     else:
         # recurrent nets: the carry is the decode state; lean on the
         # layer-wise estimator for params + per-slot activation state
@@ -1023,9 +1222,14 @@ def generation_memory_report(model, n_slots: int,
         cache = report.total_memory_bytes(batch_size=int(n_slots),
                                           training=False) - params
         cache = max(cache, 0)
-    return {"cache_bytes": int(cache), "param_bytes": int(params),
-            "total_bytes": int(cache) + int(params),
-            "n_slots": int(n_slots), "max_length": max_length}
+    out = {"cache_bytes": int(cache), "param_bytes": int(params),
+           "total_bytes": int(cache) + int(params),
+           "n_slots": int(n_slots), "max_length": max_length}
+    if plan is not None:
+        out["cache_plan"] = [
+            {k: p[k] for k in ("kind", "layers", "columns", "ring", "bytes")}
+            for p in plan]
+    return out
 
 
 def _device_bytes_limit() -> Optional[int]:
@@ -1153,6 +1357,12 @@ class GenerationEngine:
                        else None)
         #: per-slot (t[-2], t[-1]) context feeding the n-gram draft
         self._ctx = np.zeros((self.n_slots, 2), np.int64)
+        if (prefix_cache_mb and float(prefix_cache_mb) > 0
+                and not getattr(self.backend, "supports_prefix_cache", True)):
+            raise ValueError(
+                f"the {self.backend.kind} backend has no prefix cache (a "
+                "window layer's ring keeps a prompt's last columns only); "
+                "set prefix_cache_mb=0")
         self._prefix_cache = (
             PrefixCache(int(float(prefix_cache_mb) * (1 << 20)),
                         self.metrics)
@@ -1753,6 +1963,9 @@ class GenerationEngine:
                                           emitted - n_active)
             else:
                 self.metrics.record_decode_step(dt, n_active)
+                counts = getattr(self.backend, "step_counters", None)
+                if counts is not None:
+                    self.metrics.record_moe_step(*counts)
             if dt * 1e3 > self.stall_ms:
                 _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
                                active=n_active)
@@ -1852,6 +2065,10 @@ class GenerationEngine:
                                 "engine shut down mid-decode"))
         self._worker.join(timeout=timeout)
         self._fail_queued()
+        if not self._worker.is_alive():
+            # the slab goes with the worker that owned it
+            with self._dev_lock:
+                self.backend.release()
 
     def _fail_queued(self) -> None:
         while True:

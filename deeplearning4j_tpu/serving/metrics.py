@@ -305,6 +305,15 @@ class GenerationMetrics:
         self._flops_avoided = reg.counter(
             "generation_prefill_flops_avoided_total",
             "analytic prefill FLOPs avoided by prefix-cache hits")
+        # expert layers (DecoderLM): what this holder's experts computed
+        self._moe_pairs = reg.counter(
+            "generation_moe_pairs_local_total",
+            "(token, expert) pairs computed by the experts held here, "
+            "all layers, over decode steps")
+        self._moe_hit = reg.counter(
+            "generation_moe_experts_hit_total",
+            "held experts with at least one pair, summed over layers "
+            "and decode steps")
         self._hit_rate_gauge = None
         #: lookups before the hit-rate gauge materializes (and the
         #: prefix_hit_rate_low rule can fire)
@@ -338,6 +347,12 @@ class GenerationMetrics:
         self._decode_s.inc(float(seconds))
         if tokens:
             self._tokens.inc(int(tokens))
+
+    def record_moe_step(self, pairs_local: int, experts_hit: int) -> None:
+        if pairs_local:
+            self._moe_pairs.inc(int(pairs_local))
+        if experts_hit:
+            self._moe_hit.inc(int(experts_hit))
 
     def record_first_token(self) -> None:
         self._tokens.inc()
@@ -432,6 +447,8 @@ class GenerationMetrics:
             "prefix_evictions": int(self._prefix_evicts.value()),
             "prefix_cache_bytes": int(self._prefix_bytes.value()),
             "prefill_flops_avoided": int(self._flops_avoided.value()),
+            "moe_pairs_local": int(self._moe_pairs.value()),
+            "moe_experts_hit": int(self._moe_hit.value()),
             "latency_window": n,
         }
         for name, q in (("p50", 0.50), ("p90", 0.90), ("p99", 0.99)):

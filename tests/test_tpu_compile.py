@@ -191,6 +191,67 @@ def test_decode_program_keeps_the_kv_slab_in_place(one_chip):
     assert not copies, copies
 
 
+def test_decoder_decode_program_compiles_at_published_widths(one_chip):
+    """``DecoderLM``'s decode program as the engine builds it, at the
+    mimo-v2.5-ep16 cell's widths (hidden 4096, 64 heads of 192/128, 4 and
+    8 key/value heads, 16 of 256 experts of 2048, 64 slots x 1536,
+    bfloat16) and a cut depth (a dense full layer and two window expert
+    layers). What a CPU run cannot show: the grouped expert product is a
+    Mosaic kernel on the TPU and refuses the package-wide "highest"
+    precision (``moe_dropless_ffn`` pins DEFAULT for bfloat16 operands);
+    the rings and the full layer's slab stay in place (no temporary as
+    large as a slab, no copy of one)."""
+    import re
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.models.decoder_lm import (
+        DecoderConfig,
+        init_cache,
+        init_params,
+    )
+    from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    S, T = 64, 1536
+    cfg = DecoderConfig(
+        vocab_size=19072, d_model=4096, n_heads=64, head_dim=192,
+        v_head_dim=128, rotary_dim=64,
+        attn_kinds={"full": {"n_kv_heads": 4, "rope_theta": 1e7,
+                             "window": None, "sink": False},
+                    "window": {"n_kv_heads": 8, "rope_theta": 1e4,
+                               "window": 128, "sink": True}},
+        layers=[("full", "dense"), ("window", "experts"),
+                ("window", "experts")],
+        dense_width=16384, expert_width=2048, n_experts=256, top_k=8,
+        experts_held=(0, 16), value_scale=0.707, max_length=T)
+    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
+                         lambda name: None)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(lambda: init_params(cfg)))
+    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    compiled = be._decode_fn.lower(
+        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and _custom_calls(text) >= 3
+    ring = math.prod(caches[1][1].shape)  # the smallest slab: a ring's V
+    # 275 MB planned: a 100 MB relayout of Wq, the sampler's sorts; the
+    # four slabs are 335 MB, and a second copy of each would pass this
+    assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+    copies = [
+        (name, dims) for name, dims in re.findall(
+            r"%(\S+) = bf16\[([\d,]+)\]\S* copy\(", text)
+        if len(dims.split(",")) == 5
+        and math.prod(map(int, dims.split(","))) >= ring]
+    assert not copies, copies
+
+
 def test_compiled_for_the_described_chip(one_chip):
     """The guard on the guard: the sharding these tests compile for is a
     TPU v5e, not the CPU the suite runs on."""
