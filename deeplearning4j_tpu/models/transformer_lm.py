@@ -283,6 +283,30 @@ def _sample_next(logits: np.ndarray, temperature: float, top_k: int,
     return nxt, rng
 
 
+def sampling_needs(temperature, top_k, top_p, vocab: int):
+    """What a batch's sampling policies ask of the sampler: ``(draws,
+    filters)``, two scalar booleans. *draws*: some row has
+    ``temperature > 0`` (a categorical draw is read); *filters*: some
+    row draws AND has a filter that cuts (``0 < top_k < vocab`` or
+    ``0 < top_p < 1``). A row whose result is thrown away is handed in
+    with ``temperature`` 0. Operators and ``.any()`` only, so NumPy and
+    JAX arrays (scalar or per row) serve alike: the device sampler
+    branches on it and ``GenerationEngine`` counts with it from its
+    host copy of the slots' policies."""
+    draws = temperature > 0
+    filters = draws & (((top_k > 0) & (top_k < vocab))
+                       | ((top_p > 0) & (top_p < 1)))
+    return draws.any(), filters.any()
+
+
+def _col(x):  # a scalar knob stays scalar; (b,) broadcasts per row
+    return x if jnp.ndim(x) == 0 else x[:, None]
+
+
+def _scale_logits(logits, temperature):
+    return logits / _col(jnp.where(temperature > 0, temperature, 1.0))
+
+
 def _filter_logits(logits, temperature, top_k, top_p):
     """Shared in-graph sampling filter: (b, V) fp32 logits →
     temperature-scaled, top-k- and nucleus-filtered logits. The policy
@@ -290,23 +314,22 @@ def _filter_logits(logits, temperature, top_k, top_p):
     decode) or per-row (b,) arrays (the continuous-batching engine: each
     slot its own policy); every op is row-wise either way, so a row
     filtered among other slots is bit-identical to the same row filtered
-    alone. All policy decisions are data-dependent ``where`` selects —
-    ONE compiled program covers greedy and every knob combination."""
+    alone. All policy decisions INSIDE are data-dependent ``where``
+    selects, so a row whose filters are off comes back as exactly its
+    scaled logits, permuted and permuted back. Its one sort, two
+    argsorts and three gathers over (b, V) run only in the third branch
+    of :func:`_sample_branches`: when some row that draws has a filter
+    that cuts."""
     V = logits.shape[-1]
-
-    def col(x):  # scalar stays scalar; (b,) broadcasts per row
-        return x if jnp.ndim(x) == 0 else x[:, None]
-
-    t = jnp.where(temperature > 0, temperature, 1.0)
-    l = logits / col(t)
+    l = _scale_logits(logits, temperature)
     # top-k: keep the k highest (filter active only for 0 < k < V)
     k_eff = jnp.clip(top_k, 1, V)
     use_k = (top_k > 0) & (top_k < V)
     sorted_asc = jnp.sort(l, axis=-1)
     kth = jnp.take_along_axis(
-        sorted_asc, jnp.broadcast_to(col(V - k_eff),
+        sorted_asc, jnp.broadcast_to(_col(V - k_eff),
                                      (l.shape[0], 1)), axis=-1)
-    l = jnp.where(col(use_k) & (l < kth), -jnp.inf, l)
+    l = jnp.where(_col(use_k) & (l < kth), -jnp.inf, l)
     # nucleus: smallest prefix of descending-prob tokens reaching top_p
     use_p = (top_p > 0.0) & (top_p < 1.0)
     order = jnp.argsort(-l, axis=-1)
@@ -315,32 +338,66 @@ def _filter_logits(logits, temperature, top_k, top_p):
     p_sorted = p_sorted / p_sorted.sum(-1, keepdims=True)
     cum = jnp.cumsum(p_sorted, -1)
     # keep tokens up to AND including the one crossing p (host parity)
-    cut = cum - p_sorted >= col(top_p)
-    sl = jnp.where(col(use_p) & cut, -jnp.inf, sl)
+    cut = cum - p_sorted >= _col(top_p)
+    sl = jnp.where(_col(use_p) & cut, -jnp.inf, sl)
     inv = jnp.argsort(order, axis=-1)
     return jnp.take_along_axis(sl, inv, -1)
+
+
+def _sample_branches(logits, temperature, top_k, top_p, draw):
+    """The one sampler body behind :func:`sample_next_device` and
+    :func:`sample_next_rows`: ONE compiled program, three branches
+    chosen on the device by :func:`sampling_needs` of the rows in front
+    of it, so a step pays only for what its policies read:
+
+    0. no row draws: ``argmax`` alone — no scale, sort, gather or draw;
+    1. rows draw, none filters: scale by temperature and ``draw``;
+    2. some row filters: :func:`_filter_logits`, then ``draw``.
+
+    ``draw`` maps (b, V) filtered logits to (b,) sampled ids (the
+    callers' key handling differs, nothing else). Every row's id is
+    bit-identical to the unbranched sampler's (always filter, always
+    draw, keep ``argmax`` where ``temperature <= 0``) for every policy
+    and every mix of policies in a batch: a greedy row never read the
+    draw, and with both filters off ``_filter_logits`` returns the
+    scaled logits themselves. Do not ``vmap`` this function: a batched
+    predicate turns the conditional into a select that runs every
+    branch."""
+    temperature, top_k, top_p = (jnp.asarray(temperature),
+                                 jnp.asarray(top_k), jnp.asarray(top_p))
+    greedy = jnp.argmax(logits, axis=-1)
+    draws, filters = sampling_needs(temperature, top_k, top_p,
+                                    logits.shape[-1])
+    sampled = jax.lax.switch(
+        draws.astype(jnp.int32) + filters.astype(jnp.int32),
+        (lambda: greedy,
+         lambda: draw(_scale_logits(logits, temperature)),
+         lambda: draw(_filter_logits(logits, temperature, top_k, top_p))))
+    return jnp.where(temperature <= 0, greedy, sampled).astype(jnp.int32)
 
 
 def sample_next_device(logits, temperature, top_k, top_p, key):
     """In-graph mirror of :func:`_sample_next`: (b, V) fp32 logits →
     ((b,) int32 next ids, advanced key). One key chain for the whole
     batch, exactly like the host sampler — the solo
-    ``generate_cached`` fused path.
+    ``generate_cached`` fused path. One program, three branches
+    (:func:`_sample_branches`): a greedy call runs an ``argmax`` and
+    nothing else.
 
     Parity: greedy and temperature/top-k outputs are bit-identical to
     the host sampler for the same key (sort/compare/divide are exact and
     the categorical draw uses the same key chain). top-p's cumsum may
     differ from NumPy's in reduction order, so nucleus CUTOFFS can
     differ at ties on the boundary — tolerance documented in
-    ARCHITECTURE § Continuous batching. The key is split every call
-    (data-independent chain) even under greedy, which ignores it."""
+    ARCHITECTURE § Continuous batching. The key is split every call,
+    outside the branches (a data-independent chain), even under greedy,
+    which ignores it."""
     with _scope("sample"):
-        l = _filter_logits(logits, temperature, top_k, top_p)
         key, sub = jax.random.split(key)
-        sampled = jax.random.categorical(sub, l)
-        nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1),
-                        sampled)
-        return nxt.astype(jnp.int32), key
+        nxt = _sample_branches(
+            logits, temperature, top_k, top_p,
+            lambda l: jax.random.categorical(sub, l))
+        return nxt, key
 
 
 def sample_next_rows(logits, temperature, top_k, top_p, keys):
@@ -352,16 +409,20 @@ def sample_next_rows(logits, temperature, top_k, top_p, keys):
     (1, V) lane exactly like a solo b=1 call — so lane s is bit-
     identical to ``sample_next_device(logits[s:s+1], ..., keys[s])``
     (counter-based PRNG + vmap semantics), which is what makes engine
-    output ≡ solo output."""
+    output ≡ solo output. The branch (:func:`_sample_branches`) is
+    taken for the batch: one row that filters makes every row pay the
+    sorts, and none changes what it gets. A caller that throws rows
+    away (inactive slots) hands them ``temperature`` 0, so that a freed
+    slot's last policy opens no branch."""
     with _scope("sample"):
-        l = _filter_logits(logits, temperature, top_k, top_p)
         splits = jax.vmap(jax.random.split)(keys)  # (b, 2, 2)
         nkeys, subs = splits[:, 0], splits[:, 1]
-        sampled = jax.vmap(
-            lambda k, row: jax.random.categorical(k, row[None])[0])(subs, l)
-        nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1),
-                        sampled)
-        return nxt.astype(jnp.int32), nkeys
+        nxt = _sample_branches(
+            logits, temperature, top_k, top_p,
+            lambda l: jax.vmap(
+                lambda k, row: jax.random.categorical(k, row[None])[0])(
+                    subs, l))
+        return nxt, nkeys
 
 
 def init_decode_cache(cfg: TransformerLMConfig, batch: int,

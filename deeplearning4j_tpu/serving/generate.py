@@ -387,6 +387,16 @@ class PrefixCache:
 # --------------------------------------------------------------------------
 # decode backends
 # --------------------------------------------------------------------------
+def _counted(temperature, active):
+    """The slots' temperatures as the in-graph sampler should see them:
+    0 (greedy) on a row whose result the decode program throws away. A
+    freed slot keeps its last occupant's policy on the host, and the
+    sampler branches on the policies it is handed
+    (``transformer_lm.sampling_needs``): a sampled request that has
+    finished must not make the greedy ones pay for its sorts."""
+    return jnp.where(active, temperature, 0.0)
+
+
 class _TransformerBackend:
     """TransformerLM decode backend: fixed (L, S, hn, hd, T) KV slab
     (time minor: ``init_decode_cache``), per-slot positions, per-bucket
@@ -418,6 +428,7 @@ class _TransformerBackend:
             self.max_length,
             prefill_buckets or getattr(model, "serving_seq_buckets", None))
         self._cfg = cfg
+        self.vocab = int(cfg.vocab_size)
         #: speculation lane width K: column 0 is the current token,
         #: columns 1..K-1 draft proposals. MoE pins K=1 — decode_steps'
         #: routing would compete b*K tokens where sequential decode
@@ -445,7 +456,8 @@ class _TransformerBackend:
             trace_hook("generation_decode")
             logits, c = decode_step(cfg, p, {"k": kc, "v": vc, "pos": pos},
                                     toks)
-            nxt, nkeys = sample_next_rows(logits, t, k, pp, keys)
+            nxt, nkeys = sample_next_rows(logits, _counted(t, active), k,
+                                          pp, keys)
             nxt = jnp.where(active, nxt, toks)
             nkeys = jnp.where(active[:, None], nkeys, keys)
             return nxt, nkeys, c["k"], c["v"]
@@ -512,8 +524,9 @@ class _TransformerBackend:
                 logits, c = decode_steps(
                     cfg, p, {"k": kc, "v": vc, "pos": pos}, toks)
                 outs, kstack, ks = [], [keys], keys
+                tc = _counted(t, active)
                 for j in range(K):
-                    sj, ks = sample_next_rows(logits[:, j], t, k, pp, ks)
+                    sj, ks = sample_next_rows(logits[:, j], tc, k, pp, ks)
                     outs.append(sj)
                     kstack.append(ks)
                 s = jnp.stack(outs, axis=1)          # (S, K)
@@ -762,6 +775,7 @@ class _DecoderBackend:
 
         self.model = model
         cfg = self._cfg = model.cfg
+        self.vocab = int(cfg.vocab_size)
         self.n_slots = int(n_slots)
         self.max_length = (cfg.max_length if max_length is None
                            else min(int(max_length), cfg.max_length))
@@ -788,8 +802,9 @@ class _DecoderBackend:
             keys = jax.lax.bitcast_convert_type(rows[:, 4:6], jnp.uint32)
             logits, caches, counts = decode_step(cfg, p, caches, toks, pos,
                                                  active)
-            nxt, nkeys = sample_next_rows(logits, _f32(rows[:, 6]), k,
-                                          _f32(rows[:, 7]), keys)
+            nxt, nkeys = sample_next_rows(
+                logits, _counted(_f32(rows[:, 6]), active), k,
+                _f32(rows[:, 7]), keys)
             nxt = jnp.where(active, nxt, toks)
             nkeys = jnp.where(active[:, None], nkeys, keys)
             after = jnp.concatenate(
@@ -1025,7 +1040,8 @@ class _RecurrentBackend:
                                                 rng=None, carries=carries)
                 logits = jnp.log(jnp.clip(y[:, -1, :].astype(jnp.float32),
                                           1e-30, None))
-            nxt, nkeys = sample_next_rows(logits, t, k, pp, keys)
+            nxt, nkeys = sample_next_rows(logits, _counted(t, active), k,
+                                          pp, keys)
             nxt = jnp.where(active, nxt, toks)
             nkeys = jnp.where(active[:, None], nkeys, keys)
             nc = jax.tree_util.tree_map(
@@ -1883,6 +1899,7 @@ class GenerationEngine:
         return toks_k, dlen
 
     def _step(self) -> None:
+        from deeplearning4j_tpu.models.transformer_lm import sampling_needs
         from deeplearning4j_tpu.obs import flight as _flight
 
         n_active = int(self._active.sum())
@@ -1956,13 +1973,20 @@ class GenerationEngine:
         with _EMIT:
             self._step_ewma_s = (dt if self._step_ewma_s is None
                                  else 0.8 * self._step_ewma_s + 0.2 * dt)
+            # which branch the in-graph sampler took this step, told from
+            # the host's copy of the policies it was handed (no fetch)
+            drawn, filtered = sampling_needs(
+                np.where(self._active, self._temp, 0.0), self._topk,
+                self._topp, self.backend.vocab)
             if use_spec:
                 emitted = int(e_all.sum())
-                self.metrics.record_decode_step(dt, emitted)
+                self.metrics.record_decode_step(dt, emitted, drawn,
+                                                filtered)
                 self.metrics.record_draft(int(dlen[self._active].sum()),
                                           emitted - n_active)
             else:
-                self.metrics.record_decode_step(dt, n_active)
+                self.metrics.record_decode_step(dt, n_active, drawn,
+                                                filtered)
                 counts = getattr(self.backend, "step_counters", None)
                 if counts is not None:
                     self.metrics.record_moe_step(*counts)

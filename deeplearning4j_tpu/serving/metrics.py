@@ -256,6 +256,17 @@ class GenerationMetrics:
         self._decode_steps = reg.counter(
             "generation_decode_steps_total",
             "batched decode dispatches (one per token for ALL slots)")
+        # what the in-graph sampler had to do (it branches on the
+        # batch's policies: transformer_lm.sampling_needs)
+        self._sample_drawn = reg.counter(
+            "generation_sample_drawn_steps_total",
+            "decode steps in which some active slot drew (temperature "
+            "> 0): the sampler scaled and drew, not argmax alone")
+        self._sample_filtered = reg.counter(
+            "generation_sample_filtered_steps_total",
+            "decode steps in which some active slot that drew had a "
+            "top-k or top-p that cuts: the sampler sorted and gathered "
+            "over every slot's logits")
         self._prefill_s = reg.counter(
             "generation_prefill_seconds_total",
             "wall seconds spent in prompt prefill")
@@ -342,11 +353,17 @@ class GenerationMetrics:
         self._prefills.inc()
         self._prefill_s.inc(float(seconds))
 
-    def record_decode_step(self, seconds: float, tokens: int) -> None:
+    def record_decode_step(self, seconds: float, tokens: int,
+                           drawn: bool = False,
+                           filtered: bool = False) -> None:
         self._decode_steps.inc()
         self._decode_s.inc(float(seconds))
         if tokens:
             self._tokens.inc(int(tokens))
+        if drawn:
+            self._sample_drawn.inc()
+        if filtered:
+            self._sample_filtered.inc()
 
     def record_moe_step(self, pairs_local: int, experts_hit: int) -> None:
         if pairs_local:
@@ -429,6 +446,8 @@ class GenerationMetrics:
             "tokens": self.tokens,
             "prefills": int(self._prefills.value()),
             "decode_steps": int(self._decode_steps.value()),
+            "sample_drawn_steps": int(self._sample_drawn.value()),
+            "sample_filtered_steps": int(self._sample_filtered.value()),
             "prefill_seconds": round(prefill_s, 4),
             "decode_seconds": round(decode_s, 4),
             "prefill_fraction": (

@@ -28,6 +28,8 @@ from deeplearning4j_tpu.models.transformer_lm import (
     _sample_next,
     prefill_bucket_lengths,
     sample_next_device,
+    sample_next_rows,
+    sampling_needs,
 )
 from deeplearning4j_tpu.serving import (
     GenerationEngine,
@@ -74,6 +76,88 @@ def _prompts(n, lens=(3, 21), seed=0):
 # ---------------------------------------------------------------------------
 # in-graph sampler
 # ---------------------------------------------------------------------------
+# the in-graph sampler as it was before it branched on the batch's
+# policies (PR 28), kept as the plain reference: filter always, draw
+# always, keep argmax where temperature <= 0
+def _unbranched_filter(logits, temperature, top_k, top_p):
+    jnp = jax.numpy
+    V = logits.shape[-1]
+
+    def col(x):
+        return x if jnp.ndim(x) == 0 else x[:, None]
+
+    t = jnp.where(temperature > 0, temperature, 1.0)
+    l = logits / col(t)
+    k_eff = jnp.clip(top_k, 1, V)
+    use_k = (top_k > 0) & (top_k < V)
+    sorted_asc = jnp.sort(l, axis=-1)
+    kth = jnp.take_along_axis(
+        sorted_asc, jnp.broadcast_to(col(V - k_eff), (l.shape[0], 1)),
+        axis=-1)
+    l = jnp.where(col(use_k) & (l < kth), -jnp.inf, l)
+    use_p = (top_p > 0.0) & (top_p < 1.0)
+    order = jnp.argsort(-l, axis=-1)
+    sl = jnp.take_along_axis(l, order, -1)
+    p_sorted = jnp.exp(sl - sl.max(-1, keepdims=True))
+    p_sorted = p_sorted / p_sorted.sum(-1, keepdims=True)
+    cum = jnp.cumsum(p_sorted, -1)
+    cut = cum - p_sorted >= col(top_p)
+    sl = jnp.where(col(use_p) & cut, -jnp.inf, sl)
+    inv = jnp.argsort(order, axis=-1)
+    return jnp.take_along_axis(sl, inv, -1)
+
+
+def _unbranched_device(logits, temperature, top_k, top_p, key):
+    jnp = jax.numpy
+    l = _unbranched_filter(logits, temperature, top_k, top_p)
+    key, sub = jax.random.split(key)
+    sampled = jax.random.categorical(sub, l)
+    nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1), sampled)
+    return nxt.astype(jnp.int32), key
+
+
+def _unbranched_rows(logits, temperature, top_k, top_p, keys):
+    jnp = jax.numpy
+    l = _unbranched_filter(logits, temperature, top_k, top_p)
+    splits = jax.vmap(jax.random.split)(keys)
+    nkeys, subs = splits[:, 0], splits[:, 1]
+    sampled = jax.vmap(
+        lambda k, row: jax.random.categorical(k, row[None])[0])(subs, l)
+    nxt = jnp.where(temperature <= 0, jnp.argmax(logits, axis=-1), sampled)
+    return nxt.astype(jnp.int32), nkeys
+
+
+#: id -> per-row (temperature, top_k, top_p, active) over four rows, and
+#: what ``sampling_needs`` must say of them: every policy alone, a batch
+#: that mixes them, and a filtered row whose result is thrown away
+_POLICY_MIXES = {
+    "all_greedy": ([0.0] * 4, [0] * 4, [0.0] * 4, [True] * 4,
+                   (False, False)),
+    "temperature_only": ([0.7, 1.3, 0.5, 0.7], [0] * 4, [0.0] * 4,
+                         [True] * 4, (True, False)),
+    # a top_k of the whole vocabulary and a top_p of 1 cut nothing
+    "filters_that_cut_nothing": ([0.7, 1.3, 0.5, 0.7], [0, 32, 40, 0],
+                                 [0.0, 1.0, 0.0, 1.0], [True] * 4,
+                                 (True, False)),
+    "top_k": ([0.7, 1.3, 0.5, 0.9], [5, 1, 9, 31], [0.0] * 4, [True] * 4,
+              (True, True)),
+    "top_p": ([0.7, 1.3, 0.5, 0.9], [0] * 4, [0.9, 0.5, 0.1, 0.99],
+              [True] * 4, (True, True)),
+    "top_k_and_top_p": ([0.7, 1.3, 0.5, 0.9], [5, 3, 9, 31],
+                        [0.9, 0.5, 0.1, 0.99], [True] * 4, (True, True)),
+    "mixed": ([0.0, 0.8, 1.1, 0.6], [0, 0, 4, 0], [0.0, 0.0, 0.0, 0.8],
+              [True] * 4, (True, True)),
+    "greedy_and_temperature_only": ([0.0, 0.8, 0.0, 1.2], [0] * 4,
+                                    [0.0] * 4, [True] * 4, (True, False)),
+    "filtered_row_inactive": ([0.0, 0.0, 0.9, 0.0], [0, 0, 5, 0],
+                              [0.0, 0.0, 0.7, 0.0],
+                              [True, True, False, True], (False, False)),
+    "drawing_row_inactive": ([0.0, 0.9, 0.9, 0.0], [0, 0, 5, 0],
+                             [0.0, 0.0, 0.7, 0.0],
+                             [True, True, False, True], (True, False)),
+}
+
+
 class TestDeviceSampler:
     def _logits(self, b=3, V=32, seed=4):
         return np.random.default_rng(seed).standard_normal(
@@ -116,6 +200,73 @@ class TestDeviceSampler:
                                         0, 1e-6, jax.random.PRNGKey(s))
             toks.add(int(np.asarray(dev)[0]))
         assert toks == {int(logits[0].argmax())}
+
+    @pytest.mark.parametrize("rows", [False, True],
+                             ids=["sample_next_device", "sample_next_rows"])
+    @pytest.mark.parametrize("mix", sorted(_POLICY_MIXES))
+    def test_branches_bit_identical_to_unbranched(self, mix, rows):
+        # the sampler runs only what the rows in front of it ask for
+        # (argmax alone; scale + draw; filter + draw) and must hand every
+        # row that is kept the id and the key of the sampler that always
+        # did everything, whatever the other rows ask for
+        from deeplearning4j_tpu.serving.generate import _counted
+
+        jnp = jax.numpy
+        t, k, pp, active, needs = _POLICY_MIXES[mix]
+        t, k, pp = (jnp.asarray(t, jnp.float32), jnp.asarray(k, jnp.int32),
+                    jnp.asarray(pp, jnp.float32))
+        active = jnp.asarray(active)
+        # a host copy of the same policies tells the same branch
+        assert tuple(map(bool, sampling_needs(
+            np.where(np.asarray(active), np.asarray(t), 0.0),
+            np.asarray(k), np.asarray(pp), 32))) == needs
+        assert tuple(map(bool, sampling_needs(
+            _counted(t, active), k, pp, 32))) == needs
+        new, old = ((sample_next_rows, _unbranched_rows) if rows
+                    else (sample_next_device, _unbranched_device))
+
+        def step(fn, temperature):
+            # what the decode programs keep of a step: a row that is
+            # not active keeps its token and its key
+            def run(logits, toks, keys):
+                nxt, nkeys = fn(logits, temperature, k, pp, keys)
+                if rows:
+                    nkeys = jnp.where(active[:, None], nkeys, keys)
+                return jnp.where(active, nxt, toks), nkeys
+            return jax.jit(run)
+
+        toks = jnp.arange(4, dtype=jnp.int32)
+        branched, unbranched = step(new, _counted(t, active)), step(old, t)
+        for seed in range(6):
+            logits = jnp.asarray(self._logits(b=4, seed=seed))
+            keys = (jax.vmap(jax.random.PRNGKey)(jnp.arange(4) + 10 * seed)
+                    if rows else jax.random.PRNGKey(seed))
+            got = branched(logits, toks, keys)
+            want = unbranched(logits, toks, keys)
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("policy", [(0.0, 0, 0.0), (0.8, 0, 0.0),
+                                        (0.8, 5, 0.0), (0.8, 0, 0.9),
+                                        (0.8, 5, 0.9)],
+                             ids=["greedy", "temperature", "top_k", "top_p",
+                                  "top_k_top_p"])
+    def test_scalar_policy_bit_identical_to_unbranched(self, policy):
+        # one policy for the batch, as generate_cached and the prefills
+        # hand it: scalars, not rows
+        jnp = jax.numpy
+        pol = (jnp.asarray(policy[0], jnp.float32),
+               jnp.asarray(policy[1], jnp.int32),
+               jnp.asarray(policy[2], jnp.float32))
+        branched = jax.jit(sample_next_device)
+        unbranched = jax.jit(_unbranched_device)
+        for seed in range(4):
+            logits = jnp.asarray(self._logits(seed=seed))
+            key = jax.random.PRNGKey(seed)
+            got = branched(logits, *pol, key)
+            want = unbranched(logits, *pol, key)
+            for g, w in zip(got, want):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 # ---------------------------------------------------------------------------
@@ -215,6 +366,56 @@ class TestGenerationEngine:
         solo = m.generate_cached(prompt, max_new=5, temperature=0.8,
                                  top_k=4, rng=jax.random.PRNGKey(13))[0]
         np.testing.assert_array_equal(out, solo)
+
+    def test_sampler_branch_counters_follow_the_active_policies(self):
+        # sample_drawn_steps / sample_filtered_steps: the decode steps in
+        # which the in-graph sampler had to draw / to sort, counted on
+        # the host from the slots' policies
+        eng = _engine()
+
+        def counted(run):
+            before = eng.metrics.snapshot()
+            run()
+            after = eng.metrics.snapshot()
+            return {k: after[k] - before[k]
+                    for k in ("decode_steps", "sample_drawn_steps",
+                              "sample_filtered_steps")}
+
+        prompts = _prompts(4, (3, 12), seed=21)
+
+        def greedy_storm():
+            for r in [eng.submit(p, max_new=6, timeout=90) for p in prompts]:
+                r.result(timeout=90)
+
+        d = counted(greedy_storm)
+        assert d["decode_steps"] >= 5
+        assert d["sample_drawn_steps"] == d["sample_filtered_steps"] == 0
+
+        def storm_with_one_top_p():
+            # three slots: the top-p request decodes 3 steps beside two
+            # greedy ones that go on for 8 more; its freed slot keeps
+            # its policy on the host and must count for nothing
+            reqs = [eng.submit(prompts[0], max_new=4, temperature=0.8,
+                               top_p=0.9, seed=3, timeout=90)]
+            reqs += [eng.submit(p, max_new=12, timeout=90)
+                     for p in prompts[1:3]]
+            for r in reqs:
+                r.result(timeout=90)
+
+        d = counted(storm_with_one_top_p)
+        assert d["sample_drawn_steps"] == d["sample_filtered_steps"] == 3
+        assert d["decode_steps"] >= 11
+
+        def temperature_only():
+            eng.submit(prompts[3], max_new=5, temperature=0.7, seed=4,
+                       timeout=90).result(timeout=90)
+
+        d = counted(temperature_only)
+        assert (d["decode_steps"], d["sample_drawn_steps"],
+                d["sample_filtered_steps"]) == (4, 4, 0)
+        text = eng.metrics.registry.prometheus_text()
+        assert "generation_sample_drawn_steps_total" in text
+        assert "generation_sample_filtered_steps_total" in text
 
     def test_streaming_matches_result(self):
         eng = _engine()

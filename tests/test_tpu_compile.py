@@ -144,6 +144,60 @@ def test_fused_conv_resnet50_stage(one_chip, kernel, shapes, grad):
     assert _custom_calls(text) >= (3 if grad else 1)
 
 
+def _always_run(text: str) -> dict:
+    """{computation: its instruction lines} of the computations of an
+    HLO module that run whenever the program does: reachable from ENTRY
+    by ``calls`` / ``to_apply`` / ``body`` / ``condition``, but not
+    through the branches of a ``conditional``."""
+    import re
+
+    comps, entry, cur = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            entry = cur if head.group(1) else entry
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line)
+    assert entry is not None
+    seen, todo = set(), [entry]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            line = re.sub(r"branch_computations=\{[^}]*\}"
+                          r"|(?:true|false)_computation=%?[\w.\-]+", "", line)
+            todo += [c for c in re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+                if c in comps]
+    return {name: comps[name] for name in seen}
+
+
+def _sampler_work_outside_a_conditional(text: str, slots: int,
+                                        vocab: int) -> list:
+    """The ``sort`` and ``gather`` instructions with a (slots, vocab)
+    result (the sampler's: a row of logits a slot) that the program runs
+    whatever the slots' policies are. The in-graph sampler keeps them in
+    the branch of a ``conditional`` that a batch of greedy slots does
+    not take (``transformer_lm._sample_branches``)."""
+    import re
+
+    logits = re.compile(rf"\w+\[{slots},{vocab}\]")
+    found = []
+    for name, lines in _always_run(text).items():
+        for line in lines:
+            op = re.search(r"^\s*(?:ROOT )?%?(\S+) = (.*?) (sort|gather)\(",
+                           line)
+            if op and logits.search(op.group(2)):
+                found.append((name, op.group(1), op.group(3)))
+    return found
+
+
 def test_decode_program_keeps_the_kv_slab_in_place(one_chip):
     """The serving engine's decode program (``_decode``) at the
     gpt2-large.chat cell's widths (d 1280, 20 heads, 24 slots x 1024,
@@ -189,6 +243,12 @@ def test_decode_program_keeps_the_kv_slab_in_place(one_chip):
             r"%(\S+) = \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
         if math.prod(map(int, dims.split(","))) >= layer_slice]
     assert not copies, copies
+    # all 24 slots greedy is the common step: its sorts and gathers over
+    # 24 x 50257 logits (27 of 49 ms a step on the chip) wait in a branch
+    text = compiled.as_text()
+    assert " conditional(" in text and " sort(" in text
+    assert not _sampler_work_outside_a_conditional(text, S,
+                                                   cfg.vocab_size)
 
 
 def test_decoder_decode_program_compiles_at_published_widths(one_chip):
@@ -241,9 +301,13 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     text = compiled.as_text()
     assert "ragged-dot" in text and _custom_calls(text) >= 3
     ring = math.prod(caches[1][1].shape)  # the smallest slab: a ring's V
-    # 275 MB planned: a 100 MB relayout of Wq, the sampler's sorts; the
-    # four slabs are 335 MB, and a second copy of each would pass this
+    # 275 MB planned: a 100 MB relayout of Wq, the temporaries of the
+    # sampler's filtering branch; the four slabs are 335 MB, and a
+    # second copy of each would pass this
     assert compiled.memory_analysis().temp_size_in_bytes < 400e6
+    assert " conditional(" in text and " sort(" in text
+    assert not _sampler_work_outside_a_conditional(text, S,
+                                                   cfg.vocab_size)
     copies = [
         (name, dims) for name, dims in re.findall(
             r"%(\S+) = bf16\[([\d,]+)\]\S* copy\(", text)
