@@ -160,12 +160,17 @@ def _ln(x, g, b, cd):
     return _layer_norm(x.astype(jnp.float32), g, b).astype(cd)
 
 
-def block_apply(cfg: TransformerLMConfig, bp: Dict[str, Array], x: Array,
-                attn_fn=None, tp_axis: Optional[str] = None,
-                expert_axis: Optional[str] = None):
-    """One pre-LN block on (b, T, d); bp holds UNSTACKED (single-layer)
-    params. ``attn_fn`` defaults to dense attention (ring under SP).
-    Dense FFN → returns x. MoE (cfg.n_experts > 0) → returns (x, aux).
+def _block(cfg: TransformerLMConfig, bp: Dict[str, Array], x: Array, attend,
+           tp_axis: Optional[str] = None, expert_axis: Optional[str] = None):
+    """THE pre-LN block, for every caller: x (b, T, d) with T the
+    sequence (``forward``, ``prefill_cache``), 1 (``decode_step``) or K
+    (``decode_steps``); bp holds UNSTACKED (single-layer) params.
+    ``attend(q, k, v) -> (o, handed_out)`` is the attention core, all
+    four arrays (b, heads, T, head_dim): :func:`_attend_causal`,
+    :func:`_attend_prefill` or :func:`_attend_cached`. The block never
+    writes a cache: what the core hands out is returned for the caller
+    to drop, write whole or append. Returns (x, handed_out, MoE aux
+    loss; a float32 zero for a dense FFN).
     Under compute_dtype="bfloat16": matmul operands and the carried
     activation are bf16; layernorm statistics fp32.
 
@@ -178,31 +183,28 @@ def block_apply(cfg: TransformerLMConfig, bp: Dict[str, Array], x: Array,
     derived from the sliced Wq width, so the same code serves any tp
     degree (a size-1 axis psum is a no-op)."""
     b, T, d = x.shape
-    hn = cfg.n_heads
     cd = _cdtype(cfg)
     if cd is not None:
         x = x.astype(cd)
-        bp = {k2: (v.astype(cd) if k2[0] == "W" or k2[0] == "b" else v)
+        bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
               for k2, v in bp.items()}
     # under manual TP the head projections are column slices: this
     # shard owns d_local/head_dim of the hn heads
     d_local = bp["Wq"].shape[-1]
-    hn_local = hn * d_local // d
+    hn_local = cfg.n_heads * d_local // d
     with _scope("attn"):
         a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
 
-        def heads(W):
-            return (a_in @ W).reshape(b, T, hn_local, -1).transpose(0, 2, 1, 3)
+        def heads(y):
+            return y.reshape(b, T, hn_local, -1).transpose(0, 2, 1, 3)
 
         if cfg.fused_qkv:
             qkv = a_in @ jnp.concatenate(
                 [bp["Wq"], bp["Wk"], bp["Wv"]], axis=-1)  # (b, T, 3*d_local)
-            q, k, v = (s.reshape(b, T, hn_local, -1).transpose(0, 2, 1, 3)
-                       for s in jnp.split(qkv, 3, axis=-1))
+            q, k, v = (heads(y) for y in jnp.split(qkv, 3, axis=-1))
         else:
-            q, k, v = heads(bp["Wq"]), heads(bp["Wk"]), heads(bp["Wv"])
-        fn = attn_fn if attn_fn is not None else dense_attention
-        o = fn(q, k, v, causal=True, mask=None)
+            q, k, v = (heads(a_in @ bp[w]) for w in ("Wq", "Wk", "Wv"))
+        o, handed_out = attend(q, k, v)
         o = o.transpose(0, 2, 1, 3).reshape(b, T, d_local).astype(x.dtype)
         om = o @ bp["Wo"]
         if tp_axis is not None:
@@ -219,12 +221,136 @@ def block_apply(cfg: TransformerLMConfig, bp: Dict[str, Array], x: Array,
                 _moe_capacity(cfg, b * T), cfg.top_k,
                 expert_axis=expert_axis, tp_axis=tp_axis,
             )
-            return x + y2.reshape(b, T, d).astype(x.dtype), aux
-        h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-        hm = h @ bp["W2"]
-        if tp_axis is not None:
-            hm = jax.lax.psum(hm, tp_axis)
-        return x + hm + bp["b2"]
+            x = x + y2.reshape(b, T, d).astype(x.dtype)
+        else:
+            h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
+            hm = h @ bp["W2"]
+            if tp_axis is not None:
+                hm = jax.lax.psum(hm, tp_axis)
+            x = x + hm + bp["b2"]
+            aux = jnp.zeros((), jnp.float32)
+    return x, handed_out, aux
+
+
+def _attend_causal(attn_fn=None):
+    """Attention core of ``forward`` and training: causal over the
+    block's own keys, through ``attn_fn`` (default dense attention, which
+    routes to the flash kernel; ring under SP). Hands out nothing."""
+    fn = attn_fn if attn_fn is not None else dense_attention
+
+    def attend(q, k, v):
+        return fn(q, k, v, causal=True, mask=None), None
+
+    return attend
+
+
+def _attend_prefill(kv_dtype):
+    """Attention core of ``prefill_cache``: the causal core, and the
+    block's keys and values handed out as the cache holds them:
+    ``kv_dtype``, (b, hn, hd, Tp)."""
+    causal = _attend_causal()
+
+    def attend(q, k, v):
+        with _scope("kv_write"):
+            kt = k.astype(kv_dtype).transpose(0, 1, 3, 2)
+            vt = v.astype(kv_dtype).transpose(0, 1, 3, 2)
+        return causal(q, k, v)[0], (kt, vt)
+
+    return attend
+
+
+def _attend_cached(kc, vc, live, causal):
+    """Attention core of ``decode_step`` (K = 1) and ``decode_steps``:
+    the step's K queries (b, hn, K, hd) attend, under one softmax
+    (:func:`_joint_softmax`), to the cache columns ``live`` marks (kc,
+    vc: one layer's (b, hn, hd, T); live (b, 1, 1, T): below the row's
+    position) and, through ``causal`` (K, K), to columns 0..j of their
+    own block. Hands out the step's new keys and values, (b, hn, K, hd)
+    in the cache's dtype.
+
+    The cache contract, for every caller. The cache is READ-ONLY inside
+    the layer loop, and both einsums read it where it lies (time minor).
+    The caller stacks what each layer hands out and writes it AFTER the
+    loop, in place, one column a row and position (:func:`_put_columns`)
+    at ``min(position, T-1)``: a write inside the loop, or a column that
+    first reads its old value, makes XLA convert each layer's slice to
+    the write's layout and back and copy the whole stacked cache twice
+    more, every step (PERF.md section 5). A column whose position falls
+    past the cache is DROPPED by that write, never left clipped over the
+    real write of a row whose last token sits exactly at the edge; its
+    position embedding was clipped, so callers never ACCEPT one. Stale
+    columns at and past a row's position (a rejected draft, a prefill's
+    padding) are never read and are overwritten as the row advances:
+    rolling back is free. The math is row-independent, so a row decoded
+    among other slots is bit-identical to the same row decoded alone
+    (parity-asserted in tests/test_generate.py).
+
+    MoE: the block routes only the b * K tokens of the step (per-step
+    capacity) where the full forward competes all window tokens, so when
+    training-time capacity BINDS (dropped tokens) cached decoding can
+    legitimately differ from ``generate``; and K > 1 would compete b * K
+    where sequential decode competes b, so ``decode_steps`` refuses MoE."""
+    kvd = kc.dtype
+
+    def attend(q, k, v):
+        scale = 1.0 / math.sqrt(q.shape[-1])
+        kn, vn = k.astype(kvd), v.astype(kvd)
+        s_slab = jnp.einsum("bhkd,bhdt->bhkt", q,
+                            kc).astype(jnp.float32) * scale
+        s_slab = jnp.where(live, s_slab, -1e30)
+        s_new = jnp.einsum("bhkd,bhjd->bhkj", q,
+                           kn).astype(jnp.float32) * scale
+        s_new = jnp.where(causal, s_new, -1e30)
+        p_slab, p_new = _joint_softmax(s_slab, s_new, kvd)
+        o = jnp.einsum("bhkt,bhdt->bhkd", p_slab, vc,
+                       preferred_element_type=jnp.float32)
+        o = o + jnp.einsum("bhkj,bhjd->bhkd", p_new, vn,
+                           preferred_element_type=jnp.float32)
+        return o, (kn, vn)
+
+    return attend
+
+
+def block_apply(cfg: TransformerLMConfig, bp: Dict[str, Array], x: Array,
+                attn_fn=None, tp_axis: Optional[str] = None,
+                expert_axis: Optional[str] = None):
+    """:func:`_block` with the causal core, for callers that scan the
+    stack themselves (the pipeline stages of parallel/transformer.py).
+    Dense FFN → returns x. MoE (cfg.n_experts > 0) → returns (x, aux)."""
+    x, _, aux = _block(cfg, bp, x, _attend_causal(attn_fn),
+                       tp_axis=tp_axis, expert_axis=expert_axis)
+    return (x, aux) if cfg.n_experts > 0 else x
+
+
+def _embed(cfg: TransformerLMConfig, params: Dict[str, Array], ids: Array,
+           positions):
+    """Token rows + position rows, in the compute dtype (the stable
+    scan-carry dtype; the blocks keep it). ``positions`` indexes the
+    position table's rows: a static slice for a whole sequence, or an
+    int array shaped like ids (cached decode: each token's own). An
+    array is CLIPPED to the table, not filled with ``jnp.take``'s
+    default NaN: a column past the table is dropped from the cache but
+    still sits in its block's softmax at probability 0, and 0 * NaN
+    would reach its row."""
+    with _scope("embed"):
+        ptab = params["pos"].at[positions].get(mode="clip")
+        x = params["embed"][ids] + ptab
+        cd = _cdtype(cfg)
+        return x if cd is None else x.astype(cd)
+
+
+def _head(cfg: TransformerLMConfig, params: Dict[str, Array], x: Array,
+          cast_logits: bool = True):
+    """Final norm + vocabulary projection on x (..., d). Logits are fp32
+    for the inference APIs; ``cast_logits=False`` keeps them in the
+    compute dtype — the loss path's choice, so no full-vocab fp32 tensor
+    is materialized (see ``token_nll``)."""
+    cd = _cdtype(cfg)
+    with _scope("head"):
+        x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
+        head = params["head"].astype(cd) if cd is not None else params["head"]
+        logits = x @ head
+        return logits.astype(jnp.float32) if cast_logits else logits
 
 
 class ContextWindowExceeded(ValueError):
@@ -451,67 +577,27 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
                   cache: Dict, ids: Array, length=None):
     """Batched prompt prefill: ids (b, Tp) int32 into a fresh cache →
     (last-position logits (b, V) fp32, cache with pos=Tp). One device
-    launch regardless of prompt length (causal attention within the
-    prompt; each layer hands its K/V out transposed to the cache's
-    (b, hn, hd, Tp) and all layers' Tp columns are written after the
-    loop as one slice); MoE routing competes all b*Tp prompt tokens,
-    exactly like ``forward``.
+    launch regardless of prompt length (:func:`_attend_prefill`: causal
+    attention within the prompt; all layers' Tp columns are written
+    after the loop as one slice); MoE routing competes all b*Tp prompt
+    tokens, exactly like ``forward``.
 
     ``length`` (traced scalar int32, <= Tp) marks the REAL prompt length
     when ids is right-padded up to a bucketed Tp: logits are gathered at
     position length-1 and the cache's pos is set to length. Causal
     attention makes end-padding exact for dense models — position i
     attends only to <= i, so pad positions can never influence real
-    ones; their K/V is written but masked from every future decode read
-    (decode reads the cache below pos) and overwritten as decoding
-    advances. The
+    ones, and their K/V is stale past pos (:func:`_attend_cached`). The
     one exception is MoE (cfg.n_experts > 0), where pad tokens compete
     for expert capacity — callers keep MoE prefill unbucketed (see
     ``TransformerLM.generate_cached``)."""
-    cd = _cdtype(cfg)
-    b, Tp = ids.shape
-    hn = cfg.n_heads
-    d = cfg.d_model
-    with _scope("embed"):
-        x = params["embed"][ids] + params["pos"][:Tp][None]
-        if cd is not None:
-            x = x.astype(cd)
-
-    kvd = cache["k"].dtype
+    Tp = ids.shape[1]
+    x = _embed(cfg, params, ids, slice(0, Tp))
+    attend = _attend_prefill(cache["k"].dtype)
 
     def body(x, bp):
-        if cd is not None:
-            bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
-                  for k2, v in bp.items()}
-        with _scope("attn"):
-            a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
-
-            def heads(W):
-                return (a_in @ W).reshape(b, Tp, hn, -1).transpose(0, 2, 1, 3)
-
-            q, k, v = heads(bp["Wq"]), heads(bp["Wk"]), heads(bp["Wv"])
-        with _scope("kv_write"):
-            kt = k.astype(kvd).transpose(0, 1, 3, 2)  # (b, hn, hd, Tp)
-            vt = v.astype(kvd).transpose(0, 1, 3, 2)
-        with _scope("attn"):
-            o = dense_attention(q, k, v, causal=True, mask=None)
-            o = o.transpose(0, 2, 1, 3).reshape(b, Tp, d).astype(x.dtype)
-            x = x + o @ bp["Wo"] + bp["bo"]
-        with _scope("mlp"):
-            m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
-            if cfg.n_experts > 0:
-                from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
-
-                y2, _aux, _load = _moe_ffn(
-                    {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
-                    m_in.reshape(b * Tp, d), jax.nn.gelu,
-                    _moe_capacity(cfg, b * Tp), cfg.top_k,
-                )
-                x = x + y2.reshape(b, Tp, d).astype(x.dtype)
-            else:
-                h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-                x = x + h @ bp["W2"] + bp["b2"]
-        return x, (kt, vt)
+        x, kv, _aux = _block(cfg, bp, x, attend)
+        return x, kv
 
     x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
     with _scope("kv_write"):
@@ -525,11 +611,8 @@ def prefill_cache(cfg: TransformerLMConfig, params: Dict[str, Array],
         pos_out = jnp.asarray(length, jnp.int32)
         x_last = jax.lax.dynamic_index_in_dim(x, pos_out - 1, axis=1,
                                               keepdims=False)
-    with _scope("head"):
-        x_last = _ln(x_last, params["lnf_g"], params["lnf_b"], cd)
-        head = params["head"].astype(cd) if cd is not None else params["head"]
-        logits = (x_last @ head).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v, "pos": pos_out}
+    return _head(cfg, params, x_last), {"k": new_k, "v": new_v,
+                                        "pos": pos_out}
 
 
 def _joint_softmax(s_slab, s_new, dtype):
@@ -566,115 +649,60 @@ def _put_columns(slab, new, wp):
     return slab
 
 
+def _decode_columns(cfg: TransformerLMConfig, params: Dict[str, Array],
+                    cache: Dict, ids_k: Array, pos: Array):
+    """The layer loop of cached decoding: ids_k (b, K) with row s's
+    column j at position pos[s] + j (pos (b,)) → (x (b, K, d) before the
+    head, the layers' new keys and values (L, b, hn, K, hd), the clamped
+    write positions (b, K)). The cache is only read
+    (:func:`_attend_cached`); the caller writes."""
+    T = cache["k"].shape[4]
+    K = ids_k.shape[1]
+    cols = pos[:, None] + jnp.arange(K)[None, :]  # (b, K) absolute pos
+    x = _embed(cfg, params, ids_k, cols)
+    live = (jnp.arange(T)[None, :] < pos[:, None])[:, None, None, :]
+    causal = jnp.arange(K)[None, :] <= jnp.arange(K)[:, None]  # (K, K)
+
+    def body(x, xs):
+        bp, kc, vc = xs  # kc/vc: (b, hn, hd, T), never written here
+        x, kv, _aux = _block(cfg, bp, x, _attend_cached(kc, vc, live, causal))
+        return x, kv
+
+    x, (ks, vs) = jax.lax.scan(
+        body, x, (params["blocks"], cache["k"], cache["v"]))
+    return x, ks, vs, jnp.minimum(cols, T - 1)
+
+
 def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
                 cache: Dict, ids_1: Array):
     """One autoregressive step: ids_1 (b,) int32 at position cache["pos"]
     → (logits (b, V) fp32, new cache). Attention reads the cached K/V
     instead of re-running the prefix — O(T) decoding vs the O(T²)
     full-forward loop; greedy-parity tested against ``forward`` in
-    tests/test_moe.py.
-
-    The cache is READ-ONLY inside the layer loop: each layer scores the
-    cached positions < pos and the step's own key (one more logit) under
-    one softmax, adds ``p_new * v_new`` to the cached part's output, and
-    hands its new (b, hn, hd) key and value out of the scan. After the
-    loop the (L, b, hn, hd) stacks are written into the cache in place
-    (``_put_columns``), at ``min(pos, T-1)``. Writing inside the loop
-    made XLA convert each layer's slice to the write's layout and back
-    and copy the whole stacked cache once more, every step.
+    tests/test_moe.py. The K = 1 case of ``decode_steps``; the cache
+    contract and the MoE caveat are :func:`_attend_cached`'s.
 
     ``cache["pos"]`` may be a scalar (every row at the same position —
     the single-request path: one column written for all rows) or a
     per-row (b,) vector (the continuous-batching engine: each slot
-    carries its own position; the mask is per-row and each row's column
-    is written at its own position). The attention math is
-    row-independent either way, so a row decoded among other slots is
-    bit-identical to the same row decoded alone (parity-asserted in
-    tests/test_generate.py).
-
-    MoE note: decode routes only the b current-step tokens (per-step
-    capacity), while the full forward competes all window tokens; when
-    training-time capacity BINDS (dropped tokens), cached decoding can
-    legitimately differ from ``generate`` — parity holds whenever no
-    token is dropped."""
-    cd = _cdtype(cfg)
+    carries its own position and its column is written there)."""
     pos = cache["pos"]
     per_row = getattr(pos, "ndim", 0) == 1
-    T = cache["k"].shape[4]
-    kvd = cache["k"].dtype
-    with _scope("embed"):
-        # clip, not jnp.take's default NaN fill: a row past the table
-        # (a draft chained over the window's end) writes the clamped
-        # column T-1, which later steps read at probability 0
-        ptab = jnp.take(params["pos"], pos, axis=0, mode="clip")
-        x = params["embed"][ids_1] + (ptab if per_row else ptab[None, :])
-        if cd is not None:
-            x = x.astype(cd)
-    b = x.shape[0]
-    hn = cfg.n_heads
-    d = cfg.d_model
-    scale = 1.0 / math.sqrt(d // hn)
-    if per_row:
-        live = (jnp.arange(T)[None, :] < pos[:, None])[:, None, :]
-    else:
-        live = (jnp.arange(T) < pos)[None, None, :]
-    wp = jnp.minimum(pos, T - 1)  # clamped write index
-
-    def body(x, xs):
-        bp, kc, vc = xs  # kc/vc: (b, hn, hd, T), never written here
-        if cd is not None:
-            bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
-                  for k2, v in bp.items()}
-        with _scope("attn"):
-            a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
-
-            def head_proj(W):
-                return (a_in @ W).reshape(b, hn, -1)
-
-            q, k, v = (head_proj(bp["Wq"]), head_proj(bp["Wk"]),
-                       head_proj(bp["Wv"]))
-            kn, vn = k.astype(kvd), v.astype(kvd)
-            s_slab = jnp.einsum("bhd,bhdt->bht", q,
-                                kc).astype(jnp.float32) * scale
-            s_slab = jnp.where(live, s_slab, -1e30)
-            s_new = jnp.einsum("bhd,bhd->bh", q,
-                               kn).astype(jnp.float32)[..., None] * scale
-            p_slab, p_new = _joint_softmax(s_slab, s_new, kvd)
-            o = jnp.einsum("bht,bhdt->bhd", p_slab, vc,
-                           preferred_element_type=jnp.float32)
-            o = o + p_new.astype(jnp.float32) * vn.astype(jnp.float32)
-            o = o.reshape(b, d).astype(x.dtype)
-            x = x + o @ bp["Wo"] + bp["bo"]
-        with _scope("mlp"):
-            m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
-            if cfg.n_experts > 0:
-                from deeplearning4j_tpu.nn.conf.layers.moe import _moe_ffn
-
-                y2, _aux, _load = _moe_ffn(
-                    {k2: bp[k2] for k2 in ("Wg", "W1", "b1", "W2", "b2")},
-                    m_in, jax.nn.gelu, _moe_capacity(cfg, b), cfg.top_k,
-                )
-                x = x + y2.astype(x.dtype)
-            else:
-                h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-                x = x + h @ bp["W2"] + bp["b2"]
-        return x, (kn, vn)
-
-    x, (ks, vs) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
-    with _scope("kv_write"):  # ks/vs: (L, b, hn, hd)
+    x, ks, vs, wp = _decode_columns(
+        cfg, params, cache, ids_1[:, None],
+        pos if per_row else jnp.broadcast_to(pos, ids_1.shape))
+    with _scope("kv_write"):
         if per_row:  # one in-place column a slot
-            new_k = _put_columns(cache["k"], ks[:, :, :, None], wp[:, None])
-            new_v = _put_columns(cache["v"], vs[:, :, :, None], wp[:, None])
+            new_k = _put_columns(cache["k"], ks, wp)
+            new_v = _put_columns(cache["v"], vs, wp)
         else:  # one column for all rows
-            at = (0, 0, 0, 0, wp)
-            new_k = jax.lax.dynamic_update_slice(cache["k"], ks[..., None], at)
-            new_v = jax.lax.dynamic_update_slice(cache["v"], vs[..., None], at)
-    with _scope("head"):
-        x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-        head = params["head"].astype(cd) if cd is not None else params["head"]
-        logits = (x @ head).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v, "pos": pos + 1}
+            at = (0, 0, 0, 0, wp[0, 0])
+            new_k = jax.lax.dynamic_update_slice(
+                cache["k"], ks[:, :, :, 0, :, None], at)
+            new_v = jax.lax.dynamic_update_slice(
+                cache["v"], vs[:, :, :, 0, :, None], at)
+    return _head(cfg, params, x[:, 0]), {"k": new_k, "v": new_v,
+                                         "pos": pos + 1}
 
 
 def decode_steps(cfg: TransformerLMConfig, params: Dict[str, Array],
@@ -688,93 +716,23 @@ def decode_steps(cfg: TransformerLMConfig, params: Dict[str, Array],
     the distribution token-by-token decode would have produced, which is
     what makes speculative acceptance exact.
 
-    As in ``decode_step`` the cache is read-only inside the layer loop:
-    column j attends to the cached positions < pos and, causally, to
-    columns 0..j of its own block, under one softmax. All K columns are
-    written after the loop (``_put_columns``), and a column whose
-    absolute position falls past the cache (pos+j >= T) is DROPPED,
-    never left clipped over the real write of a row whose final token
-    sits exactly at the edge. Callers must never ACCEPT a column at
-    pos+j > T-1 (its position embedding is clipped); the engine clamps
-    draft lengths to the window. A row at pos >= T is outside the
-    contract, as in ``decode_step``: its clamped write stays on T-1.
-
-    Rejected-draft "rollback" is free: stale K/V at and past the
-    accepted position is never read (reads stop below pos') and each
-    later dispatch rewrites its columns contiguously from pos', so
-    garbage is always overwritten before it becomes visible.
-
-    MoE is unsupported (routing would compete b*K tokens per step where
-    sequential decode competes b — acceptance would no longer be exact);
-    callers keep MoE engines at k=1."""
+    All K columns are written after the loop; the cache contract is
+    :func:`_attend_cached`'s. Callers must never ACCEPT a column at
+    pos+j > T-1; the engine clamps draft lengths to the window. A row at
+    pos >= T is outside the contract, as in ``decode_step``: its clamped
+    write stays on T-1. MoE is unsupported; callers keep MoE engines at
+    k=1."""
     if cfg.n_experts > 0:
         raise ValueError("decode_steps does not support MoE models "
                          "(per-step routing capacity differs from "
                          "sequential decode); use decode_step")
-    cd = _cdtype(cfg)
     pos = cache["pos"]
-    T = cache["k"].shape[4]
-    kvd = cache["k"].dtype
-    b, K = ids_k.shape
-    hn = cfg.n_heads
-    d = cfg.d_model
-    scale = 1.0 / math.sqrt(d // hn)
-    cols = pos[:, None] + jnp.arange(K)[None, :]  # (b, K) absolute pos
-    with _scope("embed"):
-        # clip, not jnp.take's default NaN fill: a column past the table
-        # is dropped from the cache but still sits in its block's
-        # softmax at probability 0, and 0 * NaN would reach its row
-        ptab = jnp.take(params["pos"], cols, axis=0, mode="clip")
-        x = params["embed"][ids_k] + ptab
-        if cd is not None:
-            x = x.astype(cd)
-    live = (jnp.arange(T)[None, :] < pos[:, None])[:, None, None, :]
-    causal = jnp.arange(K)[None, :] <= jnp.arange(K)[:, None]  # (K, K)
-
-    def body(x, xs):
-        bp, kc, vc = xs  # kc/vc: (b, hn, hd, T), never written here
-        if cd is not None:
-            bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
-                  for k2, v in bp.items()}
-        with _scope("attn"):
-            a_in = _ln(x, bp["ln1_g"], bp["ln1_b"], cd)
-
-            def head_proj(W):  # (b, hn, K, hd)
-                return (a_in @ W).reshape(b, K, hn, -1).transpose(0, 2, 1, 3)
-
-            q, k, v = (head_proj(bp["Wq"]), head_proj(bp["Wk"]),
-                       head_proj(bp["Wv"]))
-            kn, vn = k.astype(kvd), v.astype(kvd)
-            s_slab = jnp.einsum("bhkd,bhdt->bhkt", q,
-                                kc).astype(jnp.float32) * scale
-            s_slab = jnp.where(live, s_slab, -1e30)
-            s_new = jnp.einsum("bhkd,bhjd->bhkj", q,
-                               kn).astype(jnp.float32) * scale
-            s_new = jnp.where(causal, s_new, -1e30)
-            p_slab, p_new = _joint_softmax(s_slab, s_new, kvd)
-            o = jnp.einsum("bhkt,bhdt->bhkd", p_slab, vc,
-                           preferred_element_type=jnp.float32)
-            o = o + jnp.einsum("bhkj,bhjd->bhkd", p_new, vn,
-                               preferred_element_type=jnp.float32)
-            o = o.transpose(0, 2, 1, 3).reshape(b, K, d).astype(x.dtype)
-            x = x + o @ bp["Wo"] + bp["bo"]
-        with _scope("mlp"):
-            m_in = _ln(x, bp["ln2_g"], bp["ln2_b"], cd)
-            h = jax.nn.gelu(m_in @ bp["W1"] + bp["b1"])
-            x = x + h @ bp["W2"] + bp["b2"]
-        return x, (kn, vn)
-
-    x, (ks, vs) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
-    with _scope("kv_write"):  # ks/vs: (L, b, hn, K, hd)
-        wp = jnp.minimum(cols, T - 1)
+    x, ks, vs, wp = _decode_columns(cfg, params, cache, ids_k, pos)
+    with _scope("kv_write"):
         new_k = _put_columns(cache["k"], ks, wp)
         new_v = _put_columns(cache["v"], vs, wp)
-    with _scope("head"):
-        x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-        head = params["head"].astype(cd) if cd is not None else params["head"]
-        logits = (x @ head).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v, "pos": pos + K}
+    return _head(cfg, params, x), {"k": new_k, "v": new_v,
+                                   "pos": pos + ids_k.shape[1]}
 
 
 def prefill_bucket_lengths(max_length: int, hint=None):
@@ -804,35 +762,19 @@ def forward(cfg: TransformerLMConfig, params: Dict[str, Array], ids: Array,
             cast_logits: bool = True):
     """ids (b, T) int32 → logits (b, T, V) [, total MoE aux loss].
     Single-device path: blocks via lax.scan over the stacked layer axis.
-    ``cast_logits=False`` keeps logits in the compute dtype — the loss
-    path's choice, so no full-vocab fp32 tensor is materialized (see
-    ``token_nll``)."""
-    cd = _cdtype(cfg)
-    with _scope("embed"):
-        x = params["embed"][ids] + params["pos"][pos_offset:pos_offset + ids.shape[1]][None]
-        if cd is not None:
-            x = x.astype(cd)  # stable scan-carry dtype; blocks keep it bf16
+    ``cast_logits``: see :func:`_head`."""
+    x = _embed(cfg, params, ids,
+               slice(pos_offset, pos_offset + ids.shape[1]))
+    attend = _attend_causal(attn_fn)
 
-    if cfg.n_experts > 0:
-        def body(carry, bp):
-            x, aux = carry
-            x, a = block_apply(cfg, bp, x, attn_fn=attn_fn)
-            return (x, aux + a), None
+    def body(carry, bp):
+        x, aux = carry
+        x, _kv, a = _block(cfg, bp, x, attend)
+        return (x, aux + a), None
 
-        (x, aux), _ = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
-    else:
-        def body(x, bp):
-            return block_apply(cfg, bp, x, attn_fn=attn_fn), None
-
-        x, _ = jax.lax.scan(body, x, params["blocks"])
-        aux = jnp.zeros((), jnp.float32)
-    with _scope("head"):
-        x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-        head = params["head"].astype(cd) if cd is not None else params["head"]
-        logits = x @ head
-        if cast_logits:
-            logits = logits.astype(jnp.float32)  # inference APIs: fp32 logits
+    (x, aux), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
+    logits = _head(cfg, params, x, cast_logits)
     if return_aux:
         return logits, aux
     return logits

@@ -397,29 +397,24 @@ class DistributedLMTrainer:
 
     def _loss_fn(self):
         from deeplearning4j_tpu.models.transformer_lm import (
-            _cdtype,
-            _ln,
+            _embed,
+            _head,
             token_nll,
         )
 
         cfg = self.cfg
         blocks_fn = self._blocks_fn()
         moe = cfg.n_experts > 0
-        cd = _cdtype(cfg)
 
         def loss(params, ids, targets):
-            x = params["embed"][ids] + params["pos"][: ids.shape[1]][None]
-            if cd is not None:
-                x = x.astype(cd)  # stable scan-carry dtype (block_apply
-                # keeps the carried activation bf16), as in forward()
+            x = _embed(cfg, params, ids, slice(0, ids.shape[1]))
             out = blocks_fn(params["blocks"], x)
             x, aux = out if moe else (out, None)
-            x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-            head = params["head"].astype(cd) if cd is not None else params["head"]
             # compute-dtype logits into the lse - target-logit CE (no
             # full-vocab fp32 log-prob tensor; see models.transformer_lm
             # token_nll)
-            l, _ = token_nll(x @ head, targets)
+            l, _ = token_nll(_head(cfg, params, x, cast_logits=False),
+                             targets)
             if moe:
                 l = l + cfg.aux_loss_weight * aux
             return l

@@ -608,11 +608,12 @@ class TestKVCacheDecoding:
             m.generate_cached(ids[:1, :10], max_new=10)
 
     _BY_DTYPE = {}
+    _KINDS = {"float32": {}, "bfloat16": {"compute_dtype": "bfloat16"},
+              "moe": {"n_experts": 4, "capacity_factor": 2.0}}
 
     def _trained_once(self, dtype):
         if dtype not in self._BY_DTYPE:
-            kw = {} if dtype == "float32" else {"compute_dtype": dtype}
-            self._BY_DTYPE[dtype] = self._trained(**kw)
+            self._BY_DTYPE[dtype] = self._trained(**self._KINDS[dtype])
         return self._BY_DTYPE[dtype]
 
     @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
@@ -659,6 +660,57 @@ class TestKVCacheDecoding:
             assert np.flatnonzero(changed).tolist() == [at]
         assert int(new_s["pos"]) == at + 1
         assert np.asarray(new_r["pos"]).tolist() == [at + 1] * b
+
+    @pytest.mark.parametrize("kind", ["float32", "bfloat16", "moe"])
+    def test_every_entry_point_runs_the_one_block_body(self, kind,
+                                                       monkeypatch):
+        # forward, prefill_cache, decode_step (scalar and per-row pos)
+        # and decode_steps have no block of their own: each traces
+        # through transformer_lm._block, on (b, T, d) with T the
+        # sequence, 1 or K. So decode_steps at K = 1 IS decode_step per
+        # row: the same logits and the same written column, bit for bit.
+        from deeplearning4j_tpu.models import transformer_lm as tlm
+
+        m, ids = self._trained_once(kind)
+        cfg, p, b, at, K = m.cfg, m.params_, 3, 5, 4
+        ids = jnp.asarray(ids[:b])
+        seen = []
+
+        def counted(cfg_, bp, x, attend, *a, body=tlm._block, **kw):
+            seen.append(x.shape)
+            return body(cfg_, bp, x, attend, *a, **kw)
+
+        monkeypatch.setattr(tlm, "_block", counted)
+
+        def through_block(T, fn, *args):
+            del seen[:]
+            out = fn(cfg, p, *args)
+            assert seen and set(seen) == {(b, T, cfg.d_model)}, seen
+            return out
+
+        through_block(at, tlm.forward, ids[:, :at])
+        _, cache = through_block(at, tlm.prefill_cache,
+                                 tlm.init_decode_cache(cfg, b), ids[:, :at])
+        through_block(1, tlm.decode_step, cache, ids[:, at])
+        rows = {**cache, "pos": jnp.full((b,), at, jnp.int32)}
+        got_r, new_r = through_block(1, tlm.decode_step, rows, ids[:, at])
+        if kind == "moe":
+            with pytest.raises(ValueError, match="MoE"):
+                tlm.decode_steps(cfg, p, rows, ids[:, at:at + K])
+            return
+        through_block(K, tlm.decode_steps, rows, ids[:, at:at + K])
+        got_1, new_1 = through_block(1, tlm.decode_steps, rows,
+                                     ids[:, at:at + 1])
+        np.testing.assert_array_equal(np.asarray(got_1[:, 0]),
+                                      np.asarray(got_r))
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(new_1[name], np.float32),
+                np.asarray(new_r[name], np.float32))
+            changed = np.any(np.asarray(new_1[name] != cache[name]),
+                             axis=(0, 1, 2, 3))
+            assert np.flatnonzero(changed).tolist() == [at]
+        assert np.asarray(new_1["pos"]).tolist() == [at + 1] * b
 
     @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
                                             ("bfloat16", 0.15)])
