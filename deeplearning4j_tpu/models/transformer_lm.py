@@ -152,6 +152,62 @@ def _cdtype(cfg: TransformerLMConfig):
     return jnp.bfloat16 if cfg.compute_dtype == "bfloat16" else None
 
 
+def _matmul_leaves(level: Dict) -> list:
+    """THE rule of which parameters the matmuls read in the compute
+    dtype, as the keys of one level of a params tree (the top level, or
+    a block's dict, stacked or one layer's): a block's ``W*`` and ``b*``
+    (``Wq Wk Wv Wo bo W1 b1 W2 b2``, ``Wg`` for MoE) and ``head``. Norm
+    gains and shifts, ``embed`` and ``pos`` stay float32: the embedding
+    adds two float32 rows and casts the sum, so a bf16 table would be a
+    different result."""
+    return [k for k, v in level.items()
+            if not isinstance(v, dict) and (k == "head" or k[0] in "Wb")]
+
+
+def _cast_matmul_leaves(cd, level: Dict) -> Dict:
+    """``level`` with its :func:`_matmul_leaves` in ``cd``, every other
+    entry the same object; ``level`` itself for ``cd`` None. A leaf that
+    already has the dtype comes back as itself (``astype`` to an array's
+    own dtype is the identity, traced or not), so a program handed the
+    :func:`serving_copy` casts nothing."""
+    if cd is None:
+        return level
+    return {**level, **{k: level[k].astype(cd) for k in _matmul_leaves(level)}}
+
+
+_astype_tree = jax.jit(
+    lambda tree, cd: jax.tree_util.tree_map(lambda a: a.astype(cd), tree),
+    static_argnums=1)
+
+
+def serving_copy(cfg: TransformerLMConfig, params: Dict) -> Dict:
+    """The params tree a serving program is handed in place of the
+    float32 masters: the leaves :func:`_block` and :func:`_head` would
+    cast on every call (:func:`_matmul_leaves`) cast ONCE, by the same
+    ``astype``, in one jitted program (elementwise: a sharded master's
+    copy keeps its sharding); every other leaf (``embed``, ``pos``, the
+    norms) the master itself, no second buffer. Bitwise what the
+    programs computed from the masters. Under ``compute_dtype=None``, or
+    with nothing left to cast, the tree itself. The masters are neither
+    altered nor dropped: whoever keeps the copy re-makes it when
+    ``params`` changes (``serving/generate.py``:
+    ``_TransformerBackend._params``)."""
+    cd = _cdtype(cfg)
+    if cd is None:
+        return params
+
+    def masters(level):
+        return {k: level[k] for k in _matmul_leaves(level)
+                if level[k].dtype != cd}
+
+    blocks = params["blocks"]
+    stale = masters(params), masters(blocks)
+    if not any(stale):
+        return params
+    top, inner = _astype_tree(stale, cd)
+    return {**params, **top, "blocks": {**blocks, **inner}}
+
+
 def _ln(x, g, b, cd):
     """LayerNorm with fp32 statistics under mixed precision (the same
     exemption the layer stack's norm layers use)."""
@@ -186,8 +242,7 @@ def _block(cfg: TransformerLMConfig, bp: Dict[str, Array], x: Array, attend,
     cd = _cdtype(cfg)
     if cd is not None:
         x = x.astype(cd)
-        bp = {k2: (v.astype(cd) if k2[0] in ("W", "b") else v)
-              for k2, v in bp.items()}
+    bp = _cast_matmul_leaves(cd, bp)
     # under manual TP the head projections are column slices: this
     # shard owns d_local/head_dim of the hn heads
     d_local = bp["Wq"].shape[-1]
@@ -348,8 +403,7 @@ def _head(cfg: TransformerLMConfig, params: Dict[str, Array], x: Array,
     cd = _cdtype(cfg)
     with _scope("head"):
         x = _ln(x, params["lnf_g"], params["lnf_b"], cd)
-        head = params["head"].astype(cd) if cd is not None else params["head"]
-        logits = x @ head
+        logits = x @ _cast_matmul_leaves(cd, params)["head"]
         return logits.astype(jnp.float32) if cast_logits else logits
 
 

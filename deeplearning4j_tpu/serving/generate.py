@@ -408,7 +408,8 @@ class _TransformerBackend:
 
     def __init__(self, model, n_slots: int, max_length: Optional[int],
                  prefill_buckets: Optional[Sequence[int]], trace_hook,
-                 spec_k: int = 1, draft_layers: int = 0):
+                 spec_k: int = 1, draft_layers: int = 0,
+                 on_param_cast: Callable[[], None] = lambda: None):
         from deeplearning4j_tpu.models.transformer_lm import (
             decode_step,
             decode_steps,
@@ -420,6 +421,11 @@ class _TransformerBackend:
         )
 
         self.model = model
+        #: the weights the programs read (:meth:`_params`), the master
+        #: leaves they were made from, and who counts a cast
+        self._copy = None
+        self._copy_of: list = []
+        self._on_param_cast = on_param_cast
         cfg = model.cfg
         self.n_slots = int(n_slots)
         self.max_length = (cfg.max_length if max_length is None
@@ -585,8 +591,33 @@ class _TransformerBackend:
             self._dvc = self._vc[:0]
 
     def release(self) -> None:
-        """Let the slabs go (engine shutdown)."""
+        """Let the slabs and the serving copy go (engine shutdown)."""
         self._kc = self._vc = self._dkc = self._dvc = None
+        self._copy, self._copy_of = None, []
+
+    def _params(self) -> dict:
+        """What every program reads as its weights: the model's
+        ``serving_copy`` (block matrices and head in the compute dtype,
+        the rest the float32 masters themselves), made by one cast
+        program the first time it is asked for (warm-up) and kept
+        beside the master leaves it was made from. Re-made when, and
+        only when, the leaves of ``model.params_`` are not those objects
+        any more: a swap of ``params_`` is served at the next token for
+        one cast, no recompile, and a step on unchanged weights compares
+        ~16 identities. Under ``compute_dtype=None`` the copy is the
+        tree itself and nothing is counted."""
+        from deeplearning4j_tpu.models.transformer_lm import serving_copy
+
+        params = self.model.params_
+        leaves = jax.tree_util.tree_leaves(params)
+        if len(leaves) != len(self._copy_of) or any(
+                a is not b for a, b in zip(leaves, self._copy_of)):
+            self._copy = None  # the old copy goes before the new is made
+            self._copy = serving_copy(self._cfg, params)
+            self._copy_of = leaves
+            if self._copy is not params:
+                self._on_param_cast()
+        return self._copy
 
     def bucket_for(self, prompt_len: int) -> int:
         return next(t for t in self.buckets if t >= prompt_len)
@@ -614,7 +645,7 @@ class _TransformerBackend:
                     jnp.asarray(top_p, jnp.float32), jnp.asarray(key))
         tok0, key, self._kc, self._vc, self._dkc, self._dvc, logits0 = \
             self._prefill_fn(
-                self.model.params_, self._kc, self._vc, self._dkc,
+                self._params(), self._kc, self._vc, self._dkc,
                 self._dvc, *args)
         del args  # see decode
         return int(tok0), np.asarray(key), tb, logits0
@@ -630,7 +661,7 @@ class _TransformerBackend:
                     jnp.asarray(keys))
         with _DECODE_DISPATCH:
             nxt, nkeys, self._kc, self._vc = self._decode_fn(
-                self.model.params_, self._kc, self._vc, *args)
+                self._params(), self._kc, self._vc, *args)
             # let the step's input buffers go while it runs, as the
             # call temporaries they were before the put had a phase of
             # its own: held until this returns, they are freed on this
@@ -653,7 +684,7 @@ class _TransformerBackend:
                     jnp.asarray(top_p), jnp.asarray(keys))
         with _DECODE_DISPATCH:
             s, e, last, nkeys, self._kc, self._vc = self._verify_fn(
-                self.model.params_, self._kc, self._vc, *args)
+                self._params(), self._kc, self._vc, *args)
             del args  # see decode
         with _DECODE_FETCH:
             return (np.asarray(s), np.asarray(e), np.asarray(last),
@@ -667,7 +698,7 @@ class _TransformerBackend:
                     jnp.asarray(active))
         with _DECODE_DISPATCH:
             drafts, self._dkc, self._dvc = self._draft_fn(
-                self.model.params_, self._dkc, self._dvc, *args)
+                self._params(), self._dkc, self._dvc, *args)
             del args  # see decode
         with _DECODE_FETCH:
             return np.asarray(drafts)
@@ -1166,8 +1197,8 @@ class _RecurrentBackend:
 
 
 def _pick_backend(model, n_slots, max_length, prefill_buckets, trace_hook,
-                  cell_path: Optional[bool] = None, spec_k: int = 1,
-                  draft_layers: int = 0):
+                  on_param_cast, cell_path: Optional[bool] = None,
+                  spec_k: int = 1, draft_layers: int = 0):
     from deeplearning4j_tpu.models.decoder_lm import DecoderLM
     from deeplearning4j_tpu.models.transformer_lm import TransformerLM
 
@@ -1175,7 +1206,8 @@ def _pick_backend(model, n_slots, max_length, prefill_buckets, trace_hook,
         return _TransformerBackend(model, n_slots, max_length,
                                    prefill_buckets, trace_hook,
                                    spec_k=spec_k,
-                                   draft_layers=draft_layers)
+                                   draft_layers=draft_layers,
+                                   on_param_cast=on_param_cast)
     if isinstance(model, DecoderLM):
         return _DecoderBackend(model, n_slots, max_length, prefill_buckets,
                                trace_hook)
@@ -1204,14 +1236,21 @@ def generation_memory_report(model, n_slots: int,
                              draft_layers: int = 0) -> dict:
     """Analytic 'will the decode slab fit' answer BEFORE allocating it —
     the nn/conf/memory.py estimator discipline applied to generation
-    state: per-slot cache bytes × n_slots + resident params.
-    ``draft_layers`` > 0 adds the truncated-layer speculation slab (the
-    draft model keeps its own KV over the first ``draft_layers``
-    blocks)."""
+    state: per-slot cache bytes × n_slots + resident params + for a
+    ``TransformerLM`` under a compute dtype ``param_copy_bytes``, the
+    copy of the block matrices and the head its programs read
+    (``transformer_lm.serving_copy``; the float32 masters stay
+    resident beside it). ``draft_layers`` > 0 adds the truncated-layer
+    speculation slab (the draft model keeps its own KV over the first
+    ``draft_layers`` blocks)."""
     from deeplearning4j_tpu.models.decoder_lm import DecoderLM
-    from deeplearning4j_tpu.models.transformer_lm import TransformerLM
+    from deeplearning4j_tpu.models.transformer_lm import (
+        TransformerLM,
+        serving_copy,
+    )
 
     plan = None
+    param_copy = 0
     if isinstance(model, (TransformerLM, DecoderLM)):
         cfg = model.cfg
         T = cfg.max_length if max_length is None else min(int(max_length),
@@ -1228,6 +1267,13 @@ def generation_memory_report(model, n_slots: int,
             itemsize = 2 if cfg.compute_dtype == "bfloat16" else 4
             cache = 2 * (cfg.n_layers + int(draft_layers)) * int(n_slots) \
                 * cfg.n_heads * T * hd * itemsize
+            copy = jax.eval_shape(lambda p: serving_copy(cfg, p),
+                                  model.params_)
+            param_copy = sum(
+                int(np.prod(c.shape)) * c.dtype.itemsize
+                for m, c in zip(jax.tree_util.tree_leaves(model.params_),
+                                jax.tree_util.tree_leaves(copy))
+                if c.dtype != m.dtype)
     else:
         # recurrent nets: the carry is the decode state; lean on the
         # layer-wise estimator for params + per-slot activation state
@@ -1239,7 +1285,8 @@ def generation_memory_report(model, n_slots: int,
                                           training=False) - params
         cache = max(cache, 0)
     out = {"cache_bytes": int(cache), "param_bytes": int(params),
-           "total_bytes": int(cache) + int(params),
+           "param_copy_bytes": int(param_copy),
+           "total_bytes": int(cache) + int(params) + int(param_copy),
            "n_slots": int(n_slots), "max_length": max_length}
     if plan is not None:
         out["cache_plan"] = [
@@ -1268,8 +1315,13 @@ class GenerationEngine:
     One background worker owns ALL device state (slab / carries, under
     ``_dev_lock``); callers only touch the bounded admission queue and
     their own :class:`GenerationRequest`. Hot params reload composes:
-    the jitted programs read ``model.params_`` per dispatch, so an
-    atomic params swap (same shapes) takes effect at the next token.
+    every dispatch looks at ``model.params_``, so an atomic params swap
+    (same shapes) takes effect at the next token, zero recompiles. A
+    ``TransformerLM`` under a compute dtype pays one cast program a
+    swap, not one a dispatch: its programs read a copy of the block
+    matrices and the head in that dtype, re-made when the leaves of
+    ``params_`` are other objects (``_TransformerBackend._params``;
+    ``param_casts`` in the metrics counts them).
 
     ``memory_limit_bytes``: explicit budget, ``"auto"`` (device
     ``bytes_limit`` when the backend reports one, else unchecked), or
@@ -1357,6 +1409,7 @@ class GenerationEngine:
         #: leg). Ignored by the transformer backend.
         self.backend = _pick_backend(model, n_slots, max_length,
                                      prefill_buckets, trace_hook,
+                                     self.metrics.record_param_cast,
                                      cell_path=decode_cell_path,
                                      spec_k=int(spec_decode_k),
                                      draft_layers=draft_layers)
@@ -1418,7 +1471,8 @@ class GenerationEngine:
             raise GenerationMemoryError(
                 f"decode slab needs {self.memory_report['cache_bytes']:,} "
                 f"cache bytes (+{self.memory_report['param_bytes']:,} "
-                f"params) for n_slots={self.n_slots} × "
+                f"params, +{self.memory_report['param_copy_bytes']:,} "
+                f"their serving copy) for n_slots={self.n_slots} × "
                 f"max_length={self.backend.max_length}, over the "
                 f"{limit:,}-byte budget; lower n_slots or max_length")
 

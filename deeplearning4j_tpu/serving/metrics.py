@@ -325,6 +325,10 @@ class GenerationMetrics:
             "generation_moe_experts_hit_total",
             "held experts with at least one pair, summed over layers "
             "and decode steps")
+        self._param_casts = reg.counter(
+            "generation_param_casts_total",
+            "times the transformer backend made its compute-dtype copy "
+            "of the weights: 1 after warm-up, +1 a swap of params_")
         self._hit_rate_gauge = None
         #: lookups before the hit-rate gauge materializes (and the
         #: prefix_hit_rate_low rule can fire)
@@ -370,6 +374,9 @@ class GenerationMetrics:
             self._moe_pairs.inc(int(pairs_local))
         if experts_hit:
             self._moe_hit.inc(int(experts_hit))
+
+    def record_param_cast(self) -> None:
+        self._param_casts.inc()
 
     def record_first_token(self) -> None:
         self._tokens.inc()
@@ -468,6 +475,7 @@ class GenerationMetrics:
             "prefill_flops_avoided": int(self._flops_avoided.value()),
             "moe_pairs_local": int(self._moe_pairs.value()),
             "moe_experts_hit": int(self._moe_hit.value()),
+            "param_casts": int(self._param_casts.value()),
             "latency_window": n,
         }
         for name, q in (("p50", 0.50), ("p90", 0.90), ("p99", 0.99)):

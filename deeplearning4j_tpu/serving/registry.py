@@ -1707,8 +1707,10 @@ class ModelRouter:
                          old: Optional[_VersionedEngine]) -> None:
         """Point the model's generation engine at the promoted weights.
         Same architecture → atomic params swap on the bound model object
-        (the jitted decode programs read ``params_`` per dispatch, so
-        the swap takes effect at the next token, zero recompiles);
+        (every dispatch looks at ``params_``, so the swap takes effect
+        at the next token, zero recompiles; a transformer backend that
+        serves a compute-dtype copy of the weights re-makes it then:
+        one cast program a swap);
         different architecture → retire and rebuild lazily."""
         gen = mm.generation
         if gen is None:

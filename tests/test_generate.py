@@ -556,7 +556,15 @@ class TestGenerationEngine:
         rep = _engine().memory_report
         assert rep["cache_bytes"] > 0
         assert rep["param_bytes"] > 0
+        assert rep["param_copy_bytes"] == 0  # float32 compute: no copy
         assert rep["total_bytes"] == rep["cache_bytes"] + rep["param_bytes"]
+        rep = _bf16_engine().memory_report
+        copy = _bf16_engine().backend._params()
+        assert rep["param_copy_bytes"] == sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(copy)
+            if a.dtype != np.float32) > 0
+        assert rep["total_bytes"] == (rep["cache_bytes"] + rep["param_bytes"]
+                                      + rep["param_copy_bytes"])
 
     def test_flight_events_slot_lifecycle(self):
         from deeplearning4j_tpu.obs.flight import default_flight_recorder
@@ -894,6 +902,218 @@ class TestSpeculativePrefix:
 
 
 # ---------------------------------------------------------------------------
+# the serving copy: weights cast once, not in every program
+# ---------------------------------------------------------------------------
+_BF16 = {}
+
+
+def _bf16_lm(seed=5, **kw) -> TransformerLM:
+    return TransformerLM(vocab_size=48, d_model=32, n_heads=2, n_layers=2,
+                         max_length=48, seed=seed,
+                         compute_dtype="bfloat16", **kw).init()
+
+
+def _bf16_engine() -> GenerationEngine:
+    """Module-shared engine on float32 masters under bf16 compute, with
+    every program the backend has: K = 3 verify, the truncated draft."""
+    if "e" not in _BF16:
+        e = GenerationEngine(_bf16_lm(), n_slots=3, queue_limit=32,
+                             default_timeout_s=120.0, spec_decode_k=3,
+                             draft_mode="truncated")
+        e.warmup()
+        _BF16["e"] = e
+    return _BF16["e"]
+
+
+def _casts_to_bf16(jaxpr) -> int:
+    """float32 -> bfloat16 ``convert_element_type``s in a jaxpr and the
+    jaxprs its equations carry (a scan's body, a jit's)."""
+    import jax.numpy as jnp
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "convert_element_type"
+                and eqn.params["new_dtype"] == jnp.bfloat16
+                and eqn.invars[0].aval.dtype == jnp.float32):
+            n += 1
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                n += _casts_to_bf16(inner)
+    return n
+
+
+class TestServingCopy:
+    """``_TransformerBackend`` hands its programs ``serving_copy`` (the
+    block matrices and the head cast once) in place of the float32
+    masters: bitwise the same results, one cast a changed tree."""
+
+    @pytest.mark.parametrize("program", ["prefill", "decode", "verify",
+                                         "draft"])
+    def test_programs_bitwise_as_on_the_masters(self, program):
+        # each program the backend has, on the copy and on the float32
+        # masters themselves (where it casts per call, as it did for
+        # every dispatch before there was a copy), from one slab state
+        import jax.numpy as jnp
+
+        eng = _bf16_engine()
+        b, S, K = eng.backend, eng.n_slots, eng.spec_decode_k
+        masters, copy = b.model.params_, b._params()
+        assert copy["head"].dtype == jnp.bfloat16
+        assert masters["head"].dtype == jnp.float32
+        key = np.asarray(jax.random.PRNGKey(0))
+        prompts = _prompts(S, (5, 15), seed=11)
+        with eng._dev_lock:  # the idle worker dispatches nothing
+            for slot, prompt in enumerate(prompts):
+                b.prefill(slot, prompt, 0.0, 0, 0.0, key)
+            pos = jnp.asarray([p.shape[0] for p in prompts], jnp.int32)
+
+            def slabs():  # the programs consume the slabs they are given
+                return [jnp.copy(a) for a in (b._kc, b._vc, b._dkc, b._dvc)]
+
+            toks = jnp.asarray([7, 8, 9], jnp.int32)
+            on = jnp.ones((S,), bool)
+            policy = (jnp.zeros((S,), jnp.float32),
+                      jnp.zeros((S,), jnp.int32),
+                      jnp.zeros((S,), jnp.float32))
+            keys = jnp.zeros((S, 2), jnp.uint32)
+
+            def run(p):
+                kc, vc, dkc, dvc = slabs()
+                if program == "prefill":
+                    return b._prefill_fn(
+                        p, kc, vc, dkc, dvc,
+                        jnp.asarray(np.arange(16)[None], jnp.int32),
+                        jnp.asarray(11, jnp.int32),
+                        jnp.asarray(1, jnp.int32),
+                        *(a[0] for a in policy), keys[0])
+                if program == "decode":
+                    return b._decode_fn(p, kc, vc, toks, pos, on, *policy,
+                                        keys)
+                if program == "verify":
+                    return b._verify_fn(
+                        p, kc, vc,
+                        jnp.asarray([[7, 1, 2], [8, 3, 4], [9, 5, 6]],
+                                    jnp.int32),
+                        jnp.full((S,), K - 1, jnp.int32), pos, on, *policy,
+                        keys)
+                return b._draft_fn(p, dkc, dvc, toks, pos, on)
+
+            got, want = run(copy), run(masters)
+        assert len(got) == len(want)
+        for g, w in zip(got, want):  # tokens, logits, keys, slabs
+            assert g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    def test_a_params_swap_is_one_cast_and_no_retrace(self):
+        m, other = _bf16_lm(seed=5), _bf16_lm(seed=6)
+        eng = GenerationEngine(m, n_slots=2, default_timeout_s=120.0)
+        ref = GenerationEngine(other, n_slots=2, default_timeout_s=120.0)
+        try:
+            eng.warmup()
+            assert eng.metrics.snapshot()["param_casts"] == 1
+            traced = dict(eng.trace_counts)
+            prompt = _prompts(1, seed=4)[0]
+            first = eng.submit(prompt, max_new=8).result(timeout=90)
+            eng.submit(prompt, max_new=8).result(timeout=90)
+            # sixteen steps and two prefills on unchanged weights
+            assert eng.metrics.snapshot()["param_casts"] == 1
+            m.params_ = other.params_
+            swapped = eng.submit(prompt, max_new=8).result(timeout=90)
+            eng.submit(prompt, max_new=8).result(timeout=90)
+            assert eng.metrics.snapshot()["param_casts"] == 2
+            assert eng.trace_counts == traced
+            np.testing.assert_array_equal(
+                swapped, ref.submit(prompt, max_new=8).result(timeout=90))
+            assert not np.array_equal(swapped, first)
+            assert "generation_param_casts_total 2" in \
+                eng.metrics.registry.prometheus_text()
+        finally:
+            eng.shutdown()
+            ref.shutdown()
+
+    @pytest.mark.parametrize("kind", ["dense", "moe", "float32"])
+    def test_copy_casts_what_the_block_and_head_cast(self, kind):
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.models.transformer_lm import (
+            TransformerLMConfig,
+            forward,
+            init_params,
+            serving_copy,
+        )
+
+        cfg = TransformerLMConfig(
+            vocab_size=48, d_model=32, n_heads=2, n_layers=2, max_length=16,
+            n_experts=4 if kind == "moe" else 0,
+            compute_dtype=None if kind == "float32" else "bfloat16")
+        masters = init_params(cfg)
+        copy = serving_copy(cfg, masters)
+        ids = jnp.asarray(np.arange(12).reshape(2, 6), jnp.int32)
+
+        def casts(p):
+            return _casts_to_bf16(
+                jax.make_jaxpr(lambda q: forward(cfg, q, ids))(p).jaxpr)
+
+        if kind == "float32":
+            assert copy is masters and casts(masters) == 0
+            return
+        cast = {"Wq", "Wk", "Wv", "Wo", "bo", "W1", "b1", "W2", "b2"} \
+            | ({"Wg"} if kind == "moe" else set())
+        for name, leaf in masters["blocks"].items():
+            if name in cast:
+                np.testing.assert_array_equal(
+                    np.asarray(copy["blocks"][name]),
+                    np.asarray(leaf.astype(jnp.bfloat16)))
+            else:
+                assert copy["blocks"][name] is leaf, name
+        for name in ("embed", "pos", "lnf_g", "lnf_b"):
+            assert copy[name] is masters[name], name
+        assert copy["head"].dtype == jnp.bfloat16
+        assert set(copy) == set(masters)
+        assert set(copy["blocks"]) == set(masters["blocks"])
+        # each of them is a cast the forward no longer makes, and it
+        # makes no other of a parameter: what is left is activations'
+        assert casts(masters) - casts(copy) == len(cast) + 1
+        assert serving_copy(cfg, copy) is copy
+        np.testing.assert_array_equal(
+            np.asarray(forward(cfg, copy, ids)),
+            np.asarray(forward(cfg, masters, ids)))
+
+    def test_copy_keeps_the_masters_shardings(self):
+        from deeplearning4j_tpu.parallel.serving_mesh import ServingMesh
+        from deeplearning4j_tpu.serving.sharded import (
+            sharded_generation_engine,
+        )
+
+        mesh = ServingMesh(batch=2, model=4, devices=jax.devices()[:8])
+        lm = TransformerLM(vocab_size=64, d_model=32, n_heads=4, n_layers=2,
+                           max_length=48, seed=9,
+                           compute_dtype="bfloat16").init()
+        eng = sharded_generation_engine(lm, mesh, n_slots=4, max_length=48)
+        try:
+            masters = eng.backend.model.params_
+            copy = eng.backend._params()
+            flat_m = jax.tree_util.tree_leaves_with_path(masters)
+            flat_c = jax.tree_util.tree_leaves(copy)
+            assert any("model" in str(m.sharding.spec) for _, m in flat_m)
+            for (path, m), c in zip(flat_m, flat_c):
+                assert c.sharding.is_equivalent_to(m.sharding, m.ndim), path
+            assert eng.memory_report["param_copy_bytes"] == sum(
+                c.nbytes for (_, m), c in zip(flat_m, flat_c) if c is not m)
+            # and the programs take it as placed: traced once each
+            prompt = np.asarray([5, 9, 11, 2], np.int32)
+            toks = eng.submit(prompt, max_new=4).result(timeout=240)
+            traced = dict(eng.trace_counts)
+            np.testing.assert_array_equal(
+                toks, eng.submit(prompt, max_new=4).result(timeout=240))
+            assert eng.trace_counts == traced
+            assert eng.metrics.snapshot()["param_casts"] == 1
+        finally:
+            eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
 # LSTM carried-state backend
 # ---------------------------------------------------------------------------
 class TestRecurrentGeneration:
@@ -1054,7 +1274,8 @@ class TestGenerateHTTP:
 
 
 def teardown_module(module):
-    eng = _ENG.pop("e", None)
-    if eng is not None:
-        eng.shutdown()
+    for held in (_ENG, _BF16):
+        eng = held.pop("e", None)
+        if eng is not None:
+            eng.shutdown()
     _LM.clear()

@@ -198,20 +198,19 @@ def _sampler_work_outside_a_conditional(text: str, slots: int,
     return found
 
 
-def test_decode_program_keeps_the_kv_slab_in_place(one_chip):
+@pytest.fixture(scope="module")
+def chat_decode(one_chip):
     """The serving engine's decode program (``_decode``) at the
     gpt2-large.chat cell's widths (d 1280, 20 heads, 24 slots x 1024,
-    bf16) and a cut depth (4 layers; ~35 s of compile): the slab is read
-    where it lies and written in place, so the plan holds no temporary
-    near a layer's slice and no ``copy`` of one. With the cache written
-    inside the layer loop this program planned 1.29 GB of temporaries
-    and six such copies: 59 of a decode step's 113 ms on the chip."""
-    import re
+    bf16) and a cut depth (4 layers; ~35 s of compile), lowered on what
+    the backend hands it: the shapes of the weights' serving copy.
+    Returns (compiled, cfg, slots, the slab's shape)."""
     from types import SimpleNamespace
 
     from deeplearning4j_tpu.models.transformer_lm import (
         TransformerLMConfig,
         init_params,
+        serving_copy,
     )
     from deeplearning4j_tpu.serving.generate import _TransformerBackend
 
@@ -227,15 +226,28 @@ def test_decode_program_keeps_the_kv_slab_in_place(one_chip):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    masters = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     params = jax.tree_util.tree_map(
         lambda a: arg(a.shape, a.dtype),
-        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+        jax.eval_shape(lambda p: serving_copy(cfg, p), masters))
     slab = arg((L, S, cfg.n_heads, cfg.d_model // cfg.n_heads, T), BF16)
     compiled = be._decode_fn.lower(
         params, slab, slab, arg((S,), jnp.int32), arg((S,), jnp.int32),
         arg((S,), jnp.bool_), arg((S,), F32), arg((S,), jnp.int32),
         arg((S,), F32), arg((S, 2), jnp.uint32)).compile()
-    layer_slice = slab.size // L  # elements of one layer's K (or V)
+    return compiled, cfg, S, slab.shape
+
+
+def test_decode_program_keeps_the_kv_slab_in_place(chat_decode):
+    """The slab is read where it lies and written in place, so the plan
+    holds no temporary near a layer's slice and no ``copy`` of one. With
+    the cache written inside the layer loop this program planned 1.29 GB
+    of temporaries and six such copies: 59 of a decode step's 113 ms on
+    the chip."""
+    import re
+
+    compiled, cfg, S, slab_shape = chat_decode
+    layer_slice = math.prod(slab_shape[1:])  # elements of one layer's K
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries < 2 * layer_slice * 2, temporaries  # 126 MB
     copies = [
@@ -249,6 +261,40 @@ def test_decode_program_keeps_the_kv_slab_in_place(one_chip):
     assert " conditional(" in text and " sort(" in text
     assert not _sampler_work_outside_a_conditional(text, S,
                                                    cfg.vocab_size)
+
+
+def test_decode_program_casts_no_weights(chat_decode):
+    """Handed the serving copy, the decode program reads its block
+    matrices and head as bfloat16 arguments and re-makes none of them:
+    in the computations that always run no ``convert`` has a bfloat16
+    result of a stacked block matrix's shape or the head's, and no
+    float32 argument has one. On float32 masters it made six such
+    converts every step, 0.94 + 0.47 GB read and written for ``W1`` and
+    ``W2`` alone at 36 layers: 6.3 of a decode step's 22.4 ms on the
+    chip."""
+    import re
+
+    compiled, cfg, _S, _slab = chat_decode
+    L, d = cfg.n_layers, cfg.d_model
+    matrices = {(L, d, d), (L, d, cfg.mlp_ratio * d),
+                (L, cfg.mlp_ratio * d, d), (d, cfg.vocab_size)}
+
+    def shape(dims):
+        return tuple(map(int, dims.split(",")))
+
+    text = compiled.as_text()
+    converts = [
+        (name, op.group(1), op.group(2))
+        for name, lines in _always_run(text).items()
+        for op in (re.search(
+            r"^\s*(?:ROOT )?%?(\S+) = bf16\[([\d,]+)\]\S* convert\(", line)
+            for line in lines)
+        if op and shape(op.group(2)) in matrices]
+    assert not converts, converts
+    arguments = {(dtype, shape(dims)) for dtype, dims in re.findall(
+        r" = (\w+)\[([\d,]+)\]\S* parameter\(\d+\)", text)}
+    assert {("bf16", m) for m in matrices} <= arguments
+    assert not {("f32", m) for m in matrices} & arguments
 
 
 def test_decoder_decode_program_compiles_at_published_widths(one_chip):
