@@ -3,14 +3,18 @@
 
 ``TransformerLM`` is one GPT-2 block scanned L times. Today's open
 decoders are not that: RMSNorm, no biases, rotary positions on part of a
-head, fewer key/value heads than query heads, query/key heads wider than
-value heads, window layers (with a learned softmax sink) among full
-layers, a leading dense layer and then expert layers of which a chip
-holds its share. Here all of that is configuration:
+head (plain or with scaled frequencies), fewer key/value heads than query
+heads, query/key heads wider than value heads, window layers (with a
+learned softmax sink) among full layers, latent attention (low-rank
+queries and one compressed key/value entry a position shared by all
+heads), a leading dense layer and then expert layers of which a chip
+holds its share, routed by sigmoid or by group-limited softmax scores,
+with or without a shared expert. Here all of that is configuration:
 
 - ``attn_kinds`` names the kinds of attention layer (key/value heads,
-  rotary base, window, sink) and ``layers`` gives each layer's attention
-  kind and FFN kind (``"dense"`` / ``"experts"``), in order;
+  rotary base and scaling, window, sink, or the ranks of a latent kind)
+  and ``layers`` gives each layer's attention kind and FFN kind
+  (``"dense"`` / ``"experts"``), in order;
 - consecutive layers of one (attention, FFN) pair form a SEGMENT whose
   parameters are stacked and scanned; the stack is the list of segments;
 - ONE block function (:func:`block`) serves the full forward, prefill
@@ -20,10 +24,18 @@ holds its share. Here all of that is configuration:
   code with different position maps;
 - the cache is sized by layer kind (:meth:`DecoderConfig.cache_plan`):
   the slot's length for a full layer, a ring of ``window`` columns for a
-  window layer, T-minor and written in place after the layer loop, as
-  ``TransformerLM``'s slab is (``_put_columns``);
-- expert layers route over every expert of the layer and compute the
-  part of the result their held experts give
+  window layer, K and V by head; ONE slab of ``kv_rank + rotary_dim``
+  values a position for a latent layer; T-minor and written in place
+  after the layer loop, as ``TransformerLM``'s slab is
+  (``_put_columns``);
+- a latent layer decodes ABSORBED (the queries are taken into the latent
+  space, so a step reads each cached position once for all heads and
+  never expands K or V) and prefills EXPANDED, by blocks of queries and
+  keys under one running softmax (``_causal_blocked``): no program plans
+  a score tensor of a whole bucket;
+- expert layers route over every expert of the layer (the rule is the
+  configuration's ``routing``) and compute the part of the result their
+  held experts give, plus the shared expert where there is one
   (``nn/conf/layers/moe.moe_dropless_ffn``); the vocabulary may be the
   chip's slice of the published one.
 
@@ -32,6 +44,7 @@ Serving only: there is no training step for this block yet (ROADMAP M1).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -46,25 +59,53 @@ from deeplearning4j_tpu.models.transformer_lm import (
     prefill_bucket_lengths,
     sample_next_device,
 )
-from deeplearning4j_tpu.nn.conf.layers.moe import moe_dropless_ffn
+from deeplearning4j_tpu.nn.conf.layers.moe import (
+    group_limited_softmax_route,
+    moe_dropless_ffn,
+    sigmoid_topk_route,
+)
 
 Array = jax.Array
 
 #: device-time scopes of this model, beside ``transformer_lm.SCOPES``
 #: (``embed``, ``kv_write``, ``head``, ``sample`` are shared): attention
-#: by layer kind, the dense FFN, and the two halves of an expert layer
-SCOPES = ("attn_full", "attn_window", "mlp", "moe_route", "moe_experts")
+#: by layer kind (a latent layer's in two: ``attn_latent_proj``, the norms,
+#: projections, rotation, absorption and output projection, bound by
+#: weights, around ``attn_latent_core``, the scores over the latent cache,
+#: the softmax and the weighted sum of latents; in prefill the blocked
+#: attention), the dense FFN, the two halves of an expert layer and its
+#: shared expert
+SCOPES = ("attn_full", "attn_window", "attn_latent_proj", "attn_latent_core",
+          "mlp", "moe_route", "moe_experts", "moe_shared")
 _scope = jax.named_scope
 _NEG = -1e30
+#: queries and keys a block of a latent layer's prefill attention
+#: (``_causal_blocked``): the score tensor a program plans is (heads,
+#: block, block) float32, 128 MB at 128 heads, whatever the bucket
+PREFILL_BLOCK = 512
+#: tokens an expert layer takes at a time (``_experts``): the gathered
+#: rows of a longer prefill, N x k x d in float32, would not fit beside
+#: the weights
+EXPERT_TOKEN_CHUNK = 2048
 
 
 class DecoderConfig:
     """The decoder as data. ``attn_kinds``: name -> {"n_kv_heads",
-    "rope_theta", "window" (None = full), "sink" (bool)}; ``layers``:
-    one (attention kind, "dense" | "experts") pair a layer.
+    "rope_theta", "window" (None = full), "sink" (bool)} and, optional,
+    "rope_scaling" (YaRN: ``factor``, ``beta_fast``, ``beta_slow``,
+    ``mscale``, ``mscale_all_dim``, ``original_max_position_embeddings``)
+    and "latent" = {"q_rank", "kv_rank"}: a latent kind,
+    whose heads are ``head_dim`` = (``head_dim - rotary_dim`` without
+    position | ``rotary_dim`` rotated) wide and share ONE rotary key, and
+    whose cache entry is ``kv_rank + rotary_dim`` values a position.
+    ``layers``: one (attention kind, "dense" | "experts") pair a layer.
     ``experts_held`` = (offset, count): which of the ``n_experts`` the
-    router scores have their weights here. ``vocab_size`` is what is
-    held here (the chip's slice, where the vocabulary is sliced)."""
+    router scores have their weights here. ``routing``: None (sigmoid
+    scores, a correction bias in the choice, weights renormalised) or
+    {"n_group", "topk_group", "renormalise", "scale"} (softmax scores,
+    group-limited: no bias). ``shared_width``: the shared expert's width
+    (0: none). ``vocab_size`` is what is held here (the chip's slice,
+    where the vocabulary is sliced)."""
 
     def __init__(self, vocab_size: int, d_model: int, n_heads: int,
                  head_dim: int, v_head_dim: int, rotary_dim: int,
@@ -74,7 +115,8 @@ class DecoderConfig:
                  experts_held: Optional[Sequence[int]] = None,
                  value_scale: float = 1.0, norm_eps: float = 1e-5,
                  max_length: int = 2048, param_dtype: str = "bfloat16",
-                 seed: int = 0):
+                 seed: int = 0, routing: Optional[dict] = None,
+                 shared_width: int = 0):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.n_heads = int(n_heads)
@@ -84,12 +126,21 @@ class DecoderConfig:
         if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
             raise ValueError("rotary_dim must be even and <= head_dim")
         self.attn_kinds = {
-            name: {"n_kv_heads": int(k["n_kv_heads"]),
+            name: {"n_kv_heads": int(k.get("n_kv_heads", n_heads)),
                    "rope_theta": float(k["rope_theta"]),
                    "window": None if k.get("window") is None
                    else int(k["window"]),
-                   "sink": bool(k.get("sink", False))}
+                   "sink": bool(k.get("sink", False)),
+                   "rope_scaling": (dict(k["rope_scaling"])
+                                    if k.get("rope_scaling") else None),
+                   "latent": ({"q_rank": int(k["latent"]["q_rank"]),
+                               "kv_rank": int(k["latent"]["kv_rank"])}
+                              if k.get("latent") else None)}
             for name, k in attn_kinds.items()}
+        for name, k in self.attn_kinds.items():
+            if k["latent"] and (k["window"] is not None or k["sink"]):
+                raise ValueError(f"latent kind {name!r} takes no window or "
+                                 "sink")
         self.layers = [(str(a), str(f)) for a, f in layers]
         for a, f in self.layers:
             if a not in self.attn_kinds or f not in ("dense", "experts"):
@@ -111,6 +162,16 @@ class DecoderConfig:
             raise ValueError("param_dtype must be 'float32' or 'bfloat16'")
         self.param_dtype = param_dtype
         self.seed = int(seed)
+        self.routing = None if routing is None else {
+            "n_group": int(routing.get("n_group", 1)),
+            "topk_group": int(routing.get("topk_group", 1)),
+            "renormalise": bool(routing.get("renormalise", False)),
+            "scale": float(routing.get("scale", 1.0))}
+        if (self.routing is not None
+                and self.n_experts % self.routing["n_group"]):
+            raise ValueError("routing: n_group groups that divide "
+                             "n_experts, or None")
+        self.shared_width = int(shared_width)
 
     @property
     def n_layers(self) -> int:
@@ -138,20 +199,46 @@ class DecoderConfig:
         return int(max_length) if window is None else min(window,
                                                           int(max_length))
 
+    def latent_width(self, kind: str) -> int:
+        """Values a latent kind caches a position and layer: the
+        compressed key/value entry and the one rotated key."""
+        return self.attn_kinds[kind]["latent"]["kv_rank"] + self.rotary_dim
+
+    def route(self):
+        """The expert layers' routing rule, as ``moe_dropless_ffn`` takes
+        it: (router outputs, bias, k) -> (chosen, weights)."""
+        if self.routing is None:
+            return sigmoid_topk_route
+        r = self.routing
+        return functools.partial(
+            group_limited_softmax_route, n_group=r["n_group"],
+            topk_group=r["topk_group"], renormalise=r["renormalise"],
+            scale=r["scale"])
+
     def cache_plan(self, n_slots: int, max_length: int) -> List[dict]:
-        """What the engine allocates, a segment at a time: shapes
-        (layers, slots, kv heads, head size, columns) of K and V."""
+        """What the engine allocates, a segment at a time: ``slabs``, the
+        shapes (layers, slots, kv heads, head size, columns) of K and V
+        or, for a latent segment, ONE slab (layers, slots, kv_rank +
+        rotary_dim, columns); ``values``: what a position and layer
+        keeps."""
         item = jnp.dtype(self.dtype).itemsize
         plan = []
         for kind, _ffn, n in self.segments():
-            hkv = self.attn_kinds[kind]["n_kv_heads"]
             cols = self.cache_columns(kind, max_length)
-            k = (n, int(n_slots), hkv, self.head_dim, cols)
-            v = (n, int(n_slots), hkv, self.v_head_dim, cols)
-            plan.append({"kind": kind, "layers": n, "columns": cols,
-                         "ring": self.attn_kinds[kind]["window"] is not None,
-                         "k": k, "v": v,
-                         "bytes": (int(np.prod(k)) + int(np.prod(v))) * item})
+            entry = {"kind": kind, "layers": n, "columns": cols,
+                     "ring": self.attn_kinds[kind]["window"] is not None}
+            if self.attn_kinds[kind]["latent"]:
+                width = self.latent_width(kind)
+                slabs = [(n, int(n_slots), width, cols)]
+            else:
+                hkv = self.attn_kinds[kind]["n_kv_heads"]
+                slabs = [(n, int(n_slots), hkv, self.head_dim, cols),
+                         (n, int(n_slots), hkv, self.v_head_dim, cols)]
+                entry["k"], entry["v"] = slabs
+                width = hkv * (self.head_dim + self.v_head_dim)
+            entry.update(slabs=slabs, values=width, bytes=sum(
+                int(np.prod(shape)) for shape in slabs) * item)
+            plan.append(entry)
         return plan
 
 
@@ -161,16 +248,31 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
     the router stay float32 whatever the parameter dtype. ``Wq`` is
     stored by head, (d, heads, head size): flat, the TPU compiler
     re-laid its 100 MB out in every layer of a decode step to split a
-    product 12,288 wide into heads of 192 (by compile, PR 27)."""
+    product 12,288 wide into heads of 192 (by compile, PR 27). A latent
+    kind's up-projections are by head for the same reason: ``Wqb``
+    (q_rank, heads, head size), and the key/value one in its two halves,
+    ``Wuk`` (kv_rank, heads, head size - rotary_dim) and ``Wuv`` (kv_rank,
+    heads, value size), which the absorbed decode contracts on opposite
+    sides and never together."""
     d, hq = cfg.d_model, cfg.n_heads
-    hkv = cfg.attn_kinds[kind]["n_kv_heads"]
+    ak = cfg.attn_kinds[kind]
     pd, f32 = cfg.dtype, jnp.float32
-    out = {"norm1": ((d,), f32), "norm2": ((d,), f32),
-           "Wq": ((d, hq, cfg.head_dim), pd),
-           "Wk": ((d, hkv * cfg.head_dim), pd),
-           "Wv": ((d, hkv * cfg.v_head_dim), pd),
-           "Wo": ((hq * cfg.v_head_dim, d), pd)}
-    if cfg.attn_kinds[kind]["sink"]:
+    out = {"norm1": ((d,), f32), "norm2": ((d,), f32)}
+    if ak["latent"]:
+        qr, kr = ak["latent"]["q_rank"], ak["latent"]["kv_rank"]
+        out.update({"Wqa": ((d, qr), pd), "norm_q": ((qr,), f32),
+                    "Wqb": ((qr, hq, cfg.head_dim), pd),
+                    "Wkva": ((d, kr + cfg.rotary_dim), pd),
+                    "norm_kv": ((kr,), f32),
+                    "Wuk": ((kr, hq, cfg.head_dim - cfg.rotary_dim), pd),
+                    "Wuv": ((kr, hq, cfg.v_head_dim), pd)})
+    else:
+        hkv = ak["n_kv_heads"]
+        out.update({"Wq": ((d, hq, cfg.head_dim), pd),
+                    "Wk": ((d, hkv * cfg.head_dim), pd),
+                    "Wv": ((d, hkv * cfg.v_head_dim), pd)})
+    out["Wo"] = ((hq * cfg.v_head_dim, d), pd)
+    if ak["sink"]:
         out["sink"] = ((hq,), f32)
     if ffn == "dense":
         out.update({"Wg": ((d, cfg.dense_width), pd),
@@ -178,10 +280,15 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
                     "Wd": ((cfg.dense_width, d), pd)})
     else:
         held, f = cfg.experts_held[1], cfg.expert_width
-        out.update({"Wr": ((d, cfg.n_experts), f32),
-                    "br": ((cfg.n_experts,), f32),
-                    "Eg": ((held, d, f), pd), "Eu": ((held, d, f), pd),
+        out["Wr"] = ((d, cfg.n_experts), f32)
+        if cfg.routing is None:
+            out["br"] = ((cfg.n_experts,), f32)
+        out.update({"Eg": ((held, d, f), pd), "Eu": ((held, d, f), pd),
                     "Ed": ((held, f, d), pd)})
+        if cfg.shared_width:
+            out.update({"Sg": ((d, cfg.shared_width), pd),
+                        "Su": ((d, cfg.shared_width), pd),
+                        "Sd": ((cfg.shared_width, d), pd)})
     return out
 
 
@@ -222,19 +329,65 @@ def _rms_norm(x, g, eps):
     return xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps) * g
 
 
-def _rotate(x, pos, rotary_dim: int, theta: float):
+def _rotate(x, pos, rotary_dim: int, theta: float, scaling=None):
     """Rotary positions on the first ``rotary_dim`` of each head
     (half-split pairing: dimension i turns with i + rotary_dim/2); the
-    rest pass through. x (b, T, h, hd), pos (b, T) absolute."""
+    rest pass through. x (b, T, h, hd), pos (b, T) absolute. With
+    ``scaling`` the frequencies and the amplitude are YaRN's
+    (:func:`yarn_frequencies`)."""
     half = rotary_dim // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+    if scaling is None:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
+        amp = 1.0
+    else:
+        inv, amp = yarn_frequencies(rotary_dim, theta, scaling)
+        inv = jnp.asarray(inv, jnp.float32)
     ang = pos.astype(jnp.float32)[..., None] * inv          # (b, T, half)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     xf = x.astype(jnp.float32)
     a, b = xf[..., :half], xf[..., half:rotary_dim]
     return jnp.concatenate(
         [a * cos - b * sin, b * cos + a * sin, xf[..., rotary_dim:]],
         axis=-1).astype(x.dtype)
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(rotary_dim: int, theta: float, scaling: dict):
+    """(inverse frequencies (rotary_dim / 2,) float32, amplitude of cos
+    and sin) of YaRN: pair i keeps its frequency ``theta^(-2i/dim)``
+    below the correction dimension of ``beta_fast`` turns within the
+    original context, takes it divided by ``factor`` above that of
+    ``beta_slow``, and a linear blend between the two."""
+    dim, factor = rotary_dim, float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(float(scaling["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(scaling["beta_slow"]))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    inv = extra / factor * ramp + extra * (1 - ramp)
+    amp = (_yarn_mscale(factor, float(scaling.get("mscale", 1.0)))
+           / _yarn_mscale(factor, float(scaling.get("mscale_all_dim", 0.0))))
+    return inv.astype(np.float32), float(amp)
+
+
+def softmax_scale(cfg: "DecoderConfig", kind: str) -> float:
+    """1 / sqrt(head size), times YaRN's ``mscale_all_dim`` factor squared
+    where the kind's rotary scaling has one."""
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    sc = cfg.attn_kinds[kind]["rope_scaling"]
+    if sc and sc.get("mscale_all_dim"):
+        scale *= _yarn_mscale(float(sc["factor"]),
+                              float(sc["mscale_all_dim"])) ** 2
+    return scale
 
 
 def _visible(q_pos, k_pos, window):
@@ -246,6 +399,157 @@ def _visible(q_pos, k_pos, window):
     if window is not None:
         ok &= (qp - kp) < window
     return ok
+
+
+def _causal_blocked(q, k, v, scale: float, block: int, n_real=None):
+    """Causal attention of q (b, T, h, dk) over k (b, T, h, dk) and
+    v (b, T, h, dv) -> (b, T, h, dv), by blocks of ``block`` queries and
+    ``block`` keys under one running softmax: the largest score tensor is
+    (b, h, block, block) whatever T, and the key blocks above a query
+    block's diagonal are not visited. With ``n_real`` (traced) the query
+    blocks past the first ``n_real`` positions are not computed either
+    (a bucket's padding): their rows come back zero."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    pad = -t % block
+    if pad:  # padded keys lie after every real query: causality hides them
+        q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for a in (q, k, v))
+    at = jnp.arange(block)
+
+    def q_block(i, out):
+        qi = jax.lax.dynamic_slice_in_dim(q, i * block, block, axis=1)
+
+        def k_block(j, carry):
+            m, z, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * block, block, axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * block, block, axis=1)
+            s = jnp.einsum("bqhd,bkhd->bhqk", qi, kj,
+                           preferred_element_type=f32) * scale
+            s = jnp.where((j * block + at)[None, :] <= (i * block + at)[:, None],
+                          s, _NEG)
+            m_new = jnp.maximum(m, s.max(-1))
+            keep = jnp.exp(m - m_new)
+            e = jnp.exp(s - m_new[..., None])
+            acc = acc * keep[..., None] + jnp.einsum(
+                "bhqk,bkhd->bhqd", e.astype(q.dtype), vj,
+                preferred_element_type=f32)
+            return m_new, z * keep + e.sum(-1), acc
+
+        # key block 0 holds position 0, which every query sees: the
+        # running maximum is a real score from the first block on
+        m, z, acc = jax.lax.fori_loop(
+            0, i + 1, k_block,
+            (jnp.full((b, h, block), _NEG, f32), jnp.zeros((b, h, block), f32),
+             jnp.zeros((b, h, block, dv), f32)))
+        o = (acc / z[..., None]).transpose(0, 2, 1, 3).astype(q.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(out, o, i * block, axis=1)
+
+    n_blocks = (t + pad) // block
+    if n_real is not None:
+        n_blocks = jnp.minimum(n_blocks, (n_real + block - 1) // block)
+    out = jax.lax.fori_loop(0, n_blocks, q_block,
+                            jnp.zeros((b, t + pad, h, dv), q.dtype))
+    return out[:, :t]
+
+
+def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
+                      x: Array, q_pos: Array, cache=None, n_real=None):
+    """A latent layer's attention on x (b, Tq, d): returns (x + its
+    output, the (b, Tq, kv_rank + rotary_dim) cache entries of the step's
+    own positions: the compressed key/value latent after its norm, then
+    the one rotary key after rotation).
+
+    Without a cache (forward, prefill) the EXPANDED form: keys and values
+    of every head are made from the positions' own latents and attention
+    goes by blocks (``_causal_blocked``). With ``cache`` = (slab
+    (b, kv_rank + rotary_dim, Tc), c_pos (b, Tc)) the ABSORBED form: with
+    the up-projection split by head into ``Wuk`` and ``Wuv``, a head's
+    query is taken into the latent space (``q_nope Wuk^T``), scored
+    against the cached latents and the rotary key as they lie, the
+    softmax weights sum the LATENTS, and ``Wuv`` then ``Wo`` bring that
+    sum out: each cached position is read once for all heads and no key
+    or value of a head is ever made over the cache. Both einsums take the
+    slab whole (the weighted sum over all its rows, the rotary key's
+    dropped after): a slice of it would be copied."""
+    ak = cfg.attn_kinds[kind]
+    b, tq, _d = x.shape
+    hq, rot, vd = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
+    nope, kr = cfg.head_dim - rot, ak["latent"]["kv_rank"]
+    theta, scaling = ak["rope_theta"], ak["rope_scaling"]
+    scale = softmax_scale(cfg, kind)
+    f32, dt = jnp.float32, x.dtype
+    with _scope("attn_latent_proj"):
+        a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(dt)
+        c_q = _rms_norm(a_in @ bp["Wqa"], bp["norm_q"], cfg.norm_eps).astype(dt)
+        q = jnp.einsum("btr,rhk->bthk", c_q, bp["Wqb"])
+        # the rotated part of a head is its LAST rotary_dim columns
+        q_nope = q[..., :nope]
+        q_pe = _rotate(q[..., nope:], q_pos, rot, theta, scaling)
+        ckv = a_in @ bp["Wkva"]
+        c = _rms_norm(ckv[..., :kr], bp["norm_kv"], cfg.norm_eps).astype(dt)
+        k_pe = _rotate(ckv[:, :, None, kr:], q_pos, rot, theta, scaling)[:, :, 0]
+        new = jnp.concatenate([c, k_pe], axis=-1)            # (b, Tq, kr + rot)
+        if cache is None:
+            k = jnp.concatenate(
+                [jnp.einsum("btc,chn->bthn", c, bp["Wuk"]),
+                 jnp.broadcast_to(k_pe[:, :, None], (b, tq, hq, rot))], axis=-1)
+            v = jnp.einsum("btc,chv->bthv", c, bp["Wuv"])
+            with _scope("attn_latent_core"):
+                o = _causal_blocked(jnp.concatenate([q_nope, q_pe], axis=-1),
+                                    k, v, scale, PREFILL_BLOCK, n_real)
+        else:
+            slab, c_pos = cache
+            q_lat = jnp.concatenate(
+                [jnp.einsum("bqhn,chn->bqhc", q_nope, bp["Wuk"]), q_pe], axis=-1)
+            with _scope("attn_latent_core"):
+                s_own = jnp.einsum("bqhc,bkc->bqhk", q_lat, new,
+                                   preferred_element_type=f32) * scale
+                s_own = jnp.where(_visible(q_pos, q_pos, None)[:, :, None],
+                                  s_own, _NEG)
+                s_c = jnp.einsum("bqhc,bct->bqht", q_lat, slab,
+                                 preferred_element_type=f32) * scale
+                s_c = jnp.where(_visible(q_pos, c_pos, None)[:, :, None],
+                                s_c, _NEG)
+                m = jnp.maximum(s_own.max(-1), s_c.max(-1))[..., None]
+                e_own, e_c = jnp.exp(s_own - m), jnp.exp(s_c - m)
+                lat = (jnp.einsum("bqhk,bkc->bqhc", e_own.astype(dt), new,
+                                  preferred_element_type=f32)
+                       + jnp.einsum("bqht,bct->bqhc", e_c.astype(dt), slab,
+                                    preferred_element_type=f32))
+                z = (e_own.sum(-1) + e_c.sum(-1))[..., None]
+                lat = (lat[..., :kr] / z).astype(dt)
+            o = jnp.einsum("bqhc,chv->bqhv", lat, bp["Wuv"])
+        if cfg.value_scale != 1.0:
+            o = o * cfg.value_scale
+        x = x + o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"]
+    return x, new
+
+
+def _experts(cfg: DecoderConfig, bp: Dict[str, Array], r_in: Array, dtype,
+             token_mask, layer):
+    """The expert layer on r_in (N, d) float32 (the normed residual):
+    ``moe_dropless_ffn`` with the configuration's routing rule and shared
+    expert, ``EXPERT_TOKEN_CHUNK`` tokens at a time where there are more
+    (each chunk reads the held experts' weights again, which a prefill of
+    thousands of tokens can afford and a plan of N x k gathered rows in
+    float32 cannot)."""
+    def run(r, mask):
+        return moe_dropless_ffn(r.astype(dtype), r, bp, cfg.top_k,
+                                cfg.experts_held, mask, layer,
+                                route=cfg.route(), shared=bool(cfg.shared_width))
+
+    n, d = r_in.shape
+    chunk = EXPERT_TOKEN_CHUNK
+    if n <= chunk:
+        return run(r_in, token_mask)
+    pad = -n % chunk
+    mask = (jnp.ones((n,), bool) if token_mask is None else token_mask)
+    r_in = jnp.pad(r_in, ((0, pad), (0, 0))).reshape(-1, chunk, d)
+    mask = jnp.pad(mask, (0, pad)).reshape(-1, chunk)
+    y, pairs, hit = jax.lax.map(lambda rm: run(*rm), (r_in, mask))
+    return y.reshape(-1, d)[:n], pairs.sum(), hit.sum()
 
 
 #: the leaves of an expert layer that stay stacked through a segment's scan
@@ -261,12 +565,21 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     each of which holds absolute position ``c_pos`` (< 0: nothing). The
     cache is only READ: the layer's new (b, hkv, Tq, hd) keys and
     (b, hkv, Tq, vd) values are returned for the caller to drop (full
-    forward), write whole (prefill) or append (decode). With ``layer``
+    forward), write whole (prefill) or append (decode). A latent kind's
+    cache is (slab (b, kv_rank + rotary_dim, Tc), c_pos) and what it
+    returns in their place is ((b, Tq, kv_rank + rotary_dim) entries,)
+    (:func:`_latent_attention`). With ``layer``
     the expert weights in ``bp`` are a segment's whole stacks and
     ``layer`` the one to use (``moe_dropless_ffn``). Returns
     (x, (k, v), (expert pairs computed here, held experts hit))."""
     ak = cfg.attn_kinds[kind]
     b, tq, d = x.shape
+    if ak["latent"]:
+        n_real = (None if token_mask is None or cache is not None
+                  else jnp.max(jnp.sum(token_mask, axis=-1)))
+        x, entries = _latent_attention(cfg, kind, bp, x, q_pos, cache, n_real)
+        x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
+        return x, (entries,), counts
     hq, hkv, hd, vd = cfg.n_heads, ak["n_kv_heads"], cfg.head_dim, cfg.v_head_dim
     grp = hq // hkv
     window = ak["window"]
@@ -311,6 +624,16 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
         o = o * (cfg.value_scale / z[..., None])
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
         x = x + o @ bp["Wo"]
+    x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
+    return x, (kh, vh), counts
+
+
+def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
+         token_mask, layer):
+    """The second half of a layer: x + the dense MLP or the expert layer
+    of the normed x -> (x, (expert pairs computed here, held experts
+    hit))."""
+    b, tq, d = x.shape
     if ffn == "dense":
         with _scope("mlp"):
             m_in = _rms_norm(x, bp["norm2"], cfg.norm_eps).astype(x.dtype)
@@ -320,22 +643,23 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     else:
         with _scope("moe_route"):
             r_in = _rms_norm(x, bp["norm2"], cfg.norm_eps).reshape(b * tq, d)
-        y, pairs, hit = moe_dropless_ffn(
-            r_in.astype(x.dtype), r_in, bp, cfg.top_k, cfg.experts_held,
-            None if token_mask is None else token_mask.reshape(b * tq),
-            layer)
+        y, pairs, hit = _experts(
+            cfg, bp, r_in, x.dtype,
+            None if token_mask is None else token_mask.reshape(b * tq), layer)
         x = x + y.reshape(b, tq, d).astype(x.dtype)
         counts = (pairs.astype(jnp.int32), hit)
-    return x, (kh, vh), counts
+    return x, counts
 
 
 def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
                caches=None, c_pos=None, token_mask=None):
     """Every segment in order, each one ``lax.scan`` of :func:`block`
-    over its stacked layers. ``caches``: per segment (K, V) slabs
-    (layers, b, hkv, hd, Tc) to read, with ``c_pos`` the position map of
-    each attention kind. Returns (x, per segment (k, v) stacks
-    (layers, b, hkv, Tq, hd), summed expert counters)."""
+    over its stacked layers. ``caches``: per segment the slabs to read,
+    (K, V) (layers, b, hkv, hd, Tc) or a latent segment's one
+    (layers, b, width, Tc), with ``c_pos`` the position map of each
+    attention kind. Returns (x, per segment what the layers made to
+    cache, (k, v) stacks (layers, b, hkv, Tq, hd) or (entries
+    (layers, b, Tq, width),), summed expert counters)."""
     new_kv = []
     pairs = hit = jnp.zeros((), jnp.int32)
     for i, (kind, ffn, n) in enumerate(cfg.segments()):
@@ -347,7 +671,7 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
 
         def body(x, xs, kind=kind, ffn=ffn, stacks=stacks):
             bp, kv, layer = xs
-            cache = None if kv is None else (kv[0], kv[1], c_pos[kind])
+            cache = None if kv is None else (*kv, c_pos[kind])
             x, knew, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
                                     q_pos, cache, token_mask,
                                     layer if stacks else None)
@@ -382,8 +706,9 @@ def forward(cfg: DecoderConfig, params: Dict, ids: Array):
 
 # -- the cache ----------------------------------------------------------------
 def init_cache(cfg: DecoderConfig, n_slots: int, max_length: int):
-    """Zeroed (K, V) slabs a segment, by the cache plan."""
-    return [(jnp.zeros(p["k"], cfg.dtype), jnp.zeros(p["v"], cfg.dtype))
+    """Zeroed slabs a segment, by the cache plan: (K, V), or a latent
+    segment's one."""
+    return [tuple(jnp.zeros(shape, cfg.dtype) for shape in p["slabs"])
             for p in cfg.cache_plan(n_slots, max_length)]
 
 
@@ -423,10 +748,11 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
     """One token a row: ids_1 (b,) at per-row positions pos (b,) ->
     (logits (b, V), caches, (expert pairs, experts hit)). The caches are
     read inside the layer loop and written after it: a full layer's slab
-    by one in-place column a row at ``pos`` (``_put_columns``), a ring by
-    one select at ``pos mod window`` (``_put_ring``). ``active`` (b,) bool keeps idle rows
+    (a latent layer's too) by one in-place column a row at ``pos``
+    (``_put_columns``), a ring by one select at ``pos mod window``
+    (``_put_ring``). ``active`` (b,) bool keeps idle rows
     out of the expert layers (and of their counters)."""
-    t_max = max(p[0].shape[4] for p in caches)
+    t_max = max(p[0].shape[-1] for p in caches)
     q_pos = pos.astype(jnp.int32)[:, None]
     x, new_kv, counts = _run_stack(
         cfg, params, _embed(cfg, params, ids_1[:, None]), q_pos, caches,
@@ -434,14 +760,18 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
         None if active is None else active[:, None])
     out = []
     with _scope("kv_write"):
-        for (kind, _ffn, _n), (kc, vc), (k, v) in zip(cfg.segments(), caches,
-                                                      new_kv):
-            cols = kc.shape[4]
-            if cfg.attn_kinds[kind]["window"] is None:
-                wp = jnp.minimum(q_pos, cols - 1)
-                out.append((_put_columns(kc, k, wp), _put_columns(vc, v, wp)))
+        for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
+            wp = jnp.minimum(q_pos, slabs[0].shape[-1] - 1)
+            if cfg.attn_kinds[kind]["latent"]:
+                # as a slab of one head whose "head size" is the entry
+                out.append((_put_columns(slabs[0][:, :, None],
+                                         new[0][:, :, None], wp)[:, :, 0],))
+            elif cfg.attn_kinds[kind]["window"] is None:
+                out.append(tuple(_put_columns(c, n, wp)
+                                 for c, n in zip(slabs, new)))
             else:
-                out.append((_put_ring(kc, k, q_pos), _put_ring(vc, v, q_pos)))
+                out.append(tuple(_put_ring(c, n, q_pos)
+                                 for c, n in zip(slabs, new)))
     return _head(cfg, params, x[:, 0]), out, counts
 
 
@@ -449,7 +779,7 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
                  length: Array, slot: Array):
     """One prompt, right-padded to a bucket: ids (1, Tb), ``length`` real
     tokens, into row ``slot`` of every slab, from ONE pass. A full layer
-    gets the bucket's columns at 0..Tb-1; a ring gets, in column c, the
+    (or a latent one) gets the bucket's columns at 0..Tb-1; a ring gets, in column c, the
     latest real position congruent to c, i.e. the prompt's last
     ``window`` columns when it is longer than the window. Padding follows
     the real tokens, so causal attention keeps it from them, and the
@@ -462,20 +792,23 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
                                     q_pos, token_mask=real)
     out = []
     with _scope("kv_write"):
-        for (kind, _ffn, _n), (kc, vc), (k, v) in zip(cfg.segments(), caches,
-                                                      new_kv):
-            cols = kc.shape[4]
-            kt = k.transpose(0, 1, 2, 4, 3)           # (L, 1, hkv, hd, Tb)
-            vt = v.transpose(0, 1, 2, 4, 3)
-            if cfg.attn_kinds[kind]["window"] is not None and tb > cols:
+        for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
+            cols = slabs[0].shape[-1]
+            if cfg.attn_kinds[kind]["window"] is None and tb > cols:
+                raise ValueError("prefill bucket longer than the slot")
+            if cfg.attn_kinds[kind]["latent"]:
+                out.append((jax.lax.dynamic_update_slice(
+                    slabs[0], new[0].transpose(0, 1, 3, 2), (0, slot, 0, 0)),))
+                continue
+            # (L, 1, hkv, hd, Tb)
+            new = tuple(n.transpose(0, 1, 2, 4, 3) for n in new)
+            if tb > cols:  # a ring shorter than the bucket
                 c = jnp.arange(cols, dtype=jnp.int32)
                 src = jnp.maximum(length - 1 - jnp.mod(length - 1 - c, cols), 0)
-                kt, vt = jnp.take(kt, src, axis=4), jnp.take(vt, src, axis=4)
-            elif tb > cols:
-                raise ValueError("prefill bucket longer than the slot")
-            at = (0, slot, 0, 0, 0)
-            out.append((jax.lax.dynamic_update_slice(kc, kt, at),
-                        jax.lax.dynamic_update_slice(vc, vt, at)))
+                new = tuple(jnp.take(n, src, axis=4) for n in new)
+            out.append(tuple(
+                jax.lax.dynamic_update_slice(c, n, (0, slot, 0, 0, 0))
+                for c, n in zip(slabs, new)))
     x_last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
                                           keepdims=False)
     return _head(cfg, params, x_last), out

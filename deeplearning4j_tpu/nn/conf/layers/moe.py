@@ -162,6 +162,40 @@ def sigmoid_topk_route(z, bias, top_k: int):
     return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
 
 
+def group_limited_softmax_route(z, bias, top_k: int, n_group: int,
+                                topk_group: int, renormalise: bool = False,
+                                scale: float = 1.0):
+    """Group-limited greedy routing on softmax scores: z (N, E) float32
+    router outputs -> (chosen (N, k), weights (N, k) float32). The E
+    experts are ``n_group`` groups of consecutive experts (a group is
+    what one device holds); a group's score is its largest softmax
+    probability, the ``topk_group`` best groups stay, and the k experts
+    are the largest probabilities inside them. The weights are those
+    probabilities, renormalised to sum to one only where ``renormalise``,
+    times ``scale``. This rule has no correction bias (``bias`` is not
+    read)."""
+    del bias
+    p = jax.nn.softmax(z.astype(jnp.float32), axis=-1)
+    n, e = p.shape
+    by_group = p.reshape(n, n_group, e // n_group)
+    _, best = jax.lax.top_k(by_group.max(-1), topk_group)       # (N, g)
+    kept = (best[:, :, None] == jnp.arange(n_group)[None, None, :]).any(1)
+    w, chosen = jax.lax.top_k(
+        jnp.where(kept[:, :, None], by_group, 0.0).reshape(n, e), top_k)
+    if renormalise:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), w * scale
+
+
+def shared_swiglu(x, params):
+    """The shared expert, which every token takes and every holder of a
+    layer computes alike: ``(silu(x Sg) * (x Su)) Sd`` -> (N, d)
+    float32."""
+    with jax.named_scope("moe_shared"):
+        h = jax.nn.silu(x @ params["Sg"]) * (x @ params["Su"])
+        return (h @ params["Sd"]).astype(jnp.float32)
+
+
 def _grouped_swiglu(rows, experts, sizes, chunk: int):
     """rows (M, d), sorted by group, through each row's expert:
     ``(silu(r Eg_e) * (r Eu_e)) Ed_e`` as three grouped products ->
@@ -207,12 +241,20 @@ def _grouped_swiglu(rows, experts, sizes, chunk: int):
 
 
 def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
-                     token_mask=None, layer=None, chunk: int = 128):
+                     token_mask=None, layer=None, chunk: int = 128,
+                     route=sigmoid_topk_route, shared: bool = False):
     """Dropless expert FFN, this holder's part of it: x (N, d) ->
     (y (N, d) float32, pairs computed here, held experts with a pair).
 
-    The router (``Wr`` (d, E), ``br`` (E,), float32, over ALL E experts
-    of the layer) reads ``router_in`` (N, d) float32; ``experts_held`` =
+    The router (``Wr`` (d, E), float32, over ALL E experts of the layer)
+    reads ``router_in`` (N, d) float32 and ``route`` (router outputs,
+    ``br`` (E,) or None where the rule has no bias, k) -> (chosen (N, k),
+    weights (N, k)) makes the choice: ``sigmoid_topk_route`` or
+    ``group_limited_softmax_route`` with its groups bound. With
+    ``shared`` the shared expert (``Sg``, ``Su`` (d, fs), ``Sd`` (fs, d):
+    ``shared_swiglu``) is added to every token, under its own scope and
+    outside the grouped products; a caller that sums the shares of
+    several holders leaves it out and adds it once. ``experts_held`` =
     (offset, count) names the experts whose weights ``Eg``/``Eu``
     (count, d, f) and ``Ed`` (count, f, d) are in ``params`` (offset may
     be traced: ``axis_index * count`` under manual expert parallelism).
@@ -242,7 +284,7 @@ def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
     with jax.named_scope("moe_route"):
         z = jnp.matmul(router_in.astype(jnp.float32), params["Wr"],
                        precision=jax.lax.Precision.HIGHEST)
-        chosen, w = sigmoid_topk_route(z, params["br"], top_k)
+        chosen, w = route(z, params.get("br"), top_k)
         local = chosen - offset                      # (N, k)
         held = (local >= 0) & (local < count)
         if token_mask is not None:
@@ -267,6 +309,8 @@ def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
         back = jnp.argsort(order)                    # pair -> sorted row
         out = jnp.take(out, back, axis=0).reshape(n, top_k, d)
         y = jnp.sum(out * jnp.where(held, w, 0.0)[:, :, None], axis=1)
+    if shared:
+        y = y + shared_swiglu(x, params)
     return y, n_local, hit
 
 

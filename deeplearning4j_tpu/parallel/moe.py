@@ -24,6 +24,8 @@ from deeplearning4j_tpu.nn.conf.layers.moe import (
     MixtureOfExpertsLayer,
     MoETransformerBlock,
     moe_dropless_ffn,
+    shared_swiglu,
+    sigmoid_topk_route,
 )
 from deeplearning4j_tpu.parallel.mesh import TrainingMesh
 
@@ -31,19 +33,27 @@ _EXPERT_PARAMS = ("W1", "b1", "W2", "b2")
 
 
 def expert_parallel_dropless_ffn(x, router_in, params, top_k: int,
-                                 expert_axis: str, token_mask=None):
+                                 expert_axis: str, token_mask=None,
+                                 route=sigmoid_topk_route,
+                                 shared: bool = False):
     """The dropless expert layer (``moe_dropless_ffn``) under MANUAL
     expert parallelism, for use inside a ``shard_map`` region: ``Eg`` /
     ``Eu`` / ``Ed`` arrive with their expert dimension sliced over
     ``expert_axis`` and the router (``Wr``, ``br``) replicated. Every
     shard routes its tokens over ALL experts, computes the share of the
     experts it holds (``experts_held`` = its slice), and the shares,
-    which are disjoint, are summed over the axis. Returns (y, pairs
-    computed, held experts hit), each over the whole axis."""
+    which are disjoint, are summed over the axis. The shared expert
+    (``shared``; its weights replicated) is computed by every shard alike
+    and counted once: added after the sum. Returns (y, pairs computed,
+    held experts hit), each over the whole axis."""
     count = params["Eg"].shape[0]
     held = (jax.lax.axis_index(expert_axis) * count, count)
-    share = moe_dropless_ffn(x, router_in, params, top_k, held, token_mask)
-    return tuple(jax.lax.psum(part, expert_axis) for part in share)
+    share = moe_dropless_ffn(x, router_in, params, top_k, held, token_mask,
+                             route=route)
+    y, pairs, hit = (jax.lax.psum(part, expert_axis) for part in share)
+    if shared:
+        y = y + shared_swiglu(x, params)
+    return y, pairs, hit
 
 
 class ExpertParallelWrapper:

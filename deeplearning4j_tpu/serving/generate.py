@@ -780,12 +780,16 @@ class _DecoderBackend:
     """``DecoderLM`` decode backend: the cache the model's plan asks
     for, a segment at a time: a full layer's (L, S, hkv, hd, T) slab and
     a window layer's (L, S, hkv, hd, window) ring, K and V of their own
-    head sizes. Decode reads them and writes one column a slot in place
-    after the layer loop; prefill writes a slot's columns of every slab
-    from one pass. Speculation stays at K = 1 (an expert model routes
-    per step), and there is no prefix cache: a ring holds a prompt's
-    last ``window`` columns only, so a captured prefix could not be
-    spliced under a longer prompt's own columns."""
+    head sizes; a latent layer's ONE (L, S, kv_rank + rotary_dim, T)
+    slab, which its decode reads absorbed (no key or value of a head is
+    made over it). Decode reads them and writes one column a slot in
+    place after the layer loop; prefill writes a slot's columns of every
+    slab from one pass, whole (no chunked prefill: it holds the decoding
+    slots for its length). Speculation stays at K = 1 (an expert model
+    routes per step), and there is no prefix cache: a ring holds a
+    prompt's last ``window`` columns only, so a captured prefix could
+    not be spliced under a longer prompt's own columns, and a latent
+    slab has no capture path yet."""
 
     kind = "decoder"
     spec_k = 1
@@ -818,6 +822,11 @@ class _DecoderBackend:
         #: (expert pairs computed here, held experts hit) of the last
         #: decode step, all layers; the engine adds them to its metrics
         self.step_counters = (0, 0)
+        #: cache positions the last decode step's active slots had behind
+        #: them, where a layer keeps a latent cache (else 0): host
+        #: arithmetic on what ``decode`` is handed
+        self.step_latent_positions = 0
+        self._latent = any(k["latent"] for k in cfg.attn_kinds.values())
         self.reset()
 
         def _f32(bits):
@@ -934,6 +943,9 @@ class _DecoderBackend:
             mine = self._mine
             self._state(mine[:-1], tokens, pos, active, temperature, top_k,
                         top_p, keys)
+            if self._latent:
+                self.step_latent_positions = int(np.dot(mine[:-1, 1],
+                                                        mine[:-1, 2]))
             # no new array and no loop over the whole of one before the
             # dispatch: NumPy lets the interpreter go inside a loop over
             # more than 500 elements (64 slots x 8 are 512), and the
@@ -1259,7 +1271,9 @@ def generation_memory_report(model, n_slots: int,
                      for p in jax.tree_util.tree_leaves(model.params_))
         if isinstance(model, DecoderLM):
             # sized by layer kind: the slot's length for a full layer,
-            # a ring of ``window`` columns for a window layer
+            # a ring of ``window`` columns for a window layer, K and V
+            # by head; one entry of kv_rank + rotary_dim values a
+            # position for a latent layer
             plan = cfg.cache_plan(n_slots, T)
             cache = sum(p["bytes"] for p in plan)
         else:
@@ -1290,7 +1304,8 @@ def generation_memory_report(model, n_slots: int,
            "n_slots": int(n_slots), "max_length": max_length}
     if plan is not None:
         out["cache_plan"] = [
-            {k: p[k] for k in ("kind", "layers", "columns", "ring", "bytes")}
+            {k: p[k] for k in ("kind", "layers", "columns", "ring", "values",
+                               "bytes")}
             for p in plan]
     return out
 
@@ -1430,8 +1445,8 @@ class GenerationEngine:
                 and not getattr(self.backend, "supports_prefix_cache", True)):
             raise ValueError(
                 f"the {self.backend.kind} backend has no prefix cache (a "
-                "window layer's ring keeps a prompt's last columns only); "
-                "set prefix_cache_mb=0")
+                "window layer's ring keeps a prompt's last columns only, "
+                "a latent slab has no capture path); set prefix_cache_mb=0")
         self._prefix_cache = (
             PrefixCache(int(float(prefix_cache_mb) * (1 << 20)),
                         self.metrics)
@@ -2044,6 +2059,8 @@ class GenerationEngine:
                 counts = getattr(self.backend, "step_counters", None)
                 if counts is not None:
                     self.metrics.record_moe_step(*counts)
+                    self.metrics.record_latent_positions(
+                        self.backend.step_latent_positions)
             if dt * 1e3 > self.stall_ms:
                 _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
                                active=n_active)

@@ -325,6 +325,10 @@ class GenerationMetrics:
             "generation_moe_experts_hit_total",
             "held experts with at least one pair, summed over layers "
             "and decode steps")
+        self._latent_positions = reg.counter(
+            "generation_latent_positions_read_total",
+            "cache positions the active slots had behind them, summed "
+            "over decode steps, where a layer keeps a latent cache")
         self._param_casts = reg.counter(
             "generation_param_casts_total",
             "times the transformer backend made its compute-dtype copy "
@@ -374,6 +378,10 @@ class GenerationMetrics:
             self._moe_pairs.inc(int(pairs_local))
         if experts_hit:
             self._moe_hit.inc(int(experts_hit))
+
+    def record_latent_positions(self, positions: int) -> None:
+        if positions:
+            self._latent_positions.inc(int(positions))
 
     def record_param_cast(self) -> None:
         self._param_casts.inc()
@@ -475,6 +483,7 @@ class GenerationMetrics:
             "prefill_flops_avoided": int(self._flops_avoided.value()),
             "moe_pairs_local": int(self._moe_pairs.value()),
             "moe_experts_hit": int(self._moe_hit.value()),
+            "latent_positions_read": int(self._latent_positions.value()),
             "param_casts": int(self._param_casts.value()),
             "latency_window": n,
         }

@@ -362,6 +362,83 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     assert not copies, copies
 
 
+def test_latent_decoder_programs_compile_at_published_widths(one_chip):
+    """``DecoderLM`` with latent attention as the engine builds its
+    programs, at the deepseek-v2-ep8 cell's widths (hidden 5120, 128 heads
+    of 128 + 64 / 128 over a 1536-wide query and a 512 + 64-wide key/value
+    latent, 20 of 160 experts of 1536 by group-limited routing, two shared
+    experts, 48 slots x 10,240, bfloat16) and a cut depth (the dense layer
+    and one expert layer). What a CPU run cannot show: the decode program
+    keeps the latent slab in place (no copy of one, no per-head key or
+    value over the cache: its temporaries are the float32 scores), and the
+    prefill at the 8,192 bucket attends by blocks, so its plan stays far
+    under the 4.7 GB that weights and cache leave (128 heads x 8,192^2
+    float32 scores in one piece would be 34 GB)."""
+    import re
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.models.decoder_lm import (
+        DecoderConfig,
+        init_cache,
+        init_params,
+    )
+    from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    S, T = 48, 10240
+    cfg = DecoderConfig(
+        vocab_size=12800, d_model=5120, n_heads=128, head_dim=192,
+        v_head_dim=128, rotary_dim=64,
+        attn_kinds={"latent": {
+            "rope_theta": 1e4,
+            "rope_scaling": {"type": "yarn", "factor": 40, "beta_fast": 32,
+                             "beta_slow": 1, "mscale": 0.707,
+                             "mscale_all_dim": 0.707,
+                             "original_max_position_embeddings": 4096},
+            "latent": {"q_rank": 1536, "kv_rank": 512}}},
+        layers=[("latent", "dense"), ("latent", "experts")],
+        dense_width=12288, expert_width=1536, n_experts=160, top_k=6,
+        experts_held=(0, 20), norm_eps=1e-6, max_length=T,
+        routing={"n_group": 8, "topk_group": 3, "renormalise": False,
+                 "scale": 16.0},
+        shared_width=3072)
+    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
+                         lambda name: None)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(lambda: init_params(cfg)))
+    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    slab = math.prod(caches[0][0].shape)        # one layer's: 283 M values
+    assert [tuple(c.shape for c in seg) for seg in caches] == [
+        ((1, S, 576, T),), ((1, S, 576, T),)]
+
+    def slab_copies(text):
+        return [(name, dims) for name, dims in re.findall(
+            r"%(\S+) = bf16\[([\d,]+)\]\S* copy\(", text)
+            if math.prod(map(int, dims.split(","))) >= slab // 2]
+
+    decode = be._decode_fn.lower(
+        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    text = decode.as_text()
+    assert "ragged-dot" in text and not slab_copies(text)
+    # 0.27 GB planned at six layers: one layer's float32 scores
+    # (48 x 128 x 10,240 x 4 B = 252 MB) and small change; a head's keys or
+    # values over the cache would be 48 x 128 x 128 x 10,240 x 2 B = 16 GB
+    assert decode.memory_analysis().temp_size_in_bytes < 450e6
+    prefill = be._prefill_fn.lower(
+        params, caches, arg((S + 1, 8), jnp.int32),
+        arg((8 + 8192,), jnp.int32)).compile()
+    assert not slab_copies(prefill.as_text())
+    # 2.1 GB planned at six layers (the plan is a layer's, not the stack's)
+    assert prefill.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
 def test_compiled_for_the_described_chip(one_chip):
     """The guard on the guard: the sharding these tests compile for is a
     TPU v5e, not the CPU the suite runs on."""
