@@ -50,6 +50,10 @@ FULL = {
                 max_length=512),
     "lm4_batch": 8, "lm4_steps": 3,
     "mlp4": (768, 3072, 768, 1000), "mlp4_rows": 32,
+    # the deepseek-v2-ep8 cell's latent decode core: 128 heads over
+    # entries of 512 + 64 values, slots of 10,240
+    "latent_core": dict(heads=128, width=576, t_c=10240, dtype="bfloat16",
+                        kv_rank=512),
 }
 TINY = {
     "lm": dict(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
@@ -63,6 +67,8 @@ TINY = {
                 max_length=128),
     "lm4_batch": 4, "lm4_steps": 3,
     "mlp4": (32, 64, 32, 8), "mlp4_rows": 8,
+    "latent_core": dict(heads=4, width=32, t_c=64, dtype="float32",
+                        kv_rank=16),
 }
 
 #: relative tolerance of one logit row against another: bf16 keeps 8
@@ -359,13 +365,19 @@ def phase_resnet_serve(size):
     return {"model": size["serve_model"], "rc": rc}
 
 
-def phase_kernels(platform):
+def phase_kernels(platform, size=None):
     """Which Pallas kernels the phases asked for and what each resolved
     to. On the TPU backend a kernel that fell back to its reference is
-    a failure: the run would otherwise pass on dense XLA."""
+    a failure: the run would otherwise pass on dense XLA. No phase above
+    serves a latent layer, so with ``size`` its decode kernel is asked for
+    here, at the widths a cell runs it at (its probe holds it to the
+    einsums)."""
     from deeplearning4j_tpu.nn.conf.layers import attention
+    from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
     from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
 
+    if size is not None:
+        latent_decode_impl(**size["latent_core"])
     snap = default_kernel_registry().snapshot()
     flash = {repr(k): (None if impl is None
                        else getattr(impl.args[0], "__module__", "?"))
@@ -598,7 +610,7 @@ def main(argv=None) -> int:
         del model
         run_phase("resnet_train", phase_resnet_train, meter, size)
         run_phase("resnet_serve", phase_resnet_serve, meter, size)
-    run_phase("kernels", phase_kernels, meter, dev["platform"])
+    run_phase("kernels", phase_kernels, meter, dev["platform"], size)
     seconds, hits, misses = meter.read()
     print(json.dumps({
         "total_seconds": round(time.perf_counter() - t0, 3),

@@ -64,6 +64,7 @@ from deeplearning4j_tpu.nn.conf.layers.moe import (
     moe_dropless_ffn,
     sigmoid_topk_route,
 )
+from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
 
 Array = jax.Array
 
@@ -472,7 +473,13 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
     sum out: each cached position is read once for all heads and no key
     or value of a head is ever made over the cache. Both einsums take the
     slab whole (the weighted sum over all its rows, the rotary key's
-    dropped after): a slice of it would be copied."""
+    dropped after): a slice of it would be copied. They also take every
+    column of every row, whatever it holds. With ``cache`` = (the
+    segment's slabs (layers, b, kv_rank + rotary_dim, Tc), layer,
+    lengths (b,)) and Tq = 1 the same sums come from the kernel of
+    ``nn/ops/latent_decode.py``, which reads row s of layer ``layer`` in
+    its first ``lengths[s]`` columns, once (``_run_stack`` hands the cache
+    over in this form where the kernel registry admits the shapes)."""
     ak = cfg.attn_kinds[kind]
     b, tq, _d = x.shape
     hq, rot, vd = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
@@ -500,26 +507,34 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
                 o = _causal_blocked(jnp.concatenate([q_nope, q_pe], axis=-1),
                                     k, v, scale, PREFILL_BLOCK, n_real)
         else:
-            slab, c_pos = cache
             q_lat = jnp.concatenate(
                 [jnp.einsum("bqhn,chn->bqhc", q_nope, bp["Wuk"]), q_pe], axis=-1)
-            with _scope("attn_latent_core"):
-                s_own = jnp.einsum("bqhc,bkc->bqhk", q_lat, new,
-                                   preferred_element_type=f32) * scale
-                s_own = jnp.where(_visible(q_pos, q_pos, None)[:, :, None],
-                                  s_own, _NEG)
-                s_c = jnp.einsum("bqhc,bct->bqht", q_lat, slab,
-                                 preferred_element_type=f32) * scale
-                s_c = jnp.where(_visible(q_pos, c_pos, None)[:, :, None],
-                                s_c, _NEG)
-                m = jnp.maximum(s_own.max(-1), s_c.max(-1))[..., None]
-                e_own, e_c = jnp.exp(s_own - m), jnp.exp(s_c - m)
-                lat = (jnp.einsum("bqhk,bkc->bqhc", e_own.astype(dt), new,
-                                  preferred_element_type=f32)
-                       + jnp.einsum("bqht,bct->bqhc", e_c.astype(dt), slab,
-                                    preferred_element_type=f32))
-                z = (e_own.sum(-1) + e_c.sum(-1))[..., None]
-                lat = (lat[..., :kr] / z).astype(dt)
+            if len(cache) == 3:
+                slabs, layer, lengths = cache
+                core = latent_decode_impl(hq, kr + rot, slabs.shape[-1],
+                                          slabs.dtype, kr)
+                with _scope("attn_latent_core"):
+                    lat = core(q_lat[:, 0], new[:, 0], slabs, layer, lengths,
+                               scale=scale)[:, None]
+            else:
+                slab, c_pos = cache
+                with _scope("attn_latent_core"):
+                    s_own = jnp.einsum("bqhc,bkc->bqhk", q_lat, new,
+                                       preferred_element_type=f32) * scale
+                    s_own = jnp.where(_visible(q_pos, q_pos, None)[:, :, None],
+                                      s_own, _NEG)
+                    s_c = jnp.einsum("bqhc,bct->bqht", q_lat, slab,
+                                     preferred_element_type=f32) * scale
+                    s_c = jnp.where(_visible(q_pos, c_pos, None)[:, :, None],
+                                    s_c, _NEG)
+                    m = jnp.maximum(s_own.max(-1), s_c.max(-1))[..., None]
+                    e_own, e_c = jnp.exp(s_own - m), jnp.exp(s_c - m)
+                    lat = (jnp.einsum("bqhk,bkc->bqhc", e_own.astype(dt), new,
+                                      preferred_element_type=f32)
+                           + jnp.einsum("bqht,bct->bqhc", e_c.astype(dt), slab,
+                                        preferred_element_type=f32))
+                    z = (e_own.sum(-1) + e_c.sum(-1))[..., None]
+                    lat = (lat[..., :kr] / z).astype(dt)
             o = jnp.einsum("bqhc,chv->bqhv", lat, bp["Wuv"])
         if cfg.value_scale != 1.0:
             o = o * cfg.value_scale
@@ -556,6 +571,17 @@ def _experts(cfg: DecoderConfig, bp: Dict[str, Array], r_in: Array, dtype,
 EXPERT_STACKS = ("Eg", "Eu", "Ed")
 
 
+def _latent_kernel_admits(cfg: DecoderConfig, kind: str, slab: Array) -> bool:
+    """Whether a decode step over ``slab`` (layers, b, width, Tc) of
+    attention kind ``kind`` goes through the length-aware kernel: a latent
+    kind, and the kernel registry's verdict for these shapes (a TPU, the
+    probe passed; elsewhere the einsums serve)."""
+    latent = cfg.attn_kinds[kind]["latent"]
+    return bool(latent) and latent_decode_impl(
+        cfg.n_heads, slab.shape[2], slab.shape[3], slab.dtype,
+        latent["kv_rank"]) is not None
+
+
 def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
           x: Array, q_pos: Array, cache=None, token_mask=None, layer=None):
     """One layer on x (b, Tq, d) at absolute positions q_pos (b, Tq);
@@ -566,7 +592,8 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     cache is only READ: the layer's new (b, hkv, Tq, hd) keys and
     (b, hkv, Tq, vd) values are returned for the caller to drop (full
     forward), write whole (prefill) or append (decode). A latent kind's
-    cache is (slab (b, kv_rank + rotary_dim, Tc), c_pos) and what it
+    cache is (slab (b, kv_rank + rotary_dim, Tc), c_pos), or (the
+    segment's slabs, layer, lengths) for the decode kernel, and what it
     returns in their place is ((b, Tq, kv_rank + rotary_dim) entries,)
     (:func:`_latent_attention`). With ``layer``
     the expert weights in ``bp`` are a segment's whole stacks and
@@ -657,7 +684,9 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     over its stacked layers. ``caches``: per segment the slabs to read,
     (K, V) (layers, b, hkv, hd, Tc) or a latent segment's one
     (layers, b, width, Tc), with ``c_pos`` the position map of each
-    attention kind. Returns (x, per segment what the layers made to
+    attention kind (a latent segment's decode step where the kernel
+    registry admits it: the slab whole, the layer's index and the rows'
+    lengths instead). Returns (x, per segment what the layers made to
     cache, (k, v) stacks (layers, b, hkv, Tq, hd) or (entries
     (layers, b, Tq, width),), summed expert counters)."""
     new_kv = []
@@ -668,16 +697,29 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
         # product takes them whole and the layer's index
         stacks = {k: seg[k] for k in EXPERT_STACKS if k in seg}
         scanned = {k: v for k, v in seg.items() if k not in stacks}
+        kv = None if caches is None else caches[i]
+        # nor is a latent segment's slab where the decode kernel reads it
+        # (a custom call's operand is made whole: the scan's slice of the
+        # slab would be copied a layer), by the rows' lengths: a row that
+        # is not active has none
+        whole = lengths = None
+        if (kv is not None and x.shape[1] == 1
+                and _latent_kernel_admits(cfg, kind, kv[0])):
+            (whole,), kv = kv, None
+            lengths = q_pos[:, 0] if token_mask is None else jnp.where(
+                token_mask[:, 0], q_pos[:, 0], 0)
 
-        def body(x, xs, kind=kind, ffn=ffn, stacks=stacks):
+        def body(x, xs, kind=kind, ffn=ffn, stacks=stacks, whole=whole,
+                 lengths=lengths):
             bp, kv, layer = xs
             cache = None if kv is None else (*kv, c_pos[kind])
+            if whole is not None:
+                cache = (whole, layer, lengths)
             x, knew, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
                                     q_pos, cache, token_mask,
                                     layer if stacks else None)
             return x, (knew, counts)
 
-        kv = None if caches is None else caches[i]
         x, (knew, counts) = jax.lax.scan(
             body, x, (scanned, kv, jnp.arange(n, dtype=jnp.int32)))
         new_kv.append(knew)

@@ -9,6 +9,8 @@ The kernel SUBSYSTEM (this package):
 - ``fused_lstm`` — the LSTM cell (training scan + engine decode);
 - ``fused_update`` — the single-pass ZeRO-1 Adam update;
 - ``int8_matmul`` — int8 weight-quantized serving matmul;
+- ``latent_decode`` — a decode step's attention over a latent cache, by
+  the slots' live lengths;
 - ``registry`` — the shared probe-once/fallback/observability contract
   every kernel resolves through (``KernelRegistry``).
 """

@@ -38,6 +38,11 @@ from deeplearning4j_tpu.nn.conf.layers.moe import (  # noqa: E402
     moe_dropless_ffn,
     shared_swiglu,
 )
+from deeplearning4j_tpu.nn.ops import latent_decode  # noqa: E402
+from deeplearning4j_tpu.nn.ops.registry import (  # noqa: E402
+    ENV_FLAGS,
+    default_kernel_registry,
+)
 
 TOL = 1e-5
 SEED = 7
@@ -94,6 +99,20 @@ def small_blocks():
 def base():
     cfg = tiny()
     return cfg, build(cfg)
+
+
+#: the two ways a decode step reads the latent cache: the whole-slab
+#: einsums (what the CPU gets) and the length-aware kernel
+#: (``nn/ops/latent_decode.py``) under the Pallas interpreter
+CORES = ["einsums", "kernel"]
+
+
+def kernel_interpreted(patch):
+    """The registry's mode ``interpret`` and a tile of 8 columns, for what
+    is traced while ``patch`` lasts."""
+    patch.setenv(ENV_FLAGS[latent_decode.NAME], "interpret")
+    patch.setattr(latent_decode, "TILE", 8)
+    default_kernel_registry().reset(latent_decode.NAME)
 
 
 # -- the whole model ----------------------------------------------------------
@@ -192,11 +211,14 @@ def test_yarn_frequencies_and_scale_by_hand():
 
 
 # -- absorbed against expanded ------------------------------------------------
-def test_absorbed_step_equals_expanded_attention(base):
+@pytest.mark.parametrize("core", CORES)
+def test_absorbed_step_equals_expanded_attention(base, core, monkeypatch):
     """One latent layer on the same float32 weights: the expanded form over
     21 positions against the absorbed form for the last position over a
     cache that holds the entries of the first 20 (with idle columns after
-    them). Equal to rounding: the two contract in different orders."""
+    them). Equal to rounding: the two contract in different orders. The
+    kernel reads the same cache as layer 1 of a segment's three slabs, by
+    the rows' lengths."""
     _cfg, model = base
     cfg = model.cfg
     bp = {k: v[0] for k, v in model.params_["segments"][1].items() if v.ndim and k[0] != "E"}
@@ -206,8 +228,13 @@ def test_absorbed_step_equals_expanded_attention(base):
     assert entries.shape == (2, 21, 32)  # kv_lora_rank 16 + 16 rotated
     slab = jnp.zeros((2, 32, 40)).at[:, :, :20].set(entries[:, :20].transpose(0, 2, 1))
     c_pos = decoder_lm.cache_positions(cfg, jnp.asarray([20, 20]), 40)["latent"]
+    cache = (slab, c_pos)
+    if core == "kernel":
+        kernel_interpreted(monkeypatch)
+        cache = (jnp.full((3, 2, 32, 40), jnp.nan).at[1].set(slab),
+                 jnp.asarray(1, jnp.int32), jnp.asarray([20, 20], jnp.int32))
     step, entry = decoder_lm._latent_attention(
-        cfg, "latent", bp, x[:, 20:], pos[:, 20:], (slab, c_pos))
+        cfg, "latent", bp, x[:, 20:], pos[:, 20:], cache)
     np.testing.assert_allclose(np.asarray(step[:, 0]), np.asarray(whole[:, 20]), atol=2e-6)
     np.testing.assert_allclose(np.asarray(entry[:, 0]), np.asarray(entries[:, 20]), atol=1e-6)
     assert np.abs(np.asarray(whole[:, 20] - x[:, 20])).max() > 1e-3  # attention did add something
@@ -409,19 +436,41 @@ def test_expert_layer_in_token_chunks_gives_what_one_call_gives(base, monkeypatc
 
 
 # -- the engine ---------------------------------------------------------------
-@pytest.fixture(scope="module")
-def engine(base):
+def _engine(model):
     from deeplearning4j_tpu.serving.generate import GenerationEngine
 
-    _cfg, model = base
     gen = GenerationEngine(model, n_slots=3, max_length=96, prefill_buckets=[8, 16, 32])
     gen.warmup()
+    return gen
+
+
+@pytest.fixture(scope="module")
+def engine(base):
+    gen = _engine(base[1])
     yield gen
     gen.shutdown(drain=False)
 
 
-def test_engine_serves_what_the_model_generates_alone(base, engine):
+@pytest.fixture(scope="module")
+def kernel_engine(base):
+    """An engine whose decode program was traced with the kernel (12 tiles
+    of 8 columns a slot)."""
+    with pytest.MonkeyPatch.context() as patch:
+        kernel_interpreted(patch)
+        gen = _engine(base[1])
+        verdicts = default_kernel_registry().snapshot()[latent_decode.NAME]
+    default_kernel_registry().reset(latent_decode.NAME)
+    assert [v["enabled"] for v in verdicts.values()] == [True], verdicts
+    yield gen
+    gen.shutdown(drain=False)
+
+
+@pytest.mark.parametrize("core", CORES)
+def test_engine_serves_what_the_model_generates_alone(base, core, request):
+    """``alone`` is the model's own cached generation, whose decode program
+    reads the cache by the einsums under either engine."""
     cfg, model = base
+    engine = request.getfixturevalue("engine" if core == "einsums" else "kernel_engine")
     traced = dict(engine.trace_counts)
     prompts = [ids_of(cfg, n, seed=n) for n in (5, 9, 20, 31, 12)]
     requests = [engine.submit(p, max_new=24) for p in prompts]

@@ -362,27 +362,43 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     assert not copies, copies
 
 
-def test_latent_decoder_programs_compile_at_published_widths(one_chip):
+def test_latent_decoder_programs_compile_at_published_widths(one_chip,
+                                                             monkeypatch):
     """``DecoderLM`` with latent attention as the engine builds its
     programs, at the deepseek-v2-ep8 cell's widths (hidden 5120, 128 heads
     of 128 + 64 / 128 over a 1536-wide query and a 512 + 64-wide key/value
     latent, 20 of 160 experts of 1536 by group-limited routing, two shared
     experts, 48 slots x 10,240, bfloat16) and a cut depth (the dense layer
     and one expert layer). What a CPU run cannot show: the decode program
-    keeps the latent slab in place (no copy of one, no per-head key or
-    value over the cache: its temporaries are the float32 scores), and the
-    prefill at the 8,192 bucket attends by blocks, so its plan stays far
-    under the 4.7 GB that weights and cache leave (128 heads x 8,192^2
-    float32 scores in one piece would be 34 GB)."""
+    reads the cache through the length-aware kernel
+    (``nn/ops/latent_decode.py``: Mosaic takes it at 128 heads x 576 x
+    10,240; the registry's verdict is steered here, since this process's
+    backend is the CPU) and keeps the latent slab in place (no copy of
+    one, no per-head key or value over the cache, no float32 scores of a
+    whole slab), and the prefill at the 8,192 bucket attends by blocks, so
+    its plan stays far under the 4.7 GB that weights and cache leave (128
+    heads x 8,192^2 float32 scores in one piece would be 34 GB)."""
+    import functools
     import re
     from types import SimpleNamespace
 
+    from deeplearning4j_tpu.models import decoder_lm
     from deeplearning4j_tpu.models.decoder_lm import (
         DecoderConfig,
         init_cache,
         init_params,
     )
+    from deeplearning4j_tpu.nn.ops import latent_decode
     from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    asked = []
+
+    def admitted(heads, width, t_c, dtype, kv_rank):
+        asked.append((heads, width, t_c, jnp.dtype(dtype).name, kv_rank))
+        return functools.partial(latent_decode.latent_decode_core,
+                                 kv_rank=kv_rank, tile=latent_decode.TILE)
+
+    monkeypatch.setattr(decoder_lm, "latent_decode_impl", admitted)
 
     S, T = 48, 10240
     cfg = DecoderConfig(
@@ -427,10 +443,16 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip):
         params, caches, arg((S + 1, 8), jnp.int32)).compile()
     text = decode.as_text()
     assert "ragged-dot" in text and not slab_copies(text)
-    # 0.27 GB planned at six layers: one layer's float32 scores
-    # (48 x 128 x 10,240 x 4 B = 252 MB) and small change; a head's keys or
-    # values over the cache would be 48 x 128 x 128 x 10,240 x 2 B = 16 GB
-    assert decode.memory_analysis().temp_size_in_bytes < 450e6
+    assert set(asked) == {(128, 576, T, "bfloat16", 512)}
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "attn_latent_core" in line]
+    assert len(kernels) == 2, kernels                   # one a segment
+    assert all(latent_decode.NAME in line for line in kernels)
+    # the einsum path planned 0.27 GB: one layer's float32 scores (48 x 128
+    # x 10,240 x 4 B = 252 MB) and small change; a head's keys or values
+    # over the cache would be 48 x 128 x 128 x 10,240 x 2 B = 16 GB
+    assert not re.search(rf"f32\[{S},(1,)?128,(1,)?{T}\]", text)
+    assert decode.memory_analysis().temp_size_in_bytes < 100e6
     prefill = be._prefill_fn.lower(
         params, caches, arg((S + 1, 8), jnp.int32),
         arg((8 + 8192,), jnp.int32)).compile()
