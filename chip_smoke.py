@@ -4,7 +4,8 @@ server that answers requests — run on one TPU v5e through the entry
 points a user calls, at the full width of models the repo supports.
 
     python chip_smoke.py              one chip: device, lm_train, lm_serve,
-                                      resnet_train, resnet_serve, kernels
+                                      hybrid_serve, resnet_train,
+                                      resnet_serve, kernels
     python chip_smoke.py --chips 4    the cross-chip paths only: the
                                       DistributedLMTrainer on a 2x2 mesh and
                                       tensor-parallel serving on 1x4, each
@@ -54,6 +55,24 @@ FULL = {
     # entries of 512 + 64 values, slots of 10,240
     "latent_core": dict(heads=128, width=576, t_c=10240, dtype="bfloat16",
                         kv_rank=512),
+    # the tiny state-space hybrid of tests/test_granite_lm.py (two Mamba-2
+    # layers, a NoPE attention layer, another Mamba-2 layer; experts and a
+    # shared expert in each), float32 so that equal tokens mean something
+    "hybrid": dict(
+        vocab_size=256, d_model=64, n_heads=4, head_dim=16, v_head_dim=16,
+        rotary_dim=0,
+        attn_kinds={"ssm": {"ssm": dict(n_heads=8, head_dim=16, d_state=16,
+                                        n_groups=1, d_conv=4, expand=2,
+                                        chunk=8)},
+                    "attention": {"n_kv_heads": 2, "rope_theta": 1e4}},
+        layers=[("ssm", "experts")] * 2 + [("attention", "experts"),
+                                           ("ssm", "experts")],
+        dense_width=0, expert_width=32, n_experts=8, top_k=3,
+        experts_held=(4, 4), shared_width=48, max_length=128,
+        routing={"n_group": 1, "topk_group": 1, "renormalise": True},
+        param_dtype="float32", embedding_multiplier=12,
+        residual_multiplier=0.22, attention_multiplier=0.0625,
+        logits_scaling=16, tied_head=True),
 }
 TINY = {
     "lm": dict(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
@@ -70,6 +89,7 @@ TINY = {
     "latent_core": dict(heads=4, width=32, t_c=64, dtype="float32",
                         kv_rank=16),
 }
+TINY["hybrid"] = FULL["hybrid"]
 
 #: relative tolerance of one logit row against another: bf16 keeps 8
 #: bits of mantissa and a 12-block stack rounds the residual stream
@@ -332,6 +352,50 @@ def phase_lm_serve(size, model):
             "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
             "retraces_after_warmup": 0, "healthz": 200,
             "vs_generate_cached": parity}
+
+
+def phase_hybrid_serve(size):
+    """A decoder whose layers are state-space mixers around an attention
+    layer (``models/decoder_lm.py``), served by ``GenerationEngine``: more
+    requests than slots, so slots are claimed again over another request's
+    recurrent state and rows sit idle beside live ones; every request's
+    tokens against the model's own cached generation on one slot."""
+    import numpy as np
+
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
+    from deeplearning4j_tpu.serving.generate import GenerationEngine
+
+    model = DecoderLM.from_dict(size["hybrid"]).init()
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, model.cfg.vocab_size, n)
+               for n in (5, 9, 20, 31, 2)]
+    max_new = 24
+    gen = GenerationEngine(model, n_slots=3, max_length=96,
+                           prefill_buckets=[8, 16, 32])
+    try:
+        warm = gen.warmup()
+        traced = dict(gen.trace_counts)
+        requests = [gen.submit(p, max_new=max_new) for p in prompts]
+        served = [np.asarray(r.result(timeout=900)) for r in requests]
+        check(gen.trace_counts == traced,
+              f"retraced after warm-up: {traced} -> {gen.trace_counts}")
+        snapshot = gen.metrics.snapshot()
+        report = gen.describe()["memory"]
+    finally:
+        gen.shutdown()
+    for i, (prompt, got) in enumerate(zip(prompts, served)):
+        alone = model.generate_cached(prompt, max_new=max_new)
+        check(np.array_equal(got[-max_new:], alone[-max_new:]),
+              f"request {i}: engine {got[-max_new:].tolist()} != alone "
+              f"{alone[-max_new:].tolist()}")
+    check(snapshot["state_slots"] > 0, "no live state slot was counted")
+    return {"requests": len(prompts), "slots": 3, "max_new": max_new,
+            "segments": model.cfg.segments(),
+            "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
+            "state_bytes": report["state_bytes"],
+            "slab_bytes": report["slab_bytes"],
+            "state_slots": snapshot["state_slots"],
+            "tokens_equal_generate_cached": True}
 
 
 def phase_resnet_train(size):
@@ -608,6 +672,7 @@ def main(argv=None) -> int:
         model = run_phase("lm_train", phase_lm_train, meter, size)
         run_phase("lm_serve", phase_lm_serve, meter, size, model)
         del model
+        run_phase("hybrid_serve", phase_hybrid_serve, meter, size)
         run_phase("resnet_train", phase_resnet_train, meter, size)
         run_phase("resnet_serve", phase_resnet_serve, meter, size)
     run_phase("kernels", phase_kernels, meter, dev["platform"], size)

@@ -11,11 +11,14 @@ heads), a leading dense layer and then expert layers of which a chip
 holds its share, routed by sigmoid or by group-limited softmax scores,
 with or without a shared expert. Here all of that is configuration:
 
-- ``attn_kinds`` names the kinds of attention layer (key/value heads,
-  rotary base and scaling, window, sink, or the ranks of a latent kind)
-  and ``layers`` gives each layer's attention kind and FFN kind
+- ``attn_kinds`` names the kinds of MIXER, a layer's first half: the
+  kinds of attention layer (key/value heads, rotary base and scaling,
+  window, sink, or the ranks of a latent kind) and, since a layer need
+  not attend at all, the state-space kind (``"ssm"``: a Mamba-2 mixer's
+  heads, head size, state size, groups, convolution width, expansion and
+  chunk); ``layers`` gives each layer's mixer kind and FFN kind
   (``"dense"`` / ``"experts"``), in order;
-- consecutive layers of one (attention, FFN) pair form a SEGMENT whose
+- consecutive layers of one (mixer, FFN) pair form a SEGMENT whose
   parameters are stacked and scanned; the stack is the list of segments;
 - ONE block function (:func:`block`) serves the full forward, prefill
   and decode. It attends over the step's own keys and, when given one,
@@ -37,7 +40,19 @@ with or without a shared expert. Here all of that is configuration:
   configuration's ``routing``) and compute the part of the result their
   held experts give, plus the shared expert where there is one
   (``nn/conf/layers/moe.moe_dropless_ffn``); the vocabulary may be the
-  chip's slice of the published one.
+  chip's slice of the published one;
+- a state-space layer keeps NO columns: its cache is a recurrent state
+  (layers, slots, heads, head size, state size) in float32 and the last
+  ``d_conv - 1`` inputs of its convolution, whatever the slot's length.
+  A decode step reads a layer's whole state and writes it whole, so the
+  state goes through the layer loop as a CARRY updated in place on the
+  donated buffer (a scan's stacked output would be a second copy of it);
+  prefill is the chunked dual form (:func:`_ssm_chunked`), whose padding
+  leaves the state alone;
+- four scalars of the configuration scale the embedding, every residual
+  branch, the attention scores and the logits (each 1, or
+  ``1/sqrt(head)``, by default), ``rotary_dim`` 0 means no positions at
+  all, and the head may be the embedding read transposed (``tied_head``).
 
 Serving only: there is no training step for this block yet (ROADMAP M1).
 """
@@ -75,38 +90,66 @@ Array = jax.Array
 #: weights, around ``attn_latent_core``, the scores over the latent cache,
 #: the softmax and the weighted sum of latents; in prefill the blocked
 #: attention), the dense FFN, the two halves of an expert layer and its
-#: shared expert
+#: shared expert; a state-space mixer's in three (``ssm_proj``: the norm,
+#: the two projections and the gated norm, bound by weights; ``ssm_conv``:
+#: the causal convolution and its tail; ``ssm_scan``: the recurrence, one
+#: step over the cached state in decode, the chunked form in prefill) and
+#: ``state_write``, a prefill's write of its slot's state and tail
 SCOPES = ("attn_full", "attn_window", "attn_latent_proj", "attn_latent_core",
-          "mlp", "moe_route", "moe_experts", "moe_shared")
+          "mlp", "moe_route", "moe_experts", "moe_shared",
+          "ssm_proj", "ssm_conv", "ssm_scan", "state_write")
 _scope = jax.named_scope
 _NEG = -1e30
 #: queries and keys a block of a latent layer's prefill attention
 #: (``_causal_blocked``): the score tensor a program plans is (heads,
 #: block, block) float32, 128 MB at 128 heads, whatever the bucket
 PREFILL_BLOCK = 512
+#: a full layer without a cache (forward, prefill) attends by those blocks
+#: too where the float32 scores of the whole bucket, heads x T x T, would
+#: be larger than this (32 heads at 4,096 positions: 2.1 GB beside 13 GB
+#: of weights and cache); under it the scores are one tensor, as they
+#: were before there was a bucket that long
+BLOCKED_SCORE_BYTES = 1 << 30
 #: tokens an expert layer takes at a time (``_experts``): the gathered
 #: rows of a longer prefill, N x k x d in float32, would not fit beside
 #: the weights
 EXPERT_TOKEN_CHUNK = 2048
 
 
+#: what a state-space kind states (``DecoderConfig.attn_kinds[...]["ssm"]``)
+_SSM_FIELDS = ("n_heads", "head_dim", "d_state", "n_groups", "d_conv",
+               "expand", "chunk")
+
+
 class DecoderConfig:
-    """The decoder as data. ``attn_kinds``: name -> {"n_kv_heads",
-    "rope_theta", "window" (None = full), "sink" (bool)} and, optional,
+    """The decoder as data. ``attn_kinds``: name -> a kind of MIXER (the
+    name stays from when every mixer attended). An attention kind is
+    {"n_kv_heads", "rope_theta", "window" (None = full), "sink" (bool)} and, optional,
     "rope_scaling" (YaRN: ``factor``, ``beta_fast``, ``beta_slow``,
     ``mscale``, ``mscale_all_dim``, ``original_max_position_embeddings``)
     and "latent" = {"q_rank", "kv_rank"}: a latent kind,
     whose heads are ``head_dim`` = (``head_dim - rotary_dim`` without
     position | ``rotary_dim`` rotated) wide and share ONE rotary key, and
-    whose cache entry is ``kv_rank + rotary_dim`` values a position.
-    ``layers``: one (attention kind, "dense" | "experts") pair a layer.
+    whose cache entry is ``kv_rank + rotary_dim`` values a position. A
+    state-space kind is {"ssm": {"n_heads", "head_dim", "d_state",
+    "n_groups", "d_conv", "expand", "chunk"}} (Mamba-2: ``expand x
+    d_model`` = ``n_heads x head_dim`` inner channels, a state of
+    ``head_dim x d_state`` a head, B and C shared by the heads of a group,
+    a causal depthwise convolution ``d_conv`` wide, prefill by chunks of
+    ``chunk``); it keeps no columns and takes none of the attention keys.
+    ``layers``: one (mixer kind, "dense" | "experts") pair a layer.
     ``experts_held`` = (offset, count): which of the ``n_experts`` the
     router scores have their weights here. ``routing``: None (sigmoid
     scores, a correction bias in the choice, weights renormalised) or
     {"n_group", "topk_group", "renormalise", "scale"} (softmax scores,
     group-limited: no bias). ``shared_width``: the shared expert's width
     (0: none). ``vocab_size`` is what is held here (the chip's slice,
-    where the vocabulary is sliced)."""
+    where the vocabulary is sliced). ``rotary_dim`` 0: no positions.
+    ``embedding_multiplier`` scales the embedded tokens,
+    ``residual_multiplier`` every residual branch (mixer and FFN),
+    ``attention_multiplier`` the attention scores (None: ``1/sqrt(head)``)
+    and ``logits_scaling`` divides the logits; ``tied_head``: the head is
+    the embedding, one leaf, read transposed."""
 
     def __init__(self, vocab_size: int, d_model: int, n_heads: int,
                  head_dim: int, v_head_dim: int, rotary_dim: int,
@@ -117,7 +160,10 @@ class DecoderConfig:
                  value_scale: float = 1.0, norm_eps: float = 1e-5,
                  max_length: int = 2048, param_dtype: str = "bfloat16",
                  seed: int = 0, routing: Optional[dict] = None,
-                 shared_width: int = 0):
+                 shared_width: int = 0, embedding_multiplier: float = 1.0,
+                 residual_multiplier: float = 1.0,
+                 attention_multiplier: Optional[float] = None,
+                 logits_scaling: float = 1.0, tied_head: bool = False):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.n_heads = int(n_heads)
@@ -128,7 +174,8 @@ class DecoderConfig:
             raise ValueError("rotary_dim must be even and <= head_dim")
         self.attn_kinds = {
             name: {"n_kv_heads": int(k.get("n_kv_heads", n_heads)),
-                   "rope_theta": float(k["rope_theta"]),
+                   "rope_theta": float(k.get("rope_theta", 0.0)
+                                       if k.get("ssm") else k["rope_theta"]),
                    "window": None if k.get("window") is None
                    else int(k["window"]),
                    "sink": bool(k.get("sink", False)),
@@ -136,12 +183,24 @@ class DecoderConfig:
                                     if k.get("rope_scaling") else None),
                    "latent": ({"q_rank": int(k["latent"]["q_rank"]),
                                "kv_rank": int(k["latent"]["kv_rank"])}
-                              if k.get("latent") else None)}
+                              if k.get("latent") else None),
+                   "ssm": ({f: int(k["ssm"][f]) for f in _SSM_FIELDS}
+                           if k.get("ssm") else None)}
             for name, k in attn_kinds.items()}
         for name, k in self.attn_kinds.items():
             if k["latent"] and (k["window"] is not None or k["sink"]):
                 raise ValueError(f"latent kind {name!r} takes no window or "
                                  "sink")
+            if k["ssm"]:
+                m = k["ssm"]
+                if (k["latent"] or k["window"] is not None or k["sink"]
+                        or m["n_heads"] * m["head_dim"]
+                        != m["expand"] * self.d_model
+                        or m["n_heads"] % m["n_groups"]):
+                    raise ValueError(
+                        f"ssm kind {name!r}: n_heads x head_dim = expand x "
+                        "d_model, n_groups dividing n_heads, and none of "
+                        "the attention keys")
         self.layers = [(str(a), str(f)) for a, f in layers]
         for a, f in self.layers:
             if a not in self.attn_kinds or f not in ("dense", "experts"):
@@ -173,6 +232,12 @@ class DecoderConfig:
             raise ValueError("routing: n_group groups that divide "
                              "n_experts, or None")
         self.shared_width = int(shared_width)
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.residual_multiplier = float(residual_multiplier)
+        self.attention_multiplier = (None if attention_multiplier is None
+                                     else float(attention_multiplier))
+        self.logits_scaling = float(logits_scaling)
+        self.tied_head = bool(tied_head)
 
     @property
     def n_layers(self) -> int:
@@ -183,7 +248,7 @@ class DecoderConfig:
         return jnp.bfloat16 if self.param_dtype == "bfloat16" else jnp.float32
 
     def segments(self) -> List[Tuple[str, str, int]]:
-        """Runs of consecutive layers of one kind: (attention kind, FFN
+        """Runs of consecutive layers of one kind: (mixer kind, FFN
         kind, layers in the run). Each is one ``lax.scan``."""
         out: List[List] = []
         for a, f in self.layers:
@@ -199,6 +264,14 @@ class DecoderConfig:
         window = self.attn_kinds[kind]["window"]
         return int(max_length) if window is None else min(window,
                                                           int(max_length))
+
+    def ssm_dims(self, kind: str) -> Tuple[int, int, int, int, int]:
+        """(heads H, head size P, state size N, inner channels H x P,
+        convolved channels H x P + 2 x groups x N) of a state-space kind."""
+        m = self.attn_kinds[kind]["ssm"]
+        inner = m["n_heads"] * m["head_dim"]
+        return (m["n_heads"], m["head_dim"], m["d_state"], inner,
+                inner + 2 * m["n_groups"] * m["d_state"])
 
     def latent_width(self, kind: str) -> int:
         """Values a latent kind caches a position and layer: the
@@ -221,10 +294,28 @@ class DecoderConfig:
         shapes (layers, slots, kv heads, head size, columns) of K and V
         or, for a latent segment, ONE slab (layers, slots, kv_rank +
         rotary_dim, columns); ``values``: what a position and layer
-        keeps."""
+        keeps. A state-space segment keeps no columns: ``state`` (layers,
+        slots, heads, head size, state size) in float32 (a bfloat16
+        state would round at every step of a recurrence thousands long)
+        and ``conv`` (layers, slots, convolved channels, d_conv - 1), the
+        convolution's last inputs, in the parameter dtype: the same bytes
+        whatever ``max_length``. ``dtypes`` goes with ``slabs``."""
         item = jnp.dtype(self.dtype).itemsize
         plan = []
         for kind, _ffn, n in self.segments():
+            if self.attn_kinds[kind]["ssm"]:
+                h, p, ns, _inner, conv = self.ssm_dims(kind)
+                tail = self.attn_kinds[kind]["ssm"]["d_conv"] - 1
+                state = (n, int(n_slots), h, p, ns)
+                taps = (n, int(n_slots), conv, tail)
+                plan.append({
+                    "kind": kind, "layers": n, "columns": 0,
+                    "state": state, "conv": taps,
+                    "slabs": [state, taps],
+                    "dtypes": [jnp.float32, self.dtype],
+                    "bytes": int(np.prod(state)) * 4
+                    + int(np.prod(taps)) * item})
+                continue
             cols = self.cache_columns(kind, max_length)
             entry = {"kind": kind, "layers": n, "columns": cols,
                      "ring": self.attn_kinds[kind]["window"] is not None}
@@ -237,7 +328,8 @@ class DecoderConfig:
                          (n, int(n_slots), hkv, self.v_head_dim, cols)]
                 entry["k"], entry["v"] = slabs
                 width = hkv * (self.head_dim + self.v_head_dim)
-            entry.update(slabs=slabs, values=width, bytes=sum(
+            entry.update(slabs=slabs, dtypes=[self.dtype] * len(slabs),
+                         values=width, bytes=sum(
                 int(np.prod(shape)) for shape in slabs) * item)
             plan.append(entry)
         return plan
@@ -254,12 +346,29 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
     (q_rank, heads, head size), and the key/value one in its two halves,
     ``Wuk`` (kv_rank, heads, head size - rotary_dim) and ``Wuv`` (kv_rank,
     heads, value size), which the absorbed decode contracts on opposite
-    sides and never together."""
+    sides and never together. A state-space kind's input projection is
+    ONE leaf, ``Win`` (d, inner + convolved + heads) with its columns in
+    the published order [z | xBC | dt]: one product reads the weights
+    once, and its three parts are cut from the RESULT at offsets that are
+    multiples of 128 lanes at the published widths (8,192 and 16,640), so
+    no part of the weight is ever sliced or re-laid (by compile:
+    ``tests/test_tpu_compile.py``). ``conv_w`` (channels, d_conv) and
+    ``conv_b`` are the depthwise convolution, ``dt_bias``, ``A_log`` and
+    ``D`` (heads,) the recurrence's per-head scalars and ``norm_g`` the
+    gated norm's gain, float32; ``Wo`` (inner, d) brings the result
+    out."""
     d, hq = cfg.d_model, cfg.n_heads
     ak = cfg.attn_kinds[kind]
     pd, f32 = cfg.dtype, jnp.float32
     out = {"norm1": ((d,), f32), "norm2": ((d,), f32)}
-    if ak["latent"]:
+    if ak["ssm"]:
+        h, _p, _n, inner, conv = cfg.ssm_dims(kind)
+        out.update({"Win": ((d, inner + conv + h), pd),
+                    "conv_w": ((conv, ak["ssm"]["d_conv"]), pd),
+                    "conv_b": ((conv,), pd), "dt_bias": ((h,), f32),
+                    "A_log": ((h,), f32), "D": ((h,), f32),
+                    "norm_g": ((inner,), f32), "Wo": ((inner, d), pd)})
+    elif ak["latent"]:
         qr, kr = ak["latent"]["q_rank"], ak["latent"]["kv_rank"]
         out.update({"Wqa": ((d, qr), pd), "norm_q": ((qr,), f32),
                     "Wqb": ((qr, hq, cfg.head_dim), pd),
@@ -272,7 +381,7 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
         out.update({"Wq": ((d, hq, cfg.head_dim), pd),
                     "Wk": ((d, hkv * cfg.head_dim), pd),
                     "Wv": ((d, hkv * cfg.v_head_dim), pd)})
-    out["Wo"] = ((hq * cfg.v_head_dim, d), pd)
+    out.setdefault("Wo", ((hq * cfg.v_head_dim, d), pd))
     if ak["sink"]:
         out["sink"] = ((hq,), f32)
     if ffn == "dense":
@@ -295,8 +404,11 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
 
 def init_params(cfg: DecoderConfig, rng: Optional[Array] = None) -> Dict:
     """{"embed", "segments": [stacked leaves a segment], "norm_f",
-    "head"}; normal(0, 0.02) matrices, unit gains, zero sinks, a small
-    router bias."""
+    "head"} (no "head" where it is tied to the embedding); normal(0, 0.02)
+    matrices, unit gains, zero sinks, a small router bias; a state-space
+    mixer's scalars at Mamba-2's defaults (``A`` uniform in [1, 16],
+    ``dt`` log-uniform in [1e-3, 1e-1] with ``dt_bias`` its inverse
+    softplus, ``D`` 1)."""
     rng = rng if rng is not None else jax.random.PRNGKey(cfg.seed)
     keys = iter(jax.random.split(rng, 16 * len(cfg.segments()) + 4))
     pd = cfg.dtype
@@ -310,17 +422,31 @@ def init_params(cfg: DecoderConfig, rng: Optional[Array] = None) -> Dict:
         seg = {}
         for name, (shape, dtype) in segment_shapes(cfg, kind, ffn).items():
             full = (n,) + shape
-            if name.startswith("norm"):
+            if name.startswith("norm") or name == "D":
                 seg[name] = jnp.ones(full, dtype)
             elif name == "sink":
                 seg[name] = jnp.zeros(full, dtype)
+            elif name == "A_log":
+                seg[name] = jnp.log(jax.random.uniform(
+                    next(keys), full, dtype, 1.0, 16.0))
+            elif name == "conv_w":
+                seg[name] = jax.random.uniform(
+                    next(keys), full, jnp.float32, -0.5, 0.5).astype(dtype)
+            elif name == "dt_bias":
+                dt = jnp.exp(jax.random.uniform(
+                    next(keys), full, dtype, math.log(1e-3), math.log(1e-1)))
+                seg[name] = dt + jnp.log(-jnp.expm1(-dt))
             else:
                 seg[name] = normal(full, dtype)
         segments.append(seg)
-    return {"embed": normal((cfg.vocab_size, cfg.d_model), pd),
-            "segments": segments,
-            "norm_f": jnp.ones((cfg.d_model,), jnp.float32),
-            "head": normal((cfg.d_model, cfg.vocab_size), pd)}
+    # the multiplied embedding enters the stream at the matrices' scale
+    out = {"embed": normal((cfg.vocab_size, cfg.d_model), pd,
+                           0.02 / cfg.embedding_multiplier),
+           "segments": segments,
+           "norm_f": jnp.ones((cfg.d_model,), jnp.float32)}
+    if not cfg.tied_head:
+        out["head"] = normal((cfg.d_model, cfg.vocab_size), pd)
+    return out
 
 
 # -- the block ----------------------------------------------------------------
@@ -335,7 +461,10 @@ def _rotate(x, pos, rotary_dim: int, theta: float, scaling=None):
     (half-split pairing: dimension i turns with i + rotary_dim/2); the
     rest pass through. x (b, T, h, hd), pos (b, T) absolute. With
     ``scaling`` the frequencies and the amplitude are YaRN's
-    (:func:`yarn_frequencies`)."""
+    (:func:`yarn_frequencies`). ``rotary_dim`` 0: no positions, x as it
+    came."""
+    if rotary_dim == 0:
+        return x
     half = rotary_dim // 2
     if scaling is None:
         inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rotary_dim)
@@ -381,14 +510,24 @@ def yarn_frequencies(rotary_dim: int, theta: float, scaling: dict):
 
 
 def softmax_scale(cfg: "DecoderConfig", kind: str) -> float:
-    """1 / sqrt(head size), times YaRN's ``mscale_all_dim`` factor squared
-    where the kind's rotary scaling has one."""
-    scale = 1.0 / math.sqrt(cfg.head_dim)
+    """1 / sqrt(head size), or the configuration's
+    ``attention_multiplier`` where it states one, times YaRN's
+    ``mscale_all_dim`` factor squared where the kind's rotary scaling has
+    one."""
+    scale = (1.0 / math.sqrt(cfg.head_dim)
+             if cfg.attention_multiplier is None else cfg.attention_multiplier)
     sc = cfg.attn_kinds[kind]["rope_scaling"]
     if sc and sc.get("mscale_all_dim"):
         scale *= _yarn_mscale(float(sc["factor"]),
                               float(sc["mscale_all_dim"])) ** 2
     return scale
+
+
+def _residual(cfg: "DecoderConfig", x, branch):
+    """x + ``residual_multiplier`` x branch, in x's dtype."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch.astype(x.dtype)
 
 
 def _visible(q_pos, k_pos, window):
@@ -538,8 +677,163 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
             o = jnp.einsum("bqhc,chv->bqhv", lat, bp["Wuv"])
         if cfg.value_scale != 1.0:
             o = o * cfg.value_scale
-        x = x + o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"]
+        x = _residual(cfg, x, o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"])
     return x, new
+
+
+def _ssm_chunked(x, dt, a, bmat, cmat, chunk: int, n_real=None):
+    """The state-space recurrence ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t
+    (outer) B_t``, ``y_t = h_t C_t`` from a zero state over a whole
+    sequence, in the chunked dual form: x (b, T, G, R, P) (G groups of R
+    heads), dt (b, T, G, R) (0 where a position is padding: decay 1 and no
+    input, so it leaves the state alone), a (G, R) negative, bmat and cmat
+    (b, T, G, N), all float32 -> (y (b, T, G, R, P), the state after the
+    last position (b, G, R, P, N)). Inside a chunk of ``chunk`` positions
+    the outputs are ``((C B^T) * L) (dt x)`` with ``L[i, j]`` the product
+    of the decays from j + 1 to i (lower triangle), as matrix products;
+    between chunks the recurrence runs on the chunks' states: the same
+    numbers as the step-by-step scan up to summation order. With
+    ``n_real`` (traced) the chunks past the first ``n_real`` positions are
+    not visited (a bucket's padding): their rows of y come back zero."""
+    b, t = x.shape[:2]
+    q = min(chunk, t)
+    pad = -t % q
+    if pad:
+        x, dt, bmat, cmat = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                             for v in (x, dt, bmat, cmat))
+    lower = jnp.tril(jnp.ones((q, q), bool))
+
+    def one_chunk(c, carry):
+        h, out = carry
+        xc, dc, bc, cc = (jax.lax.dynamic_slice_in_dim(v, c * q, q, axis=1)
+                          for v in (x, dt, bmat, cmat))
+        cum = jnp.cumsum(dc * a, axis=1)                      # (b, q, G, R), <= 0
+        cum_t = cum.transpose(0, 2, 3, 1)                     # (b, G, R, q)
+        # decays from j + 1 to i; the upper triangle never leaves the mask
+        span = jnp.exp(jnp.where(lower, cum_t[..., :, None] - cum_t[..., None, :],
+                                 -jnp.inf))
+        cb = jnp.einsum("bqgn,bkgn->bgqk", cc, bc)
+        w = cb[:, :, None] * span * dc.transpose(0, 2, 3, 1)[..., None, :]
+        y = jnp.einsum("bgrqk,bkgrp->bqgrp", w, xc)
+        y = y + jnp.einsum("bqgn,bgrpn->bqgrp", cc, h) * jnp.exp(cum)[..., None]
+        to_end = jnp.exp(cum[:, -1:] - cum) * dc               # (b, q, G, R)
+        h = (h * jnp.exp(cum_t[..., -1])[..., None, None]
+             + jnp.einsum("bkgrp,bkgn->bgrpn", xc * to_end[..., None], bc))
+        return h, jax.lax.dynamic_update_slice_in_dim(out, y, c * q, axis=1)
+
+    n_chunks = (t + pad) // q
+    if n_real is not None:
+        n_chunks = jnp.minimum(n_chunks, (n_real + q - 1) // q)
+    h, y = jax.lax.fori_loop(
+        0, n_chunks, one_chunk,
+        (jnp.zeros(x.shape[:1] + x.shape[2:] + bmat.shape[-1:], jnp.float32),
+         jnp.zeros(x.shape, jnp.float32)))
+    return y[:, :t], h
+
+
+def _ssm_step(h, x, dt, a, bvec, cvec):
+    """One step of the recurrence over a cached state: h (b, G, R, P, N),
+    x (b, G, R, P), dt (b, G, R), a (G, R), bvec and cvec (b, G, N), all
+    float32 -> (y (b, G, R, P), the new state). The readout is taken from
+    the OLD state, ``y = decay (h C) + dt x (B . C)``, which is ``h_new C``
+    written out: the state is then read once by a reduction and once by
+    the update, both elementwise over it, and never made a second time."""
+    decay = jnp.exp(dt * a)
+    y = (decay[..., None] * jnp.sum(h * cvec[:, :, None, None, :], axis=-1)
+         + (dt * jnp.sum(bvec * cvec, axis=-1)[:, :, None])[..., None] * x)
+    h_new = (h * decay[..., None, None]
+             + (dt[..., None] * x)[..., None] * bvec[:, :, None, None, :])
+    return y, h_new
+
+
+def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
+               cache=None, token_mask=None):
+    """A state-space layer's first half (Mamba-2) on x (b, Tq, d): returns
+    (x + its output, what the layer keeps).
+
+    ``[z | xBC | dt] = RMSNorm(x) Win``; a causal depthwise convolution
+    ``d_conv`` wide over time on ``xBC``, plus bias, then SiLU; ``[x | B |
+    C]`` cut from it; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``
+    a head; the recurrence (:func:`_ssm_chunked`, :func:`_ssm_step`) with
+    the skip ``D x``; the gated norm ``RMSNorm(y * silu(z))`` over a
+    group's channels; ``Wo``. Everything between the two projections is
+    float32 but ``xBC`` itself, which is rounded to the parameter dtype
+    before the convolution, as the cached tail is.
+
+    Without a cache (forward, prefill) the whole sequence goes through the
+    chunked form from a zero state, positions where ``token_mask`` is
+    False (padding after the real tokens) get ``dt = 0``, and what is kept
+    is (the state after the last real token (b, heads, head size, state
+    size), the last ``d_conv - 1`` REAL inputs of the convolution
+    (b, channels, d_conv - 1), zeros where the prompt is shorter). With
+    ``cache`` = (the segment's states (layers, b, heads, head size, state
+    size), its tails (layers, b, channels, d_conv - 1), layer) and Tq = 1
+    one step of the recurrence: the layer's state and tail are read at
+    ``layer`` and written back there, rows where ``token_mask`` is False
+    bit for bit as they were, and what is kept is the two arrays whole
+    (the layer loop's carry: ``_run_stack``)."""
+    m = cfg.attn_kinds[kind]["ssm"]
+    heads, p, n, inner, conv = cfg.ssm_dims(kind)
+    g, k = m["n_groups"], m["d_conv"]
+    r = heads // g
+    b, tq, _d = x.shape
+    f32, dt_ = jnp.float32, x.dtype
+    with _scope("ssm_proj"):
+        a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(dt_)
+        proj = jnp.matmul(a_in, bp["Win"], preferred_element_type=f32)
+        z = proj[..., :inner]
+        xbc = proj[..., inner:inner + conv].astype(dt_)
+        dt = proj[..., inner + conv:]
+    with _scope("ssm_conv"):
+        w, bias = bp["conv_w"].astype(f32), bp["conv_b"].astype(f32)
+        if cache is None:
+            padded = jnp.pad(xbc.astype(f32), ((0, 0), (k - 1, 0), (0, 0)))
+            u = bias + sum(padded[:, j:j + tq] * w[:, j] for j in range(k))
+            lengths = (jnp.full((b,), tq, jnp.int32) if token_mask is None
+                       else jnp.sum(token_mask, axis=-1).astype(jnp.int32))
+            at = lengths[:, None] - (k - 1) + jnp.arange(k - 1)[None]
+            tail = jnp.take_along_axis(xbc, jnp.maximum(at, 0)[:, :, None],
+                                       axis=1)
+            tail = jnp.where(at[:, :, None] >= 0, tail, 0).transpose(0, 2, 1)
+        else:
+            states, tails, layer = cache
+            old = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)
+            window = jnp.concatenate([old, xbc[:, 0, :, None]], axis=-1)
+            u = (bias + jnp.sum(window.astype(f32) * w, axis=-1))[:, None]
+            tail = window[..., 1:]
+            if token_mask is not None:
+                tail = jnp.where(token_mask[:, :, None], tail, old)
+            tails = jax.lax.dynamic_update_index_in_dim(tails, tail, layer, 0)
+        u = jax.nn.silu(u)
+        xs = u[..., :inner].reshape(b, tq, g, r, p)
+        bm = u[..., inner:inner + g * n].reshape(b, tq, g, n)
+        cm = u[..., inner + g * n:].reshape(b, tq, g, n)
+    with _scope("ssm_scan"):
+        dt = jax.nn.softplus(dt + bp["dt_bias"]).reshape(b, tq, g, r)
+        a = -jnp.exp(bp["A_log"]).reshape(g, r)
+        if cache is None:
+            if token_mask is not None:
+                dt = jnp.where(token_mask[:, :, None, None], dt, 0.0)
+            y, h = _ssm_chunked(xs, dt, a, bm, cm, m["chunk"],
+                                None if token_mask is None else jnp.max(lengths))
+            made = (h.reshape(b, heads, p, n), tail)
+        else:
+            old = jax.lax.dynamic_index_in_dim(
+                states, layer, 0, keepdims=False).reshape(b, g, r, p, n)
+            y, h = _ssm_step(old, xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
+            if token_mask is not None:
+                h = jnp.where(token_mask[:, :, None, None, None], h, old)
+            states = jax.lax.dynamic_update_index_in_dim(
+                states, h.reshape(b, heads, p, n), layer, 0)
+            y, made = y[:, None], (states, tails)
+        y = y + bp["D"].reshape(g, r, 1) * xs
+    with _scope("ssm_proj"):
+        gated = y.reshape(b, tq, g, inner // g) * jax.nn.silu(
+            z.reshape(b, tq, g, inner // g))
+        gated = _rms_norm(gated, bp["norm_g"].reshape(g, inner // g),
+                          cfg.norm_eps).reshape(b, tq, inner).astype(dt_)
+        x = _residual(cfg, x, gated @ bp["Wo"])
+    return x, made
 
 
 def _experts(cfg: DecoderConfig, bp: Dict[str, Array], r_in: Array, dtype,
@@ -595,12 +889,19 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     cache is (slab (b, kv_rank + rotary_dim, Tc), c_pos), or (the
     segment's slabs, layer, lengths) for the decode kernel, and what it
     returns in their place is ((b, Tq, kv_rank + rotary_dim) entries,)
-    (:func:`_latent_attention`). With ``layer``
+    (:func:`_latent_attention`). A state-space kind attends to nothing:
+    its cache is (the segment's states, its tails, layer), WRITTEN here
+    at ``layer``, and it returns what it keeps in their place
+    (:func:`_ssm_mixer`). With ``layer``
     the expert weights in ``bp`` are a segment's whole stacks and
     ``layer`` the one to use (``moe_dropless_ffn``). Returns
     (x, (k, v), (expert pairs computed here, held experts hit))."""
     ak = cfg.attn_kinds[kind]
     b, tq, d = x.shape
+    if ak["ssm"]:
+        x, made = _ssm_mixer(cfg, kind, bp, x, cache, token_mask)
+        x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
+        return x, made, counts
     if ak["latent"]:
         n_real = (None if token_mask is None or cache is not None
                   else jnp.max(jnp.sum(token_mask, axis=-1)))
@@ -617,40 +918,52 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
         v = (a_in @ bp["Wv"]).reshape(b, tq, hkv, vd)
         q = _rotate(q, q_pos, cfg.rotary_dim, ak["rope_theta"])
         k = _rotate(k, q_pos, cfg.rotary_dim, ak["rope_theta"])
-        # query head i reads key/value head i // grp
-        qg = q.reshape(b, tq, hkv, grp, hd).transpose(0, 2, 3, 1, 4)
-        kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-        scale = 1.0 / math.sqrt(hd)
-        f32 = jnp.float32
-        s_own = jnp.einsum("bkgqd,bktd->bkgqt", qg, kh,
-                           preferred_element_type=f32) * scale
-        s_own = jnp.where(_visible(q_pos, q_pos, window)[:, None, None],
-                          s_own, _NEG)
-        m = s_own.max(-1)
-        if cache is not None:
-            kc, vc, c_pos = cache
-            s_c = jnp.einsum("bkgqd,bkdt->bkgqt", qg, kc,
-                             preferred_element_type=f32) * scale
-            s_c = jnp.where(_visible(q_pos, c_pos, window)[:, None, None],
-                            s_c, _NEG)
-            m = jnp.maximum(m, s_c.max(-1))
-        if ak["sink"]:
-            sink = bp["sink"].astype(f32).reshape(1, hkv, grp, 1)
-            m = jnp.maximum(m, sink)
-        e_own = jnp.exp(s_own - m[..., None])
-        z = e_own.sum(-1)
-        o = jnp.einsum("bkgqt,bktd->bkgqd", e_own.astype(x.dtype), vh,
-                       preferred_element_type=f32)
-        if cache is not None:
-            e_c = jnp.exp(s_c - m[..., None])
-            z = z + e_c.sum(-1)
-            o = o + jnp.einsum("bkgqt,bkdt->bkgqd", e_c.astype(x.dtype), vc,
-                               preferred_element_type=f32)
-        if ak["sink"]:
-            z = z + jnp.exp(sink - m)  # the sink takes weight, adds no value
-        o = o * (cfg.value_scale / z[..., None])
-        o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
-        x = x + o @ bp["Wo"]
+        if (cache is None and window is None and not ak["sink"]
+                and hq * tq * tq * 4 > BLOCKED_SCORE_BYTES):
+            kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            o = _causal_blocked(
+                q, jnp.repeat(k, grp, axis=2), jnp.repeat(v, grp, axis=2),
+                softmax_scale(cfg, kind), PREFILL_BLOCK,
+                None if token_mask is None
+                else jnp.max(jnp.sum(token_mask, axis=-1)))
+            if cfg.value_scale != 1.0:
+                o = o * cfg.value_scale
+            o = o.reshape(b, tq, hq * vd)
+        else:
+            # query head i reads key/value head i // grp
+            qg = q.reshape(b, tq, hkv, grp, hd).transpose(0, 2, 3, 1, 4)
+            kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            scale = softmax_scale(cfg, kind)
+            f32 = jnp.float32
+            s_own = jnp.einsum("bkgqd,bktd->bkgqt", qg, kh,
+                               preferred_element_type=f32) * scale
+            s_own = jnp.where(_visible(q_pos, q_pos, window)[:, None, None],
+                              s_own, _NEG)
+            m = s_own.max(-1)
+            if cache is not None:
+                kc, vc, c_pos = cache
+                s_c = jnp.einsum("bkgqd,bkdt->bkgqt", qg, kc,
+                                 preferred_element_type=f32) * scale
+                s_c = jnp.where(_visible(q_pos, c_pos, window)[:, None, None],
+                                s_c, _NEG)
+                m = jnp.maximum(m, s_c.max(-1))
+            if ak["sink"]:
+                sink = bp["sink"].astype(f32).reshape(1, hkv, grp, 1)
+                m = jnp.maximum(m, sink)
+            e_own = jnp.exp(s_own - m[..., None])
+            z = e_own.sum(-1)
+            o = jnp.einsum("bkgqt,bktd->bkgqd", e_own.astype(x.dtype), vh,
+                           preferred_element_type=f32)
+            if cache is not None:
+                e_c = jnp.exp(s_c - m[..., None])
+                z = z + e_c.sum(-1)
+                o = o + jnp.einsum("bkgqt,bkdt->bkgqd", e_c.astype(x.dtype),
+                                   vc, preferred_element_type=f32)
+            if ak["sink"]:
+                z = z + jnp.exp(sink - m)  # the sink takes weight, adds no value
+            o = o * (cfg.value_scale / z[..., None])
+            o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
+        x = _residual(cfg, x, o @ bp["Wo"])
     x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
     return x, (kh, vh), counts
 
@@ -665,7 +978,7 @@ def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
         with _scope("mlp"):
             m_in = _rms_norm(x, bp["norm2"], cfg.norm_eps).astype(x.dtype)
             h = jax.nn.silu(m_in @ bp["Wg"]) * (m_in @ bp["Wu"])
-            x = x + h @ bp["Wd"]
+            x = _residual(cfg, x, h @ bp["Wd"])
         counts = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     else:
         with _scope("moe_route"):
@@ -673,7 +986,7 @@ def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
         y, pairs, hit = _experts(
             cfg, bp, r_in, x.dtype,
             None if token_mask is None else token_mask.reshape(b * tq), layer)
-        x = x + y.reshape(b, tq, d).astype(x.dtype)
+        x = _residual(cfg, x, y.reshape(b, tq, d))
         counts = (pairs.astype(jnp.int32), hit)
     return x, counts
 
@@ -686,9 +999,15 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     (layers, b, width, Tc), with ``c_pos`` the position map of each
     attention kind (a latent segment's decode step where the kernel
     registry admits it: the slab whole, the layer's index and the rows'
-    lengths instead). Returns (x, per segment what the layers made to
-    cache, (k, v) stacks (layers, b, hkv, Tq, hd) or (entries
-    (layers, b, Tq, width),), summed expert counters)."""
+    lengths instead). A state-space segment's cache is (states, tails),
+    WRITTEN in the loop: the two arrays go through the scan as its carry,
+    each layer reading and writing its own index in place, and come back
+    whole in the cache's stead (stacked as a scan's output they would be a
+    second copy of the state). Returns (x, per segment what the layers
+    made to cache, (k, v) stacks (layers, b, hkv, Tq, hd) or (entries
+    (layers, b, Tq, width),) or, of a state-space segment, (states
+    (layers, b, heads, head size, state size), tails (layers, b, channels,
+    d_conv - 1)), summed expert counters)."""
     new_kv = []
     pairs = hit = jnp.zeros((), jnp.int32)
     for i, (kind, ffn, n) in enumerate(cfg.segments()):
@@ -698,6 +1017,21 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
         stacks = {k: seg[k] for k in EXPERT_STACKS if k in seg}
         scanned = {k: v for k, v in seg.items() if k not in stacks}
         kv = None if caches is None else caches[i]
+        if kv is not None and cfg.attn_kinds[kind]["ssm"]:
+            def step(carry, xs, kind=kind, ffn=ffn, stacks=stacks):
+                x, held = carry
+                bp, layer = xs
+                x, held, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
+                                        q_pos, (*held, layer), token_mask,
+                                        layer if stacks else None)
+                return (x, held), counts
+
+            (x, held), counts = jax.lax.scan(
+                step, (x, tuple(kv)),
+                (scanned, jnp.arange(n, dtype=jnp.int32)))
+            new_kv.append(held)
+            pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
+            continue
         # nor is a latent segment's slab where the decode kernel reads it
         # (a custom call's operand is made whole: the scan's slice of the
         # slab would be copied a layer), by the rows' lengths: a row that
@@ -728,14 +1062,28 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
 
 
 def _head(cfg: DecoderConfig, params: Dict, x: Array):
+    """Logits over the held vocabulary. A tied head is the embedding
+    (V, d) read transposed, by a product that contracts the minor
+    dimension of both: one leaf, no second copy."""
     with _scope("head"):
         x = _rms_norm(x, params["norm_f"], cfg.norm_eps).astype(cfg.dtype)
-        return (x @ params["head"]).astype(jnp.float32)
+        if cfg.tied_head:
+            logits = jax.lax.dot_general(
+                x, params["embed"], (((x.ndim - 1,), (1,)), ((), ())))
+        else:
+            logits = x @ params["head"]
+        logits = logits.astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
 
 def _embed(cfg: DecoderConfig, params: Dict, ids: Array):
     with _scope("embed"):
-        return params["embed"][ids].astype(cfg.dtype)
+        x = params["embed"][ids].astype(cfg.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
+        return x
 
 
 def forward(cfg: DecoderConfig, params: Dict, ids: Array):
@@ -748,9 +1096,10 @@ def forward(cfg: DecoderConfig, params: Dict, ids: Array):
 
 # -- the cache ----------------------------------------------------------------
 def init_cache(cfg: DecoderConfig, n_slots: int, max_length: int):
-    """Zeroed slabs a segment, by the cache plan: (K, V), or a latent
-    segment's one."""
-    return [tuple(jnp.zeros(shape, cfg.dtype) for shape in p["slabs"])
+    """Zeroed slabs a segment, by the cache plan: (K, V), a latent
+    segment's one, or a state-space segment's (states in float32, tails)."""
+    return [tuple(jnp.zeros(shape, dtype)
+                  for shape, dtype in zip(p["slabs"], p["dtypes"]))
             for p in cfg.cache_plan(n_slots, max_length)]
 
 
@@ -759,9 +1108,12 @@ def cache_positions(cfg: DecoderConfig, pos: Array, max_length: int):
     column holds for a row that has ``pos`` positions behind it; -1
     where it holds none. A full layer keeps position p in column p; a
     ring keeps p in column p mod window, so column c holds the latest
-    position below ``pos`` that is congruent to c."""
+    position below ``pos`` that is congruent to c. A state-space kind has
+    no columns and no map."""
     out = {}
     for kind in cfg.attn_kinds:
+        if cfg.attn_kinds[kind]["ssm"]:
+            continue
         cols = cfg.cache_columns(kind, max_length)
         c = jnp.arange(cols, dtype=jnp.int32)[None, :]
         last = pos.astype(jnp.int32)[:, None] - 1
@@ -792,9 +1144,13 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
     read inside the layer loop and written after it: a full layer's slab
     (a latent layer's too) by one in-place column a row at ``pos``
     (``_put_columns``), a ring by one select at ``pos mod window``
-    (``_put_ring``). ``active`` (b,) bool keeps idle rows
-    out of the expert layers (and of their counters)."""
-    t_max = max(p[0].shape[-1] for p in caches)
+    (``_put_ring``). A state-space segment's states and tails were
+    written inside the loop, in place, and come back as they are.
+    ``active`` (b,) bool keeps idle rows out of the expert layers (and of
+    their counters) and leaves their state and tail bit for bit alone."""
+    t_max = max([p[0].shape[-1] for (kind, _f, _n), p
+                 in zip(cfg.segments(), caches)
+                 if not cfg.attn_kinds[kind]["ssm"]], default=0)
     q_pos = pos.astype(jnp.int32)[:, None]
     x, new_kv, counts = _run_stack(
         cfg, params, _embed(cfg, params, ids_1[:, None]), q_pos, caches,
@@ -803,6 +1159,9 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
     out = []
     with _scope("kv_write"):
         for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
+            if cfg.attn_kinds[kind]["ssm"]:
+                out.append(tuple(new))
+                continue
             wp = jnp.minimum(q_pos, slabs[0].shape[-1] - 1)
             if cfg.attn_kinds[kind]["latent"]:
                 # as a slab of one head whose "head size" is the entry
@@ -825,16 +1184,25 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
     latest real position congruent to c, i.e. the prompt's last
     ``window`` columns when it is longer than the window. Padding follows
     the real tokens, so causal attention keeps it from them, and the
-    expert layers leave it out. Returns (logits (1, V) at length-1,
-    caches)."""
+    expert layers leave it out. A state-space segment gets the state
+    after the ``length`` real tokens (padding has ``dt = 0``) and the last
+    ``d_conv - 1`` real inputs of its convolution, under ``state_write``.
+    Returns (logits (1, V) at length-1, caches)."""
     _b, tb = ids.shape
     q_pos = jnp.arange(tb, dtype=jnp.int32)[None]
     real = q_pos < length
     x, new_kv, _counts = _run_stack(cfg, params, _embed(cfg, params, ids),
                                     q_pos, token_mask=real)
     out = []
-    with _scope("kv_write"):
-        for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
+    for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
+        if cfg.attn_kinds[kind]["ssm"]:
+            with _scope("state_write"):
+                out.append(tuple(
+                    jax.lax.dynamic_update_slice(
+                        c, n, (0, slot) + (0,) * (c.ndim - 2))
+                    for c, n in zip(slabs, new)))
+            continue
+        with _scope("kv_write"):
             cols = slabs[0].shape[-1]
             if cfg.attn_kinds[kind]["window"] is None and tb > cols:
                 raise ValueError("prefill bucket longer than the slot")

@@ -103,6 +103,16 @@ class DecodeStalledError(ServingError):
     it, and the slab is rebuilt when (if) the dispatch returns."""
 
 
+class RecurrentStateError(ServingError, ValueError):
+    """A prefix cache or speculation (K > 1) was asked of a model whose
+    layers keep a recurrent state. Both work by dropping or splicing
+    COLUMNS of a cache laid out by position; a state-space layer's state
+    is one array a slot that every token has been folded into, so it can
+    neither be rolled back to an earlier token by dropping columns nor be
+    captured for a prefix without a snapshot of its own. Raised at engine
+    BUILD time."""
+
+
 class GenerationRequest:
     """One generation request: prompt + sampling policy + streaming
     output. Completion (``finish``/``fail``) is idempotent first-wins,
@@ -789,7 +799,14 @@ class _DecoderBackend:
     routes per step), and there is no prefix cache: a ring holds a
     prompt's last ``window`` columns only, so a captured prefix could
     not be spliced under a longer prompt's own columns, and a latent
-    slab has no capture path yet."""
+    slab has no capture path yet. A state-space layer keeps no columns
+    at all: a float32 state (L, S, heads, head size, state size) and the
+    convolution's tail (L, S, channels, d_conv - 1), the same bytes
+    whatever the slot's length, read whole and written whole in place by
+    every decode step inside the layer loop (idle slots bit for bit as
+    they were) and written for one slot by a prefill. With such a layer
+    K > 1 and a prefix cache are REFUSED (:class:`RecurrentStateError`):
+    a state cannot be rolled back by dropping columns."""
 
     kind = "decoder"
     spec_k = 1
@@ -797,7 +814,8 @@ class _DecoderBackend:
     supports_prefix_cache = False
 
     def __init__(self, model, n_slots: int, max_length: Optional[int],
-                 prefill_buckets: Optional[Sequence[int]], trace_hook):
+                 prefill_buckets: Optional[Sequence[int]], trace_hook,
+                 spec_k: int = 1):
         from deeplearning4j_tpu.models.decoder_lm import (
             decode_step,
             prefill_slot,
@@ -827,6 +845,17 @@ class _DecoderBackend:
         #: arithmetic on what ``decode`` is handed
         self.step_latent_positions = 0
         self._latent = any(k["latent"] for k in cfg.attn_kinds.values())
+        #: slots whose recurrent state the last decode step advanced,
+        #: where a layer keeps one (else 0): host arithmetic too
+        self.step_state_slots = 0
+        #: some layer keeps a recurrent state: no prefix cache, K = 1
+        self.keeps_state = any(k["ssm"] for k in cfg.attn_kinds.values())
+        if self.keeps_state and int(spec_k) > 1:
+            raise RecurrentStateError(
+                f"spec_decode_k={spec_k}: a rejected draft token would have "
+                "to be taken out of the state-space layers' recurrent state, "
+                "which cannot be rolled back by dropping columns; set "
+                "spec_decode_k=1")
         self.reset()
 
         def _f32(bits):
@@ -889,6 +918,10 @@ class _DecoderBackend:
         self._caches = self._kept = None
 
     bucket_for = _TransformerBackend.bucket_for
+    #: by the slot's length, which an attention layer's columns set. A
+    #: stack of state-space layers alone would have no such bound; it is
+    #: held to ``max_length`` all the same (the engine's positions and the
+    #: model's declared context), so that case is refused, not unbounded
     window_check = _TransformerBackend.window_check
 
     @staticmethod
@@ -946,6 +979,8 @@ class _DecoderBackend:
             if self._latent:
                 self.step_latent_positions = int(np.dot(mine[:-1, 1],
                                                         mine[:-1, 2]))
+            if self.keeps_state:
+                self.step_state_slots = int(mine[:-1, 2].sum())
             # no new array and no loop over the whole of one before the
             # dispatch: NumPy lets the interpreter go inside a loop over
             # more than 500 elements (64 slots x 8 are 512), and the
@@ -1222,7 +1257,7 @@ def _pick_backend(model, n_slots, max_length, prefill_buckets, trace_hook,
                                    on_param_cast=on_param_cast)
     if isinstance(model, DecoderLM):
         return _DecoderBackend(model, n_slots, max_length, prefill_buckets,
-                               trace_hook)
+                               trace_hook, spec_k=spec_k)
     layers = getattr(model, "layers", None)
     if layers is not None:
         from deeplearning4j_tpu.nn.conf.layers.recurrent import (
@@ -1273,7 +1308,8 @@ def generation_memory_report(model, n_slots: int,
             # sized by layer kind: the slot's length for a full layer,
             # a ring of ``window`` columns for a window layer, K and V
             # by head; one entry of kv_rank + rotary_dim values a
-            # position for a latent layer
+            # position for a latent layer; a state and a convolution
+            # tail a slot, whatever T, for a state-space layer
             plan = cfg.cache_plan(n_slots, T)
             cache = sum(p["bytes"] for p in plan)
         else:
@@ -1305,8 +1341,12 @@ def generation_memory_report(model, n_slots: int,
     if plan is not None:
         out["cache_plan"] = [
             {k: p[k] for k in ("kind", "layers", "columns", "ring", "values",
-                               "bytes")}
+                               "bytes", "state", "conv") if k in p}
             for p in plan]
+        # the recurrent state (and its tails) apart from the slabs of
+        # columns: the first does not grow with max_length
+        out["state_bytes"] = sum(p["bytes"] for p in plan if "state" in p)
+        out["slab_bytes"] = int(cache) - out["state_bytes"]
     return out
 
 
@@ -1441,6 +1481,14 @@ class GenerationEngine:
                        else None)
         #: per-slot (t[-2], t[-1]) context feeding the n-gram draft
         self._ctx = np.zeros((self.n_slots, 2), np.int64)
+        if (prefix_cache_mb and float(prefix_cache_mb) > 0
+                and getattr(self.backend, "keeps_state", False)):
+            raise RecurrentStateError(
+                f"the {self.backend.kind} backend has no prefix cache for a "
+                "model with state-space layers: a prefix's recurrent state "
+                "is not a run of columns that could be copied under a longer "
+                "prompt's own, and no snapshot of it is kept; set "
+                "prefix_cache_mb=0")
         if (prefix_cache_mb and float(prefix_cache_mb) > 0
                 and not getattr(self.backend, "supports_prefix_cache", True)):
             raise ValueError(
@@ -2061,6 +2109,8 @@ class GenerationEngine:
                     self.metrics.record_moe_step(*counts)
                     self.metrics.record_latent_positions(
                         self.backend.step_latent_positions)
+                    self.metrics.record_state_slots(
+                        self.backend.step_state_slots)
             if dt * 1e3 > self.stall_ms:
                 _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
                                active=n_active)

@@ -329,6 +329,11 @@ class GenerationMetrics:
             "generation_latent_positions_read_total",
             "cache positions the active slots had behind them, summed "
             "over decode steps, where a layer keeps a latent cache")
+        self._state_slots = reg.counter(
+            "generation_state_slots_total",
+            "slots whose recurrent state a decode step advanced, summed "
+            "over decode steps, where a layer keeps one (state-space "
+            "layers): each is read whole and written whole a layer")
         self._param_casts = reg.counter(
             "generation_param_casts_total",
             "times the transformer backend made its compute-dtype copy "
@@ -382,6 +387,10 @@ class GenerationMetrics:
     def record_latent_positions(self, positions: int) -> None:
         if positions:
             self._latent_positions.inc(int(positions))
+
+    def record_state_slots(self, slots: int) -> None:
+        if slots:
+            self._state_slots.inc(int(slots))
 
     def record_param_cast(self) -> None:
         self._param_casts.inc()
@@ -484,6 +493,7 @@ class GenerationMetrics:
             "moe_pairs_local": int(self._moe_pairs.value()),
             "moe_experts_hit": int(self._moe_hit.value()),
             "latent_positions_read": int(self._latent_positions.value()),
+            "state_slots": int(self._state_slots.value()),
             "param_casts": int(self._param_casts.value()),
             "latency_window": n,
         }

@@ -542,10 +542,11 @@ class TestObservability:
         _drain(ld)
         ld.shutdown()
         # consumer-wait on a cold loader is near-certain but not
-        # guaranteed; assert the label plumbing, not the timing
+        # guaranteed; assert the label plumbing, not the timing (a
+        # labelled child's key is the registry's label string)
         pools = starved_pools(reg)
         for name in pools:
-            assert name in ("pool_x", "async_prefetch")
+            assert name in ("pool=pool_x", "async_prefetch")
 
     def test_loader_worker_exit_forensics(self, tmp_path):
         d = _pack(tmp_path, n=6, batches_per_shard=2)

@@ -43,8 +43,15 @@ from deeplearning4j_tpu.serving import (
 @pytest.fixture(scope="module", autouse=True)
 def _release_compiled_programs():
     """Same discipline as test_serving.py: drop this module's compiled
-    executables when done (short-lived engines on a cramped CPU host)."""
+    executables when done (short-lived engines on a cramped CPU host).
+    The module-shared engines are shut down first: an idle engine's loop
+    records a phase every 50 ms into the process-wide ring, which a later
+    test file in the same worker reads (``test_obs.py``'s bounded ring)."""
     yield
+    for shared in (_ENG, _SPEC, _BF16):
+        engine = shared.pop("e", None)
+        if engine is not None:
+            engine.shutdown(drain=False)
     gc.collect()
     jax.clear_caches()
 

@@ -461,6 +461,106 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip,
     assert prefill.memory_analysis().temp_size_in_bytes < 3.0e9
 
 
+def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
+    """``DecoderLM`` with state-space layers as the engine builds its
+    programs, at the granite-4.0-h-small-ep2 cell's widths and FULL cut
+    depth (five Mamba-2 layers of 128 heads x 64 x 128 state over 8,448
+    convolved channels, one NoPE attention layer of 32 / 8 heads of 128,
+    four more Mamba-2 layers; 36 of 72 top-10 experts of 768 and a shared
+    expert of 1,536 in every layer; half of the vocabulary, tied; 64 slots
+    x 4,096; bfloat16 with a float32 state). What a CPU run cannot show:
+    weights and cache are 13.0 GB of arguments; the decode program updates
+    the 2.4 GB of state IN PLACE as the layer loop's carry (its plan holds
+    no temporary of a layer's state over the slots, 268 MB, let alone a
+    segment's; the caches come back aliased to their arguments; nothing
+    copies a layer's state or the attention slab); the input projection
+    reads its stacked leaf where it lies (no slice or re-layout of a
+    16,768-wide matrix); and the largest prefill, the 4,096 bucket the
+    engine appends, attends by blocks (32 heads x 4,096^2 float32 scores in
+    one piece would be 2.1 GB), so plan + arguments stay under the chip's
+    15.75 GB."""
+    import re
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.models.decoder_lm import (
+        DecoderConfig,
+        init_cache,
+        init_params,
+    )
+    from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    S, T = 64, 4096
+    ssm = {"ssm": dict(n_heads=128, head_dim=64, d_state=128, n_groups=1,
+                       d_conv=4, expand=2, chunk=256)}
+    cfg = DecoderConfig(
+        vocab_size=50176, d_model=4096, n_heads=32, head_dim=128,
+        v_head_dim=128, rotary_dim=0,
+        attn_kinds={"ssm": ssm, "attention": {"n_kv_heads": 8,
+                                              "rope_theta": 1e4}},
+        layers=[("ssm", "experts")] * 5 + [("attention", "experts")]
+        + [("ssm", "experts")] * 4,
+        dense_width=0, expert_width=768, n_experts=72, top_k=10,
+        experts_held=(0, 36), shared_width=1536, max_length=T,
+        routing={"n_group": 1, "topk_group": 1, "renormalise": True},
+        embedding_multiplier=12, residual_multiplier=0.22,
+        attention_multiplier=0.0078125, logits_scaling=16, tied_head=True)
+    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
+                         lambda name: None)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(lambda: init_params(cfg)))
+    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    assert [tuple((c.shape, c.dtype.name) for c in seg) for seg in caches] == [
+        (((5, S, 128, 64, 128), "float32"), ((5, S, 8448, 3), "bfloat16")),
+        (((1, S, 8, 128, T), "bfloat16"),) * 2,
+        (((4, S, 128, 64, 128), "float32"), ((4, S, 8448, 3), "bfloat16"))]
+    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
+                      for seg in caches for c in seg)
+    one_layer_state = S * 128 * 64 * 128                    # 67 M values
+    slab = S * 8 * 128 * T                                  # 268 M values
+
+    def big_copies(text):
+        found = []
+        for dtype, dims in re.findall(r"= (\w+)\[([\d,]+)\]\S* copy\(", text):
+            size = math.prod(map(int, dims.split(",")))
+            # a cache has four or five dimensions; the prefill re-lays
+            # (1, 4096, 8192) activations out, three a layer
+            if (len(dims.split(",")) >= 4
+                    and size >= min(one_layer_state, slab) // 2):
+                found.append((dtype, dims))
+        return found
+
+    decode = be._decode_fn.lower(
+        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    text, plan = decode.as_text(), decode.memory_analysis()
+    assert 12.9e9 < plan.argument_size_in_bytes < 13.1e9
+    assert "ragged-dot" in text and not big_copies(text)
+    # 32 MB planned; one layer's state over the slots would be 268 MB
+    assert plan.temp_size_in_bytes < 0.1e9
+    assert abs(plan.alias_size_in_bytes - cache_bytes) < 1e6
+    assert not re.search(r"= bf16\[(\d+,)?4096,16768\]\S* (copy|slice)\(", text)
+    assert " conditional(" in text
+    assert not _sampler_work_outside_a_conditional(text, S, cfg.vocab_size)
+
+    prefill = be._prefill_fn.lower(
+        params, caches, arg((S + 1, 8), jnp.int32),
+        arg((8 + T,), jnp.int32)).compile()
+    text, plan = prefill.as_text(), prefill.memory_analysis()
+    assert not big_copies(text)
+    assert abs(plan.alias_size_in_bytes - cache_bytes) < 1e6
+    # 1.6 GB planned (2.2 GB before a bucket this long attended by blocks)
+    assert plan.temp_size_in_bytes < 2.0e9
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 15.75e9
+    assert not re.search(rf"f32\[(1,)?32,(1,)?{T},(1,)?{T}\]", text)
+
+
 def test_compiled_for_the_described_chip(one_chip):
     """The guard on the guard: the sharding these tests compile for is a
     TPU v5e, not the CPU the suite runs on."""
