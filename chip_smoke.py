@@ -55,6 +55,10 @@ FULL = {
     # entries of 512 + 64 values, slots of 10,240
     "latent_core": dict(heads=128, width=576, t_c=10240, dtype="bfloat16",
                         kv_rank=512),
+    # the granite-4.0-h-small-ep2 cell's state-space decode step: 64 slots
+    # of 128 heads x 64 x 128 float32 a layer, one group of B and C
+    "ssm_step": dict(heads=128, p=64, n=128, groups=1, slots=64,
+                     dtype="float32"),
     # the tiny state-space hybrid of tests/test_granite_lm.py (two Mamba-2
     # layers, a NoPE attention layer, another Mamba-2 layer; experts and a
     # shared expert in each), float32 so that equal tokens mean something
@@ -88,6 +92,8 @@ TINY = {
     "mlp4": (32, 64, 32, 8), "mlp4_rows": 8,
     "latent_core": dict(heads=4, width=32, t_c=64, dtype="float32",
                         kv_rank=16),
+    "ssm_step": dict(heads=8, p=16, n=16, groups=1, slots=3,
+                     dtype="float32"),
 }
 TINY["hybrid"] = FULL["hybrid"]
 
@@ -433,15 +439,18 @@ def phase_kernels(platform, size=None):
     """Which Pallas kernels the phases asked for and what each resolved
     to. On the TPU backend a kernel that fell back to its reference is
     a failure: the run would otherwise pass on dense XLA. No phase above
-    serves a latent layer, so with ``size`` its decode kernel is asked for
-    here, at the widths a cell runs it at (its probe holds it to the
-    einsums)."""
+    serves a latent layer, and ``hybrid_serve`` takes its state-space step
+    at a tiny size, so with ``size`` the two decode kernels are asked for
+    here, at the widths a cell runs them at (each probe holds its kernel
+    to the ``jnp`` form)."""
     from deeplearning4j_tpu.nn.conf.layers import attention
     from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
     from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
+    from deeplearning4j_tpu.nn.ops.ssm_decode import ssm_decode_impl
 
     if size is not None:
         latent_decode_impl(**size["latent_core"])
+        ssm_decode_impl(**size["ssm_step"])
     snap = default_kernel_registry().snapshot()
     flash = {repr(k): (None if impl is None
                        else getattr(impl.args[0], "__module__", "?"))

@@ -42,11 +42,16 @@ with or without a shared expert. Here all of that is configuration:
   (``nn/conf/layers/moe.moe_dropless_ffn``); the vocabulary may be the
   chip's slice of the published one;
 - a state-space layer keeps NO columns: its cache is a recurrent state
-  (layers, slots, heads, head size, state size) in float32 and the last
+  (layers, slots, state size, heads x head size) in float32 (the state
+  size major, so that the decode kernel's per-channel scalars are lane
+  vectors) and the last
   ``d_conv - 1`` inputs of its convolution, whatever the slot's length.
-  A decode step reads a layer's whole state and writes it whole, so the
+  A decode step reads and writes a layer's state where it lies, so the
   state goes through the layer loop as a CARRY updated in place on the
-  donated buffer (a scan's stacked output would be a second copy of it);
+  donated buffer (a scan's stacked output would be a second copy of it):
+  by a kernel that visits the live slots alone, each block once
+  (``nn/ops/ssm_decode.py``), where the kernel registry admits the
+  shapes, by :func:`_ssm_step` over all slots elsewhere;
   prefill is the chunked dual form (:func:`_ssm_chunked`), whose padding
   leaves the state alone;
 - four scalars of the configuration scale the embedding, every residual
@@ -80,6 +85,7 @@ from deeplearning4j_tpu.nn.conf.layers.moe import (
     sigmoid_topk_route,
 )
 from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
+from deeplearning4j_tpu.nn.ops.ssm_decode import live_table, ssm_decode_impl
 
 Array = jax.Array
 
@@ -295,7 +301,7 @@ class DecoderConfig:
         or, for a latent segment, ONE slab (layers, slots, kv_rank +
         rotary_dim, columns); ``values``: what a position and layer
         keeps. A state-space segment keeps no columns: ``state`` (layers,
-        slots, heads, head size, state size) in float32 (a bfloat16
+        slots, state size, heads x head size) in float32 (a bfloat16
         state would round at every step of a recurrence thousands long)
         and ``conv`` (layers, slots, convolved channels, d_conv - 1), the
         convolution's last inputs, in the parameter dtype: the same bytes
@@ -306,7 +312,7 @@ class DecoderConfig:
             if self.attn_kinds[kind]["ssm"]:
                 h, p, ns, _inner, conv = self.ssm_dims(kind)
                 tail = self.attn_kinds[kind]["ssm"]["d_conv"] - 1
-                state = (n, int(n_slots), h, p, ns)
+                state = (n, int(n_slots), ns, h * p)
                 taps = (n, int(n_slots), conv, tail)
                 plan.append({
                     "kind": kind, "layers": n, "columns": 0,
@@ -763,15 +769,18 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
     Without a cache (forward, prefill) the whole sequence goes through the
     chunked form from a zero state, positions where ``token_mask`` is
     False (padding after the real tokens) get ``dt = 0``, and what is kept
-    is (the state after the last real token (b, heads, head size, state
+    is (the state after the last real token (b, state size, heads x head
     size), the last ``d_conv - 1`` REAL inputs of the convolution
     (b, channels, d_conv - 1), zeros where the prompt is shorter). With
-    ``cache`` = (the segment's states (layers, b, heads, head size, state
+    ``cache`` = (the segment's states (layers, b, state size, heads x head
     size), its tails (layers, b, channels, d_conv - 1), layer) and Tq = 1
     one step of the recurrence: the layer's state and tail are read at
     ``layer`` and written back there, rows where ``token_mask`` is False
     bit for bit as they were, and what is kept is the two arrays whole
-    (the layer loop's carry: ``_run_stack``)."""
+    (the layer loop's carry: ``_run_stack``). With a fourth entry, the
+    live slots' table (``nn/ops/ssm_decode.live_table``), the state goes
+    through the kernel that visits those slots alone, each block once, in
+    ``_ssm_step``'s stead."""
     m = cfg.attn_kinds[kind]["ssm"]
     heads, p, n, inner, conv = cfg.ssm_dims(kind)
     g, k = m["n_groups"], m["d_conv"]
@@ -796,7 +805,7 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
                                        axis=1)
             tail = jnp.where(at[:, :, None] >= 0, tail, 0).transpose(0, 2, 1)
         else:
-            states, tails, layer = cache
+            states, tails, layer, *table = cache
             old = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)
             window = jnp.concatenate([old, xbc[:, 0, :, None]], axis=-1)
             u = (bias + jnp.sum(window.astype(f32) * w, axis=-1))[:, None]
@@ -816,15 +825,29 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
                 dt = jnp.where(token_mask[:, :, None, None], dt, 0.0)
             y, h = _ssm_chunked(xs, dt, a, bm, cm, m["chunk"],
                                 None if token_mask is None else jnp.max(lengths))
-            made = (h.reshape(b, heads, p, n), tail)
+            made = (h.reshape(b, inner, n).transpose(0, 2, 1), tail)
+        elif table:
+            step = ssm_decode_impl(heads, p, n, g, b, states.dtype)
+            x1, d1, b1, c1 = xs[:, 0], dt[:, 0], bm[:, 0], cm[:, 0]
+            decay = jnp.exp(d1 * a)
+            hc, states = step(
+                states, layer, table[0], (d1[..., None] * x1).reshape(b, inner),
+                jnp.broadcast_to(decay[..., None], x1.shape).reshape(b, inner),
+                b1, c1)
+            # ``_ssm_step``'s readout, on the kernel's sum over the old state
+            y = (decay[..., None] * hc.reshape(b, g, r, p)
+                 + (d1 * jnp.sum(b1 * c1, axis=-1)[:, :, None])[..., None] * x1)
+            y, made = y[:, None], (states, tails)
         else:
+            # ``_ssm_step`` takes the state size minor
             old = jax.lax.dynamic_index_in_dim(
-                states, layer, 0, keepdims=False).reshape(b, g, r, p, n)
+                states, layer, 0, keepdims=False).transpose(0, 2, 1).reshape(
+                    b, g, r, p, n)
             y, h = _ssm_step(old, xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0])
             if token_mask is not None:
                 h = jnp.where(token_mask[:, :, None, None, None], h, old)
             states = jax.lax.dynamic_update_index_in_dim(
-                states, h.reshape(b, heads, p, n), layer, 0)
+                states, h.reshape(b, inner, n).transpose(0, 2, 1), layer, 0)
             y, made = y[:, None], (states, tails)
         y = y + bp["D"].reshape(g, r, 1) * xs
     with _scope("ssm_proj"):
@@ -876,6 +899,17 @@ def _latent_kernel_admits(cfg: DecoderConfig, kind: str, slab: Array) -> bool:
         latent["kv_rank"]) is not None
 
 
+def _ssm_kernel_admits(cfg: DecoderConfig, kind: str, states: Array) -> bool:
+    """Whether a decode step over ``states`` (layers, b, state size, heads x
+    head size) of a state-space kind goes through the live-slot kernel:
+    the kernel registry's verdict for these shapes (a TPU, the probe
+    passed; elsewhere ``_ssm_step`` serves)."""
+    heads, p, n, _inner, _conv = cfg.ssm_dims(kind)
+    return ssm_decode_impl(
+        heads, p, n, cfg.attn_kinds[kind]["ssm"]["n_groups"],
+        states.shape[1], states.dtype) is not None
+
+
 def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
           x: Array, q_pos: Array, cache=None, token_mask=None, layer=None):
     """One layer on x (b, Tq, d) at absolute positions q_pos (b, Tq);
@@ -890,8 +924,9 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     segment's slabs, layer, lengths) for the decode kernel, and what it
     returns in their place is ((b, Tq, kv_rank + rotary_dim) entries,)
     (:func:`_latent_attention`). A state-space kind attends to nothing:
-    its cache is (the segment's states, its tails, layer), WRITTEN here
-    at ``layer``, and it returns what it keeps in their place
+    its cache is (the segment's states, its tails, layer), or those and
+    the live slots' table for the decode kernel, WRITTEN here at
+    ``layer``, and it returns what it keeps in their place
     (:func:`_ssm_mixer`). With ``layer``
     the expert weights in ``bp`` are a segment's whole stacks and
     ``layer`` the one to use (``moe_dropless_ffn``). Returns
@@ -1003,10 +1038,12 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     WRITTEN in the loop: the two arrays go through the scan as its carry,
     each layer reading and writing its own index in place, and come back
     whole in the cache's stead (stacked as a scan's output they would be a
-    second copy of the state). Returns (x, per segment what the layers
+    second copy of the state); a decode step where the kernel registry
+    admits it also hands each layer the table of the rows that are
+    active. Returns (x, per segment what the layers
     made to cache, (k, v) stacks (layers, b, hkv, Tq, hd) or (entries
     (layers, b, Tq, width),) or, of a state-space segment, (states
-    (layers, b, heads, head size, state size), tails (layers, b, channels,
+    (layers, b, state size, heads x head size), tails (layers, b, channels,
     d_conv - 1)), summed expert counters)."""
     new_kv = []
     pairs = hit = jnp.zeros((), jnp.int32)
@@ -1018,12 +1055,19 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
         scanned = {k: v for k, v in seg.items() if k not in stacks}
         kv = None if caches is None else caches[i]
         if kv is not None and cfg.attn_kinds[kind]["ssm"]:
-            def step(carry, xs, kind=kind, ffn=ffn, stacks=stacks):
+            table = ()
+            if x.shape[1] == 1 and _ssm_kernel_admits(cfg, kind, kv[0]):
+                table = (live_table(
+                    jnp.ones(x.shape[:1], bool) if token_mask is None
+                    else token_mask[:, 0]),)
+
+            def step(carry, xs, kind=kind, ffn=ffn, stacks=stacks,
+                     table=table):
                 x, held = carry
                 bp, layer = xs
                 x, held, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
-                                        q_pos, (*held, layer), token_mask,
-                                        layer if stacks else None)
+                                        q_pos, (*held, layer, *table),
+                                        token_mask, layer if stacks else None)
                 return (x, held), counts
 
             (x, held), counts = jax.lax.scan(

@@ -11,6 +11,8 @@ The kernel SUBSYSTEM (this package):
 - ``int8_matmul`` — int8 weight-quantized serving matmul;
 - ``latent_decode`` — a decode step's attention over a latent cache, by
   the slots' live lengths;
+- ``ssm_decode`` — a decode step's state-space recurrence, readout and
+  in-place update from one read of the live slots' state;
 - ``registry`` — the shared probe-once/fallback/observability contract
   every kernel resolves through (``KernelRegistry``).
 """

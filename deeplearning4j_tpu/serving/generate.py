@@ -800,9 +800,9 @@ class _DecoderBackend:
     prompt's last ``window`` columns only, so a captured prefix could
     not be spliced under a longer prompt's own columns, and a latent
     slab has no capture path yet. A state-space layer keeps no columns
-    at all: a float32 state (L, S, heads, head size, state size) and the
+    at all: a float32 state (L, S, state size, heads x head size) and the
     convolution's tail (L, S, channels, d_conv - 1), the same bytes
-    whatever the slot's length, read whole and written whole in place by
+    whatever the slot's length, read and written in place by
     every decode step inside the layer loop (idle slots bit for bit as
     they were) and written for one slot by a prefill. With such a layer
     K > 1 and a prefix cache are REFUSED (:class:`RecurrentStateError`):

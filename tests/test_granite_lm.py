@@ -295,9 +295,9 @@ def test_the_state_goes_through_the_layer_loop_as_a_carry(base):
         dcfg, p, c, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32)))(model.params_, caches)
     scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
     assert len(scans) == 3
-    state_shapes = {c.shape for seg in caches for c in seg if c.ndim == 5 and c.dtype == jnp.float32
-                    and c.shape[-1] == 16 and c.shape[2] == 8}
-    assert state_shapes == {(2, 3, 8, 16, 16), (1, 3, 8, 16, 16)}
+    state_shapes = {c.shape for seg in caches for c in seg if c.ndim == 4 and c.dtype == jnp.float32
+                    and c.shape[2:] == (16, 128)}
+    assert state_shapes == {(2, 3, 16, 128), (1, 3, 16, 128)}
     carried = 0
     for e in scans:
         n_carry = e.params["num_carry"]
@@ -316,14 +316,14 @@ def test_cache_plan_has_a_state_and_a_tail_and_no_columns(base):
     plan = model.cfg.cache_plan(3, 64)
     assert [(p["kind"], p["layers"], p["columns"]) for p in plan] == [
         ("ssm", 2, 0), ("attention", 1, 64), ("ssm", 1, 0)]
-    assert plan[0]["state"] == (2, 3, 8, 16, 16) and plan[0]["conv"] == (2, 3, 128 + 32, 3)
+    assert plan[0]["state"] == (2, 3, 16, 8 * 16) and plan[0]["conv"] == (2, 3, 128 + 32, 3)
     assert plan[0]["bytes"] == 2 * 3 * (8 * 16 * 16 * 4 + 160 * 3 * 4)     # float32 parameters here
     assert plan[0] == model.cfg.cache_plan(3, 4096)[0]                     # whatever the length
     caches = decoder_lm.init_cache(model.cfg, 3, 64)
     assert [tuple((c.shape, c.dtype.name) for c in seg) for seg in caches] == [
-        (((2, 3, 8, 16, 16), "float32"), ((2, 3, 160, 3), "float32")),
+        (((2, 3, 16, 128), "float32"), ((2, 3, 160, 3), "float32")),
         (((1, 3, 2, 16, 64), "float32"), ((1, 3, 2, 16, 64), "float32")),
-        (((1, 3, 8, 16, 16), "float32"), ((1, 3, 160, 3), "float32"))]
+        (((1, 3, 16, 128), "float32"), ((1, 3, 160, 3), "float32"))]
     bf16 = build(tiny("bfloat16")).cfg
     assert [c.dtype.name for c in decoder_lm.init_cache(bf16, 1, 8)[0]] == ["float32", "bfloat16"]
 
@@ -352,7 +352,7 @@ def test_published_cut_by_arithmetic():
     assert slab == 64 * 4096 * 2 * 8 * 128 * 2 and slab // (64 * 4096) == 4096
     assert 3.50e9 < state + tails + slab < 3.54e9
     assert 12.9e9 < stored + state + tails + slab < 13.1e9
-    assert plan[0]["state"] == (5, 64, 128, 64, 128) and plan[0]["conv"] == (5, 64, 8448, 3)
+    assert plan[0]["state"] == (5, 64, 128, 128 * 64) and plan[0]["conv"] == (5, 64, 8448, 3)
     # the input projection's three parts start at multiples of 128 lanes
     seg = decoder_lm.segment_shapes(cfg, "ssm", "experts")
     assert seg["Win"][0] == (4096, 8192 + 8448 + 128) and 8192 % 128 == 0 and (8192 + 8448) % 128 == 0
@@ -552,7 +552,7 @@ def test_memory_report_lists_state_and_slab_apart(served):
     assert report["cache_bytes"] == state + slab == engine.backend.cache_bytes
     assert [(p["kind"], p["layers"], p["columns"]) for p in report["cache_plan"]] == [
         ("ssm", 2, 0), ("attention", 1, 96), ("ssm", 1, 0)]
-    assert report["cache_plan"][0]["state"] == (2, 3, 8, 16, 16)
+    assert report["cache_plan"][0]["state"] == (2, 3, 16, 128)
     described = engine.describe()
     assert described["backend"] == "decoder" and described["spec_decode_k"] == 1
     assert described["memory"]["cache_plan"] == report["cache_plan"]

@@ -461,7 +461,8 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip,
     assert prefill.memory_analysis().temp_size_in_bytes < 3.0e9
 
 
-def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
+def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
+                                                             monkeypatch):
     """``DecoderLM`` with state-space layers as the engine builds its
     programs, at the granite-4.0-h-small-ep2 cell's widths and FULL cut
     depth (five Mamba-2 layers of 128 heads x 64 x 128 state over 8,448
@@ -469,12 +470,17 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
     four more Mamba-2 layers; 36 of 72 top-10 experts of 768 and a shared
     expert of 1,536 in every layer; half of the vocabulary, tied; 64 slots
     x 4,096; bfloat16 with a float32 state). What a CPU run cannot show:
-    weights and cache are 13.0 GB of arguments; the decode program updates
-    the 2.4 GB of state IN PLACE as the layer loop's carry (its plan holds
-    no temporary of a layer's state over the slots, 268 MB, let alone a
-    segment's; the caches come back aliased to their arguments; nothing
-    copies a layer's state or the attention slab); the input projection
-    reads its stacked leaf where it lies (no slice or re-layout of a
+    weights and cache are 13.0 GB of arguments; the decode program takes a
+    layer's state through the live-slot kernel (``nn/ops/ssm_decode.py``:
+    Mosaic takes it at 128 x 8,192 a slot in blocks of 2 MB; the
+    registry's verdict is steered here, since this process's backend is the
+    CPU), one custom call a segment under ``ssm_scan`` with the segment's
+    states aliased through it, and so updates the 2.4 GB of state IN PLACE
+    as the layer loop's carry (its plan holds no temporary of a layer's
+    state over the slots, 268 MB, let alone a segment's; the caches come
+    back aliased to their arguments; nothing copies a layer's state or the
+    attention slab; no fusion selects over a layer's state); the input
+    projection reads its stacked leaf where it lies (no slice or re-layout of a
     16,768-wide matrix); and the largest prefill, the 4,096 bucket the
     engine appends, attends by blocks (32 heads x 4,096^2 float32 scores in
     one piece would be 2.1 GB), so plan + arguments stay under the chip's
@@ -482,12 +488,23 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
     import re
     from types import SimpleNamespace
 
+    from deeplearning4j_tpu.models import decoder_lm
     from deeplearning4j_tpu.models.decoder_lm import (
         DecoderConfig,
         init_cache,
         init_params,
     )
+    from deeplearning4j_tpu.nn.ops import ssm_decode
     from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    asked = []
+
+    def admitted(heads, p, n, groups, slots, dtype):
+        asked.append((heads, p, n, groups, slots, jnp.dtype(dtype).name))
+        return functools.partial(ssm_decode.ssm_decode_step,
+                                 tile=ssm_decode._tile(heads, p, n, groups))
+
+    monkeypatch.setattr(decoder_lm, "ssm_decode_impl", admitted)
 
     S, T = 64, 4096
     ssm = {"ssm": dict(n_heads=128, head_dim=64, d_state=128, n_groups=1,
@@ -518,9 +535,9 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
     params = described(jax.eval_shape(lambda: init_params(cfg)))
     caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
     assert [tuple((c.shape, c.dtype.name) for c in seg) for seg in caches] == [
-        (((5, S, 128, 64, 128), "float32"), ((5, S, 8448, 3), "bfloat16")),
+        (((5, S, 128, 8192), "float32"), ((5, S, 8448, 3), "bfloat16")),
         (((1, S, 8, 128, T), "bfloat16"),) * 2,
-        (((4, S, 128, 64, 128), "float32"), ((4, S, 8448, 3), "bfloat16"))]
+        (((4, S, 128, 8192), "float32"), ((4, S, 8448, 3), "bfloat16"))]
     cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
                       for seg in caches for c in seg)
     one_layer_state = S * 128 * 64 * 128                    # 67 M values
@@ -542,6 +559,13 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
     text, plan = decode.as_text(), decode.memory_analysis()
     assert 12.9e9 < plan.argument_size_in_bytes < 13.1e9
     assert "ragged-dot" in text and not big_copies(text)
+    assert set(asked) == {(128, 64, 128, 1, S, "float32")}
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "ssm_scan" in line]
+    assert len(kernels) == 2, kernels                   # one a segment
+    assert all(ssm_decode.NAME in line for line in kernels)
+    assert not re.search(rf"f32\[(\d+,)?{S},128,8192\]\S* (fusion|select)\(",
+                         text)
     # 32 MB planned; one layer's state over the slots would be 268 MB
     assert plan.temp_size_in_bytes < 0.1e9
     assert abs(plan.alias_size_in_bytes - cache_bytes) < 1e6
