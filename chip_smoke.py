@@ -290,6 +290,31 @@ def _http(port, method, path, body=None, timeout=600):
         conn.close()
 
 
+def step_ids_in_ring(gen, since_ns):
+    """What the engine's ring entries since ``since_ns`` say of its
+    decode steps (``obs/trace.py``: an entry's cause is its step's id):
+    each step one put, one dispatch, one fetch and one emit under one id,
+    and ``gen.turn`` between two steps."""
+    from deeplearning4j_tpu.obs import trace as obs_trace
+
+    mine = gen._dispatch_gen >> 32
+    steps = {}
+    for name, _, _, cause in obs_trace.caused_phases(since_ns):
+        if cause is not None and cause >> 32 == mine:
+            steps.setdefault(cause, []).append(name)
+    check(steps, "the ring holds no entry with one of the engine's step ids")
+    step = ["gen.decode.put", "gen.decode.dispatch", "gen.decode.fetch",
+            "gen.emit"]
+    decoded = {i: names for i, names in steps.items() if "gen.emit" in names}
+    for i, names in decoded.items():
+        check([n for n in names if n in step] == step,
+              f"step {i}: not one id over one step's four phases: {names}")
+    turns = sum(names.count("gen.turn") for names in steps.values())
+    check(0 < turns <= len(decoded),
+          f"{turns} gen.turn entries beside {len(decoded)} decode steps")
+    return {"decode_steps": len(decoded), "turns": turns}
+
+
 def phase_lm_serve(size, model):
     import numpy as np
 
@@ -313,6 +338,7 @@ def phase_lm_serve(size, model):
     try:
         warm = gen.warmup()
         traced = dict(gen.trace_counts)
+        mark = time.time_ns()
         answers = [None] * len(prompts)
 
         def ask(i):
@@ -341,6 +367,7 @@ def phase_lm_serve(size, model):
         check(not retraces, f"retraced after warm-up: {retraces}")
         status, raw = _http(srv.port, "GET", "/healthz")
         check(status == 200, f"/healthz: HTTP {status} {raw[:300]}")
+        ring = step_ids_in_ring(gen, mark)
     finally:
         srv.generation = None
         srv.shutdown()
@@ -356,7 +383,7 @@ def phase_lm_serve(size, model):
     return {"requests": len(prompts), "slots": size["slots"],
             "prompt_lens": [len(p) for p in prompts], "max_new": max_new,
             "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
-            "retraces_after_warmup": 0, "healthz": 200,
+            "retraces_after_warmup": 0, "healthz": 200, "ring": ring,
             "vs_generate_cached": parity}
 
 
@@ -381,10 +408,12 @@ def phase_hybrid_serve(size):
     try:
         warm = gen.warmup()
         traced = dict(gen.trace_counts)
+        mark = time.time_ns()
         requests = [gen.submit(p, max_new=max_new) for p in prompts]
         served = [np.asarray(r.result(timeout=900)) for r in requests]
         check(gen.trace_counts == traced,
               f"retraced after warm-up: {traced} -> {gen.trace_counts}")
+        ring = step_ids_in_ring(gen, mark)
         snapshot = gen.metrics.snapshot()
         report = gen.describe()["memory"]
     finally:
@@ -400,7 +429,7 @@ def phase_hybrid_serve(size):
             "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
             "state_bytes": report["state_bytes"],
             "slab_bytes": report["slab_bytes"],
-            "state_slots": snapshot["state_slots"],
+            "state_slots": snapshot["state_slots"], "ring": ring,
             "tokens_equal_generate_cached": True}
 
 

@@ -970,6 +970,7 @@ class TransformerLM(ZooModel):
             self._jit_cache[key] = self._make_step(
                 with_seg=segment_ids is not None)
         self.iteration += 1
+        _trace.set_cause(self.iteration)
         with _PUT_BATCH:
             args = [self.params_, self.opt_state_,
                     jnp.asarray(ids, jnp.int32),

@@ -572,6 +572,7 @@ class ComputationGraph:
     def _fit_batch(self, step, mds: MultiDataSet, tconf=None):
         from deeplearning4j_tpu.train.listeners import _hook_recipients
 
+        _trace.set_cause(self.iteration)
         with _PUT_BATCH:
             feats = tuple(jnp.asarray(f) for f in mds.features)
             labels = tuple(jnp.asarray(l) for l in mds.labels)

@@ -638,6 +638,7 @@ class MultiLayerNetwork:
     def _fit_batch(self, step, ds: DataSet, tconf=None):
         from deeplearning4j_tpu.train.listeners import _hook_recipients
 
+        _trace.set_cause(self.iteration)
         with _PUT_BATCH:
             features = jnp.asarray(ds.features)
             labels = None if ds.labels is None else jnp.asarray(ds.labels)

@@ -7,7 +7,7 @@ Three pieces:
   One call site feeds two sinks from one pair of clock reads: a
   ``jax.profiler.TraceAnnotation`` (xprof and the benchmark's gap
   attribution see it where the host tracer is on) and one process-wide
-  bounded ring of ``(name, start_ns, duration_ns)`` plus the per-name
+  bounded ring of ``(name, start_ns, duration_ns, cause)`` plus the per-name
   counters ``host_phase_total`` / ``host_phase_seconds_total`` in the
   default registry. ``start_ns`` is ``time.time_ns()``: the profiler
   stamps its planes from the same wall clock (a plane's times count from
@@ -15,7 +15,11 @@ Three pieces:
   the device plane of the same process with the host tracer off. Phases
   are always counted; there is no switch. :func:`observe` enters an
   interval measured elsewhere (queue wait spans two threads);
-  :func:`phases` hands the ring out.
+  :func:`phases` hands the ring out. Every entry also keeps its **cause**:
+  the integer its thread last gave :func:`set_cause` (a decode step's
+  id, a training iteration), so that the phases of one unit of work are
+  joined by what caused them and not by their order in the ring;
+  :func:`caused_phases` hands those four-wide entries out.
 
 - **Spans**: :func:`span` and :func:`step_span` are the bare
   ``jax.profiler`` annotations (``TraceAnnotation``,
@@ -146,10 +150,25 @@ PHASE_COUNTER = "host_phase_total"
 PHASE_SECONDS = "host_phase_seconds_total"
 RING_SIZE = 65536
 
-#: (name, start_ns on ``time.time_ns()``, duration_ns), oldest first
+#: (name, start_ns on ``time.time_ns()``, duration_ns, cause), oldest first
 _ring: deque = deque(maxlen=RING_SIZE)
 _sites: Dict[str, "Phase"] = {}
 _sites_lock = threading.Lock()
+
+
+class _Cause(threading.local):
+    """What the thread's phases are entered for; ``None`` until set."""
+
+    value: Optional[int] = None
+
+
+_cause = _Cause()
+
+
+def set_cause(cause: Optional[int]) -> None:
+    """Name the unit of work (a decode step's id, a training iteration)
+    that this thread's phases belong to from here on."""
+    _cause.value = cause
 
 
 class _Open(threading.local):
@@ -195,8 +214,11 @@ class Phase:
         annotation, _ = self._open.stack.pop()
         annotation.__exit__(None, None, None)
 
-    def record(self, start_ns: int, duration_ns: int) -> None:
-        _ring.append((self.name, start_ns, duration_ns))
+    def record(self, start_ns: int, duration_ns: int,
+               cause: Optional[int] = None) -> None:
+        """Enter one interval; ``cause`` where it is not the thread's."""
+        _ring.append((self.name, start_ns, duration_ns,
+                      _cause.value if cause is None else cause))
         self._count.inc()
         self._seconds.inc(max(duration_ns, 0) * 1e-9)
 
@@ -211,20 +233,30 @@ def phase(name: str) -> Phase:
     return site
 
 
-def observe(name: str, start_ns: int, duration_ns: int) -> None:
+def observe(name: str, start_ns: int, duration_ns: int,
+            cause: Optional[int] = None) -> None:
     """Enter an interval measured elsewhere into the ring and counters
-    of ``name``; ``start_ns`` is on ``time.time_ns()``."""
-    phase(name).record(int(start_ns), int(duration_ns))
+    of ``name``; ``start_ns`` is on ``time.time_ns()``. ``cause`` where
+    the interval belongs to another unit of work than the thread's."""
+    phase(name).record(int(start_ns), int(duration_ns), cause)
 
 
-def phases(since_ns: Optional[int] = None) -> List[Tuple[str, int, int]]:
-    """The ring, oldest first: entries are appended when a phase ENDS,
-    so an enclosing phase follows the phases inside it. ``since_ns``
-    keeps the entries that start at or after it."""
+def caused_phases(since_ns: Optional[int] = None
+                  ) -> List[Tuple[str, int, int, Optional[int]]]:
+    """The ring, oldest first, as ``(name, start_ns, duration_ns,
+    cause)``: entries are appended when a phase ENDS, so an enclosing
+    phase follows the phases inside it. ``since_ns`` keeps the entries
+    that start at or after it."""
     entries = list(_ring)
     if since_ns is None:
         return entries
     return [e for e in entries if e[1] >= since_ns]
+
+
+def phases(since_ns: Optional[int] = None) -> List[Tuple[str, int, int]]:
+    """:func:`caused_phases` without the cause: ``(name, start_ns,
+    duration_ns)``, in the same order."""
+    return [e[:3] for e in caused_phases(since_ns)]
 
 
 def each_next(site: Phase, iterable):

@@ -54,6 +54,7 @@ Observability: flight-recorder slot lifecycle events (``slot_claim`` /
 from __future__ import annotations
 
 import hashlib
+import itertools
 import queue
 import threading
 import time
@@ -77,8 +78,12 @@ from deeplearning4j_tpu.serving.batcher import (
 from deeplearning4j_tpu.serving.metrics import GenerationMetrics
 
 # host phases of the worker loop (obs/trace.py). ``gen.admit`` encloses
-# ``gen.prefill``, which encloses ``gen.prefill.put``; the others follow
-# one another, so the self times sum to a loop iteration.
+# ``gen.prefill``, which encloses ``gen.prefill.put``; ``gen.turn`` (the
+# loop's own time from one step's ``gen.emit`` to the next step's
+# ``gen.decode.put``) encloses the claims made in it; the others follow
+# one another, so the self times sum to a loop iteration. Every entry
+# carries its decode step's id as its cause: a claim's, and the turn's,
+# the id of the step they precede.
 _ADMIT = _trace.phase("gen.admit")
 _PREFILL = _trace.phase("gen.prefill")
 _PREFILL_PUT = _trace.phase("gen.prefill.put")
@@ -88,6 +93,11 @@ _DECODE_FETCH = _trace.phase("gen.decode.fetch")
 _EMIT = _trace.phase("gen.emit")
 _IDLE_WAIT = _trace.phase("gen.idle_wait")
 _QUEUE_WAIT = _trace.phase("gen.queue_wait")
+_TURN = _trace.phase("gen.turn")
+
+#: one a GenerationEngine of this process: the high bits of its step ids,
+#: so that two engines' phases in the one ring are told apart by cause
+_ENGINE_IDS = itertools.count()
 
 class GenerationMemoryError(ServingError):
     """The requested ``n_slots × max_length`` decode slab would not fit
@@ -1416,9 +1426,15 @@ class GenerationEngine:
         #: observed hung, and the worker only honors a trip for the
         #: dispatch it actually fired on — a dispatch that completes
         #: just past the limit must not get its trip charged to the
-        #: NEXT, healthy dispatch
-        self._dispatch_gen = 0
+        #: NEXT, healthy dispatch. It is also the step's id, the cause
+        #: of the step's ring entries (obs/trace.py): unique in the
+        #: process, an engine's ids counting up from its own base
+        self._dispatch_gen = next(_ENGINE_IDS) << 32
         self._stall_gen = -1
+        #: ``time.time_ns()`` at the end of the last step's ``gen.emit``,
+        #: where ``gen.turn`` begins; None when no step has just ended
+        #: (an idle loop, a failed dispatch)
+        self._turn_t0: Optional[int] = None
         self._stall_tripped = False
         #: identity tags merged into this engine's chaos seam ctx — the
         #: router tags canary generation engines so a drill can target
@@ -1753,6 +1769,8 @@ class GenerationEngine:
                 req.fail(RequestDeadlineExceeded(
                     "request deadline passed while queued"))
                 continue
+            # a claim's phases belong to the step they precede
+            _trace.set_cause(self._dispatch_gen + 1)
             with _ADMIT:
                 self._claim(slot, req)
 
@@ -2034,6 +2052,13 @@ class GenerationEngine:
             # looks like
             chaos_hooks.fire("generate.decode_dispatch",
                              active=n_active, **self.chaos_ctx)
+            _trace.set_cause(gen)
+            if self._turn_t0 is not None:
+                # entered, not a ``with``: the turn crosses the device
+                # lock's release and re-take in ``_loop``
+                _TURN.record(self._turn_t0,
+                             time.time_ns() - self._turn_t0)
+                self._turn_t0 = None
             if K > 1:
                 # draft building may itself dispatch (truncated mode) —
                 # keep it inside the watchdog's stamped window
@@ -2055,6 +2080,7 @@ class GenerationEngine:
             # so the slots cannot continue — but freed slots + a live
             # worker mean the next prefill rebuilds per-slot state.
             self._dispatch_t0 = None
+            self._turn_t0 = None
             self._stall_tripped = False
             _flight.record("decode_error", error=type(e).__name__,
                            active=n_active)
@@ -2156,6 +2182,7 @@ class GenerationEngine:
                         slot, reason="deadline",
                         error=RequestDeadlineExceeded(
                             "request deadline passed mid-decode"))
+        self._turn_t0 = time.time_ns()
 
     def _learn(self, slot: int, tok: int) -> None:
         """Advance the slot's 2-token draft context and teach the n-gram
@@ -2174,6 +2201,7 @@ class GenerationEngine:
                     self._step()
             self.metrics.set_active_slots(int(self._active.sum()))
             if not any_active:
+                self._turn_t0 = None  # an idle loop is no turn
                 if self._shutdown and self._queue.empty():
                     return
                 # idle: wait for work without holding the device lock

@@ -629,15 +629,17 @@ class TestGenerationEngine:
 class TestEnginePhases:
     """obs/trace.py phases inside GenerationEngine._loop: they nest or
     follow one another, cover a loop iteration, stay within the budget a
-    decode iteration is given, and cost no retrace."""
+    decode iteration is given, carry their decode step's id as their
+    cause, and cost no retrace."""
 
     @pytest.fixture(scope="class")
     def storm(self):
         from deeplearning4j_tpu.obs import trace as obs_trace
 
         # large enough that a decode step outweighs the loop's own
-        # bookkeeping, as on the chip
-        lm = TransformerLM(vocab_size=256, d_model=256, n_heads=4,
+        # bookkeeping, as on the chip: some tens of microseconds a step
+        # lie between one phase's exit and the next one's entry
+        lm = TransformerLM(vocab_size=256, d_model=768, n_heads=4,
                            n_layers=6, max_length=64, seed=3).init()
         eng = GenerationEngine(lm, n_slots=2, queue_limit=16,
                                default_timeout_s=120.0)
@@ -652,47 +654,117 @@ class TestEnginePhases:
             r.result(timeout=120)
         time.sleep(0.05)
         ring = [e for e in obs_trace.phases(mark) if e[0].startswith("gen.")]
-        yield eng, reqs, ring, traced
+        # this engine's own: the module's other engines idle beside it
+        caused = [e for e in obs_trace.caused_phases(mark)
+                  if e[0].startswith("gen.") and e[3] is not None
+                  and e[3] >> 32 == eng._dispatch_gen >> 32]
+        yield eng, reqs, ring, traced, caused
         eng.shutdown(drain=False)
+
+    @staticmethod
+    def _steps(caused):
+        """{step id: [its entries, in ring order]}, the queue waits and
+        idle waits left out."""
+        steps = {}
+        for e in caused:
+            if e[0] not in ("gen.queue_wait", "gen.idle_wait"):
+                steps.setdefault(e[3], []).append(e)
+        return steps
 
     def test_named_phases_nest_and_cover_the_loop(self, storm):
         from tests.phase_checks import assert_nested_or_disjoint, covered_ns
 
-        _eng, _reqs, ring, _ = storm
+        _eng, _reqs, ring, _, _ = storm
         loop = [e for e in ring if e[0] not in ("gen.queue_wait",
                                                 "gen.idle_wait")]
         assert {e[0] for e in loop} == {
             "gen.admit", "gen.prefill", "gen.prefill.put", "gen.decode.put",
-            "gen.decode.dispatch", "gen.decode.fetch", "gen.emit"}
+            "gen.decode.dispatch", "gen.decode.fetch", "gen.emit",
+            "gen.turn"}
         assert_nested_or_disjoint(loop)
-        # the busy stretch: first claim to the last token handed out
-        lo = min(a for n, a, _ in loop if n == "gen.admit")
+        # the busy stretch: the first step's put to the last token handed
+        # out (the claims before the first step follow an idle wait, which
+        # is no turn); with ``gen.turn`` the phases tile it
+        lo = min(a for n, a, _ in loop if n == "gen.decode.put")
         hi = max(a + d for n, a, d in loop if n == "gen.emit")
-        assert covered_ns(loop, lo, hi) >= 0.95 * (hi - lo)
+        assert covered_ns(loop, lo, hi) >= 0.99 * (hi - lo)
 
     def test_phase_budget_of_a_decode_iteration(self, storm):
-        _eng, _reqs, ring, _ = storm
-        loop = sorted((e for e in ring if e[0] not in ("gen.queue_wait",
-                                                       "gen.idle_wait")),
-                      key=lambda e: e[1])
-        # an iteration runs from one claim-or-put to the emit that ends it
-        groups, cur = [], []
-        for e in loop:
-            cur.append(e[0])
-            if e[0] == "gen.emit":
-                groups.append(cur)
-                cur = []
-        assert len(groups) >= 20
-        for names in groups:
+        _eng, _reqs, _ring, _, caused = storm
+        # an iteration is what one step id caused: the turn with the
+        # claims made in it, then put, dispatch, fetch, emit
+        steps = self._steps(caused)
+        assert len(steps) >= 20 and None not in steps
+        for entries in steps.values():
+            names = [e[0] for e in entries]
             claims = names.count("gen.admit")
-            assert len(names) <= 4 + 3 * claims, names
+            assert len(names) <= 5 + 3 * claims, names
             if claims <= 1:
-                assert len(names) <= 8, names
+                assert len(names) <= 9, names
             assert names[-4:] == ["gen.decode.put", "gen.decode.dispatch",
                                   "gen.decode.fetch", "gen.emit"]
+            assert names.count("gen.turn") <= 1
+            if "gen.turn" in names:
+                assert names[-5] == "gen.turn"
+
+    def test_a_step_id_joins_a_decode_steps_phases(self, storm):
+        eng, _reqs, _ring, _, caused = storm
+        steps = self._steps(caused)
+        ids = sorted(steps)
+        # the engine's own ids, counting up by one from its base
+        assert ids == list(range(ids[0], ids[0] + len(ids)))
+        assert ids[-1] == eng._dispatch_gen
+        for step in ids:
+            by_name = {}
+            for e in steps[step]:
+                by_name.setdefault(e[0], []).append(e)
+            for name in ("gen.decode.put", "gen.decode.dispatch",
+                         "gen.decode.fetch", "gen.emit"):
+                assert len(by_name[name]) == 1, (step, name)
+            put, emit = by_name["gen.decode.put"][0], by_name["gen.emit"][0]
+            assert put[1] + put[2] <= by_name["gen.decode.dispatch"][0][1]
+            assert by_name["gen.decode.fetch"][0][1] <= emit[1]
+            # a claim's phases carry the id of the step they precede
+            for name in ("gen.admit", "gen.prefill", "gen.prefill.put"):
+                for e in by_name.get(name, ()):
+                    assert e[1] + e[2] <= put[1], (step, name)
+            assert len(by_name.get("gen.admit", ())) == \
+                len(by_name.get("gen.prefill", ()))
+        waits = [e for e in caused if e[0] == "gen.queue_wait"]
+        claims = [e for e in caused if e[0] == "gen.admit"]
+        assert sorted(e[3] for e in waits) == sorted(e[3] for e in claims)
+        assert len(claims) == 4
+
+    def test_turn_runs_from_an_emit_to_the_next_put(self, storm):
+        from tests.phase_checks import assert_nested_or_disjoint
+
+        _eng, _reqs, _ring, _, caused = storm
+        steps = self._steps(caused)
+        turns = [e for e in caused if e[0] == "gen.turn"]
+        assert len(turns) >= 20
+        for _, a, d, step in turns:
+            # from the end of the step before to this step's put
+            emit = [e for e in steps[step - 1] if e[0] == "gen.emit"][0]
+            put = [e for e in steps[step] if e[0] == "gen.decode.put"][0]
+            assert emit[1] + emit[2] <= a
+            assert a + d <= put[1]
+            # nothing of the loop's between them but the turn itself
+            assert a - (emit[1] + emit[2]) < 200_000
+            assert put[1] - (a + d) < 200_000
+            # the claims made in a turn lie inside it, whole
+            for e in steps[step]:
+                if e[0] == "gen.admit":
+                    assert a <= e[1] and e[1] + e[2] <= a + d
+        # a turn that claimed: slots free up while requests still queue
+        assert any(e[0] == "gen.admit" and any(
+            t[3] == e[3] for t in turns) for e in caused)
+        idle = [e for e in caused if e[0] == "gen.idle_wait"]
+        assert_nested_or_disjoint([e[:3] for e in turns + idle])
+        for _, a, d, _ in idle:
+            assert not any(t[1] < a + d and a < t[1] + t[2] for t in turns)
 
     def test_queue_wait_is_the_rtrace_queue_stage(self, storm):
-        eng, reqs, ring, _ = storm
+        eng, reqs, ring, _, _ = storm
         waits_ms = sorted(r.trace.timeline()["stages"][0]["ms"] for r in reqs)
         assert [r.trace.timeline()["stages"][0]["stage"]
                 for r in reqs] == ["queue"] * 4
@@ -711,8 +783,53 @@ class TestEnginePhases:
             eng.metrics.registry.prometheus_text()
 
     def test_phases_cost_no_retrace(self, storm):
-        eng, _reqs, _ring, traced = storm
+        eng, _reqs, _ring, traced, _ = storm
         assert eng.trace_counts == traced
+
+    def test_two_engines_do_not_share_step_ids(self, storm):
+        from deeplearning4j_tpu.obs import trace as obs_trace
+
+        eng, _reqs, _ring, _, caused = storm
+        other = _engine()
+        assert other._dispatch_gen >> 32 != eng._dispatch_gen >> 32
+        mark = time.time_ns()
+        before = other._dispatch_gen
+        out = other.submit(_prompts(1, seed=77)[0], max_new=5,
+                           timeout=60).result(timeout=60)
+        assert out.size > 0
+        time.sleep(0.05)
+        mine = [e for e in obs_trace.caused_phases(mark)
+                if e[0].startswith("gen.decode.")]
+        assert mine and all(before < e[3] <= other._dispatch_gen
+                            for e in mine)
+        assert not {e[3] for e in mine} & {e[3] for e in caused}
+
+    def test_verify_and_draft_carry_the_steps_id(self):
+        """K > 1 through the truncated draft: a step's draft rollout and
+        its verify are two dispatches of one step, under one id."""
+        from deeplearning4j_tpu.obs import trace as obs_trace
+
+        eng = _bf16_engine()
+        mark = time.time_ns()
+        before = eng._dispatch_gen
+        eng.submit(_prompts(1, (8, 12), seed=78)[0], max_new=12,
+                   timeout=90).result(timeout=90)
+        time.sleep(0.05)
+        steps = self._steps(
+            [e for e in obs_trace.caused_phases(mark)
+             if e[0].startswith("gen.") and e[3] is not None
+             and before < e[3] <= eng._dispatch_gen])
+        assert steps
+        speculated = 0
+        for step, entries in steps.items():
+            names = [e[0] for e in entries if e[0] != "gen.turn"
+                     and not e[0].startswith(("gen.admit", "gen.prefill"))]
+            one = ["gen.decode.put", "gen.decode.dispatch",
+                   "gen.decode.fetch"]
+            assert names in (one + ["gen.emit"], one + one + ["gen.emit"]), (
+                step, names)
+            speculated += names == one + one + ["gen.emit"]
+        assert speculated >= 1
 
     def test_scope_names_in_the_engine_programs(self, storm):
         import jax.numpy as jnp

@@ -493,6 +493,104 @@ class TestPhases:
         got = [e for e in obs_trace.phases(mark) if e[0] == "t.threads"]
         assert len(got) == 4 * 400 and all(d >= 0 for _, _, d in got)
 
+    def test_three_wide_view_is_the_four_wide_one_without_the_cause(self):
+        obs_trace.set_cause(None)
+        mark = time.time_ns()
+        with obs_trace.phase("t.view.outer"):
+            obs_trace.set_cause(41)
+            with obs_trace.phase("t.view.inner"):
+                pass
+        obs_trace.observe("t.view.seen", mark, 7)
+        obs_trace.observe("t.view.seen", mark, 8, cause=99)
+        obs_trace.set_cause(None)
+        with obs_trace.phase("t.view.inner"):
+            pass
+        wide = [e for e in obs_trace.caused_phases(mark)
+                if e[0].startswith("t.view.")]
+        narrow = [e for e in obs_trace.phases(mark)
+                  if e[0].startswith("t.view.")]
+        # same entries, same order, plain tuples of three as before
+        assert narrow == [e[:3] for e in wide]
+        assert all(type(e) is tuple and len(e) == 3 for e in narrow)
+        assert all(type(e) is tuple and len(e) == 4 for e in wide)
+        # the cause is the thread's when the phase ENDS; observe takes
+        # the thread's or the one it is given
+        assert [(e[0], e[3]) for e in wide] == [
+            ("t.view.inner", 41), ("t.view.outer", 41), ("t.view.seen", 41),
+            ("t.view.seen", 99), ("t.view.inner", None)]
+        assert len(obs_trace.phases()) == len(obs_trace.caused_phases())
+
+    def test_the_cause_is_the_threads_own(self):
+        import threading
+
+        p = obs_trace.phase("t.cause.threads")
+        mark = time.time_ns()
+
+        def work(base):
+            for i in range(100):
+                obs_trace.set_cause(base + i)
+                with p:
+                    time.sleep(0)  # let the other threads in
+
+        threads = [threading.Thread(target=work, args=(1000 * (k + 1),))
+                   for k in range(4)]
+        for t in threads:
+            t.start()
+        obs_trace.set_cause(7)
+        with p:
+            pass
+        for t in threads:
+            t.join()
+        got = [e for e in obs_trace.caused_phases(mark)
+               if e[0] == "t.cause.threads"]
+        assert sorted(e[3] for e in got) == sorted(
+            [7] + [1000 * (k + 1) + i for k in range(4) for i in range(100)])
+        # a thread that never set one has none
+        t = threading.Thread(target=lambda: p.record(1, 1))
+        t.start()
+        t.join()
+        assert obs_trace.caused_phases()[-1] == ("t.cause.threads", 1, 1,
+                                                 None)
+        obs_trace.set_cause(None)
+
+    @pytest.mark.parametrize("runtime", ["multilayer", "graph", "lm"])
+    def test_training_phases_carry_the_iteration(self, runtime):
+        if runtime == "lm":
+            from deeplearning4j_tpu.models.transformer_lm import TransformerLM
+
+            lm = TransformerLM(vocab_size=32, d_model=16, n_heads=2,
+                               n_layers=1, max_length=8, seed=1).init()
+            ids = np.arange(16, dtype=np.int32).reshape(2, 8) % 32
+            lm.fit_batch(ids, ids)
+            mark = time.time_ns()
+            first = lm.iteration + 1  # counted before the step
+            for _ in range(3):
+                lm.fit_batch(ids, ids)
+        else:
+            conf = (NeuralNetConfiguration.builder().seed(7)
+                    .updater(Adam(1e-3)).list()
+                    .layer(DenseLayer(n_out=8, activation="relu"))
+                    .layer(OutputLayer(n_out=3, activation="softmax",
+                                       loss="mcxent"))
+                    .set_input_type(InputType.feed_forward(4)).build())
+            net = MultiLayerNetwork(conf).init()
+            if runtime == "graph":
+                net = net.to_computation_graph()
+            it = ExistingDataSetIterator(_batches(3, b=8, d=4))
+            net.fit(it, epochs=1)
+            mark = time.time_ns()
+            first = net.iteration  # counted after the step
+            net.fit(it, epochs=1)
+        got = [e for e in obs_trace.caused_phases(mark)
+               if e[0] in ("train.put_batch", "train.dispatch",
+                           "train.fetch_loss")]
+        by_cause = {}
+        for name, _, _, cause in got:
+            by_cause.setdefault(cause, []).append(name)
+        assert sorted(by_cause) == [first, first + 1, first + 2]
+        for names in by_cause.values():
+            assert names[:2] == ["train.put_batch", "train.dispatch"]
+
     def test_fit_phases_cover_the_step_and_never_retrace(self):
         """MultiLayerNetwork.fit: iterate / put_batch / dispatch /
         fetch_loss, at most four a step, nested or disjoint, covering
