@@ -290,18 +290,22 @@ def _http(port, method, path, body=None, timeout=600):
         conn.close()
 
 
-def step_ids_in_ring(gen, since_ns):
+def step_ids_in_ring(gen, since_ns, ahead=None):
     """What the engine's ring entries since ``since_ns`` say of its
     decode steps (``obs/trace.py``: an entry's cause is its step's id):
     each step one put, one dispatch, one fetch and one emit under one id,
-    and ``gen.turn`` between two steps."""
+    and ``gen.turn`` between two steps. ``ahead``: the engine's
+    ``decode_steps_ahead`` where its loop keeps a step in flight: as many
+    steps, and at least one, were dispatched before the step before them
+    was fetched."""
     from deeplearning4j_tpu.obs import trace as obs_trace
 
     mine = gen._dispatch_gen >> 32
-    steps = {}
-    for name, _, _, cause in obs_trace.caused_phases(since_ns):
+    steps, began = {}, {}
+    for name, start, _, cause in obs_trace.caused_phases(since_ns):
         if cause is not None and cause >> 32 == mine:
             steps.setdefault(cause, []).append(name)
+            began[cause, name] = start
     check(steps, "the ring holds no entry with one of the engine's step ids")
     step = ["gen.decode.put", "gen.decode.dispatch", "gen.decode.fetch",
             "gen.emit"]
@@ -312,7 +316,17 @@ def step_ids_in_ring(gen, since_ns):
     turns = sum(names.count("gen.turn") for names in steps.values())
     check(0 < turns <= len(decoded),
           f"{turns} gen.turn entries beside {len(decoded)} decode steps")
-    return {"decode_steps": len(decoded), "turns": turns}
+    out = {"decode_steps": len(decoded), "turns": turns}
+    if ahead is not None:
+        out["dispatched_ahead"] = sum(
+            1 for i in decoded if i + 1 in decoded
+            and began[i + 1, "gen.decode.dispatch"]
+            < began[i, "gen.decode.fetch"])
+        check(0 < ahead == out["dispatched_ahead"],
+              f"decode_steps_ahead {ahead}, and {out['dispatched_ahead']} "
+              f"of {len(decoded)} steps in the ring were dispatched before "
+              "the fetch of the step before them")
+    return out
 
 
 def phase_lm_serve(size, model):
@@ -413,8 +427,9 @@ def phase_hybrid_serve(size):
         served = [np.asarray(r.result(timeout=900)) for r in requests]
         check(gen.trace_counts == traced,
               f"retraced after warm-up: {traced} -> {gen.trace_counts}")
-        ring = step_ids_in_ring(gen, mark)
         snapshot = gen.metrics.snapshot()
+        ring = step_ids_in_ring(gen, mark,
+                                ahead=snapshot["decode_steps_ahead"])
         report = gen.describe()["memory"]
     finally:
         gen.shutdown()
@@ -429,7 +444,8 @@ def phase_hybrid_serve(size):
             "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
             "state_bytes": report["state_bytes"],
             "slab_bytes": report["slab_bytes"],
-            "state_slots": snapshot["state_slots"], "ring": ring,
+            "state_slots": snapshot["state_slots"],
+            "late_slot_steps": snapshot["late_slot_steps"], "ring": ring,
             "tokens_equal_generate_cached": True}
 
 

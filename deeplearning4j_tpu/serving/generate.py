@@ -58,8 +58,16 @@ import itertools
 import queue
 import threading
 import time
-from collections import OrderedDict
-from typing import Callable, Dict, List, Optional, Sequence
+from collections import OrderedDict, deque
+from typing import (
+    Callable,
+    Dict,
+    List,
+    NamedTuple,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -79,11 +87,13 @@ from deeplearning4j_tpu.serving.metrics import GenerationMetrics
 
 # host phases of the worker loop (obs/trace.py). ``gen.admit`` encloses
 # ``gen.prefill``, which encloses ``gen.prefill.put``; ``gen.turn`` (the
-# loop's own time from one step's ``gen.emit`` to the next step's
-# ``gen.decode.put``) encloses the claims made in it; the others follow
-# one another, so the self times sum to a loop iteration. Every entry
-# carries its decode step's id as its cause: a claim's, and the turn's,
-# the id of the step they precede.
+# loop's own time from one step's ``gen.emit`` to the next backend call)
+# encloses the claims made in it; the others follow one another, so the
+# self times sum to a loop iteration. Every entry carries its decode
+# step's id as its cause: a claim's, and the turn's, the id of the step
+# they precede. Lock-step a step's put, dispatch, fetch and emit follow
+# one another; where a step is kept in flight (``_step_ahead``) the put
+# and dispatch of step t+1 come before the fetch and emit of step t.
 _ADMIT = _trace.phase("gen.admit")
 _PREFILL = _trace.phase("gen.prefill")
 _PREFILL_PUT = _trace.phase("gen.prefill.put")
@@ -816,7 +826,19 @@ class _DecoderBackend:
     every decode step inside the layer loop (idle slots bit for bit as
     they were) and written for one slot by a prefill. With such a layer
     K > 1 and a prefix cache are REFUSED (:class:`RecurrentStateError`):
-    a state cannot be rolled back by dropping columns."""
+    a state cannot be rolled back by dropping columns.
+
+    The slots' inputs live on the device as one array (``_state``), so
+    the step has no ``decode`` that puts, dispatches and fetches in one
+    call: it has a ``launch`` and a ``collect``, and the engine, which
+    sees the pair, keeps ONE STEP IN FLIGHT (``GenerationEngine.
+    _step_ahead``): step t+1 is launched from the array step t will hand
+    back before step t's copy of it is fetched, so the device goes from
+    one decode program to the next without waiting ~2-3 ms for the
+    tokens' way to the host and the launch's way back (PERF.md, PRs 37
+    and 39). The backends that keep the slots' inputs on the host
+    (``_TransformerBackend``, ``_RecurrentBackend``) cannot launch ahead
+    and stay lock-step."""
 
     kind = "decoder"
     spec_k = 1
@@ -847,18 +869,11 @@ class _DecoderBackend:
             prefill_buckets or getattr(model, "serving_seq_buckets", None))
         self.cache_bytes = sum(
             p["bytes"] for p in cfg.cache_plan(self.n_slots, self.max_length))
-        #: (expert pairs computed here, held experts hit) of the last
-        #: decode step, all layers; the engine adds them to its metrics
-        self.step_counters = (0, 0)
-        #: cache positions the last decode step's active slots had behind
-        #: them, where a layer keeps a latent cache (else 0): host
-        #: arithmetic on what ``decode`` is handed
-        self.step_latent_positions = 0
-        self._latent = any(k["latent"] for k in cfg.attn_kinds.values())
-        #: slots whose recurrent state the last decode step advanced,
-        #: where a layer keeps one (else 0): host arithmetic too
-        self.step_state_slots = 0
-        #: some layer keeps a recurrent state: no prefix cache, K = 1
+        #: some layer keeps a latent cache: the engine counts the
+        #: positions a launched step's slots have behind them
+        self.latent = any(k["latent"] for k in cfg.attn_kinds.values())
+        #: some layer keeps a recurrent state: no prefix cache, K = 1;
+        #: the engine counts the slots a launched step advances
         self.keeps_state = any(k["ssm"] for k in cfg.attn_kinds.values())
         if self.keeps_state and int(spec_k) > 1:
             raise RecurrentStateError(
@@ -877,7 +892,8 @@ class _DecoderBackend:
             trace_hook("generation_decode")
             rows = state[:-1]
             toks, pos, k = rows[:, 0], rows[:, 1], rows[:, 3]
-            active = rows[:, 2] != 0
+            left = rows[:, 2]
+            active = left > 0
             keys = jax.lax.bitcast_convert_type(rows[:, 4:6], jnp.uint32)
             logits, caches, counts = decode_step(cfg, p, caches, toks, pos,
                                                  active)
@@ -887,7 +903,8 @@ class _DecoderBackend:
             nxt = jnp.where(active, nxt, toks)
             nkeys = jnp.where(active[:, None], nkeys, keys)
             after = jnp.concatenate(
-                [nxt[:, None], (pos + active)[:, None], rows[:, 2:4],
+                [nxt[:, None], (pos + active)[:, None],
+                 (left - active)[:, None], k[:, None],
                  jax.lax.bitcast_convert_type(nkeys, jnp.int32),
                  rows[:, 6:8]], axis=1)
             last = jnp.zeros((1, 8), jnp.int32).at[0, :2].set(
@@ -896,36 +913,41 @@ class _DecoderBackend:
 
         def _prefill(p, caches, state, req):
             # req: the request's row as ``_state`` lays it (its token is
-            # still to come), then the prompt padded to its bucket
+            # still to come: the word holds the slot), then the prompt
+            # padded to its bucket
             trace_hook("generation_prefill")
-            ln, slot, k = req[1], req[2], req[3]
+            slot, ln, left, k = req[0], req[1], req[2], req[3]
             key = jax.lax.bitcast_convert_type(req[4:6], jnp.uint32)
             logits, caches = prefill_slot(cfg, p, caches, req[None, 8:], ln,
                                           slot)
             tok0, key = sample_next_device(logits, _f32(req[6]), k,
                                            _f32(req[7]), key)
             row = jnp.concatenate(
-                [tok0, ln[None], jnp.ones((1,), jnp.int32), k[None],
+                [tok0, ln[None], left[None], k[None],
                  jax.lax.bitcast_convert_type(key, jnp.int32), req[6:8]])
             return caches, state.at[slot].set(row), row, logits[0]
 
+        def _stop(state, stopped):
+            # the host's edit of the rows it stopped itself: no step left
+            return state.at[:, 2].multiply(1 - stopped)
+
         self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))
+        self._stop_fn = jax.jit(_stop)
 
     def reset(self) -> None:
         from deeplearning4j_tpu.models.decoder_lm import init_cache
 
         self._caches = init_cache(self._cfg, self.n_slots, self.max_length)
-        #: what the next step takes if the host changes nothing, on the
-        #: device and as the host's copy of it (see ``_state``)
-        self._kept = (np.zeros((self.n_slots + 1, 8), np.int32),
-                      jnp.zeros((self.n_slots + 1, 8), jnp.int32))
-        #: where ``decode`` lays the host's view of the slots each step
-        self._mine = np.zeros((self.n_slots + 1, 8), np.int32)
+        #: what the next launch takes, on the device: the last launch's
+        #: or prefill's output, maybe still to be computed (``_state``)
+        self._slots_state = jnp.zeros((self.n_slots + 1, 8), jnp.int32)
+        #: slots the host stopped since the last launch (``stop``)
+        self._stopped: set = set()
 
     def release(self) -> None:
         """Let the cache go (engine shutdown)."""
-        self._caches = self._kept = None
+        self._caches = self._slots_state = None
 
     bucket_for = _TransformerBackend.bucket_for
     #: by the slot's length, which an attention layer's columns set. A
@@ -935,80 +957,86 @@ class _DecoderBackend:
     window_check = _TransformerBackend.window_check
 
     @staticmethod
-    def _state(rows, tokens, pos, active, temperature, top_k, top_p, keys):
-        """The slots' inputs as ONE int32 array, a row a slot: token,
-        position, active, top_k, the key's two words, and temperature and
-        top_p as their bits. The decode program returns the next step's
-        (tokens, positions advanced, keys) with the step's two expert
-        counters in a last row, and a prefill writes its slot's row, so
-        the array stays on the device and the host puts it only after it
-        changed a slot itself (a finish). A step costs one fetch and, as
-        a rule, no put; a claim one put and one fetch. Seven puts a step
-        took 5.7 ms of 54 on the chip among 42 streaming threads, and the
-        host's share of a step, most of all of the step after a prefill,
-        is where the cell's run-to-run spread comes from (PERF.md,
-        PR 27)."""
-        rows[:, 0], rows[:, 1], rows[:, 2], rows[:, 3] = (tokens, pos,
-                                                          active, top_k)
-        rows[:, 4:6] = np.asarray(keys, np.uint32).reshape(-1, 2).view(
+    def _state(row, word0, pos, left, temperature, top_k, top_p, key):
+        """A slot's inputs as one int32 row: token, position, the decode
+        steps the slot has LEFT (active while > 0), top_k, the key's two
+        words, and temperature and top_p as their bits; the slots' rows
+        and a last row for the step's two expert counters are ONE array
+        that never leaves the device. The decode program returns the
+        next step's (tokens, positions advanced, steps left counted
+        down, keys) and a prefill writes its slot's row, steps left =
+        ``max_new - 1`` among it, so a launch needs nothing from the
+        host: the loop launches step t+1 from the array step t will
+        hand back and only then fetches step t's copy to stream its
+        tokens. A request that ends by ``max_new`` stops on the device
+        by itself; a stop the host decides (a deadline, a caller that
+        gave up) it sees a step late, and ``stop`` zeroes that row's
+        steps in the next launch: a whole put would roll the other
+        slots back a step. A step costs one fetch and no put; a claim
+        one put and one fetch. Seven puts a step took 5.7 ms of 54 on
+        the chip among 42 streaming threads (PERF.md, PR 27), and the
+        fetch-then-launch order left the device waiting 2-3 ms a step
+        (PERF.md, PRs 37 and 39)."""
+        row[0], row[1], row[2], row[3] = word0, pos, left, top_k
+        row[4:6] = np.asarray(key, np.uint32).reshape(2).view(np.int32)
+        row[6:8] = np.asarray([temperature, top_p], np.float32).view(
             np.int32)
-        rows[:, 6] = np.asarray(temperature, np.float32).reshape(-1).view(
-            np.int32)
-        rows[:, 7] = np.asarray(top_p, np.float32).reshape(-1).view(np.int32)
-        return rows
+        return row
 
     def prefill(self, slot: int, prompt: np.ndarray, temperature: float,
-                top_k: int, top_p: float, key: np.ndarray):
+                top_k: int, top_p: float, key: np.ndarray, steps: int = 0):
         """As ``_TransformerBackend.prefill``; every prompt is bucketed:
-        the dropless expert layer has no capacity for padding to take."""
+        the dropless expert layer has no capacity for padding to take.
+        ``steps``: the decode steps the slot runs after its first token.
+        Dispatched behind the step in flight, whose caches and state it
+        takes as they will be; the fetch of its row waits for both."""
         tp = int(prompt.shape[0])
         tb = self.bucket_for(tp)
         with _PREFILL_PUT:
             req = np.zeros((8 + tb,), np.int32)
-            self._state(req[None, :8], 0, tp, 1, temperature, top_k, top_p,
+            self._state(req[:8], slot, tp, steps, temperature, top_k, top_p,
                         key)
-            req[2] = slot
             req[8:8 + tp] = prompt
             req = jnp.asarray(req)
-        mirror, state = self._kept
-        self._caches, state, row, logits0 = self._prefill_fn(
-            self.model.params_, self._caches, state, req)
+        # the prefill writes the whole row: the host's stop of the slot's
+        # last occupant has nothing left to edit
+        self._stopped.discard(slot)
+        self._caches, self._slots_state, row, logits0 = self._prefill_fn(
+            self.model.params_, self._caches, self._slots_state, req)
         del req
         row = np.asarray(row)
-        mirror[slot] = row
-        self._kept = (mirror, state)
         return int(row[0]), row[4:6].view(np.uint32), tb, logits0
 
-    def decode(self, tokens, pos, active, temperature, top_k, top_p, keys):
-        """As ``_TransformerBackend.decode``, through ``_state``."""
+    def stop(self, slot: int) -> None:
+        """The host ended ``slot``'s request before its steps ran out:
+        its row has no step left from the next launch on."""
+        self._stopped.add(slot)
+
+    def launch(self):
+        """Dispatch one decode step for all slots from the state on the
+        device and return what ``collect`` takes: the step's own output
+        array, which the NEXT launch reads too (it is not donated)."""
         with _DECODE_PUT:
-            mirror, state = self._kept
-            mine = self._mine
-            self._state(mine[:-1], tokens, pos, active, temperature, top_k,
-                        top_p, keys)
-            if self._latent:
-                self.step_latent_positions = int(np.dot(mine[:-1, 1],
-                                                        mine[:-1, 2]))
-            if self.keeps_state:
-                self.step_state_slots = int(mine[:-1, 2].sum())
-            # no new array and no loop over the whole of one before the
-            # dispatch: NumPy lets the interpreter go inside a loop over
-            # more than 500 elements (64 slots x 8 are 512), and the
-            # streaming threads that the last emit woke then all run
-            # while the device waits for this step: with ``array_equal``
-            # here the phase took 8.9 ms a step on the chip (PERF.md,
-            # PR 27). A put may read its host array after it returns:
-            # it gets a copy
-            if mine[:-1].tobytes() != mirror[:-1].tobytes():
-                state = jnp.asarray(mine.copy())
+            state = self._slots_state
+            if self._stopped:
+                stopped = np.zeros((self.n_slots + 1,), np.int32)
+                stopped[list(self._stopped)] = 1
+                self._stopped.clear()
+                state = self._stop_fn(state, jnp.asarray(stopped))
         with _DECODE_DISPATCH:
             self._caches, state = self._decode_fn(
                 self.model.params_, self._caches, state)
+        self._slots_state = state
+        return state
+
+    @staticmethod
+    def collect(state):
+        """The tokens of the step ``launch`` returned ``state`` for, as
+        host arrays: (next tokens (S,), (expert pairs computed here,
+        held experts hit) of the step, all layers)."""
         with _DECODE_FETCH:
-            mirror = np.array(state)  # a copy: prefill writes a row into it
-            self.step_counters = (int(mirror[-1, 0]), int(mirror[-1, 1]))
-            self._kept = (mirror, state)
-            return mirror[:-1, 0], mirror[:-1, 4:6].view(np.uint32)
+            rows = np.asarray(state)
+            return rows[:-1, 0], (int(rows[-1, 0]), int(rows[-1, 1]))
 
 
 def _cell_decode_supported(model) -> bool:
@@ -1374,6 +1402,21 @@ def _device_bytes_limit() -> Optional[int]:
 # --------------------------------------------------------------------------
 # the engine
 # --------------------------------------------------------------------------
+class _Launched(NamedTuple):
+    """A decode step that was launched and not yet collected; beside
+    it in ``GenerationEngine._flight`` lies what the backend's
+    ``collect`` takes."""
+
+    gen: int                #: the step's id
+    t0: float               #: ``time.monotonic()`` at its launch
+    ran: np.ndarray         #: (S,) bool: the slots it runs
+    slots: list             #: the requests that held the slots then
+    drawn: bool             #: the sampler's branches, as ``_step`` counts
+    filtered: bool
+    latent_positions: int   #: what ``record_latent_positions`` takes
+    state_slots: int        #: what ``record_state_slots`` takes
+
+
 class GenerationEngine:
     """Slotted continuous-batching decode engine over one model.
 
@@ -1387,6 +1430,17 @@ class GenerationEngine:
     matrices and the head in that dtype, re-made when the leaves of
     ``params_`` are other objects (``_TransformerBackend._params``;
     ``param_casts`` in the metrics counts them).
+
+    The loop runs one of two orders, by what the backend offers. Where
+    it keeps the slots' inputs on the host (``decode``) the loop is
+    lock-step: put, dispatch, fetch, emit, turn. Where they live on the
+    device and the step comes as ``launch`` / ``collect``
+    (``_DecoderBackend``) the loop keeps one step in flight
+    (``_step_ahead``): launch t+1, fetch and emit t, turn, launch t+2.
+    The tokens are the same; the device no longer waits for the host
+    between two steps, and a stop that only the host can decide
+    (deadline, a caller that gave up) is honoured one thrown-away
+    slot-step late (``late_slot_steps``).
 
     ``memory_limit_bytes``: explicit budget, ``"auto"`` (device
     ``bytes_limit`` when the backend reports one, else unchecked), or
@@ -1566,6 +1620,19 @@ class GenerationEngine:
         self._topk = np.zeros((S,), np.int32)
         self._topp = np.zeros((S,), np.float32)
         self._keys = np.zeros((S, 2), np.uint32)
+        #: the backend's step is a launch and a collect: one step is
+        #: kept in flight (``_step_ahead``)
+        self._ahead = hasattr(self.backend, "launch")
+        #: launched, uncollected steps, oldest first, each (what the
+        #: backend's ``collect`` takes, the step): at most two, and two
+        #: only between a launch and the collect that follows it
+        self._flight: "deque[Tuple[object, _Launched]]" = deque()
+        #: decode steps each slot has left on the device, which counts
+        #: them down itself; the host's copy, counted down at a launch
+        self._left = np.zeros((S,), np.int32)
+        #: ``time.monotonic()`` when the last collect or claim ended:
+        #: where the next collected step's seconds begin
+        self._step_ref = 0.0
         self._shutdown = False
         self._dev_lock = witnessed_lock("generate.device")
         self._worker = threading.Thread(target=self._loop, daemon=True,
@@ -1710,7 +1777,7 @@ class GenerationEngine:
         t0 = time.perf_counter()
         before = dict(self.trace_counts)
         with self._dev_lock:
-            if self._active.any():
+            if self._active.any() or self._flight:
                 return {"skipped": "slots active (already warm)"}
             key = np.asarray(jax.random.PRNGKey(0))
             for tb in self.backend.buckets:
@@ -1729,9 +1796,15 @@ class GenerationEngine:
                 if verbose:
                     print(f"generation warmup: prefill bucket {tb}",
                           flush=True)
-            self.backend.decode(self._tokens, self._pos,
-                                np.zeros_like(self._active), self._temp,
-                                self._topk, self._topp, self._keys)
+            if self._ahead:
+                # no slot has a step left: the step, and the edit of a
+                # row the host stopped, run on idle rows
+                self.backend.stop(0)
+                self.backend.collect(self.backend.launch())
+            else:
+                self.backend.decode(self._tokens, self._pos,
+                                    np.zeros_like(self._active), self._temp,
+                                    self._topk, self._topp, self._keys)
             if self.spec_decode_k > 1:
                 # the proposal-lane programs: truncated draft rollout
                 # (when that mode is on) + the batched verify
@@ -1824,9 +1897,12 @@ class GenerationEngine:
                             * int(req.prompt.size))
             if not hit:
                 try:
+                    # the device counts a slot's steps down where the
+                    # loop launches ahead of the tokens
                     tok0, key, bucket, logits0 = self.backend.prefill(
                         slot, req.prompt, req.temperature, req.top_k,
-                        req.top_p, key0)
+                        req.top_p, key0,
+                        *((req.max_new - 1,) if self._ahead else ()))
                 except BaseException as e:  # keep the worker alive
                     self.metrics.record_error()
                     req.fail(e)
@@ -1853,6 +1929,8 @@ class GenerationEngine:
         self._slots[slot] = req
         req.slot = slot
         self._active[slot] = True
+        if self._ahead:
+            self._left[slot] = req.max_new - 1
         self._tokens[slot] = tok0
         self._pos[slot] = req.prompt.size
         self._temp[slot] = req.temperature
@@ -1873,6 +1951,7 @@ class GenerationEngine:
         self._replay_advance(slot, int(tok0), 1)
         if len(req.tokens) >= req.max_new:
             self._finish_slot(slot, reason="done")
+        self._step_ref = time.monotonic()
 
     def _replay_advance(self, slot: int, tok: int, n: int) -> None:
         """Invalidate the slot's completion replay at the first emitted
@@ -1897,6 +1976,15 @@ class GenerationEngine:
         if req is None:
             return
         req.slot = None
+        if self._ahead:
+            # steps in flight that still run the slot are thrown away
+            late = sum(1 for _, step in self._flight
+                       if step.ran[slot] and step.slots[slot] is req)
+            if late:
+                self.metrics.record_late_slot_steps(late)
+            if self._left[slot] > 0:
+                self._left[slot] = 0
+                self.backend.stop(slot)
         if req.trace is not None:
             req.trace.mark("decode_done")
         n_tok = len(req.tokens)
@@ -1941,7 +2029,9 @@ class GenerationEngine:
         from outside, and past the limit fails the active requests
         typed and records the escalated stall — callers unblock, the
         blocked worker performs slab cleanup when (if) the dispatch
-        finally returns."""
+        finally returns. With a step kept in flight the stamp is the
+        launch of the OLDEST uncollected step, so a hung collect trips
+        it as a hung dispatch does."""
         from deeplearning4j_tpu.obs import flight as _flight
 
         while True:
@@ -1958,11 +2048,11 @@ class GenerationEngine:
             elapsed = time.monotonic() - t0
             if elapsed <= limit:
                 continue
-            if self._dispatch_gen != gen or self._dispatch_t0 is None:
+            if self._dispatch_gen != gen or self._dispatch_t0 != t0:
                 continue  # that dispatch completed while we measured
             self._stall_gen = gen
             self._stall_tripped = True
-            if self._dispatch_gen != gen or self._dispatch_t0 is None:
+            if self._dispatch_gen != gen or self._dispatch_t0 != t0:
                 # completed in the set window: withdraw the trip before
                 # failing anyone — these slots now belong to a healthy
                 # (or no) dispatch
@@ -2033,6 +2123,28 @@ class GenerationEngine:
                     toks_k[slot, 1:1 + len(ds)] = ds
         return toks_k, dlen
 
+    def _end_turn(self) -> None:
+        """``gen.turn`` ends where the next backend call begins. Entered
+        from two clock reads, not a ``with``: the turn crosses the
+        device lock's release and re-take in ``_loop``."""
+        if self._turn_t0 is not None:
+            _TURN.record(self._turn_t0, time.time_ns() - self._turn_t0)
+            self._turn_t0 = None
+
+    def _drop_steps(self, reason: str, error: BaseException) -> None:
+        """A step failed or hung: what was launched is lost (the donated
+        caches went with it), so every active request fails typed and
+        the backend starts over; freed slots and a live worker mean the
+        next prefill rebuilds per-slot state."""
+        self._dispatch_t0 = None
+        self._turn_t0 = None
+        self._stall_tripped = False
+        self._flight.clear()
+        for slot in range(self.n_slots):
+            if self._slots[slot] is not None:
+                self._finish_slot(slot, reason=reason, error=error)
+        self.backend.reset()
+
     def _step(self) -> None:
         from deeplearning4j_tpu.models.transformer_lm import sampling_needs
         from deeplearning4j_tpu.obs import flight as _flight
@@ -2053,12 +2165,7 @@ class GenerationEngine:
             chaos_hooks.fire("generate.decode_dispatch",
                              active=n_active, **self.chaos_ctx)
             _trace.set_cause(gen)
-            if self._turn_t0 is not None:
-                # entered, not a ``with``: the turn crosses the device
-                # lock's release and re-take in ``_loop``
-                _TURN.record(self._turn_t0,
-                             time.time_ns() - self._turn_t0)
-                self._turn_t0 = None
+            self._end_turn()
             if K > 1:
                 # draft building may itself dispatch (truncated mode) —
                 # keep it inside the watchdog's stamped window
@@ -2076,18 +2183,10 @@ class GenerationEngine:
             # failure (bad hot-swapped params, transient device error)
             # fails the ACTIVE requests typed instead of silently
             # killing the loop and hanging every present and future
-            # caller. The donated slab is gone with the failed dispatch,
-            # so the slots cannot continue — but freed slots + a live
-            # worker mean the next prefill rebuilds per-slot state.
-            self._dispatch_t0 = None
-            self._turn_t0 = None
-            self._stall_tripped = False
+            # caller
             _flight.record("decode_error", error=type(e).__name__,
                            active=n_active)
-            for slot in range(self.n_slots):
-                if self._slots[slot] is not None:
-                    self._finish_slot(slot, reason="decode_error", error=e)
-            self.backend.reset()
+            self._drop_steps("decode_error", e)
             return
         self._dispatch_t0 = None
         dt = time.monotonic() - t0
@@ -2105,13 +2204,8 @@ class GenerationEngine:
                 # decode-failure path
                 _flight.record("decode_stall_recovered",
                                wall_ms=round(dt * 1e3, 1), active=n_active)
-                err = DecodeStalledError("decode dispatch exceeded the "
-                                         "watchdog limit")
-                for slot in range(self.n_slots):
-                    if self._slots[slot] is not None:
-                        self._finish_slot(slot, reason="decode_stall",
-                                          error=err)
-                self.backend.reset()
+                self._drop_steps("decode_stall", DecodeStalledError(
+                    "decode dispatch exceeded the watchdog limit"))
                 return
         with _EMIT:
             self._step_ewma_s = (dt if self._step_ewma_s is None
@@ -2130,13 +2224,6 @@ class GenerationEngine:
             else:
                 self.metrics.record_decode_step(dt, n_active, drawn,
                                                 filtered)
-                counts = getattr(self.backend, "step_counters", None)
-                if counts is not None:
-                    self.metrics.record_moe_step(*counts)
-                    self.metrics.record_latent_positions(
-                        self.backend.step_latent_positions)
-                    self.metrics.record_state_slots(
-                        self.backend.step_state_slots)
             if dt * 1e3 > self.stall_ms:
                 _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
                                active=n_active)
@@ -2184,6 +2271,119 @@ class GenerationEngine:
                             "request deadline passed mid-decode"))
         self._turn_t0 = time.time_ns()
 
+    def _step_ahead(self) -> None:
+        """One pass of the loop where the backend's step is a launch and
+        a collect: launch the next step from the state on the device,
+        THEN fetch the step before it and hand its tokens out, so the
+        device runs step t+1 while the host streams step t. The first
+        step after an idle stretch is launched and left in flight; with
+        nothing left to launch the pass collects alone and the pipeline
+        drains. What the host cannot know at a launch is what the tokens
+        decide: a slot it stops at step t's emit (deadline, a caller
+        that gave up) still runs in step t+1, whose token for it is
+        dropped; a request's last step by ``max_new`` the device counts
+        down itself."""
+        from deeplearning4j_tpu.models.transformer_lm import sampling_needs
+        from deeplearning4j_tpu.obs import flight as _flight
+
+        be, flight = self.backend, self._flight
+        n_active = int(self._active.sum())
+        step = None
+        try:
+            ran = self._left > 0
+            launching = bool(ran.any())
+            if launching:
+                self._dispatch_gen += 1
+                t0 = time.monotonic()
+                if not flight:
+                    self._dispatch_t0 = t0
+                # chaos seam, as in ``_step``: the watchdog's stamp is
+                # set (this launch's, or the older step's in flight)
+                chaos_hooks.fire("generate.decode_dispatch",
+                                 active=n_active, **self.chaos_ctx)
+                _trace.set_cause(self._dispatch_gen)
+                self._end_turn()
+                handle = be.launch()
+                if flight:
+                    self.metrics.record_step_ahead()
+                # what the step was handed, from the host's copy (no
+                # fetch): the sampler's branch, the positions and states
+                # its slots read, a slot whose stop comes too late among
+                # them
+                drawn, filtered = sampling_needs(
+                    np.where(ran, self._temp, 0.0), self._topk, self._topp,
+                    be.vocab)
+                flight.append((handle, _Launched(
+                    self._dispatch_gen, t0, ran, list(self._slots),
+                    drawn, filtered,
+                    int(self._pos[ran].sum()) if be.latent else 0,
+                    int(ran.sum()) if be.keeps_state else 0)))
+                del handle
+                self._left[ran] -= 1
+                self._pos[ran] += 1
+            # the first step after an idle stretch stays in flight: the
+            # pipeline fills; with nothing launched it drains
+            if len(flight) > 1 or not launching:
+                handle, step = flight[0]
+                _trace.set_cause(step.gen)
+                self._end_turn()
+                toks, counts = be.collect(handle)
+                flight.popleft()
+                # the step's array goes HERE, where no stream waits for
+                # the interpreter: let go after the emit has woken them,
+                # the release hands it to every one of them in the turn
+                # (1.98 ms a turn among 33 streams on the chip; PERF.md,
+                # PR 39)
+                del handle
+                self._dispatch_t0 = flight[0][1].t0 if flight else None
+        except BaseException as e:  # keep the worker alive, as ``_step``
+            _flight.record("decode_error", error=type(e).__name__,
+                           active=n_active)
+            self._drop_steps("decode_error", e)
+            return
+        now = time.monotonic()
+        if self._stall_tripped:
+            self._stall_tripped = False
+            if self._stall_gen == self._dispatch_gen:
+                # the watchdog failed the active requests while this
+                # pass hung (no launch since): see ``_step``
+                _flight.record("decode_stall_recovered", active=n_active)
+                self._drop_steps("decode_stall", DecodeStalledError(
+                    "decode step exceeded the watchdog limit"))
+                return
+        if step is None:
+            return
+        # what this step's tokens cost the loop: from the collect (or
+        # claim) before it, or from its launch after an idle stretch
+        dt = now - max(step.t0, self._step_ref)
+        self._step_ref = now
+        with _EMIT:
+            self._step_ewma_s = (dt if self._step_ewma_s is None
+                                 else 0.8 * self._step_ewma_s + 0.2 * dt)
+            if dt * 1e3 > self.stall_ms:
+                _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
+                               active=n_active)
+            pushed = 0
+            for slot in np.flatnonzero(step.ran):
+                req = step.slots[slot]
+                if self._slots[slot] is not req:
+                    continue  # stopped since the launch: a late slot-step
+                req.push_token(int(toks[slot]))
+                pushed += 1
+                if len(req.tokens) >= req.max_new:
+                    self._finish_slot(slot, reason="done")
+                elif req.expired(now) or req.done():
+                    self._finish_slot(
+                        slot, reason="deadline",
+                        error=RequestDeadlineExceeded(
+                            "request deadline passed mid-decode"))
+            self.metrics.record_decode_step(dt, pushed, step.drawn,
+                                            step.filtered)
+            self.metrics.record_moe_step(*counts)
+            self.metrics.record_latent_positions(step.latent_positions)
+            self.metrics.record_state_slots(step.state_slots)
+        self._turn_t0 = time.time_ns()
+
     def _learn(self, slot: int, tok: int) -> None:
         """Advance the slot's 2-token draft context and teach the n-gram
         table (ngram mode) each emitted token."""
@@ -2193,12 +2393,15 @@ class GenerationEngine:
         self._ctx[slot, 1] = tok
 
     def _loop(self) -> None:
+        step = self._step_ahead if self._ahead else self._step
         while True:
             with self._dev_lock:
                 self._admit(block_s=0.0)
-                any_active = self._active.any()
+                # a step in flight whose slots were all stopped is
+                # still to be collected
+                any_active = self._active.any() or bool(self._flight)
                 if any_active:
-                    self._step()
+                    step()
             self.metrics.set_active_slots(int(self._active.sum()))
             if not any_active:
                 self._turn_t0 = None  # an idle loop is no turn

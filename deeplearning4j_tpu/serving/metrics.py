@@ -334,6 +334,17 @@ class GenerationMetrics:
             "slots whose recurrent state a decode step advanced, summed "
             "over decode steps, where a layer keeps one (state-space "
             "layers): each is read whole and written whole a layer")
+        self._steps_ahead = reg.counter(
+            "generation_decode_steps_ahead_total",
+            "decode steps launched while the step before them was not "
+            "yet collected (a loop that keeps one step in flight; over "
+            "generation_decode_steps_total near 1 when busy, 0 on a "
+            "lock-step backend)")
+        self._late_slot_steps = reg.counter(
+            "generation_late_slot_steps_total",
+            "slot-steps the device ran for a slot whose request the "
+            "host had already ended (a stop seen one step late under a "
+            "step in flight); their tokens are never streamed")
         self._param_casts = reg.counter(
             "generation_param_casts_total",
             "times the transformer backend made its compute-dtype copy "
@@ -391,6 +402,12 @@ class GenerationMetrics:
     def record_state_slots(self, slots: int) -> None:
         if slots:
             self._state_slots.inc(int(slots))
+
+    def record_step_ahead(self) -> None:
+        self._steps_ahead.inc()
+
+    def record_late_slot_steps(self, n: int) -> None:
+        self._late_slot_steps.inc(int(n))
 
     def record_param_cast(self) -> None:
         self._param_casts.inc()
@@ -494,6 +511,8 @@ class GenerationMetrics:
             "moe_experts_hit": int(self._moe_hit.value()),
             "latent_positions_read": int(self._latent_positions.value()),
             "state_slots": int(self._state_slots.value()),
+            "decode_steps_ahead": int(self._steps_ahead.value()),
+            "late_slot_steps": int(self._late_slot_steps.value()),
             "param_casts": int(self._param_casts.value()),
             "latency_window": n,
         }

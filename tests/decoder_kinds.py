@@ -1,0 +1,46 @@
+"""Four tiny ``DecoderLM`` models, one a kind of layer the serving engine
+has a cache for: dense (full and window attention over a dense MLP),
+expert (the same attention over routed experts), latent (one latent
+cache, group-limited routing, a shared expert) and state-space (Mamba-2
+mixers around one attention layer). Built from the benchmark's rehearsal
+presets through their family modules, as the cells build theirs, with
+float32 parameters drawn by ``init_params``."""
+
+import importlib.util
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+
+KINDS = {"dense": ("decoder_lm", "tiny-mimo"),
+         "expert": ("decoder_lm", "tiny-mimo"),
+         "latent": ("latent_decoder_lm", "tiny-deepseek"),
+         "state-space": ("hybrid_decoder_lm", "tiny-granite")}
+
+
+def _family(name):
+    for p in (ROOT, BENCH):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    spec = importlib.util.spec_from_file_location(
+        f"decoder_kinds_{name}", os.path.join(BENCH, "families", f"{name}.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def decoder_lm(kind):
+    """An initialised float32 ``DecoderLM`` of ``kind`` (a key of
+    ``KINDS``), context 128."""
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
+
+    family, preset = KINDS[kind]
+    with open(os.path.join(BENCH, "configs", f"{preset}.json")) as f:
+        config = json.load(f)
+    config["deployment"]["param_dtype"] = "float32"
+    program = _family(family).program_config(config)
+    if kind == "dense":
+        program["layers"] = [(mixer, "dense") for mixer, _ in program["layers"]]
+    return DecoderLM.from_dict(program).init()
