@@ -4,8 +4,8 @@ server that answers requests — run on one TPU v5e through the entry
 points a user calls, at the full width of models the repo supports.
 
     python chip_smoke.py              one chip: device, lm_train, lm_serve,
-                                      hybrid_serve, resnet_train,
-                                      resnet_serve, kernels
+                                      hybrid_serve, sparse_serve,
+                                      resnet_train, resnet_serve, kernels
     python chip_smoke.py --chips 4    the cross-chip paths only: the
                                       DistributedLMTrainer on a 2x2 mesh and
                                       tensor-parallel serving on 1x4, each
@@ -77,6 +77,24 @@ FULL = {
         param_dtype="float32", embedding_multiplier=12,
         residual_multiplier=0.22, attention_multiplier=0.0625,
         logits_scaling=16, tied_head=True),
+    # latent attention over an indexer's selection at a middling size (a
+    # latent entry of 96 + 32 values = one tile of lanes, 4 indexer heads of
+    # 64 that keep 16 positions): a dense layer that owns the indexer, two
+    # expert layers that share its selection, an expert layer that owns one;
+    # float32 so that equal tokens mean something
+    "sparse": dict(
+        vocab_size=512, d_model=256, n_heads=4, head_dim=64, v_head_dim=32,
+        rotary_dim=32,
+        attn_kinds={
+            kind: {"rope_theta": 1e4, "latent": {"q_rank": 64, "kv_rank": 96},
+                   "index": {"heads": 4, "head_dim": 64, "topk": 16,
+                             "own": own}}
+            for kind, own in (("indexed", True), ("shared", False))},
+        layers=[("indexed", "dense"), ("shared", "experts"),
+                ("shared", "experts"), ("indexed", "experts")],
+        dense_width=512, expert_width=128, n_experts=8, top_k=2,
+        experts_held=(4, 4), shared_width=128, max_length=128,
+        routing={"scoring": "sigmoid", "scale": 2.5}, param_dtype="float32"),
 }
 TINY = {
     "lm": dict(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
@@ -96,6 +114,7 @@ TINY = {
                      dtype="float32"),
 }
 TINY["hybrid"] = FULL["hybrid"]
+TINY["sparse"] = FULL["sparse"]
 
 #: relative tolerance of one logit row against another: bf16 keeps 8
 #: bits of mantissa and a 12-block stack rounds the residual stream
@@ -449,6 +468,71 @@ def phase_hybrid_serve(size):
             "tokens_equal_generate_cached": True}
 
 
+def phase_sparse_serve(size):
+    """A decoder whose latent attention reads the positions an indexer
+    selects (``models/decoder_lm.py``), served by ``GenerationEngine``:
+    prompts past the indexer's top-k, so prefills select and every decode
+    step scores the key slab, keeps the exact top-k and gathers the chosen
+    rows, two layers by a selection another segment's layer made; every
+    request's tokens against the model's own cached generation on one slot
+    and against the greedy tokens of ONE forward over what was served (the
+    selection as a mask, no cache)."""
+    import numpy as np
+
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
+    from deeplearning4j_tpu.serving.generate import GenerationEngine
+
+    model = DecoderLM.from_dict(size["sparse"]).init()
+    topk = model.cfg.attn_kinds["indexed"]["index"]["topk"]
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, model.cfg.vocab_size, n)
+               for n in (5, 9, 20, 31, 40)]
+    max_new = 24
+    gen = GenerationEngine(model, n_slots=3, max_length=96,
+                           prefill_buckets=[8, 16, 32, 64])
+    try:
+        warm = gen.warmup()
+        traced = dict(gen.trace_counts)
+        mark = time.time_ns()
+        requests = [gen.submit(p, max_new=max_new) for p in prompts]
+        served = [np.asarray(r.result(timeout=900)) for r in requests]
+        check(gen.trace_counts == traced,
+              f"retraced after warm-up: {traced} -> {gen.trace_counts}")
+        snapshot = gen.metrics.snapshot()
+        ring = step_ids_in_ring(gen, mark,
+                                ahead=snapshot["decode_steps_ahead"])
+        plan = gen.describe()["memory"]["cache_plan"]
+    finally:
+        gen.shutdown()
+    agree = total = 0
+    for i, (prompt, got) in enumerate(zip(prompts, served)):
+        alone = model.generate_cached(prompt, max_new=max_new)
+        check(np.array_equal(got[-max_new:], alone[-max_new:]),
+              f"request {i}: engine {got[-max_new:].tolist()} != alone "
+              f"{alone[-max_new:].tolist()}")
+        greedy = model.logits(got[None, :-1])[0, len(prompt) - 1:].argmax(-1)
+        agree += int((greedy == got[-max_new:]).sum())
+        total += max_new
+    # a near-tie of two logits or of two indexer scores may fall the other
+    # way in the other form; more than a few is a fault
+    check(agree >= 0.9 * total,
+          f"forward's greedy tokens agree with {agree} of {total} served")
+    scored, read = (snapshot["index_positions_scored"],
+                    snapshot["sparse_positions_read"])
+    check(0 < read < scored, f"selection not active: {read} of {scored}")
+    check(snapshot["decode_steps_ahead"] > 0, "no step was launched ahead")
+    return {"requests": len(prompts), "slots": 3, "max_new": max_new,
+            "segments": model.cfg.segments(), "index_topk": topk,
+            "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
+            "cache_plan": [{k: p[k] for k in ("kind", "layers", "values",
+                                              "row", "bytes")} for p in plan],
+            "index_positions_scored": scored, "sparse_positions_read": read,
+            "decode_steps_ahead": snapshot["decode_steps_ahead"],
+            "late_slot_steps": snapshot["late_slot_steps"], "ring": ring,
+            "tokens_equal_generate_cached": True,
+            "tokens_equal_forward_greedy": [agree, total]}
+
+
 def phase_resnet_train(size):
     import numpy as np
 
@@ -727,6 +811,7 @@ def main(argv=None) -> int:
         run_phase("lm_serve", phase_lm_serve, meter, size, model)
         del model
         run_phase("hybrid_serve", phase_hybrid_serve, meter, size)
+        run_phase("sparse_serve", phase_sparse_serve, meter, size)
         run_phase("resnet_train", phase_resnet_train, meter, size)
         run_phase("resnet_serve", phase_resnet_serve, meter, size)
     run_phase("kernels", phase_kernels, meter, dev["platform"], size)

@@ -36,6 +36,16 @@ with or without a shared expert. Here all of that is configuration:
   never expands K or V) and prefills EXPANDED, by blocks of queries and
   keys under one running softmax (``_causal_blocked``): no program plans
   a score tensor of a whole bucket;
+- a latent kind with an INDEXER attends to a selection: a layer that
+  owns one scores every position behind a query with a few small heads
+  against ONE cached key a position (its second slab), keeps the
+  ``topk`` largest scores exactly (:func:`_select_mask`) and attends,
+  under the same softmax, to those positions alone; a layer that shares has no indexer and no key slab and
+  attends to the selection of the nearest owner before it, which
+  ``_run_stack`` carries from a layer to the next and from a segment to
+  the next. Decode gathers the chosen entries from a position-major slab
+  (a row an entry); prefill and the forward put the selection as a mask
+  on the blocked attention's scores;
 - expert layers route over every expert of the layer (the rule is the
   configuration's ``routing``) and compute the part of the result their
   held experts give, plus the shared expert where there is one
@@ -100,10 +110,19 @@ Array = jax.Array
 #: the two projections and the gated norm, bound by weights; ``ssm_conv``:
 #: the causal convolution and its tail; ``ssm_scan``: the recurrence, one
 #: step over the cached state in decode, the chunked form in prefill) and
-#: ``state_write``, a prefill's write of its slot's state and tail
+#: ``state_write``, a prefill's write of its slot's state and tail; a
+#: latent layer with an indexer has, inside ``attn_latent_proj``,
+#: ``attn_index_proj`` (the indexer's three projections, the key's norm,
+#: the rotations), ``attn_index_score`` (its heads' scores over the key
+#: cache, ReLU, the weighted sum over heads), ``attn_index_select`` (the
+#: exact top-k) and, owner or sharer, ``attn_sparse_core`` (the gather of
+#: the selected entries, the scores, softmax and weighted sum over them;
+#: in prefill the blocked attention under the selection's mask)
 SCOPES = ("attn_full", "attn_window", "attn_latent_proj", "attn_latent_core",
           "mlp", "moe_route", "moe_experts", "moe_shared",
-          "ssm_proj", "ssm_conv", "ssm_scan", "state_write")
+          "ssm_proj", "ssm_conv", "ssm_scan", "state_write",
+          "attn_index_proj", "attn_index_score", "attn_index_select",
+          "attn_sparse_core")
 _scope = jax.named_scope
 _NEG = -1e30
 #: queries and keys a block of a latent layer's prefill attention
@@ -137,6 +156,15 @@ class DecoderConfig:
     whose heads are ``head_dim`` = (``head_dim - rotary_dim`` without
     position | ``rotary_dim`` rotated) wide and share ONE rotary key, and
     whose cache entry is ``kv_rank + rotary_dim`` values a position. A
+    latent kind may state "index" = {"heads", "head_dim", "topk", "own"}:
+    its attention reads only the ``topk`` positions an indexer of
+    ``heads`` heads of ``head_dim`` picks for the query (the first
+    ``rotary_dim`` of an indexer head are rotated). ``own`` true: the
+    layer has the indexer's weights, caches ONE indexer key of
+    ``head_dim`` a position in a second slab beside the latent one, and
+    makes the selection; ``own`` false: it has neither and attends to the
+    selection of the nearest owning layer before it (there must be one).
+    Both keep their slabs position-major (:meth:`cache_plan`). A
     state-space kind is {"ssm": {"n_heads", "head_dim", "d_state",
     "n_groups", "d_conv", "expand", "chunk"}} (Mamba-2: ``expand x
     d_model`` = ``n_heads x head_dim`` inner channels, a state of
@@ -145,11 +173,12 @@ class DecoderConfig:
     ``chunk``); it keeps no columns and takes none of the attention keys.
     ``layers``: one (mixer kind, "dense" | "experts") pair a layer.
     ``experts_held`` = (offset, count): which of the ``n_experts`` the
-    router scores have their weights here. ``routing``: None (sigmoid
-    scores, a correction bias in the choice, weights renormalised) or
-    {"n_group", "topk_group", "renormalise", "scale"} (softmax scores,
-    group-limited: no bias). ``shared_width``: the shared expert's width
-    (0: none). ``vocab_size`` is what is held here (the chip's slice,
+    router scores have their weights here. ``routing``:
+    {"scoring": "sigmoid", "scale"} (sigmoid scores, a correction bias in
+    the choice, weights renormalised, then times ``scale``; None reads as
+    this rule with ``scale`` 1) or {"n_group", "topk_group", "renormalise", "scale"}
+    (softmax scores, group-limited: no bias). ``shared_width``: the shared
+    expert's width (0: none). ``vocab_size`` is what is held here (the chip's slice,
     where the vocabulary is sliced). ``rotary_dim`` 0: no positions.
     ``embedding_multiplier`` scales the embedded tokens,
     ``residual_multiplier`` every residual branch (mixer and FFN),
@@ -190,6 +219,11 @@ class DecoderConfig:
                    "latent": ({"q_rank": int(k["latent"]["q_rank"]),
                                "kv_rank": int(k["latent"]["kv_rank"])}
                               if k.get("latent") else None),
+                   "index": ({"heads": int(k["index"]["heads"]),
+                              "head_dim": int(k["index"]["head_dim"]),
+                              "topk": int(k["index"]["topk"]),
+                              "own": bool(k["index"]["own"])}
+                             if k.get("index") else None),
                    "ssm": ({f: int(k["ssm"][f]) for f in _SSM_FIELDS}
                            if k.get("ssm") else None)}
             for name, k in attn_kinds.items()}
@@ -197,6 +231,12 @@ class DecoderConfig:
             if k["latent"] and (k["window"] is not None or k["sink"]):
                 raise ValueError(f"latent kind {name!r} takes no window or "
                                  "sink")
+            if k["index"] and (not k["latent"] or k["index"]["topk"] < 1
+                               or self.rotary_dim > k["index"]["head_dim"]):
+                raise ValueError(
+                    f"kind {name!r}: an indexer goes with a latent kind, "
+                    "picks at least one position and has heads no "
+                    "narrower than rotary_dim")
             if k["ssm"]:
                 m = k["ssm"]
                 if (k["latent"] or k["window"] is not None or k["sink"]
@@ -208,11 +248,17 @@ class DecoderConfig:
                         "d_model, n_groups dividing n_heads, and none of "
                         "the attention keys")
         self.layers = [(str(a), str(f)) for a, f in layers]
+        owner = False
         for a, f in self.layers:
             if a not in self.attn_kinds or f not in ("dense", "experts"):
                 raise ValueError(f"unknown layer ({a!r}, {f!r})")
             if self.n_heads % self.attn_kinds[a]["n_kv_heads"]:
                 raise ValueError("n_heads must be a multiple of n_kv_heads")
+            index = self.attn_kinds[a]["index"]
+            owner = owner or bool(index and index["own"])
+            if index and not owner:
+                raise ValueError(f"layer kind {a!r} shares a selection and "
+                                 "no layer before it makes one")
         self.dense_width = int(dense_width)
         self.expert_width = int(expert_width)
         self.n_experts = int(n_experts)
@@ -228,15 +274,19 @@ class DecoderConfig:
             raise ValueError("param_dtype must be 'float32' or 'bfloat16'")
         self.param_dtype = param_dtype
         self.seed = int(seed)
-        self.routing = None if routing is None else {
-            "n_group": int(routing.get("n_group", 1)),
-            "topk_group": int(routing.get("topk_group", 1)),
-            "renormalise": bool(routing.get("renormalise", False)),
-            "scale": float(routing.get("scale", 1.0))}
-        if (self.routing is not None
-                and self.n_experts % self.routing["n_group"]):
-            raise ValueError("routing: n_group groups that divide "
-                             "n_experts, or None")
+        routing = routing or {"scoring": "sigmoid"}
+        if routing.get("scoring") == "sigmoid":
+            self.routing = {"scoring": "sigmoid",
+                            "scale": float(routing.get("scale", 1.0))}
+        else:
+            self.routing = {
+                "n_group": int(routing.get("n_group", 1)),
+                "topk_group": int(routing.get("topk_group", 1)),
+                "renormalise": bool(routing.get("renormalise", False)),
+                "scale": float(routing.get("scale", 1.0))}
+            if self.n_experts % self.routing["n_group"]:
+                raise ValueError("routing: n_group groups that divide "
+                                 "n_experts, or None")
         self.shared_width = int(shared_width)
         self.embedding_multiplier = float(embedding_multiplier)
         self.residual_multiplier = float(residual_multiplier)
@@ -252,6 +302,12 @@ class DecoderConfig:
     @property
     def dtype(self):
         return jnp.bfloat16 if self.param_dtype == "bfloat16" else jnp.float32
+
+    @property
+    def sigmoid_routing(self) -> bool:
+        """The router scores by sigmoid and has a correction bias
+        (``br``); otherwise by softmax, group-limited, without one."""
+        return self.routing.get("scoring") == "sigmoid"
 
     def segments(self) -> List[Tuple[str, str, int]]:
         """Runs of consecutive layers of one kind: (mixer kind, FFN
@@ -284,12 +340,21 @@ class DecoderConfig:
         compressed key/value entry and the one rotated key."""
         return self.attn_kinds[kind]["latent"]["kv_rank"] + self.rotary_dim
 
+    def latent_row(self, kind: str) -> int:
+        """Values a ROW of a position-major latent slab holds (a latent
+        kind with an indexer): ``latent_width`` rounded up to whole tiles
+        of 128 lanes, the tail zero. At 576 values a row the TPU compiler
+        copies the whole slab, padded, before every gather from it (528 MB
+        a layer and step at 32 slots x 14,336, by compile for a described
+        v5e, PR 40); at 640 the gather reads the rows where they lie."""
+        return -(-self.latent_width(kind) // 128) * 128
+
     def route(self):
         """The expert layers' routing rule, as ``moe_dropless_ffn`` takes
         it: (router outputs, bias, k) -> (chosen, weights)."""
-        if self.routing is None:
-            return sigmoid_topk_route
         r = self.routing
+        if self.sigmoid_routing:
+            return functools.partial(sigmoid_topk_route, scale=r["scale"])
         return functools.partial(
             group_limited_softmax_route, n_group=r["n_group"],
             topk_group=r["topk_group"], renormalise=r["renormalise"],
@@ -300,7 +365,21 @@ class DecoderConfig:
         shapes (layers, slots, kv heads, head size, columns) of K and V
         or, for a latent segment, ONE slab (layers, slots, kv_rank +
         rotary_dim, columns); ``values``: what a position and layer
-        keeps. A state-space segment keeps no columns: ``state`` (layers,
+        keeps. A latent segment with an indexer keeps its slabs
+        POSITION-MAJOR, (layers, slots, columns, row): its decode
+        gathers ``topk`` chosen positions a slot, and a chosen position
+        is then one row whose values lie together, where the T-minor
+        slab would put the gather on the minor axis, a value a lane
+        apart (PERF.md, PR 40: what the gather read on the chip). ``row``
+        is the kv_rank + rotary_dim values of the entry in whole tiles of
+        128 lanes (:meth:`latent_row`: 576 in 640, the tail zero). A
+        segment whose layers OWN the indexer has TWO slabs of different
+        widths, the latent one and the indexer's keys (rows of the
+        indexer's head size; ``index``: that width), and ``values``, what
+        the mathematics keeps a position and layer, is kv_rank +
+        rotary_dim + that; a segment whose layers share a selection has
+        the latent slab alone. ``bytes`` counts the rows as stored. A
+        state-space segment keeps no columns: ``state`` (layers,
         slots, state size, heads x head size) in float32 (a bfloat16
         state would round at every step of a recurrence thousands long)
         and ``conv`` (layers, slots, convolved channels, d_conv - 1), the
@@ -325,7 +404,16 @@ class DecoderConfig:
             cols = self.cache_columns(kind, max_length)
             entry = {"kind": kind, "layers": n, "columns": cols,
                      "ring": self.attn_kinds[kind]["window"] is not None}
-            if self.attn_kinds[kind]["latent"]:
+            index = self.attn_kinds[kind]["index"]
+            if index:
+                width = self.latent_width(kind)
+                entry["row"] = self.latent_row(kind)
+                slabs = [(n, int(n_slots), cols, entry["row"])]
+                if index["own"]:
+                    entry["index"] = index["head_dim"]
+                    slabs.append((n, int(n_slots), cols, index["head_dim"]))
+                    width += index["head_dim"]
+            elif self.attn_kinds[kind]["latent"]:
                 width = self.latent_width(kind)
                 slabs = [(n, int(n_slots), width, cols)]
             else:
@@ -352,7 +440,13 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
     (q_rank, heads, head size), and the key/value one in its two halves,
     ``Wuk`` (kv_rank, heads, head size - rotary_dim) and ``Wuv`` (kv_rank,
     heads, value size), which the absorbed decode contracts on opposite
-    sides and never together. A state-space kind's input projection is
+    sides and never together. A latent kind that OWNS an indexer adds its
+    four matrices: ``Iq`` (q_rank, indexer heads, indexer head size), the
+    indexer's queries from the query latent, by head; ``Ik`` (d, indexer
+    head size), its one key a position, with the key's LayerNorm
+    (``norm_ik`` gain and ``bias_ik``, float32); ``Iw`` (d, indexer
+    heads), the heads' weights. A kind that shares a selection has none
+    of them. A state-space kind's input projection is
     ONE leaf, ``Win`` (d, inner + convolved + heads) with its columns in
     the published order [z | xBC | dt]: one product reads the weights
     once, and its three parts are cut from the RESULT at offsets that are
@@ -382,6 +476,11 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
                     "norm_kv": ((kr,), f32),
                     "Wuk": ((kr, hq, cfg.head_dim - cfg.rotary_dim), pd),
                     "Wuv": ((kr, hq, cfg.v_head_dim), pd)})
+        if ak["index"] and ak["index"]["own"]:
+            ih, idim = ak["index"]["heads"], ak["index"]["head_dim"]
+            out.update({"Iq": ((qr, ih, idim), pd), "Ik": ((d, idim), pd),
+                        "norm_ik": ((idim,), f32), "bias_ik": ((idim,), f32),
+                        "Iw": ((d, ih), pd)})
     else:
         hkv = ak["n_kv_heads"]
         out.update({"Wq": ((d, hq, cfg.head_dim), pd),
@@ -397,7 +496,7 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
     else:
         held, f = cfg.experts_held[1], cfg.expert_width
         out["Wr"] = ((d, cfg.n_experts), f32)
-        if cfg.routing is None:
+        if cfg.sigmoid_routing:
             out["br"] = ((cfg.n_experts,), f32)
         out.update({"Eg": ((held, d, f), pd), "Eu": ((held, d, f), pd),
                     "Ed": ((held, f, d), pd)})
@@ -416,7 +515,10 @@ def init_params(cfg: DecoderConfig, rng: Optional[Array] = None) -> Dict:
     ``dt`` log-uniform in [1e-3, 1e-1] with ``dt_bias`` its inverse
     softplus, ``D`` 1)."""
     rng = rng if rng is not None else jax.random.PRNGKey(cfg.seed)
-    keys = iter(jax.random.split(rng, 16 * len(cfg.segments()) + 4))
+    # 16 a segment covers every kind but the one that owns an indexer
+    per = max([16] + [len(segment_shapes(cfg, kind, ffn))
+                      for kind, ffn, _n in cfg.segments()])
+    keys = iter(jax.random.split(rng, per * len(cfg.segments()) + 4))
     pd = cfg.dtype
 
     def normal(shape, dtype, std=0.02):
@@ -547,14 +649,19 @@ def _visible(q_pos, k_pos, window):
     return ok
 
 
-def _causal_blocked(q, k, v, scale: float, block: int, n_real=None):
+def _causal_blocked(q, k, v, scale: float, block: int, n_real=None,
+                    allowed=None):
     """Causal attention of q (b, T, h, dk) over k (b, T, h, dk) and
     v (b, T, h, dv) -> (b, T, h, dv), by blocks of ``block`` queries and
     ``block`` keys under one running softmax: the largest score tensor is
     (b, h, block, block) whatever T, and the key blocks above a query
     block's diagonal are not visited. With ``n_real`` (traced) the query
     blocks past the first ``n_real`` positions are not computed either
-    (a bucket's padding): their rows come back zero."""
+    (a bucket's padding): their rows come back zero. With ``allowed``
+    (b, T, T) bool a query sees, of the keys not after it, those alone
+    that its row allows (a selection; every row allows at least one, so
+    what a key block without any left in the running sums is wiped by
+    the first real score)."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     f32 = jnp.float32
@@ -562,6 +669,8 @@ def _causal_blocked(q, k, v, scale: float, block: int, n_real=None):
     if pad:  # padded keys lie after every real query: causality hides them
         q, k, v = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
                    for a in (q, k, v))
+        if allowed is not None:
+            allowed = jnp.pad(allowed, ((0, 0), (0, pad), (0, pad)))
     at = jnp.arange(block)
 
     def q_block(i, out):
@@ -575,6 +684,10 @@ def _causal_blocked(q, k, v, scale: float, block: int, n_real=None):
                            preferred_element_type=f32) * scale
             s = jnp.where((j * block + at)[None, :] <= (i * block + at)[:, None],
                           s, _NEG)
+            if allowed is not None:
+                s = jnp.where(jax.lax.dynamic_slice(
+                    allowed, (0, i * block, j * block),
+                    (b, block, block))[:, None], s, _NEG)
             m_new = jnp.maximum(m, s.max(-1))
             keep = jnp.exp(m - m_new)
             e = jnp.exp(s - m_new[..., None])
@@ -598,6 +711,46 @@ def _causal_blocked(q, k, v, scale: float, block: int, n_real=None):
     out = jax.lax.fori_loop(0, n_blocks, q_block,
                             jnp.zeros((b, t + pad, h, dv), q.dtype))
     return out[:, :t]
+
+
+def _latent_project(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
+                    x: Array, q_pos: Array):
+    """What every form of a latent layer's attention starts from, x
+    (b, Tq, d) at q_pos (b, Tq): (the normed input (b, Tq, d), the normed
+    query latent (b, Tq, q_rank), the heads' queries without position
+    (b, Tq, h, head - rotary) and rotated (b, Tq, h, rotary), the normed
+    key/value latent (b, Tq, kv_rank), the one rotated key (b, Tq,
+    rotary), the cache entry [latent | rotated key])."""
+    ak = cfg.attn_kinds[kind]
+    rot = cfg.rotary_dim
+    nope, kr = cfg.head_dim - rot, ak["latent"]["kv_rank"]
+    theta, scaling = ak["rope_theta"], ak["rope_scaling"]
+    dt = x.dtype
+    a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(dt)
+    c_q = _rms_norm(a_in @ bp["Wqa"], bp["norm_q"], cfg.norm_eps).astype(dt)
+    q = jnp.einsum("btr,rhk->bthk", c_q, bp["Wqb"])
+    # the rotated part of a head is its LAST rotary_dim columns
+    q_nope = q[..., :nope]
+    q_pe = _rotate(q[..., nope:], q_pos, rot, theta, scaling)
+    ckv = a_in @ bp["Wkva"]
+    c = _rms_norm(ckv[..., :kr], bp["norm_kv"], cfg.norm_eps).astype(dt)
+    k_pe = _rotate(ckv[:, :, None, kr:], q_pos, rot, theta, scaling)[:, :, 0]
+    new = jnp.concatenate([c, k_pe], axis=-1)            # (b, Tq, kr + rot)
+    return a_in, c_q, q_nope, q_pe, c, k_pe, new
+
+
+def _latent_expand(cfg: DecoderConfig, bp: Dict[str, Array], q_nope, q_pe,
+                   c, k_pe):
+    """The EXPANDED form's operands: every head's query (b, T, h, head),
+    key (the head's own part from the latent, the one rotated key
+    repeated) and value (b, T, h, value size)."""
+    b, tq = c.shape[:2]
+    k = jnp.concatenate(
+        [jnp.einsum("btc,chn->bthn", c, bp["Wuk"]),
+         jnp.broadcast_to(k_pe[:, :, None],
+                          (b, tq, cfg.n_heads, cfg.rotary_dim))], axis=-1)
+    v = jnp.einsum("btc,chv->bthv", c, bp["Wuv"])
+    return jnp.concatenate([q_nope, q_pe], axis=-1), k, v
 
 
 def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
@@ -628,29 +781,16 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
     ak = cfg.attn_kinds[kind]
     b, tq, _d = x.shape
     hq, rot, vd = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
-    nope, kr = cfg.head_dim - rot, ak["latent"]["kv_rank"]
-    theta, scaling = ak["rope_theta"], ak["rope_scaling"]
+    kr = ak["latent"]["kv_rank"]
     scale = softmax_scale(cfg, kind)
     f32, dt = jnp.float32, x.dtype
     with _scope("attn_latent_proj"):
-        a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(dt)
-        c_q = _rms_norm(a_in @ bp["Wqa"], bp["norm_q"], cfg.norm_eps).astype(dt)
-        q = jnp.einsum("btr,rhk->bthk", c_q, bp["Wqb"])
-        # the rotated part of a head is its LAST rotary_dim columns
-        q_nope = q[..., :nope]
-        q_pe = _rotate(q[..., nope:], q_pos, rot, theta, scaling)
-        ckv = a_in @ bp["Wkva"]
-        c = _rms_norm(ckv[..., :kr], bp["norm_kv"], cfg.norm_eps).astype(dt)
-        k_pe = _rotate(ckv[:, :, None, kr:], q_pos, rot, theta, scaling)[:, :, 0]
-        new = jnp.concatenate([c, k_pe], axis=-1)            # (b, Tq, kr + rot)
+        _a_in, _c_q, q_nope, q_pe, c, k_pe, new = _latent_project(
+            cfg, kind, bp, x, q_pos)
         if cache is None:
-            k = jnp.concatenate(
-                [jnp.einsum("btc,chn->bthn", c, bp["Wuk"]),
-                 jnp.broadcast_to(k_pe[:, :, None], (b, tq, hq, rot))], axis=-1)
-            v = jnp.einsum("btc,chv->bthv", c, bp["Wuv"])
+            q, k, v = _latent_expand(cfg, bp, q_nope, q_pe, c, k_pe)
             with _scope("attn_latent_core"):
-                o = _causal_blocked(jnp.concatenate([q_nope, q_pe], axis=-1),
-                                    k, v, scale, PREFILL_BLOCK, n_real)
+                o = _causal_blocked(q, k, v, scale, PREFILL_BLOCK, n_real)
         else:
             q_lat = jnp.concatenate(
                 [jnp.einsum("bqhn,chn->bqhc", q_nope, bp["Wuk"]), q_pe], axis=-1)
@@ -685,6 +825,258 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
             o = o * cfg.value_scale
         x = _residual(cfg, x, o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"])
     return x, new
+
+
+def _layer_norm(x, g, bias, eps):
+    """LayerNorm over the last axis with float32 statistics; returns
+    float32 (the caller casts)."""
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, -1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), -1, keepdims=True)
+    return (xf - mean) * jax.lax.rsqrt(var + eps) * g + bias
+
+
+def _index_scores(q_i, w_i, k_i):
+    """The indexer's scores of queries over keys, float32: q_i (b, Tq, h,
+    d) the indexer heads' queries, w_i (b, Tq, h) float32 their weights
+    (the two scale factors in them), k_i (b, Tk, d) ONE key a position ->
+    (b, Tq, Tk): ``sum_h w_h ReLU(q_h . k)``. The products accumulate in
+    float32 and the ReLU, the weights and the sum over heads are float32
+    elementwise (a float32 matrix product would round its operands on
+    the chip)."""
+    s = jnp.einsum("bqhd,bkd->bqhk", q_i, k_i,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(s) * w_i[..., None], axis=2)
+
+
+def _select_mask(scores, k: int):
+    """The EXACT top-k of each row as a mask: scores (..., n) float32 with
+    -inf where a position cannot be chosen -> bool (..., n), true at the
+    ``k`` largest, ties to the lower position, and at every choosable one
+    where there are fewer than ``k``. No sort: the k-th largest value is
+    found by bisection on the scores' bits (float32 read as an unsigned
+    integer that orders as the numbers do), 32 passes that compare and
+    count, and the ties at that value are counted off from the left
+    (the set a stable descending sort's first ``k`` are)."""
+    n = scores.shape[-1]
+    valid = scores > -jnp.inf
+    if n <= k:
+        return valid
+    # -0 orders as +0 does
+    bits = jax.lax.bitcast_convert_type(jnp.where(scores == 0, 0.0, scores),
+                                        jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    u = jnp.where(bits >= top, ~bits, bits | top)
+
+    def one_bit(i, thr):
+        cand = thr | jnp.left_shift(jnp.uint32(1), (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(u >= cand[..., None], axis=-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, one_bit,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = u > thr[..., None]
+    ties = u == thr[..., None]
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    first = jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room[..., None]
+    return (above | (ties & first)) & valid
+
+
+def _select_indices(scores, own, lengths, k: int):
+    """A decode step's selection: scores (b, Tc) float32 of the cached
+    positions (-inf from a row's ``lengths`` on), ``own`` (b,) the score of
+    the step's own position, which lies outside the slab -> (the columns
+    of the ``k`` best cached positions (b, k), best first; how many of
+    them are in the selection (b,); whether the own position is (b,)).
+    The selection is the ``min(k, lengths + 1)`` largest of cached and own
+    together, ties to the lower position, the set ``_select_mask`` gives
+    with the own score at column ``lengths``: the own position, the
+    highest, is in where there is room for all or where it beats the k-th
+    best cached one outright, and then takes that one's place. By
+    ``lax.top_k`` (exact, equal values lower index first): on the chip a
+    sort of 32 rows of 14,336 takes 0.47 ms where the bisection and a
+    compaction of its mask into columns took 1.13 (PERF.md, PR 40)."""
+    # -0 orders as +0 does, as in ``_select_mask``
+    scores, own = (jnp.where(a == 0, 0.0, a) for a in (scores, own))
+    vals, idx = jax.lax.top_k(scores, k)
+    own_in = (lengths < k) | (own > vals[:, -1])
+    n_sel = jnp.where(own_in, jnp.minimum(lengths, k - 1), k)
+    return idx.astype(jnp.int32), n_sel.astype(jnp.int32), own_in
+
+
+def _index_mask(q_i, w_i, k_i, topk: int, block: int, n_real=None):
+    """The selection of every query of a sequence as a mask (b, T, T)
+    bool: row t true at the ``min(topk, t + 1)`` positions not after t
+    whose indexer scores are largest. By blocks of ``block`` queries: a
+    block's scores over the key blocks up to its own (``_index_scores``),
+    then the exact select over the row (``_select_mask``); the largest
+    tensor beside the mask is (b, block, heads, block) float32. With
+    ``n_real`` the query blocks past it are not visited: rows all
+    false."""
+    b, t = q_i.shape[:2]
+    pad = -t % block
+    if pad:
+        q_i, w_i, k_i = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q_i, w_i, k_i))
+    tp = t + pad
+    at = jnp.arange(block)
+
+    def q_block(i, allowed):
+        qi, wi = (jax.lax.dynamic_slice_in_dim(a, i * block, block, axis=1)
+                  for a in (q_i, w_i))
+
+        def k_block(j, row):
+            kj = jax.lax.dynamic_slice_in_dim(k_i, j * block, block, axis=1)
+            return jax.lax.dynamic_update_slice_in_dim(
+                row, _index_scores(qi, wi, kj), j * block, axis=2)
+
+        with _scope("attn_index_score"):
+            row = jax.lax.fori_loop(
+                0, i + 1, k_block, jnp.zeros((b, block, tp), jnp.float32))
+            seen = jnp.arange(tp)[None, :] <= (i * block + at)[:, None]
+            row = jnp.where(seen, row, -jnp.inf)
+        with _scope("attn_index_select"):
+            chosen = _select_mask(row, topk)
+        return jax.lax.dynamic_update_slice_in_dim(allowed, chosen, i * block,
+                                                   axis=1)
+
+    n_blocks = tp // block
+    if n_real is not None:
+        n_blocks = jnp.minimum(n_blocks, (n_real + block - 1) // block)
+    allowed = jax.lax.fori_loop(0, n_blocks, q_block,
+                                jnp.zeros((b, tp, tp), bool))
+    return allowed[:, :t, :t] if pad else allowed
+
+
+def _no_selection(cfg: DecoderConfig, kind: str, b: int, tq: int, columns):
+    """A selection's shapes with nothing in them: what a segment of layers
+    that own an indexer starts its scan's carry from (each layer puts its
+    own in its place). ``columns``: the slab's, in decode; None without a
+    cache, where there is a selection only past ``topk`` positions."""
+    topk = cfg.attn_kinds[kind]["index"]["topk"]
+    if columns is None:
+        return jnp.zeros((b, tq, tq), bool) if tq > topk else None
+    return (jnp.zeros((b, min(topk, columns)), jnp.int32),
+            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+
+
+def _sparse_latent_attention(cfg: DecoderConfig, kind: str,
+                             bp: Dict[str, Array], x: Array, q_pos: Array,
+                             cache=None, sel=None, n_real=None):
+    """A latent layer's attention over a SELECTION of positions, x
+    (b, Tq, d): returns (x + its output, what the layer caches of the
+    step's positions, the selection it attended by).
+
+    The block's attention is ``_latent_attention``'s, expanded without a
+    cache and absorbed over one, with the softmax over the selected
+    positions only. A layer that OWNS the indexer makes the selection: the
+    indexer heads' queries from the query latent (``Iq``, rotated on their
+    first ``rotary_dim``), ONE key a position (``Ik``, LayerNorm, rotated
+    alike; cached), the heads' weights (``Iw``, times heads^-1/2 x
+    head_dim^-1/2), the score ``sum_h w_h ReLU(q_h . k)`` in float32 of
+    every position not after the query, and the ``topk`` largest, exactly.
+    It returns (latent entries (b, Tq, row) (``latent_row``: the kv_rank +
+    rotary_dim values and a zero tail), key entries (b, Tq, indexer head
+    size)). A layer that SHARES has none of that: it
+    attends by ``sel``, the selection the nearest owner before it made for
+    the same tokens, and returns the latent entries alone.
+
+    Without a cache (forward, prefill) a selection is a mask (b, Tq, Tq)
+    on the blocked attention's scores (``_index_mask``,
+    ``_causal_blocked``), or None where Tq <= topk: every query then
+    attends to all before it, the dense latent layer. With ``cache`` =
+    (the segment's slabs, position-major: (latents (layers, b, Tc, row),
+    and an owner's keys (layers, b, Tc, indexer head
+    size)), layer, lengths (b,)) and Tq = 1 it is (columns (b, K), how
+    many of them count (b,), whether the step's own position is in (b,))
+    (``_select_indices``; K = min(topk, Tc)): the own entry lies outside
+    the slab, so it is scored beside the cached ones and a sharer is told
+    whether it was chosen. The chosen rows are gathered from the slab as
+    it lies, K rows a slot, and the absorbed scores, the softmax and the
+    weighted sum of latents run over them and the own entry."""
+    ak = cfg.attn_kinds[kind]
+    index = ak["index"]
+    b, tq, _d = x.shape
+    hq, rot, vd = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
+    kr = ak["latent"]["kv_rank"]
+    theta, scaling = ak["rope_theta"], ak["rope_scaling"]
+    scale = softmax_scale(cfg, kind)
+    f32, dt = jnp.float32, x.dtype
+    with _scope("attn_latent_proj"):
+        a_in, c_q, q_nope, q_pe, c, k_pe, new = _latent_project(
+            cfg, kind, bp, x, q_pos)
+        # the entry as a row of the slab: whole tiles, the tail zero
+        tail = cfg.latent_row(kind) - new.shape[-1]
+        new = jnp.pad(new, ((0, 0), (0, 0), (0, tail)))
+        made = (new,)
+        if index["own"]:
+            with _scope("attn_index_proj"):
+                q_i = _rotate(jnp.einsum("btr,rhk->bthk", c_q, bp["Iq"]),
+                              q_pos, rot, theta, scaling)
+                k_i = _layer_norm(a_in @ bp["Ik"], bp["norm_ik"],
+                                  bp["bias_ik"], cfg.norm_eps)
+                k_i = _rotate(k_i[:, :, None], q_pos, rot, theta,
+                              scaling)[:, :, 0].astype(dt)
+                w_i = jnp.matmul(
+                    a_in, bp["Iw"], preferred_element_type=f32) * (
+                        index["heads"] ** -0.5 * index["head_dim"] ** -0.5)
+            made = (new, k_i)
+        selects = cache is not None or tq > index["topk"]
+        if selects and not index["own"] and sel is None:
+            raise ValueError(f"kind {kind!r} shares a selection and was "
+                             "handed none")
+        if cache is None:
+            if not selects:
+                sel = None
+            elif index["own"]:
+                sel = _index_mask(q_i, w_i, k_i, index["topk"], PREFILL_BLOCK,
+                                  n_real)
+            q, k, v = _latent_expand(cfg, bp, q_nope, q_pe, c, k_pe)
+            with _scope("attn_sparse_core"):
+                o = _causal_blocked(q, k, v, scale, PREFILL_BLOCK, n_real, sel)
+        else:
+            slabs, layer, lengths = cache
+            q_lat = jnp.concatenate(
+                [jnp.einsum("bqhn,chn->bqhc", q_nope, bp["Wuk"]), q_pe,
+                 jnp.zeros((b, tq, hq, tail), dt)], axis=-1)[:, 0]
+            if index["own"]:
+                columns = slabs[1].shape[2]
+                with _scope("attn_index_score"):
+                    keys = jax.lax.dynamic_index_in_dim(slabs[1], layer, 0,
+                                                        keepdims=False)
+                    s_i = _index_scores(q_i, w_i, keys)[:, 0]
+                    s_i = jnp.where(
+                        jnp.arange(columns)[None, :] < lengths[:, None], s_i,
+                        -jnp.inf)
+                    s_own = _index_scores(q_i, w_i, k_i)[:, 0, 0]
+                with _scope("attn_index_select"):
+                    sel = _select_indices(s_i, s_own, lengths,
+                                          min(index["topk"], columns))
+            idx, n_sel, own_in = sel
+            with _scope("attn_sparse_core"):
+                rows = slabs[0].at[
+                    layer, jnp.arange(b)[:, None], idx].get(
+                        mode="promise_in_bounds")             # (b, K, width)
+                s_c = jnp.einsum("bhc,bkc->bhk", q_lat, rows,
+                                 preferred_element_type=f32) * scale
+                counts = jnp.arange(idx.shape[1])[None, :] < n_sel[:, None]
+                s_c = jnp.where(counts[:, None], s_c, _NEG)
+                s_own = jnp.einsum("bhc,bc->bh", q_lat, new[:, 0],
+                                   preferred_element_type=f32) * scale
+                s_own = jnp.where(own_in[:, None], s_own, _NEG)
+                m = jnp.maximum(s_c.max(-1), s_own)
+                e_c, e_own = jnp.exp(s_c - m[..., None]), jnp.exp(s_own - m)
+                lat = (jnp.einsum("bhk,bkc->bhc", e_c.astype(dt), rows,
+                                  preferred_element_type=f32)
+                       + e_own[..., None] * new[:, 0, None].astype(f32))
+                z = (e_c.sum(-1) + e_own)[..., None]
+                lat = (lat[..., :kr] / z).astype(dt)[:, None]
+            o = jnp.einsum("bqhc,chv->bqhv", lat, bp["Wuv"])
+        if cfg.value_scale != 1.0:
+            o = o * cfg.value_scale
+        x = _residual(cfg, x, o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"])
+    return x, made, sel
 
 
 def _ssm_chunked(x, dt, a, bmat, cmat, chunk: int, n_real=None):
@@ -911,7 +1303,8 @@ def _ssm_kernel_admits(cfg: DecoderConfig, kind: str, states: Array) -> bool:
 
 
 def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
-          x: Array, q_pos: Array, cache=None, token_mask=None, layer=None):
+          x: Array, q_pos: Array, cache=None, token_mask=None, layer=None,
+          sel=None):
     """One layer on x (b, Tq, d) at absolute positions q_pos (b, Tq);
     bp holds ONE layer's leaves. The queries attend, under one softmax,
     to the layer's own Tq keys and, if ``cache`` = (kc (b, hkv, hd, Tc),
@@ -923,7 +1316,12 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     cache is (slab (b, kv_rank + rotary_dim, Tc), c_pos), or (the
     segment's slabs, layer, lengths) for the decode kernel, and what it
     returns in their place is ((b, Tq, kv_rank + rotary_dim) entries,)
-    (:func:`_latent_attention`). A state-space kind attends to nothing:
+    (:func:`_latent_attention`). A latent kind with an indexer takes
+    ``cache`` = (the segment's position-major slabs, layer, lengths) and
+    ``sel``, the selection of the nearest owning layer before it (an owner
+    makes its own), and returns in their place ((latent entries, and an
+    owner's indexer keys), the selection it attended by)
+    (:func:`_sparse_latent_attention`). A state-space kind attends to nothing:
     its cache is (the segment's states, its tails, layer), or those and
     the live slots' table for the decode kernel, WRITTEN here at
     ``layer``, and it returns what it keeps in their place
@@ -940,6 +1338,11 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     if ak["latent"]:
         n_real = (None if token_mask is None or cache is not None
                   else jnp.max(jnp.sum(token_mask, axis=-1)))
+        if ak["index"]:
+            x, entries, sel = _sparse_latent_attention(
+                cfg, kind, bp, x, q_pos, cache, sel, n_real)
+            x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
+            return x, (entries, sel), counts
         x, entries = _latent_attention(cfg, kind, bp, x, q_pos, cache, n_real)
         x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
         return x, (entries,), counts
@@ -1040,13 +1443,21 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     whole in the cache's stead (stacked as a scan's output they would be a
     second copy of the state); a decode step where the kernel registry
     admits it also hands each layer the table of the rows that are
-    active. Returns (x, per segment what the layers
+    active. A segment of latent layers with an indexer reads its
+    position-major slabs whole, by the layer's index (the decode gathers
+    rows of them), and the SELECTION goes through its scan as a carry and
+    on to the next segment: a layer that owns the indexer puts its own in
+    the carry's place, a layer that shares reads what the last owner left,
+    be it a layer or a segment back. Returns (x, per segment what the layers
     made to cache, (k, v) stacks (layers, b, hkv, Tq, hd) or (entries
     (layers, b, Tq, width),) or, of a state-space segment, (states
     (layers, b, state size, heads x head size), tails (layers, b, channels,
-    d_conv - 1)), summed expert counters)."""
+    d_conv - 1)), summed expert counters); of a segment with an indexer
+    over a cache, ((entries, and an owner's keys), its slabs as the loop
+    hands them on)."""
     new_kv = []
     pairs = hit = jnp.zeros((), jnp.int32)
+    sel = None
     for i, (kind, ffn, n) in enumerate(cfg.segments()):
         seg = params["segments"][i]
         # the expert stacks are not sliced by the scan: the grouped
@@ -1054,6 +1465,34 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
         stacks = {k: seg[k] for k in EXPERT_STACKS if k in seg}
         scanned = {k: v for k, v in seg.items() if k not in stacks}
         kv = None if caches is None else caches[i]
+        index = cfg.attn_kinds[kind]["index"]
+        if index:
+            lengths = None if kv is None else q_pos[:, 0]
+            if index["own"]:
+                sel = _no_selection(cfg, kind, x.shape[0], x.shape[1],
+                                    None if kv is None else kv[0].shape[2])
+
+            # the slabs go through the scan as a carry that no layer
+            # changes, and the after-loop write takes them from its end:
+            # closed over, the loop's copy of them and the donated buffer
+            # the write updates were two, a slab-sized copy a step (by
+            # compile, PR 40)
+            def chosen(carry, xs, kind=kind, ffn=ffn, stacks=stacks,
+                       lengths=lengths):
+                x, sel, kv = carry
+                bp, layer = xs
+                x, (knew, sel), counts = block(
+                    cfg, kind, ffn, {**bp, **stacks}, x, q_pos,
+                    None if kv is None else (kv, layer, lengths), token_mask,
+                    layer if stacks else None, sel)
+                return (x, sel, kv), (knew, counts)
+
+            (x, sel, kv), (knew, counts) = jax.lax.scan(
+                chosen, (x, sel, kv),
+                (scanned, jnp.arange(n, dtype=jnp.int32)))
+            new_kv.append(knew if kv is None else (knew, kv))
+            pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
+            continue
         if kv is not None and cfg.attn_kinds[kind]["ssm"]:
             table = ()
             if x.shape[1] == 1 and _ssm_kernel_admits(cfg, kind, kv[0]):
@@ -1141,7 +1580,9 @@ def forward(cfg: DecoderConfig, params: Dict, ids: Array):
 # -- the cache ----------------------------------------------------------------
 def init_cache(cfg: DecoderConfig, n_slots: int, max_length: int):
     """Zeroed slabs a segment, by the cache plan: (K, V), a latent
-    segment's one, or a state-space segment's (states in float32, tails)."""
+    segment's one (with an indexer: position-major, and the keys' beside
+    it where the layers own it), or a state-space segment's (states in
+    float32, tails)."""
     return [tuple(jnp.zeros(shape, dtype)
                   for shape, dtype in zip(p["slabs"], p["dtypes"]))
             for p in cfg.cache_plan(n_slots, max_length)]
@@ -1181,6 +1622,17 @@ def _put_ring(ring, new, pos):
     return jnp.where(here, new.transpose(0, 1, 2, 4, 3), ring)
 
 
+def _put_rows(slab, new, wp):
+    """The after-loop write of a position-major slab: new (L, b, 1,
+    width), row s's entry -> slab[:, s, wp[s, 0], :], each as ONE
+    ``dynamic_update_slice`` on the (donated) slab, in place
+    (``_put_columns``'s reasons)."""
+    for s in range(new.shape[1]):
+        slab = jax.lax.dynamic_update_slice(slab, new[:, s:s + 1],
+                                            (0, s, wp[s, 0], 0))
+    return slab
+
+
 def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
                 pos: Array, active: Optional[Array] = None):
     """One token a row: ids_1 (b,) at per-row positions pos (b,) ->
@@ -1188,13 +1640,17 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
     read inside the layer loop and written after it: a full layer's slab
     (a latent layer's too) by one in-place column a row at ``pos``
     (``_put_columns``), a ring by one select at ``pos mod window``
-    (``_put_ring``). A state-space segment's states and tails were
+    (``_put_ring``), a position-major slab of a latent layer with an
+    indexer (its latents and, where it owns the indexer, its keys) by one
+    in-place row a slot (``_put_rows``). A state-space segment's states
+    and tails were
     written inside the loop, in place, and come back as they are.
     ``active`` (b,) bool keeps idle rows out of the expert layers (and of
     their counters) and leaves their state and tail bit for bit alone."""
     t_max = max([p[0].shape[-1] for (kind, _f, _n), p
                  in zip(cfg.segments(), caches)
-                 if not cfg.attn_kinds[kind]["ssm"]], default=0)
+                 if not cfg.attn_kinds[kind]["ssm"]
+                 and not cfg.attn_kinds[kind]["index"]], default=0)
     q_pos = pos.astype(jnp.int32)[:, None]
     x, new_kv, counts = _run_stack(
         cfg, params, _embed(cfg, params, ids_1[:, None]), q_pos, caches,
@@ -1205,6 +1661,12 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
         for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
             if cfg.attn_kinds[kind]["ssm"]:
                 out.append(tuple(new))
+                continue
+            if cfg.attn_kinds[kind]["index"]:
+                new, slabs = new  # the slabs as the layer loop hands them on
+                wp = jnp.minimum(q_pos, slabs[0].shape[2] - 1)
+                out.append(tuple(_put_rows(c, n, wp)
+                                 for c, n in zip(slabs, new)))
                 continue
             wp = jnp.minimum(q_pos, slabs[0].shape[-1] - 1)
             if cfg.attn_kinds[kind]["latent"]:
@@ -1224,7 +1686,8 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
                  length: Array, slot: Array):
     """One prompt, right-padded to a bucket: ids (1, Tb), ``length`` real
     tokens, into row ``slot`` of every slab, from ONE pass. A full layer
-    (or a latent one) gets the bucket's columns at 0..Tb-1; a ring gets, in column c, the
+    (or a latent one, with its indexer's keys where it has them) gets the
+    bucket's columns at 0..Tb-1; a ring gets, in column c, the
     latest real position congruent to c, i.e. the prompt's last
     ``window`` columns when it is longer than the window. Padding follows
     the real tokens, so causal attention keeps it from them, and the
@@ -1247,9 +1710,15 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
                     for c, n in zip(slabs, new)))
             continue
         with _scope("kv_write"):
-            cols = slabs[0].shape[-1]
+            position_major = bool(cfg.attn_kinds[kind]["index"])
+            cols = slabs[0].shape[2 if position_major else -1]
             if cfg.attn_kinds[kind]["window"] is None and tb > cols:
                 raise ValueError("prefill bucket longer than the slot")
+            if position_major:  # (L, 1, Tb, width) entries, as they lie
+                out.append(tuple(
+                    jax.lax.dynamic_update_slice(c, n, (0, slot, 0, 0))
+                    for c, n in zip(slabs, new)))
+                continue
             if cfg.attn_kinds[kind]["latent"]:
                 out.append((jax.lax.dynamic_update_slice(
                     slabs[0], new[0].transpose(0, 1, 3, 2), (0, slot, 0, 0)),))

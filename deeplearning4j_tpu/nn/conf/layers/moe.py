@@ -150,16 +150,19 @@ def _moe_ffn(params, x2, act_fn, capacity: int, top_k: int, valid=None,
     return y, aux, load
 
 
-def sigmoid_topk_route(z, bias, top_k: int):
+def sigmoid_topk_route(z, bias, top_k: int, scale: float = 1.0):
     """Sigmoid-scored routing over every expert of the layer: z (N, E)
     float32 router outputs -> (chosen (N, k) expert ids, weights (N, k)
     float32). The k experts are the largest of ``sigmoid(z) + bias``
     (the correction bias enters the CHOICE only); the weights are the
-    chosen experts' plain scores, renormalised to sum to one."""
+    chosen experts' plain scores, renormalised to sum to one, and
+    ``scale`` (a published ``routed_scaling_factor``) multiplies them
+    AFTER the renormalisation: they then sum to ``scale``."""
     s = jax.nn.sigmoid(z.astype(jnp.float32))
     _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, chosen, axis=-1)
-    return chosen.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), w if scale == 1.0 else w * scale
 
 
 def group_limited_softmax_route(z, bias, top_k: int, n_group: int,

@@ -812,9 +812,14 @@ class _DecoderBackend:
     a window layer's (L, S, hkv, hd, window) ring, K and V of their own
     head sizes; a latent layer's ONE (L, S, kv_rank + rotary_dim, T)
     slab, which its decode reads absorbed (no key or value of a head is
-    made over it). Decode reads them and writes one column a slot in
-    place after the layer loop; prefill writes a slot's columns of every
-    slab from one pass, whole (no chunked prefill: it holds the decoding
+    made over it); a latent layer with an indexer keeps its slabs
+    position-major, (L, S, T, 640) rows of latent entries that its decode
+    GATHERS by the indexer's selection and, where the layer owns the
+    indexer, (L, S, T, indexer head size) rows of indexer keys beside
+    them, which every step scores whole; a layer that shares a selection
+    owns the latent slab alone. Decode reads them and writes one column
+    (or row) a slot in place after the layer loop; prefill writes a
+    slot's columns of every slab from one pass, whole (no chunked prefill: it holds the decoding
     slots for its length). Speculation stays at K = 1 (an expert model
     routes per step), and there is no prefix cache: a ring holds a
     prompt's last ``window`` columns only, so a captured prefix could
@@ -872,6 +877,12 @@ class _DecoderBackend:
         #: some layer keeps a latent cache: the engine counts the
         #: positions a launched step's slots have behind them
         self.latent = any(k["latent"] for k in cfg.attn_kinds.values())
+        #: the positions a latent layer's indexer keeps for its attention
+        #: (0: no layer selects): the engine counts the positions a
+        #: launched step's slots score and those they select
+        self.index_topk = max([k["index"]["topk"]
+                               for k in cfg.attn_kinds.values()
+                               if k["index"]], default=0)
         #: some layer keeps a recurrent state: no prefix cache, K = 1;
         #: the engine counts the slots a launched step advances
         self.keeps_state = any(k["ssm"] for k in cfg.attn_kinds.values())
@@ -1346,7 +1357,10 @@ def generation_memory_report(model, n_slots: int,
             # sized by layer kind: the slot's length for a full layer,
             # a ring of ``window`` columns for a window layer, K and V
             # by head; one entry of kv_rank + rotary_dim values a
-            # position for a latent layer; a state and a convolution
+            # position for a latent layer (with an indexer: a row of
+            # whole lane tiles a position and, where the layer owns the
+            # indexer, one indexer key beside it; a layer that shares
+            # a selection owns no key slab); a state and a convolution
             # tail a slot, whatever T, for a state-space layer
             plan = cfg.cache_plan(n_slots, T)
             cache = sum(p["bytes"] for p in plan)
@@ -1379,7 +1393,8 @@ def generation_memory_report(model, n_slots: int,
     if plan is not None:
         out["cache_plan"] = [
             {k: p[k] for k in ("kind", "layers", "columns", "ring", "values",
-                               "bytes", "state", "conv") if k in p}
+                               "row", "index", "bytes", "state", "conv")
+             if k in p}
             for p in plan]
         # the recurrent state (and its tails) apart from the slabs of
         # columns: the first does not grow with max_length
@@ -1414,6 +1429,8 @@ class _Launched(NamedTuple):
     drawn: bool             #: the sampler's branches, as ``_step`` counts
     filtered: bool
     latent_positions: int   #: what ``record_latent_positions`` takes
+    selected_positions: int  #: ``record_selection``'s second (the first
+    #: is ``latent_positions``: an indexer scores every position behind)
     state_slots: int        #: what ``record_state_slots`` takes
 
 
@@ -2317,6 +2334,8 @@ class GenerationEngine:
                     self._dispatch_gen, t0, ran, list(self._slots),
                     drawn, filtered,
                     int(self._pos[ran].sum()) if be.latent else 0,
+                    int(np.minimum(self._pos[ran], be.index_topk).sum())
+                    if be.index_topk else 0,
                     int(ran.sum()) if be.keeps_state else 0)))
                 del handle
                 self._left[ran] -= 1
@@ -2381,6 +2400,9 @@ class GenerationEngine:
                                             step.filtered)
             self.metrics.record_moe_step(*counts)
             self.metrics.record_latent_positions(step.latent_positions)
+            if be.index_topk:
+                self.metrics.record_selection(step.latent_positions,
+                                              step.selected_positions)
             self.metrics.record_state_slots(step.state_slots)
         self._turn_t0 = time.time_ns()
 

@@ -329,6 +329,16 @@ class GenerationMetrics:
             "generation_latent_positions_read_total",
             "cache positions the active slots had behind them, summed "
             "over decode steps, where a layer keeps a latent cache")
+        self._index_scored = reg.counter(
+            "generation_index_positions_scored_total",
+            "cache positions the active slots had behind them, summed "
+            "over decode steps, where a layer's indexer scores them all "
+            "to select the ones its attention reads")
+        self._sparse_read = reg.counter(
+            "generation_sparse_positions_read_total",
+            "cache positions selected for the active slots (each slot's "
+            "positions behind it, at most the indexer's top-k), summed "
+            "over decode steps: what the attention over a selection reads")
         self._state_slots = reg.counter(
             "generation_state_slots_total",
             "slots whose recurrent state a decode step advanced, summed "
@@ -398,6 +408,13 @@ class GenerationMetrics:
     def record_latent_positions(self, positions: int) -> None:
         if positions:
             self._latent_positions.inc(int(positions))
+
+    def record_selection(self, scored: int, read: int) -> None:
+        """A decode step of a model whose attention reads a selection:
+        the positions its indexer scored and those it selected."""
+        if scored:
+            self._index_scored.inc(int(scored))
+            self._sparse_read.inc(int(read))
 
     def record_state_slots(self, slots: int) -> None:
         if slots:
@@ -510,6 +527,8 @@ class GenerationMetrics:
             "moe_pairs_local": int(self._moe_pairs.value()),
             "moe_experts_hit": int(self._moe_hit.value()),
             "latent_positions_read": int(self._latent_positions.value()),
+            "index_positions_scored": int(self._index_scored.value()),
+            "sparse_positions_read": int(self._sparse_read.value()),
             "state_slots": int(self._state_slots.value()),
             "decode_steps_ahead": int(self._steps_ahead.value()),
             "late_slot_steps": int(self._late_slot_steps.value()),

@@ -1,8 +1,10 @@
-"""Four tiny ``DecoderLM`` models, one a kind of layer the serving engine
+"""Five tiny ``DecoderLM`` models, one a kind of layer the serving engine
 has a cache for: dense (full and window attention over a dense MLP),
 expert (the same attention over routed experts), latent (one latent
-cache, group-limited routing, a shared expert) and state-space (Mamba-2
-mixers around one attention layer). Built from the benchmark's rehearsal
+cache, group-limited routing, a shared expert), sparse-latent (latent
+attention over an indexer's selection: a key slab beside the latent one,
+layers that share a selection) and state-space (Mamba-2 mixers around one
+attention layer). Built from the benchmark's rehearsal
 presets through their family modules, as the cells build theirs, with
 float32 parameters drawn by ``init_params``."""
 
@@ -17,6 +19,7 @@ BENCH = os.path.join(ROOT, "benchmark")
 KINDS = {"dense": ("decoder_lm", "tiny-mimo"),
          "expert": ("decoder_lm", "tiny-mimo"),
          "latent": ("latent_decoder_lm", "tiny-deepseek"),
+         "sparse-latent": ("sparse_latent_decoder_lm", "tiny-glm"),
          "state-space": ("hybrid_decoder_lm", "tiny-granite")}
 
 

@@ -461,6 +461,104 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip,
     assert prefill.memory_analysis().temp_size_in_bytes < 3.0e9
 
 
+def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
+                                                                    capsys):
+    """``DecoderLM`` with latent attention over an indexer's selection as
+    the engine builds its programs, at the glm-5.2-ep16 cell's widths and
+    its whole cut (hidden 6144, 64 heads of 192 + 64 / 256 over a 2048-wide
+    query and a 512 + 64-wide key/value latent, 32 indexer heads of 128
+    that keep 2,048 positions, 16 of 256 sigmoid-routed experts of 2048
+    with a shared expert; a dense layer that owns the indexer, three expert
+    layers that share its selection, an expert layer that owns one; 32
+    slots x 14,336, bfloat16). What a CPU run cannot show: the decode
+    program gathers the chosen rows from the position-major slabs WHERE
+    THEY LIE (at rows of 576 values the compiler copied a whole slab,
+    padded to 640, before every gather: 528 MB a layer and step; at 640
+    there is none, and the slabs that go through the three-layer scan as
+    its carry are not copied for the after-loop write either), plans no
+    float32 score tensor of a whole slab, and the prefill at the longest
+    bucket attends and selects by blocks, so its plan stays under the
+    4.8 GB that weights and cache leave. The plans are printed."""
+    import re
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.models.decoder_lm import (
+        DecoderConfig,
+        init_cache,
+        init_params,
+    )
+    from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    S, T = 32, 14336
+    latent = {"q_rank": 2048, "kv_rank": 512}
+    cfg = DecoderConfig(
+        vocab_size=19360, d_model=6144, n_heads=64, head_dim=256,
+        v_head_dim=256, rotary_dim=64,
+        attn_kinds={
+            kind: {"rope_theta": 8e6, "latent": latent,
+                   "index": {"heads": 32, "head_dim": 128, "topk": 2048,
+                             "own": own}}
+            for kind, own in (("indexed", True), ("shared", False))},
+        layers=[("indexed", "dense")] + [("shared", "experts")] * 3
+        + [("indexed", "experts")],
+        dense_width=12288, expert_width=2048, n_experts=256, top_k=8,
+        experts_held=(0, 16), norm_eps=1e-5, max_length=T,
+        routing={"scoring": "sigmoid", "scale": 2.5}, shared_width=2048)
+    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
+                         lambda name: None)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = described(jax.eval_shape(lambda: init_params(cfg)))
+    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    assert [tuple(c.shape for c in seg) for seg in caches] == [
+        ((1, S, T, 640), (1, S, T, 128)), ((3, S, T, 640),),
+        ((1, S, T, 640), (1, S, T, 128))]
+    keys = math.prod(caches[0][1].shape)        # the smallest slab: 59 M values
+
+    def slab_copies(text):
+        # a cache slab has four dimensions and slots second; what is left
+        # under that size are three re-laid weights a layer (``Wqb``,
+        # ``Wuv``, ``Iq``: 100 MB a layer and step, PERF.md section 7)
+        return [(name, dims) for name, dims in re.findall(
+            r"%(\S+) = bf16\[([\d,]+)\]\S* copy\(", text)
+            if math.prod(map(int, dims.split(","))) >= keys // 2
+            and dims.split(",")[1:2] == [str(S)]]
+
+    decode = be._decode_fn.lower(
+        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    text = decode.as_text()
+    assert "ragged-dot" in text and not slab_copies(text), slab_copies(text)
+    gathers = [line for line in text.splitlines()
+               if " gather(" in line and "attn_sparse_core" in line]
+    assert gathers and all(f"bf16[{S},2048,640]" in g for g in gathers), gathers
+    # 31 MB read: the indexer's scores of a slot's whole key slab, 32 heads
+    # in float32, would be 58.7 MB a layer; they are fused into the sum over
+    # heads and never planned
+    plan = decode.memory_analysis()
+    assert plan.temp_size_in_bytes < 200e6
+    prefill = be._prefill_fn.lower(
+        params, caches, arg((S + 1, 8), jnp.int32),
+        arg((8 + T,), jnp.int32)).compile()
+    assert not slab_copies(prefill.as_text())
+    longest = prefill.memory_analysis()
+    # 2.65 GB read: the expanded keys and values of 14,336 positions (0.47
+    # GB each), the selection's mask (0.21 GB) and the blocks' scores
+    assert longest.temp_size_in_bytes < 3.3e9
+    with capsys.disabled():
+        for name, m in (("decode", plan), ("prefill@14336", longest)):
+            print(f"\nglm-5.2-ep16 {name}: arguments "
+                  f"{m.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+                  f"{m.temp_size_in_bytes / 1e9:.3f} GB, code "
+                  f"{m.generated_code_size_in_bytes / 1e6:.1f} MB")
+
+
 def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
                                                              monkeypatch):
     """``DecoderLM`` with state-space layers as the engine builds its
