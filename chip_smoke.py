@@ -400,7 +400,9 @@ def phase_lm_serve(size, model):
         check(not retraces, f"retraced after warm-up: {retraces}")
         status, raw = _http(srv.port, "GET", "/healthz")
         check(status == 200, f"/healthz: HTTP {status} {raw[:300]}")
-        ring = step_ids_in_ring(gen, mark)
+        # K = 1 and no prefix cache: the loop keeps a step in flight
+        ring = step_ids_in_ring(
+            gen, mark, ahead=gen.metrics.snapshot()["decode_steps_ahead"])
     finally:
         srv.generation = None
         srv.shutdown()

@@ -91,9 +91,12 @@ from deeplearning4j_tpu.serving.metrics import GenerationMetrics
 # encloses the claims made in it; the others follow one another, so the
 # self times sum to a loop iteration. Every entry carries its decode
 # step's id as its cause: a claim's, and the turn's, the id of the step
-# they precede. Lock-step a step's put, dispatch, fetch and emit follow
-# one another; where a step is kept in flight (``_step_ahead``) the put
-# and dispatch of step t+1 come before the fetch and emit of step t.
+# they precede. Lock-step (a speculating or prefix-cached
+# ``TransformerLM`` engine, a recurrent net's) a step's put, dispatch,
+# fetch and emit follow one another; where a step is kept in flight
+# (``_step_ahead``: every ``DecoderLM`` engine, and a ``TransformerLM``'s
+# with K = 1 and no prefix cache) the put and dispatch of step t+1 come
+# before the fetch and emit of step t.
 _ADMIT = _trace.phase("gen.admit")
 _PREFILL = _trace.phase("gen.prefill")
 _PREFILL_PUT = _trace.phase("gen.prefill.put")
@@ -427,12 +430,39 @@ def _counted(temperature, active):
     return jnp.where(active, temperature, 0.0)
 
 
+def _prefill_columns(cfg, p, kc, vc, ids, ln, slot):
+    """A TransformerLM prompt's (1, bucket) ids through ``prefill_cache``
+    and its K and V written into ``slot``'s first columns of the slabs;
+    returns (last-position logits (1, V), the slabs, the prompt's own
+    cache). Only the bucket's columns are written: what a slot holds
+    past them is never read before decode overwrites it."""
+    from deeplearning4j_tpu.models.transformer_lm import (
+        init_decode_cache,
+        prefill_cache,
+    )
+
+    tmp = init_decode_cache(cfg, 1, max_length=ids.shape[1])
+    logits, tmp = prefill_cache(cfg, p, tmp, ids, length=ln)
+    with jax.named_scope("kv_write"):
+        kc = jax.lax.dynamic_update_slice(kc, tmp["k"], (0, slot, 0, 0, 0))
+        vc = jax.lax.dynamic_update_slice(vc, tmp["v"], (0, slot, 0, 0, 0))
+    return logits, kc, vc, tmp
+
+
 class _TransformerBackend:
     """TransformerLM decode backend: fixed (L, S, hn, hd, T) KV slab
     (time minor: ``init_decode_cache``), per-slot positions, per-bucket
     prefill programs. Decode and verify read the slab and write their
     new columns in place after the layer loop; prefill and the prefix
-    cache move whole blocks of columns with one slice a slab."""
+    cache move whole blocks of columns with one slice a slab.
+
+    This class keeps the slots' inputs on the HOST and hands them to a
+    step as seven small arrays (``decode``), which is what speculation
+    (``verify`` / ``draft`` read and edit the tokens between two steps)
+    and the prefix cache (``prefix_restore``, the completion replay)
+    work on: the engine runs it lock-step. An engine with neither gets
+    ``_TransformerAheadBackend``, below, whose programs
+    ``_build_programs`` replaces."""
 
     kind = "transformer"
 
@@ -441,13 +471,7 @@ class _TransformerBackend:
                  spec_k: int = 1, draft_layers: int = 0,
                  on_param_cast: Callable[[], None] = lambda: None):
         from deeplearning4j_tpu.models.transformer_lm import (
-            decode_step,
-            decode_steps,
-            init_decode_cache,
             prefill_bucket_lengths,
-            prefill_cache,
-            sample_next_device,
-            sample_next_rows,
         )
 
         self.model = model
@@ -487,6 +511,20 @@ class _TransformerBackend:
         #: and pre-warmed by GenerationEngine.warmup
         self._cap_fns: Dict[int, Callable] = {}
         self._res_fns: Dict[int, Callable] = {}
+        self._build_programs(trace_hook)
+
+    def _build_programs(self, trace_hook) -> None:
+        """The jitted programs over slots' inputs that live on the HOST:
+        seven small arrays a decode step."""
+        from deeplearning4j_tpu.models.transformer_lm import (
+            decode_step,
+            decode_steps,
+            prefill_cache,
+            sample_next_device,
+            sample_next_rows,
+        )
+
+        cfg = self._cfg
 
         def _decode(p, kc, vc, toks, pos, active, t, k, pp, keys):
             trace_hook("generation_decode")
@@ -506,15 +544,8 @@ class _TransformerBackend:
 
         def _prefill(p, kc, vc, dkc, dvc, ids, ln, slot, t, k, pp, key):
             trace_hook("generation_prefill")
-            # only the bucket's columns are written: what a slot holds
-            # past them is never read before decode overwrites it
-            tmp = init_decode_cache(cfg, 1, max_length=ids.shape[1])
-            logits, tmp = prefill_cache(cfg, p, tmp, ids, length=ln)
-            with jax.named_scope("kv_write"):
-                kc = jax.lax.dynamic_update_slice(kc, tmp["k"],
-                                                  (0, slot, 0, 0, 0))
-                vc = jax.lax.dynamic_update_slice(vc, tmp["v"],
-                                                  (0, slot, 0, 0, 0))
+            logits, kc, vc, tmp = _prefill_columns(cfg, p, kc, vc, ids, ln,
+                                                   slot)
             if Ld:
                 # the truncated draft model prefills its own (shallower)
                 # slab from the same prompt
@@ -632,7 +663,7 @@ class _TransformerBackend:
         program the first time it is asked for (warm-up) and kept
         beside the master leaves it was made from. Re-made when, and
         only when, the leaves of ``model.params_`` are not those objects
-        any more: a swap of ``params_`` is served at the next token for
+        any more: a swap of ``params_`` is served at the next dispatch for
         one cast, no recompile, and a step on unchanged weights compares
         ~16 identities. Under ``compute_dtype=None`` the copy is the
         tree itself and nothing is counted."""
@@ -841,9 +872,11 @@ class _DecoderBackend:
     back before step t's copy of it is fetched, so the device goes from
     one decode program to the next without waiting ~2-3 ms for the
     tokens' way to the host and the launch's way back (PERF.md, PRs 37
-    and 39). The backends that keep the slots' inputs on the host
-    (``_TransformerBackend``, ``_RecurrentBackend``) cannot launch ahead
-    and stay lock-step."""
+    and 39). ``_TransformerAheadBackend`` does the same for a
+    ``TransformerLM`` that neither speculates nor keeps a prefix cache
+    (PR 41); the backends that keep the slots' inputs on the host
+    (``_TransformerBackend`` for K > 1 or a prefix cache,
+    ``_RecurrentBackend``) cannot launch ahead and stay lock-step."""
 
     kind = "decoder"
     spec_k = 1
@@ -1048,6 +1081,153 @@ class _DecoderBackend:
         with _DECODE_FETCH:
             rows = np.asarray(state)
             return rows[:-1, 0], (int(rows[-1, 0]), int(rows[-1, 1]))
+
+
+class _TransformerAheadBackend(_TransformerBackend):
+    """``_TransformerBackend`` for an engine that neither speculates nor
+    keeps a prefix cache: the slots' inputs live on the device as the
+    ONE array ``_DecoderBackend._state`` lays out (its last row, the
+    step's counters, stays zero: a TransformerLM counts none), the step
+    is a ``launch`` and a ``collect`` as ``_DecoderBackend``'s are, and
+    the engine, which sees the pair, keeps one step in flight
+    (``GenerationEngine._step_ahead``). Same slab, same ``decode_step``
+    and sampler and key chain over the same serving copy of the weights,
+    so the tokens are the lock-step backend's bit for bit; a step costs
+    one fetch and no put (seven puts took 2.8 ms of a 19.8 ms step on
+    the chip and the fetch-then-launch order another 2.2: PERF.md,
+    PRs 37 and 41).
+
+    A speculating engine reads and edits the host's copy of the tokens
+    between two steps (``verify`` / ``draft``), and a prefix cache
+    restores a slot from the host (``prefix_restore``, the completion
+    replay): both stay on ``_TransformerBackend`` and the lock-step
+    loop (``_pick_backend``)."""
+
+    latent = False
+    index_topk = 0
+    keeps_state = False
+
+    def _build_programs(self, trace_hook) -> None:
+        """The jitted programs over slots' inputs that live on the
+        DEVICE: one array in, one array out."""
+        from deeplearning4j_tpu.models.transformer_lm import (
+            decode_step,
+            sample_next_device,
+            sample_next_rows,
+        )
+
+        cfg = self._cfg
+
+        def _f32(bits):
+            return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+        def _decode(p, kc, vc, state):
+            trace_hook("generation_decode")
+            rows = state[:-1]
+            toks, pos, k = rows[:, 0], rows[:, 1], rows[:, 3]
+            left = rows[:, 2]
+            active = left > 0
+            keys = jax.lax.bitcast_convert_type(rows[:, 4:6], jnp.uint32)
+            logits, c = decode_step(cfg, p, {"k": kc, "v": vc, "pos": pos},
+                                    toks)
+            nxt, nkeys = sample_next_rows(
+                logits, _counted(_f32(rows[:, 6]), active), k,
+                _f32(rows[:, 7]), keys)
+            nxt = jnp.where(active, nxt, toks)
+            nkeys = jnp.where(active[:, None], nkeys, keys)
+            after = jnp.concatenate(
+                [nxt[:, None], (pos + active)[:, None],
+                 (left - active)[:, None], k[:, None],
+                 jax.lax.bitcast_convert_type(nkeys, jnp.int32),
+                 rows[:, 6:8]], axis=1)
+            return c["k"], c["v"], jnp.concatenate([after, state[-1:]])
+
+        def _prefill(p, kc, vc, state, req):
+            # req: the request's row as ``_state`` lays it (its token is
+            # still to come: the word holds the slot), then the prompt
+            # padded to its bucket
+            trace_hook("generation_prefill")
+            slot, ln, left, k = req[0], req[1], req[2], req[3]
+            key = jax.lax.bitcast_convert_type(req[4:6], jnp.uint32)
+            logits, kc, vc, _ = _prefill_columns(cfg, p, kc, vc,
+                                                 req[None, 8:], ln, slot)
+            tok0, key = sample_next_device(logits, _f32(req[6]), k,
+                                           _f32(req[7]), key)
+            row = jnp.concatenate(
+                [tok0, ln[None], left[None], k[None],
+                 jax.lax.bitcast_convert_type(key, jnp.int32), req[6:8]])
+            return kc, vc, state.at[slot].set(row), row
+
+        def _stop(state, stopped):
+            # the host's edit of the rows it stopped itself: no step left
+            return state.at[:, 2].multiply(1 - stopped)
+
+        self._decode_fn = jax.jit(_decode, donate_argnums=(1, 2))
+        self._prefill_fn = jax.jit(_prefill, donate_argnums=(1, 2))
+        self._stop_fn = jax.jit(_stop)
+
+    def reset(self) -> None:
+        super().reset()
+        #: what the next launch takes, on the device: the last launch's
+        #: or prefill's output, maybe still to be computed
+        self._slots_state = jnp.zeros((self.n_slots + 1, 8), jnp.int32)
+        #: slots the host stopped since the last launch (``stop``)
+        self._stopped: set = set()
+
+    def release(self) -> None:
+        super().release()
+        self._slots_state = None
+
+    _state = staticmethod(_DecoderBackend._state)
+    stop = _DecoderBackend.stop
+    collect = staticmethod(_DecoderBackend.collect)
+
+    def prefill(self, slot: int, prompt: np.ndarray, temperature: float,
+                top_k: int, top_p: float, key: np.ndarray, steps: int = 0):
+        """As ``_TransformerBackend.prefill`` (an expert model's prompt
+        is not bucketed), with ``steps``, the decode steps the slot runs
+        after its first token, written into the slot's row on the
+        device; no logits come back (there is no prefix cache to keep
+        them). One put and one fetch a claim. Dispatched behind the step
+        in flight, whose slabs and state it takes as they will be; the
+        fetch of its row waits for both."""
+        tp = int(prompt.shape[0])
+        tb = tp if self._cfg.n_experts > 0 else self.bucket_for(tp)
+        with _PREFILL_PUT:
+            req = np.zeros((8 + tb,), np.int32)
+            self._state(req[:8], slot, tp, steps, temperature, top_k, top_p,
+                        key)
+            req[8:8 + tp] = prompt
+            req = jnp.asarray(req)
+        # the prefill writes the whole row: the host's stop of the slot's
+        # last occupant has nothing left to edit
+        self._stopped.discard(slot)
+        self._kc, self._vc, self._slots_state, row = self._prefill_fn(
+            self._params(), self._kc, self._vc, self._slots_state, req)
+        del req
+        row = np.asarray(row)
+        return int(row[0]), row[4:6].view(np.uint32), tb, None
+
+    def launch(self):
+        """Dispatch one decode step for all slots from the state on the
+        device and return what ``collect`` takes: the step's own output
+        array, which the NEXT launch reads too (it is not donated; the
+        slabs are). The weights are read at each launch: a swap of
+        ``model.params_`` is served for one cast and no recompile from
+        the next LAUNCH on, which with a step in flight is one step
+        later than the lock-step loop would serve it."""
+        with _DECODE_PUT:
+            state = self._slots_state
+            if self._stopped:
+                stopped = np.zeros((self.n_slots + 1,), np.int32)
+                stopped[list(self._stopped)] = 1
+                self._stopped.clear()
+                state = self._stop_fn(state, jnp.asarray(stopped))
+        with _DECODE_DISPATCH:
+            self._kc, self._vc, state = self._decode_fn(
+                self._params(), self._kc, self._vc, state)
+        self._slots_state = state
+        return state
 
 
 def _cell_decode_supported(model) -> bool:
@@ -1294,11 +1474,19 @@ class _RecurrentBackend:
 
 def _pick_backend(model, n_slots, max_length, prefill_buckets, trace_hook,
                   on_param_cast, cell_path: Optional[bool] = None,
-                  spec_k: int = 1, draft_layers: int = 0):
+                  spec_k: int = 1, draft_layers: int = 0,
+                  prefix_cache: bool = False):
     from deeplearning4j_tpu.models.decoder_lm import DecoderLM
     from deeplearning4j_tpu.models.transformer_lm import TransformerLM
 
     if isinstance(model, TransformerLM):
+        if spec_k == 1 and not prefix_cache:
+            # nothing reads or edits the host's copy of the slots'
+            # inputs between two steps: they live on the device and the
+            # loop launches ahead
+            return _TransformerAheadBackend(model, n_slots, max_length,
+                                            prefill_buckets, trace_hook,
+                                            on_param_cast=on_param_cast)
         return _TransformerBackend(model, n_slots, max_length,
                                    prefill_buckets, trace_hook,
                                    spec_k=spec_k,
@@ -1441,7 +1629,8 @@ class GenerationEngine:
     ``_dev_lock``); callers only touch the bounded admission queue and
     their own :class:`GenerationRequest`. Hot params reload composes:
     every dispatch looks at ``model.params_``, so an atomic params swap
-    (same shapes) takes effect at the next token, zero recompiles. A
+    (same shapes) takes effect at the next dispatch (the next token, or
+    with a step in flight the one after it), zero recompiles. A
     ``TransformerLM`` under a compute dtype pays one cast program a
     swap, not one a dispatch: its programs read a copy of the block
     matrices and the head in that dtype, re-made when the leaves of
@@ -1449,10 +1638,14 @@ class GenerationEngine:
     ``param_casts`` in the metrics counts them).
 
     The loop runs one of two orders, by what the backend offers. Where
-    it keeps the slots' inputs on the host (``decode``) the loop is
-    lock-step: put, dispatch, fetch, emit, turn. Where they live on the
-    device and the step comes as ``launch`` / ``collect``
-    (``_DecoderBackend``) the loop keeps one step in flight
+    it keeps the slots' inputs on the host (``decode``:
+    ``_TransformerBackend``, which an engine built with
+    ``spec_decode_k`` > 1 or a prefix cache gets, and
+    ``_RecurrentBackend``) the loop is lock-step: put, dispatch, fetch,
+    emit, turn. Where they live on the device and the step comes as
+    ``launch`` / ``collect`` (``_DecoderBackend``, and
+    ``_TransformerAheadBackend`` for a ``TransformerLM`` with K = 1 and
+    no prefix cache) the loop keeps one step in flight
     (``_step_ahead``): launch t+1, fetch and emit t, turn, launch t+2.
     The tokens are the same; the device no longer waits for the host
     between two steps, and a stop that only the host can decide
@@ -1545,6 +1738,7 @@ class GenerationEngine:
             draft_layers = max(
                 getattr(getattr(model, "cfg", None), "n_layers", 0) // 2,
                 0)
+        prefix_cache = bool(prefix_cache_mb and float(prefix_cache_mb) > 0)
         #: None → auto (env ``DL4J_TPU_LSTM_DECODE_CELL``, else on for
         #: supported recurrent stacks); False forces the legacy
         #: ``_forward``-over-T=1 decode program (the bench's reference
@@ -1554,7 +1748,8 @@ class GenerationEngine:
                                      self.metrics.record_param_cast,
                                      cell_path=decode_cell_path,
                                      spec_k=int(spec_decode_k),
-                                     draft_layers=draft_layers)
+                                     draft_layers=draft_layers,
+                                     prefix_cache=prefix_cache)
         self.n_slots = self.backend.n_slots
         self.max_length = self.backend.max_length
         #: effective speculation width: the backend may pin K=1 (MoE,
@@ -1568,16 +1763,15 @@ class GenerationEngine:
                        else None)
         #: per-slot (t[-2], t[-1]) context feeding the n-gram draft
         self._ctx = np.zeros((self.n_slots, 2), np.int64)
-        if (prefix_cache_mb and float(prefix_cache_mb) > 0
-                and getattr(self.backend, "keeps_state", False)):
+        if prefix_cache and getattr(self.backend, "keeps_state", False):
             raise RecurrentStateError(
                 f"the {self.backend.kind} backend has no prefix cache for a "
                 "model with state-space layers: a prefix's recurrent state "
                 "is not a run of columns that could be copied under a longer "
                 "prompt's own, and no snapshot of it is kept; set "
                 "prefix_cache_mb=0")
-        if (prefix_cache_mb and float(prefix_cache_mb) > 0
-                and not getattr(self.backend, "supports_prefix_cache", True)):
+        if prefix_cache and not getattr(self.backend,
+                                        "supports_prefix_cache", True):
             raise ValueError(
                 f"the {self.backend.kind} backend has no prefix cache (a "
                 "window layer's ring keeps a prompt's last columns only, "
@@ -1585,7 +1779,7 @@ class GenerationEngine:
         self._prefix_cache = (
             PrefixCache(int(float(prefix_cache_mb) * (1 << 20)),
                         self.metrics)
-            if prefix_cache_mb and float(prefix_cache_mb) > 0 else None)
+            if prefix_cache else None)
         #: per-slot completion replay: a prefix-cache entry remembers
         #: the prompt's first greedy completion, and later hits replay
         #: it as the slot's draft source (the exact verify rule keeps

@@ -314,6 +314,11 @@ def sharded_generation_engine(model, mesh: ServingMesh,
         else:
             be._dkc = jax.device_put(be._kc[:0], mesh.replicated())
             be._dvc = jax.device_put(be._vc[:0], mesh.replicated())
+        if hasattr(be, "_slots_state"):
+            # the slots' inputs of a backend that launches ahead: one
+            # small array, whole on every device
+            be._slots_state = jax.device_put(be._slots_state,
+                                             mesh.replicated())
 
     orig_reset = be.reset
 

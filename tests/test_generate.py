@@ -49,9 +49,8 @@ def _release_compiled_programs():
     test file in the same worker reads (``test_obs.py``'s bounded ring)."""
     yield
     for shared in (_ENG, _SPEC, _BF16):
-        engine = shared.pop("e", None)
-        if engine is not None:
-            engine.shutdown(drain=False)
+        while shared:
+            shared.popitem()[1].shutdown(drain=False)
     gc.collect()
     jax.clear_caches()
 
@@ -437,9 +436,25 @@ class TestGenerationEngine:
         eng = _engine()
         prompt = _prompts(1, seed=9)[0]
         max_new = 48 - len(prompt)  # fill the window: a long decode
-        req = eng.submit(prompt, max_new=max_new, timeout=0.02)
-        with pytest.raises(RequestDeadlineExceeded):
-            req.result(timeout=90)
+        # the deadline passes at the third launch: with a step in flight
+        # this model's 38 tokens take some 12 ms, so no wall-clock
+        # deadline lies surely between the claim and the last token
+        real, calls = eng.backend.launch, {"n": 0}
+
+        def launch():
+            calls["n"] += 1
+            if calls["n"] == 3:
+                req.deadline = 0.0
+            return real()
+
+        eng.backend.launch = launch
+        try:
+            with eng._dev_lock:
+                req = eng.submit(prompt, max_new=max_new, timeout=90)
+            with pytest.raises(RequestDeadlineExceeded):
+                req.result(timeout=90)
+        finally:
+            del eng.backend.launch  # the class's own again
         assert 0 < len(req.tokens) < max_new  # died mid-decode, not queued
         deadline = time.monotonic() + 10
         while eng.active_slots and time.monotonic() < deadline:
@@ -465,7 +480,7 @@ class TestGenerationEngine:
                                default_timeout_s=60.0)
         try:
             eng.warmup()
-            real = eng.backend.decode
+            real = eng.backend.launch
             boom = {"armed": True}
 
             def exploding(*a, **kw):
@@ -474,7 +489,7 @@ class TestGenerationEngine:
                     raise RuntimeError("injected decode failure")
                 return real(*a, **kw)
 
-            eng.backend.decode = exploding
+            eng.backend.launch = exploding
             prompt = _prompts(1, seed=41)[0]
             with pytest.raises(RuntimeError, match="injected"):
                 eng.submit(prompt, max_new=8, timeout=60).result(timeout=60)
@@ -500,7 +515,7 @@ class TestGenerationEngine:
                                watchdog_mult=2.0, watchdog_min_s=0.3)
         try:
             eng.warmup()
-            real = eng.backend.decode
+            real = eng.backend.launch
             hang = {"armed": True}
 
             def hung(*a, **kw):
@@ -509,7 +524,7 @@ class TestGenerationEngine:
                     time.sleep(1.5)  # well past the watchdog limit
                 return real(*a, **kw)
 
-            eng.backend.decode = hung
+            eng.backend.launch = hung
             prompt = _prompts(1, seed=51)[0]
             t0 = time.monotonic()
             with pytest.raises(DecodeStalledError, match="stuck"):
@@ -627,7 +642,10 @@ class TestGenerationEngine:
 # host phases of the worker loop, queue wait, named scopes
 # ---------------------------------------------------------------------------
 class TestEnginePhases:
-    """obs/trace.py phases inside GenerationEngine._loop: they nest or
+    """obs/trace.py phases inside GenerationEngine._loop in its lock-step
+    order (``_step``; here an engine with a prefix cache, which keeps a
+    ``TransformerLM``'s slots' inputs on the host; the order with a step
+    in flight is ``tests/test_generate_ahead.py``'s): they nest or
     follow one another, cover a loop iteration, stay within the budget a
     decode iteration is given, carry their decode step's id as their
     cause, and cost no retrace."""
@@ -642,7 +660,8 @@ class TestEnginePhases:
         lm = TransformerLM(vocab_size=256, d_model=768, n_heads=4,
                            n_layers=6, max_length=64, seed=3).init()
         eng = GenerationEngine(lm, n_slots=2, queue_limit=16,
-                               default_timeout_s=120.0)
+                               default_timeout_s=120.0, prefix_cache_mb=4.0)
+        assert not eng._ahead
         eng.warmup()
         traced = dict(eng.trace_counts)
         mark = time.time_ns()
@@ -831,24 +850,41 @@ class TestEnginePhases:
             speculated += names == one + one + ["gen.emit"]
         assert speculated >= 1
 
-    def test_scope_names_in_the_engine_programs(self, storm):
+    @pytest.mark.parametrize("inputs", ["on_the_host", "on_the_device"])
+    def test_scope_names_in_the_engine_programs(self, storm, inputs):
         import jax.numpy as jnp
 
-        eng = storm[0]
-        b = eng.backend
-        S = eng.n_slots
-        small = (jnp.zeros((S,), jnp.float32), jnp.zeros((S,), jnp.int32),
-                 jnp.zeros((S,), jnp.float32))
-        decode = b._decode_fn.lower(
-            b.model.params_, b._kc, b._vc, jnp.zeros((S,), jnp.int32),
-            jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool), *small,
-            jnp.zeros((S, 2), jnp.uint32)).as_text(debug_info=True)
-        prefill = b._prefill_fn.lower(
-            b.model.params_, b._kc, b._vc, b._dkc, b._dvc,
-            jnp.zeros((1, 16), jnp.int32), jnp.asarray(5, jnp.int32),
-            jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
-            jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
-            jnp.zeros((2,), jnp.uint32)).as_text(debug_info=True)
+        if inputs == "on_the_host":
+            b = storm[0].backend
+            S = b.n_slots
+            small = (jnp.zeros((S,), jnp.float32),
+                     jnp.zeros((S,), jnp.int32),
+                     jnp.zeros((S,), jnp.float32))
+            decode = b._decode_fn.lower(
+                b.model.params_, b._kc, b._vc, jnp.zeros((S,), jnp.int32),
+                jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool), *small,
+                jnp.zeros((S, 2), jnp.uint32)).as_text(debug_info=True)
+            prefill = b._prefill_fn.lower(
+                b.model.params_, b._kc, b._vc, b._dkc, b._dvc,
+                jnp.zeros((1, 16), jnp.int32), jnp.asarray(5, jnp.int32),
+                jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
+                jnp.asarray(0, jnp.int32), jnp.asarray(0.0, jnp.float32),
+                jnp.zeros((2,), jnp.uint32)).as_text(debug_info=True)
+        else:
+            # the programs of the backend that launches ahead
+            eng = GenerationEngine(_lm(), n_slots=2)
+            try:
+                b = eng.backend
+                assert hasattr(b, "launch")
+                state = jnp.zeros((b.n_slots + 1, 8), jnp.int32)
+                decode = b._decode_fn.lower(
+                    b.model.params_, b._kc, b._vc,
+                    state).as_text(debug_info=True)
+                prefill = b._prefill_fn.lower(
+                    b.model.params_, b._kc, b._vc, state,
+                    jnp.zeros((8 + 16,), jnp.int32)).as_text(debug_info=True)
+            finally:
+                eng.shutdown()
         from tests.phase_checks import scopes_in
 
         want = {"embed", "attn", "kv_write", "mlp", "head", "sample"}
@@ -1049,6 +1085,17 @@ def _bf16_engine() -> GenerationEngine:
     return _BF16["e"]
 
 
+def _bf16_ahead_engine() -> GenerationEngine:
+    """Module-shared engine on the same masters with K = 1 and no prefix
+    cache: the backend that keeps the slots' inputs on the device."""
+    if "ahead" not in _BF16:
+        e = GenerationEngine(_bf16_lm(), n_slots=3, queue_limit=32,
+                             default_timeout_s=120.0)
+        e.warmup()
+        _BF16["ahead"] = e
+    return _BF16["ahead"]
+
+
 def _casts_to_bf16(jaxpr) -> int:
     """float32 -> bfloat16 ``convert_element_type``s in a jaxpr and the
     jaxprs its equations carry (a scan's body, a jit's)."""
@@ -1073,15 +1120,18 @@ class TestServingCopy:
     masters: bitwise the same results, one cast a changed tree."""
 
     @pytest.mark.parametrize("program", ["prefill", "decode", "verify",
-                                         "draft"])
+                                         "draft", "prefill_on_device",
+                                         "decode_on_device"])
     def test_programs_bitwise_as_on_the_masters(self, program):
-        # each program the backend has, on the copy and on the float32
-        # masters themselves (where it casts per call, as it did for
-        # every dispatch before there was a copy), from one slab state
+        # each program the two backends have, on the copy and on the
+        # float32 masters themselves (where it casts per call, as it did
+        # for every dispatch before there was a copy), from one slab state
         import jax.numpy as jnp
 
-        eng = _bf16_engine()
+        on_device = program.endswith("_on_device")
+        eng = _bf16_ahead_engine() if on_device else _bf16_engine()
         b, S, K = eng.backend, eng.n_slots, eng.spec_decode_k
+        assert hasattr(b, "launch") == on_device
         masters, copy = b.model.params_, b._params()
         assert copy["head"].dtype == jnp.bfloat16
         assert masters["head"].dtype == jnp.float32
@@ -1089,7 +1139,8 @@ class TestServingCopy:
         prompts = _prompts(S, (5, 15), seed=11)
         with eng._dev_lock:  # the idle worker dispatches nothing
             for slot, prompt in enumerate(prompts):
-                b.prefill(slot, prompt, 0.0, 0, 0.0, key)
+                b.prefill(slot, prompt, 0.0, 0, 0.0, key,
+                          *((5,) if on_device else ()))
             pos = jnp.asarray([p.shape[0] for p in prompts], jnp.int32)
 
             def slabs():  # the programs consume the slabs they are given
@@ -1104,6 +1155,15 @@ class TestServingCopy:
 
             def run(p):
                 kc, vc, dkc, dvc = slabs()
+                if program == "prefill_on_device":
+                    req = np.zeros((8 + 16,), np.int32)
+                    b._state(req[:8], 1, 11, 5, 0.0, 0, 0.0, key)
+                    req[8:] = np.arange(16)
+                    return b._prefill_fn(p, kc, vc, b._slots_state,
+                                         jnp.asarray(req))
+                if program == "decode_on_device":
+                    # the three slots' rows as their prefills left them
+                    return b._decode_fn(p, kc, vc, b._slots_state)
                 if program == "prefill":
                     return b._prefill_fn(
                         p, kc, vc, dkc, dvc,
@@ -1155,6 +1215,56 @@ class TestServingCopy:
         finally:
             eng.shutdown()
             ref.shutdown()
+
+    def test_a_swap_under_a_step_in_flight_is_served_within_two_steps(self):
+        """``params_`` is swapped when three tokens are out and step 3 is
+        in flight: the next LAUNCH (step 4) reads the new weights, two
+        steps after the last token the caller saw, where the lock-step
+        loop swapped before its step 4 serves the same: one cast, no
+        trace, no second executable."""
+        other = _bf16_lm(seed=6)
+        prompt = _prompts(1, seed=4)[0]
+
+        def swapped_before_step_four(seam, **engine):
+            m = _bf16_lm(seed=5)
+            eng = GenerationEngine(m, n_slots=2, default_timeout_s=120.0,
+                                   **engine)
+            try:
+                eng.warmup()
+                assert eng._ahead == (seam == "launch")
+                first = eng.submit(prompt, max_new=9).result(timeout=90)
+                traced, seen = dict(eng.trace_counts), {"calls": 0}
+                real = getattr(eng.backend, seam)
+
+                def step(*args):
+                    seen["calls"] += 1
+                    if seen["calls"] == 4:
+                        seen["tokens_out"] = len(req.tokens)
+                        m.params_ = other.params_
+                    return real(*args)
+
+                setattr(eng.backend, seam, step)
+                with eng._dev_lock:
+                    req = eng.submit(prompt, max_new=9)
+                out = req.result(timeout=90)
+                assert eng.metrics.snapshot()["param_casts"] == 2
+                assert eng.trace_counts == traced
+                assert eng.backend._decode_fn._cache_size() == 1
+                return first, out, seen["tokens_out"]
+            finally:
+                eng.shutdown()
+
+        first, ahead, out_at_swap = swapped_before_step_four("launch")
+        assert out_at_swap == 3  # the prefill's and two steps'; one flies
+        _, lock_step, out_at_swap = swapped_before_step_four(
+            "decode", prefix_cache_mb=1.0)
+        assert out_at_swap == 4
+        np.testing.assert_array_equal(ahead, lock_step)
+        # the prompt, the prefill's token and three steps' on the old
+        # weights, then the new weights' tokens
+        n = len(prompt) + 4
+        np.testing.assert_array_equal(ahead[:n], first[:n])
+        assert not np.array_equal(ahead[n:], first[n:])
 
     @pytest.mark.parametrize("kind", ["dense", "moe", "float32"])
     def test_copy_casts_what_the_block_and_head_cast(self, kind):
@@ -1399,7 +1509,6 @@ class TestGenerateHTTP:
 
 def teardown_module(module):
     for held in (_ENG, _BF16):
-        eng = held.pop("e", None)
-        if eng is not None:
-            eng.shutdown()
+        while held:
+            held.popitem()[1].shutdown()
     _LM.clear()

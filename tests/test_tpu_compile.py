@@ -200,7 +200,9 @@ def _sampler_work_outside_a_conditional(text: str, slots: int,
 
 @pytest.fixture(scope="module")
 def chat_decode(one_chip):
-    """The serving engine's decode program (``_decode``) at the
+    """The serving engine's decode program (``_decode``) as the chat
+    cell runs it (``_TransformerAheadBackend``: K = 1, no prefix cache,
+    the slots' inputs one int32 array on the device) at the
     gpt2-large.chat cell's widths (d 1280, 20 heads, 24 slots x 1024,
     bf16) and a cut depth (4 layers; ~35 s of compile), lowered on what
     the backend hands it: the shapes of the weights' serving copy.
@@ -212,7 +214,7 @@ def chat_decode(one_chip):
         init_params,
         serving_copy,
     )
-    from deeplearning4j_tpu.serving.generate import _TransformerBackend
+    from deeplearning4j_tpu.serving.generate import _TransformerAheadBackend
 
     L, S, T = 4, 24, 1024
     cfg = TransformerLMConfig(vocab_size=50257, max_length=T, d_model=1280,
@@ -220,8 +222,8 @@ def chat_decode(one_chip):
                               compute_dtype="bfloat16")
     # the program takes its shapes from its arguments: the backend
     # itself is built at two slots, so no slab is allocated here
-    be = _TransformerBackend(SimpleNamespace(cfg=cfg), 2, T, None,
-                             lambda name: None)
+    be = _TransformerAheadBackend(SimpleNamespace(cfg=cfg), 2, T, None,
+                                  lambda name: None)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -232,9 +234,7 @@ def chat_decode(one_chip):
         jax.eval_shape(lambda p: serving_copy(cfg, p), masters))
     slab = arg((L, S, cfg.n_heads, cfg.d_model // cfg.n_heads, T), BF16)
     compiled = be._decode_fn.lower(
-        params, slab, slab, arg((S,), jnp.int32), arg((S,), jnp.int32),
-        arg((S,), jnp.bool_), arg((S,), F32), arg((S,), jnp.int32),
-        arg((S,), F32), arg((S, 2), jnp.uint32)).compile()
+        params, slab, slab, arg((S + 1, 8), jnp.int32)).compile()
     return compiled, cfg, S, slab.shape
 
 
