@@ -5,7 +5,8 @@ points a user calls, at the full width of models the repo supports.
 
     python chip_smoke.py              one chip: device, lm_train, lm_serve,
                                       hybrid_serve, sparse_serve,
-                                      resnet_train, resnet_serve, kernels
+                                      looped_serve, resnet_train,
+                                      resnet_serve, kernels
     python chip_smoke.py --chips 4    the cross-chip paths only: the
                                       DistributedLMTrainer on a 2x2 mesh and
                                       tensor-parallel serving on 1x4, each
@@ -95,6 +96,17 @@ FULL = {
         dense_width=512, expert_width=128, n_experts=8, top_k=2,
         experts_held=(4, 4), shared_width=128, max_length=128,
         routing={"scoring": "sigmoid", "scale": 2.5}, param_dtype="float32"),
+    # a looped decoder at Ouro-2.6B's published widths and three of its 48
+    # layers: the stack run four times a token over one set of weights, a
+    # cache entry a (pass, layer), sandwich norms, the exit gate; float32
+    # so that equal tokens mean something
+    "looped": dict(
+        vocab_size=49152, d_model=2048, n_heads=16, head_dim=128,
+        v_head_dim=128, rotary_dim=128,
+        attn_kinds={"full": {"n_kv_heads": 16, "rope_theta": 1e6}},
+        layers=[("full", "dense")] * 3, dense_width=5632, norm_eps=1e-6,
+        max_length=128, param_dtype="float32", passes=4,
+        sandwich_norm=True, exit_gate=True),
 }
 TINY = {
     "lm": dict(vocab_size=256, d_model=64, n_heads=4, n_layers=2,
@@ -115,6 +127,10 @@ TINY = {
 }
 TINY["hybrid"] = FULL["hybrid"]
 TINY["sparse"] = FULL["sparse"]
+TINY["looped"] = dict(
+    FULL["looped"], vocab_size=256, d_model=64, n_heads=4, head_dim=16,
+    v_head_dim=16, rotary_dim=16, dense_width=160, passes=3,
+    attn_kinds={"full": {"n_kv_heads": 4, "rope_theta": 1e6}})
 
 #: relative tolerance of one logit row against another: bf16 keeps 8
 #: bits of mantissa and a 12-block stack rounds the residual stream
@@ -422,24 +438,19 @@ def phase_lm_serve(size, model):
             "vs_generate_cached": parity}
 
 
-def phase_hybrid_serve(size):
-    """A decoder whose layers are state-space mixers around an attention
-    layer (``models/decoder_lm.py``), served by ``GenerationEngine``: more
-    requests than slots, so slots are claimed again over another request's
-    recurrent state and rows sit idle beside live ones; every request's
-    tokens against the model's own cached generation on one slot."""
+def serve_decoder(model, prompts, max_new, buckets):
+    """``prompts`` through a three-slot ``GenerationEngine`` over a
+    ``DecoderLM`` (more requests than slots: slots are claimed again and
+    rows sit idle beside live ones), each request's tokens against the
+    model's own cached generation on one slot; no retrace after warm-up.
+    Returns (what was served, the engine's counters, the ring's step ids,
+    its memory report, the warm-up's)."""
     import numpy as np
 
-    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
     from deeplearning4j_tpu.serving.generate import GenerationEngine
 
-    model = DecoderLM.from_dict(size["hybrid"]).init()
-    rng = np.random.default_rng(2)
-    prompts = [rng.integers(0, model.cfg.vocab_size, n)
-               for n in (5, 9, 20, 31, 2)]
-    max_new = 24
     gen = GenerationEngine(model, n_slots=3, max_length=96,
-                           prefill_buckets=[8, 16, 32])
+                           prefill_buckets=buckets)
     try:
         warm = gen.warmup()
         traced = dict(gen.trace_counts)
@@ -459,10 +470,30 @@ def phase_hybrid_serve(size):
         check(np.array_equal(got[-max_new:], alone[-max_new:]),
               f"request {i}: engine {got[-max_new:].tolist()} != alone "
               f"{alone[-max_new:].tolist()}")
+    return served, snapshot, ring, report, {
+        k: warm.get(k) for k in ("buckets", "compiles")}
+
+
+def phase_hybrid_serve(size):
+    """A decoder whose layers are state-space mixers around an attention
+    layer (``models/decoder_lm.py``), served by ``GenerationEngine``: more
+    requests than slots, so slots are claimed again over another request's
+    recurrent state and rows sit idle beside live ones; every request's
+    tokens against the model's own cached generation on one slot."""
+    import numpy as np
+
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
+
+    model = DecoderLM.from_dict(size["hybrid"]).init()
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, model.cfg.vocab_size, n)
+               for n in (5, 9, 20, 31, 2)]
+    max_new = 24
+    _served, snapshot, ring, report, warm = serve_decoder(
+        model, prompts, max_new, [8, 16, 32])
     check(snapshot["state_slots"] > 0, "no live state slot was counted")
     return {"requests": len(prompts), "slots": 3, "max_new": max_new,
-            "segments": model.cfg.segments(),
-            "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
+            "segments": model.cfg.segments(), "warmup": warm,
             "state_bytes": report["state_bytes"],
             "slab_bytes": report["slab_bytes"],
             "state_slots": snapshot["state_slots"],
@@ -482,7 +513,6 @@ def phase_sparse_serve(size):
     import numpy as np
 
     from deeplearning4j_tpu.models.decoder_lm import DecoderLM
-    from deeplearning4j_tpu.serving.generate import GenerationEngine
 
     model = DecoderLM.from_dict(size["sparse"]).init()
     topk = model.cfg.attn_kinds["indexed"]["index"]["topk"]
@@ -490,28 +520,11 @@ def phase_sparse_serve(size):
     prompts = [rng.integers(0, model.cfg.vocab_size, n)
                for n in (5, 9, 20, 31, 40)]
     max_new = 24
-    gen = GenerationEngine(model, n_slots=3, max_length=96,
-                           prefill_buckets=[8, 16, 32, 64])
-    try:
-        warm = gen.warmup()
-        traced = dict(gen.trace_counts)
-        mark = time.time_ns()
-        requests = [gen.submit(p, max_new=max_new) for p in prompts]
-        served = [np.asarray(r.result(timeout=900)) for r in requests]
-        check(gen.trace_counts == traced,
-              f"retraced after warm-up: {traced} -> {gen.trace_counts}")
-        snapshot = gen.metrics.snapshot()
-        ring = step_ids_in_ring(gen, mark,
-                                ahead=snapshot["decode_steps_ahead"])
-        plan = gen.describe()["memory"]["cache_plan"]
-    finally:
-        gen.shutdown()
+    served, snapshot, ring, report, warm = serve_decoder(
+        model, prompts, max_new, [8, 16, 32, 64])
+    plan = report["cache_plan"]
     agree = total = 0
-    for i, (prompt, got) in enumerate(zip(prompts, served)):
-        alone = model.generate_cached(prompt, max_new=max_new)
-        check(np.array_equal(got[-max_new:], alone[-max_new:]),
-              f"request {i}: engine {got[-max_new:].tolist()} != alone "
-              f"{alone[-max_new:].tolist()}")
+    for prompt, got in zip(prompts, served):
         greedy = model.logits(got[None, :-1])[0, len(prompt) - 1:].argmax(-1)
         agree += int((greedy == got[-max_new:]).sum())
         total += max_new
@@ -525,7 +538,7 @@ def phase_sparse_serve(size):
     check(snapshot["decode_steps_ahead"] > 0, "no step was launched ahead")
     return {"requests": len(prompts), "slots": 3, "max_new": max_new,
             "segments": model.cfg.segments(), "index_topk": topk,
-            "warmup": {k: warm.get(k) for k in ("buckets", "compiles")},
+            "warmup": warm,
             "cache_plan": [{k: p[k] for k in ("kind", "layers", "values",
                                               "row", "bytes")} for p in plan],
             "index_positions_scored": scored, "sparse_positions_read": read,
@@ -783,6 +796,46 @@ def phase_sharded_serve(size):
 
 
 # -- entry -------------------------------------------------------------------
+def phase_looped_serve(size):
+    """A decoder whose whole stack runs several times a token over one set
+    of weights (``models/decoder_lm.py``: ``passes``), served by
+    ``GenerationEngine``: every request's tokens against the model's own
+    cached generation on one slot, the cache plan's entries a position
+    (passes x layers) and the passes the launched steps ran."""
+    import numpy as np
+
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
+
+    model = DecoderLM.from_dict(size["looped"]).init()
+    cfg = model.cfg
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, n) for n in (5, 9, 20, 31, 40)]
+    max_new = 24
+    _served, snapshot, ring, report, warm = serve_decoder(
+        model, prompts, max_new, [8, 16, 32, 64])
+    plan = report["cache_plan"]
+    entries = cfg.passes * cfg.n_layers
+    check(snapshot["cache_entries_per_position"] == entries
+          and [p["passes"] for p in plan] == [cfg.passes],
+          f"cache entries a position: {snapshot} {plan}")
+    # a step launched for a slot the host had stopped streams nothing
+    check(snapshot["stack_passes"] >= cfg.passes * snapshot["decode_steps"] > 0
+          and snapshot["stack_passes"] % cfg.passes == 0,
+          f"stack passes: {snapshot}")
+    check(snapshot["decode_steps_ahead"] > 0, "no step was launched ahead")
+    return {"requests": len(prompts), "slots": 3, "max_new": max_new,
+            "passes": cfg.passes, "layers": cfg.n_layers, "warmup": warm,
+            "cache_plan": [{k: p[k] for k in ("kind", "layers", "passes",
+                                              "values", "bytes")}
+                           for p in plan],
+            "stack_passes": snapshot["stack_passes"],
+            "decode_steps": snapshot["decode_steps"],
+            "cache_entries_per_position": entries,
+            "decode_steps_ahead": snapshot["decode_steps_ahead"],
+            "late_slot_steps": snapshot["late_slot_steps"], "ring": ring,
+            "tokens_equal_generate_cached": True}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
@@ -814,6 +867,7 @@ def main(argv=None) -> int:
         del model
         run_phase("hybrid_serve", phase_hybrid_serve, meter, size)
         run_phase("sparse_serve", phase_sparse_serve, meter, size)
+        run_phase("looped_serve", phase_looped_serve, meter, size)
         run_phase("resnet_train", phase_resnet_train, meter, size)
         run_phase("resnet_serve", phase_resnet_serve, meter, size)
     run_phase("kernels", phase_kernels, meter, dev["platform"], size)

@@ -67,7 +67,23 @@ with or without a shared expert. Here all of that is configuration:
 - four scalars of the configuration scale the embedding, every residual
   branch, the attention scores and the logits (each 1, or
   ``1/sqrt(head)``, by default), ``rotary_dim`` 0 means no positions at
-  all, and the head may be the embedding read transposed (``tied_head``).
+  all, and the head may be the embedding read transposed (``tied_head``);
+- the stack may run SEVERAL TIMES a token over one set of weights
+  (``passes``; a looped model, which buys depth with passes instead of
+  parameters): the final norm closes every pass and the next starts from
+  the normed stream; each (pass, layer) attends to keys and values of its
+  own, so every slab, ring and state of the cache plan leads with passes x
+  layers, pass-major, and ``_run_stack`` runs the passes as one scan that
+  carries the slabs whole and hands pass r the entries ``r x layers + i``;
+  a layer's two outputs may be normed again before they join the residual
+  (``sandwich_norm``: four norms a layer); and a gate of one output may
+  read each pass's closed stream for the probability of leaving there
+  (``exit_gate``), which :func:`forward` holds against ``exit_threshold``
+  a token at a time. The cached programs run every pass for every token,
+  the rule at threshold 1, and the serving backend refuses a lower one:
+  rows of one batched step that leave after different passes, and what a
+  row that left owes the later passes' cache entries, are a scheduler's
+  question (ROADMAP, Queue R).
 
 Serving only: there is no training step for this block yet (ROADMAP M1).
 """
@@ -117,12 +133,14 @@ Array = jax.Array
 #: cache, ReLU, the weighted sum over heads), ``attn_index_select`` (the
 #: exact top-k) and, owner or sharer, ``attn_sparse_core`` (the gather of
 #: the selected entries, the scores, softmax and weighted sum over them;
-#: in prefill the blocked attention under the selection's mask)
+#: in prefill the blocked attention under the selection's mask); where the
+#: stack runs more than once, ``pass_close``: the final norm that closes
+#: each pass, and the exit gate's reading of the closed stream
 SCOPES = ("attn_full", "attn_window", "attn_latent_proj", "attn_latent_core",
           "mlp", "moe_route", "moe_experts", "moe_shared",
           "ssm_proj", "ssm_conv", "ssm_scan", "state_write",
           "attn_index_proj", "attn_index_score", "attn_index_select",
-          "attn_sparse_core")
+          "attn_sparse_core", "pass_close")
 _scope = jax.named_scope
 _NEG = -1e30
 #: queries and keys a block of a latent layer's prefill attention
@@ -184,7 +202,18 @@ class DecoderConfig:
     ``residual_multiplier`` every residual branch (mixer and FFN),
     ``attention_multiplier`` the attention scores (None: ``1/sqrt(head)``)
     and ``logits_scaling`` divides the logits; ``tied_head``: the head is
-    the embedding, one leaf, read transposed."""
+    the embedding, one leaf, read transposed. ``passes``: how many times
+    the whole stack is applied to every token, with ONE set of weights:
+    each pass closes with the final norm, the next starts from the normed
+    stream, and every (pass, layer) keeps a cache entry of its own
+    (:meth:`cache_plan`). ``sandwich_norm``: a layer has four norms, the
+    two outputs (mixer and FFN) being normed again (``norm1b``,
+    ``norm2b``) before they join the residual. ``exit_gate``: a gate of
+    one output (``gate_w`` (d,), ``gate_b``) reads each pass's normed
+    stream and gives the probability of leaving there;
+    ``exit_threshold`` q: a token's logits come from the first pass at
+    which the cumulative exit probability reaches q (:func:`forward`;
+    1.0: from the last pass, whatever the gate says)."""
 
     def __init__(self, vocab_size: int, d_model: int, n_heads: int,
                  head_dim: int, v_head_dim: int, rotary_dim: int,
@@ -198,7 +227,9 @@ class DecoderConfig:
                  shared_width: int = 0, embedding_multiplier: float = 1.0,
                  residual_multiplier: float = 1.0,
                  attention_multiplier: Optional[float] = None,
-                 logits_scaling: float = 1.0, tied_head: bool = False):
+                 logits_scaling: float = 1.0, tied_head: bool = False,
+                 passes: int = 1, sandwich_norm: bool = False,
+                 exit_gate: bool = False, exit_threshold: float = 1.0):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.n_heads = int(n_heads)
@@ -294,6 +325,15 @@ class DecoderConfig:
                                      else float(attention_multiplier))
         self.logits_scaling = float(logits_scaling)
         self.tied_head = bool(tied_head)
+        self.passes = int(passes)
+        self.sandwich_norm = bool(sandwich_norm)
+        self.exit_gate = bool(exit_gate)
+        self.exit_threshold = float(exit_threshold)
+        if self.passes < 1 or not 0.0 <= self.exit_threshold <= 1.0:
+            raise ValueError("passes >= 1 and 0 <= exit_threshold <= 1")
+        if self.exit_threshold < 1.0 and not self.exit_gate:
+            raise ValueError("an exit_threshold under 1 needs the exit_gate "
+                             "whose probabilities it is held against")
 
     @property
     def n_layers(self) -> int:
@@ -384,25 +424,31 @@ class DecoderConfig:
         state would round at every step of a recurrence thousands long)
         and ``conv`` (layers, slots, convolved channels, d_conv - 1), the
         convolution's last inputs, in the parameter dtype: the same bytes
-        whatever ``max_length``. ``dtypes`` goes with ``slabs``."""
+        whatever ``max_length``. ``dtypes`` goes with ``slabs``. A stack
+        that runs ``passes`` times keeps all of that a PASS: every shape
+        leads with passes x layers, pass-major (pass r's layer i is entry
+        r x layers + i), and ``bytes`` counts them; ``layers`` stays the
+        segment's and ``passes`` stands beside it."""
         item = jnp.dtype(self.dtype).itemsize
         plan = []
-        for kind, _ffn, n in self.segments():
+        for kind, _ffn, layers in self.segments():
+            n = self.passes * layers
             if self.attn_kinds[kind]["ssm"]:
                 h, p, ns, _inner, conv = self.ssm_dims(kind)
                 tail = self.attn_kinds[kind]["ssm"]["d_conv"] - 1
                 state = (n, int(n_slots), ns, h * p)
                 taps = (n, int(n_slots), conv, tail)
                 plan.append({
-                    "kind": kind, "layers": n, "columns": 0,
-                    "state": state, "conv": taps,
+                    "kind": kind, "layers": layers, "passes": self.passes,
+                    "columns": 0, "state": state, "conv": taps,
                     "slabs": [state, taps],
                     "dtypes": [jnp.float32, self.dtype],
                     "bytes": int(np.prod(state)) * 4
                     + int(np.prod(taps)) * item})
                 continue
             cols = self.cache_columns(kind, max_length)
-            entry = {"kind": kind, "layers": n, "columns": cols,
+            entry = {"kind": kind, "layers": layers, "passes": self.passes,
+                     "columns": cols,
                      "ring": self.attn_kinds[kind]["window"] is not None}
             index = self.attn_kinds[kind]["index"]
             if index:
@@ -456,11 +502,14 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
     ``conv_b`` are the depthwise convolution, ``dt_bias``, ``A_log`` and
     ``D`` (heads,) the recurrence's per-head scalars and ``norm_g`` the
     gated norm's gain, float32; ``Wo`` (inner, d) brings the result
-    out."""
+    out. With ``sandwich_norm`` every kind of layer has ``norm1b`` and
+    ``norm2b``, the gains of the norms its two outputs go through."""
     d, hq = cfg.d_model, cfg.n_heads
     ak = cfg.attn_kinds[kind]
     pd, f32 = cfg.dtype, jnp.float32
     out = {"norm1": ((d,), f32), "norm2": ((d,), f32)}
+    if cfg.sandwich_norm:
+        out.update({"norm1b": ((d,), f32), "norm2b": ((d,), f32)})
     if ak["ssm"]:
         h, _p, _n, inner, conv = cfg.ssm_dims(kind)
         out.update({"Win": ((d, inner + conv + h), pd),
@@ -509,7 +558,8 @@ def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
 
 def init_params(cfg: DecoderConfig, rng: Optional[Array] = None) -> Dict:
     """{"embed", "segments": [stacked leaves a segment], "norm_f",
-    "head"} (no "head" where it is tied to the embedding); normal(0, 0.02)
+    "head"} (no "head" where it is tied to the embedding; "gate_w" and
+    "gate_b", float32, where there is an exit gate); normal(0, 0.02)
     matrices, unit gains, zero sinks, a small router bias; a state-space
     mixer's scalars at Mamba-2's defaults (``A`` uniform in [1, 16],
     ``dt`` log-uniform in [1e-3, 1e-1] with ``dt_bias`` its inverse
@@ -554,6 +604,9 @@ def init_params(cfg: DecoderConfig, rng: Optional[Array] = None) -> Dict:
            "norm_f": jnp.ones((cfg.d_model,), jnp.float32)}
     if not cfg.tied_head:
         out["head"] = normal((cfg.d_model, cfg.vocab_size), pd)
+    if cfg.exit_gate:
+        out["gate_w"] = normal((cfg.d_model,), jnp.float32)
+        out["gate_b"] = jnp.zeros((), jnp.float32)
     return out
 
 
@@ -636,6 +689,12 @@ def _residual(cfg: "DecoderConfig", x, branch):
     if cfg.residual_multiplier != 1.0:
         branch = branch * cfg.residual_multiplier
     return x + branch.astype(x.dtype)
+
+
+def _branch(cfg: "DecoderConfig", bp, gain: str, y):
+    """A layer's output on its way to the residual: under
+    ``sandwich_norm`` normed once more, by the gain ``bp[gain]``."""
+    return _rms_norm(y, bp[gain], cfg.norm_eps) if cfg.sandwich_norm else y
 
 
 def _visible(q_pos, k_pos, window):
@@ -823,7 +882,8 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
             o = jnp.einsum("bqhc,chv->bqhv", lat, bp["Wuv"])
         if cfg.value_scale != 1.0:
             o = o * cfg.value_scale
-        x = _residual(cfg, x, o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"])
+        x = _residual(cfg, x, _branch(
+            cfg, bp, "norm1b", o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"]))
     return x, new
 
 
@@ -1075,7 +1135,8 @@ def _sparse_latent_attention(cfg: DecoderConfig, kind: str,
             o = jnp.einsum("bqhc,chv->bqhv", lat, bp["Wuv"])
         if cfg.value_scale != 1.0:
             o = o * cfg.value_scale
-        x = _residual(cfg, x, o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"])
+        x = _residual(cfg, x, _branch(
+            cfg, bp, "norm1b", o.reshape(b, tq, hq * vd).astype(dt) @ bp["Wo"]))
     return x, made, sel
 
 
@@ -1247,7 +1308,7 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
             z.reshape(b, tq, g, inner // g))
         gated = _rms_norm(gated, bp["norm_g"].reshape(g, inner // g),
                           cfg.norm_eps).reshape(b, tq, inner).astype(dt_)
-        x = _residual(cfg, x, gated @ bp["Wo"])
+        x = _residual(cfg, x, _branch(cfg, bp, "norm1b", gated @ bp["Wo"]))
     return x, made
 
 
@@ -1401,7 +1462,7 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
                 z = z + jnp.exp(sink - m)  # the sink takes weight, adds no value
             o = o * (cfg.value_scale / z[..., None])
             o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
-        x = _residual(cfg, x, o @ bp["Wo"])
+        x = _residual(cfg, x, _branch(cfg, bp, "norm1b", o @ bp["Wo"]))
     x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
     return x, (kh, vh), counts
 
@@ -1416,7 +1477,7 @@ def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
         with _scope("mlp"):
             m_in = _rms_norm(x, bp["norm2"], cfg.norm_eps).astype(x.dtype)
             h = jax.nn.silu(m_in @ bp["Wg"]) * (m_in @ bp["Wu"])
-            x = _residual(cfg, x, h @ bp["Wd"])
+            x = _residual(cfg, x, _branch(cfg, bp, "norm2b", h @ bp["Wd"]))
         counts = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     else:
         with _scope("moe_route"):
@@ -1424,14 +1485,14 @@ def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
         y, pairs, hit = _experts(
             cfg, bp, r_in, x.dtype,
             None if token_mask is None else token_mask.reshape(b * tq), layer)
-        x = _residual(cfg, x, y.reshape(b, tq, d))
+        x = _residual(cfg, x, _branch(cfg, bp, "norm2b", y.reshape(b, tq, d)))
         counts = (pairs.astype(jnp.int32), hit)
     return x, counts
 
 
-def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
-               caches=None, c_pos=None, token_mask=None):
-    """Every segment in order, each one ``lax.scan`` of :func:`block`
+def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
+              caches=None, c_pos=None, token_mask=None, r=None):
+    """Every segment in order ONCE, each one ``lax.scan`` of :func:`block`
     over its stacked layers. ``caches``: per segment the slabs to read,
     (K, V) (layers, b, hkv, hd, Tc) or a latent segment's one
     (layers, b, width, Tc), with ``c_pos`` the position map of each
@@ -1448,14 +1509,25 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     rows of them), and the SELECTION goes through its scan as a carry and
     on to the next segment: a layer that owns the indexer puts its own in
     the carry's place, a layer that shares reads what the last owner left,
-    be it a layer or a segment back. Returns (x, per segment what the layers
-    made to cache, (k, v) stacks (layers, b, hkv, Tq, hd) or (entries
-    (layers, b, Tq, width),) or, of a state-space segment, (states
-    (layers, b, state size, heads x head size), tails (layers, b, channels,
-    d_conv - 1)), summed expert counters); of a segment with an indexer
-    over a cache, ((entries, and an owner's keys), its slabs as the loop
-    hands them on)."""
-    new_kv = []
+    be it a layer or a segment back; it starts afresh with the pass.
+
+    ``r`` (traced) is the pass, where the stack runs more than once: the
+    caches then hold every pass's entries, passes x layers, and layer i
+    reads (a state-space layer: writes) entry ``r x layers + i`` of its
+    segment's. No pass's part is cut out of a slab on the way: a slice of
+    it handed to a scan is a copy of it. Every slab goes through the
+    segment's scan whole, as a carry that no attention layer changes, and
+    a layer takes its own entry by index inside the loop, as a scan takes
+    a layer's from what it scans over.
+
+    Returns (x, per segment what the layers made to cache, (k, v) stacks
+    (layers, b, hkv, Tq, hd) or (entries (layers, b, Tq, width),) (with an
+    indexer: and an owner's keys) or, of a state-space segment without a
+    cache, (states (layers, b, state size, heads x head size), tails
+    (layers, b, channels, d_conv - 1)) and nothing over one; summed expert
+    counters; per segment the slabs as the loops hand them on, a
+    state-space segment's written, the after-loop write's to take)."""
+    made, held_out = [], []
     pairs = hit = jnp.zeros((), jnp.int32)
     sel = None
     for i, (kind, ffn, n) in enumerate(cfg.segments()):
@@ -1464,7 +1536,13 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
         # product takes them whole and the layer's index
         stacks = {k: seg[k] for k in EXPERT_STACKS if k in seg}
         scanned = {k: v for k, v in seg.items() if k not in stacks}
-        kv = None if caches is None else caches[i]
+        kv = held = None if caches is None else caches[i]
+        # a layer's entry in the segment's cache: this pass's run of them
+        base = None if r is None or kv is None else r * n
+
+        def entry(layer, base=base):
+            return layer if base is None else base + layer
+
         index = cfg.attn_kinds[kind]["index"]
         if index:
             lengths = None if kv is None else q_pos[:, 0]
@@ -1478,19 +1556,20 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
             # the write updates were two, a slab-sized copy a step (by
             # compile, PR 40)
             def chosen(carry, xs, kind=kind, ffn=ffn, stacks=stacks,
-                       lengths=lengths):
+                       lengths=lengths, entry=entry):
                 x, sel, kv = carry
                 bp, layer = xs
                 x, (knew, sel), counts = block(
                     cfg, kind, ffn, {**bp, **stacks}, x, q_pos,
-                    None if kv is None else (kv, layer, lengths), token_mask,
-                    layer if stacks else None, sel)
+                    None if kv is None else (kv, entry(layer), lengths),
+                    token_mask, layer if stacks else None, sel)
                 return (x, sel, kv), (knew, counts)
 
             (x, sel, kv), (knew, counts) = jax.lax.scan(
                 chosen, (x, sel, kv),
                 (scanned, jnp.arange(n, dtype=jnp.int32)))
-            new_kv.append(knew if kv is None else (knew, kv))
+            made.append(knew)
+            held_out.append(kv)
             pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
             continue
         if kv is not None and cfg.attn_kinds[kind]["ssm"]:
@@ -1501,55 +1580,124 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
                     else token_mask[:, 0]),)
 
             def step(carry, xs, kind=kind, ffn=ffn, stacks=stacks,
-                     table=table):
+                     table=table, entry=entry):
                 x, held = carry
                 bp, layer = xs
                 x, held, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
-                                        q_pos, (*held, layer, *table),
+                                        q_pos, (*held, entry(layer), *table),
                                         token_mask, layer if stacks else None)
                 return (x, held), counts
 
             (x, held), counts = jax.lax.scan(
                 step, (x, tuple(kv)),
                 (scanned, jnp.arange(n, dtype=jnp.int32)))
-            new_kv.append(held)
+            made.append(())
+            held_out.append(held)
             pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
             continue
         # nor is a latent segment's slab where the decode kernel reads it
         # (a custom call's operand is made whole: the scan's slice of the
         # slab would be copied a layer), by the rows' lengths: a row that
         # is not active has none
-        whole = lengths = None
+        whole = lengths = every = None
         if (kv is not None and x.shape[1] == 1
                 and _latent_kernel_admits(cfg, kind, kv[0])):
             (whole,), kv = kv, None
             lengths = q_pos[:, 0] if token_mask is None else jnp.where(
                 token_mask[:, 0], q_pos[:, 0], 0)
+        elif kv is not None and r is not None:
+            every, kv = tuple(kv), None  # all passes' entries: by index
 
-        def body(x, xs, kind=kind, ffn=ffn, stacks=stacks, whole=whole,
-                 lengths=lengths):
+        def body(carry, xs, kind=kind, ffn=ffn, stacks=stacks, whole=whole,
+                 lengths=lengths, entry=entry):
+            x, every = carry
             bp, kv, layer = xs
+            if every is not None:
+                kv = tuple(jax.lax.dynamic_index_in_dim(
+                    c, entry(layer), 0, keepdims=False) for c in every)
             cache = None if kv is None else (*kv, c_pos[kind])
             if whole is not None:
-                cache = (whole, layer, lengths)
+                cache = (whole, entry(layer), lengths)
             x, knew, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
                                     q_pos, cache, token_mask,
                                     layer if stacks else None)
-            return x, (knew, counts)
+            return (x, every), (knew, counts)
 
-        x, (knew, counts) = jax.lax.scan(
-            body, x, (scanned, kv, jnp.arange(n, dtype=jnp.int32)))
-        new_kv.append(knew)
+        (x, every), (knew, counts) = jax.lax.scan(
+            body, (x, every),
+            (scanned, kv, jnp.arange(n, dtype=jnp.int32)))
+        made.append(knew)
+        # the loop's own hand-on where it carried the slabs, else as given
+        held_out.append(held if every is None else every)
         pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
-    return x, new_kv, (pairs, hit)
+    return x, made, (pairs, hit), None if caches is None else held_out
+
+
+def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
+               caches=None, c_pos=None, token_mask=None):
+    """The stack, ``cfg.passes`` times over the SAME ``params``
+    (:func:`_run_pass` is one time). With one pass that is all, and the
+    stream comes back as the last layer left it (:func:`_head` norms it).
+    With more, the passes are ONE ``lax.scan`` (its body, the segments'
+    scans, is compiled once however many passes there are): the caches
+    hold passes x layers entries a segment and go through it whole as its
+    carry, pass ``r`` reading its own (``_run_pass``); every pass closes
+    under ``pass_close`` with the final norm, the next starting from the
+    NORMED stream, and with the exit gate's reading of it where there is
+    one; what the layers made to cache comes back stacked passes x layers,
+    pass-major, as the cache plan lays the slabs out, so that the
+    after-loop writes are what they are for one pass on a longer leading
+    axis. Returns (x, closed by the norm where passes > 1; per segment
+    what was made to cache; summed expert counters; per segment the slabs
+    for the after-loop write to take, None without a cache; (every pass's
+    closed stream (passes, b, Tq, d), the gate's probabilities (passes, b,
+    Tq) float32 or None), None where the stack runs once)."""
+    if cfg.passes == 1:
+        return *_run_pass(cfg, params, x, q_pos, caches, c_pos,
+                          token_mask), None
+
+    def one_pass(carry, r):
+        x, held = carry
+        x, made, counts, held = _run_pass(cfg, params, x, q_pos, held, c_pos,
+                                          token_mask, r)
+        with _scope("pass_close"):
+            h = _rms_norm(x, params["norm_f"], cfg.norm_eps)
+            leave = (jax.nn.sigmoid(jnp.sum(h * params["gate_w"], axis=-1)
+                                    + params["gate_b"])
+                     if cfg.exit_gate else None)
+            x = h.astype(x.dtype)
+        return (x, held), (made, counts, x, leave)
+
+    (x, held), (made, counts, closed, leave) = jax.lax.scan(
+        one_pass, (x, caches), jnp.arange(cfg.passes, dtype=jnp.int32))
+    made = jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), made)
+    return (x, made, (counts[0].sum(), counts[1].sum()), held,
+            (closed, leave))
+
+
+def _exit_stream(cfg: DecoderConfig, closed: Array, leave: Array):
+    """The stream each token's logits are read from under the exit rule:
+    ``closed`` (passes, b, T, d), the passes' normed streams, ``leave``
+    (passes, b, T), the gate's probability of leaving after each. The
+    probability of leaving at pass r is ``leave_r x prod_{j<r} (1 -
+    leave_j)``, the last pass taking what is left; a token leaves at the
+    first pass where the cumulated probability reaches
+    ``exit_threshold``."""
+    reached = 1.0 - jnp.cumprod(1.0 - leave, axis=0) >= cfg.exit_threshold
+    at = jnp.argmax(reached.at[-1].set(True), axis=0)        # the first
+    return jnp.take_along_axis(closed, at[None, ..., None], axis=0)[0]
 
 
 def _head(cfg: DecoderConfig, params: Dict, x: Array):
     """Logits over the held vocabulary. A tied head is the embedding
     (V, d) read transposed, by a product that contracts the minor
-    dimension of both: one leaf, no second copy."""
+    dimension of both: one leaf, no second copy. A stack that runs more
+    than once hands the stream over closed by the final norm, as every
+    pass closes (``_run_stack``): it is not normed a second time."""
     with _scope("head"):
-        x = _rms_norm(x, params["norm_f"], cfg.norm_eps).astype(cfg.dtype)
+        if cfg.passes == 1:
+            x = _rms_norm(x, params["norm_f"], cfg.norm_eps).astype(cfg.dtype)
         if cfg.tied_head:
             logits = jax.lax.dot_general(
                 x, params["embed"], (((x.ndim - 1,), (1,)), ((), ())))
@@ -1570,10 +1718,17 @@ def _embed(cfg: DecoderConfig, params: Dict, ids: Array):
 
 
 def forward(cfg: DecoderConfig, params: Dict, ids: Array):
-    """ids (b, T) -> float32 logits (b, T, V) over the held vocabulary."""
+    """ids (b, T) -> float32 logits (b, T, V) over the held vocabulary.
+    Where the stack runs more than once and ``exit_threshold`` is under 1,
+    each token's are read from the pass it leaves at (``_exit_stream``);
+    the cached programs (:func:`prefill_slot`, :func:`decode_step`) run
+    every pass for every token, the rule at threshold 1."""
     b, t = ids.shape
     q_pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
-    x, _kv, _counts = _run_stack(cfg, params, _embed(cfg, params, ids), q_pos)
+    x, _made, _counts, _held, passes = _run_stack(
+        cfg, params, _embed(cfg, params, ids), q_pos)
+    if passes is not None and cfg.exit_threshold < 1.0:
+        x = _exit_stream(cfg, *passes)
     return _head(cfg, params, x)
 
 
@@ -1652,18 +1807,19 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
                  if not cfg.attn_kinds[kind]["ssm"]
                  and not cfg.attn_kinds[kind]["index"]], default=0)
     q_pos = pos.astype(jnp.int32)[:, None]
-    x, new_kv, counts = _run_stack(
+    x, new_kv, counts, held, _passes = _run_stack(
         cfg, params, _embed(cfg, params, ids_1[:, None]), q_pos, caches,
         cache_positions(cfg, pos, t_max),
         None if active is None else active[:, None])
     out = []
     with _scope("kv_write"):
-        for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
+        # the slabs as the layer loop hands them on: with one pass, the
+        # arguments themselves but where a loop carried them
+        for (kind, _f, _n), slabs, new in zip(cfg.segments(), held, new_kv):
             if cfg.attn_kinds[kind]["ssm"]:
-                out.append(tuple(new))
+                out.append(tuple(slabs))
                 continue
             if cfg.attn_kinds[kind]["index"]:
-                new, slabs = new  # the slabs as the layer loop hands them on
                 wp = jnp.minimum(q_pos, slabs[0].shape[2] - 1)
                 out.append(tuple(_put_rows(c, n, wp)
                                  for c, n in zip(slabs, new)))
@@ -1698,8 +1854,8 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
     _b, tb = ids.shape
     q_pos = jnp.arange(tb, dtype=jnp.int32)[None]
     real = q_pos < length
-    x, new_kv, _counts = _run_stack(cfg, params, _embed(cfg, params, ids),
-                                    q_pos, token_mask=real)
+    x, new_kv, _counts, _held, _passes = _run_stack(
+        cfg, params, _embed(cfg, params, ids), q_pos, token_mask=real)
     out = []
     for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
         if cfg.attn_kinds[kind]["ssm"]:
@@ -1792,6 +1948,10 @@ class DecoderLM:
         if ids.size + max_new > cfg.max_length:
             raise ContextWindowExceeded(ids.size, max_new, cfg.max_length)
         _validate_sampling(temperature, top_k, top_p)
+        if cfg.exit_threshold < 1.0:
+            raise ValueError(
+                f"exit_threshold={cfg.exit_threshold}: the cached programs "
+                "run every pass for every token (the rule at threshold 1)")
         if "prefill" not in self._jit_cache:
             self._jit_cache["prefill"] = jax.jit(
                 lambda p, c, i, n: prefill_slot(cfg, p, c, i, n,

@@ -136,6 +136,16 @@ class RecurrentStateError(ServingError, ValueError):
     BUILD time."""
 
 
+class EarlyExitError(ServingError, ValueError):
+    """An exit threshold under 1 was asked of a model whose stack runs
+    several passes a token. The served programs run every pass for every
+    row, the rule at threshold 1; under a lower one the rows of one
+    batched step would leave after different passes, and what a row that
+    left owes the later passes' cache entries (which the positions after
+    it attend to) is a scheduler's question that no program here answers.
+    Raised at engine BUILD time."""
+
+
 class GenerationRequest:
     """One generation request: prompt + sampling policy + streaming
     output. Completion (``finish``/``fail``) is idempotent first-wins,
@@ -862,7 +872,11 @@ class _DecoderBackend:
     every decode step inside the layer loop (idle slots bit for bit as
     they were) and written for one slot by a prefill. With such a layer
     K > 1 and a prefix cache are REFUSED (:class:`RecurrentStateError`):
-    a state cannot be rolled back by dropping columns.
+    a state cannot be rolled back by dropping columns. A stack that runs
+    several passes a token (``DecoderConfig.passes``) keeps all of the
+    above a PASS, passes x layers entries a segment, read and written by
+    the same programs; an ``exit_threshold`` under 1 is REFUSED
+    (:class:`EarlyExitError`).
 
     The slots' inputs live on the device as one array (``_state``), so
     the step has no ``decode`` that puts, dispatches and fetches in one
@@ -919,6 +933,18 @@ class _DecoderBackend:
         #: some layer keeps a recurrent state: no prefix cache, K = 1;
         #: the engine counts the slots a launched step advances
         self.keeps_state = any(k["ssm"] for k in cfg.attn_kinds.values())
+        #: passes over the stack a step runs, and the (pass, layer) pairs
+        #: that keep a cache entry a position: the engine's counters
+        self.passes = cfg.passes
+        self.cache_entries = cfg.passes * sum(
+            1 for kind, _ffn in cfg.layers if not cfg.attn_kinds[kind]["ssm"])
+        if cfg.exit_threshold < 1.0:
+            raise EarlyExitError(
+                f"exit_threshold={cfg.exit_threshold}: the decode step runs "
+                f"all {cfg.passes} passes for every slot; rows that leave "
+                "after different passes, and the later passes' cache "
+                "entries of a row that left, have no program here; serve "
+                "with exit_threshold=1")
         if self.keeps_state and int(spec_k) > 1:
             raise RecurrentStateError(
                 f"spec_decode_k={spec_k}: a rejected draft token would have "
@@ -1106,6 +1132,7 @@ class _TransformerAheadBackend(_TransformerBackend):
     latent = False
     index_topk = 0
     keeps_state = False
+    passes = 1
 
     def _build_programs(self, trace_hook) -> None:
         """The jitted programs over slots' inputs that live on the
@@ -1580,8 +1607,9 @@ def generation_memory_report(model, n_slots: int,
            "n_slots": int(n_slots), "max_length": max_length}
     if plan is not None:
         out["cache_plan"] = [
-            {k: p[k] for k in ("kind", "layers", "columns", "ring", "values",
-                               "row", "index", "bytes", "state", "conv")
+            {k: p[k] for k in ("kind", "layers", "passes", "columns", "ring",
+                               "values", "row", "index", "bytes", "state",
+                               "conv")
              if k in p}
             for p in plan]
         # the recurrent state (and its tails) apart from the slabs of
@@ -1790,6 +1818,8 @@ class GenerationEngine:
         self._replay: List[Optional[List[int]]] = [None] * self.n_slots
         self._slot_pk: List[Optional[tuple]] = [None] * self.n_slots
         self.metrics.set_slots(self.n_slots)
+        self.metrics.set_cache_entries(
+            getattr(self.backend, "cache_entries", 0))
 
         self.memory_report = generation_memory_report(
             model, self.n_slots, self.backend.max_length,
@@ -2515,6 +2545,7 @@ class GenerationEngine:
                 _trace.set_cause(self._dispatch_gen)
                 self._end_turn()
                 handle = be.launch()
+                self.metrics.record_stack_passes(be.passes)
                 if flight:
                     self.metrics.record_step_ahead()
                 # what the step was handed, from the host's copy (no

@@ -344,6 +344,16 @@ class GenerationMetrics:
             "slots whose recurrent state a decode step advanced, summed "
             "over decode steps, where a layer keeps one (state-space "
             "layers): each is read whole and written whole a layer")
+        self._stack_passes = reg.counter(
+            "generation_stack_passes_total",
+            "passes over the layer stack that the launched decode steps "
+            "ran: a step of a model whose stack is applied several times "
+            "a token reads the layers' weights that many times (counted "
+            "by the loop that launches steps; 0 on a lock-step backend)")
+        self._cache_entries = reg.gauge(
+            "generation_cache_entries_per_position",
+            "(pass, layer) pairs that keep a cache entry for every "
+            "position of a slot: passes x the layers that keep columns")
         self._steps_ahead = reg.counter(
             "generation_decode_steps_ahead_total",
             "decode steps launched while the step before them was not "
@@ -370,6 +380,9 @@ class GenerationMetrics:
 
     def set_active_slots(self, n: int) -> None:
         self._active.set(int(n))
+
+    def set_cache_entries(self, n: int) -> None:
+        self._cache_entries.set(int(n))
 
     def record_request(self) -> None:
         self._requests.inc()
@@ -419,6 +432,10 @@ class GenerationMetrics:
     def record_state_slots(self, slots: int) -> None:
         if slots:
             self._state_slots.inc(int(slots))
+
+    def record_stack_passes(self, passes: int) -> None:
+        """A decode step was launched: the passes over the stack it runs."""
+        self._stack_passes.inc(int(passes))
 
     def record_step_ahead(self) -> None:
         self._steps_ahead.inc()
@@ -530,6 +547,8 @@ class GenerationMetrics:
             "index_positions_scored": int(self._index_scored.value()),
             "sparse_positions_read": int(self._sparse_read.value()),
             "state_slots": int(self._state_slots.value()),
+            "stack_passes": int(self._stack_passes.value()),
+            "cache_entries_per_position": int(self._cache_entries.value()),
             "decode_steps_ahead": int(self._steps_ahead.value()),
             "late_slot_steps": int(self._late_slot_steps.value()),
             "param_casts": int(self._param_casts.value()),

@@ -689,3 +689,61 @@ def test_compiled_for_the_described_chip(one_chip):
     (dev,) = one_chip.device_set
     assert dev.platform == "tpu"
     assert "v5" in dev.device_kind.lower()
+
+
+def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip):
+    """``DecoderLM`` with a stack that runs four times a token as the
+    engine builds its decode program, at the ouro-2.6b cell's widths
+    (hidden 2048, 16 heads of 128 with as many key/value heads, MLP 5632,
+    49,152 ids, 5 slots x 896, bfloat16, sandwich norms, the exit gate) and
+    a cut depth (12 of the 48 layers: 48 cache entries a position). What a
+    CPU run cannot show: the passes are one loop around the layers' loop,
+    and no pass's part of a slab is cut out on the way into it: no
+    temporary as large as ONE pass's slab (a quarter of K or of V), no copy
+    of a slab or of a pass's part of one. What the plan does hold is two
+    relayouts of a weight stack that the compiler hoists out of the pass
+    loop (``Wk`` to contraction-minor, ``Wq`` by head: 101 MB each here;
+    a one-pass program of this block makes them a layer at a time)."""
+    import re
+    from types import SimpleNamespace
+
+    from deeplearning4j_tpu.models.decoder_lm import (
+        DecoderConfig,
+        init_cache,
+        init_params,
+    )
+    from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    S, T, L, R = 5, 896, 12, 4
+    cfg = DecoderConfig(
+        vocab_size=49152, d_model=2048, n_heads=16, head_dim=128,
+        v_head_dim=128, rotary_dim=128,
+        attn_kinds={"full": {"n_kv_heads": 16, "rope_theta": 1e6}},
+        layers=[("full", "dense")] * L, dense_width=5632, norm_eps=1e-6,
+        max_length=T, passes=R, sandwich_norm=True, exit_gate=True)
+    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
+                         lambda name: None)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(lambda: init_params(cfg)))
+    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    assert caches[0][0].shape == (R * L, S, 16, 128, T)
+    compiled = be._decode_fn.lower(
+        params, caches, jax.ShapeDtypeStruct((S + 1, 8), jnp.int32,
+                                             sharding=one_chip)).compile()
+    text = compiled.as_text()
+    a_pass = math.prod(caches[0][0].shape) // R        # elements
+    # 0.10 GB planned (the relayouts); a pass's part of K is 0.55 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < a_pass * 2 * 0.6
+    copies = [
+        (name, dims) for name, dims in re.findall(
+            r"%(\S+) = bf16\[([\d,]+)\]\S* copy\(", text)
+        if math.prod(map(int, dims.split(","))) >= a_pass // 2]
+    assert not copies, copies
+    # one loop over the passes around one over the layers: the layer body
+    # is compiled once, whatever the passes
+    assert text.count(" while(") == 2
