@@ -60,6 +60,15 @@ FULL = {
     # of 128 heads x 64 x 128 float32 a layer, one group of B and C
     "ssm_step": dict(heads=128, p=64, n=128, groups=1, slots=64,
                      dtype="float32"),
+    # the cache's column write at the gpt2-large.chat cell's slab (36
+    # layers, 24 slots of 1,024, 20 heads of 64) and at the
+    # ouro-2.6b.reason-looped cell's (192 (pass, layer) entries, 5 slots of
+    # 896, 16 heads of 128)
+    "kv_columns": [
+        dict(entries=36, slots=24, heads=20, head_size=64, t=1024,
+             dtype="bfloat16"),
+        dict(entries=192, slots=5, heads=16, head_size=128, t=896,
+             dtype="bfloat16")],
     # the tiny state-space hybrid of tests/test_granite_lm.py (two Mamba-2
     # layers, a NoPE attention layer, another Mamba-2 layer; experts and a
     # shared expert in each), float32 so that equal tokens mean something
@@ -124,6 +133,11 @@ TINY = {
                         kv_rank=16),
     "ssm_step": dict(heads=8, p=16, n=16, groups=1, slots=3,
                      dtype="float32"),
+    "kv_columns": [
+        dict(entries=2, slots=4, heads=4, head_size=16, t=128,
+             dtype="bfloat16"),
+        dict(entries=12, slots=3, heads=2, head_size=32, t=128,
+             dtype="float32")],
 }
 TINY["hybrid"] = FULL["hybrid"]
 TINY["sparse"] = FULL["sparse"]
@@ -583,11 +597,13 @@ def phase_kernels(platform, size=None):
     """Which Pallas kernels the phases asked for and what each resolved
     to. On the TPU backend a kernel that fell back to its reference is
     a failure: the run would otherwise pass on dense XLA. No phase above
-    serves a latent layer, and ``hybrid_serve`` takes its state-space step
-    at a tiny size, so with ``size`` the two decode kernels are asked for
-    here, at the widths a cell runs them at (each probe holds its kernel
-    to the ``jnp`` form)."""
+    serves a latent layer, ``hybrid_serve`` takes its state-space step at
+    a tiny size and ``lm_serve`` / ``looped_serve`` write small or short
+    slabs, so with ``size`` the two decode kernels and the cache's column
+    write are asked for here, at the widths a cell runs them at (each
+    probe holds its kernel to the ``jnp`` form)."""
     from deeplearning4j_tpu.nn.conf.layers import attention
+    from deeplearning4j_tpu.nn.ops.kv_column_write import kv_column_write_impl
     from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
     from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
     from deeplearning4j_tpu.nn.ops.ssm_decode import ssm_decode_impl
@@ -595,6 +611,8 @@ def phase_kernels(platform, size=None):
     if size is not None:
         latent_decode_impl(**size["latent_core"])
         ssm_decode_impl(**size["ssm_step"])
+        for slab in size["kv_columns"]:
+            kv_column_write_impl(**slab)
     snap = default_kernel_registry().snapshot()
     flash = {repr(k): (None if impl is None
                        else getattr(impl.args[0], "__module__", "?"))

@@ -1793,8 +1793,9 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
     """One token a row: ids_1 (b,) at per-row positions pos (b,) ->
     (logits (b, V), caches, (expert pairs, experts hit)). The caches are
     read inside the layer loop and written after it: a full layer's slab
-    (a latent layer's too) by one in-place column a row at ``pos``
-    (``_put_columns``), a ring by one select at ``pos mod window``
+    (a latent layer's too) by one in-place column a live row at ``pos``
+    (``_put_columns``: one kernel call a slab where the registry admits
+    it), a ring by one select at ``pos mod window``
     (``_put_ring``), a position-major slab of a latent layer with an
     indexer (its latents and, where it owns the indexer, its keys) by one
     in-place row a slot (``_put_rows``). A state-space segment's states
@@ -1828,9 +1829,10 @@ def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
             if cfg.attn_kinds[kind]["latent"]:
                 # as a slab of one head whose "head size" is the entry
                 out.append((_put_columns(slabs[0][:, :, None],
-                                         new[0][:, :, None], wp)[:, :, 0],))
+                                         new[0][:, :, None], wp,
+                                         active)[:, :, 0],))
             elif cfg.attn_kinds[kind]["window"] is None:
-                out.append(tuple(_put_columns(c, n, wp)
+                out.append(tuple(_put_columns(c, n, wp, active)
                                  for c, n in zip(slabs, new)))
             else:
                 out.append(tuple(_put_ring(c, n, q_pos)

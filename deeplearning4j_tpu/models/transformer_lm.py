@@ -31,6 +31,8 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     _layer_norm,
     dense_attention,
 )
+from deeplearning4j_tpu.nn.ops.kv_column_write import kv_column_write_impl
+from deeplearning4j_tpu.nn.ops.ssm_decode import live_table
 
 Array = jax.Array
 
@@ -681,21 +683,37 @@ def _joint_softmax(s_slab, s_new, dtype):
     return (e_slab / z).astype(dtype), (e_new / z).astype(dtype)
 
 
-def _put_columns(slab, new, wp):
+def _put_columns(slab, new, wp, active=None):
     """The after-loop cache write: new (L, b, hn, K, hd), row s's column
-    j → slab[:, s, :, :, wp[s, j]], each as ONE ``dynamic_update_slice``
-    on the (donated) slab — in place, in the slab's own layout. A
-    scatter here picks its layout for the whole slab and brings two
-    slab-sized copies a step back; so does a column that first reads its
-    old value (the (L, 1, hn, hd, 1) read; along the slot axis it also
-    gathers a slot-sharded slab), and a K-wide update clamps its start
-    and shifts the block (PERF.md section 5).
+    j → slab[:, s, :, :, wp[s, j]], in place, in the slab's own layout.
+
+    One column a row (K = 1) goes through ONE Pallas call a slab where
+    the kernel registry admits the slab (``nn/ops/kv_column_write.py``:
+    a TPU, whole blocks of 128 columns, no mesh): it reads and rewrites
+    only the 128-column blocks that hold the positions of the rows
+    ``active`` (b,) bool names (all of them when None); an idle row's
+    column, stale at or past its position, is never read
+    (:func:`_attend_cached`), so it is not written.
+
+    Everywhere else each column is ONE ``dynamic_update_slice`` on the
+    (donated) slab, every row's. A scatter here picks its layout for the
+    whole slab and brings two slab-sized copies a step back; so does a
+    column that first reads its old value (the (L, 1, hn, hd, 1) read;
+    along the slot axis it also gathers a slot-sharded slab), and a
+    K-wide update clamps its start and shifts the block (PERF.md
+    section 5).
 
     ``wp`` is clamped to T-1 by the caller, and a row's columns go
     last-first: every column past the end lands on T-1 BEFORE the
     column that belongs there (there is one whenever the row's first
     position is <= T-1), so columns past the end are dropped, never
     left clipped over a real write."""
+    if new.shape[3] == 1:
+        put = kv_column_write_impl(*slab.shape, slab.dtype)
+        if put is not None:
+            live = (jnp.ones(new.shape[1], bool) if active is None
+                    else active)
+            return put(slab, new[:, :, :, 0], wp[:, 0], live_table(live))
     for s in range(new.shape[1]):
         for j in reversed(range(new.shape[3])):
             slab = jax.lax.dynamic_update_slice(
@@ -728,7 +746,7 @@ def _decode_columns(cfg: TransformerLMConfig, params: Dict[str, Array],
 
 
 def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
-                cache: Dict, ids_1: Array):
+                cache: Dict, ids_1: Array, active: Optional[Array] = None):
     """One autoregressive step: ids_1 (b,) int32 at position cache["pos"]
     → (logits (b, V) fp32, new cache). Attention reads the cached K/V
     instead of re-running the prefix — O(T) decoding vs the O(T²)
@@ -739,7 +757,9 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
     ``cache["pos"]`` may be a scalar (every row at the same position —
     the single-request path: one column written for all rows) or a
     per-row (b,) vector (the continuous-batching engine: each slot
-    carries its own position and its column is written there)."""
+    carries its own position and its column is written there; with
+    ``active`` (b,) bool, only the live rows' need be:
+    :func:`_put_columns`)."""
     pos = cache["pos"]
     per_row = getattr(pos, "ndim", 0) == 1
     x, ks, vs, wp = _decode_columns(
@@ -747,8 +767,8 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
         pos if per_row else jnp.broadcast_to(pos, ids_1.shape))
     with _scope("kv_write"):
         if per_row:  # one in-place column a slot
-            new_k = _put_columns(cache["k"], ks, wp)
-            new_v = _put_columns(cache["v"], vs, wp)
+            new_k = _put_columns(cache["k"], ks, wp, active)
+            new_v = _put_columns(cache["v"], vs, wp, active)
         else:  # one column for all rows
             at = (0, 0, 0, 0, wp[0, 0])
             new_k = jax.lax.dynamic_update_slice(

@@ -13,6 +13,8 @@ The kernel SUBSYSTEM (this package):
   the slots' live lengths;
 - ``ssm_decode`` — a decode step's state-space recurrence, readout and
   in-place update from one read of the live slots' state;
+- ``kv_column_write`` — a decode step's new key/value column a live slot
+  into a time-minor cache slab, block by block in place;
 - ``registry`` — the shared probe-once/fallback/observability contract
   every kernel resolves through (``KernelRegistry``).
 """

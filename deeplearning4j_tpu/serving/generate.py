@@ -539,7 +539,7 @@ class _TransformerBackend:
         def _decode(p, kc, vc, toks, pos, active, t, k, pp, keys):
             trace_hook("generation_decode")
             logits, c = decode_step(cfg, p, {"k": kc, "v": vc, "pos": pos},
-                                    toks)
+                                    toks, active)
             nxt, nkeys = sample_next_rows(logits, _counted(t, active), k,
                                           pp, keys)
             nxt = jnp.where(active, nxt, toks)
@@ -1156,7 +1156,7 @@ class _TransformerAheadBackend(_TransformerBackend):
             active = left > 0
             keys = jax.lax.bitcast_convert_type(rows[:, 4:6], jnp.uint32)
             logits, c = decode_step(cfg, p, {"k": kc, "v": vc, "pos": pos},
-                                    toks)
+                                    toks, active)
             nxt, nkeys = sample_next_rows(
                 logits, _counted(_f32(rows[:, 6]), active), k,
                 _f32(rows[:, 7]), keys)

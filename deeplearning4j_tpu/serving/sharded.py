@@ -329,15 +329,17 @@ def sharded_generation_engine(model, mesh: ServingMesh,
     be.reset = reset_sharded
     _place_slab()
 
-    # prefill is the one program that reaches dense_attention: trace it
-    # with the mesh visible, so the flash route sees axes GSPMD would
-    # partition over and keeps to XLA attention (a Pallas kernel cannot
-    # be partitioned automatically)
-    solo_prefill = be.prefill
+    # the programs are traced with the mesh visible, so the routes to a
+    # Pallas kernel (flash attention in prefill, the cache's column write
+    # in decode) see axes GSPMD would partition over and keep to XLA (a
+    # Mosaic call cannot be partitioned automatically)
+    def on_mesh(solo):
+        def run(*args, **kw):
+            with jax.set_mesh(mesh.mesh):
+                return solo(*args, **kw)
+        return run
 
-    def prefill_on_mesh(*args, **kw):
-        with jax.set_mesh(mesh.mesh):
-            return solo_prefill(*args, **kw)
-
-    be.prefill = prefill_on_mesh
+    for name in ("prefill", "decode", "launch", "verify", "draft"):
+        if hasattr(be, name):
+            setattr(be, name, on_mesh(getattr(be, name)))
     return eng

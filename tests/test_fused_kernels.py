@@ -167,6 +167,28 @@ class TestKernelRegistry:
         kernel_env("fused_lstm", "interpret")
         assert kernel_route("fused_lstm", ("ik",)) is True
 
+    @pytest.mark.parametrize("mode,route", [("0", None), ("1", None),
+                                            ("interpret", True)],
+                             ids=["off", "auto-on-the-cpu", "interpret"])
+    def test_kv_column_write_name_and_switch(self, kernel_env, mode, route):
+        """The cache's column write is a registered name with the
+        conventional switch: 0 kills it, 1 falls back off the TPU (both
+        recorded under the slab's key), ``interpret`` routes to the
+        Pallas interpreter and the probe holds the kernel to the
+        ``dynamic_update_slice`` form."""
+        from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
+
+        assert ENV_FLAGS[kcw.NAME] == "DL4J_TPU_KV_COLUMN_WRITE"
+        kernel_env(kcw.NAME, mode)
+        key = (2, 3, 2, 16, 128, "float32")
+        assert kernel_route(kcw.NAME, key) is route
+        impl = kcw.kv_column_write_impl(*key[:5], jnp.float32)
+        assert (impl is None) == (route is None)
+        verdict = default_kernel_registry().snapshot()[kcw.NAME][repr(key)]
+        assert verdict["enabled"] is (route is True)
+        if route:
+            assert impl.keywords == {"lb": 2, "interpret": True}
+
 
 # ==========================================================================
 # fused LSTM cell

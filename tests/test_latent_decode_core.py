@@ -187,8 +187,10 @@ def test_chip_smoke_asks_for_the_kernel_and_fails_where_it_fell_back(monkeypatch
     assert chip_smoke.FULL["latent_core"] == dict(
         heads=128, width=576, t_c=10240, dtype="bfloat16", kv_rank=512)
 
-    # the phase asks for the state-space decode kernel too
+    # the phase asks for the state-space decode kernel and the cache's
+    # column write too
     monkeypatch.setenv(ENV_FLAGS["ssm_decode_step"], "interpret")
+    monkeypatch.setenv(ENV_FLAGS["kv_column_write"], "interpret")
     default_kernel_registry().reset()
     report = chip_smoke.phase_kernels("tpu", chip_smoke.TINY)
     (verdict,) = report["registry"][latent_decode.NAME].values()

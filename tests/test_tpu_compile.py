@@ -116,6 +116,39 @@ def test_int8_matmul_ffn_width(one_chip, kn):
     assert _custom_calls(text) >= 1
 
 
+@pytest.mark.parametrize("shape", [
+    (36, 24, 20, 64, 1024), (192, 5, 16, 128, 896), (27, 48, 1, 576, 10240),
+    (37, 3, 20, 64, 256)], ids=["chat", "ouro", "latent", "a-block-cut-short"])
+def test_kv_column_write_at_the_cells_slabs(one_chip, shape):
+    """The cache's column write (``nn/ops/kv_column_write.py``) at the
+    gpt2-large.chat cell's slab, the ouro-2.6b cell's and a latent slab as
+    one head of 576, with the block the shapes choose (6, 4 and 14
+    entries), the slab donated: one custom call, the slab aliased through
+    it, and no copy as large as one entry's part of the slab."""
+    import re
+
+    from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
+    from deeplearning4j_tpu.nn.ops.ssm_decode import live_table
+
+    entries, slots, heads, hd, _t = shape
+    lb = kcw.entries_a_block(entries, heads, hd, 2)
+
+    def put(slab, new, wp, active):
+        return kcw.kv_column_write(slab, new, wp, live_table(active),
+                                   lb=lb)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        (shape, BF16), (shape[:4], BF16), ((slots,), jnp.int32),
+        ((slots,), jnp.bool_))]
+    compiled = jax.jit(put, donate_argnums=0).lower(*args).compile()
+    text = compiled.as_text()
+    assert _custom_calls(text) == 1 and kcw.NAME in text
+    assert compiled.memory_analysis().alias_size_in_bytes == math.prod(shape) * 2
+    copies = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+              if math.prod(map(int, dims.split(","))) >= math.prod(shape[1:])]
+    assert not copies, copies
+
+
 def _conv_loss(conv):
     def loss(x, s, t, w):
         y, st = conv(x, s, t, w, True)
@@ -216,6 +249,15 @@ def chat_decode(one_chip):
     )
     from deeplearning4j_tpu.serving.generate import _TransformerAheadBackend
 
+    # this process's backend is the CPU: steer the registry's verdict,
+    # as the chip's probe gives it
+    from deeplearning4j_tpu.models import transformer_lm
+    from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
+
+    def admitted(entries, slots, heads, head_size, t, dtype):
+        return functools.partial(kcw.kv_column_write, lb=kcw.entries_a_block(
+            entries, heads, head_size, jnp.dtype(dtype).itemsize))
+
     L, S, T = 4, 24, 1024
     cfg = TransformerLMConfig(vocab_size=50257, max_length=T, d_model=1280,
                               n_heads=20, n_layers=L,
@@ -233,8 +275,10 @@ def chat_decode(one_chip):
         lambda a: arg(a.shape, a.dtype),
         jax.eval_shape(lambda p: serving_copy(cfg, p), masters))
     slab = arg((L, S, cfg.n_heads, cfg.d_model // cfg.n_heads, T), BF16)
-    compiled = be._decode_fn.lower(
-        params, slab, slab, arg((S + 1, 8), jnp.int32)).compile()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(transformer_lm, "kv_column_write_impl", admitted)
+        compiled = be._decode_fn.lower(
+            params, slab, slab, arg((S + 1, 8), jnp.int32)).compile()
     return compiled, cfg, S, slab.shape
 
 
@@ -255,9 +299,18 @@ def test_decode_program_keeps_the_kv_slab_in_place(chat_decode):
             r"%(\S+) = \w+\[([\d,]+)\]\S* copy\(", compiled.as_text())
         if math.prod(map(int, dims.split(","))) >= layer_slice]
     assert not copies, copies
+    # the after-loop write: one call of the column kernel a slab, the
+    # slabs aliased through the program (since PR 43; 48 updates before)
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "kv_write" in line]
+    assert len(kernels) == 2 and all("kv_column_write" in k for k in kernels)
+    assert not [line for line in text.splitlines()
+                if "dynamic-update-slice(" in line and "kv_write" in line]
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        2 * math.prod(slab_shape) * 2
     # all 24 slots greedy is the common step: its sorts and gathers over
     # 24 x 50257 logits (27 of 49 ms a step on the chip) wait in a branch
-    text = compiled.as_text()
     assert " conditional(" in text and " sort(" in text
     assert not _sampler_work_outside_a_conditional(text, S,
                                                    cfg.vocab_size)
@@ -691,7 +744,8 @@ def test_compiled_for_the_described_chip(one_chip):
     assert "v5" in dev.device_kind.lower()
 
 
-def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip):
+def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip,
+                                                                    monkeypatch):
     """``DecoderLM`` with a stack that runs four times a token as the
     engine builds its decode program, at the ouro-2.6b cell's widths
     (hidden 2048, 16 heads of 128 with as many key/value heads, MLP 5632,
@@ -713,6 +767,20 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip):
         init_params,
     )
     from deeplearning4j_tpu.serving.generate import _DecoderBackend
+
+    from deeplearning4j_tpu.models import transformer_lm
+    from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
+
+    asked = []
+
+    def admitted(entries, slots, heads, head_size, t, dtype):
+        asked.append((entries, slots, heads, head_size, t,
+                      jnp.dtype(dtype).name))
+        return functools.partial(kcw.kv_column_write, lb=kcw.entries_a_block(
+            entries, heads, head_size, jnp.dtype(dtype).itemsize))
+
+    # this process's backend is the CPU: steer the registry's verdict
+    monkeypatch.setattr(transformer_lm, "kv_column_write_impl", admitted)
 
     S, T, L, R = 5, 896, 12, 4
     cfg = DecoderConfig(
@@ -747,3 +815,11 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip):
     # one loop over the passes around one over the layers: the layer body
     # is compiled once, whatever the passes
     assert text.count(" while(") == 2
+    # the after-loop write: one call of the column kernel a slab (since
+    # PR 43; an update a slot and slab before)
+    assert set(asked) == {(R * L, S, 16, 128, T, "bfloat16")}
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "kv_write" in line]
+    assert len(kernels) == 2 and all(kcw.NAME in k for k in kernels)
+    assert not [line for line in text.splitlines()
+                if "dynamic-update-slice(" in line and "kv_write" in line]
