@@ -69,6 +69,15 @@ FULL = {
              dtype="bfloat16"),
         dict(entries=192, slots=5, heads=16, head_size=128, t=896,
              dtype="bfloat16")],
+    # decode attention over the live tiles at the gpt2-large.chat cell's
+    # slabs (24 slots of 1,024, 20 heads of 64) and at the
+    # granite-4.0-h-small-ep2 cell's (64 slots of 4,096, 8 key/value heads
+    # of 128 under 32 query heads): two probes in one process
+    "decode_attn": [
+        dict(slots=24, hkv=20, grp=1, hd=64, vd=64, t=1024,
+             dtype="bfloat16"),
+        dict(slots=64, hkv=8, grp=4, hd=128, vd=128, t=4096,
+             dtype="bfloat16")],
     # the tiny state-space hybrid of tests/test_granite_lm.py (two Mamba-2
     # layers, a NoPE attention layer, another Mamba-2 layer; experts and a
     # shared expert in each), float32 so that equal tokens mean something
@@ -138,6 +147,10 @@ TINY = {
              dtype="bfloat16"),
         dict(entries=12, slots=3, heads=2, head_size=32, t=128,
              dtype="float32")],
+    # (slabs this small are under the kernel's floor: nothing resolves)
+    "decode_attn": [
+        dict(slots=4, hkv=4, grp=1, hd=16, vd=16, t=128, dtype="bfloat16"),
+        dict(slots=3, hkv=2, grp=4, hd=32, vd=16, t=128, dtype="float32")],
 }
 TINY["hybrid"] = FULL["hybrid"]
 TINY["sparse"] = FULL["sparse"]
@@ -599,10 +612,11 @@ def phase_kernels(platform, size=None):
     a failure: the run would otherwise pass on dense XLA. No phase above
     serves a latent layer, ``hybrid_serve`` takes its state-space step at
     a tiny size and ``lm_serve`` / ``looped_serve`` write small or short
-    slabs, so with ``size`` the two decode kernels and the cache's column
-    write are asked for here, at the widths a cell runs them at (each
-    probe holds its kernel to the ``jnp`` form)."""
+    slabs, so with ``size`` the three decode kernels and the cache's
+    column write are asked for here, at the widths a cell runs them at
+    (each probe holds its kernel to the ``jnp`` form)."""
     from deeplearning4j_tpu.nn.conf.layers import attention
+    from deeplearning4j_tpu.nn.ops.decode_attention import decode_attention_impl
     from deeplearning4j_tpu.nn.ops.kv_column_write import kv_column_write_impl
     from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
     from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
@@ -613,6 +627,8 @@ def phase_kernels(platform, size=None):
         ssm_decode_impl(**size["ssm_step"])
         for slab in size["kv_columns"]:
             kv_column_write_impl(**slab)
+        for slabs in size["decode_attn"]:
+            decode_attention_impl(**slabs)
     snap = default_kernel_registry().snapshot()
     flash = {repr(k): (None if impl is None
                        else getattr(impl.args[0], "__module__", "?"))

@@ -110,6 +110,10 @@ from deeplearning4j_tpu.nn.conf.layers.moe import (
     moe_dropless_ffn,
     sigmoid_topk_route,
 )
+from deeplearning4j_tpu.nn.ops.decode_attention import (
+    decode_attention_impl,
+    live_tiles,
+)
 from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
 from deeplearning4j_tpu.nn.ops.ssm_decode import live_table, ssm_decode_impl
 
@@ -1352,6 +1356,21 @@ def _latent_kernel_admits(cfg: DecoderConfig, kind: str, slab: Array) -> bool:
         latent["kv_rank"]) is not None
 
 
+def _attention_kernel(cfg: DecoderConfig, kind: str, k_slab: Array,
+                      v_slab: Array):
+    """The live-tile kernel for a decode step over ``k_slab`` (layers, b,
+    hkv, hd, Tc) and ``v_slab`` of attention kind ``kind``, and its tile:
+    a kind that keeps every position (no window) and has no sink, and the
+    kernel registry's verdict for these shapes (a TPU, the probe passed,
+    a layer's K + V worth a call); None where the einsums serve."""
+    ak = cfg.attn_kinds[kind]
+    if ak["latent"] or ak["ssm"] or ak["window"] is not None or ak["sink"]:
+        return None
+    _layers, b, hkv, hd, t = k_slab.shape
+    return decode_attention_impl(b, hkv, cfg.n_heads // hkv, hd,
+                                 v_slab.shape[3], t, k_slab.dtype)
+
+
 def _ssm_kernel_admits(cfg: DecoderConfig, kind: str, states: Array) -> bool:
     """Whether a decode step over ``states`` (layers, b, state size, heads x
     head size) of a state-space kind goes through the live-slot kernel:
@@ -1370,7 +1389,10 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
     bp holds ONE layer's leaves. The queries attend, under one softmax,
     to the layer's own Tq keys and, if ``cache`` = (kc (b, hkv, hd, Tc),
     vc (b, hkv, vd, Tc), c_pos (b, Tc)) is given, to the cache columns,
-    each of which holds absolute position ``c_pos`` (< 0: nothing). The
+    each of which holds absolute position ``c_pos`` (< 0: nothing); or, for
+    the live-tile kernel of a decode step, ``cache`` = (the segment's K
+    slabs, its V slabs, layer, (the kernel, its walk over the rows' live
+    tiles)) (``nn/ops/decode_attention.py``). The
     cache is only READ: the layer's new (b, hkv, Tq, hd) keys and
     (b, hkv, Tq, vd) values are returned for the caller to drop (full
     forward), write whole (prefill) or append (decode). A latent kind's
@@ -1417,7 +1439,17 @@ def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
         v = (a_in @ bp["Wv"]).reshape(b, tq, hkv, vd)
         q = _rotate(q, q_pos, cfg.rotary_dim, ak["rope_theta"])
         k = _rotate(k, q_pos, cfg.rotary_dim, ak["rope_theta"])
-        if (cache is None and window is None and not ak["sink"]
+        if cache is not None and len(cache) == 4:
+            k_slab, v_slab, at, (core, table) = cache
+            kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            # query head i reads key/value head i // grp
+            o = core(q.reshape(b, hkv, grp, hd), kh[:, :, 0], vh[:, :, 0],
+                     k_slab, v_slab, at, table,
+                     scale=softmax_scale(cfg, kind))
+            if cfg.value_scale != 1.0:
+                o = o * cfg.value_scale
+            o = o.reshape(b, tq, hq * vd).astype(x.dtype)
+        elif (cache is None and window is None and not ak["sink"]
                 and hq * tq * tq * 4 > BLOCKED_SCORE_BYTES):
             kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             o = _causal_blocked(
@@ -1498,7 +1530,9 @@ def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     (layers, b, width, Tc), with ``c_pos`` the position map of each
     attention kind (a latent segment's decode step where the kernel
     registry admits it: the slab whole, the layer's index and the rows'
-    lengths instead). A state-space segment's cache is (states, tails),
+    lengths instead; a full-attention segment's likewise: K and V whole,
+    the layer's index and the walk over the rows' live tiles). A
+    state-space segment's cache is (states, tails),
     WRITTEN in the loop: the two arrays go through the scan as its carry,
     each layer reading and writing its own index in place, and come back
     whole in the cache's stead (stacked as a scan's output they would be a
@@ -1598,18 +1632,26 @@ def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
         # nor is a latent segment's slab where the decode kernel reads it
         # (a custom call's operand is made whole: the scan's slice of the
         # slab would be copied a layer), by the rows' lengths: a row that
-        # is not active has none
-        whole = lengths = every = None
-        if (kv is not None and x.shape[1] == 1
-                and _latent_kernel_admits(cfg, kind, kv[0])):
-            (whole,), kv = kv, None
+        # is not active has none; nor are a full layer's K and V where the
+        # live-tile kernel reads them, by a walk over the rows' live tiles
+        # made here, once for the segment's layers
+        whole = walk = every = None
+        if kv is not None and x.shape[1] == 1:
             lengths = q_pos[:, 0] if token_mask is None else jnp.where(
                 token_mask[:, 0], q_pos[:, 0], 0)
-        elif kv is not None and r is not None:
+            if _latent_kernel_admits(cfg, kind, kv[0]):
+                whole, kv, walk = tuple(kv), None, lengths
+            elif len(kv) == 2:
+                core = _attention_kernel(cfg, kind, *kv)
+                if core is not None:
+                    whole, kv = tuple(kv), None
+                    walk = (core[0], live_tiles(lengths, whole[0].shape[-1],
+                                                core[1]))
+        if whole is None and kv is not None and r is not None:
             every, kv = tuple(kv), None  # all passes' entries: by index
 
         def body(carry, xs, kind=kind, ffn=ffn, stacks=stacks, whole=whole,
-                 lengths=lengths, entry=entry):
+                 walk=walk, entry=entry):
             x, every = carry
             bp, kv, layer = xs
             if every is not None:
@@ -1617,7 +1659,7 @@ def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
                     c, entry(layer), 0, keepdims=False) for c in every)
             cache = None if kv is None else (*kv, c_pos[kind])
             if whole is not None:
-                cache = (whole, entry(layer), lengths)
+                cache = (*whole, entry(layer), walk)
             x, knew, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
                                     q_pos, cache, token_mask,
                                     layer if stacks else None)

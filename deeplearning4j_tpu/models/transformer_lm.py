@@ -31,6 +31,10 @@ from deeplearning4j_tpu.nn.conf.layers.attention import (
     _layer_norm,
     dense_attention,
 )
+from deeplearning4j_tpu.nn.ops.decode_attention import (
+    decode_attention_impl,
+    live_tiles,
+)
 from deeplearning4j_tpu.nn.ops.kv_column_write import kv_column_write_impl
 from deeplearning4j_tpu.nn.ops.ssm_decode import live_table
 
@@ -340,7 +344,10 @@ def _attend_cached(kc, vc, live, causal):
     padding) are never read and are overwritten as the row advances:
     rolling back is free. The math is row-independent, so a row decoded
     among other slots is bit-identical to the same row decoded alone
-    (parity-asserted in tests/test_generate.py).
+    (parity-asserted in tests/test_generate.py). The engine's one-token
+    step takes :func:`_attend_live_tiles` in this core's stead where the
+    kernel registry admits the slabs (:func:`_decode_columns`); this is
+    the reference it is held to, and every other caller's path.
 
     MoE: the block routes only the b * K tokens of the step (per-step
     capacity) where the full forward competes all window tokens, so when
@@ -363,6 +370,25 @@ def _attend_cached(kc, vc, live, causal):
                        preferred_element_type=jnp.float32)
         o = o + jnp.einsum("bhkj,bhjd->bhkd", p_new, vn,
                            preferred_element_type=jnp.float32)
+        return o, (kn, vn)
+
+    return attend
+
+
+def _attend_live_tiles(core, k_slab, v_slab, layer, table):
+    """:func:`_attend_cached` for ONE query a row (K = 1) through the
+    kernel ``core`` (``nn/ops/decode_attention.py``): layer ``layer`` of
+    the whole stacked slabs (L, b, hn, hd, T), read where they lie and only
+    in the column tiles ``table`` (``live_tiles`` of the rows' lengths)
+    names, under the same joint softmax with the step's own key and value.
+    Hands out what :func:`_attend_cached` does."""
+    kvd = k_slab.dtype
+
+    def attend(q, k, v):
+        kn, vn = k.astype(kvd), v.astype(kvd)
+        # (b, hn, 1, hd) is (slots, key heads, one query head each, hd)
+        o = core(q, kn[:, :, 0], vn[:, :, 0], k_slab, v_slab, layer, table,
+                 scale=1.0 / math.sqrt(q.shape[-1]))
         return o, (kn, vn)
 
     return attend
@@ -722,16 +748,43 @@ def _put_columns(slab, new, wp, active=None):
 
 
 def _decode_columns(cfg: TransformerLMConfig, params: Dict[str, Array],
-                    cache: Dict, ids_k: Array, pos: Array):
+                    cache: Dict, ids_k: Array, pos: Array, lengths=None):
     """The layer loop of cached decoding: ids_k (b, K) with row s's
     column j at position pos[s] + j (pos (b,)) → (x (b, K, d) before the
     head, the layers' new keys and values (L, b, hn, K, hd), the clamped
     write positions (b, K)). The cache is only read
-    (:func:`_attend_cached`); the caller writes."""
-    T = cache["k"].shape[4]
+    (:func:`_attend_cached`); the caller writes.
+
+    ``lengths`` (b,), the columns each row reads (its position; 0 for a
+    row that does not stream), comes with the engine's one-token step:
+    where the kernel registry admits the slabs
+    (``nn/ops/decode_attention.py``: a TPU, a tile that divides T, no
+    mesh, a layer's K + V worth a call) the layers attend through ONE
+    Pallas call each that reads the live column tiles only
+    (:func:`_attend_live_tiles`). The slabs are then closed over whole and
+    the loop scans the layer's index (a custom call cannot read through a
+    scan's slice as a fusion does: it would be handed a copy of it), and
+    the walk over the live tiles is made once, here, for every layer."""
+    L, b, hn, hd, T = cache["k"].shape
     K = ids_k.shape[1]
     cols = pos[:, None] + jnp.arange(K)[None, :]  # (b, K) absolute pos
     x = _embed(cfg, params, ids_k, cols)
+    core = None
+    if lengths is not None and K == 1:
+        core = decode_attention_impl(b, hn, 1, hd, hd, T, cache["k"].dtype)
+    if core is not None:
+        attend_tiles, tile = core
+        table = live_tiles(lengths, T, tile)
+
+        def at_layer(x, xs):
+            bp, layer = xs
+            x, kv, _aux = _block(cfg, bp, x, _attend_live_tiles(
+                attend_tiles, cache["k"], cache["v"], layer, table))
+            return x, kv
+
+        x, (ks, vs) = jax.lax.scan(
+            at_layer, x, (params["blocks"], jnp.arange(L, dtype=jnp.int32)))
+        return x, ks, vs, jnp.minimum(cols, T - 1)
     live = (jnp.arange(T)[None, :] < pos[:, None])[:, None, None, :]
     causal = jnp.arange(K)[None, :] <= jnp.arange(K)[:, None]  # (K, K)
 
@@ -758,13 +811,19 @@ def decode_step(cfg: TransformerLMConfig, params: Dict[str, Array],
     the single-request path: one column written for all rows) or a
     per-row (b,) vector (the continuous-batching engine: each slot
     carries its own position and its column is written there; with
-    ``active`` (b,) bool, only the live rows' need be:
-    :func:`_put_columns`)."""
+    ``active`` (b,) bool, only the live rows' need be written,
+    :func:`_put_columns`, and only theirs are read where the layers
+    attend through the live-tile kernel, :func:`_decode_columns`)."""
     pos = cache["pos"]
     per_row = getattr(pos, "ndim", 0) == 1
-    x, ks, vs, wp = _decode_columns(
-        cfg, params, cache, ids_1[:, None],
-        pos if per_row else jnp.broadcast_to(pos, ids_1.shape))
+    if per_row:  # the engine's step: a row reads what lies behind it
+        x, ks, vs, wp = _decode_columns(
+            cfg, params, cache, ids_1[:, None], pos,
+            pos if active is None else jnp.where(active, pos, 0))
+    else:
+        x, ks, vs, wp = _decode_columns(
+            cfg, params, cache, ids_1[:, None],
+            jnp.broadcast_to(pos, ids_1.shape))
     with _scope("kv_write"):
         if per_row:  # one in-place column a slot
             new_k = _put_columns(cache["k"], ks, wp, active)

@@ -15,6 +15,8 @@ The kernel SUBSYSTEM (this package):
   in-place update from one read of the live slots' state;
 - ``kv_column_write`` — a decode step's new key/value column a live slot
   into a time-minor cache slab, block by block in place;
+- ``decode_attention`` — a decode step's attention over the K and V
+  slabs where they lie, the live column tiles of the live slots only;
 - ``registry`` — the shared probe-once/fallback/observability contract
   every kernel resolves through (``KernelRegistry``).
 """

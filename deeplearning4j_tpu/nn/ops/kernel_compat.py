@@ -15,6 +15,11 @@
    deterministic, so only failures carrying the crash signature are
    retried — a plain lowering error (or any failure on a non-TPU
    backend) still costs exactly one attempt.
+
+3. ``mesh_in_sight``: a Mosaic call cannot be partitioned automatically,
+   so a kernel whose operand may be sharded declines where the ambient
+   mesh (multi-device callers make theirs visible, ``jax.set_mesh``) has
+   an axis larger than one that is not manual (``shard_map``).
 """
 
 from __future__ import annotations
@@ -29,6 +34,12 @@ PRECISION = jax.lax.Precision.DEFAULT
 #: substrings identifying a compile service falling over, as opposed
 #: to a deterministic Mosaic lowering reject
 _TRANSIENT_MARKERS = ("remote_compile", "tpu_compile_helper", "HTTP 500")
+
+
+def mesh_in_sight() -> bool:
+    ambient = jax.sharding.get_abstract_mesh()
+    return any(size > 1 and name not in ambient.manual_axes
+               for name, size in ambient.shape.items())
 
 
 def is_transient_compile_error(e: Exception) -> bool:

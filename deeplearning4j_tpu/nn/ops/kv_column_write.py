@@ -43,6 +43,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deeplearning4j_tpu.nn.ops.kernel_compat import mesh_in_sight
+
 NAME = "kv_column_write"
 #: columns of time a block spans: one tile of lanes
 LANES = 128
@@ -198,9 +200,7 @@ def kv_column_write_impl(entries: int, slots: int, heads: int,
     ``jax.set_mesh``)."""
     from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
 
-    ambient = jax.sharding.get_abstract_mesh()
-    if t % LANES or any(size > 1 and name not in ambient.manual_axes
-                        for name, size in ambient.shape.items()):
+    if t % LANES or mesh_in_sight():
         return None
     dtype = jnp.dtype(dtype)
     key = (int(entries), int(slots), int(heads), int(head_size), int(t),

@@ -474,6 +474,10 @@ class _TransformerBackend:
     ``_TransformerAheadBackend``, below, whose programs
     ``_build_programs`` replaces."""
 
+    #: every layer keeps keys and values a position: the engine counts
+    #: the positions a step's slots have behind them
+    attends = True
+
     kind = "transformer"
 
     def __init__(self, model, n_slots: int, max_length: Optional[int],
@@ -924,6 +928,11 @@ class _DecoderBackend:
         #: some layer keeps a latent cache: the engine counts the
         #: positions a launched step's slots have behind them
         self.latent = any(k["latent"] for k in cfg.attn_kinds.values())
+        #: some layer keeps keys and values a position and reads all that
+        #: lie behind a slot (no window): the engine counts them likewise
+        self.attends = any(
+            not k["latent"] and not k["ssm"] and k["window"] is None
+            for k in cfg.attn_kinds.values())
         #: the positions a latent layer's indexer keeps for its attention
         #: (0: no layer selects): the engine counts the positions a
         #: launched step's slots score and those they select
@@ -1310,6 +1319,8 @@ class _RecurrentBackend:
     outputs (asserted in tests), zero steady-state recompiles."""
 
     kind = "recurrent"
+    #: a carried state, no keys and values a position: nothing to count
+    attends = False
 
     def __init__(self, model, n_slots: int, max_length: Optional[int],
                  prefill_buckets: Optional[Sequence[int]], trace_hook,
@@ -1645,6 +1656,7 @@ class _Launched(NamedTuple):
     drawn: bool             #: the sampler's branches, as ``_step`` counts
     filtered: bool
     latent_positions: int   #: what ``record_latent_positions`` takes
+    attn_positions: int     #: what ``record_attn_positions`` takes
     selected_positions: int  #: ``record_selection``'s second (the first
     #: is ``latent_positions``: an indexer scores every position behind)
     state_slots: int        #: what ``record_state_slots`` takes
@@ -2465,6 +2477,10 @@ class GenerationEngine:
             else:
                 self.metrics.record_decode_step(dt, n_active, drawn,
                                                 filtered)
+            if self.backend.attends:
+                # before this step moved them: what its slots read
+                self.metrics.record_attn_positions(
+                    int(self._pos[self._active].sum()))
             if dt * 1e3 > self.stall_ms:
                 _flight.record("decode_stall", wall_ms=round(dt * 1e3, 1),
                                active=n_active)
@@ -2559,6 +2575,7 @@ class GenerationEngine:
                     self._dispatch_gen, t0, ran, list(self._slots),
                     drawn, filtered,
                     int(self._pos[ran].sum()) if be.latent else 0,
+                    int(self._pos[ran].sum()) if be.attends else 0,
                     int(np.minimum(self._pos[ran], be.index_topk).sum())
                     if be.index_topk else 0,
                     int(ran.sum()) if be.keeps_state else 0)))
@@ -2625,6 +2642,7 @@ class GenerationEngine:
                                             step.filtered)
             self.metrics.record_moe_step(*counts)
             self.metrics.record_latent_positions(step.latent_positions)
+            self.metrics.record_attn_positions(step.attn_positions)
             if be.index_topk:
                 self.metrics.record_selection(step.latent_positions,
                                               step.selected_positions)

@@ -329,6 +329,12 @@ class GenerationMetrics:
             "generation_latent_positions_read_total",
             "cache positions the active slots had behind them, summed "
             "over decode steps, where a layer keeps a latent cache")
+        self._attn_positions = reg.counter(
+            "generation_attn_positions_read_total",
+            "cache positions the active slots had behind them, summed "
+            "over decode steps, where a layer keeps keys and values a "
+            "position: what attention over the live tiles reads, against "
+            "slots x slot length a step for attention over the whole slab")
         self._index_scored = reg.counter(
             "generation_index_positions_scored_total",
             "cache positions the active slots had behind them, summed "
@@ -421,6 +427,10 @@ class GenerationMetrics:
     def record_latent_positions(self, positions: int) -> None:
         if positions:
             self._latent_positions.inc(int(positions))
+
+    def record_attn_positions(self, positions: int) -> None:
+        if positions:
+            self._attn_positions.inc(int(positions))
 
     def record_selection(self, scored: int, read: int) -> None:
         """A decode step of a model whose attention reads a selection:
@@ -544,6 +554,7 @@ class GenerationMetrics:
             "moe_pairs_local": int(self._moe_pairs.value()),
             "moe_experts_hit": int(self._moe_hit.value()),
             "latent_positions_read": int(self._latent_positions.value()),
+            "attn_positions_read": int(self._attn_positions.value()),
             "index_positions_scored": int(self._index_scored.value()),
             "sparse_positions_read": int(self._sparse_read.value()),
             "state_slots": int(self._state_slots.value()),

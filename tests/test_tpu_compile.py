@@ -149,6 +149,44 @@ def test_kv_column_write_at_the_cells_slabs(one_chip, shape):
     assert not copies, copies
 
 
+@pytest.mark.parametrize("shape", [
+    (4, 24, 20, 1, 64, 64, 1024), (1, 64, 8, 4, 128, 128, 4096),
+    (1, 64, 4, 16, 192, 128, 1536)], ids=["chat", "granite", "mimo"])
+def test_decode_attention_at_the_cells_slabs(one_chip, shape):
+    """Decode attention over the live tiles (``nn/ops/decode_attention.py``)
+    at the gpt2-large.chat cell's slabs (a cut depth), the
+    granite-4.0-h-small-ep2 cell's and the mimo-v2.5-ep16 cell's, with the
+    tile the slot length chooses, all layers' calls in one loop over the
+    layer's index with the slabs closed over whole: one custom call in the
+    loop's body and no copy as large as one layer's part of a slab."""
+    import re
+
+    from deeplearning4j_tpu.nn.ops import decode_attention as da
+
+    layers, slots, hkv, grp, hd, vd, t = shape
+    tile = da.tile_for(t)
+
+    def attend(q, k_new, v_new, k_slab, v_slab, lengths):
+        table = da.live_tiles(lengths, t, tile)
+
+        def layer(carry, i):
+            return carry, da.decode_attention(
+                q, k_new, v_new, k_slab, v_slab, i, table, scale=0.125,
+                tile=tile)
+
+        return jax.lax.scan(layer, 0, jnp.arange(layers, dtype=jnp.int32))[1]
+
+    text = _compile(
+        attend, one_chip, ((slots, hkv, grp, hd), BF16),
+        ((slots, hkv, hd), BF16), ((slots, hkv, vd), BF16),
+        ((layers, slots, hkv, hd, t), BF16),
+        ((layers, slots, hkv, vd, t), BF16), ((slots,), jnp.int32))
+    assert tile == 128 and _custom_calls(text) == 1 and da.NAME in text
+    copies = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+              if math.prod(map(int, dims.split(","))) >= slots * hkv * vd * t]
+    assert not copies, copies
+
+
 def _conv_loss(conv):
     def loss(x, s, t, w):
         y, st = conv(x, s, t, w, True)
@@ -231,6 +269,15 @@ def _sampler_work_outside_a_conditional(text: str, slots: int,
     return found
 
 
+def _attends(slots, hkv, grp, hd, vd, t, dtype):
+    """``decode_attention_impl`` as the chip's probe answers it: this
+    process's backend is the CPU, so the compiles below steer the verdict."""
+    from deeplearning4j_tpu.nn.ops import decode_attention as da
+
+    tile = da.tile_for(t)
+    return functools.partial(da.decode_attention, tile=tile), tile
+
+
 @pytest.fixture(scope="module")
 def chat_decode(one_chip):
     """The serving engine's decode program (``_decode``) as the chat
@@ -277,6 +324,7 @@ def chat_decode(one_chip):
     slab = arg((L, S, cfg.n_heads, cfg.d_model // cfg.n_heads, T), BF16)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(transformer_lm, "kv_column_write_impl", admitted)
+        patch.setattr(transformer_lm, "decode_attention_impl", _attends)
         compiled = be._decode_fn.lower(
             params, slab, slab, arg((S + 1, 8), jnp.int32)).compile()
     return compiled, cfg, S, slab.shape
@@ -309,6 +357,14 @@ def test_decode_program_keeps_the_kv_slab_in_place(chat_decode):
                 if "dynamic-update-slice(" in line and "kv_write" in line]
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         2 * math.prod(slab_shape) * 2
+    # the layers attend through the live-tile kernel (since PR 44; two
+    # whole-slab einsums a layer before): one call in the layer loop's
+    # body, under the block's ``attn`` scope, and no score temporary of
+    # slots x heads x T
+    attends = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "decode_attention" in line]
+    assert len(attends) == 1 and "/attn/" in attends[0], attends
+    assert not re.findall(rf"f32\[{S},{cfg.n_heads},1,{slab_shape[-1]}\]", text)
     # all 24 slots greedy is the common step: its sorts and gathers over
     # 24 x 50257 logits (27 of 49 ms a step on the chip) wait in a branch
     assert " conditional(" in text and " sort(" in text
@@ -350,7 +406,8 @@ def test_decode_program_casts_no_weights(chat_decode):
     assert not {("f32", m) for m in matrices} & arguments
 
 
-def test_decoder_decode_program_compiles_at_published_widths(one_chip):
+def test_decoder_decode_program_compiles_at_published_widths(one_chip,
+                                                             monkeypatch):
     """``DecoderLM``'s decode program as the engine builds it, at the
     mimo-v2.5-ep16 cell's widths (hidden 4096, 64 heads of 192/128, 4 and
     8 key/value heads, 16 of 256 experts of 2048, 64 slots x 1536,
@@ -359,10 +416,13 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     Mosaic kernel on the TPU and refuses the package-wide "highest"
     precision (``moe_dropless_ffn`` pins DEFAULT for bfloat16 operands);
     the rings and the full layer's slab stay in place (no temporary as
-    large as a slab, no copy of one)."""
+    large as a slab, no copy of one), the full layer attending through the
+    live-tile kernel (``nn/ops/decode_attention.py``, the verdict steered:
+    16 query heads a key head, keys of 192 and values of 128)."""
     import re
     from types import SimpleNamespace
 
+    from deeplearning4j_tpu.models import decoder_lm
     from deeplearning4j_tpu.models.decoder_lm import (
         DecoderConfig,
         init_cache,
@@ -370,6 +430,7 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     )
     from deeplearning4j_tpu.serving.generate import _DecoderBackend
 
+    monkeypatch.setattr(decoder_lm, "decode_attention_impl", _attends)
     S, T = 64, 1536
     cfg = DecoderConfig(
         vocab_size=19072, d_model=4096, n_heads=64, head_dim=192,
@@ -399,6 +460,9 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
         params, caches, arg((S + 1, 8), jnp.int32)).compile()
     text = compiled.as_text()
     assert "ragged-dot" in text and _custom_calls(text) >= 3
+    attends = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "attn_full" in line]
+    assert len(attends) == 1 and "decode_attention" in attends[0], attends
     ring = math.prod(caches[1][1].shape)  # the smallest slab: a ring's V
     # 275 MB planned: a 100 MB relayout of Wq, the temporaries of the
     # sampler's filtering branch; the four slabs are 335 MB, and a
@@ -656,6 +720,8 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
                                  tile=ssm_decode._tile(heads, p, n, groups))
 
     monkeypatch.setattr(decoder_lm, "ssm_decode_impl", admitted)
+    # and the attention layer reads its slab through the live-tile kernel
+    monkeypatch.setattr(decoder_lm, "decode_attention_impl", _attends)
 
     S, T = 64, 4096
     ssm = {"ssm": dict(n_heads=128, head_dim=64, d_state=128, n_groups=1,
@@ -717,6 +783,9 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
     assert all(ssm_decode.NAME in line for line in kernels)
     assert not re.search(rf"f32\[(\d+,)?{S},128,8192\]\S* (fusion|select)\(",
                          text)
+    attends = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "attn_full" in line]
+    assert len(attends) == 1 and "decode_attention" in attends[0], attends
     # 32 MB planned; one layer's state over the slots would be 268 MB
     assert plan.temp_size_in_bytes < 0.1e9
     assert abs(plan.alias_size_in_bytes - cache_bytes) < 1e6
@@ -768,7 +837,7 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip,
     )
     from deeplearning4j_tpu.serving.generate import _DecoderBackend
 
-    from deeplearning4j_tpu.models import transformer_lm
+    from deeplearning4j_tpu.models import decoder_lm, transformer_lm
     from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
 
     asked = []
@@ -779,8 +848,9 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip,
         return functools.partial(kcw.kv_column_write, lb=kcw.entries_a_block(
             entries, heads, head_size, jnp.dtype(dtype).itemsize))
 
-    # this process's backend is the CPU: steer the registry's verdict
+    # this process's backend is the CPU: steer the registry's verdicts
     monkeypatch.setattr(transformer_lm, "kv_column_write_impl", admitted)
+    monkeypatch.setattr(decoder_lm, "decode_attention_impl", _attends)
 
     S, T, L, R = 5, 896, 12, 4
     cfg = DecoderConfig(
@@ -823,3 +893,8 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip,
     assert len(kernels) == 2 and all(kcw.NAME in k for k in kernels)
     assert not [line for line in text.splitlines()
                 if "dynamic-update-slice(" in line and "kv_write" in line]
+    # the layers attend through the live-tile kernel (since PR 44), the
+    # slabs whole from the pass loop's carry: one call in the layer body
+    attends = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "attn_full" in line]
+    assert len(attends) == 1 and "decode_attention" in attends[0], attends
