@@ -3,93 +3,65 @@
 
 ``TransformerLM`` is one GPT-2 block scanned L times. Today's open
 decoders are not that: RMSNorm, no biases, rotary positions on part of a
-head (plain or with scaled frequencies), fewer key/value heads than query
-heads, query/key heads wider than value heads, window layers (with a
-learned softmax sink) among full layers, latent attention (low-rank
-queries and one compressed key/value entry a position shared by all
-heads), a leading dense layer and then expert layers of which a chip
-holds its share, routed by sigmoid or by group-limited softmax scores,
-with or without a shared expert. Here all of that is configuration:
+head, fewer key/value heads than query heads, query/key heads wider than
+value heads, window layers (with a learned softmax sink) among full
+layers, latent attention (alone or over an indexer's selection),
+state-space layers, a leading dense layer and then expert layers of which
+a chip holds its share, with or without a shared expert, a stack that runs
+several times a token over one set of weights. Here all of that is
+configuration (:class:`DecoderConfig`):
 
-- ``attn_kinds`` names the kinds of MIXER, a layer's first half: the
-  kinds of attention layer (key/value heads, rotary base and scaling,
-  window, sink, or the ranks of a latent kind) and, since a layer need
-  not attend at all, the state-space kind (``"ssm"``: a Mamba-2 mixer's
-  heads, head size, state size, groups, convolution width, expansion and
-  chunk); ``layers`` gives each layer's mixer kind and FFN kind
-  (``"dense"`` / ``"experts"``), in order;
-- consecutive layers of one (mixer, FFN) pair form a SEGMENT whose
-  parameters are stacked and scanned; the stack is the list of segments;
+- ``attn_kinds`` names the kinds of MIXER, a layer's first half, and
+  ``layers`` each layer's mixer kind and FFN kind; consecutive layers of
+  one pair form a SEGMENT whose parameters are stacked and scanned;
 - ONE block function (:func:`block`) serves the full forward, prefill
-  and decode. It attends over the step's own keys and, when given one,
-  over a cache described by the absolute position each of its columns
-  holds, so a full layer's slab and a window layer's ring are the same
-  code with different position maps;
-- the cache is sized by layer kind (:meth:`DecoderConfig.cache_plan`):
-  the slot's length for a full layer, a ring of ``window`` columns for a
-  window layer, K and V by head; ONE slab of ``kv_rank + rotary_dim``
-  values a position for a latent layer; T-minor and written in place
-  after the layer loop, as ``TransformerLM``'s slab is
-  (``_put_columns``);
-- a latent layer decodes ABSORBED (the queries are taken into the latent
-  space, so a step reads each cached position once for all heads and
-  never expands K or V) and prefills EXPANDED, by blocks of queries and
-  keys under one running softmax (``_causal_blocked``): no program plans
-  a score tensor of a whole bucket;
-- a latent kind with an INDEXER attends to a selection: a layer that
-  owns one scores every position behind a query with a few small heads
-  against ONE cached key a position (its second slab), keeps the
-  ``topk`` largest scores exactly (:func:`_select_mask`) and attends,
-  under the same softmax, to those positions alone; a layer that shares has no indexer and no key slab and
-  attends to the selection of the nearest owner before it, which
-  ``_run_stack`` carries from a layer to the next and from a segment to
-  the next. Decode gathers the chosen entries from a position-major slab
-  (a row an entry); prefill and the forward put the selection as a mask
-  on the blocked attention's scores;
-- expert layers route over every expert of the layer (the rule is the
-  configuration's ``routing``) and compute the part of the result their
-  held experts give, plus the shared expert where there is one
-  (``nn/conf/layers/moe.moe_dropless_ffn``); the vocabulary may be the
-  chip's slice of the published one;
-- a state-space layer keeps NO columns: its cache is a recurrent state
-  (layers, slots, state size, heads x head size) in float32 (the state
-  size major, so that the decode kernel's per-channel scalars are lane
-  vectors) and the last
-  ``d_conv - 1`` inputs of its convolution, whatever the slot's length.
-  A decode step reads and writes a layer's state where it lies, so the
-  state goes through the layer loop as a CARRY updated in place on the
-  donated buffer (a scan's stacked output would be a second copy of it):
-  by a kernel that visits the live slots alone, each block once
-  (``nn/ops/ssm_decode.py``), where the kernel registry admits the
-  shapes, by :func:`_ssm_step` over all slots elsewhere;
-  prefill is the chunked dual form (:func:`_ssm_chunked`), whose padding
-  leaves the state alone;
-- four scalars of the configuration scale the embedding, every residual
-  branch, the attention scores and the logits (each 1, or
-  ``1/sqrt(head)``, by default), ``rotary_dim`` 0 means no positions at
-  all, and the head may be the embedding read transposed (``tied_head``);
-- the stack may run SEVERAL TIMES a token over one set of weights
-  (``passes``; a looped model, which buys depth with passes instead of
-  parameters): the final norm closes every pass and the next starts from
-  the normed stream; each (pass, layer) attends to keys and values of its
-  own, so every slab, ring and state of the cache plan leads with passes x
-  layers, pass-major, and ``_run_stack`` runs the passes as one scan that
-  carries the slabs whole and hands pass r the entries ``r x layers + i``;
-  a layer's two outputs may be normed again before they join the residual
-  (``sandwich_norm``: four norms a layer); and a gate of one output may
-  read each pass's closed stream for the probability of leaving there
-  (``exit_gate``), which :func:`forward` holds against ``exit_threshold``
-  a token at a time. The cached programs run every pass for every token,
-  the rule at threshold 1, and the serving backend refuses a lower one:
-  rows of one batched step that leave after different passes, and what a
-  row that left owes the later passes' cache entries, are a scheduler's
-  question (ROADMAP, Queue R).
+  and decode: the kind's mixer, then the FFN. Every decision about a
+  mixer kind has ONE home, its entry (:class:`_Mixer`, resolved once by
+  ``DecoderConfig.mixer``): its leaves, its part of the cache plan, how
+  the layer loop reads its cache, the mixer itself and the two writes.
+  ``_run_pass``, ``decode_step``, ``prefill_slot`` and the engine ask the
+  entry and test no kind:
+
+  entry           cache a segment               read in the layer loop
+  --------------  ----------------------------  ------------------------------
+  _KeysValues     K, V by head, T-minor, the    sliced by the scan (*)
+                  slot's length
+  _Ring           K, V rings of ``window``      sliced by the scan (*)
+                  columns (window, sink)
+  _Latent         one slab of kv_rank +         sliced by the scan (*)
+                  rotary_dim values, T-minor
+  _IndexedLatent  position-major rows (and an   whole by index, CARRIED; the
+                  owner's indexer keys)         selection carried beside them
+  _StateSpace     float32 state, the            CARRIED and WRITTEN in the
+                  convolution's tail            loop
+
+  entry           decode write         prefill write          decode kernel
+  --------------  -------------------  ---------------------  ----------------------
+  _KeysValues     a column a live row  the bucket's columns   decode_attention: K, V
+                  (_put_columns)                              whole, live tiles' walk
+  _Ring           one select over the  the last ``window``    none
+                  whole ring           real columns
+  _Latent         a column, the slab   the bucket's columns   latent_decode: the slab
+                  as one head                                 whole, rows' lengths
+  _IndexedLatent  a row a slot         the bucket's rows      none (a gather)
+  _StateSpace     none (in the loop)   the state after the    ssm_decode: the live
+                                       real tokens            slots' table
+
+  (*) where the stack runs more than once, carried whole and a layer's
+  entry taken by index; a kernel reads the slabs whole by index;
+- expert layers route over every expert of the layer and compute the
+  part of the result their held experts give (:func:`_experts`); the
+  vocabulary may be the chip's slice of the published one;
+- the cached programs of a stack that runs several times run every pass
+  for every token, the exit rule at threshold 1; the serving backend
+  refuses a lower one (a scheduler's question: ROADMAP, Queue R).
 
 Serving only: there is no training step for this block yet (ROADMAP M1).
 """
 
 from __future__ import annotations
 
+import abc
 import functools
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -120,26 +92,16 @@ from deeplearning4j_tpu.nn.ops.ssm_decode import live_table, ssm_decode_impl
 Array = jax.Array
 
 #: device-time scopes of this model, beside ``transformer_lm.SCOPES``
-#: (``embed``, ``kv_write``, ``head``, ``sample`` are shared): attention
-#: by layer kind (a latent layer's in two: ``attn_latent_proj``, the norms,
-#: projections, rotation, absorption and output projection, bound by
-#: weights, around ``attn_latent_core``, the scores over the latent cache,
-#: the softmax and the weighted sum of latents; in prefill the blocked
-#: attention), the dense FFN, the two halves of an expert layer and its
-#: shared expert; a state-space mixer's in three (``ssm_proj``: the norm,
-#: the two projections and the gated norm, bound by weights; ``ssm_conv``:
-#: the causal convolution and its tail; ``ssm_scan``: the recurrence, one
-#: step over the cached state in decode, the chunked form in prefill) and
-#: ``state_write``, a prefill's write of its slot's state and tail; a
-#: latent layer with an indexer has, inside ``attn_latent_proj``,
-#: ``attn_index_proj`` (the indexer's three projections, the key's norm,
-#: the rotations), ``attn_index_score`` (its heads' scores over the key
-#: cache, ReLU, the weighted sum over heads), ``attn_index_select`` (the
-#: exact top-k) and, owner or sharer, ``attn_sparse_core`` (the gather of
-#: the selected entries, the scores, softmax and weighted sum over them;
-#: in prefill the blocked attention under the selection's mask); where the
-#: stack runs more than once, ``pass_close``: the final norm that closes
-#: each pass, and the exit gate's reading of the closed stream
+#: (``embed``, ``kv_write``, ``head``, ``sample`` are shared), each opened
+#: at one site (what each covers: PERF.md section 3): attention by kind
+#: (a latent layer's ``attn_latent_proj``, bound by weights, around
+#: ``attn_latent_core``, the scores over the cache and the weighted sum;
+#: with an indexer ``attn_index_proj`` / ``_score`` / ``_select`` inside it
+#: and ``attn_sparse_core`` for the core over the selection), the dense
+#: FFN, the two halves of an expert layer and its shared expert, a
+#: state-space mixer's three and ``state_write`` (a prefill's write of its
+#: slot's state and tail), and ``pass_close`` (the norm that closes each
+#: pass of a stack that runs more than once, and the exit gate's reading)
 SCOPES = ("attn_full", "attn_window", "attn_latent_proj", "attn_latent_core",
           "mlp", "moe_route", "moe_experts", "moe_shared",
           "ssm_proj", "ssm_conv", "ssm_scan", "state_write",
@@ -171,53 +133,49 @@ _SSM_FIELDS = ("n_heads", "head_dim", "d_state", "n_groups", "d_conv",
 class DecoderConfig:
     """The decoder as data. ``attn_kinds``: name -> a kind of MIXER (the
     name stays from when every mixer attended). An attention kind is
-    {"n_kv_heads", "rope_theta", "window" (None = full), "sink" (bool)} and, optional,
-    "rope_scaling" (YaRN: ``factor``, ``beta_fast``, ``beta_slow``,
-    ``mscale``, ``mscale_all_dim``, ``original_max_position_embeddings``)
-    and "latent" = {"q_rank", "kv_rank"}: a latent kind,
-    whose heads are ``head_dim`` = (``head_dim - rotary_dim`` without
-    position | ``rotary_dim`` rotated) wide and share ONE rotary key, and
-    whose cache entry is ``kv_rank + rotary_dim`` values a position. A
-    latent kind may state "index" = {"heads", "head_dim", "topk", "own"}:
-    its attention reads only the ``topk`` positions an indexer of
-    ``heads`` heads of ``head_dim`` picks for the query (the first
-    ``rotary_dim`` of an indexer head are rotated). ``own`` true: the
-    layer has the indexer's weights, caches ONE indexer key of
-    ``head_dim`` a position in a second slab beside the latent one, and
-    makes the selection; ``own`` false: it has neither and attends to the
-    selection of the nearest owning layer before it (there must be one).
-    Both keep their slabs position-major (:meth:`cache_plan`). A
-    state-space kind is {"ssm": {"n_heads", "head_dim", "d_state",
-    "n_groups", "d_conv", "expand", "chunk"}} (Mamba-2: ``expand x
-    d_model`` = ``n_heads x head_dim`` inner channels, a state of
-    ``head_dim x d_state`` a head, B and C shared by the heads of a group,
-    a causal depthwise convolution ``d_conv`` wide, prefill by chunks of
-    ``chunk``); it keeps no columns and takes none of the attention keys.
-    ``layers``: one (mixer kind, "dense" | "experts") pair a layer.
-    ``experts_held`` = (offset, count): which of the ``n_experts`` the
-    router scores have their weights here. ``routing``:
-    {"scoring": "sigmoid", "scale"} (sigmoid scores, a correction bias in
-    the choice, weights renormalised, then times ``scale``; None reads as
-    this rule with ``scale`` 1) or {"n_group", "topk_group", "renormalise", "scale"}
-    (softmax scores, group-limited: no bias). ``shared_width``: the shared
-    expert's width (0: none). ``vocab_size`` is what is held here (the chip's slice,
-    where the vocabulary is sliced). ``rotary_dim`` 0: no positions.
+    {"n_kv_heads", "rope_theta", "window" (None = full), "sink" (bool)} and,
+    optional, "rope_scaling" (YaRN: ``factor``, ``beta_fast``,
+    ``beta_slow``, ``mscale``, ``mscale_all_dim``,
+    ``original_max_position_embeddings``) and "latent" = {"q_rank",
+    "kv_rank"}: a latent kind, whose heads are ``head_dim`` = (``head_dim -
+    rotary_dim`` without position | ``rotary_dim`` rotated) wide and share
+    ONE rotary key. A latent kind may state "index" = {"heads", "head_dim",
+    "topk", "own"}: its attention reads only the ``topk`` positions an
+    indexer of ``heads`` heads of ``head_dim`` picks for the query (the
+    first ``rotary_dim`` of an indexer head are rotated). ``own`` true: the
+    layer has the indexer's weights and makes the selection; ``own`` false:
+    it has none and attends to the selection of the nearest owning layer
+    before it (there must be one). A state-space kind is {"ssm": {"n_heads",
+    "head_dim", "d_state", "n_groups", "d_conv", "expand", "chunk"}}
+    (Mamba-2: ``expand x d_model`` = ``n_heads x head_dim`` inner channels,
+    a state of ``head_dim x d_state`` a head, B and C shared by the heads of
+    a group, a causal depthwise convolution ``d_conv`` wide, prefill by
+    chunks of ``chunk``); it takes none of the attention keys. ``layers``:
+    one (mixer kind, "dense" | "experts") pair a layer. ``experts_held`` =
+    (offset, count): which of the ``n_experts`` the router scores have their
+    weights here. ``routing``: {"scoring": "sigmoid", "scale"} (sigmoid
+    scores, a correction bias in the choice, weights renormalised, then
+    times ``scale``; None reads as this rule with ``scale`` 1) or
+    {"n_group", "topk_group", "renormalise", "scale"} (softmax scores,
+    group-limited: no bias). ``shared_width``: the shared expert's width (0:
+    none). ``vocab_size`` is what is held here (the chip's slice, where the
+    vocabulary is sliced). ``rotary_dim`` 0: no positions.
     ``embedding_multiplier`` scales the embedded tokens,
     ``residual_multiplier`` every residual branch (mixer and FFN),
     ``attention_multiplier`` the attention scores (None: ``1/sqrt(head)``)
     and ``logits_scaling`` divides the logits; ``tied_head``: the head is
-    the embedding, one leaf, read transposed. ``passes``: how many times
-    the whole stack is applied to every token, with ONE set of weights:
-    each pass closes with the final norm, the next starts from the normed
-    stream, and every (pass, layer) keeps a cache entry of its own
-    (:meth:`cache_plan`). ``sandwich_norm``: a layer has four norms, the
-    two outputs (mixer and FFN) being normed again (``norm1b``,
-    ``norm2b``) before they join the residual. ``exit_gate``: a gate of
-    one output (``gate_w`` (d,), ``gate_b``) reads each pass's normed
-    stream and gives the probability of leaving there;
-    ``exit_threshold`` q: a token's logits come from the first pass at
-    which the cumulative exit probability reaches q (:func:`forward`;
-    1.0: from the last pass, whatever the gate says)."""
+    the embedding, one leaf, read transposed. ``passes``: how many times the
+    whole stack is applied to every token, with ONE set of weights: each
+    pass closes with the final norm, the next starts from the normed stream,
+    and every (pass, layer) keeps a cache entry of its own
+    (:meth:`cache_plan`). ``sandwich_norm``: a layer has four norms, the two
+    outputs (mixer and FFN) being normed again (``norm1b``, ``norm2b``)
+    before they join the residual. ``exit_gate``: a gate of one output
+    (``gate_w`` (d,), ``gate_b``) reads each pass's normed stream and gives
+    the probability of leaving there; ``exit_threshold`` q: a token's logits
+    come from the first pass at which the cumulative exit probability
+    reaches q (:func:`forward`; 1.0: from the last pass, whatever the gate
+    says)."""
 
     def __init__(self, vocab_size: int, d_model: int, n_heads: int,
                  head_dim: int, v_head_dim: int, rotary_dim: int,
@@ -338,6 +296,8 @@ class DecoderConfig:
         if self.exit_threshold < 1.0 and not self.exit_gate:
             raise ValueError("an exit_threshold under 1 needs the exit_gate "
                              "whose probabilities it is held against")
+        self._mixers = {name: _Mixer.of(self, name)
+                        for name in self.attn_kinds}
 
     @property
     def n_layers(self) -> int:
@@ -364,34 +324,10 @@ class DecoderConfig:
                 out.append([a, f, 1])
         return [tuple(s) for s in out]
 
-    def cache_columns(self, kind: str, max_length: int) -> int:
-        """Columns a layer of ``kind`` keeps a slot: a ring of ``window``
-        for a window layer, the slot's length for a full one."""
-        window = self.attn_kinds[kind]["window"]
-        return int(max_length) if window is None else min(window,
-                                                          int(max_length))
-
-    def ssm_dims(self, kind: str) -> Tuple[int, int, int, int, int]:
-        """(heads H, head size P, state size N, inner channels H x P,
-        convolved channels H x P + 2 x groups x N) of a state-space kind."""
-        m = self.attn_kinds[kind]["ssm"]
-        inner = m["n_heads"] * m["head_dim"]
-        return (m["n_heads"], m["head_dim"], m["d_state"], inner,
-                inner + 2 * m["n_groups"] * m["d_state"])
-
-    def latent_width(self, kind: str) -> int:
-        """Values a latent kind caches a position and layer: the
-        compressed key/value entry and the one rotated key."""
-        return self.attn_kinds[kind]["latent"]["kv_rank"] + self.rotary_dim
-
-    def latent_row(self, kind: str) -> int:
-        """Values a ROW of a position-major latent slab holds (a latent
-        kind with an indexer): ``latent_width`` rounded up to whole tiles
-        of 128 lanes, the tail zero. At 576 values a row the TPU compiler
-        copies the whole slab, padded, before every gather from it (528 MB
-        a layer and step at 32 slots x 14,336, by compile for a described
-        v5e, PR 40); at 640 the gather reads the rows where they lie."""
-        return -(-self.latent_width(kind) // 128) * 128
+    def mixer(self, kind: str) -> "_Mixer":
+        """The entry of mixer kind ``kind`` (:class:`_Mixer`), resolved once
+        from ``attn_kinds[kind]``."""
+        return self._mixers[kind]
 
     def route(self):
         """The expert layers' routing rule, as ``moe_dropless_ffn`` takes
@@ -405,143 +341,33 @@ class DecoderConfig:
             scale=r["scale"])
 
     def cache_plan(self, n_slots: int, max_length: int) -> List[dict]:
-        """What the engine allocates, a segment at a time: ``slabs``, the
-        shapes (layers, slots, kv heads, head size, columns) of K and V
-        or, for a latent segment, ONE slab (layers, slots, kv_rank +
-        rotary_dim, columns); ``values``: what a position and layer
-        keeps. A latent segment with an indexer keeps its slabs
-        POSITION-MAJOR, (layers, slots, columns, row): its decode
-        gathers ``topk`` chosen positions a slot, and a chosen position
-        is then one row whose values lie together, where the T-minor
-        slab would put the gather on the minor axis, a value a lane
-        apart (PERF.md, PR 40: what the gather read on the chip). ``row``
-        is the kv_rank + rotary_dim values of the entry in whole tiles of
-        128 lanes (:meth:`latent_row`: 576 in 640, the tail zero). A
-        segment whose layers OWN the indexer has TWO slabs of different
-        widths, the latent one and the indexer's keys (rows of the
-        indexer's head size; ``index``: that width), and ``values``, what
-        the mathematics keeps a position and layer, is kv_rank +
-        rotary_dim + that; a segment whose layers share a selection has
-        the latent slab alone. ``bytes`` counts the rows as stored. A
-        state-space segment keeps no columns: ``state`` (layers,
-        slots, state size, heads x head size) in float32 (a bfloat16
-        state would round at every step of a recurrence thousands long)
-        and ``conv`` (layers, slots, convolved channels, d_conv - 1), the
-        convolution's last inputs, in the parameter dtype: the same bytes
-        whatever ``max_length``. ``dtypes`` goes with ``slabs``. A stack
-        that runs ``passes`` times keeps all of that a PASS: every shape
+        """What the engine allocates, a segment at a time: ``kind``,
+        ``layers``, ``passes`` and the kind's own part (``_Mixer.plan``):
+        ``slabs``, ``dtypes`` and ``bytes`` as allocated; the report's
+        fields (``columns``, ``ring``, ``values``, ``row``, ``index``,
+        ``state``, ``conv``); what the engine counts (``latent``,
+        ``attends``, ``topk``, ``keeps_state``, ``entries``). Every shape
         leads with passes x layers, pass-major (pass r's layer i is entry
-        r x layers + i), and ``bytes`` counts them; ``layers`` stays the
-        segment's and ``passes`` stands beside it."""
-        item = jnp.dtype(self.dtype).itemsize
-        plan = []
-        for kind, _ffn, layers in self.segments():
-            n = self.passes * layers
-            if self.attn_kinds[kind]["ssm"]:
-                h, p, ns, _inner, conv = self.ssm_dims(kind)
-                tail = self.attn_kinds[kind]["ssm"]["d_conv"] - 1
-                state = (n, int(n_slots), ns, h * p)
-                taps = (n, int(n_slots), conv, tail)
-                plan.append({
-                    "kind": kind, "layers": layers, "passes": self.passes,
-                    "columns": 0, "state": state, "conv": taps,
-                    "slabs": [state, taps],
-                    "dtypes": [jnp.float32, self.dtype],
-                    "bytes": int(np.prod(state)) * 4
-                    + int(np.prod(taps)) * item})
-                continue
-            cols = self.cache_columns(kind, max_length)
-            entry = {"kind": kind, "layers": layers, "passes": self.passes,
-                     "columns": cols,
-                     "ring": self.attn_kinds[kind]["window"] is not None}
-            index = self.attn_kinds[kind]["index"]
-            if index:
-                width = self.latent_width(kind)
-                entry["row"] = self.latent_row(kind)
-                slabs = [(n, int(n_slots), cols, entry["row"])]
-                if index["own"]:
-                    entry["index"] = index["head_dim"]
-                    slabs.append((n, int(n_slots), cols, index["head_dim"]))
-                    width += index["head_dim"]
-            elif self.attn_kinds[kind]["latent"]:
-                width = self.latent_width(kind)
-                slabs = [(n, int(n_slots), width, cols)]
-            else:
-                hkv = self.attn_kinds[kind]["n_kv_heads"]
-                slabs = [(n, int(n_slots), hkv, self.head_dim, cols),
-                         (n, int(n_slots), hkv, self.v_head_dim, cols)]
-                entry["k"], entry["v"] = slabs
-                width = hkv * (self.head_dim + self.v_head_dim)
-            entry.update(slabs=slabs, dtypes=[self.dtype] * len(slabs),
-                         values=width, bytes=sum(
-                int(np.prod(shape)) for shape in slabs) * item)
-            plan.append(entry)
-        return plan
+        r x layers + i); ``layers`` stays the segment's."""
+        return [{"kind": kind, "layers": layers, "passes": self.passes,
+                 **self.mixer(kind).plan(self.passes * layers, int(n_slots),
+                                         int(max_length))}
+                for kind, _ffn, layers in self.segments()]
 
 
 # -- parameters ---------------------------------------------------------------
 def segment_shapes(cfg: DecoderConfig, kind: str, ffn: str) -> Dict[str, tuple]:
-    """Leaf name -> (shape of ONE layer, dtype). Norm gains, sinks and
-    the router stay float32 whatever the parameter dtype. ``Wq`` is
-    stored by head, (d, heads, head size): flat, the TPU compiler
-    re-laid its 100 MB out in every layer of a decode step to split a
-    product 12,288 wide into heads of 192 (by compile, PR 27). A latent
-    kind's up-projections are by head for the same reason: ``Wqb``
-    (q_rank, heads, head size), and the key/value one in its two halves,
-    ``Wuk`` (kv_rank, heads, head size - rotary_dim) and ``Wuv`` (kv_rank,
-    heads, value size), which the absorbed decode contracts on opposite
-    sides and never together. A latent kind that OWNS an indexer adds its
-    four matrices: ``Iq`` (q_rank, indexer heads, indexer head size), the
-    indexer's queries from the query latent, by head; ``Ik`` (d, indexer
-    head size), its one key a position, with the key's LayerNorm
-    (``norm_ik`` gain and ``bias_ik``, float32); ``Iw`` (d, indexer
-    heads), the heads' weights. A kind that shares a selection has none
-    of them. A state-space kind's input projection is
-    ONE leaf, ``Win`` (d, inner + convolved + heads) with its columns in
-    the published order [z | xBC | dt]: one product reads the weights
-    once, and its three parts are cut from the RESULT at offsets that are
-    multiples of 128 lanes at the published widths (8,192 and 16,640), so
-    no part of the weight is ever sliced or re-laid (by compile:
-    ``tests/test_tpu_compile.py``). ``conv_w`` (channels, d_conv) and
-    ``conv_b`` are the depthwise convolution, ``dt_bias``, ``A_log`` and
-    ``D`` (heads,) the recurrence's per-head scalars and ``norm_g`` the
-    gated norm's gain, float32; ``Wo`` (inner, d) brings the result
-    out. With ``sandwich_norm`` every kind of layer has ``norm1b`` and
-    ``norm2b``, the gains of the norms its two outputs go through."""
-    d, hq = cfg.d_model, cfg.n_heads
-    ak = cfg.attn_kinds[kind]
+    """Leaf name -> (shape of ONE layer, dtype): the norms' gains, the
+    mixer kind's leaves (``_Mixer.leaves``), then the FFN's. Norm gains,
+    sinks and the router stay float32 whatever the parameter dtype. With
+    ``sandwich_norm`` every kind of layer has ``norm1b`` and ``norm2b``,
+    the gains of the norms its two outputs go through."""
+    d = cfg.d_model
     pd, f32 = cfg.dtype, jnp.float32
     out = {"norm1": ((d,), f32), "norm2": ((d,), f32)}
     if cfg.sandwich_norm:
         out.update({"norm1b": ((d,), f32), "norm2b": ((d,), f32)})
-    if ak["ssm"]:
-        h, _p, _n, inner, conv = cfg.ssm_dims(kind)
-        out.update({"Win": ((d, inner + conv + h), pd),
-                    "conv_w": ((conv, ak["ssm"]["d_conv"]), pd),
-                    "conv_b": ((conv,), pd), "dt_bias": ((h,), f32),
-                    "A_log": ((h,), f32), "D": ((h,), f32),
-                    "norm_g": ((inner,), f32), "Wo": ((inner, d), pd)})
-    elif ak["latent"]:
-        qr, kr = ak["latent"]["q_rank"], ak["latent"]["kv_rank"]
-        out.update({"Wqa": ((d, qr), pd), "norm_q": ((qr,), f32),
-                    "Wqb": ((qr, hq, cfg.head_dim), pd),
-                    "Wkva": ((d, kr + cfg.rotary_dim), pd),
-                    "norm_kv": ((kr,), f32),
-                    "Wuk": ((kr, hq, cfg.head_dim - cfg.rotary_dim), pd),
-                    "Wuv": ((kr, hq, cfg.v_head_dim), pd)})
-        if ak["index"] and ak["index"]["own"]:
-            ih, idim = ak["index"]["heads"], ak["index"]["head_dim"]
-            out.update({"Iq": ((qr, ih, idim), pd), "Ik": ((d, idim), pd),
-                        "norm_ik": ((idim,), f32), "bias_ik": ((idim,), f32),
-                        "Iw": ((d, ih), pd)})
-    else:
-        hkv = ak["n_kv_heads"]
-        out.update({"Wq": ((d, hq, cfg.head_dim), pd),
-                    "Wk": ((d, hkv * cfg.head_dim), pd),
-                    "Wv": ((d, hkv * cfg.v_head_dim), pd)})
-    out.setdefault("Wo", ((hq * cfg.v_head_dim, d), pd))
-    if ak["sink"]:
-        out["sink"] = ((hq,), f32)
+    out.update(cfg.mixer(kind).leaves())
     if ffn == "dense":
         out.update({"Wg": ((d, cfg.dense_width), pd),
                     "Wu": ((d, cfg.dense_width), pd),
@@ -784,10 +610,10 @@ def _latent_project(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
     (b, Tq, h, head - rotary) and rotated (b, Tq, h, rotary), the normed
     key/value latent (b, Tq, kv_rank), the one rotated key (b, Tq,
     rotary), the cache entry [latent | rotated key])."""
-    ak = cfg.attn_kinds[kind]
+    mixer = cfg.mixer(kind)
     rot = cfg.rotary_dim
-    nope, kr = cfg.head_dim - rot, ak["latent"]["kv_rank"]
-    theta, scaling = ak["rope_theta"], ak["rope_scaling"]
+    nope, kr = cfg.head_dim - rot, mixer.kv_rank
+    theta, scaling = mixer.theta, mixer.scaling
     dt = x.dtype
     a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(dt)
     c_q = _rms_norm(a_in @ bp["Wqa"], bp["norm_q"], cfg.norm_eps).astype(dt)
@@ -825,26 +651,23 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
 
     Without a cache (forward, prefill) the EXPANDED form: keys and values
     of every head are made from the positions' own latents and attention
-    goes by blocks (``_causal_blocked``). With ``cache`` = (slab
-    (b, kv_rank + rotary_dim, Tc), c_pos (b, Tc)) the ABSORBED form: with
-    the up-projection split by head into ``Wuk`` and ``Wuv``, a head's
-    query is taken into the latent space (``q_nope Wuk^T``), scored
-    against the cached latents and the rotary key as they lie, the
-    softmax weights sum the LATENTS, and ``Wuv`` then ``Wo`` bring that
-    sum out: each cached position is read once for all heads and no key
-    or value of a head is ever made over the cache. Both einsums take the
-    slab whole (the weighted sum over all its rows, the rotary key's
-    dropped after): a slice of it would be copied. They also take every
-    column of every row, whatever it holds. With ``cache`` = (the
-    segment's slabs (layers, b, kv_rank + rotary_dim, Tc), layer,
-    lengths (b,)) and Tq = 1 the same sums come from the kernel of
-    ``nn/ops/latent_decode.py``, which reads row s of layer ``layer`` in
-    its first ``lengths[s]`` columns, once (``_run_stack`` hands the cache
-    over in this form where the kernel registry admits the shapes)."""
-    ak = cfg.attn_kinds[kind]
+    goes by blocks (``_causal_blocked``). With ``cache`` (a view made by
+    ``_Latent.open``) the ABSORBED form: with the up-projection split by
+    head into ``Wuk`` and ``Wuv``, a head's query is taken into the latent
+    space (``q_nope Wuk^T``), scored against the cached latents and the
+    rotary key as they lie, the softmax weights sum the LATENTS, and
+    ``Wuv`` then ``Wo`` bring that sum out: each cached position is read
+    once for all heads and no key or value of a head is ever made over the
+    cache. Over ("columns", slab (b, width, Tc), c_pos (b, Tc)) both
+    einsums take the slab whole (the weighted sum over all its rows, the
+    rotary key's dropped after: a slice of it would be copied) and every
+    column of every row, whatever it holds. Over ("kernel", the segment's
+    slabs (entries, b, width, Tc), entry, lengths (b,)) and Tq = 1 the
+    kernel of ``nn/ops/latent_decode.py`` gives the same sums, reading row
+    s of that entry in its first ``lengths[s]`` columns, once."""
     b, tq, _d = x.shape
     hq, rot, vd = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
-    kr = ak["latent"]["kv_rank"]
+    kr = cfg.mixer(kind).kv_rank
     scale = softmax_scale(cfg, kind)
     f32, dt = jnp.float32, x.dtype
     with _scope("attn_latent_proj"):
@@ -857,15 +680,15 @@ def _latent_attention(cfg: DecoderConfig, kind: str, bp: Dict[str, Array],
         else:
             q_lat = jnp.concatenate(
                 [jnp.einsum("bqhn,chn->bqhc", q_nope, bp["Wuk"]), q_pe], axis=-1)
-            if len(cache) == 3:
-                slabs, layer, lengths = cache
+            if cache[0] == "kernel":
+                _how, slabs, layer, lengths = cache
                 core = latent_decode_impl(hq, kr + rot, slabs.shape[-1],
                                           slabs.dtype, kr)
                 with _scope("attn_latent_core"):
                     lat = core(q_lat[:, 0], new[:, 0], slabs, layer, lengths,
                                scale=scale)[:, None]
             else:
-                slab, c_pos = cache
+                _how, slab, c_pos = cache
                 with _scope("attn_latent_core"):
                     s_own = jnp.einsum("bqhc,bkc->bqhk", q_lat, new,
                                        preferred_element_type=f32) * scale
@@ -1013,18 +836,6 @@ def _index_mask(q_i, w_i, k_i, topk: int, block: int, n_real=None):
     return allowed[:, :t, :t] if pad else allowed
 
 
-def _no_selection(cfg: DecoderConfig, kind: str, b: int, tq: int, columns):
-    """A selection's shapes with nothing in them: what a segment of layers
-    that own an indexer starts its scan's carry from (each layer puts its
-    own in its place). ``columns``: the slab's, in decode; None without a
-    cache, where there is a selection only past ``topk`` positions."""
-    topk = cfg.attn_kinds[kind]["index"]["topk"]
-    if columns is None:
-        return jnp.zeros((b, tq, tq), bool) if tq > topk else None
-    return (jnp.zeros((b, min(topk, columns)), jnp.int32),
-            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
-
-
 def _sparse_latent_attention(cfg: DecoderConfig, kind: str,
                              bp: Dict[str, Array], x: Array, q_pos: Array,
                              cache=None, sel=None, n_real=None):
@@ -1032,46 +843,43 @@ def _sparse_latent_attention(cfg: DecoderConfig, kind: str,
     (b, Tq, d): returns (x + its output, what the layer caches of the
     step's positions, the selection it attended by).
 
-    The block's attention is ``_latent_attention``'s, expanded without a
-    cache and absorbed over one, with the softmax over the selected
-    positions only. A layer that OWNS the indexer makes the selection: the
-    indexer heads' queries from the query latent (``Iq``, rotated on their
-    first ``rotary_dim``), ONE key a position (``Ik``, LayerNorm, rotated
-    alike; cached), the heads' weights (``Iw``, times heads^-1/2 x
-    head_dim^-1/2), the score ``sum_h w_h ReLU(q_h . k)`` in float32 of
-    every position not after the query, and the ``topk`` largest, exactly.
-    It returns (latent entries (b, Tq, row) (``latent_row``: the kv_rank +
-    rotary_dim values and a zero tail), key entries (b, Tq, indexer head
-    size)). A layer that SHARES has none of that: it
-    attends by ``sel``, the selection the nearest owner before it made for
-    the same tokens, and returns the latent entries alone.
+    The attention is ``_latent_attention``'s, expanded without a cache and
+    absorbed over one, with the softmax over the selected positions only.
+    A layer that OWNS the indexer makes the selection: the indexer heads'
+    queries from the query latent (``Iq``, rotated on their first
+    ``rotary_dim``), ONE key a position (``Ik``, LayerNorm, rotated alike;
+    cached), the heads' weights (``Iw``, times heads^-1/2 x head_dim^-1/2),
+    the score ``sum_h w_h ReLU(q_h . k)`` in float32 of every position not
+    after the query, and the ``topk`` largest, exactly. It returns (latent
+    entries (b, Tq, ``_IndexedLatent.row``), key entries (b, Tq, indexer
+    head size)). A layer that SHARES attends by ``sel``, the selection the
+    nearest owner before it made for the same tokens, and returns the
+    latent entries alone.
 
     Without a cache (forward, prefill) a selection is a mask (b, Tq, Tq)
     on the blocked attention's scores (``_index_mask``,
     ``_causal_blocked``), or None where Tq <= topk: every query then
     attends to all before it, the dense latent layer. With ``cache`` =
-    (the segment's slabs, position-major: (latents (layers, b, Tc, row),
-    and an owner's keys (layers, b, Tc, indexer head
-    size)), layer, lengths (b,)) and Tq = 1 it is (columns (b, K), how
+    (the segment's slabs, position-major: (latents (entries, b, Tc, row),
+    and an owner's keys), entry, lengths (b,)) and Tq = 1 it is (columns (b, K), how
     many of them count (b,), whether the step's own position is in (b,))
     (``_select_indices``; K = min(topk, Tc)): the own entry lies outside
     the slab, so it is scored beside the cached ones and a sharer is told
     whether it was chosen. The chosen rows are gathered from the slab as
     it lies, K rows a slot, and the absorbed scores, the softmax and the
     weighted sum of latents run over them and the own entry."""
-    ak = cfg.attn_kinds[kind]
-    index = ak["index"]
+    mixer = cfg.mixer(kind)
+    index = mixer.index
     b, tq, _d = x.shape
     hq, rot, vd = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
-    kr = ak["latent"]["kv_rank"]
-    theta, scaling = ak["rope_theta"], ak["rope_scaling"]
+    kr, theta, scaling = mixer.kv_rank, mixer.theta, mixer.scaling
     scale = softmax_scale(cfg, kind)
     f32, dt = jnp.float32, x.dtype
     with _scope("attn_latent_proj"):
         a_in, c_q, q_nope, q_pe, c, k_pe, new = _latent_project(
             cfg, kind, bp, x, q_pos)
         # the entry as a row of the slab: whole tiles, the tail zero
-        tail = cfg.latent_row(kind) - new.shape[-1]
+        tail = mixer.row - new.shape[-1]
         new = jnp.pad(new, ((0, 0), (0, 0), (0, tail)))
         made = (new,)
         if index["own"]:
@@ -1224,23 +1032,23 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
     before the convolution, as the cached tail is.
 
     Without a cache (forward, prefill) the whole sequence goes through the
-    chunked form from a zero state, positions where ``token_mask`` is
-    False (padding after the real tokens) get ``dt = 0``, and what is kept
-    is (the state after the last real token (b, state size, heads x head
-    size), the last ``d_conv - 1`` REAL inputs of the convolution
-    (b, channels, d_conv - 1), zeros where the prompt is shorter). With
-    ``cache`` = (the segment's states (layers, b, state size, heads x head
-    size), its tails (layers, b, channels, d_conv - 1), layer) and Tq = 1
-    one step of the recurrence: the layer's state and tail are read at
-    ``layer`` and written back there, rows where ``token_mask`` is False
-    bit for bit as they were, and what is kept is the two arrays whole
-    (the layer loop's carry: ``_run_stack``). With a fourth entry, the
-    live slots' table (``nn/ops/ssm_decode.live_table``), the state goes
-    through the kernel that visits those slots alone, each block once, in
-    ``_ssm_step``'s stead."""
-    m = cfg.attn_kinds[kind]["ssm"]
-    heads, p, n, inner, conv = cfg.ssm_dims(kind)
-    g, k = m["n_groups"], m["d_conv"]
+    chunked form from a zero state, padding (``token_mask`` False) gets
+    ``dt = 0``, and what is kept is (the state after the last real token
+    (b, state size, heads x head size), the last ``d_conv - 1`` REAL inputs
+    of the convolution (b, channels, d_conv - 1), zeros before a short
+    prompt). With
+    ``cache`` = (the segment's states (entries, b, state size, heads x head
+    size), its tails (entries, b, channels, d_conv - 1), entry, table) and
+    Tq = 1 one step of the recurrence: the layer's state and tail are read
+    at ``entry`` and written back there, rows where ``token_mask`` is
+    False bit for bit as they were, and what is kept is the two arrays
+    whole (the layer loop's carry). With ``table``, the live slots'
+    (``nn/ops/ssm_decode.live_table``), the state goes through the kernel
+    that visits those slots alone, each block once, in ``_ssm_step``'s
+    stead."""
+    m = cfg.mixer(kind)
+    heads, p, n, inner, conv = m.n_heads, m.head_dim, m.d_state, m.inner, m.conv
+    g, k = m.n_groups, m.d_conv
     r = heads // g
     b, tq, _d = x.shape
     f32, dt_ = jnp.float32, x.dtype
@@ -1262,7 +1070,7 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
                                        axis=1)
             tail = jnp.where(at[:, :, None] >= 0, tail, 0).transpose(0, 2, 1)
         else:
-            states, tails, layer, *table = cache
+            states, tails, layer, table = cache
             old = jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False)
             window = jnp.concatenate([old, xbc[:, 0, :, None]], axis=-1)
             u = (bias + jnp.sum(window.astype(f32) * w, axis=-1))[:, None]
@@ -1280,15 +1088,15 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
         if cache is None:
             if token_mask is not None:
                 dt = jnp.where(token_mask[:, :, None, None], dt, 0.0)
-            y, h = _ssm_chunked(xs, dt, a, bm, cm, m["chunk"],
+            y, h = _ssm_chunked(xs, dt, a, bm, cm, m.chunk,
                                 None if token_mask is None else jnp.max(lengths))
             made = (h.reshape(b, inner, n).transpose(0, 2, 1), tail)
-        elif table:
+        elif table is not None:
             step = ssm_decode_impl(heads, p, n, g, b, states.dtype)
             x1, d1, b1, c1 = xs[:, 0], dt[:, 0], bm[:, 0], cm[:, 0]
             decay = jnp.exp(d1 * a)
             hc, states = step(
-                states, layer, table[0], (d1[..., None] * x1).reshape(b, inner),
+                states, layer, table, (d1[..., None] * x1).reshape(b, inner),
                 jnp.broadcast_to(decay[..., None], x1.shape).reshape(b, inner),
                 b1, c1)
             # ``_ssm_step``'s readout, on the kernel's sum over the old state
@@ -1345,158 +1153,549 @@ def _experts(cfg: DecoderConfig, bp: Dict[str, Array], r_in: Array, dtype,
 EXPERT_STACKS = ("Eg", "Eu", "Ed")
 
 
-def _latent_kernel_admits(cfg: DecoderConfig, kind: str, slab: Array) -> bool:
-    """Whether a decode step over ``slab`` (layers, b, width, Tc) of
-    attention kind ``kind`` goes through the length-aware kernel: a latent
-    kind, and the kernel registry's verdict for these shapes (a TPU, the
-    probe passed; elsewhere the einsums serve)."""
-    latent = cfg.attn_kinds[kind]["latent"]
-    return bool(latent) and latent_decode_impl(
-        cfg.n_heads, slab.shape[2], slab.shape[3], slab.dtype,
-        latent["kv_rank"]) is not None
+# -- the mixer kinds ----------------------------------------------------------
+def _live_lengths(q_pos, token_mask):
+    """What a decode kernel reads a row by: its positions, 0 where idle."""
+    return q_pos[:, 0] if token_mask is None else jnp.where(
+        token_mask[:, 0], q_pos[:, 0], 0)
 
 
-def _attention_kernel(cfg: DecoderConfig, kind: str, k_slab: Array,
-                      v_slab: Array):
-    """The live-tile kernel for a decode step over ``k_slab`` (layers, b,
-    hkv, hd, Tc) and ``v_slab`` of attention kind ``kind``, and its tile:
-    a kind that keeps every position (no window) and has no sink, and the
-    kernel registry's verdict for these shapes (a TPU, the probe passed,
-    a layer's K + V worth a call); None where the einsums serve."""
-    ak = cfg.attn_kinds[kind]
-    if ak["latent"] or ak["ssm"] or ak["window"] is not None or ak["sink"]:
+def _n_real(token_mask):
+    """The real positions of the longest row: the blocked forms stop there."""
+    return None if token_mask is None else jnp.max(jnp.sum(token_mask, axis=-1))
+
+
+def _write_slot(slab, new, slot):
+    """new (entries, 1, ...) -> row ``slot`` of the (donated) slab."""
+    if any(n > held for n, held in zip(new.shape[2:], slab.shape[2:])):
+        raise ValueError("prefill bucket longer than the slot")
+    return jax.lax.dynamic_update_slice(
+        slab, new, (0, slot) + (0,) * (slab.ndim - 2))
+
+
+class _Mixer(abc.ABC):
+    """ONE kind of mixer: every decision this file and the engine need
+    about the kind, resolved once from ``attn_kinds[kind]`` (:meth:`of`). A
+    kind that leaves an answer out fails where the configuration is built,
+    not inside a trace."""
+
+    #: the scope a prefill's write of the kind's cache runs under
+    fill_scope = "kv_write"
+    #: what the engine counts for the kind (``plan``; ``_DecoderBackend``)
+    latent = attends = keeps_state = False
+    topk = 0
+
+    def __init__(self, cfg: "DecoderConfig", kind: str):
+        self.cfg, self.kind = cfg, kind
+        ak = cfg.attn_kinds[kind]
+        self.theta, self.scaling = ak["rope_theta"], ak["rope_scaling"]
+
+    @staticmethod
+    def of(cfg: "DecoderConfig", kind: str) -> "_Mixer":
+        """The entry that ``attn_kinds[kind]`` describes."""
+        ak = cfg.attn_kinds[kind]
+        if ak["ssm"]:
+            return _StateSpace(cfg, kind)
+        if ak["latent"]:
+            return (_IndexedLatent if ak["index"] else _Latent)(cfg, kind)
+        return (_KeysValues if ak["window"] is None else _Ring)(cfg, kind)
+
+    @abc.abstractmethod
+    def leaves(self) -> Dict[str, tuple]:
+        """The mixer half of :func:`segment_shapes`: leaf -> (ONE layer's
+        shape, dtype), in the order ``init_params`` draws them."""
+
+    @abc.abstractmethod
+    def plan(self, entries: int, slots: int, max_length: int) -> dict:
+        """The kind's part of a ``cache_plan`` entry (:meth:`_plan`) for
+        ``entries`` = passes x layers."""
+
+    def _plan(self, slabs, entries=0, dtypes=None, **report) -> dict:
+        """``slabs`` as allocated, ``dtypes`` (the parameters' unless given)
+        and ``bytes``; the report's fields; what the engine counts."""
+        dtypes = dtypes or [self.cfg.dtype] * len(slabs)
+        return {**report, "slabs": slabs, "dtypes": dtypes,
+                "bytes": sum(int(np.prod(s)) * jnp.dtype(d).itemsize
+                             for s, d in zip(slabs, dtypes)),
+                "latent": self.latent, "attends": self.attends,
+                "topk": self.topk, "keeps_state": self.keeps_state,
+                "entries": entries}
+
+    def positions(self, pos: Array, slabs) -> Optional[Array]:
+        """(b, Tc): the absolute position each column of ``slabs`` holds
+        for a row with ``pos`` positions behind it, -1 where none; None
+        for a kind that reads its cache by no map."""
         return None
-    _layers, b, hkv, hd, t = k_slab.shape
-    return decode_attention_impl(b, hkv, cfg.n_heads // hkv, hd,
-                                 v_slab.shape[3], t, k_slab.dtype)
+
+    @abc.abstractmethod
+    def open(self, slabs, q_pos, c_pos, token_mask, looped: bool):
+        """How the layer loop reads the segment's ``slabs``, decided ONCE a
+        segment and step, before the scan: (the slabs the scan SLICES a
+        layer at a time or None; those it CARRIES whole or None;
+        ``look(sliced, carried, entry)`` -> the layer's view of the cache,
+        which :meth:`mix` alone unpacks). What is made once lies in
+        ``look``'s closure: the rows' lengths, the live slots' table, the
+        walk over the live tiles, the kernel the registry admitted, slabs
+        a kernel reads whole by the entry's index. ``c_pos``: the position
+        maps by kind; ``looped``: the slabs hold several passes' entries."""
+
+    def _by_layer(self, slabs, looped: bool, view):
+        """:meth:`open`'s answer for slabs read a layer at a time: sliced
+        by the scan or, where they hold every pass's entries, carried whole
+        (no layer changes them) and a layer's entry taken by index: a
+        pass's part cut out of a slab for a scan would be a copy of it."""
+        if not looped:
+            return slabs, None, lambda sliced, _held, _at: view(sliced)
+        return None, slabs, lambda _sliced, held, at: view(tuple(
+            jax.lax.dynamic_index_in_dim(c, at, 0, keepdims=False)
+            for c in held))
+
+    def first(self, sel, b: int, tq: int, slabs):
+        """The selection a segment's scan starts from (``sel``: the last)."""
+        return sel
+
+    @abc.abstractmethod
+    def mix(self, bp, x, q_pos, view, token_mask, sel):
+        """The mixer on x (b, Tq, d) at absolute positions q_pos (b, Tq),
+        bp ONE layer's leaves, ``view`` the layer's cache (:meth:`open`)
+        or None (full forward, prefill) -> (x + its output; what the layer
+        made to cache of the step's own positions; the selection it hands
+        the next layer, ``sel`` as it came where it selects nothing; the
+        carried slabs where it WROTE them, else None)."""
+
+    @abc.abstractmethod
+    def put(self, slabs, new, q_pos, active):
+        """A decode step's after-loop write of ``new`` (entries, b, ...),
+        made at q_pos (b, 1), into the (donated) slabs as the loop handed
+        them on; ``active`` (b,) bool or None."""
+
+    def fill(self, slabs, new, slot, length):
+        """A prefill's write of ``new`` (entries, 1, ...), made of a bucket's
+        positions, ``length`` real, into row ``slot`` of the (donated)
+        slabs: as made, unless the kind lays its slabs out otherwise."""
+        return tuple(_write_slot(c, n, slot) for c, n in zip(slabs, new))
 
 
-def _ssm_kernel_admits(cfg: DecoderConfig, kind: str, states: Array) -> bool:
-    """Whether a decode step over ``states`` (layers, b, state size, heads x
-    head size) of a state-space kind goes through the live-slot kernel:
-    the kernel registry's verdict for these shapes (a TPU, the probe
-    passed; elsewhere ``_ssm_step`` serves)."""
-    heads, p, n, _inner, _conv = cfg.ssm_dims(kind)
-    return ssm_decode_impl(
-        heads, p, n, cfg.attn_kinds[kind]["ssm"]["n_groups"],
-        states.shape[1], states.dtype) is not None
+class _KeysValues(_Mixer):
+    """Keys and values by head over the slot's whole length, T-minor: K
+    (entries, slots, hkv, head, T) and V. The queries attend, under one
+    softmax (with the learned ``sink`` where the kind has one), to the
+    step's own keys and to the cache columns: by two whole-slab einsums
+    or by the live-tile kernel (``nn/ops/decode_attention.py``)."""
+
+    attends = True
+
+    def __init__(self, cfg, kind):
+        super().__init__(cfg, kind)
+        ak = cfg.attn_kinds[kind]
+        self.hkv, self.sink, self.window = (ak["n_kv_heads"], ak["sink"],
+                                            ak["window"])
+
+    def leaves(self):
+        """``Wq`` is stored by head, (d, heads, head size): flat, the TPU
+        compiler re-laid its 100 MB out in every layer of a decode step to
+        split a product 12,288 wide into heads of 192 (by compile, PR 27)."""
+        c, pd = self.cfg, self.cfg.dtype
+        out = {"Wq": ((c.d_model, c.n_heads, c.head_dim), pd),
+               "Wk": ((c.d_model, self.hkv * c.head_dim), pd),
+               "Wv": ((c.d_model, self.hkv * c.v_head_dim), pd),
+               "Wo": ((c.n_heads * c.v_head_dim, c.d_model), pd)}
+        if self.sink:
+            out["sink"] = ((c.n_heads,), jnp.float32)
+        return out
+
+    def plan(self, entries, slots, max_length):
+        c = self.cfg
+        cols = max_length if self.window is None else min(self.window,
+                                                         max_length)
+        k = (entries, slots, self.hkv, c.head_dim, cols)
+        v = (entries, slots, self.hkv, c.v_head_dim, cols)
+        return self._plan([k, v], entries, columns=cols,
+                          ring=self.window is not None, k=k, v=v,
+                          values=self.hkv * (c.head_dim + c.v_head_dim))
+
+    def positions(self, pos, slabs):
+        """Column p holds position p."""
+        c = jnp.arange(slabs[0].shape[-1], dtype=jnp.int32)[None, :]
+        return jnp.where(c <= pos.astype(jnp.int32)[:, None] - 1, c, -1)
+
+    def kernel(self, k_slab, v_slab):
+        """The live-tile kernel for a decode step over the slabs and its
+        tile, by the registry's verdict for these shapes (a TPU, the probe
+        passed, a layer's K + V worth a call); None where the einsums
+        serve, as they do for a kind with a sink or a window."""
+        if self.sink or self.window is not None:
+            return None
+        _entries, b, hkv, hd, t = k_slab.shape
+        return decode_attention_impl(b, hkv, self.cfg.n_heads // hkv, hd,
+                                     v_slab.shape[3], t, k_slab.dtype)
+
+    def open(self, slabs, q_pos, c_pos, token_mask, looped):
+        core = self.kernel(*slabs) if q_pos.shape[1] == 1 else None
+        if core is None:
+            return self._by_layer(
+                slabs, looped, lambda kv: ("columns", *kv, c_pos[self.kind]))
+        # a custom call's operand is made whole, so the scan's slice of a
+        # slab would be copied a layer: K and V whole, the entry's index
+        # and the walk over the rows' live tiles, made here once
+        walk = live_tiles(_live_lengths(q_pos, token_mask),
+                          slabs[0].shape[-1], core[1])
+        return None, None, lambda _sliced, _held, at: (
+            "tiles", *slabs, at, core[0], walk)
+
+    def mix(self, bp, x, q_pos, view, token_mask, sel):
+        """Makes the layer's new (b, hkv, Tq, head) keys and (b, hkv, Tq,
+        value size) values. Without a cache, a sink or a window, a bucket
+        whose float32 scores would pass ``BLOCKED_SCORE_BYTES`` attends by
+        blocks."""
+        cfg, window = self.cfg, self.window
+        b, tq, _d = x.shape
+        hq, hkv, hd, vd = cfg.n_heads, self.hkv, cfg.head_dim, cfg.v_head_dim
+        grp = hq // hkv
+        scale = softmax_scale(cfg, self.kind)
+        with _scope("attn_window" if window is not None else "attn_full"):
+            a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(x.dtype)
+            q = jnp.einsum("btd,dhk->bthk", a_in, bp["Wq"])
+            k = (a_in @ bp["Wk"]).reshape(b, tq, hkv, hd)
+            v = (a_in @ bp["Wv"]).reshape(b, tq, hkv, vd)
+            q = _rotate(q, q_pos, cfg.rotary_dim, self.theta)
+            k = _rotate(k, q_pos, cfg.rotary_dim, self.theta)
+            if view is not None and view[0] == "tiles":
+                _how, k_slab, v_slab, at, core, walk = view
+                kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+                # query head i reads key/value head i // grp
+                o = core(q.reshape(b, hkv, grp, hd), kh[:, :, 0], vh[:, :, 0],
+                         k_slab, v_slab, at, walk, scale=scale)
+                if cfg.value_scale != 1.0:
+                    o = o * cfg.value_scale
+                o = o.reshape(b, tq, hq * vd).astype(x.dtype)
+            elif (view is None and window is None and not self.sink
+                    and hq * tq * tq * 4 > BLOCKED_SCORE_BYTES):
+                kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+                o = _causal_blocked(
+                    q, jnp.repeat(k, grp, axis=2), jnp.repeat(v, grp, axis=2),
+                    scale, PREFILL_BLOCK, _n_real(token_mask))
+                if cfg.value_scale != 1.0:
+                    o = o * cfg.value_scale
+                o = o.reshape(b, tq, hq * vd)
+            else:
+                # query head i reads key/value head i // grp
+                qg = q.reshape(b, tq, hkv, grp, hd).transpose(0, 2, 3, 1, 4)
+                kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+                f32 = jnp.float32
+                s_own = jnp.einsum("bkgqd,bktd->bkgqt", qg, kh,
+                                   preferred_element_type=f32) * scale
+                s_own = jnp.where(_visible(q_pos, q_pos, window)[:, None, None],
+                                  s_own, _NEG)
+                m = s_own.max(-1)
+                if view is not None:
+                    _how, kc, vc, c_pos = view
+                    s_c = jnp.einsum("bkgqd,bkdt->bkgqt", qg, kc,
+                                     preferred_element_type=f32) * scale
+                    s_c = jnp.where(_visible(q_pos, c_pos, window)[:, None, None],
+                                    s_c, _NEG)
+                    m = jnp.maximum(m, s_c.max(-1))
+                if self.sink:
+                    sink = bp["sink"].astype(f32).reshape(1, hkv, grp, 1)
+                    m = jnp.maximum(m, sink)
+                e_own = jnp.exp(s_own - m[..., None])
+                z = e_own.sum(-1)
+                o = jnp.einsum("bkgqt,bktd->bkgqd", e_own.astype(x.dtype), vh,
+                               preferred_element_type=f32)
+                if view is not None:
+                    e_c = jnp.exp(s_c - m[..., None])
+                    z = z + e_c.sum(-1)
+                    o = o + jnp.einsum("bkgqt,bkdt->bkgqd", e_c.astype(x.dtype),
+                                       vc, preferred_element_type=f32)
+                if self.sink:
+                    z = z + jnp.exp(sink - m)  # the sink takes weight, adds no value
+                o = o * (cfg.value_scale / z[..., None])
+                o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
+            x = _residual(cfg, x, _branch(cfg, bp, "norm1b", o @ bp["Wo"]))
+        return x, (kh, vh), sel, None
+
+    def put(self, slabs, new, q_pos, active):
+        """One in-place column a live row (``_put_columns``: one kernel
+        call a slab where the registry admits it)."""
+        wp = jnp.minimum(q_pos, slabs[0].shape[-1] - 1)
+        return tuple(_put_columns(c, n, wp, active)
+                     for c, n in zip(slabs, new))
+
+    def fill(self, slabs, new, slot, length):
+        """The bucket's columns at 0..Tb-1: padding follows the real
+        tokens, so causal attention keeps it from them."""
+        return tuple(_write_slot(c, n.transpose(0, 1, 2, 4, 3), slot)
+                     for c, n in zip(slabs, new))
+
+
+class _Ring(_KeysValues):
+    """Keys and values of a WINDOW layer: a ring of ``window`` columns
+    whatever the slot's length, position p in column p mod window, read by
+    the same einsums under another position map."""
+
+    attends = False
+
+    def positions(self, pos, slabs):
+        """Column c holds the latest position below ``pos`` that is
+        congruent to c (< 0: not yet written)."""
+        cols = slabs[0].shape[-1]
+        c = jnp.arange(cols, dtype=jnp.int32)[None, :]
+        last = pos.astype(jnp.int32)[:, None] - 1
+        return last - jnp.mod(last - c, cols)
+
+    def put(self, slabs, new, q_pos, active):
+        """Row s's column -> ring[:, s, :, :, pos[s] mod window], as ONE
+        select over the whole (donated) ring: a fixed pass whatever the
+        slot's length, where ``_put_columns`` costs an operation a slot."""
+        def select(ring, n):
+            cols = ring.shape[4]
+            at = jnp.mod(q_pos[:, 0], cols)[None, :, None, None, None]
+            here = jnp.arange(cols, dtype=at.dtype)[None, None, None, None, :]
+            return jnp.where(here == at, n.transpose(0, 1, 2, 4, 3), ring)
+
+        return tuple(select(c, n) for c, n in zip(slabs, new))
+
+    def fill(self, slabs, new, slot, length):
+        """Column c gets the latest real position congruent to c: the
+        prompt's last ``window`` columns where it is longer than the ring."""
+        cols = slabs[0].shape[-1]
+        new = tuple(n.transpose(0, 1, 2, 4, 3) for n in new)
+        if new[0].shape[-1] > cols:
+            c = jnp.arange(cols, dtype=jnp.int32)
+            src = jnp.maximum(length - 1 - jnp.mod(length - 1 - c, cols), 0)
+            new = tuple(jnp.take(n, src, axis=4) for n in new)
+        return tuple(_write_slot(c, n, slot) for c, n in zip(slabs, new))
+
+
+class _Latent(_Mixer):
+    """Latent attention (:func:`_latent_attention`): ONE slab (entries,
+    slots, kv_rank + rotary_dim, T) of the compressed key/value entry and
+    the one rotated key a position, T-minor, mapped as a full K/V slab."""
+
+    latent = True
+    positions = _KeysValues.positions
+
+    def __init__(self, cfg, kind):
+        super().__init__(cfg, kind)
+        latent = cfg.attn_kinds[kind]["latent"]
+        self.q_rank, self.kv_rank = latent["q_rank"], latent["kv_rank"]
+        #: values a position and layer caches
+        self.width = self.kv_rank + cfg.rotary_dim
+
+    def leaves(self):
+        """The up-projections are by head (``Wq``'s reason), the key/value
+        one in its two halves, ``Wuk`` and ``Wuv``, which the absorbed
+        decode contracts on opposite sides and never together."""
+        c, pd, f32 = self.cfg, self.cfg.dtype, jnp.float32
+        d, hq, qr, kr = c.d_model, c.n_heads, self.q_rank, self.kv_rank
+        return {"Wqa": ((d, qr), pd), "norm_q": ((qr,), f32),
+                "Wqb": ((qr, hq, c.head_dim), pd),
+                "Wkva": ((d, kr + c.rotary_dim), pd),
+                "norm_kv": ((kr,), f32),
+                "Wuk": ((kr, hq, c.head_dim - c.rotary_dim), pd),
+                "Wuv": ((kr, hq, c.v_head_dim), pd),
+                "Wo": ((hq * c.v_head_dim, d), pd)}
+
+    def plan(self, entries, slots, max_length):
+        return self._plan([(entries, slots, self.width, max_length)], entries,
+                          columns=max_length, ring=False, values=self.width)
+
+    def kernel(self, slab):
+        """The length-aware kernel for a decode step over ``slab``, by the
+        registry's verdict for its shape (None: the einsums serve)."""
+        return latent_decode_impl(self.cfg.n_heads, slab.shape[2],
+                                  slab.shape[3], slab.dtype, self.kv_rank)
+
+    def open(self, slabs, q_pos, c_pos, token_mask, looped):
+        if q_pos.shape[1] != 1 or self.kernel(slabs[0]) is None:
+            return self._by_layer(
+                slabs, looped, lambda kv: ("columns", *kv, c_pos[self.kind]))
+        # the slab whole (``_KeysValues.open``'s reason), by the rows'
+        # lengths: a row that is not active has none
+        lengths = _live_lengths(q_pos, token_mask)
+        return None, None, lambda _sliced, _held, at: (
+            "kernel", slabs[0], at, lengths)
+
+    def mix(self, bp, x, q_pos, view, token_mask, sel):
+        x, entries = _latent_attention(
+            self.cfg, self.kind, bp, x, q_pos, view,
+            _n_real(token_mask) if view is None else None)
+        return x, (entries,), sel, None
+
+    def put(self, slabs, new, q_pos, active):
+        """``_KeysValues.put``'s column write, on the slab seen as one
+        head whose "head size" is the entry."""
+        wp = jnp.minimum(q_pos, slabs[0].shape[-1] - 1)
+        return (_put_columns(slabs[0][:, :, None], new[0][:, :, None], wp,
+                             active)[:, :, 0],)
+
+    def fill(self, slabs, new, slot, length):
+        return (_write_slot(slabs[0], new[0].transpose(0, 1, 3, 2), slot),)
+
+
+class _IndexedLatent(_Latent):
+    """Latent attention over an indexer's SELECTION
+    (:func:`_sparse_latent_attention`). The slabs are POSITION-MAJOR,
+    (entries, slots, T, row): a decode step gathers ``topk`` chosen
+    positions a slot, and a chosen position is then one row whose values
+    lie together, where a T-minor slab would put the gather on the minor
+    axis (PERF.md, PR 40). An owner has a second slab, its indexer's keys
+    (entries, slots, T, indexer head size). The layer loop carries the
+    selection, and the slabs whole for the after-loop write to take from
+    its end: closed over, the loop's copy of them and the donated buffer
+    the write updates were two, a slab-sized copy a step (PR 40)."""
+
+    def __init__(self, cfg, kind):
+        super().__init__(cfg, kind)
+        self.index = cfg.attn_kinds[kind]["index"]
+        self.topk = self.index["topk"]
+        #: values a ROW of the latent slab holds: ``width`` in whole tiles
+        #: of 128 lanes, the tail zero. At 576 values a row the TPU
+        #: compiler copies the whole slab, padded, before every gather from
+        #: it (528 MB a layer and step, by compile, PR 40); at 640 it does not
+        self.row = -(-self.width // 128) * 128
+
+    def leaves(self):
+        """An owner adds the indexer's matrices (``Iq`` by head, ``Ik``
+        with its LayerNorm, ``Iw``); a sharer has none."""
+        out = super().leaves()
+        if self.index["own"]:
+            c, pd, f32 = self.cfg, self.cfg.dtype, jnp.float32
+            ih, idim = self.index["heads"], self.index["head_dim"]
+            wo = out.pop("Wo")
+            out.update({"Iq": ((self.q_rank, ih, idim), pd),
+                        "Ik": ((c.d_model, idim), pd),
+                        "norm_ik": ((idim,), f32), "bias_ik": ((idim,), f32),
+                        "Iw": ((c.d_model, ih), pd), "Wo": wo})
+        return out
+
+    def plan(self, entries, slots, max_length):
+        """``row``: a latent row as stored; ``index``: the key slab's width;
+        ``values``: what the mathematics keeps a position and layer."""
+        slabs, report = [(entries, slots, max_length, self.row)], {}
+        if self.index["own"]:
+            report["index"] = self.index["head_dim"]
+            slabs.append((entries, slots, max_length, report["index"]))
+        return self._plan(slabs, entries, columns=max_length, ring=False,
+                          row=self.row, **report,
+                          values=self.width + report.get("index", 0))
+
+    positions = _Mixer.positions  # read by the rows' lengths, not by a map
+
+    def open(self, slabs, q_pos, c_pos, token_mask, looped):
+        lengths = q_pos[:, 0]
+        return None, slabs, lambda _sliced, held, at: (held, at, lengths)
+
+    def first(self, sel, b, tq, slabs):
+        """An owner's scan starts from a selection's shapes with nothing
+        in them: a mask without a cache (none up to ``topk`` positions)."""
+        if not self.index["own"]:
+            return sel
+        if slabs is None:
+            return jnp.zeros((b, tq, tq), bool) if tq > self.topk else None
+        return (jnp.zeros((b, min(self.topk, slabs[0].shape[2])), jnp.int32),
+                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+
+    def mix(self, bp, x, q_pos, view, token_mask, sel):
+        x, entries, sel = _sparse_latent_attention(
+            self.cfg, self.kind, bp, x, q_pos, view, sel,
+            _n_real(token_mask) if view is None else None)
+        return x, entries, sel, None
+
+    def put(self, slabs, new, q_pos, active):
+        """Row s's entry -> slab[:, s, pos[s], :], latents and keys, each
+        ONE in-place ``dynamic_update_slice`` (``_put_columns``'s reasons)."""
+        wp = jnp.minimum(q_pos, slabs[0].shape[2] - 1)
+        out = []
+        for slab, n in zip(slabs, new):
+            for s in range(n.shape[1]):
+                slab = jax.lax.dynamic_update_slice(slab, n[:, s:s + 1],
+                                                    (0, s, wp[s, 0], 0))
+            out.append(slab)
+        return tuple(out)
+
+    fill = _Mixer.fill  # the bucket's rows, as made
+
+
+class _StateSpace(_Mixer):
+    """A state-space (Mamba-2) mixer (:func:`_ssm_mixer`): no columns, no
+    position map. Its cache is ``state`` (entries, slots, state size, heads
+    x head size) in float32 (a bfloat16 state would round at every step of
+    a recurrence thousands long; the state size major, so that the decode
+    kernel's per-channel scalars are lane vectors) and ``conv`` (entries,
+    slots, convolved channels, d_conv - 1), the convolution's last inputs,
+    in the parameter dtype. A decode step WRITES both in the layer loop,
+    which carries them, each layer's entry in place on the donated buffer
+    (a scan's stacked output would be a second copy of the state)."""
+
+    fill_scope = "state_write"
+    keeps_state = True
+
+    def __init__(self, cfg, kind):
+        super().__init__(cfg, kind)
+        for field, value in cfg.attn_kinds[kind]["ssm"].items():
+            setattr(self, field, value)
+        #: inner channels, and the convolved ones ([x | B | C])
+        self.inner = self.n_heads * self.head_dim
+        self.conv = self.inner + 2 * self.n_groups * self.d_state
+
+    def leaves(self):
+        """The input projection is ONE leaf, ``Win`` (d, inner + convolved
+        + heads) with its columns in the published order [z | xBC | dt]:
+        one product reads the weights once, and its three parts are cut
+        from the RESULT at offsets that are multiples of 128 lanes at the
+        published widths (8,192 and 16,640), so no part of the weight is
+        ever sliced or re-laid (by compile). The recurrence's per-head
+        scalars and the gated norm's gain are float32."""
+        d, pd, f32 = self.cfg.d_model, self.cfg.dtype, jnp.float32
+        h, inner, conv = self.n_heads, self.inner, self.conv
+        return {"Win": ((d, inner + conv + h), pd),
+                "conv_w": ((conv, self.d_conv), pd), "conv_b": ((conv,), pd),
+                "dt_bias": ((h,), f32), "A_log": ((h,), f32), "D": ((h,), f32),
+                "norm_g": ((inner,), f32), "Wo": ((inner, d), pd)}
+
+    def plan(self, entries, slots, max_length):
+        state = (entries, slots, self.d_state, self.inner)
+        taps = (entries, slots, self.conv, self.d_conv - 1)
+        return self._plan([state, taps], dtypes=[jnp.float32, self.cfg.dtype],
+                          columns=0, state=state, conv=taps)
+
+    def kernel(self, states):
+        """The live-slot kernel for a decode step over ``states``, by the
+        registry's verdict for their shape (None: ``_ssm_step`` serves)."""
+        return ssm_decode_impl(self.n_heads, self.head_dim, self.d_state,
+                               self.n_groups, states.shape[1], states.dtype)
+
+    def open(self, slabs, q_pos, c_pos, token_mask, looped):
+        table = None
+        if q_pos.shape[1] == 1 and self.kernel(slabs[0]) is not None:
+            table = live_table(jnp.ones(q_pos.shape[:1], bool)
+                               if token_mask is None else token_mask[:, 0])
+        return None, slabs, lambda _sliced, held, at: (*held, at, table)
+
+    def mix(self, bp, x, q_pos, view, token_mask, sel):
+        """Over a cache nothing is made for an after-loop write: the two
+        arrays come back WRITTEN, the loop's carry."""
+        x, kept = _ssm_mixer(self.cfg, self.kind, bp, x, view, token_mask)
+        return (x, kept, sel, None) if view is None else (x, (), sel, kept)
+
+    def put(self, slabs, new, q_pos, active):
+        """Written inside the loop, in place: as they are."""
+        return tuple(slabs)
 
 
 def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
-          x: Array, q_pos: Array, cache=None, token_mask=None, layer=None,
+          x: Array, q_pos: Array, view=None, token_mask=None, layer=None,
           sel=None):
-    """One layer on x (b, Tq, d) at absolute positions q_pos (b, Tq);
-    bp holds ONE layer's leaves. The queries attend, under one softmax,
-    to the layer's own Tq keys and, if ``cache`` = (kc (b, hkv, hd, Tc),
-    vc (b, hkv, vd, Tc), c_pos (b, Tc)) is given, to the cache columns,
-    each of which holds absolute position ``c_pos`` (< 0: nothing); or, for
-    the live-tile kernel of a decode step, ``cache`` = (the segment's K
-    slabs, its V slabs, layer, (the kernel, its walk over the rows' live
-    tiles)) (``nn/ops/decode_attention.py``). The
-    cache is only READ: the layer's new (b, hkv, Tq, hd) keys and
-    (b, hkv, Tq, vd) values are returned for the caller to drop (full
-    forward), write whole (prefill) or append (decode). A latent kind's
-    cache is (slab (b, kv_rank + rotary_dim, Tc), c_pos), or (the
-    segment's slabs, layer, lengths) for the decode kernel, and what it
-    returns in their place is ((b, Tq, kv_rank + rotary_dim) entries,)
-    (:func:`_latent_attention`). A latent kind with an indexer takes
-    ``cache`` = (the segment's position-major slabs, layer, lengths) and
-    ``sel``, the selection of the nearest owning layer before it (an owner
-    makes its own), and returns in their place ((latent entries, and an
-    owner's indexer keys), the selection it attended by)
-    (:func:`_sparse_latent_attention`). A state-space kind attends to nothing:
-    its cache is (the segment's states, its tails, layer), or those and
-    the live slots' table for the decode kernel, WRITTEN here at
-    ``layer``, and it returns what it keeps in their place
-    (:func:`_ssm_mixer`). With ``layer``
-    the expert weights in ``bp`` are a segment's whole stacks and
-    ``layer`` the one to use (``moe_dropless_ffn``). Returns
-    (x, (k, v), (expert pairs computed here, held experts hit))."""
-    ak = cfg.attn_kinds[kind]
-    b, tq, d = x.shape
-    if ak["ssm"]:
-        x, made = _ssm_mixer(cfg, kind, bp, x, cache, token_mask)
-        x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
-        return x, made, counts
-    if ak["latent"]:
-        n_real = (None if token_mask is None or cache is not None
-                  else jnp.max(jnp.sum(token_mask, axis=-1)))
-        if ak["index"]:
-            x, entries, sel = _sparse_latent_attention(
-                cfg, kind, bp, x, q_pos, cache, sel, n_real)
-            x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
-            return x, (entries, sel), counts
-        x, entries = _latent_attention(cfg, kind, bp, x, q_pos, cache, n_real)
-        x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
-        return x, (entries,), counts
-    hq, hkv, hd, vd = cfg.n_heads, ak["n_kv_heads"], cfg.head_dim, cfg.v_head_dim
-    grp = hq // hkv
-    window = ak["window"]
-    with _scope("attn_window" if window is not None else "attn_full"):
-        a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(x.dtype)
-        q = jnp.einsum("btd,dhk->bthk", a_in, bp["Wq"])
-        k = (a_in @ bp["Wk"]).reshape(b, tq, hkv, hd)
-        v = (a_in @ bp["Wv"]).reshape(b, tq, hkv, vd)
-        q = _rotate(q, q_pos, cfg.rotary_dim, ak["rope_theta"])
-        k = _rotate(k, q_pos, cfg.rotary_dim, ak["rope_theta"])
-        if cache is not None and len(cache) == 4:
-            k_slab, v_slab, at, (core, table) = cache
-            kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            # query head i reads key/value head i // grp
-            o = core(q.reshape(b, hkv, grp, hd), kh[:, :, 0], vh[:, :, 0],
-                     k_slab, v_slab, at, table,
-                     scale=softmax_scale(cfg, kind))
-            if cfg.value_scale != 1.0:
-                o = o * cfg.value_scale
-            o = o.reshape(b, tq, hq * vd).astype(x.dtype)
-        elif (cache is None and window is None and not ak["sink"]
-                and hq * tq * tq * 4 > BLOCKED_SCORE_BYTES):
-            kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            o = _causal_blocked(
-                q, jnp.repeat(k, grp, axis=2), jnp.repeat(v, grp, axis=2),
-                softmax_scale(cfg, kind), PREFILL_BLOCK,
-                None if token_mask is None
-                else jnp.max(jnp.sum(token_mask, axis=-1)))
-            if cfg.value_scale != 1.0:
-                o = o * cfg.value_scale
-            o = o.reshape(b, tq, hq * vd)
-        else:
-            # query head i reads key/value head i // grp
-            qg = q.reshape(b, tq, hkv, grp, hd).transpose(0, 2, 3, 1, 4)
-            kh, vh = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            scale = softmax_scale(cfg, kind)
-            f32 = jnp.float32
-            s_own = jnp.einsum("bkgqd,bktd->bkgqt", qg, kh,
-                               preferred_element_type=f32) * scale
-            s_own = jnp.where(_visible(q_pos, q_pos, window)[:, None, None],
-                              s_own, _NEG)
-            m = s_own.max(-1)
-            if cache is not None:
-                kc, vc, c_pos = cache
-                s_c = jnp.einsum("bkgqd,bkdt->bkgqt", qg, kc,
-                                 preferred_element_type=f32) * scale
-                s_c = jnp.where(_visible(q_pos, c_pos, window)[:, None, None],
-                                s_c, _NEG)
-                m = jnp.maximum(m, s_c.max(-1))
-            if ak["sink"]:
-                sink = bp["sink"].astype(f32).reshape(1, hkv, grp, 1)
-                m = jnp.maximum(m, sink)
-            e_own = jnp.exp(s_own - m[..., None])
-            z = e_own.sum(-1)
-            o = jnp.einsum("bkgqt,bktd->bkgqd", e_own.astype(x.dtype), vh,
-                           preferred_element_type=f32)
-            if cache is not None:
-                e_c = jnp.exp(s_c - m[..., None])
-                z = z + e_c.sum(-1)
-                o = o + jnp.einsum("bkgqt,bkdt->bkgqd", e_c.astype(x.dtype),
-                                   vc, preferred_element_type=f32)
-            if ak["sink"]:
-                z = z + jnp.exp(sink - m)  # the sink takes weight, adds no value
-            o = o * (cfg.value_scale / z[..., None])
-            o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
-        x = _residual(cfg, x, _branch(cfg, bp, "norm1b", o @ bp["Wo"]))
+    """One layer on x (b, Tq, d) at absolute positions q_pos (b, Tq); bp
+    holds ONE layer's leaves: the kind's mixer (``_Mixer.mix``, which says
+    what ``view`` and ``sel`` are and what it keeps), then :func:`_ffn`.
+    With ``layer`` the expert weights in ``bp`` are a segment's whole
+    stacks and ``layer`` the one to use. Returns (x, (made to cache,
+    selection handed on, carried slabs where written), expert counters)."""
+    x, *kept = cfg.mixer(kind).mix(bp, x, q_pos, view, token_mask, sel)
     x, counts = _ffn(cfg, ffn, bp, x, token_mask, layer)
-    return x, (kh, vh), counts
+    return x, tuple(kept), counts
 
 
 def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
@@ -1524,43 +1723,22 @@ def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
 
 def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
               caches=None, c_pos=None, token_mask=None, r=None):
-    """Every segment in order ONCE, each one ``lax.scan`` of :func:`block`
-    over its stacked layers. ``caches``: per segment the slabs to read,
-    (K, V) (layers, b, hkv, hd, Tc) or a latent segment's one
-    (layers, b, width, Tc), with ``c_pos`` the position map of each
-    attention kind (a latent segment's decode step where the kernel
-    registry admits it: the slab whole, the layer's index and the rows'
-    lengths instead; a full-attention segment's likewise: K and V whole,
-    the layer's index and the walk over the rows' live tiles). A
-    state-space segment's cache is (states, tails),
-    WRITTEN in the loop: the two arrays go through the scan as its carry,
-    each layer reading and writing its own index in place, and come back
-    whole in the cache's stead (stacked as a scan's output they would be a
-    second copy of the state); a decode step where the kernel registry
-    admits it also hands each layer the table of the rows that are
-    active. A segment of latent layers with an indexer reads its
-    position-major slabs whole, by the layer's index (the decode gathers
-    rows of them), and the SELECTION goes through its scan as a carry and
-    on to the next segment: a layer that owns the indexer puts its own in
-    the carry's place, a layer that shares reads what the last owner left,
-    be it a layer or a segment back; it starts afresh with the pass.
+    """Every segment in order ONCE, each ONE ``lax.scan`` of :func:`block`
+    over its stacked layers. ``caches``: the slabs a segment, read as the
+    kind's entry says (``_Mixer.open``, with ``c_pos`` the position maps
+    by kind); None without a cache. The scan carries (x, the selection a
+    layer hands the next, which a segment hands the next too and which
+    starts afresh with the pass, the slabs the entry has carried) over (the
+    layer's leaves, the slabs the entry has sliced, the layer's index); a
+    part the segment's kind does not use is None. ``r`` (traced) is the
+    pass, where the stack runs more than once: the caches then hold passes
+    x layers entries and layer i reads (a state-space layer: writes) entry
+    ``r x layers + i`` of its segment's.
 
-    ``r`` (traced) is the pass, where the stack runs more than once: the
-    caches then hold every pass's entries, passes x layers, and layer i
-    reads (a state-space layer: writes) entry ``r x layers + i`` of its
-    segment's. No pass's part is cut out of a slab on the way: a slice of
-    it handed to a scan is a copy of it. Every slab goes through the
-    segment's scan whole, as a carry that no attention layer changes, and
-    a layer takes its own entry by index inside the loop, as a scan takes
-    a layer's from what it scans over.
-
-    Returns (x, per segment what the layers made to cache, (k, v) stacks
-    (layers, b, hkv, Tq, hd) or (entries (layers, b, Tq, width),) (with an
-    indexer: and an owner's keys) or, of a state-space segment without a
-    cache, (states (layers, b, state size, heads x head size), tails
-    (layers, b, channels, d_conv - 1)) and nothing over one; summed expert
-    counters; per segment the slabs as the loops hand them on, a
-    state-space segment's written, the after-loop write's to take)."""
+    Returns (x; per segment what the layers made to cache, stacked
+    (layers, b, ...); summed expert counters; per segment the slabs as the
+    loops hand them on, for the after-loop write to take, None without a
+    cache)."""
     made, held_out = [], []
     pairs = hit = jnp.zeros((), jnp.int32)
     sel = None
@@ -1570,107 +1748,33 @@ def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
         # product takes them whole and the layer's index
         stacks = {k: seg[k] for k in EXPERT_STACKS if k in seg}
         scanned = {k: v for k, v in seg.items() if k not in stacks}
-        kv = held = None if caches is None else caches[i]
+        mixer = cfg.mixer(kind)
+        slabs = sliced = held = look = None
+        if caches is not None:
+            slabs = tuple(caches[i])
+            sliced, held, look = mixer.open(slabs, q_pos, c_pos, token_mask,
+                                            r is not None)
         # a layer's entry in the segment's cache: this pass's run of them
-        base = None if r is None or kv is None else r * n
+        base = None if r is None or slabs is None else r * n
+        sel = mixer.first(sel, x.shape[0], x.shape[1], slabs)
 
-        def entry(layer, base=base):
-            return layer if base is None else base + layer
+        def layer(carry, xs, kind=kind, ffn=ffn, stacks=stacks, look=look,
+                  base=base):
+            x, sel, held = carry
+            bp, sliced, at = xs
+            view = None if look is None else look(
+                sliced, held, at if base is None else base + at)
+            x, (knew, sel, wrote), counts = block(
+                cfg, kind, ffn, {**bp, **stacks}, x, q_pos, view, token_mask,
+                at if stacks else None, sel)
+            return (x, sel, held if wrote is None else wrote), (knew, counts)
 
-        index = cfg.attn_kinds[kind]["index"]
-        if index:
-            lengths = None if kv is None else q_pos[:, 0]
-            if index["own"]:
-                sel = _no_selection(cfg, kind, x.shape[0], x.shape[1],
-                                    None if kv is None else kv[0].shape[2])
-
-            # the slabs go through the scan as a carry that no layer
-            # changes, and the after-loop write takes them from its end:
-            # closed over, the loop's copy of them and the donated buffer
-            # the write updates were two, a slab-sized copy a step (by
-            # compile, PR 40)
-            def chosen(carry, xs, kind=kind, ffn=ffn, stacks=stacks,
-                       lengths=lengths, entry=entry):
-                x, sel, kv = carry
-                bp, layer = xs
-                x, (knew, sel), counts = block(
-                    cfg, kind, ffn, {**bp, **stacks}, x, q_pos,
-                    None if kv is None else (kv, entry(layer), lengths),
-                    token_mask, layer if stacks else None, sel)
-                return (x, sel, kv), (knew, counts)
-
-            (x, sel, kv), (knew, counts) = jax.lax.scan(
-                chosen, (x, sel, kv),
-                (scanned, jnp.arange(n, dtype=jnp.int32)))
-            made.append(knew)
-            held_out.append(kv)
-            pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
-            continue
-        if kv is not None and cfg.attn_kinds[kind]["ssm"]:
-            table = ()
-            if x.shape[1] == 1 and _ssm_kernel_admits(cfg, kind, kv[0]):
-                table = (live_table(
-                    jnp.ones(x.shape[:1], bool) if token_mask is None
-                    else token_mask[:, 0]),)
-
-            def step(carry, xs, kind=kind, ffn=ffn, stacks=stacks,
-                     table=table, entry=entry):
-                x, held = carry
-                bp, layer = xs
-                x, held, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
-                                        q_pos, (*held, entry(layer), *table),
-                                        token_mask, layer if stacks else None)
-                return (x, held), counts
-
-            (x, held), counts = jax.lax.scan(
-                step, (x, tuple(kv)),
-                (scanned, jnp.arange(n, dtype=jnp.int32)))
-            made.append(())
-            held_out.append(held)
-            pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
-            continue
-        # nor is a latent segment's slab where the decode kernel reads it
-        # (a custom call's operand is made whole: the scan's slice of the
-        # slab would be copied a layer), by the rows' lengths: a row that
-        # is not active has none; nor are a full layer's K and V where the
-        # live-tile kernel reads them, by a walk over the rows' live tiles
-        # made here, once for the segment's layers
-        whole = walk = every = None
-        if kv is not None and x.shape[1] == 1:
-            lengths = q_pos[:, 0] if token_mask is None else jnp.where(
-                token_mask[:, 0], q_pos[:, 0], 0)
-            if _latent_kernel_admits(cfg, kind, kv[0]):
-                whole, kv, walk = tuple(kv), None, lengths
-            elif len(kv) == 2:
-                core = _attention_kernel(cfg, kind, *kv)
-                if core is not None:
-                    whole, kv = tuple(kv), None
-                    walk = (core[0], live_tiles(lengths, whole[0].shape[-1],
-                                                core[1]))
-        if whole is None and kv is not None and r is not None:
-            every, kv = tuple(kv), None  # all passes' entries: by index
-
-        def body(carry, xs, kind=kind, ffn=ffn, stacks=stacks, whole=whole,
-                 walk=walk, entry=entry):
-            x, every = carry
-            bp, kv, layer = xs
-            if every is not None:
-                kv = tuple(jax.lax.dynamic_index_in_dim(
-                    c, entry(layer), 0, keepdims=False) for c in every)
-            cache = None if kv is None else (*kv, c_pos[kind])
-            if whole is not None:
-                cache = (*whole, entry(layer), walk)
-            x, knew, counts = block(cfg, kind, ffn, {**bp, **stacks}, x,
-                                    q_pos, cache, token_mask,
-                                    layer if stacks else None)
-            return (x, every), (knew, counts)
-
-        (x, every), (knew, counts) = jax.lax.scan(
-            body, (x, every),
-            (scanned, kv, jnp.arange(n, dtype=jnp.int32)))
+        (x, sel, held), (knew, counts) = jax.lax.scan(
+            layer, (x, sel, held),
+            (scanned, sliced, jnp.arange(n, dtype=jnp.int32)))
         made.append(knew)
         # the loop's own hand-on where it carried the slabs, else as given
-        held_out.append(held if every is None else every)
+        held_out.append(slabs if held is None else held)
         pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
     return x, made, (pairs, hit), None if caches is None else held_out
 
@@ -1681,19 +1785,16 @@ def _run_stack(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     (:func:`_run_pass` is one time). With one pass that is all, and the
     stream comes back as the last layer left it (:func:`_head` norms it).
     With more, the passes are ONE ``lax.scan`` (its body, the segments'
-    scans, is compiled once however many passes there are): the caches
-    hold passes x layers entries a segment and go through it whole as its
-    carry, pass ``r`` reading its own (``_run_pass``); every pass closes
+    scans, is compiled once however many passes there are) that carries
+    the caches whole, pass ``r`` reading its own entries; every pass closes
     under ``pass_close`` with the final norm, the next starting from the
     NORMED stream, and with the exit gate's reading of it where there is
     one; what the layers made to cache comes back stacked passes x layers,
-    pass-major, as the cache plan lays the slabs out, so that the
-    after-loop writes are what they are for one pass on a longer leading
-    axis. Returns (x, closed by the norm where passes > 1; per segment
-    what was made to cache; summed expert counters; per segment the slabs
-    for the after-loop write to take, None without a cache; (every pass's
-    closed stream (passes, b, Tq, d), the gate's probabilities (passes, b,
-    Tq) float32 or None), None where the stack runs once)."""
+    pass-major, as the cache plan lays the slabs out. Returns
+    (:func:`_run_pass`'s four, x closed by the norm where passes > 1;
+    (every pass's closed stream (passes, b, Tq, d), the gate's
+    probabilities (passes, b, Tq) float32 or None), None where the stack
+    runs once)."""
     if cfg.passes == 1:
         return *_run_pass(cfg, params, x, q_pos, caches, c_pos,
                           token_mask), None
@@ -1776,125 +1877,51 @@ def forward(cfg: DecoderConfig, params: Dict, ids: Array):
 
 # -- the cache ----------------------------------------------------------------
 def init_cache(cfg: DecoderConfig, n_slots: int, max_length: int):
-    """Zeroed slabs a segment, by the cache plan: (K, V), a latent
-    segment's one (with an indexer: position-major, and the keys' beside
-    it where the layers own it), or a state-space segment's (states in
-    float32, tails)."""
+    """Zeroed slabs a segment, as the cache plan has them."""
     return [tuple(jnp.zeros(shape, dtype)
                   for shape, dtype in zip(p["slabs"], p["dtypes"]))
             for p in cfg.cache_plan(n_slots, max_length)]
 
 
-def cache_positions(cfg: DecoderConfig, pos: Array, max_length: int):
-    """Per attention kind, (b, Tc): the absolute position each cache
-    column holds for a row that has ``pos`` positions behind it; -1
-    where it holds none. A full layer keeps position p in column p; a
-    ring keeps p in column p mod window, so column c holds the latest
-    position below ``pos`` that is congruent to c. A state-space kind has
-    no columns and no map."""
+def cache_positions(cfg: DecoderConfig, pos: Array, caches):
+    """Per mixer kind, the position map of its slabs in ``caches`` for
+    rows that have ``pos`` positions behind them (``_Mixer.positions``;
+    None for a kind that reads its cache by no map)."""
     out = {}
-    for kind in cfg.attn_kinds:
-        if cfg.attn_kinds[kind]["ssm"]:
-            continue
-        cols = cfg.cache_columns(kind, max_length)
-        c = jnp.arange(cols, dtype=jnp.int32)[None, :]
-        last = pos.astype(jnp.int32)[:, None] - 1
-        if cfg.attn_kinds[kind]["window"] is None:
-            out[kind] = jnp.where(c <= last, c, -1)
-        else:
-            out[kind] = last - jnp.mod(last - c, cols)  # < 0: not yet written
+    for (kind, _f, _n), slabs in zip(cfg.segments(), caches):
+        if kind not in out:
+            out[kind] = cfg.mixer(kind).positions(pos, slabs)
     return out
-
-
-def _put_ring(ring, new, pos):
-    """The after-loop write of a ring: new (L, b, hkv, 1, hd), row s's
-    column -> ring[:, s, :, :, pos[s] mod window], as ONE select over the
-    whole (donated) ring. A ring is ``window`` columns whatever the
-    slot's length, so rewriting it costs a fixed pass over a few hundred
-    megabytes, where a column update a slot (``_put_columns``, what a
-    full layer's slab takes) costs an operation a slot and slab."""
-    cols = ring.shape[4]
-    at = jnp.mod(pos[:, 0], cols)[None, :, None, None, None]
-    here = jnp.arange(cols, dtype=at.dtype)[None, None, None, None, :] == at
-    return jnp.where(here, new.transpose(0, 1, 2, 4, 3), ring)
-
-
-def _put_rows(slab, new, wp):
-    """The after-loop write of a position-major slab: new (L, b, 1,
-    width), row s's entry -> slab[:, s, wp[s, 0], :], each as ONE
-    ``dynamic_update_slice`` on the (donated) slab, in place
-    (``_put_columns``'s reasons)."""
-    for s in range(new.shape[1]):
-        slab = jax.lax.dynamic_update_slice(slab, new[:, s:s + 1],
-                                            (0, s, wp[s, 0], 0))
-    return slab
 
 
 def decode_step(cfg: DecoderConfig, params: Dict, caches, ids_1: Array,
                 pos: Array, active: Optional[Array] = None):
     """One token a row: ids_1 (b,) at per-row positions pos (b,) ->
     (logits (b, V), caches, (expert pairs, experts hit)). The caches are
-    read inside the layer loop and written after it: a full layer's slab
-    (a latent layer's too) by one in-place column a live row at ``pos``
-    (``_put_columns``: one kernel call a slab where the registry admits
-    it), a ring by one select at ``pos mod window``
-    (``_put_ring``), a position-major slab of a latent layer with an
-    indexer (its latents and, where it owns the indexer, its keys) by one
-    in-place row a slot (``_put_rows``). A state-space segment's states
-    and tails were
-    written inside the loop, in place, and come back as they are.
-    ``active`` (b,) bool keeps idle rows out of the expert layers (and of
-    their counters) and leaves their state and tail bit for bit alone."""
-    t_max = max([p[0].shape[-1] for (kind, _f, _n), p
-                 in zip(cfg.segments(), caches)
-                 if not cfg.attn_kinds[kind]["ssm"]
-                 and not cfg.attn_kinds[kind]["index"]], default=0)
+    read inside the layer loop and written after it, each segment's as its
+    kind's entry says (``_Mixer.put``), from the slabs as the loop hands
+    them on. ``active`` (b,) bool keeps idle rows out of the expert layers
+    (and their counters) and leaves their state and tail bit for bit alone."""
     q_pos = pos.astype(jnp.int32)[:, None]
     x, new_kv, counts, held, _passes = _run_stack(
         cfg, params, _embed(cfg, params, ids_1[:, None]), q_pos, caches,
-        cache_positions(cfg, pos, t_max),
+        cache_positions(cfg, pos, caches),
         None if active is None else active[:, None])
-    out = []
     with _scope("kv_write"):
-        # the slabs as the layer loop hands them on: with one pass, the
-        # arguments themselves but where a loop carried them
-        for (kind, _f, _n), slabs, new in zip(cfg.segments(), held, new_kv):
-            if cfg.attn_kinds[kind]["ssm"]:
-                out.append(tuple(slabs))
-                continue
-            if cfg.attn_kinds[kind]["index"]:
-                wp = jnp.minimum(q_pos, slabs[0].shape[2] - 1)
-                out.append(tuple(_put_rows(c, n, wp)
-                                 for c, n in zip(slabs, new)))
-                continue
-            wp = jnp.minimum(q_pos, slabs[0].shape[-1] - 1)
-            if cfg.attn_kinds[kind]["latent"]:
-                # as a slab of one head whose "head size" is the entry
-                out.append((_put_columns(slabs[0][:, :, None],
-                                         new[0][:, :, None], wp,
-                                         active)[:, :, 0],))
-            elif cfg.attn_kinds[kind]["window"] is None:
-                out.append(tuple(_put_columns(c, n, wp, active)
-                                 for c, n in zip(slabs, new)))
-            else:
-                out.append(tuple(_put_ring(c, n, q_pos)
-                                 for c, n in zip(slabs, new)))
+        out = [cfg.mixer(kind).put(tuple(slabs), new, q_pos, active)
+               for (kind, _f, _n), slabs, new
+               in zip(cfg.segments(), held, new_kv)]
     return _head(cfg, params, x[:, 0]), out, counts
 
 
 def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
                  length: Array, slot: Array):
     """One prompt, right-padded to a bucket: ids (1, Tb), ``length`` real
-    tokens, into row ``slot`` of every slab, from ONE pass. A full layer
-    (or a latent one, with its indexer's keys where it has them) gets the
-    bucket's columns at 0..Tb-1; a ring gets, in column c, the
-    latest real position congruent to c, i.e. the prompt's last
-    ``window`` columns when it is longer than the window. Padding follows
-    the real tokens, so causal attention keeps it from them, and the
-    expert layers leave it out. A state-space segment gets the state
-    after the ``length`` real tokens (padding has ``dt = 0``) and the last
-    ``d_conv - 1`` real inputs of its convolution, under ``state_write``.
-    Returns (logits (1, V) at length-1, caches)."""
+    tokens, into row ``slot`` of every slab, from ONE pass, each segment's
+    write as its kind's entry says (``_Mixer.fill``), under its scope.
+    Padding follows the real tokens: causal attention keeps it from them,
+    the expert layers leave it out and a state-space layer's state passes
+    it by. Returns (logits (1, V) at length-1, caches)."""
     _b, tb = ids.shape
     q_pos = jnp.arange(tb, dtype=jnp.int32)[None]
     real = q_pos < length
@@ -1902,36 +1929,9 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
         cfg, params, _embed(cfg, params, ids), q_pos, token_mask=real)
     out = []
     for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
-        if cfg.attn_kinds[kind]["ssm"]:
-            with _scope("state_write"):
-                out.append(tuple(
-                    jax.lax.dynamic_update_slice(
-                        c, n, (0, slot) + (0,) * (c.ndim - 2))
-                    for c, n in zip(slabs, new)))
-            continue
-        with _scope("kv_write"):
-            position_major = bool(cfg.attn_kinds[kind]["index"])
-            cols = slabs[0].shape[2 if position_major else -1]
-            if cfg.attn_kinds[kind]["window"] is None and tb > cols:
-                raise ValueError("prefill bucket longer than the slot")
-            if position_major:  # (L, 1, Tb, width) entries, as they lie
-                out.append(tuple(
-                    jax.lax.dynamic_update_slice(c, n, (0, slot, 0, 0))
-                    for c, n in zip(slabs, new)))
-                continue
-            if cfg.attn_kinds[kind]["latent"]:
-                out.append((jax.lax.dynamic_update_slice(
-                    slabs[0], new[0].transpose(0, 1, 3, 2), (0, slot, 0, 0)),))
-                continue
-            # (L, 1, hkv, hd, Tb)
-            new = tuple(n.transpose(0, 1, 2, 4, 3) for n in new)
-            if tb > cols:  # a ring shorter than the bucket
-                c = jnp.arange(cols, dtype=jnp.int32)
-                src = jnp.maximum(length - 1 - jnp.mod(length - 1 - c, cols), 0)
-                new = tuple(jnp.take(n, src, axis=4) for n in new)
-            out.append(tuple(
-                jax.lax.dynamic_update_slice(c, n, (0, slot, 0, 0, 0))
-                for c, n in zip(slabs, new)))
+        mixer = cfg.mixer(kind)
+        with _scope(mixer.fill_scope):
+            out.append(mixer.fill(tuple(slabs), new, slot, length))
     x_last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
                                           keepdims=False)
     return _head(cfg, params, x_last), out

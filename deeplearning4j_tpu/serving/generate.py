@@ -923,30 +923,24 @@ class _DecoderBackend:
         self.buckets = prefill_bucket_lengths(
             self.max_length,
             prefill_buckets or getattr(model, "serving_seq_buckets", None))
-        self.cache_bytes = sum(
-            p["bytes"] for p in cfg.cache_plan(self.n_slots, self.max_length))
-        #: some layer keeps a latent cache: the engine counts the
-        #: positions a launched step's slots have behind them
-        self.latent = any(k["latent"] for k in cfg.attn_kinds.values())
-        #: some layer keeps keys and values a position and reads all that
-        #: lie behind a slot (no window): the engine counts them likewise
-        self.attends = any(
-            not k["latent"] and not k["ssm"] and k["window"] is None
-            for k in cfg.attn_kinds.values())
-        #: the positions a latent layer's indexer keeps for its attention
-        #: (0: no layer selects): the engine counts the positions a
-        #: launched step's slots score and those they select
-        self.index_topk = max([k["index"]["topk"]
-                               for k in cfg.attn_kinds.values()
-                               if k["index"]], default=0)
-        #: some layer keeps a recurrent state: no prefix cache, K = 1;
-        #: the engine counts the slots a launched step advances
-        self.keeps_state = any(k["ssm"] for k in cfg.attn_kinds.values())
+        plan = cfg.cache_plan(self.n_slots, self.max_length)
+        self.cache_bytes = sum(p["bytes"] for p in plan)
+        #: what the engine counts of a launched step's slots, as the plan
+        #: says of the layer kinds: the positions they have behind them
+        #: where some layer keeps a latent cache; the same where some layer
+        #: keeps keys and values and reads all that lie behind a slot (no
+        #: window); the positions an indexer keeps for its attention (0: no
+        #: layer selects), for those they score and those they select; the
+        #: slots a step advances where some layer keeps a recurrent state
+        #: (then no prefix cache, K = 1)
+        self.latent = any(p["latent"] for p in plan)
+        self.attends = any(p["attends"] for p in plan)
+        self.index_topk = max(p["topk"] for p in plan)
+        self.keeps_state = any(p["keeps_state"] for p in plan)
         #: passes over the stack a step runs, and the (pass, layer) pairs
         #: that keep a cache entry a position: the engine's counters
         self.passes = cfg.passes
-        self.cache_entries = cfg.passes * sum(
-            1 for kind, _ffn in cfg.layers if not cfg.attn_kinds[kind]["ssm"])
+        self.cache_entries = sum(p["entries"] for p in plan)
         if cfg.exit_threshold < 1.0:
             raise EarlyExitError(
                 f"exit_threshold={cfg.exit_threshold}: the decode step runs "

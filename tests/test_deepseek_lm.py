@@ -227,11 +227,12 @@ def test_absorbed_step_equals_expanded_attention(base, core, monkeypatch):
     whole, entries = decoder_lm._latent_attention(cfg, "latent", bp, x, pos)
     assert entries.shape == (2, 21, 32)  # kv_lora_rank 16 + 16 rotated
     slab = jnp.zeros((2, 32, 40)).at[:, :, :20].set(entries[:, :20].transpose(0, 2, 1))
-    c_pos = decoder_lm.cache_positions(cfg, jnp.asarray([20, 20]), 40)["latent"]
-    cache = (slab, c_pos)
+    c_pos = decoder_lm.cache_positions(
+        cfg, jnp.asarray([20, 20]), decoder_lm.init_cache(cfg, 2, 40))["latent"]
+    cache = ("columns", slab, c_pos)
     if core == "kernel":
         kernel_interpreted(monkeypatch)
-        cache = (jnp.full((3, 2, 32, 40), jnp.nan).at[1].set(slab),
+        cache = ("kernel", jnp.full((3, 2, 32, 40), jnp.nan).at[1].set(slab),
                  jnp.asarray(1, jnp.int32), jnp.asarray([20, 20], jnp.int32))
     step, entry = decoder_lm._latent_attention(
         cfg, "latent", bp, x[:, 20:], pos[:, 20:], cache)

@@ -115,7 +115,7 @@ def program_selections(model, ids):
     for (kind, ffn, n), seg in zip(cfg.segments(), model.params_["segments"]):
         for j in range(n):
             bp = {k: v[j] for k, v in seg.items()}
-            x, (_entries, sel), _counts = decoder_lm.block(
+            x, (_entries, sel, _held), _counts = decoder_lm.block(
                 cfg, kind, ffn, bp, x, q_pos, sel=sel)
             out.append(None if sel is None else np.asarray(sel[0]))
     return out

@@ -60,14 +60,16 @@ def step_both_ways(dtype, lengths, dead=0.0):
     x = jax.random.normal(keys[0], (b, 1, cfg.d_model), jnp.float32).astype(dt)
     held = jax.random.normal(keys[1], (b, 32, T_C), jnp.float32).astype(dt)
     live = jnp.arange(T_C)[None, None, :] < pos[:, None, None]
-    c_pos = decoder_lm.cache_positions(cfg, pos, T_C)["latent"]
+    c_pos = decoder_lm.cache_positions(
+        cfg, pos, decoder_lm.init_cache(cfg, b, T_C))["latent"]
     want, entry_w = decoder_lm._latent_attention(
-        cfg, "latent", bp, x, pos[:, None], (jnp.where(live, held, 0), c_pos))
+        cfg, "latent", bp, x, pos[:, None],
+        ("columns", jnp.where(live, held, 0), c_pos))
     slabs = jax.random.normal(keys[2], (LAYERS, b, 32, T_C), jnp.float32).astype(dt)
     slabs = slabs.at[LAYER].set(jnp.where(live, held, jnp.asarray(dead, dt)))
     got, entry_g = decoder_lm._latent_attention(
         cfg, "latent", bp, x, pos[:, None],
-        (slabs, jnp.asarray(LAYER, jnp.int32), pos))
+        ("kernel", slabs, jnp.asarray(LAYER, jnp.int32), pos))
     np.testing.assert_array_equal(np.asarray(entry_g, np.float32),
                                   np.asarray(entry_w, np.float32))
     return (np.asarray(want[:, 0], np.float32), np.asarray(got[:, 0], np.float32),
@@ -129,7 +131,7 @@ def test_modes_that_keep_the_einsum_path(monkeypatch, mode, enabled):
     monkeypatch.setenv(ENV_FLAGS[latent_decode.NAME], mode)
     cfg, _bp = layer_of("float32")
     slab = jnp.zeros((1, 2, 32, T_C), jnp.float32)
-    assert decoder_lm._latent_kernel_admits(cfg, "latent", slab) is enabled
+    assert (cfg.mixer("latent").kernel(slab) is not None) is enabled
     (verdict,) = default_kernel_registry().snapshot()[latent_decode.NAME].values()
     assert verdict["enabled"] is enabled
 

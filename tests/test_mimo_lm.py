@@ -153,7 +153,8 @@ def test_ring_positions():
         attn_kinds={"full": {"n_kv_heads": 1, "rope_theta": 1e4, "window": None},
                     "window": {"n_kv_heads": 1, "rope_theta": 1e4, "window": 4, "sink": True}},
         layers=[("full", "dense"), ("window", "dense")], dense_width=8, max_length=16)
-    got = decoder_lm.cache_positions(cfg, jnp.asarray([0, 3, 4, 10]), 16)
+    got = decoder_lm.cache_positions(cfg, jnp.asarray([0, 3, 4, 10]),
+                                     decoder_lm.init_cache(cfg, 4, 16))
     np.testing.assert_array_equal(np.asarray(got["window"]), [
         [-4, -3, -2, -1], [0, 1, 2, -1], [0, 1, 2, 3], [8, 9, 6, 7]])
     np.testing.assert_array_equal(np.asarray(got["full"][1][:5]), [0, 1, 2, -1, -1])

@@ -61,7 +61,7 @@ def caches_of(cfg, idle_holds=None, active=None, seed=5):
     """A segment's states and tails, seeded; ``idle_holds`` in every entry
     of the idle slots' state at every layer."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    conv = cfg.ssm_dims("ssm")[4]
+    conv = cfg.mixer("ssm").conv
     states = jax.random.normal(keys[0], (LAYERS, SLOTS, N, HEADS * P), jnp.float32)
     tails = jax.random.normal(keys[1], (LAYERS, SLOTS, conv, 3), jnp.float32)
     if idle_holds is not None:
@@ -79,8 +79,8 @@ def step_both_ways(load, idle_holds=None):
     states, tails, x = caches_of(cfg, idle_holds, load)
     layer = jnp.asarray(LAYER, jnp.int32)
     mask = active[:, None]
-    want_x, want = decoder_lm._ssm_mixer(cfg, "ssm", bp, x, (states, tails, layer), mask)
-    assert decoder_lm._ssm_kernel_admits(cfg, "ssm", states)
+    want_x, want = decoder_lm._ssm_mixer(cfg, "ssm", bp, x, (states, tails, layer, None), mask)
+    assert cfg.mixer("ssm").kernel(states) is not None
     got_x, got = decoder_lm._ssm_mixer(
         cfg, "ssm", bp, x, (states, tails, layer, ssm_decode.live_table(active)), mask)
     return ((np.asarray(want_x), *map(np.asarray, want)),
@@ -187,7 +187,7 @@ def test_modes_that_keep_the_jnp_path(monkeypatch, mode):
     monkeypatch.setenv(ENV_FLAGS[ssm_decode.NAME], mode)
     cfg, _bp = layer_of()
     states = jnp.zeros((1, SLOTS, N, HEADS * P), jnp.float32)
-    assert decoder_lm._ssm_kernel_admits(cfg, "ssm", states) is False
+    assert cfg.mixer("ssm").kernel(states) is None
     (verdict,) = default_kernel_registry().snapshot()[ssm_decode.NAME].values()
     assert verdict["enabled"] is False
     assert ("DL4J_TPU_SSM_DECODE_STEP=0" if mode == "0" else "non-TPU") in verdict["reason"]

@@ -13,13 +13,13 @@ under xdist every worker imports this file but only one runs it.
 """
 
 import functools
+import importlib.util
 import math
 import os
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from deeplearning4j_tpu.nn.ops.flash_attention import flash_attention
 from deeplearning4j_tpu.nn.ops.fused_conv import conv3x3, pw_conv
@@ -29,17 +29,22 @@ from deeplearning4j_tpu.nn.ops.int8_matmul import int8_matmul
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
+# the cells' serving programs at published widths and a cut depth are
+# built in ONE place, which also prints them a line a program
+_spec = importlib.util.spec_from_file_location(
+    "decoder_programs", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts", "decoder_programs.py"))
+programs = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(programs)
+
 
 @pytest.fixture(scope="module")
 def one_chip():
-    from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
-    # the TPU compiler would otherwise write its logs under /tmp
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        chip = programs.described_chip()
     except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     # an executable compiled for a described chip is written to the
@@ -48,7 +53,7 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield chip
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
 
@@ -269,15 +274,6 @@ def _sampler_work_outside_a_conditional(text: str, slots: int,
     return found
 
 
-def _attends(slots, hkv, grp, hd, vd, t, dtype):
-    """``decode_attention_impl`` as the chip's probe answers it: this
-    process's backend is the CPU, so the compiles below steer the verdict."""
-    from deeplearning4j_tpu.nn.ops import decode_attention as da
-
-    tile = da.tile_for(t)
-    return functools.partial(da.decode_attention, tile=tile), tile
-
-
 @pytest.fixture(scope="module")
 def chat_decode(one_chip):
     """The serving engine's decode program (``_decode``) as the chat
@@ -285,49 +281,11 @@ def chat_decode(one_chip):
     the slots' inputs one int32 array on the device) at the
     gpt2-large.chat cell's widths (d 1280, 20 heads, 24 slots x 1024,
     bf16) and a cut depth (4 layers; ~35 s of compile), lowered on what
-    the backend hands it: the shapes of the weights' serving copy.
+    the backend hands it: the shapes of the weights' serving copy, the
+    registry's verdicts steered as the chip's probes give them.
     Returns (compiled, cfg, slots, the slab's shape)."""
-    from types import SimpleNamespace
-
-    from deeplearning4j_tpu.models.transformer_lm import (
-        TransformerLMConfig,
-        init_params,
-        serving_copy,
-    )
-    from deeplearning4j_tpu.serving.generate import _TransformerAheadBackend
-
-    # this process's backend is the CPU: steer the registry's verdict,
-    # as the chip's probe gives it
-    from deeplearning4j_tpu.models import transformer_lm
-    from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
-
-    def admitted(entries, slots, heads, head_size, t, dtype):
-        return functools.partial(kcw.kv_column_write, lb=kcw.entries_a_block(
-            entries, heads, head_size, jnp.dtype(dtype).itemsize))
-
-    L, S, T = 4, 24, 1024
-    cfg = TransformerLMConfig(vocab_size=50257, max_length=T, d_model=1280,
-                              n_heads=20, n_layers=L,
-                              compute_dtype="bfloat16")
-    # the program takes its shapes from its arguments: the backend
-    # itself is built at two slots, so no slab is allocated here
-    be = _TransformerAheadBackend(SimpleNamespace(cfg=cfg), 2, T, None,
-                                  lambda name: None)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    masters = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    params = jax.tree_util.tree_map(
-        lambda a: arg(a.shape, a.dtype),
-        jax.eval_shape(lambda p: serving_copy(cfg, p), masters))
-    slab = arg((L, S, cfg.n_heads, cfg.d_model // cfg.n_heads, T), BF16)
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(transformer_lm, "kv_column_write_impl", admitted)
-        patch.setattr(transformer_lm, "decode_attention_impl", _attends)
-        compiled = be._decode_fn.lower(
-            params, slab, slab, arg((S + 1, 8), jnp.int32)).compile()
-    return compiled, cfg, S, slab.shape
+    built = programs.build("chat", one_chip)
+    return built.decode(), built.cfg, built.slots, built.caches[0].shape
 
 
 def test_decode_program_keeps_the_kv_slab_in_place(chat_decode):
@@ -406,8 +364,7 @@ def test_decode_program_casts_no_weights(chat_decode):
     assert not {("f32", m) for m in matrices} & arguments
 
 
-def test_decoder_decode_program_compiles_at_published_widths(one_chip,
-                                                             monkeypatch):
+def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     """``DecoderLM``'s decode program as the engine builds it, at the
     mimo-v2.5-ep16 cell's widths (hidden 4096, 64 heads of 192/128, 4 and
     8 key/value heads, 16 of 256 experts of 2048, 64 slots x 1536,
@@ -420,44 +377,10 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip,
     live-tile kernel (``nn/ops/decode_attention.py``, the verdict steered:
     16 query heads a key head, keys of 192 and values of 128)."""
     import re
-    from types import SimpleNamespace
 
-    from deeplearning4j_tpu.models import decoder_lm
-    from deeplearning4j_tpu.models.decoder_lm import (
-        DecoderConfig,
-        init_cache,
-        init_params,
-    )
-    from deeplearning4j_tpu.serving.generate import _DecoderBackend
-
-    monkeypatch.setattr(decoder_lm, "decode_attention_impl", _attends)
-    S, T = 64, 1536
-    cfg = DecoderConfig(
-        vocab_size=19072, d_model=4096, n_heads=64, head_dim=192,
-        v_head_dim=128, rotary_dim=64,
-        attn_kinds={"full": {"n_kv_heads": 4, "rope_theta": 1e7,
-                             "window": None, "sink": False},
-                    "window": {"n_kv_heads": 8, "rope_theta": 1e4,
-                               "window": 128, "sink": True}},
-        layers=[("full", "dense"), ("window", "experts"),
-                ("window", "experts")],
-        dense_width=16384, expert_width=2048, n_experts=256, top_k=8,
-        experts_held=(0, 16), value_scale=0.707, max_length=T)
-    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
-                         lambda name: None)
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = described(jax.eval_shape(lambda: init_params(cfg)))
-    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
-    compiled = be._decode_fn.lower(
-        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    built = programs.build("mimo", one_chip)
+    cfg, S, caches = built.cfg, built.slots, built.caches
+    compiled = built.decode()
     text = compiled.as_text()
     assert "ragged-dot" in text and _custom_calls(text) >= 3
     attends = [line for line in text.splitlines()
@@ -479,8 +402,7 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip,
     assert not copies, copies
 
 
-def test_latent_decoder_programs_compile_at_published_widths(one_chip,
-                                                             monkeypatch):
+def test_latent_decoder_programs_compile_at_published_widths(one_chip):
     """``DecoderLM`` with latent attention as the engine builds its
     programs, at the deepseek-v2-ep8 cell's widths (hidden 5120, 128 heads
     of 128 + 64 / 128 over a 1536-wide query and a 512 + 64-wide key/value
@@ -495,58 +417,12 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip,
     whole slab), and the prefill at the 8,192 bucket attends by blocks, so
     its plan stays far under the 4.7 GB that weights and cache leave (128
     heads x 8,192^2 float32 scores in one piece would be 34 GB)."""
-    import functools
     import re
-    from types import SimpleNamespace
 
-    from deeplearning4j_tpu.models import decoder_lm
-    from deeplearning4j_tpu.models.decoder_lm import (
-        DecoderConfig,
-        init_cache,
-        init_params,
-    )
     from deeplearning4j_tpu.nn.ops import latent_decode
-    from deeplearning4j_tpu.serving.generate import _DecoderBackend
 
-    asked = []
-
-    def admitted(heads, width, t_c, dtype, kv_rank):
-        asked.append((heads, width, t_c, jnp.dtype(dtype).name, kv_rank))
-        return functools.partial(latent_decode.latent_decode_core,
-                                 kv_rank=kv_rank, tile=latent_decode.TILE)
-
-    monkeypatch.setattr(decoder_lm, "latent_decode_impl", admitted)
-
-    S, T = 48, 10240
-    cfg = DecoderConfig(
-        vocab_size=12800, d_model=5120, n_heads=128, head_dim=192,
-        v_head_dim=128, rotary_dim=64,
-        attn_kinds={"latent": {
-            "rope_theta": 1e4,
-            "rope_scaling": {"type": "yarn", "factor": 40, "beta_fast": 32,
-                             "beta_slow": 1, "mscale": 0.707,
-                             "mscale_all_dim": 0.707,
-                             "original_max_position_embeddings": 4096},
-            "latent": {"q_rank": 1536, "kv_rank": 512}}},
-        layers=[("latent", "dense"), ("latent", "experts")],
-        dense_width=12288, expert_width=1536, n_experts=160, top_k=6,
-        experts_held=(0, 20), norm_eps=1e-6, max_length=T,
-        routing={"n_group": 8, "topk_group": 3, "renormalise": False,
-                 "scale": 16.0},
-        shared_width=3072)
-    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
-                         lambda name: None)
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = described(jax.eval_shape(lambda: init_params(cfg)))
-    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    built = programs.build("deepseek", one_chip)
+    S, T, caches = built.slots, built.length, built.caches
     slab = math.prod(caches[0][0].shape)        # one layer's: 283 M values
     assert [tuple(c.shape for c in seg) for seg in caches] == [
         ((1, S, 576, T),), ((1, S, 576, T),)]
@@ -556,11 +432,10 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip,
             r"%(\S+) = bf16\[([\d,]+)\]\S* copy\(", text)
             if math.prod(map(int, dims.split(","))) >= slab // 2]
 
-    decode = be._decode_fn.lower(
-        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    decode = built.decode()
     text = decode.as_text()
     assert "ragged-dot" in text and not slab_copies(text)
-    assert set(asked) == {(128, 576, T, "bfloat16", 512)}
+    assert set(built.asked["latent_decode"]) == {(128, 576, T, "bfloat16", 512)}
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "attn_latent_core" in line]
     assert len(kernels) == 2, kernels                   # one a segment
@@ -570,10 +445,8 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip,
     # over the cache would be 48 x 128 x 128 x 10,240 x 2 B = 16 GB
     assert not re.search(rf"f32\[{S},(1,)?128,(1,)?{T}\]", text)
     assert decode.memory_analysis().temp_size_in_bytes < 100e6
-    prefill = be._prefill_fn.lower(
-        params, caches, arg((S + 1, 8), jnp.int32),
-        arg((8 + 8192,), jnp.int32)).compile()
-    assert not slab_copies(prefill.as_text())
+    prefill = built.prefill()
+    assert built.bucket == 8192 and not slab_copies(prefill.as_text())
     # 2.1 GB planned at six layers (the plan is a layer's, not the stack's)
     assert prefill.memory_analysis().temp_size_in_bytes < 3.0e9
 
@@ -597,43 +470,9 @@ def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
     bucket attends and selects by blocks, so its plan stays under the
     4.8 GB that weights and cache leave. The plans are printed."""
     import re
-    from types import SimpleNamespace
 
-    from deeplearning4j_tpu.models.decoder_lm import (
-        DecoderConfig,
-        init_cache,
-        init_params,
-    )
-    from deeplearning4j_tpu.serving.generate import _DecoderBackend
-
-    S, T = 32, 14336
-    latent = {"q_rank": 2048, "kv_rank": 512}
-    cfg = DecoderConfig(
-        vocab_size=19360, d_model=6144, n_heads=64, head_dim=256,
-        v_head_dim=256, rotary_dim=64,
-        attn_kinds={
-            kind: {"rope_theta": 8e6, "latent": latent,
-                   "index": {"heads": 32, "head_dim": 128, "topk": 2048,
-                             "own": own}}
-            for kind, own in (("indexed", True), ("shared", False))},
-        layers=[("indexed", "dense")] + [("shared", "experts")] * 3
-        + [("indexed", "experts")],
-        dense_width=12288, expert_width=2048, n_experts=256, top_k=8,
-        experts_held=(0, 16), norm_eps=1e-5, max_length=T,
-        routing={"scoring": "sigmoid", "scale": 2.5}, shared_width=2048)
-    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
-                         lambda name: None)
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = described(jax.eval_shape(lambda: init_params(cfg)))
-    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    built = programs.build("glm", one_chip)
+    S, T, caches = built.slots, built.length, built.caches
     assert [tuple(c.shape for c in seg) for seg in caches] == [
         ((1, S, T, 640), (1, S, T, 128)), ((3, S, T, 640),),
         ((1, S, T, 640), (1, S, T, 128))]
@@ -648,8 +487,7 @@ def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
             if math.prod(map(int, dims.split(","))) >= keys // 2
             and dims.split(",")[1:2] == [str(S)]]
 
-    decode = be._decode_fn.lower(
-        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    decode = built.decode()
     text = decode.as_text()
     assert "ragged-dot" in text and not slab_copies(text), slab_copies(text)
     gathers = [line for line in text.splitlines()
@@ -660,10 +498,8 @@ def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
     # heads and never planned
     plan = decode.memory_analysis()
     assert plan.temp_size_in_bytes < 200e6
-    prefill = be._prefill_fn.lower(
-        params, caches, arg((S + 1, 8), jnp.int32),
-        arg((8 + T,), jnp.int32)).compile()
-    assert not slab_copies(prefill.as_text())
+    prefill = built.prefill()
+    assert built.bucket == T and not slab_copies(prefill.as_text())
     longest = prefill.memory_analysis()
     # 2.65 GB read: the expanded keys and values of 14,336 positions (0.47
     # GB each), the selection's mask (0.21 GB) and the blocks' scores
@@ -676,8 +512,7 @@ def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
                   f"{m.generated_code_size_in_bytes / 1e6:.1f} MB")
 
 
-def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
-                                                             monkeypatch):
+def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
     """``DecoderLM`` with state-space layers as the engine builds its
     programs, at the granite-4.0-h-small-ep2 cell's widths and FULL cut
     depth (five Mamba-2 layers of 128 heads x 64 x 128 state over 8,448
@@ -701,56 +536,11 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
     one piece would be 2.1 GB), so plan + arguments stay under the chip's
     15.75 GB."""
     import re
-    from types import SimpleNamespace
 
-    from deeplearning4j_tpu.models import decoder_lm
-    from deeplearning4j_tpu.models.decoder_lm import (
-        DecoderConfig,
-        init_cache,
-        init_params,
-    )
     from deeplearning4j_tpu.nn.ops import ssm_decode
-    from deeplearning4j_tpu.serving.generate import _DecoderBackend
 
-    asked = []
-
-    def admitted(heads, p, n, groups, slots, dtype):
-        asked.append((heads, p, n, groups, slots, jnp.dtype(dtype).name))
-        return functools.partial(ssm_decode.ssm_decode_step,
-                                 tile=ssm_decode._tile(heads, p, n, groups))
-
-    monkeypatch.setattr(decoder_lm, "ssm_decode_impl", admitted)
-    # and the attention layer reads its slab through the live-tile kernel
-    monkeypatch.setattr(decoder_lm, "decode_attention_impl", _attends)
-
-    S, T = 64, 4096
-    ssm = {"ssm": dict(n_heads=128, head_dim=64, d_state=128, n_groups=1,
-                       d_conv=4, expand=2, chunk=256)}
-    cfg = DecoderConfig(
-        vocab_size=50176, d_model=4096, n_heads=32, head_dim=128,
-        v_head_dim=128, rotary_dim=0,
-        attn_kinds={"ssm": ssm, "attention": {"n_kv_heads": 8,
-                                              "rope_theta": 1e4}},
-        layers=[("ssm", "experts")] * 5 + [("attention", "experts")]
-        + [("ssm", "experts")] * 4,
-        dense_width=0, expert_width=768, n_experts=72, top_k=10,
-        experts_held=(0, 36), shared_width=1536, max_length=T,
-        routing={"n_group": 1, "topk_group": 1, "renormalise": True},
-        embedding_multiplier=12, residual_multiplier=0.22,
-        attention_multiplier=0.0078125, logits_scaling=16, tied_head=True)
-    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
-                         lambda name: None)
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = described(jax.eval_shape(lambda: init_params(cfg)))
-    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    built = programs.build("granite", one_chip)
+    cfg, S, T, caches = built.cfg, built.slots, built.length, built.caches
     assert [tuple((c.shape, c.dtype.name) for c in seg) for seg in caches] == [
         (((5, S, 128, 8192), "float32"), ((5, S, 8448, 3), "bfloat16")),
         (((1, S, 8, 128, T), "bfloat16"),) * 2,
@@ -771,12 +561,11 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
                 found.append((dtype, dims))
         return found
 
-    decode = be._decode_fn.lower(
-        params, caches, arg((S + 1, 8), jnp.int32)).compile()
+    decode = built.decode()
     text, plan = decode.as_text(), decode.memory_analysis()
     assert 12.9e9 < plan.argument_size_in_bytes < 13.1e9
     assert "ragged-dot" in text and not big_copies(text)
-    assert set(asked) == {(128, 64, 128, 1, S, "float32")}
+    assert set(built.asked["ssm_decode"]) == {(128, 64, 128, 1, S, "float32")}
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "ssm_scan" in line]
     assert len(kernels) == 2, kernels                   # one a segment
@@ -793,9 +582,8 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip,
     assert " conditional(" in text
     assert not _sampler_work_outside_a_conditional(text, S, cfg.vocab_size)
 
-    prefill = be._prefill_fn.lower(
-        params, caches, arg((S + 1, 8), jnp.int32),
-        arg((8 + T,), jnp.int32)).compile()
+    prefill = built.prefill()
+    assert built.bucket == T
     text, plan = prefill.as_text(), prefill.memory_analysis()
     assert not big_copies(text)
     assert abs(plan.alias_size_in_bytes - cache_bytes) < 1e6
@@ -813,8 +601,7 @@ def test_compiled_for_the_described_chip(one_chip):
     assert "v5" in dev.device_kind.lower()
 
 
-def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip,
-                                                                    monkeypatch):
+def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip):
     """``DecoderLM`` with a stack that runs four times a token as the
     engine builds its decode program, at the ouro-2.6b cell's widths
     (hidden 2048, 16 heads of 128 with as many key/value heads, MLP 5632,
@@ -828,51 +615,14 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip,
     loop (``Wk`` to contraction-minor, ``Wq`` by head: 101 MB each here;
     a one-pass program of this block makes them a layer at a time)."""
     import re
-    from types import SimpleNamespace
 
-    from deeplearning4j_tpu.models.decoder_lm import (
-        DecoderConfig,
-        init_cache,
-        init_params,
-    )
-    from deeplearning4j_tpu.serving.generate import _DecoderBackend
-
-    from deeplearning4j_tpu.models import decoder_lm, transformer_lm
     from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
 
-    asked = []
-
-    def admitted(entries, slots, heads, head_size, t, dtype):
-        asked.append((entries, slots, heads, head_size, t,
-                      jnp.dtype(dtype).name))
-        return functools.partial(kcw.kv_column_write, lb=kcw.entries_a_block(
-            entries, heads, head_size, jnp.dtype(dtype).itemsize))
-
-    # this process's backend is the CPU: steer the registry's verdicts
-    monkeypatch.setattr(transformer_lm, "kv_column_write_impl", admitted)
-    monkeypatch.setattr(decoder_lm, "decode_attention_impl", _attends)
-
-    S, T, L, R = 5, 896, 12, 4
-    cfg = DecoderConfig(
-        vocab_size=49152, d_model=2048, n_heads=16, head_dim=128,
-        v_head_dim=128, rotary_dim=128,
-        attn_kinds={"full": {"n_kv_heads": 16, "rope_theta": 1e6}},
-        layers=[("full", "dense")] * L, dense_width=5632, norm_eps=1e-6,
-        max_length=T, passes=R, sandwich_norm=True, exit_gate=True)
-    be = _DecoderBackend(SimpleNamespace(cfg=cfg), 1, 128, [32],
-                         lambda name: None)
-
-    def described(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    params = described(jax.eval_shape(lambda: init_params(cfg)))
-    caches = described(jax.eval_shape(lambda: init_cache(cfg, S, T)))
+    built = programs.build("ouro", one_chip)
+    S, T, caches = built.slots, built.length, built.caches
+    L, R = built.cfg.n_layers, built.cfg.passes
     assert caches[0][0].shape == (R * L, S, 16, 128, T)
-    compiled = be._decode_fn.lower(
-        params, caches, jax.ShapeDtypeStruct((S + 1, 8), jnp.int32,
-                                             sharding=one_chip)).compile()
+    compiled = built.decode()
     text = compiled.as_text()
     a_pass = math.prod(caches[0][0].shape) // R        # elements
     # 0.10 GB planned (the relayouts); a pass's part of K is 0.55 GB
@@ -887,7 +637,8 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip,
     assert text.count(" while(") == 2
     # the after-loop write: one call of the column kernel a slab (since
     # PR 43; an update a slot and slab before)
-    assert set(asked) == {(R * L, S, 16, 128, T, "bfloat16")}
+    assert set(built.asked["kv_column_write"]) == {
+        (R * L, S, 16, 128, T, "bfloat16")}
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "kv_write" in line]
     assert len(kernels) == 2 and all(kcw.NAME in k for k in kernels)
