@@ -5,8 +5,8 @@ points a user calls, at the full width of models the repo supports.
 
     python chip_smoke.py              one chip: device, lm_train, lm_serve,
                                       hybrid_serve, sparse_serve,
-                                      looped_serve, resnet_train,
-                                      resnet_serve, kernels
+                                      looped_serve, parallel_serve,
+                                      resnet_train, resnet_serve, kernels
     python chip_smoke.py --chips 4    the cross-chip paths only: the
                                       DistributedLMTrainer on a 2x2 mesh and
                                       tensor-parallel serving on 1x4, each
@@ -60,6 +60,35 @@ FULL = {
     # of 128 heads x 64 x 128 float32 a layer, one group of B and C
     "ssm_step": dict(heads=128, p=64, n=128, groups=1, slots=64,
                      dtype="float32"),
+    # the falcon-h1-34b-l6.docqa-steady cell's three kernel keys: the
+    # state-space step at 48 slots of 32 heads x 128 x 256 float32 in two
+    # groups, decode attention at 48 slots of 4,096 with FIVE query heads a
+    # key head (4 of 128), and the column write at its six-entry slab
+    "parallel_keys": {
+        "ssm_decode_step": dict(heads=32, p=128, n=256, groups=2, slots=48,
+                                dtype="float32"),
+        "decode_attention": dict(slots=48, hkv=4, grp=5, hd=128, vd=128,
+                                 t=4096, dtype="bfloat16"),
+        "kv_column_write": dict(entries=6, slots=48, heads=4, head_size=128,
+                                t=4096, dtype="bfloat16")},
+    # attention and a Mamba-2 mixer side by side in every block at a
+    # middling size (20 query heads on 4 key heads of 64: five a key head;
+    # 8 state-space heads of 64 over a state of 64 in two groups, an inner
+    # width of 512 that is not expand x hidden; the published multipliers),
+    # float32 so that equal tokens mean something
+    "parallel": dict(
+        vocab_size=1024, d_model=640, n_heads=20, head_dim=64, v_head_dim=64,
+        rotary_dim=64,
+        attn_kinds={"parallel": {
+            "parallel": True, "n_kv_heads": 4, "rope_theta": 1e11,
+            "key_multiplier": 0.011048543456039804, "out_multiplier": 0.0375,
+            "ssm": dict(n_heads=8, head_dim=64, d_state=64, n_groups=2,
+                        d_conv=4, expand=2, chunk=8, d_inner=512,
+                        in_multiplier=0.25, out_multiplier=0.0884,
+                        multipliers=[0.354, 0.25, 0.177, 0.5, 0.354])}},
+        layers=[("parallel", "dense")] * 3, dense_width=1280, max_length=128,
+        param_dtype="float32", embedding_multiplier=5.657,
+        mlp_multipliers=[0.177, 0.0112], logits_scaling=128.0),
     # the cache's column write at the gpt2-large.chat cell's slab (36
     # layers, 24 slots of 1,024, 20 heads of 64) and at the
     # ouro-2.6b.reason-looped cell's (192 (pass, layer) entries, 5 slots of
@@ -154,6 +183,17 @@ TINY = {
 }
 TINY["hybrid"] = FULL["hybrid"]
 TINY["sparse"] = FULL["sparse"]
+TINY["parallel"] = dict(
+    FULL["parallel"], vocab_size=256, d_model=64, n_heads=10, head_dim=16,
+    v_head_dim=16, rotary_dim=16, dense_width=128,
+    attn_kinds={"parallel": dict(
+        FULL["parallel"]["attn_kinds"]["parallel"], n_kv_heads=2,
+        ssm=dict(FULL["parallel"]["attn_kinds"]["parallel"]["ssm"],
+                 head_dim=8, d_state=16, d_inner=64))})
+# (a rehearsal asks again at the keys TINY has: it checks the control flow)
+TINY["parallel_keys"] = {"ssm_decode_step": TINY["ssm_step"],
+                         "decode_attention": TINY["decode_attn"][1],
+                         "kv_column_write": TINY["kv_columns"][1]}
 TINY["looped"] = dict(
     FULL["looped"], vocab_size=256, d_model=64, n_heads=4, head_dim=16,
     v_head_dim=16, rotary_dim=16, dense_width=160, passes=3,
@@ -528,6 +568,56 @@ def phase_hybrid_serve(size):
             "tokens_equal_generate_cached": True}
 
 
+def phase_parallel_serve(size, platform):
+    """A decoder whose every block has an attention AND a state-space mixer
+    on one normed input (``models/decoder_lm.py``: ``_Parallel``), served
+    by ``GenerationEngine``: more requests than slots, so slots are claimed
+    again over another request's columns and state; every request's tokens
+    against the model's own cached generation on one slot, one step id a
+    decode step and a turn between steps (``serve_decoder``). Then the
+    three kernels of a decode step at the falcon-h1-34b-l6.docqa-steady
+    cell's keys, all probes in this one process: on the TPU a fallback at
+    any of them fails the phase."""
+    import numpy as np
+
+    from deeplearning4j_tpu.models.decoder_lm import DecoderLM
+    from deeplearning4j_tpu.nn.ops.decode_attention import decode_attention_impl
+    from deeplearning4j_tpu.nn.ops.kv_column_write import kv_column_write_impl
+    from deeplearning4j_tpu.nn.ops.ssm_decode import ssm_decode_impl
+
+    model = DecoderLM.from_dict(size["parallel"]).init()
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, model.cfg.vocab_size, n)
+               for n in (5, 9, 20, 31, 2)]
+    max_new = 24
+    _served, snapshot, ring, report, warm = serve_decoder(
+        model, prompts, max_new, [8, 16, 32])
+    check(snapshot["state_slots"] > 0 and snapshot["attn_positions_read"] > 0,
+          f"the segment counts positions read AND state slots: {snapshot}")
+    (entry,) = report["cache_plan"]
+    check(entry["bytes_state"] == report["state_bytes"]
+          and entry["bytes_columns"] == report["slab_bytes"],
+          f"the entry's bytes by half: {report}")
+    keys = size["parallel_keys"]
+    engaged = {
+        "ssm_decode_step": ssm_decode_impl(**keys["ssm_decode_step"]),
+        "decode_attention": decode_attention_impl(**keys["decode_attention"]),
+        "kv_column_write": kv_column_write_impl(**keys["kv_column_write"])}
+    fell_back = sorted(k for k, impl in engaged.items() if impl is None)
+    check(platform != "tpu" or not fell_back,
+          f"kernels fell back at the cell's keys: {fell_back}")
+    return {"requests": len(prompts), "slots": 3, "max_new": max_new,
+            "segments": model.cfg.segments(), "warmup": warm,
+            "state_bytes": report["state_bytes"],
+            "slab_bytes": report["slab_bytes"],
+            "state_slots": snapshot["state_slots"],
+            "attn_positions_read": snapshot["attn_positions_read"],
+            "kernels_at_the_cells_keys": {k: impl is not None
+                                          for k, impl in engaged.items()},
+            "late_slot_steps": snapshot["late_slot_steps"], "ring": ring,
+            "tokens_equal_generate_cached": True}
+
+
 def phase_sparse_serve(size):
     """A decoder whose latent attention reads the positions an indexer
     selects (``models/decoder_lm.py``), served by ``GenerationEngine``:
@@ -624,10 +714,12 @@ def phase_kernels(platform, size=None):
 
     if size is not None:
         latent_decode_impl(**size["latent_core"])
-        ssm_decode_impl(**size["ssm_step"])
-        for slab in size["kv_columns"]:
+        keys = size["parallel_keys"]
+        for step in (size["ssm_step"], keys["ssm_decode_step"]):
+            ssm_decode_impl(**step)
+        for slab in size["kv_columns"] + [keys["kv_column_write"]]:
             kv_column_write_impl(**slab)
-        for slabs in size["decode_attn"]:
+        for slabs in size["decode_attn"] + [keys["decode_attention"]]:
             decode_attention_impl(**slabs)
     snap = default_kernel_registry().snapshot()
     flash = {repr(k): (None if impl is None
@@ -902,6 +994,8 @@ def main(argv=None) -> int:
         run_phase("hybrid_serve", phase_hybrid_serve, meter, size)
         run_phase("sparse_serve", phase_sparse_serve, meter, size)
         run_phase("looped_serve", phase_looped_serve, meter, size)
+        run_phase("parallel_serve", phase_parallel_serve, meter, size,
+                  dev["platform"])
         run_phase("resnet_train", phase_resnet_train, meter, size)
         run_phase("resnet_serve", phase_resnet_serve, meter, size)
     run_phase("kernels", phase_kernels, meter, dev["platform"], size)

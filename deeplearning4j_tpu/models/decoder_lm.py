@@ -34,6 +34,8 @@ configuration (:class:`DecoderConfig`):
                   owner's indexer keys)         selection carried beside them
   _StateSpace     float32 state, the            CARRIED and WRITTEN in the
                   convolution's tail            loop
+  _Parallel       K, V AND state, tail: one     K, V as _KeysValues; state and
+                  entry of four slabs           tail as _StateSpace, at once
 
   entry           decode write         prefill write          decode kernel
   --------------  -------------------  ---------------------  ----------------------
@@ -46,7 +48,15 @@ configuration (:class:`DecoderConfig`):
   _IndexedLatent  a row a slot         the bucket's rows      none (a gather)
   _StateSpace     none (in the loop)   the state after the    ssm_decode: the live
                                        real tokens            slots' table
+  _Parallel       K, V columns; the    both, each under its   both, by the two
+                  state as the loop    own scope              entries it is made of
+                  left it
 
+  ``_KeysValues``, ``_StateSpace`` and ``_Parallel`` are BRANCHES off one
+  normed input (:class:`_Branches`): the norm before and the join into the
+  residual after lie with the caller, so that a block whose attention and
+  state-space mixer read the SAME normed input and are ADDED
+  (``_Parallel``) is made of the two entries, not of copies of them;
   (*) where the stack runs more than once, carried whole and a layer's
   entry taken by index; a kernel reads the slabs whole by index;
 - expert layers route over every expert of the layer and compute the
@@ -100,13 +110,15 @@ Array = jax.Array
 #: and ``attn_sparse_core`` for the core over the selection), the dense
 #: FFN, the two halves of an expert layer and its shared expert, a
 #: state-space mixer's three and ``state_write`` (a prefill's write of its
-#: slot's state and tail), and ``pass_close`` (the norm that closes each
-#: pass of a stack that runs more than once, and the exit gate's reading)
+#: slot's state and tail), ``pass_close`` (the norm that closes each
+#: pass of a stack that runs more than once, and the exit gate's reading),
+#: and ``mixer_join`` (a parallel block's one norm before its two branches
+#: and their sum's way into the residual)
 SCOPES = ("attn_full", "attn_window", "attn_latent_proj", "attn_latent_core",
           "mlp", "moe_route", "moe_experts", "moe_shared",
           "ssm_proj", "ssm_conv", "ssm_scan", "state_write",
           "attn_index_proj", "attn_index_score", "attn_index_select",
-          "attn_sparse_core", "pass_close")
+          "attn_sparse_core", "pass_close", "mixer_join")
 _scope = jax.named_scope
 _NEG = -1e30
 #: queries and keys a block of a latent layer's prefill attention
@@ -130,6 +142,19 @@ _SSM_FIELDS = ("n_heads", "head_dim", "d_state", "n_groups", "d_conv",
                "expand", "chunk")
 
 
+def _ssm_kind(m: dict, d_model: int) -> dict:
+    """A state-space kind's statement, read: ``_SSM_FIELDS`` as integers;
+    ``d_inner`` (``expand x d_model`` unless stated); the three muP
+    multipliers, 1 / None unless stated."""
+    out = {f: int(m[f]) for f in _SSM_FIELDS}
+    out["d_inner"] = int(m.get("d_inner") or out["expand"] * d_model)
+    out["in_multiplier"] = float(m.get("in_multiplier", 1.0))
+    out["out_multiplier"] = float(m.get("out_multiplier", 1.0))
+    out["multipliers"] = (None if m.get("multipliers") is None
+                          else tuple(float(v) for v in m["multipliers"]))
+    return out
+
+
 class DecoderConfig:
     """The decoder as data. ``attn_kinds``: name -> a kind of MIXER (the
     name stays from when every mixer attended). An attention kind is
@@ -150,7 +175,17 @@ class DecoderConfig:
     (Mamba-2: ``expand x d_model`` = ``n_heads x head_dim`` inner channels,
     a state of ``head_dim x d_state`` a head, B and C shared by the heads of
     a group, a causal depthwise convolution ``d_conv`` wide, prefill by
-    chunks of ``chunk``); it takes none of the attention keys. ``layers``:
+    chunks of ``chunk``; ``d_inner`` where the inner width is stated apart
+    from ``expand``; ``in_multiplier`` on the mixer's input,
+    ``out_multiplier`` on its output and ``multipliers``, five, on the [z |
+    x | B | C | dt] parts of its input projection: muP's fixed scalars,
+    applied as the published code applies them); it takes none of the
+    attention keys, unless ``"parallel"`` is true: the kind is then BOTH,
+    a full attention (``n_kv_heads``, ``rope_theta``; no window, sink or
+    latent) and the state-space mixer side by side on one normed input,
+    their outputs added (:class:`_Parallel`). An attention kind may state
+    ``in_multiplier``, ``key_multiplier`` (on the keys, before rotation)
+    and ``out_multiplier`` likewise. ``layers``:
     one (mixer kind, "dense" | "experts") pair a layer. ``experts_held`` =
     (offset, count): which of the ``n_experts`` the router scores have their
     weights here. ``routing``: {"scoring": "sigmoid", "scale"} (sigmoid
@@ -162,8 +197,10 @@ class DecoderConfig:
     vocabulary is sliced). ``rotary_dim`` 0: no positions.
     ``embedding_multiplier`` scales the embedded tokens,
     ``residual_multiplier`` every residual branch (mixer and FFN),
-    ``attention_multiplier`` the attention scores (None: ``1/sqrt(head)``)
-    and ``logits_scaling`` divides the logits; ``tied_head``: the head is
+    ``attention_multiplier`` the attention scores (None: ``1/sqrt(head)``),
+    ``mlp_multipliers`` (gate, down) the dense MLP's gate before its
+    activation and its output, and ``logits_scaling`` divides the logits;
+    ``tied_head``: the head is
     the embedding, one leaf, read transposed. ``passes``: how many times the
     whole stack is applied to every token, with ONE set of weights: each
     pass closes with the final norm, the next starts from the normed stream,
@@ -191,7 +228,8 @@ class DecoderConfig:
                  attention_multiplier: Optional[float] = None,
                  logits_scaling: float = 1.0, tied_head: bool = False,
                  passes: int = 1, sandwich_norm: bool = False,
-                 exit_gate: bool = False, exit_threshold: float = 1.0):
+                 exit_gate: bool = False, exit_threshold: float = 1.0,
+                 mlp_multipliers: Optional[Sequence[float]] = None):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.n_heads = int(n_heads)
@@ -217,8 +255,11 @@ class DecoderConfig:
                               "topk": int(k["index"]["topk"]),
                               "own": bool(k["index"]["own"])}
                              if k.get("index") else None),
-                   "ssm": ({f: int(k["ssm"][f]) for f in _SSM_FIELDS}
-                           if k.get("ssm") else None)}
+                   "ssm": (_ssm_kind(k["ssm"], self.d_model)
+                           if k.get("ssm") else None),
+                   "parallel": bool(k.get("parallel", False)),
+                   **{m: float(k.get(m, 1.0)) for m in
+                      ("in_multiplier", "key_multiplier", "out_multiplier")}}
             for name, k in attn_kinds.items()}
         for name, k in self.attn_kinds.items():
             if k["latent"] and (k["window"] is not None or k["sink"]):
@@ -233,13 +274,16 @@ class DecoderConfig:
             if k["ssm"]:
                 m = k["ssm"]
                 if (k["latent"] or k["window"] is not None or k["sink"]
-                        or m["n_heads"] * m["head_dim"]
-                        != m["expand"] * self.d_model
-                        or m["n_heads"] % m["n_groups"]):
+                        or m["n_heads"] * m["head_dim"] != m["d_inner"]
+                        or m["n_heads"] % m["n_groups"]
+                        or (m["multipliers"] and len(m["multipliers"]) != 5)):
                     raise ValueError(
-                        f"ssm kind {name!r}: n_heads x head_dim = expand x "
-                        "d_model, n_groups dividing n_heads, and none of "
-                        "the attention keys")
+                        f"ssm kind {name!r}: n_heads x head_dim = d_inner "
+                        "(expand x d_model unless stated), n_groups dividing "
+                        "n_heads, five multipliers or none, and no window, "
+                        "sink or latent")
+            elif k["parallel"]:
+                raise ValueError(f"parallel kind {name!r} states no ssm")
         self.layers = [(str(a), str(f)) for a, f in layers]
         owner = False
         for a, f in self.layers:
@@ -287,6 +331,11 @@ class DecoderConfig:
                                      else float(attention_multiplier))
         self.logits_scaling = float(logits_scaling)
         self.tied_head = bool(tied_head)
+        self.mlp_multipliers = (
+            (1.0, 1.0) if mlp_multipliers is None
+            else tuple(float(v) for v in mlp_multipliers))
+        if len(self.mlp_multipliers) != 2:
+            raise ValueError("mlp_multipliers: (gate, down) or None")
         self.passes = int(passes)
         self.sandwich_norm = bool(sandwich_norm)
         self.exit_gate = bool(exit_gate)
@@ -1017,19 +1066,23 @@ def _ssm_step(h, x, dt, a, bvec, cvec):
     return y, h_new
 
 
-def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
+def _ssm_mixer(m: "_StateSpace", bp: Dict[str, Array], a_in: Array,
                cache=None, token_mask=None):
-    """A state-space layer's first half (Mamba-2) on x (b, Tq, d): returns
-    (x + its output, what the layer keeps).
+    """A state-space mixer (Mamba-2), entry ``m``, as a BRANCH: on a_in
+    (b, Tq, d), the block's normed input, returns (its output (b, Tq, d),
+    what the layer keeps); the norm before it and the join into the
+    residual are the caller's (``_Branches.mix``).
 
-    ``[z | xBC | dt] = RMSNorm(x) Win``; a causal depthwise convolution
+    ``[z | xBC | dt] = (a_in Win)``, times the kind's ``in_multiplier`` on
+    a_in and its five ``multipliers`` on the parts [z | x | B | C | dt] of
+    the product where it states them; a causal depthwise convolution
     ``d_conv`` wide over time on ``xBC``, plus bias, then SiLU; ``[x | B |
     C]`` cut from it; ``dt = softplus(dt + dt_bias)`` and ``A = -exp(A_log)``
     a head; the recurrence (:func:`_ssm_chunked`, :func:`_ssm_step`) with
     the skip ``D x``; the gated norm ``RMSNorm(y * silu(z))`` over a
-    group's channels; ``Wo``. Everything between the two projections is
-    float32 but ``xBC`` itself, which is rounded to the parameter dtype
-    before the convolution, as the cached tail is.
+    group's channels; ``Wo``, times ``out_multiplier``. Everything between
+    the two projections is float32 but ``xBC`` itself, which is rounded to
+    the parameter dtype before the convolution, as the cached tail is.
 
     Without a cache (forward, prefill) the whole sequence goes through the
     chunked form from a zero state, padding (``token_mask`` False) gets
@@ -1046,15 +1099,18 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
     (``nn/ops/ssm_decode.live_table``), the state goes through the kernel
     that visits those slots alone, each block once, in ``_ssm_step``'s
     stead."""
-    m = cfg.mixer(kind)
+    cfg = m.cfg
     heads, p, n, inner, conv = m.n_heads, m.head_dim, m.d_state, m.inner, m.conv
     g, k = m.n_groups, m.d_conv
     r = heads // g
-    b, tq, _d = x.shape
-    f32, dt_ = jnp.float32, x.dtype
+    b, tq, _d = a_in.shape
+    f32, dt_ = jnp.float32, a_in.dtype
     with _scope("ssm_proj"):
-        a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(dt_)
+        if m.in_multiplier != 1.0:
+            a_in = a_in * m.in_multiplier
         proj = jnp.matmul(a_in, bp["Win"], preferred_element_type=f32)
+        if m.multipliers:
+            proj = proj * m.mup
         z = proj[..., :inner]
         xbc = proj[..., inner:inner + conv].astype(dt_)
         dt = proj[..., inner + conv:]
@@ -1120,8 +1176,10 @@ def _ssm_mixer(cfg: DecoderConfig, kind: str, bp: Dict[str, Array], x: Array,
             z.reshape(b, tq, g, inner // g))
         gated = _rms_norm(gated, bp["norm_g"].reshape(g, inner // g),
                           cfg.norm_eps).reshape(b, tq, inner).astype(dt_)
-        x = _residual(cfg, x, _branch(cfg, bp, "norm1b", gated @ bp["Wo"]))
-    return x, made
+        out = gated @ bp["Wo"]
+        if m.out_multiplier != 1.0:
+            out = out * m.out_multiplier
+    return out, made
 
 
 def _experts(cfg: DecoderConfig, bp: Dict[str, Array], r_in: Array, dtype,
@@ -1195,7 +1253,7 @@ class _Mixer(abc.ABC):
         """The entry that ``attn_kinds[kind]`` describes."""
         ak = cfg.attn_kinds[kind]
         if ak["ssm"]:
-            return _StateSpace(cfg, kind)
+            return (_Parallel if ak["parallel"] else _StateSpace)(cfg, kind)
         if ak["latent"]:
             return (_IndexedLatent if ak["index"] else _Latent)(cfg, kind)
         return (_KeysValues if ak["window"] is None else _Ring)(cfg, kind)
@@ -1269,14 +1327,53 @@ class _Mixer(abc.ABC):
         made at q_pos (b, 1), into the (donated) slabs as the loop handed
         them on; ``active`` (b,) bool or None."""
 
+    def after(self, slabs, held):
+        """A segment's slabs as the layer loop hands them on, for the
+        after-loop write: those it carried (``held``, :meth:`open`'s second
+        answer as the scan's end has it), else ``slabs`` as they came."""
+        return slabs if held is None else held
+
     def fill(self, slabs, new, slot, length):
         """A prefill's write of ``new`` (entries, 1, ...), made of a bucket's
         positions, ``length`` real, into row ``slot`` of the (donated)
-        slabs: as made, unless the kind lays its slabs out otherwise."""
+        slabs, under the kind's ``fill_scope``."""
+        with _scope(self.fill_scope):
+            return self._fill(slabs, new, slot, length)
+
+    def _fill(self, slabs, new, slot, length):
+        """:meth:`fill`'s write: as made, unless the kind lays its slabs
+        out otherwise."""
         return tuple(_write_slot(c, n, slot) for c, n in zip(slabs, new))
 
 
-class _KeysValues(_Mixer):
+class _Branches(_Mixer):
+    """A mixer that is one BRANCH, or several, off the block's one normed
+    input: :meth:`mix` is the norm (``norm1``), the branches
+    (:meth:`branch`) and the join of what they give into the residual
+    (``residual_multiplier``, ``norm1b`` under ``sandwich_norm``), the norm
+    and the join under the kind's ``join_scope``. An entry made of other
+    entries (:class:`_Parallel`) hands the one normed input to each."""
+
+    #: the scope the norm and the join run under
+    join_scope: str
+
+    def mix(self, bp, x, q_pos, view, token_mask, sel):
+        cfg = self.cfg
+        with _scope(self.join_scope):
+            a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(x.dtype)
+        out, made, wrote = self.branch(bp, a_in, q_pos, view, token_mask)
+        with _scope(self.join_scope):
+            x = _residual(cfg, x, _branch(cfg, bp, "norm1b", out))
+        return x, made, sel, wrote
+
+    @abc.abstractmethod
+    def branch(self, bp, a_in, q_pos, view, token_mask):
+        """The kind's branch on the normed input a_in (b, Tq, d) -> (its
+        output (b, Tq, d); what it made to cache of the step's own
+        positions; the carried slabs where it WROTE them, else None)."""
+
+
+class _KeysValues(_Branches):
     """Keys and values by head over the slot's whole length, T-minor: K
     (entries, slots, hkv, head, T) and V. The queries attend, under one
     softmax (with the learned ``sink`` where the kind has one), to the
@@ -1290,6 +1387,10 @@ class _KeysValues(_Mixer):
         ak = cfg.attn_kinds[kind]
         self.hkv, self.sink, self.window = (ak["n_kv_heads"], ak["sink"],
                                             ak["window"])
+        self.in_multiplier, self.key_multiplier, self.out_multiplier = (
+            ak["in_multiplier"], ak["key_multiplier"], ak["out_multiplier"])
+        self.join_scope = ("attn_full" if self.window is None
+                           else "attn_window")
 
     def leaves(self):
         """``Wq`` is stored by head, (d, heads, head size): flat, the TPU
@@ -1343,20 +1444,25 @@ class _KeysValues(_Mixer):
         return None, None, lambda _sliced, _held, at: (
             "tiles", *slabs, at, core[0], walk)
 
-    def mix(self, bp, x, q_pos, view, token_mask, sel):
+    def branch(self, bp, a_in, q_pos, view, token_mask):
         """Makes the layer's new (b, hkv, Tq, head) keys and (b, hkv, Tq,
         value size) values. Without a cache, a sink or a window, a bucket
         whose float32 scores would pass ``BLOCKED_SCORE_BYTES`` attends by
-        blocks."""
+        blocks. The kind's multipliers, where it states them: on the
+        input, on the keys before they are rotated, on the output."""
         cfg, window = self.cfg, self.window
+        x = a_in  # the stream's dtype
         b, tq, _d = x.shape
         hq, hkv, hd, vd = cfg.n_heads, self.hkv, cfg.head_dim, cfg.v_head_dim
         grp = hq // hkv
         scale = softmax_scale(cfg, self.kind)
-        with _scope("attn_window" if window is not None else "attn_full"):
-            a_in = _rms_norm(x, bp["norm1"], cfg.norm_eps).astype(x.dtype)
+        with _scope(self.join_scope):
+            if self.in_multiplier != 1.0:
+                a_in = a_in * self.in_multiplier
             q = jnp.einsum("btd,dhk->bthk", a_in, bp["Wq"])
             k = (a_in @ bp["Wk"]).reshape(b, tq, hkv, hd)
+            if self.key_multiplier != 1.0:
+                k = k * self.key_multiplier
             v = (a_in @ bp["Wv"]).reshape(b, tq, hkv, vd)
             q = _rotate(q, q_pos, cfg.rotary_dim, self.theta)
             k = _rotate(k, q_pos, cfg.rotary_dim, self.theta)
@@ -1411,8 +1517,10 @@ class _KeysValues(_Mixer):
                     z = z + jnp.exp(sink - m)  # the sink takes weight, adds no value
                 o = o * (cfg.value_scale / z[..., None])
                 o = o.transpose(0, 3, 1, 2, 4).reshape(b, tq, hq * vd).astype(x.dtype)
-            x = _residual(cfg, x, _branch(cfg, bp, "norm1b", o @ bp["Wo"]))
-        return x, (kh, vh), sel, None
+            out = o @ bp["Wo"]
+            if self.out_multiplier != 1.0:
+                out = out * self.out_multiplier
+        return out, (kh, vh), None
 
     def put(self, slabs, new, q_pos, active):
         """One in-place column a live row (``_put_columns``: one kernel
@@ -1421,7 +1529,7 @@ class _KeysValues(_Mixer):
         return tuple(_put_columns(c, n, wp, active)
                      for c, n in zip(slabs, new))
 
-    def fill(self, slabs, new, slot, length):
+    def _fill(self, slabs, new, slot, length):
         """The bucket's columns at 0..Tb-1: padding follows the real
         tokens, so causal attention keeps it from them."""
         return tuple(_write_slot(c, n.transpose(0, 1, 2, 4, 3), slot)
@@ -1455,7 +1563,7 @@ class _Ring(_KeysValues):
 
         return tuple(select(c, n) for c, n in zip(slabs, new))
 
-    def fill(self, slabs, new, slot, length):
+    def _fill(self, slabs, new, slot, length):
         """Column c gets the latest real position congruent to c: the
         prompt's last ``window`` columns where it is longer than the ring."""
         cols = slabs[0].shape[-1]
@@ -1529,7 +1637,7 @@ class _Latent(_Mixer):
         return (_put_columns(slabs[0][:, :, None], new[0][:, :, None], wp,
                              active)[:, :, 0],)
 
-    def fill(self, slabs, new, slot, length):
+    def _fill(self, slabs, new, slot, length):
         return (_write_slot(slabs[0], new[0].transpose(0, 1, 3, 2), slot),)
 
 
@@ -1614,10 +1722,10 @@ class _IndexedLatent(_Latent):
             out.append(slab)
         return tuple(out)
 
-    fill = _Mixer.fill  # the bucket's rows, as made
+    _fill = _Mixer._fill  # the bucket's rows, as made
 
 
-class _StateSpace(_Mixer):
+class _StateSpace(_Branches):
     """A state-space (Mamba-2) mixer (:func:`_ssm_mixer`): no columns, no
     position map. Its cache is ``state`` (entries, slots, state size, heads
     x head size) in float32 (a bfloat16 state would round at every step of
@@ -1629,6 +1737,7 @@ class _StateSpace(_Mixer):
     (a scan's stacked output would be a second copy of the state)."""
 
     fill_scope = "state_write"
+    join_scope = "ssm_proj"
     keeps_state = True
 
     def __init__(self, cfg, kind):
@@ -1636,8 +1745,15 @@ class _StateSpace(_Mixer):
         for field, value in cfg.attn_kinds[kind]["ssm"].items():
             setattr(self, field, value)
         #: inner channels, and the convolved ones ([x | B | C])
-        self.inner = self.n_heads * self.head_dim
+        self.inner = self.d_inner
         self.conv = self.inner + 2 * self.n_groups * self.d_state
+        if self.multipliers:
+            #: the five multipliers spread over the input projection's
+            #: columns [z | x | B | C | dt], float32 as the product is
+            bc = self.n_groups * self.d_state
+            self.mup = np.repeat(
+                np.asarray(self.multipliers, np.float32),
+                [self.inner, self.inner, bc, bc, self.n_heads])
 
     def leaves(self):
         """The input projection is ONE leaf, ``Win`` (d, inner + convolved
@@ -1673,15 +1789,89 @@ class _StateSpace(_Mixer):
                                if token_mask is None else token_mask[:, 0])
         return None, slabs, lambda _sliced, held, at: (*held, at, table)
 
-    def mix(self, bp, x, q_pos, view, token_mask, sel):
+    def branch(self, bp, a_in, q_pos, view, token_mask):
         """Over a cache nothing is made for an after-loop write: the two
         arrays come back WRITTEN, the loop's carry."""
-        x, kept = _ssm_mixer(self.cfg, self.kind, bp, x, view, token_mask)
-        return (x, kept, sel, None) if view is None else (x, (), sel, kept)
+        out, kept = _ssm_mixer(self, bp, a_in, view, token_mask)
+        return (out, kept, None) if view is None else (out, (), kept)
 
     def put(self, slabs, new, q_pos, active):
         """Written inside the loop, in place: as they are."""
         return tuple(slabs)
+
+
+class _Parallel(_Branches):
+    """Attention AND a state-space mixer in one block (Falcon-H1): both
+    read the block's one normed input and their outputs are added before
+    the sum joins the residual. The entry is MADE OF the two that exist, a
+    :class:`_KeysValues` and a :class:`_StateSpace` of the same kind's
+    statement, and keeps no decision of theirs a second time: its leaves
+    are both sets (the state-space output projection as ``Wso``, beside
+    the attention's ``Wo``; one ``norm1``), its cache entry FOUR slabs (K,
+    V, ``state``, ``conv``), K and V read and written as ``_KeysValues``
+    says and the state and tail as ``_StateSpace`` says, in the same layer
+    loop: the scan slices K and V (or a kernel reads them whole by index)
+    while it carries, and writes, the state."""
+
+    attends = keeps_state = True
+    join_scope = "mixer_join"
+
+    def __init__(self, cfg, kind):
+        super().__init__(cfg, kind)
+        self.attn, self.ssm = _KeysValues(cfg, kind), _StateSpace(cfg, kind)
+
+    def positions(self, pos, slabs):
+        return self.attn.positions(pos, slabs[:2])
+
+    def leaves(self):
+        ssm = self.ssm.leaves()
+        return {**self.attn.leaves(), **{k: v for k, v in ssm.items()
+                                         if k != "Wo"}, "Wso": ssm["Wo"]}
+
+    def plan(self, entries, slots, max_length):
+        """``bytes_columns`` and ``bytes_state``: the entry's bytes by half,
+        what grows with the slot's length and what does not."""
+        a = self.attn.plan(entries, slots, max_length)
+        s = self.ssm.plan(entries, slots, max_length)
+        return self._plan(a["slabs"] + s["slabs"], entries,
+                          a["dtypes"] + s["dtypes"], columns=a["columns"],
+                          ring=False, k=a["k"], v=a["v"], values=a["values"],
+                          state=s["state"], conv=s["conv"],
+                          bytes_columns=a["bytes"], bytes_state=s["bytes"])
+
+    def open(self, slabs, q_pos, c_pos, token_mask, looped):
+        """Both entries' answers at once: what the attention has the scan
+        slice; carried, (what the attention has it carry or None, the
+        state and tail); a layer's view, the two views and the
+        attention's carried slabs, which :meth:`branch` hands on as they
+        came beside the state-space branch's written ones."""
+        sliced, a_held, a_look = self.attn.open(slabs[:2], q_pos, c_pos,
+                                                token_mask, looped)
+        _none, s_held, s_look = self.ssm.open(slabs[2:], q_pos, c_pos,
+                                              token_mask, looped)
+        return sliced, (a_held, s_held), lambda cut, held, at: (
+            a_look(cut, held[0], at), s_look(None, held[1], at), held[0])
+
+    def after(self, slabs, held):
+        a_held, s_held = held
+        return (*(slabs[:2] if a_held is None else a_held), *s_held)
+
+    def branch(self, bp, a_in, q_pos, view, token_mask):
+        a_view, s_view, a_held = view or (None, None, None)
+        a, kv, _ = self.attn.branch(bp, a_in, q_pos, a_view, token_mask)
+        s, kept, wrote = self.ssm.branch({**bp, "Wo": bp["Wso"]}, a_in, q_pos,
+                                         s_view, token_mask)
+        return a + s, kv + kept, None if view is None else (a_held, wrote)
+
+    def put(self, slabs, new, q_pos, active):
+        """A column a live row for K and V; the state as the loop wrote it."""
+        return (*self.attn.put(slabs[:2], new, q_pos, active),
+                *self.ssm.put(slabs[2:], (), q_pos, active))
+
+    def fill(self, slabs, new, slot, length):
+        """Both writes, each under its own scope."""
+        return (*self.attn.fill(slabs[:2], new[:2], slot, length),
+                *self.ssm.fill(slabs[2:], new[2:], slot, length))
 
 
 def block(cfg: DecoderConfig, kind: str, ffn: str, bp: Dict[str, Array],
@@ -1707,8 +1897,14 @@ def _ffn(cfg: DecoderConfig, ffn: str, bp: Dict[str, Array], x: Array,
     if ffn == "dense":
         with _scope("mlp"):
             m_in = _rms_norm(x, bp["norm2"], cfg.norm_eps).astype(x.dtype)
-            h = jax.nn.silu(m_in @ bp["Wg"]) * (m_in @ bp["Wu"])
-            x = _residual(cfg, x, _branch(cfg, bp, "norm2b", h @ bp["Wd"]))
+            gate, down = cfg.mlp_multipliers
+            h = m_in @ bp["Wg"]
+            if gate != 1.0:
+                h = h * gate
+            h = (jax.nn.silu(h) * (m_in @ bp["Wu"])) @ bp["Wd"]
+            if down != 1.0:
+                h = h * down
+            x = _residual(cfg, x, _branch(cfg, bp, "norm2b", h))
         counts = (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
     else:
         with _scope("moe_route"):
@@ -1738,7 +1934,8 @@ def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
     Returns (x; per segment what the layers made to cache, stacked
     (layers, b, ...); summed expert counters; per segment the slabs as the
     loops hand them on, for the after-loop write to take, None without a
-    cache)."""
+    cache: ``_Mixer.after``, which a kind whose entry is carried in part
+    puts together from what came and what the loop carried)."""
     made, held_out = [], []
     pairs = hit = jnp.zeros((), jnp.int32)
     sel = None
@@ -1774,7 +1971,7 @@ def _run_pass(cfg: DecoderConfig, params: Dict, x: Array, q_pos: Array,
             (scanned, sliced, jnp.arange(n, dtype=jnp.int32)))
         made.append(knew)
         # the loop's own hand-on where it carried the slabs, else as given
-        held_out.append(slabs if held is None else held)
+        held_out.append(None if slabs is None else mixer.after(slabs, held))
         pairs, hit = pairs + counts[0].sum(), hit + counts[1].sum()
     return x, made, (pairs, hit), None if caches is None else held_out
 
@@ -1918,7 +2115,8 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
                  length: Array, slot: Array):
     """One prompt, right-padded to a bucket: ids (1, Tb), ``length`` real
     tokens, into row ``slot`` of every slab, from ONE pass, each segment's
-    write as its kind's entry says (``_Mixer.fill``), under its scope.
+    write as its kind's entry says (``_Mixer.fill``), under its scope (a
+    parallel block's two, each under its own).
     Padding follows the real tokens: causal attention keeps it from them,
     the expert layers leave it out and a state-space layer's state passes
     it by. Returns (logits (1, V) at length-1, caches)."""
@@ -1929,9 +2127,7 @@ def prefill_slot(cfg: DecoderConfig, params: Dict, caches, ids: Array,
         cfg, params, _embed(cfg, params, ids), q_pos, token_mask=real)
     out = []
     for (kind, _f, _n), slabs, new in zip(cfg.segments(), caches, new_kv):
-        mixer = cfg.mixer(kind)
-        with _scope(mixer.fill_scope):
-            out.append(mixer.fill(tuple(slabs), new, slot, length))
+        out.append(cfg.mixer(kind).fill(tuple(slabs), new, slot, length))
     x_last = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
                                           keepdims=False)
     return _head(cfg, params, x_last), out

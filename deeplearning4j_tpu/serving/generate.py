@@ -876,7 +876,11 @@ class _DecoderBackend:
     every decode step inside the layer loop (idle slots bit for bit as
     they were) and written for one slot by a prefill. With such a layer
     K > 1 and a prefix cache are REFUSED (:class:`RecurrentStateError`):
-    a state cannot be rolled back by dropping columns. A stack that runs
+    a state cannot be rolled back by dropping columns. A PARALLEL block
+    (attention and a state-space mixer side by side) keeps both in ONE
+    plan entry, K and V slabs and the state and tail: its segment counts
+    the positions its slots read AND the slots it advances, its slots'
+    bytes are both kinds', and it is refused what any state is. A stack that runs
     several passes a token (``DecoderConfig.passes``) keeps all of the
     above a PASS, passes x layers entries a segment, read and written by
     the same programs; an ``exit_threshold`` under 1 is REFUSED
@@ -1614,12 +1618,14 @@ def generation_memory_report(model, n_slots: int,
         out["cache_plan"] = [
             {k: p[k] for k in ("kind", "layers", "passes", "columns", "ring",
                                "values", "row", "index", "bytes", "state",
-                               "conv")
+                               "conv", "bytes_columns", "bytes_state")
              if k in p}
             for p in plan]
         # the recurrent state (and its tails) apart from the slabs of
-        # columns: the first does not grow with max_length
-        out["state_bytes"] = sum(p["bytes"] for p in plan if "state" in p)
+        # columns: the first does not grow with max_length; an entry that
+        # is both (a parallel block's) says its bytes by half
+        out["state_bytes"] = sum(p.get("bytes_state", p["bytes"])
+                                 for p in plan if "state" in p)
         out["slab_bytes"] = int(cache) - out["state_bytes"]
     return out
 

@@ -9,6 +9,7 @@ program: what two trees must agree on to be the same programs.
     glm       glm-5.2-ep16: the whole cut (owner, three sharers, owner)
     granite   granite-4.0-h-small-ep2: the whole cut (5 + 1 + 4 layers)
     ouro      ouro-2.6b: 12 of the 48 layers, four passes
+    falcon    falcon-h1-34b-l6: the whole cut (six parallel blocks)
 
 Each ``DecoderLM`` cell gives its decode program and one prefill program;
 the registry's verdicts are steered as the chip's probes give them (this
@@ -23,7 +24,7 @@ A line holds the opcode histogram of the optimised HLO, the custom-call
 targets (a Mosaic kernel by its name), temporary / argument / output /
 alias bytes of the plan, and a hash of the HLO text without its metadata
 (source lines move with every edit; instruction names do not). Nothing
-runs: a compile that passes is not a chip run. ``--tiny`` compiles the six
+runs: a compile that passes is not a chip run. ``--tiny`` compiles the
 tiny models of ``tests/decoder_kinds.py`` for the CPU in seconds instead.
 """
 
@@ -208,6 +209,25 @@ def _ouro():
         max_length=896, passes=4, sandwich_norm=True, exit_gate=True)
 
 
+def _falcon():
+    ssm = dict(n_heads=32, head_dim=128, d_state=256, n_groups=2, d_conv=4,
+               expand=2, chunk=128, d_inner=4096, in_multiplier=0.25,
+               out_multiplier=0.08838834764831845,
+               multipliers=[0.3535533905932738, 0.25, 0.1767766952966369,
+                            0.5, 0.3535533905932738])
+    return _decoder(
+        vocab_size=261120, d_model=5120, n_heads=20, head_dim=128,
+        v_head_dim=128, rotary_dim=128,
+        attn_kinds={"parallel": {
+            "parallel": True, "n_kv_heads": 4, "rope_theta": 1e11,
+            "key_multiplier": 0.011048543456039804,
+            "out_multiplier": 0.0375, "ssm": ssm}},
+        layers=[("parallel", "dense")] * 6, dense_width=21504,
+        max_length=4096, embedding_multiplier=5.656854249492381,
+        mlp_multipliers=[0.1767766952966369, 0.011160714285714284],
+        logits_scaling=128.0)
+
+
 #: cell -> (its configuration at the cut depth, slots, the prefill bucket
 #: compiled (None: the cell has a decode program only), the verdicts
 #: steered: the kernels the chip's registry admits in that cell's programs
@@ -219,6 +239,8 @@ CELLS = {
     "glm": (_glm, 32, 14336, ()),
     "granite": (_granite, 64, 4096, ("ssm_decode", "decode_attention")),
     "ouro": (_ouro, 5, 256, ("kv_column_write", "decode_attention")),
+    "falcon": (_falcon, 48, 4096, ("ssm_decode", "decode_attention",
+                                   "kv_column_write")),
 }
 
 

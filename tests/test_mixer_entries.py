@@ -1,10 +1,11 @@
-"""One entry a mixer kind (``decoder_lm._Mixer``), over the six tiny models
+"""One entry a mixer kind (``decoder_lm._Mixer``), over the seven tiny models
 of ``tests/decoder_kinds.py``: the entry's plan is what ``init_cache``
 allocates; its two writers (a prefill's ``fill``, a decode step's ``put``)
 change the claimed slot and nothing else; what the engine counts for a kind
 is read from the plan; and a kind that leaves an answer out fails where the
 configuration is built."""
 
+import json
 import os
 import sys
 from types import SimpleNamespace
@@ -32,7 +33,12 @@ COUNTED = {"dense": (False, True, 0, False, 5, 168960),
            "latent": (True, False, 0, False, 3, 73728),
            "sparse-latent": (True, False, 8, False, 4, 442368),
            "state-space": (False, True, 0, True, 1, 140160),
-           "looped": (False, True, 0, False, 9, 884736)}
+           "looped": (False, True, 0, False, 9, 884736),
+           # K and V of 3 layers (2 heads x 16 x 64) + their states (16 x 64
+           # float32) and tails (64 + 2 x 2 x 16 channels x 3), 3 slots,
+           # float32 parameters
+           "parallel": (False, True, 0, True, 3,
+                        3 * 3 * 4 * (2 * 2 * 16 * 64 + 16 * 64 + 128 * 3))}
 
 
 @pytest.fixture(scope="module", params=KINDS)
@@ -55,11 +61,14 @@ def test_the_plan_is_what_init_cache_allocates(model):
         assert all(c.shape[:2] == (cfg.passes * layers, SLOTS) for c in slabs)
 
 
-def _positions_axis(p):
+def _positions_axis(p, slab):
     """The axis of ONE SLOT's part of a slab (entries, ...) that holds
     positions: the rows of a position-major slab, the columns of a T-minor
-    one; None for a kind without columns."""
-    return None if not p["columns"] else 1 if "row" in p else -1
+    one; None for a kind without columns, and for the state and the tail
+    of an entry that keeps both."""
+    if not p["columns"] or tuple(slab) in (p.get("state"), p.get("conv")):
+        return None
+    return 1 if "row" in p else -1
 
 
 def test_the_two_writers_change_the_claimed_slot_only(model):
@@ -94,8 +103,8 @@ def test_the_two_writers_change_the_claimed_slot_only(model):
             cfg, p, c, jnp.asarray([3, 4, 5], jnp.int32), jnp.asarray(pos),
             jnp.asarray(active)))(m.params_, filled)
     for p, was, now in zip(plan, filled_np, stepped):
-        axis = _positions_axis(p)
-        for w, n in zip(was, now):
+        for w, n, slab in zip(was, now, p["slabs"]):
+            axis = _positions_axis(p, slab)
             n = np.asarray(n)
             assert (n[:, 1] != w[:, 1]).any()
             for idle in (0, 2):
@@ -117,6 +126,36 @@ def test_what_the_engine_counts_is_read_from_the_plan(model):
     plan = m.cfg.cache_plan(SLOTS, LENGTH)
     assert be.cache_entries == sum(p["entries"] for p in plan)
     assert be.keeps_state == any("state" in p for p in plan)
+
+
+@pytest.mark.parametrize("kind", decoder_kinds.BEFORE_JOIN)
+def test_the_kinds_that_were_there_give_what_they_gave_before_the_join_moved(kind):
+    """``_KeysValues`` and ``_StateSpace`` gave their norm and their residual
+    add to a shared caller (``_Branches.mix``) so that a parallel block can
+    be made of them: the six kinds that were there give the logits (full
+    forward, prefill, one decode step) and every cache slab that the tree
+    before gave (``tests/fixtures/decoder_lm/kinds_before_join.json``,
+    written by that tree: ``python tests/decoder_kinds.py <file>``), within
+    an ulp of their scale (two builds of XLA:CPU may contract multiply-adds
+    differently; on the machine that wrote the fixture every SHA-256 is
+    equal, bit for bit: PR 47)."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
+                           "decoder_lm", "kinds_before_join.json")) as f:
+        before = json.load(f)[kind]
+    now = decoder_kinds.readings(kind)
+
+    def same(was, got):
+        scale = max(was["abs_mean"], 1e-30)
+        np.testing.assert_allclose(got["first"], was["first"], atol=2e-5 * scale,
+                                   rtol=0)
+        assert abs(got["abs_mean"] - was["abs_mean"]) <= 1e-5 * scale
+
+    for part in ("forward", "prefill", "decode"):
+        same(before[part], now[part])
+    assert [len(seg) for seg in now["caches"]] == [len(seg) for seg in before["caches"]]
+    for was_seg, now_seg in zip(before["caches"], now["caches"]):
+        for was, got in zip(was_seg, now_seg):
+            same(was, got)
 
 
 ANSWERS = ("leaves", "plan", "open", "mix", "put")

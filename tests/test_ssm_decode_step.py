@@ -79,10 +79,11 @@ def step_both_ways(load, idle_holds=None):
     states, tails, x = caches_of(cfg, idle_holds, load)
     layer = jnp.asarray(LAYER, jnp.int32)
     mask = active[:, None]
-    want_x, want = decoder_lm._ssm_mixer(cfg, "ssm", bp, x, (states, tails, layer, None), mask)
-    assert cfg.mixer("ssm").kernel(states) is not None
+    entry = cfg.mixer("ssm")
+    want_x, want = decoder_lm._ssm_mixer(entry, bp, x, (states, tails, layer, None), mask)
+    assert entry.kernel(states) is not None
     got_x, got = decoder_lm._ssm_mixer(
-        cfg, "ssm", bp, x, (states, tails, layer, ssm_decode.live_table(active)), mask)
+        entry, bp, x, (states, tails, layer, ssm_decode.live_table(active)), mask)
     return ((np.asarray(want_x), *map(np.asarray, want)),
             (np.asarray(got_x), *map(np.asarray, got)), np.asarray(states))
 

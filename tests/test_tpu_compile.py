@@ -123,7 +123,8 @@ def test_int8_matmul_ffn_width(one_chip, kn):
 
 @pytest.mark.parametrize("shape", [
     (36, 24, 20, 64, 1024), (192, 5, 16, 128, 896), (27, 48, 1, 576, 10240),
-    (37, 3, 20, 64, 256)], ids=["chat", "ouro", "latent", "a-block-cut-short"])
+    (37, 3, 20, 64, 256), (6, 40, 4, 128, 4096)],
+    ids=["chat", "ouro", "latent", "a-block-cut-short", "falcon"])
 def test_kv_column_write_at_the_cells_slabs(one_chip, shape):
     """The cache's column write (``nn/ops/kv_column_write.py``) at the
     gpt2-large.chat cell's slab, the ouro-2.6b cell's and a latent slab as
@@ -156,7 +157,8 @@ def test_kv_column_write_at_the_cells_slabs(one_chip, shape):
 
 @pytest.mark.parametrize("shape", [
     (4, 24, 20, 1, 64, 64, 1024), (1, 64, 8, 4, 128, 128, 4096),
-    (1, 64, 4, 16, 192, 128, 1536)], ids=["chat", "granite", "mimo"])
+    (1, 64, 4, 16, 192, 128, 1536), (6, 40, 4, 5, 128, 128, 4096)],
+    ids=["chat", "granite", "mimo", "falcon"])
 def test_decode_attention_at_the_cells_slabs(one_chip, shape):
     """Decode attention over the live tiles (``nn/ops/decode_attention.py``)
     at the gpt2-large.chat cell's slabs (a cut depth), the
@@ -649,3 +651,74 @@ def test_looped_decoder_decode_program_compiles_at_published_widths(one_chip):
     attends = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "attn_full" in line]
     assert len(attends) == 1 and "decode_attention" in attends[0], attends
+
+
+def test_parallel_decoder_programs_compile_at_published_widths(one_chip):
+    """``DecoderLM`` with PARALLEL blocks as the engine builds its programs,
+    at the falcon-h1-34b-l6 cell's widths and FULL cut depth (six blocks of
+    20 / 4 attention heads of 128 beside a Mamba-2 mixer of 32 heads x 128
+    over a state of 256 in two groups, inner width 4,096, MLP 21,504, the
+    whole vocabulary of 261,120, 48 slots x 4,096, bfloat16 with a float32
+    state). What a CPU run cannot show: weights and cache are 14.1 GB of
+    arguments; ONE loop over the six layers takes, a layer, the live-tile
+    attention kernel under ``attn_full`` (five query heads a key head) and
+    the live-slot state kernel under ``ssm_scan`` (a state of 256: Mosaic
+    takes it in blocks of 256 x 2,048), K and V closed over whole while
+    the state and the tail are the loop's carry, updated IN PLACE (no
+    temporary or copy of a layer's state over the slots, 201 MB, or of a
+    layer's part of a slab); after the loop one column-write call a slab;
+    the norm and the join run under ``mixer_join``; and the largest
+    prefill, the 4,096 bucket the engine appends, stays with the arguments
+    under the chip's 15.75 GB."""
+    import re
+
+    from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
+    from deeplearning4j_tpu.nn.ops import ssm_decode
+
+    built = programs.build("falcon", one_chip)
+    S, T, caches = built.slots, built.length, built.caches
+    assert [tuple((c.shape, c.dtype.name) for c in seg) for seg in caches] == [
+        (((6, S, 4, 128, T), "bfloat16"),) * 2
+        + (((6, S, 256, 4096), "float32"), ((6, S, 5120, 3), "bfloat16"))]
+    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
+                      for seg in caches for c in seg)
+    one_layer_state = S * 256 * 4096                        # 50 M values
+    one_layer_slab = S * 4 * 128 * T                        # 101 M values
+
+    def big_copies(text):
+        return [(dtype, dims) for dtype, dims in re.findall(
+            r"= (\w+)\[([\d,]+)\]\S* copy\(", text)
+            if len(dims.split(",")) >= 4 and math.prod(
+                map(int, dims.split(","))) >= one_layer_state // 2]
+
+    decode = built.decode()
+    text, plan = decode.as_text(), decode.memory_analysis()
+    assert 14.0e9 < plan.argument_size_in_bytes < 14.3e9
+    assert not big_copies(text) and text.count(" while(") == 1
+    assert set(built.asked["ssm_decode"]) == {(32, 128, 256, 2, S, "float32")}
+    assert set(built.asked["kv_column_write"]) == {(6, S, 4, 128, T, "bfloat16")}
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    states = [line for line in calls if "ssm_scan" in line]
+    assert len(states) == 1 and ssm_decode.NAME in states[0], states
+    attends = [line for line in calls if "attn_full" in line]
+    assert len(attends) == 1 and "decode_attention" in attends[0], attends
+    writes = [line for line in calls if "kv_write" in line]
+    assert len(writes) == 2 and all(kcw.NAME in w for w in writes), writes
+    assert "mixer_join" in text
+    assert not re.search(rf"f32\[(\d+,)?{S},256,4096\]\S* (fusion|select)\(", text)
+    # 0.20 GB planned (the logits over the slots and the sampler's part of
+    # them); one layer's state over the slots alone is 0.20 GB and one
+    # layer's K as much, and neither is among the temporaries
+    assert plan.temp_size_in_bytes < 0.25e9 < one_layer_slab * 2 * 2
+    assert abs(plan.alias_size_in_bytes - cache_bytes) < 1e6
+
+    prefill = built.prefill()
+    assert built.bucket == T
+    text, plan = prefill.as_text(), prefill.memory_analysis()
+    assert not big_copies(text)
+    assert abs(plan.alias_size_in_bytes - cache_bytes) < 1e6
+    # 0.67 GB planned: the bucket attends by blocks (20 heads x 4,096^2
+    # float32 scores in one piece would be 1.3 GB)
+    assert plan.temp_size_in_bytes < 1.0e9
+    assert plan.argument_size_in_bytes + plan.temp_size_in_bytes < 15.0e9
+    assert not re.search(rf"f32\[(1,)?20,(1,)?{T},(1,)?{T}\]", text)
