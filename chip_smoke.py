@@ -107,6 +107,15 @@ FULL = {
              dtype="bfloat16"),
         dict(slots=64, hkv=8, grp=4, hd=128, vd=128, t=4096,
              dtype="bfloat16")],
+    # the expert layers' grouped products of a decode step at the four
+    # expert cells' keys (granite, mimo, deepseek, glm): slots x top-k rows,
+    # hidden x expert width, the experts held a layer: four probes in one
+    # process
+    "grouped_experts": [
+        dict(m=640, d=4096, f=768, count=36, dtype="bfloat16"),
+        dict(m=512, d=4096, f=2048, count=16, dtype="bfloat16"),
+        dict(m=288, d=5120, f=1536, count=20, dtype="bfloat16"),
+        dict(m=256, d=6144, f=2048, count=16, dtype="bfloat16")],
     # the tiny state-space hybrid of tests/test_granite_lm.py (two Mamba-2
     # layers, a NoPE attention layer, another Mamba-2 layer; experts and a
     # shared expert in each), float32 so that equal tokens mean something
@@ -180,6 +189,9 @@ TINY = {
     "decode_attn": [
         dict(slots=4, hkv=4, grp=1, hd=16, vd=16, t=128, dtype="bfloat16"),
         dict(slots=3, hkv=2, grp=4, hd=32, vd=16, t=128, dtype="float32")],
+    "grouped_experts": [
+        dict(m=24, d=32, f=48, count=4, dtype="float32"),
+        dict(m=40, d=64, f=32, count=2, dtype="bfloat16")],
 }
 TINY["hybrid"] = FULL["hybrid"]
 TINY["sparse"] = FULL["sparse"]
@@ -702,11 +714,13 @@ def phase_kernels(platform, size=None):
     a failure: the run would otherwise pass on dense XLA. No phase above
     serves a latent layer, ``hybrid_serve`` takes its state-space step at
     a tiny size and ``lm_serve`` / ``looped_serve`` write small or short
-    slabs, so with ``size`` the three decode kernels and the cache's
-    column write are asked for here, at the widths a cell runs them at
-    (each probe holds its kernel to the ``jnp`` form)."""
+    slabs and no phase's expert layer has a cell's widths, so with ``size``
+    the three decode kernels, the cache's column write and the expert
+    layers' grouped products are asked for here, at the widths a cell runs
+    them at (each probe holds its kernel to the ``jnp`` form)."""
     from deeplearning4j_tpu.nn.conf.layers import attention
     from deeplearning4j_tpu.nn.ops.decode_attention import decode_attention_impl
+    from deeplearning4j_tpu.nn.ops.grouped_experts import grouped_experts_impl
     from deeplearning4j_tpu.nn.ops.kv_column_write import kv_column_write_impl
     from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
     from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
@@ -721,6 +735,8 @@ def phase_kernels(platform, size=None):
             kv_column_write_impl(**slab)
         for slabs in size["decode_attn"] + [keys["decode_attention"]]:
             decode_attention_impl(**slabs)
+        for products in size["grouped_experts"]:
+            grouped_experts_impl(**products)
     snap = default_kernel_registry().snapshot()
     flash = {repr(k): (None if impl is None
                        else getattr(impl.args[0], "__module__", "?"))

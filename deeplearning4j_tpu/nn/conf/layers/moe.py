@@ -13,7 +13,8 @@ all-to-all from the shardings alone.
 Beside it, for serving (PR 27): ``moe_dropless_ffn``, the expert layer
 as today's open models deploy it: a sigmoid router over every expert,
 the choice by score + correction bias, no capacity and no dropped token,
-the (token, expert) pairs sorted by expert and run as grouped products,
+the (token, expert) pairs sorted by expert and run as grouped products
+(few rows a group: ``nn/ops/grouped_experts.py``; many: ``ragged_dot``),
 and the layer TOLD WHICH EXPERTS IT HOLDS, computing their share of the
 result (``models/decoder_lm.py``; manual expert parallelism in
 ``parallel/moe.py``). The GShard layers below are unchanged.
@@ -37,6 +38,7 @@ from deeplearning4j_tpu.nn.conf import serde
 from deeplearning4j_tpu.nn.conf.input_type import InputType
 from deeplearning4j_tpu.nn.conf.layers.attention import TransformerBlock, _layer_norm
 from deeplearning4j_tpu.nn.conf.layers.base import FeedForwardLayer
+from deeplearning4j_tpu.nn.ops.grouped_experts import grouped_experts_impl
 from deeplearning4j_tpu.nn.ops.kernel_compat import PRECISION
 
 
@@ -199,11 +201,10 @@ def shared_swiglu(x, params):
         return (h @ params["Sd"]).astype(jnp.float32)
 
 
-def _grouped_swiglu(rows, experts, sizes, chunk: int):
-    """rows (M, d), sorted by group, through each row's expert:
-    ``(silu(r Eg_e) * (r Eu_e)) Ed_e`` as three grouped products ->
-    (M, d) float32. Rows past ``sum(sizes)`` belong to no group and come
-    back unspecified.
+def _ragged_swiglu(rows, experts, sizes, chunk: int):
+    """:func:`_grouped_swiglu` as three ``jax.lax.ragged_dot`` (a Mosaic
+    kernel of XLA's own on the TPU, its tiles (128, 512, 512)): ``sizes``
+    names every group of the stack.
 
     The rows go ``chunk`` at a time, for as many chunks as the groups
     fill: the grouped kernel multiplies a whole tile of rows for every
@@ -243,6 +244,35 @@ def _grouped_swiglu(rows, experts, sizes, chunk: int):
     return out[:m]
 
 
+def _grouped_swiglu(rows, experts, sizes, chunk: int, first=None):
+    """rows (M, d), sorted by group, through each row's expert:
+    ``(silu(r Eg_e) * (r Eu_e)) Ed_e`` as grouped products -> (M, d)
+    float32. ``experts``: the stacks ``Eg``, ``Eu`` (groups, d, f) and
+    ``Ed`` (groups, f, d); ``sizes`` (count,): the rows of the groups
+    ``first .. first + count`` (``first`` None: the stack is those groups;
+    else it may be traced, and no other group has rows). Rows past
+    ``sum(sizes)`` belong to no group and come back unspecified.
+
+    Two paths, chosen by what is known at trace time (M, the widths, the
+    dtype, the backend, an ambient mesh): at a decode step's row counts
+    on the TPU one kernel of the repo's own that reads each hit group's
+    matrices once, in wide tiles, against a short window of its rows
+    (``nn/ops/grouped_experts.py``: M <= its ``MAX_ROWS``; a group
+    without rows costs neither a DMA nor a product); else three
+    ``ragged_dot``, ``chunk`` rows at a time (:func:`_ragged_swiglu`: a
+    prefill's hundreds of rows a group, a mesh, the CPU)."""
+    groups, d, f = experts["Eg"].shape
+    kernel = grouped_experts_impl(rows.shape[0], d, f, sizes.shape[0],
+                                  rows.dtype)
+    if kernel is not None:
+        return kernel(rows, experts["Eg"], experts["Eu"], experts["Ed"],
+                      sizes, 0 if first is None else first)
+    if first is not None:
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((groups,), jnp.int32), sizes, (first,))
+    return _ragged_swiglu(rows, experts, sizes, chunk)
+
+
 def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
                      token_mask=None, layer=None, chunk: int = 128,
                      route=sigmoid_topk_route, shared: bool = False):
@@ -263,9 +293,11 @@ def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
     be traced: ``axis_index * count`` under manual expert parallelism).
     Of the N * k (token, expert) pairs those whose expert is held are
     sorted by expert, their rows gathered, and the three products run as
-    grouped products over the ragged groups (``jax.lax.ragged_dot``: on
-    the TPU a group without rows reads no weights), ``chunk`` rows at a
-    time (``_grouped_swiglu``); each result is
+    grouped products over the ragged groups (``_grouped_swiglu``: at a
+    decode step's row counts on the TPU the repo's own kernel,
+    ``nn/ops/grouped_experts.py``, else ``jax.lax.ragged_dot``, ``chunk``
+    rows at a time; on the TPU a group without rows reads no weights on
+    either path); each result is
     scaled by its routing weight and summed into its token. No capacity,
     no dropped token, no [S, E, C] tensor; what absent experts would add
     is left out, so the shares of all holders sum to the whole layer.
@@ -274,7 +306,9 @@ def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
     ``layer``: where the expert weights of SEVERAL layers are stacked
     (``Eg`` (L, count, d, f), ...), the layer to use, which may be
     traced (a scan's counter). The stack goes to the grouped product
-    whole, as L * count groups of which only this layer's have rows: a
+    whole, as L * count groups of which only this layer's have rows (the
+    kernel is told the layer's first group, ``ragged_dot`` the sizes of
+    all L * count): a
     layer sliced out of the stack inside a loop would be copied for the
     kernel every time (3 x 268 MB a layer at MiMo's widths, a third of
     a decode step on the chip: PERF.md, PR 27)."""
@@ -298,13 +332,10 @@ def moe_dropless_ffn(x, router_in, params, top_k: int, experts_held,
                         axis=0).astype(jnp.int32)
         n_local = jnp.sum(sizes)
         hit = jnp.sum(sizes > 0).astype(jnp.int32)
-        if layer is not None:
-            sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((experts["Eg"].shape[0],), jnp.int32), sizes,
-                (layer * count,))
         rows = jnp.take(x, order // top_k, axis=0)          # (N k, d)
     with jax.named_scope("moe_experts"):
-        out = _grouped_swiglu(rows, experts, sizes, chunk)
+        out = _grouped_swiglu(rows, experts, sizes, chunk,
+                              None if layer is None else layer * count)
         # rows past the held pairs belong to no group: whatever the
         # grouped product left there is not a number to keep
         out = jnp.where((jnp.arange(n * top_k) < n_local)[:, None], out, 0.0)

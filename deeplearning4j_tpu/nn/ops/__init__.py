@@ -17,6 +17,9 @@ The kernel SUBSYSTEM (this package):
   into a time-minor cache slab, block by block in place;
 - ``decode_attention`` — a decode step's attention over the K and V
   slabs where they lie, the live column tiles of the live slots only;
+- ``grouped_experts`` — an expert layer's grouped SwiGLU products at a
+  decode step's row counts: each hit expert's matrices read once, in wide
+  tiles, against a short window of its rows;
 - ``registry`` — the shared probe-once/fallback/observability contract
   every kernel resolves through (``KernelRegistry``).
 """

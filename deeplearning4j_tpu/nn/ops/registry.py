@@ -1,7 +1,8 @@
 """Kernel registry: the single availability decision point for every
 in-tree Pallas kernel (flash attention, fused conv, fused LSTM cell,
 fused ZeRO-1 update, int8 serving matmul, latent decode core, state-space
-decode step, cache column write, decode attention over the live tiles).
+decode step, cache column write, decode attention over the live tiles,
+the expert layers' grouped products at few rows a group).
 
 Before this module each kernel carried its own ad-hoc probe cache
 (``attention._FLASH_PROBE_CACHE``, ``fused_conv._PROBE_CACHE``) and its
@@ -23,7 +24,7 @@ own ``probe_with_retry`` call site. The registry unifies the contract:
   kernel math). ``interpret`` is honored by the kernels that resolve
   through :meth:`KernelRegistry.resolve` (fused_lstm, fused_zero1,
   int8_matmul, latent_decode_core, ssm_decode_step, kv_column_write,
-  decode_attention);
+  decode_attention, grouped_experts);
   flash_attention and fused_conv predate it and support
   ``0``/``1`` only (their layers call the compiled kernels directly —
   tests drive their ``interpret=`` arguments explicitly).
@@ -59,6 +60,7 @@ ENV_FLAGS = {
     "ssm_decode_step": "DL4J_TPU_SSM_DECODE_STEP",
     "kv_column_write": "DL4J_TPU_KV_COLUMN_WRITE",
     "decode_attention": "DL4J_TPU_DECODE_ATTENTION",
+    "grouped_experts": "DL4J_TPU_GROUPED_EXPERTS",
 }
 
 

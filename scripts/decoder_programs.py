@@ -76,6 +76,8 @@ def steered(*names):
     give them, for the programs lowered inside; yields {name: the keys it
     was asked at}."""
     from deeplearning4j_tpu.models import decoder_lm, transformer_lm
+    from deeplearning4j_tpu.nn.conf.layers import moe
+    from deeplearning4j_tpu.nn.ops import grouped_experts as ge
     from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
     from deeplearning4j_tpu.nn.ops import latent_decode, ssm_decode
 
@@ -99,7 +101,17 @@ def steered(*names):
         return functools.partial(ssm_decode.ssm_decode_step,
                                  tile=ssm_decode._tile(heads, p, n, groups))
 
-    seams = {"kv_column_write": [(transformer_lm, "kv_column_write_impl",
+    def products(m, d, f, count, dtype):
+        planned = ge.plan(m, d, f, dtype)   # a prefill's rows decline
+        if planned is None:
+            return None
+        window, tile = planned
+        asked["grouped_experts"].append(
+            (d, f, count, m, window, tile, jnp.dtype(dtype).name))
+        return functools.partial(ge.grouped_experts, window=window, tile=tile)
+
+    seams = {"grouped_experts": [(moe, "grouped_experts_impl", products)],
+             "kv_column_write": [(transformer_lm, "kv_column_write_impl",
                                   column_write)],
              "decode_attention": [(transformer_lm, "decode_attention_impl",
                                    _attends),
@@ -234,10 +246,11 @@ def _falcon():
 #: and ``tests/test_tpu_compile.py`` asserts on)
 CELLS = {
     "chat": (_chat, 24, None, ("kv_column_write", "decode_attention")),
-    "mimo": (_mimo, 64, 512, ("decode_attention",)),
-    "deepseek": (_deepseek, 48, 8192, ("latent_decode",)),
-    "glm": (_glm, 32, 14336, ()),
-    "granite": (_granite, 64, 4096, ("ssm_decode", "decode_attention")),
+    "mimo": (_mimo, 64, 512, ("decode_attention", "grouped_experts")),
+    "deepseek": (_deepseek, 48, 8192, ("latent_decode", "grouped_experts")),
+    "glm": (_glm, 32, 14336, ("grouped_experts",)),
+    "granite": (_granite, 64, 4096, ("ssm_decode", "decode_attention",
+                                     "grouped_experts")),
     "ouro": (_ouro, 5, 256, ("kv_column_write", "decode_attention")),
     "falcon": (_falcon, 48, 4096, ("ssm_decode", "decode_attention",
                                    "kv_column_write")),

@@ -69,6 +69,28 @@ def _custom_calls(text: str) -> int:
     return text.count("tpu_custom_call")
 
 
+def _grouped_products(built, segments, key):
+    """The decode program takes its expert layers' grouped products through
+    the repo's own kernel (``nn/ops/grouped_experts.py``, the verdict
+    steered: one custom call a segment with expert layers, under
+    ``moe_experts``, at ``key`` = (d, f, held experts, slots x top-k, window,
+    tile, dtype), and no ``ragged-dot`` left); the prefill's rows are over the
+    kernel's threshold and keep ``ragged_dot``."""
+    from deeplearning4j_tpu.nn.ops import grouped_experts
+
+    text = built.decode().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "moe_experts" in line]
+    assert len(calls) == segments, calls
+    assert all(grouped_experts.NAME in line for line in calls), calls
+    assert "ragged-dot" not in text
+    text = built.prefill().as_text()
+    assert "ragged-dot" in text and not [
+        line for line in text.splitlines()
+        if "tpu_custom_call" in line and grouped_experts.NAME in line]
+    assert set(built.asked["grouped_experts"]) == {key}
+
+
 # lm_train's attention instantiation: batch 16, 12 heads, T 512, head 64
 _QKV = [((16, 12, 512, 64), BF16)] * 3
 
@@ -191,6 +213,46 @@ def test_decode_attention_at_the_cells_slabs(one_chip, shape):
     assert tile == 128 and _custom_calls(text) == 1 and da.NAME in text
     copies = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
               if math.prod(map(int, dims.split(","))) >= slots * hkv * vd * t]
+    assert not copies, copies
+
+
+@pytest.mark.parametrize("shape", [
+    (4096, 768, 36, 5, 640), (4096, 2048, 16, 2, 512), (5120, 1536, 20, 1, 288),
+    (6144, 2048, 16, 3, 256)], ids=["granite", "mimo", "deepseek", "glm"])
+def test_grouped_experts_at_the_cells_keys(one_chip, shape):
+    """The grouped SwiGLU products (``nn/ops/grouped_experts.py``) at the four
+    expert cells' decode keys (d, f, held experts, layers of a segment, slots
+    x top-k), with the window and tile the rule chooses, all layers' calls in
+    one loop over the layer's index with the stacks closed over whole: one
+    custom call in the loop's body and no copy as large as one expert's
+    matrix (Mosaic takes 12-19 MB of weight tiles a grid step, double-
+    buffered, beside the rows and the float32 output)."""
+    import re
+
+    from deeplearning4j_tpu.nn.ops import grouped_experts as ge
+
+    d, f, count, layers, m = shape
+    window, tile = ge.plan(m, d, f, BF16)
+
+    def products(rows, eg, eu, ed, sizes):
+        def layer(total, x):
+            i, s = x
+            return total + ge.grouped_experts(rows, eg, eu, ed, s, i * count,
+                                              window=window, tile=tile), None
+
+        return jax.lax.scan(
+            layer, jnp.zeros((m, d), F32),
+            (jnp.arange(layers, dtype=jnp.int32), sizes))[0]
+
+    groups = layers * count
+    text = _compile(
+        products, one_chip, ((m, d), BF16), ((groups, d, f), BF16),
+        ((groups, d, f), BF16), ((groups, f, d), BF16),
+        ((layers, count), jnp.int32))
+    assert (window, tile) == (32, 768 if f == 768 else 512)
+    assert _custom_calls(text) == 1 and ge.NAME in text
+    copies = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+              if math.prod(map(int, dims.split(","))) >= d * f]
     assert not copies, copies
 
 
@@ -372,8 +434,9 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     8 key/value heads, 16 of 256 experts of 2048, 64 slots x 1536,
     bfloat16) and a cut depth (a dense full layer and two window expert
     layers). What a CPU run cannot show: the grouped expert product is a
-    Mosaic kernel on the TPU and refuses the package-wide "highest"
-    precision (``moe_dropless_ffn`` pins DEFAULT for bfloat16 operands);
+    Mosaic kernel on the TPU (the repo's own in decode, ``_grouped_products``;
+    XLA's ``ragged_dot`` in the prefill) and refuses the package-wide
+    "highest" precision (both pin DEFAULT for bfloat16 operands);
     the rings and the full layer's slab stay in place (no temporary as
     large as a slab, no copy of one), the full layer attending through the
     live-tile kernel (``nn/ops/decode_attention.py``, the verdict steered:
@@ -384,7 +447,7 @@ def test_decoder_decode_program_compiles_at_published_widths(one_chip):
     cfg, S, caches = built.cfg, built.slots, built.caches
     compiled = built.decode()
     text = compiled.as_text()
-    assert "ragged-dot" in text and _custom_calls(text) >= 3
+    _grouped_products(built, 1, (4096, 2048, 16, S * 8, 32, 512, "bfloat16"))
     attends = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "attn_full" in line]
     assert len(attends) == 1 and "decode_attention" in attends[0], attends
@@ -436,7 +499,8 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip):
 
     decode = built.decode()
     text = decode.as_text()
-    assert "ragged-dot" in text and not slab_copies(text)
+    assert not slab_copies(text)
+    _grouped_products(built, 1, (5120, 1536, 20, S * 6, 32, 512, "bfloat16"))
     assert set(built.asked["latent_decode"]) == {(128, 576, T, "bfloat16", 512)}
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "attn_latent_core" in line]
@@ -491,7 +555,8 @@ def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
 
     decode = built.decode()
     text = decode.as_text()
-    assert "ragged-dot" in text and not slab_copies(text), slab_copies(text)
+    assert not slab_copies(text), slab_copies(text)
+    _grouped_products(built, 2, (6144, 2048, 16, S * 8, 32, 512, "bfloat16"))
     gathers = [line for line in text.splitlines()
                if " gather(" in line and "attn_sparse_core" in line]
     assert gathers and all(f"bf16[{S},2048,640]" in g for g in gathers), gathers
@@ -566,7 +631,8 @@ def test_hybrid_decoder_programs_compile_at_published_widths(one_chip):
     decode = built.decode()
     text, plan = decode.as_text(), decode.memory_analysis()
     assert 12.9e9 < plan.argument_size_in_bytes < 13.1e9
-    assert "ragged-dot" in text and not big_copies(text)
+    assert not big_copies(text)
+    _grouped_products(built, 3, (4096, 768, 36, S * 10, 32, 768, "bfloat16"))
     assert set(built.asked["ssm_decode"]) == {(128, 64, 128, 1, S, "float32")}
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "ssm_scan" in line]
