@@ -56,6 +56,10 @@ FULL = {
     # entries of 512 + 64 values, slots of 10,240
     "latent_core": dict(heads=128, width=576, t_c=10240, dtype="bfloat16",
                         kv_rank=512),
+    # the glm-5.2-ep16 cell's attention over a selection: 64 heads over rows
+    # of 640 (512 + 64 values, the tail zero), slots of 14,336, 2,048 kept
+    "sparse_core": dict(heads=64, width=640, t_c=14336, topk=2048,
+                        dtype="bfloat16", kv_rank=512),
     # the granite-4.0-h-small-ep2 cell's state-space decode step: 64 slots
     # of 128 heads x 64 x 128 float32 a layer, one group of B and C
     "ssm_step": dict(heads=128, p=64, n=128, groups=1, slots=64,
@@ -178,6 +182,8 @@ TINY = {
     "mlp4": (32, 64, 32, 8), "mlp4_rows": 8,
     "latent_core": dict(heads=4, width=32, t_c=64, dtype="float32",
                         kv_rank=16),
+    "sparse_core": dict(heads=4, width=128, t_c=64, topk=8, dtype="float32",
+                        kv_rank=96),
     "ssm_step": dict(heads=8, p=16, n=16, groups=1, slots=3,
                      dtype="float32"),
     "kv_columns": [
@@ -634,8 +640,9 @@ def phase_sparse_serve(size):
     """A decoder whose latent attention reads the positions an indexer
     selects (``models/decoder_lm.py``), served by ``GenerationEngine``:
     prompts past the indexer's top-k, so prefills select and every decode
-    step scores the key slab, keeps the exact top-k and gathers the chosen
-    rows, two layers by a selection another segment's layer made; every
+    step scores the key slab, keeps the exact top-k and attends over the
+    chosen rows (on the chip through ``nn/ops/sparse_latent_decode.py``),
+    two layers by a selection another segment's layer made; every
     request's tokens against the model's own cached generation on one slot
     and against the greedy tokens of ONE forward over what was served (the
     selection as a mask, no cache)."""
@@ -712,22 +719,27 @@ def phase_kernels(platform, size=None):
     """Which Pallas kernels the phases asked for and what each resolved
     to. On the TPU backend a kernel that fell back to its reference is
     a failure: the run would otherwise pass on dense XLA. No phase above
-    serves a latent layer, ``hybrid_serve`` takes its state-space step at
-    a tiny size and ``lm_serve`` / ``looped_serve`` write small or short
-    slabs and no phase's expert layer has a cell's widths, so with ``size``
-    the three decode kernels, the cache's column write and the expert
-    layers' grouped products are asked for here, at the widths a cell runs
-    them at (each probe holds its kernel to the ``jnp`` form)."""
+    serves a dense latent layer, ``hybrid_serve`` takes its state-space step
+    and ``sparse_serve`` its attention over a selection at a tiny size and
+    ``lm_serve`` / ``looped_serve`` write small or short slabs and no phase's
+    expert layer has a cell's widths, so with ``size`` the four decode
+    kernels, the cache's column write and the expert layers' grouped
+    products are asked for here, at the widths a cell runs them at (each
+    probe holds its kernel to the ``jnp`` form)."""
     from deeplearning4j_tpu.nn.conf.layers import attention
     from deeplearning4j_tpu.nn.ops.decode_attention import decode_attention_impl
     from deeplearning4j_tpu.nn.ops.grouped_experts import grouped_experts_impl
     from deeplearning4j_tpu.nn.ops.kv_column_write import kv_column_write_impl
     from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
     from deeplearning4j_tpu.nn.ops.registry import default_kernel_registry
+    from deeplearning4j_tpu.nn.ops.sparse_latent_decode import (
+        sparse_latent_decode_impl,
+    )
     from deeplearning4j_tpu.nn.ops.ssm_decode import ssm_decode_impl
 
     if size is not None:
         latent_decode_impl(**size["latent_core"])
+        sparse_latent_decode_impl(**size["sparse_core"])
         keys = size["parallel_keys"]
         for step in (size["ssm_step"], keys["ssm_decode_step"]):
             ssm_decode_impl(**step)

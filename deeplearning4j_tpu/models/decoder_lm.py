@@ -45,7 +45,10 @@ configuration (:class:`DecoderConfig`):
                   whole ring           real columns
   _Latent         a column, the slab   the bucket's columns   latent_decode: the slab
                   as one head                                 whole, rows' lengths
-  _IndexedLatent  a row a slot         the bucket's rows      none (a gather)
+  _IndexedLatent  a row a slot         the bucket's rows      sparse_latent_decode:
+                                                              the live rows under the
+                                                              selection's bias (else
+                                                              a gather)
   _StateSpace     none (in the loop)   the state after the    ssm_decode: the live
                                        real tokens            slots' table
   _Parallel       K, V columns; the    both, each under its   both, by the two
@@ -97,6 +100,11 @@ from deeplearning4j_tpu.nn.ops.decode_attention import (
     live_tiles,
 )
 from deeplearning4j_tpu.nn.ops.latent_decode import latent_decode_impl
+from deeplearning4j_tpu.nn.ops.sparse_latent_decode import (
+    live_walk,
+    selection_bias,
+    sparse_latent_decode_impl,
+)
 from deeplearning4j_tpu.nn.ops.ssm_decode import live_table, ssm_decode_impl
 
 Array = jax.Array
@@ -818,12 +826,14 @@ def _select_mask(scores, k: int):
     return (above | (ties & first)) & valid
 
 
-def _select_indices(scores, own, lengths, k: int):
+def _select_indices(scores, own, lengths, k: int, as_bias: bool = False):
     """A decode step's selection: scores (b, Tc) float32 of the cached
     positions (-inf from a row's ``lengths`` on), ``own`` (b,) the score of
     the step's own position, which lies outside the slab -> (the columns
     of the ``k`` best cached positions (b, k), best first; how many of
-    them are in the selection (b,); whether the own position is (b,)).
+    them are in the selection (b,); whether the own position is (b,); with
+    ``as_bias`` also the same set of cached positions as the bias (b, 1,
+    Tc) a kernel that walks the rows takes: ``selection_bias``).
     The selection is the ``min(k, lengths + 1)`` largest of cached and own
     together, ties to the lower position, the set ``_select_mask`` gives
     with the own score at column ``lengths``: the own position, the
@@ -836,8 +846,11 @@ def _select_indices(scores, own, lengths, k: int):
     scores, own = (jnp.where(a == 0, 0.0, a) for a in (scores, own))
     vals, idx = jax.lax.top_k(scores, k)
     own_in = (lengths < k) | (own > vals[:, -1])
-    n_sel = jnp.where(own_in, jnp.minimum(lengths, k - 1), k)
-    return idx.astype(jnp.int32), n_sel.astype(jnp.int32), own_in
+    n_sel = jnp.where(own_in, jnp.minimum(lengths, k - 1), k).astype(jnp.int32)
+    sel = idx.astype(jnp.int32), n_sel, own_in
+    if as_bias:
+        sel += (selection_bias(scores, vals, sel[0], n_sel, lengths),)
+    return sel
 
 
 def _index_mask(q_i, w_i, k_i, topk: int, block: int, n_real=None):
@@ -908,15 +921,21 @@ def _sparse_latent_attention(cfg: DecoderConfig, kind: str,
     Without a cache (forward, prefill) a selection is a mask (b, Tq, Tq)
     on the blocked attention's scores (``_index_mask``,
     ``_causal_blocked``), or None where Tq <= topk: every query then
-    attends to all before it, the dense latent layer. With ``cache`` =
-    (the segment's slabs, position-major: (latents (entries, b, Tc, row),
-    and an owner's keys), entry, lengths (b,)) and Tq = 1 it is (columns (b, K), how
-    many of them count (b,), whether the step's own position is in (b,))
+    attends to all before it, the dense latent layer. With ``cache`` (a
+    view made by ``_IndexedLatent.open``) = ("rows", the segment's slabs,
+    position-major: (latents (entries, b, Tc, row), and an owner's keys),
+    entry, lengths (b,)) and Tq = 1 it is (columns (b, K), how many of them
+    count (b,), whether the step's own position is in (b,))
     (``_select_indices``; K = min(topk, Tc)): the own entry lies outside
     the slab, so it is scored beside the cached ones and a sharer is told
     whether it was chosen. The chosen rows are gathered from the slab as
     it lies, K rows a slot, and the absorbed scores, the softmax and the
-    weighted sum of latents run over them and the own entry."""
+    weighted sum of latents run over them and the own entry. Over
+    ("kernel", slabs, entry, lengths (0 for an idle row), the kernel of
+    ``nn/ops/sparse_latent_decode.py``, its walk over the live tiles) the
+    selection has a fourth part, the same set as a bias over the slot's
+    positions, and the kernel gives the same sums from the live rows read
+    where they lie, once."""
     mixer = cfg.mixer(kind)
     index = mixer.index
     b, tq, _d = x.shape
@@ -957,7 +976,7 @@ def _sparse_latent_attention(cfg: DecoderConfig, kind: str,
             with _scope("attn_sparse_core"):
                 o = _causal_blocked(q, k, v, scale, PREFILL_BLOCK, n_real, sel)
         else:
-            slabs, layer, lengths = cache
+            how, slabs, layer, lengths, *core = cache
             q_lat = jnp.concatenate(
                 [jnp.einsum("bqhn,chn->bqhc", q_nope, bp["Wuk"]), q_pe,
                  jnp.zeros((b, tq, hq, tail), dt)], axis=-1)[:, 0]
@@ -973,26 +992,33 @@ def _sparse_latent_attention(cfg: DecoderConfig, kind: str,
                     s_own = _index_scores(q_i, w_i, k_i)[:, 0, 0]
                 with _scope("attn_index_select"):
                     sel = _select_indices(s_i, s_own, lengths,
-                                          min(index["topk"], columns))
-            idx, n_sel, own_in = sel
-            with _scope("attn_sparse_core"):
-                rows = slabs[0].at[
-                    layer, jnp.arange(b)[:, None], idx].get(
-                        mode="promise_in_bounds")             # (b, K, width)
-                s_c = jnp.einsum("bhc,bkc->bhk", q_lat, rows,
-                                 preferred_element_type=f32) * scale
-                counts = jnp.arange(idx.shape[1])[None, :] < n_sel[:, None]
-                s_c = jnp.where(counts[:, None], s_c, _NEG)
-                s_own = jnp.einsum("bhc,bc->bh", q_lat, new[:, 0],
-                                   preferred_element_type=f32) * scale
-                s_own = jnp.where(own_in[:, None], s_own, _NEG)
-                m = jnp.maximum(s_c.max(-1), s_own)
-                e_c, e_own = jnp.exp(s_c - m[..., None]), jnp.exp(s_own - m)
-                lat = (jnp.einsum("bhk,bkc->bhc", e_c.astype(dt), rows,
-                                  preferred_element_type=f32)
-                       + e_own[..., None] * new[:, 0, None].astype(f32))
-                z = (e_c.sum(-1) + e_own)[..., None]
-                lat = (lat[..., :kr] / z).astype(dt)[:, None]
+                                          min(index["topk"], columns),
+                                          as_bias=how == "kernel")
+            if how == "kernel":
+                (kernel, walk), (_idx, _n_sel, own_in, bias) = core, sel
+                with _scope("attn_sparse_core"):
+                    lat = kernel(q_lat, new[:, 0], slabs[0], layer, lengths,
+                                 bias, own_in, walk, scale=scale)[:, None]
+            else:
+                idx, n_sel, own_in = sel
+                with _scope("attn_sparse_core"):
+                    rows = slabs[0].at[
+                        layer, jnp.arange(b)[:, None], idx].get(
+                            mode="promise_in_bounds")         # (b, K, width)
+                    s_c = jnp.einsum("bhc,bkc->bhk", q_lat, rows,
+                                     preferred_element_type=f32) * scale
+                    counts = jnp.arange(idx.shape[1])[None, :] < n_sel[:, None]
+                    s_c = jnp.where(counts[:, None], s_c, _NEG)
+                    s_own = jnp.einsum("bhc,bc->bh", q_lat, new[:, 0],
+                                       preferred_element_type=f32) * scale
+                    s_own = jnp.where(own_in[:, None], s_own, _NEG)
+                    m = jnp.maximum(s_c.max(-1), s_own)
+                    e_c, e_own = jnp.exp(s_c - m[..., None]), jnp.exp(s_own - m)
+                    lat = (jnp.einsum("bhk,bkc->bhc", e_c.astype(dt), rows,
+                                      preferred_element_type=f32)
+                           + e_own[..., None] * new[:, 0, None].astype(f32))
+                    z = (e_c.sum(-1) + e_own)[..., None]
+                    lat = (lat[..., :kr] / z).astype(dt)[:, None]
             o = jnp.einsum("bqhc,chv->bqhv", lat, bp["Wuv"])
         if cfg.value_scale != 1.0:
             o = o * cfg.value_scale
@@ -1690,19 +1716,44 @@ class _IndexedLatent(_Latent):
 
     positions = _Mixer.positions  # read by the rows' lengths, not by a map
 
+    def kernel(self, slabs):
+        """(The kernel that walks the live rows under the selection's bias
+        for a decode step over ``slabs``, its tile), by the registry's
+        verdict for their shape (None: the gathered rows serve)."""
+        rows = slabs[0]
+        return sparse_latent_decode_impl(
+            self.cfg.n_heads, rows.shape[3], rows.shape[2],
+            min(self.topk, rows.shape[2]), rows.dtype, self.kv_rank)
+
     def open(self, slabs, q_pos, c_pos, token_mask, looped):
-        lengths = q_pos[:, 0]
-        return None, slabs, lambda _sliced, held, at: (held, at, lengths)
+        admitted = self.kernel(slabs) if q_pos.shape[1] == 1 else None
+        if admitted is None:
+            lengths = q_pos[:, 0]
+            return None, slabs, lambda _sliced, held, at: (
+                "rows", held, at, lengths)
+        # by the rows' lengths: a row that is not active has none, and the
+        # walk over the live tiles is every layer's
+        kernel, tile = admitted
+        lengths = _live_lengths(q_pos, token_mask)
+        walk = live_walk(lengths, slabs[0].shape[2], tile)
+        return None, slabs, lambda _sliced, held, at: (
+            "kernel", held, at, lengths, kernel, walk)
 
     def first(self, sel, b, tq, slabs):
         """An owner's scan starts from a selection's shapes with nothing
-        in them: a mask without a cache (none up to ``topk`` positions)."""
+        in them: a mask without a cache (none up to ``topk`` positions);
+        over one the columns, their count and whether the own position is
+        in, and for the kernel their bias."""
         if not self.index["own"]:
             return sel
         if slabs is None:
             return jnp.zeros((b, tq, tq), bool) if tq > self.topk else None
-        return (jnp.zeros((b, min(self.topk, slabs[0].shape[2])), jnp.int32),
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+        t_c = slabs[0].shape[2]
+        sel = (jnp.zeros((b, min(self.topk, t_c)), jnp.int32),
+               jnp.zeros((b,), jnp.int32), jnp.zeros((b,), bool))
+        if tq == 1 and self.kernel(slabs) is not None:
+            sel += (jnp.zeros((b, 1, t_c), jnp.float32),)
+        return sel
 
     def mix(self, bp, x, q_pos, view, token_mask, sel):
         x, entries, sel = _sparse_latent_attention(

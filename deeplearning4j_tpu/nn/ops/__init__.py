@@ -20,6 +20,9 @@ The kernel SUBSYSTEM (this package):
 - ``grouped_experts`` — an expert layer's grouped SwiGLU products at a
   decode step's row counts: each hit expert's matrices read once, in wide
   tiles, against a short window of its rows;
+- ``sparse_latent_decode`` — a decode step's latent attention over an
+  indexer's selection: the live slots' rows streamed once, where they lie,
+  under the selection's bias;
 - ``registry`` — the shared probe-once/fallback/observability contract
   every kernel resolves through (``KernelRegistry``).
 """

@@ -2,7 +2,8 @@
 in-tree Pallas kernel (flash attention, fused conv, fused LSTM cell,
 fused ZeRO-1 update, int8 serving matmul, latent decode core, state-space
 decode step, cache column write, decode attention over the live tiles,
-the expert layers' grouped products at few rows a group).
+the expert layers' grouped products at few rows a group, latent attention
+over a selection at a decode step).
 
 Before this module each kernel carried its own ad-hoc probe cache
 (``attention._FLASH_PROBE_CACHE``, ``fused_conv._PROBE_CACHE``) and its
@@ -24,7 +25,7 @@ own ``probe_with_retry`` call site. The registry unifies the contract:
   kernel math). ``interpret`` is honored by the kernels that resolve
   through :meth:`KernelRegistry.resolve` (fused_lstm, fused_zero1,
   int8_matmul, latent_decode_core, ssm_decode_step, kv_column_write,
-  decode_attention, grouped_experts);
+  decode_attention, grouped_experts, sparse_latent_decode);
   flash_attention and fused_conv predate it and support
   ``0``/``1`` only (their layers call the compiled kernels directly —
   tests drive their ``interpret=`` arguments explicitly).
@@ -61,6 +62,7 @@ ENV_FLAGS = {
     "kv_column_write": "DL4J_TPU_KV_COLUMN_WRITE",
     "decode_attention": "DL4J_TPU_DECODE_ATTENTION",
     "grouped_experts": "DL4J_TPU_GROUPED_EXPERTS",
+    "sparse_latent_decode": "DL4J_TPU_SPARSE_LATENT_DECODE",
 }
 
 

@@ -80,6 +80,7 @@ def steered(*names):
     from deeplearning4j_tpu.nn.ops import grouped_experts as ge
     from deeplearning4j_tpu.nn.ops import kv_column_write as kcw
     from deeplearning4j_tpu.nn.ops import latent_decode, ssm_decode
+    from deeplearning4j_tpu.nn.ops import sparse_latent_decode as sld
 
     asked = {name: [] for name in names}
 
@@ -110,7 +111,16 @@ def steered(*names):
             (d, f, count, m, window, tile, jnp.dtype(dtype).name))
         return functools.partial(ge.grouped_experts, window=window, tile=tile)
 
+    def selected(heads, width, t_c, topk, dtype, kv_rank):
+        tile = sld.plan(t_c, topk)
+        asked["sparse_latent_decode"].append(
+            (heads, width, t_c, topk, tile, jnp.dtype(dtype).name))
+        return functools.partial(sld.sparse_latent_decode, kv_rank=kv_rank,
+                                 tile=tile), tile
+
     seams = {"grouped_experts": [(moe, "grouped_experts_impl", products)],
+             "sparse_latent_decode": [(decoder_lm, "sparse_latent_decode_impl",
+                                       selected)],
              "kv_column_write": [(transformer_lm, "kv_column_write_impl",
                                   column_write)],
              "decode_attention": [(transformer_lm, "decode_attention_impl",
@@ -248,7 +258,7 @@ CELLS = {
     "chat": (_chat, 24, None, ("kv_column_write", "decode_attention")),
     "mimo": (_mimo, 64, 512, ("decode_attention", "grouped_experts")),
     "deepseek": (_deepseek, 48, 8192, ("latent_decode", "grouped_experts")),
-    "glm": (_glm, 32, 14336, ("grouped_experts",)),
+    "glm": (_glm, 32, 14336, ("grouped_experts", "sparse_latent_decode")),
     "granite": (_granite, 64, 4096, ("ssm_decode", "decode_attention",
                                      "grouped_experts")),
     "ouro": (_ouro, 5, 256, ("kv_column_write", "decode_attention")),
@@ -257,17 +267,19 @@ CELLS = {
 }
 
 
-def build(cell, sharding):
+def build(cell, sharding, without=()):
     """``cell``'s programs as the engine builds them, lowered on shapes
     described for ``sharding``: a namespace of ``cfg``, ``slots``,
     ``length``, ``caches`` (shapes: K and V for the chat cell, a tuple a
     segment for a decoder cell), ``bucket``, ``asked`` (the keys the
     steered verdicts were asked at, filled as programs compile),
     ``decode()`` and ``prefill()`` -> the compiled programs (each compile
-    made once)."""
+    made once). ``without``: the cell's verdicts NOT steered on (the
+    programs where the registry declines those kernels)."""
     from deeplearning4j_tpu.serving import generate
 
     make, slots, bucket, verdicts = CELLS[cell]
+    verdicts = tuple(v for v in verdicts if v not in without)
     cfg = make()
     length = cfg.max_length
 

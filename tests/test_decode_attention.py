@@ -525,7 +525,7 @@ def test_chip_smoke_asks_for_the_kernel_and_fails_where_it_fell_back(monkeypatch
     from deeplearning4j_tpu.nn.ops import kv_column_write, latent_decode, ssm_decode
 
     for name in (latent_decode.NAME, ssm_decode.NAME, kv_column_write.NAME,
-                 "grouped_experts"):
+                 "grouped_experts", "sparse_latent_decode"):
         monkeypatch.setenv(ENV_FLAGS[name], "interpret")
     chip_smoke = _chip_smoke()
     assert chip_smoke.FULL["decode_attn"] == [

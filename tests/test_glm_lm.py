@@ -45,6 +45,11 @@ from deeplearning4j_tpu.nn.conf.layers.moe import (  # noqa: E402
     shared_swiglu,
     sigmoid_topk_route,
 )
+from deeplearning4j_tpu.nn.ops import sparse_latent_decode  # noqa: E402
+from deeplearning4j_tpu.nn.ops.registry import (  # noqa: E402
+    ENV_FLAGS,
+    default_kernel_registry,
+)
 
 TOL = 1e-5
 SEED = 7
@@ -103,6 +108,28 @@ def base():
     return cfg, build(cfg)
 
 
+@pytest.fixture(params=["gathered", "kernel"])
+def both_ways(request, base, monkeypatch):
+    """(cfg, model) whose decode steps attend over gathered rows (the
+    registry's verdict on the CPU) and, a model of its own on the same
+    weights, through ``nn/ops/sparse_latent_decode.py`` under the Pallas
+    interpreter in tiles of 8 rows (slots of 128 are sixteen selections
+    long: the span is widened for the test)."""
+    if request.param == "gathered":
+        yield base
+        return
+    kernel = sparse_latent_decode
+    monkeypatch.setenv(ENV_FLAGS[kernel.NAME], "interpret")
+    monkeypatch.setattr(kernel, "TILE", 8)
+    monkeypatch.setattr(kernel, "MAX_SPAN", 16)
+    default_kernel_registry().reset(kernel.NAME)
+    cfg = tiny()
+    yield cfg, build(cfg)
+    verdicts = default_kernel_registry().snapshot()[kernel.NAME]
+    assert verdicts and all(v["enabled"] for v in verdicts.values())
+    default_kernel_registry().reset(kernel.NAME)
+
+
 def program_selections(model, ids):
     """The selection each layer of ``model`` attends by in a forward over
     ids (T,): bool (T, T) a layer (None: all before), a layer at a time
@@ -154,7 +181,7 @@ def test_forward_selects_what_the_reference_selects(base):
 @pytest.mark.parametrize("prompt_len", [3, 8, 13, 29],
                          ids=["below-the-top-k", "the-top-k", "past-it",
                               "several-blocks"])
-def test_prefill_then_decode_matches_reference(base, prompt_len):
+def test_prefill_then_decode_matches_reference(both_ways, prompt_len):
     """Bucketed prefill (the expanded form under the selection's mask, by
     blocks of 8; the prompt of 29 in a bucket of 32 whose last block is
     padding in part; the prompts of 3 and 8 select nothing yet), then 30
@@ -162,7 +189,7 @@ def test_prefill_then_decode_matches_reference(base, prompt_len):
     top 8 of cached and own, the absorbed form over the gathered rows). The
     logits each token was chosen from against the reference's full forward
     over prompt + tokens."""
-    cfg, model = base
+    cfg, model = both_ways
     out, logits = model.generate_cached(ids_of(cfg, prompt_len), max_new=30,
                                         return_logits=True)
     want = np.asarray(ref.logits(cfg, SEED, out[:-1]))[prompt_len - 1:]
@@ -356,14 +383,21 @@ def test_blocked_attention_under_a_selection_equals_a_masked_softmax():
     np.testing.assert_allclose(np.asarray(got), whole, atol=2e-6)
 
 
-def test_absorbed_step_over_gathered_rows_equals_expanded_attention(base):
+def test_absorbed_step_over_gathered_rows_equals_expanded_attention(both_ways):
     """One owning layer on the same float32 weights: the expanded form under
     the mask over 21 positions against the absorbed form for the last
     position over slabs that hold the entries of the first 20 (layer 1 of
-    two, idle rows after them, NaN in the other layer); a sharer handed the
-    owner's selection gives on the owner's weights what the owner gives."""
-    _cfg, model = base
+    two, idle rows after them, NaN in the other layer), the view as the
+    entry's ``open`` makes it; a sharer handed the owner's selection gives
+    on the owner's weights what the owner gives."""
+    _cfg, model = both_ways
     cfg = model.cfg
+
+    def view(kind, slabs, at, lengths):
+        _sliced, held, look = cfg.mixer(kind).open(
+            slabs, lengths[:, None], None, None, False)
+        return look(None, held, at)
+
     bp = {k: v[0] for k, v in model.params_["segments"][0].items()}
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 21, cfg.d_model), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(21)[None], (2, 21))
@@ -374,18 +408,20 @@ def test_absorbed_step_over_gathered_rows_equals_expanded_attention(base):
     assert mask.shape == (2, 21, 21) and np.asarray(mask[:, 20]).sum(-1).tolist() == [8, 8]
     slabs = tuple(jnp.full((2, 2, 40, e.shape[-1]), jnp.nan).at[1].set(0.0)
                   .at[1, :, :20].set(e[:, :20]) for e in (entries, keys))
-    cache = (slabs, jnp.asarray(1, jnp.int32), jnp.asarray([20, 20], jnp.int32))
+    at, lengths = jnp.asarray(1, jnp.int32), jnp.asarray([20, 20], jnp.int32)
     step, (entry, key), sel = decoder_lm._sparse_latent_attention(
-        cfg, "indexed", bp, x[:, 20:], pos[:, 20:], cache)
+        cfg, "indexed", bp, x[:, 20:], pos[:, 20:],
+        view("indexed", slabs, at, lengths))
     np.testing.assert_allclose(np.asarray(step[:, 0]), np.asarray(whole[:, 20]), atol=2e-6)
     np.testing.assert_allclose(np.asarray(entry[:, 0]), np.asarray(entries[:, 20]), atol=1e-6)
     np.testing.assert_allclose(np.asarray(key[:, 0]), np.asarray(keys[:, 20]), atol=1e-6)
-    idx, n_sel, own_in = (np.asarray(a) for a in sel)
+    idx, n_sel, own_in = (np.asarray(a) for a in sel[:3])
     for r in range(2):
         chosen = set(idx[r, :n_sel[r]].tolist()) | ({20} if own_in[r] else set())
         assert chosen == set(np.flatnonzero(np.asarray(mask[r, 20])).tolist())
     shared, (entry_s,), _sel = decoder_lm._sparse_latent_attention(
-        cfg, "shared", bp, x[:, 20:], pos[:, 20:], ((slabs[0],), cache[1], cache[2]), sel)
+        cfg, "shared", bp, x[:, 20:], pos[:, 20:],
+        view("shared", (slabs[0],), at, lengths), sel)
     np.testing.assert_array_equal(np.asarray(shared), np.asarray(step))
     np.testing.assert_array_equal(np.asarray(entry_s), np.asarray(entry))
     assert np.abs(np.asarray(whole[:, 20] - x[:, 20])).max() > 1e-3  # attention did add something
@@ -454,12 +490,12 @@ def test_published_cut_by_hand():
         "dense", "sparse", "sparse", "sparse", "sparse"]
 
 
-def test_idle_rows_keep_their_cache_and_stay_out_of_the_active_rows(base):
+def test_idle_rows_keep_their_cache_and_stay_out_of_the_active_rows(both_ways):
     """A decode step over three rows of which the middle one is idle: what
     the idle row could read of its slabs (its first ``pos`` rows) is bit for
     bit as it was, and the active rows' logits are bit for bit what they are
     when the idle row holds another token at another position."""
-    cfg, model = base
+    cfg, model = both_ways
     dcfg = model.cfg
     caches = decoder_lm.init_cache(dcfg, 3, 32)
     prefill = jax.jit(lambda c, i, n, s: decoder_lm.prefill_slot(dcfg, model.params_, c, i, n, s))

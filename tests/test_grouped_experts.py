@@ -200,7 +200,7 @@ def test_chip_smoke_asks_for_the_kernel_and_fails_where_it_fell_back(monkeypatch
         (256, 6144, 2048, 16)]
     monkeypatch.undo()     # the cells' rule: window 32, lanes of 128
     for name in ("latent_decode_core", "ssm_decode_step", "kv_column_write",
-                 ge.NAME):
+                 "sparse_latent_decode", ge.NAME):
         monkeypatch.setenv(ENV_FLAGS[name], "interpret")
     assert [ge.plan(k["m"], k["d"], k["f"], k["dtype"])
             for k in chip_smoke.FULL["grouped_experts"]] == [

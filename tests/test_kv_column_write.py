@@ -414,7 +414,8 @@ def test_chip_smoke_asks_for_the_kernel_and_fails_where_it_fell_back(monkeypatch
     TPU platform raises with the kernel's name."""
     from deeplearning4j_tpu.nn.ops import latent_decode, ssm_decode
 
-    for name in (latent_decode.NAME, ssm_decode.NAME, "grouped_experts"):
+    for name in (latent_decode.NAME, ssm_decode.NAME, "grouped_experts",
+                 "sparse_latent_decode"):
         monkeypatch.setenv(ENV_FLAGS[name], "interpret")
     chip_smoke = _chip_smoke()
     assert chip_smoke.FULL["kv_columns"] == [

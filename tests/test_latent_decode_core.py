@@ -194,6 +194,7 @@ def test_chip_smoke_asks_for_the_kernel_and_fails_where_it_fell_back(monkeypatch
     monkeypatch.setenv(ENV_FLAGS["ssm_decode_step"], "interpret")
     monkeypatch.setenv(ENV_FLAGS["kv_column_write"], "interpret")
     monkeypatch.setenv(ENV_FLAGS["grouped_experts"], "interpret")
+    monkeypatch.setenv(ENV_FLAGS["sparse_latent_decode"], "interpret")
     default_kernel_registry().reset()
     report = chip_smoke.phase_kernels("tpu", chip_smoke.TINY)
     (verdict,) = report["registry"][latent_decode.NAME].values()

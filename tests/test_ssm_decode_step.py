@@ -259,6 +259,7 @@ def test_chip_smoke_asks_for_the_kernel_and_fails_where_it_fell_back(monkeypatch
     monkeypatch.setenv(ENV_FLAGS[latent_decode.NAME], "interpret")
     monkeypatch.setenv(ENV_FLAGS["kv_column_write"], "interpret")
     monkeypatch.setenv(ENV_FLAGS["grouped_experts"], "interpret")
+    monkeypatch.setenv(ENV_FLAGS["sparse_latent_decode"], "interpret")
     chip_smoke = _chip_smoke()
     assert chip_smoke.FULL["ssm_step"] == dict(
         heads=128, p=64, n=128, groups=1, slots=64, dtype="float32")

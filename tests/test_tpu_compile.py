@@ -256,6 +256,41 @@ def test_grouped_experts_at_the_cells_keys(one_chip, shape):
     assert not copies, copies
 
 
+def test_sparse_latent_decode_at_the_cells_key(one_chip):
+    """A latent layer's attention over a selection
+    (``nn/ops/sparse_latent_decode.py``) at the glm-5.2-ep16 cell's key (64
+    heads over rows of 640, 32 slots of 14,336, 2,048 kept), with the tile
+    the rule chooses, all layers' calls in one loop over the layer's index
+    with the position-major slab closed over whole and the walk made once:
+    one custom call in the loop's body and no copy as large as one slot's
+    part of the slab."""
+    import re
+
+    from deeplearning4j_tpu.nn.ops import sparse_latent_decode as sld
+
+    layers, slots, heads, width, t, topk = 3, 32, 64, 640, 14336, 2048
+    tile = sld.plan(t, topk)
+
+    def attend(q, new, slab, lengths, bias, own_in):
+        walk = sld.live_walk(lengths, t, tile)
+
+        def layer(carry, i):
+            return carry, sld.sparse_latent_decode(
+                q, new, slab, i, lengths, bias, own_in, walk, scale=0.0625,
+                kv_rank=512, tile=tile)
+
+        return jax.lax.scan(layer, 0, jnp.arange(layers, dtype=jnp.int32))[1]
+
+    text = _compile(
+        attend, one_chip, ((slots, heads, width), BF16), ((slots, width), BF16),
+        ((layers, slots, t, width), BF16), ((slots,), jnp.int32),
+        ((slots, 1, t), F32), ((slots,), jnp.bool_))
+    assert tile == sld.TILE and _custom_calls(text) == 1 and sld.NAME in text
+    copies = [dims for dims in re.findall(r"= \w+\[([\d,]+)\]\S* copy\(", text)
+              if math.prod(map(int, dims.split(","))) >= t * width]
+    assert not copies, copies
+
+
 def _conv_loss(conv):
     def loss(x, s, t, w):
         y, st = conv(x, s, t, w, True)
@@ -517,8 +552,10 @@ def test_latent_decoder_programs_compile_at_published_widths(one_chip):
     assert prefill.memory_analysis().temp_size_in_bytes < 3.0e9
 
 
+@pytest.mark.parametrize("core", ["gathered", "kernel"])
 def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
-                                                                    capsys):
+                                                                    capsys,
+                                                                    core):
     """``DecoderLM`` with latent attention over an indexer's selection as
     the engine builds its programs, at the glm-5.2-ep16 cell's widths and
     its whole cut (hidden 6144, 64 heads of 192 + 64 / 256 over a 2048-wide
@@ -534,10 +571,19 @@ def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
     its carry are not copied for the after-loop write either), plans no
     float32 score tensor of a whole slab, and the prefill at the longest
     bucket attends and selects by blocks, so its plan stays under the
-    4.8 GB that weights and cache leave. The plans are printed."""
+    4.8 GB that weights and cache leave. The plans are printed. With the
+    verdict of ``nn/ops/sparse_latent_decode.py`` steered on (``kernel``:
+    what the chip's probe gives; this process's backend is the CPU) the
+    decode program has one custom call a latent segment under
+    ``attn_sparse_core``, no gather there and no larger a plan; the prefill
+    program is the same one and is compiled once, where the registry
+    declines (``gathered``)."""
     import re
 
-    built = programs.build("glm", one_chip)
+    from deeplearning4j_tpu.nn.ops import sparse_latent_decode as sld
+
+    built = programs.build(
+        "glm", one_chip, without=(sld.NAME,) if core == "gathered" else ())
     S, T, caches = built.slots, built.length, built.caches
     assert [tuple(c.shape for c in seg) for seg in caches] == [
         ((1, S, T, 640), (1, S, T, 128)), ((3, S, T, 640),),
@@ -559,11 +605,24 @@ def test_sparse_latent_decoder_programs_compile_at_published_widths(one_chip,
     _grouped_products(built, 2, (6144, 2048, 16, S * 8, 32, 512, "bfloat16"))
     gathers = [line for line in text.splitlines()
                if " gather(" in line and "attn_sparse_core" in line]
+    plan = decode.memory_analysis()
+    if core == "kernel":
+        assert set(built.asked[sld.NAME]) == {
+            (64, 640, T, 2048, sld.TILE, "bfloat16")}
+        kernels = [line for line in text.splitlines()
+                   if "tpu_custom_call" in line and "attn_sparse_core" in line]
+        assert len(kernels) == 3, kernels               # one a segment
+        assert all(sld.NAME in line for line in kernels) and not gathers
+        # the gathered rows (84 MB) and their float32 scores leave the plan
+        assert plan.temp_size_in_bytes < 40e6           # the gathered: 47.2 MB
+        with capsys.disabled():
+            print(f"\nglm-5.2-ep16 decode through {sld.NAME}: temporaries "
+                  f"{plan.temp_size_in_bytes / 1e9:.3f} GB")
+        return
     assert gathers and all(f"bf16[{S},2048,640]" in g for g in gathers), gathers
     # 31 MB read: the indexer's scores of a slot's whole key slab, 32 heads
     # in float32, would be 58.7 MB a layer; they are fused into the sum over
     # heads and never planned
-    plan = decode.memory_analysis()
     assert plan.temp_size_in_bytes < 200e6
     prefill = built.prefill()
     assert built.bucket == T and not slab_copies(prefill.as_text())
